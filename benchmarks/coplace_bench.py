@@ -12,8 +12,8 @@ per-fingerprint stats stores, asserting the co-placement contract:
   q5's date dimension is DAG-shared across channels, so the rule must
   decline it and q5 doubles as placement-declines-shared coverage);
 - warm placed wall <= warm device-only wall on every plan that placed,
-  ON A REAL DEVICE BACKEND (ci/device_smoke.sh): there the host threads
-  are genuinely different silicon from the device walk, so an overlap
+  ON A REAL DEVICE BACKEND (not measured on the chip yet): there the host
+  threads are genuinely different silicon from the device walk, so an overlap
   that loses wall-clock is a placement-rule regression. Under the CPU
   nightly (JAX_PLATFORMS=cpu) the "device" walk and the host threads
   share the same cores — co-placement cannot win wall-clock by

@@ -682,3 +682,21 @@ def lockdep() -> bool:
     re-wrap existing locks."""
     return os.environ.get("SPARK_RAPIDS_TPU_LOCKDEP", "0") not in (
         "0", "", "off")
+
+
+def place_compile_cache() -> str:
+    """Place JAX's persistent compilation cache and return its directory.
+    Where JAX_COMPILATION_CACHE_DIR is set, JAX reads it itself and no
+    other directory is set in code; where it is not, the cache is
+    `<checkout>/.jax_cache` — a fixed path (the path is part of the cache
+    key: one built from a temp name, pid or time never hits). The one
+    placement rule shared by chip_smoke.py, bench.py and tests/conftest."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    import jax
+    path = os.path.join(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path

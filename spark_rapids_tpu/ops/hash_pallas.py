@@ -221,8 +221,19 @@ def _u16_halves(w) -> Tuple:
     """u32 word -> (lo16, hi16) as f32 — the split that keeps one-hot MXU
     gathers bit-exact (a single <=16-bit term per product fits the f32
     mantissa). Shared by the join/select compaction kernels."""
-    return ((w & _u32c(0xFFFF)).astype(jnp.float32),
-            (w >> _U32(16)).astype(jnp.float32))
+    # via int32: Mosaic has no uint32 -> float32 cast (both halves < 2^16)
+    return ((w & _u32c(0xFFFF)).astype(jnp.int32).astype(jnp.float32),
+            (w >> _U32(16)).astype(jnp.int32).astype(jnp.float32))
+
+
+def _exact_dot(a, b):
+    """f32 matmul at full precision. The one-hot gathers of the join/select
+    kernels carry 16-bit integers through the MXU; at the default precision
+    Mosaic feeds it bf16 passes and values above 2^8 come back rounded (seen
+    on the v5e: every compacted row wrong), so the products are pinned to
+    Precision.HIGHEST."""
+    return jnp.dot(a, b, preferred_element_type=jnp.float32,
+                   precision=jax.lax.Precision.HIGHEST)
 
 
 def _pack_inputs(cols: Sequence[Column], normalize_zero: bool, n: int,

@@ -48,8 +48,8 @@ from jax.experimental.pallas import tpu as pltpu
 from .. import dtypes
 from ..columnar import Column, Table
 from ..dtypes import Kind
-from .hash_pallas import (_mm_fmix, _mm_round, _planes, _to_tiles, _u32c,
-                          _u16_halves as _halves)
+from .hash_pallas import (_exact_dot, _mm_fmix, _mm_round, _planes,
+                          _to_tiles, _u32c, _u16_halves as _halves)
 from .join import _require_x64
 
 _LANES = 128
@@ -117,6 +117,13 @@ def _mm_hash(layout: List[int], words: List[jnp.ndarray]) -> jnp.ndarray:
     return h
 
 
+def _any(mask) -> jnp.ndarray:
+    """In-kernel `jnp.any`: Mosaic's boolean reduce goes through f64 under
+    x64 ("Only arrays with 32-bit element types can be converted to
+    scalars"), so reduce the f32 image instead."""
+    return jnp.max(mask.astype(jnp.float32)) > 0
+
+
 # ---- build kernel ------------------------------------------------------------
 
 def _build_kernel_body(layout, C: int, n_pad: int, refs):
@@ -128,7 +135,9 @@ def _build_kernel_body(layout, C: int, n_pad: int, refs):
     h = _mm_hash(layout, words)
     halves = [hh for w in words for hh in _halves(w)]
 
-    r_col = jax.lax.broadcasted_iota(jnp.float32, (n_pad, C), 0)
+    # integer iota, then cast: tpu.iota yields integer vectors only
+    r_col = jax.lax.broadcasted_iota(jnp.int32, (n_pad, C), 0) \
+        .astype(jnp.float32)
     c_ids = jax.lax.broadcasted_iota(jnp.int32, (n_pad, C), 1)
     big = jnp.float32(1e9)
 
@@ -136,14 +145,17 @@ def _build_kernel_body(layout, C: int, n_pad: int, refs):
     rowid = jnp.zeros((1, C), jnp.float32)
     tbl = tuple(jnp.zeros((1, C), jnp.float32) for _ in range(2 * n_words))
     p = jnp.zeros((1, n_pad), jnp.int32)
-    placed = ~valid                        # invalid rows never insert
+    # loop-carried masks travel as int32: Mosaic cannot legalize a loop
+    # whose carry is a boolean vector
+    placed = (~valid).astype(jnp.int32)    # invalid rows never insert
 
     def cond(st):
         d, p, placed, occ, rowid, tbl = st
-        return jnp.any(~placed) & (d < 2 * C + 2)
+        return _any(placed == 0) & (d < 2 * C + 2)
 
     def body(st):
         d, p, placed, occ, rowid, tbl = st
+        placed = placed != 0
         slot = (h + p.astype(_U32)) & _u32c(C - 1)
         slot_col = jnp.transpose(slot.astype(jnp.int32))       # (n_pad, 1)
         unplaced_col = jnp.transpose((~placed).astype(jnp.float32)) > 0
@@ -160,13 +172,12 @@ def _build_kernel_body(layout, C: int, n_pad: int, refs):
         rowid = jnp.where(won, winner, rowid)
         tbl = tuple(
             jnp.where(won,
-                      jnp.dot(half, onehot,
-                              preferred_element_type=jnp.float32), t)
+                      _exact_dot(half, onehot), t)
             for half, t in zip(halves, tbl))
         occ = jnp.where(won, jnp.float32(1), occ)
         placed = placed | placed_now
-        p = p + jnp.where(placed, 0, 1)
-        return d + 1, p, placed, occ, rowid, tbl
+        p = p + jnp.where(placed, jnp.int32(0), jnp.int32(1))
+        return d + 1, p, placed.astype(jnp.int32), occ, rowid, tbl
 
     _, _, _, occ, rowid, tbl = jax.lax.while_loop(
         cond, body, (jnp.int32(0), p, placed, occ, rowid, tbl))
@@ -217,24 +228,27 @@ def _count_kernel_body(layout, C: int, refs):
     h_col = jnp.transpose(h.astype(jnp.int32) & jnp.int32(C - 1))
     halves = [jnp.transpose(hh) for w in words for hh in _halves(w)]
     c_ids = jax.lax.broadcasted_iota(jnp.int32, (_LANES, C), 1)
-    active0 = jnp.transpose(valid)
+    # int32 image: Mosaic cannot transpose a boolean tile
+    active0 = jnp.transpose(valid.astype(jnp.int32))
     counts0 = jnp.zeros((_LANES, 1), jnp.int32)
 
+    # loop-carried masks travel as int32 (see the build kernel)
     def cond(st):
         d, active, _ = st
-        return jnp.any(active) & (d < C + 1)
+        return _any(active != 0) & (d < C + 1)
 
     def body(st):
         d, active, counts = st
+        active = active != 0
         slot = (h_col + d) & jnp.int32(C - 1)
         onehot = (slot == c_ids).astype(jnp.float32)
-        g = jnp.dot(onehot, tbl, preferred_element_type=jnp.float32)
+        g = _exact_dot(onehot, tbl)
         occ = g[:, 0:1] > 0
         eq = jnp.ones((_LANES, 1), bool)
         for j, ph in enumerate(halves):
             eq = eq & (g[:, 2 + j:3 + j] == ph)
         counts = counts + (active & occ & eq).astype(jnp.int32)
-        return d + 1, active & occ, counts
+        return d + 1, (active & occ).astype(jnp.int32), counts
 
     _, _, counts = jax.lax.while_loop(cond, body,
                                       (jnp.int32(0), active0, counts0))
@@ -253,19 +267,20 @@ def _emit_kernel_body(layout, C: int, refs):
     h_col = jnp.transpose(h.astype(jnp.int32) & jnp.int32(C - 1))
     halves = [jnp.transpose(hh) for w in words for hh in _halves(w)]
     c_ids = jax.lax.broadcasted_iota(jnp.int32, (_LANES, C), 1)
-    active0 = jnp.ones((_LANES, 1), bool)
+    active0 = jnp.ones((_LANES, 1), jnp.int32)
     seen0 = jnp.zeros((_LANES, 1), jnp.int32)
     rmap0 = jnp.zeros((_LANES, 1), jnp.int32)
 
     def cond(st):
         d, active, seen, rmap, resolved = st
-        return jnp.any(active & ~resolved) & (d < C + 1)
+        return _any((active != 0) & (resolved == 0)) & (d < C + 1)
 
     def body(st):
         d, active, seen, rmap, resolved = st
+        active, resolved = active != 0, resolved != 0
         slot = (h_col + d) & jnp.int32(C - 1)
         onehot = (slot == c_ids).astype(jnp.float32)
-        g = jnp.dot(onehot, tbl, preferred_element_type=jnp.float32)
+        g = _exact_dot(onehot, tbl)
         occ = g[:, 0:1] > 0
         rowid = g[:, 1:2].astype(jnp.int32)
         eq = jnp.ones((_LANES, 1), bool)
@@ -276,11 +291,12 @@ def _emit_kernel_body(layout, C: int, refs):
         rmap = jnp.where(hit, rowid, rmap)
         resolved = resolved | hit
         seen = seen + match.astype(jnp.int32)
-        return d + 1, active & occ, seen, rmap, resolved
+        return (d + 1, (active & occ).astype(jnp.int32), seen, rmap,
+                resolved.astype(jnp.int32))
 
     _, _, _, rmap, _ = jax.lax.while_loop(
         cond, body, (jnp.int32(0), active0, seen0, rmap0,
-                     jnp.zeros((_LANES, 1), bool)))
+                     jnp.zeros((_LANES, 1), jnp.int32)))
     out[...] = jnp.transpose(rmap)
 
 
@@ -292,18 +308,22 @@ def _run_probe(body_fn, layout, C, planes, extra_plane, tbl, out_dtype,
     def kernel(*refs):
         body_fn(layout, C, refs)
 
-    in_specs = [pl.BlockSpec((1, _LANES), lambda i: (i, i - i),
-                             memory_space=pltpu.VMEM)
-                for _ in range(n_words + 1)]
+    # (B, 1, 128) planes with the leading grid axis squeezed: each block's
+    # last two dims equal the array's (Mosaic's (8, 128) block rule)
+    def row_spec():
+        return pl.BlockSpec((None, 1, _LANES), lambda i: (i, i - i, i - i),
+                            memory_space=pltpu.VMEM)
+
+    in_specs = [row_spec() for _ in range(n_words + 1)]
     in_specs.append(pl.BlockSpec(tbl.shape, lambda i: (i - i, i - i),
                                  memory_space=pltpu.VMEM))
     out = pl.pallas_call(
         kernel,
-        out_shape=[jax.ShapeDtypeStruct((B, _LANES), out_dtype)],
-        in_specs=in_specs,
-        out_specs=[pl.BlockSpec((1, _LANES), lambda i: (i, i - i),
-                                memory_space=pltpu.VMEM)],
-        grid=(B,), interpret=interpret)(*planes, extra_plane, tbl)[0]
+        out_shape=[jax.ShapeDtypeStruct((B, 1, _LANES), out_dtype)],
+        in_specs=in_specs, out_specs=[row_spec()],
+        grid=(B,), interpret=interpret)(
+            *[p.reshape(B, 1, _LANES) for p in planes],
+            extra_plane.reshape(B, 1, _LANES), tbl)[0]
     return out.reshape(-1)
 
 

@@ -79,9 +79,9 @@ def row_layout(dts: Sequence[dtypes.DType]):
 
 def _use_word_kernel() -> bool:
     """Backend dispatch for the conversion kernels. The u32 word kernels
-    exist for TPU tiling (narrow u8 slices pad to (32, 128) tiles; measured
-    CPU A/B in BENCH_DETAIL.md round-5: the word kernel is ~1.4x SLOWER on
-    CPU where the concat lowers to clean memcpys, so CPU keeps the byte
+    exist for TPU tiling (narrow u8 slices pad to (32, 128) tiles; a CPU
+    A/B in round 5, not measured on the chip: the word kernel is ~1.4x
+    SLOWER on CPU where the concat lowers to clean memcpys, so CPU keeps the byte
     kernels). Selection lives in the kernel registry (ops/registry.py,
     docs/kernels.md): "word" is the universal fallback, "concat" registers
     for the cpu backend. Override:
@@ -427,8 +427,8 @@ def convert_from_rows(rows_col: Column, schema: Sequence[dtypes.DType]) -> Table
 # ---- kernel-registry wiring (ops/registry.py, docs/kernels.md) --------------
 # the u32 word kernels are the universal lowering (TPU tiling: narrow u8
 # slices pad to (32, 128) tiles); the byte-concat kernels register for the
-# cpu backend, where the word kernel measured ~1.4x slower (BENCH_DETAIL.md
-# round-5)
+# cpu backend, where the word kernel measured ~1.4x slower (round 5, CPU
+# only; not measured on the chip)
 from .registry import REGISTRY as _REGISTRY  # noqa: E402
 
 _REGISTRY.register("row_conversion", "word", fallback=True)

@@ -43,7 +43,7 @@ from jax.experimental.pallas import tpu as pltpu
 from ..columnar import Column, Table
 from ..dtypes import Kind
 from .gather import take
-from .hash_pallas import _to_tiles, _u16_halves
+from .hash_pallas import _exact_dot, _to_tiles, _u16_halves
 
 _LANES = 128
 _U32 = jnp.uint32
@@ -200,7 +200,7 @@ def _kernel_body(predicate, pred_layout, comp_planes: int, n: int, N: int,
     q_ids = jax.lax.broadcasted_iota(jnp.int32, (N, N), 1)
     tri = (r_ids <= q_ids).astype(jnp.float32)
     # inclusive in-block prefix: exact in f32 (counts <= N << 2^24)
-    csum = jnp.dot(maskf, tri, preferred_element_type=jnp.float32)
+    csum = _exact_dot(maskf, tri)
     pos = csum - 1.0
     mask_col = jnp.transpose(maskf)            # (N, 1)
     pos_col = jnp.transpose(pos)
@@ -210,12 +210,13 @@ def _kernel_body(predicate, pred_layout, comp_planes: int, n: int, N: int,
         x = in_refs[p][...]                    # (1, N) u32
         lo, hi = _u16_halves(x)
         # one term per one-hot column: both halves exact in f32
-        clo = jnp.dot(lo, onehot, preferred_element_type=jnp.float32)
-        chi = jnp.dot(hi, onehot, preferred_element_type=jnp.float32)
+        clo = _exact_dot(lo, onehot)
+        chi = _exact_dot(hi, onehot)
         out_refs[p][...] = (clo.astype(jnp.int32).astype(_U32)
                             | (chi.astype(jnp.int32).astype(_U32)
                                << _U32(16)))
-    cnt_ref[0, 0] = csum[0, N - 1].astype(jnp.int32)
+    cnt_ref[...] = jnp.broadcast_to(
+        csum[:, N - 1:N].astype(jnp.int32), cnt_ref.shape)
 
 
 def fused_select_compact(table: Table, predicate, needed: Sequence[str],
@@ -243,7 +244,9 @@ def fused_select_compact(table: Table, predicate, needed: Sequence[str],
     B = n_pad // N
 
     def tile(x):
-        return _to_tiles(x, n_pad, lanes=N)
+        # (B, 1, N): a leading grid axis, so each block's last two dims
+        # equal the array's (Mosaic's (8, 128) block rule)
+        return _to_tiles(x, n_pad, lanes=N).reshape(B, 1, N)
 
     planes: List[jnp.ndarray] = []
     layout: List[Tuple[str, int, Optional[bool]]] = []   # (col, nplanes, has_valid)
@@ -277,20 +280,21 @@ def fused_select_compact(table: Table, predicate, needed: Sequence[str],
     def kernel(*refs):
         _kernel_body(predicate, pred_layout, comp_planes, n, N, refs)
 
-    in_specs = [pl.BlockSpec((1, N), lambda i: (i, i - i),
-                             memory_space=pltpu.VMEM) for _ in planes]
-    out_shape = [jax.ShapeDtypeStruct((B, N), _U32)
+    def row_spec(lanes):
+        return pl.BlockSpec((None, 1, lanes), lambda i: (i, i - i, i - i),
+                            memory_space=pltpu.VMEM)
+
+    in_specs = [row_spec(N) for _ in planes]
+    out_shape = [jax.ShapeDtypeStruct((B, 1, N), _U32)
                  for _ in range(comp_planes)]
-    out_specs = [pl.BlockSpec((1, N), lambda i: (i, i - i),
-                              memory_space=pltpu.VMEM)
-                 for _ in range(comp_planes)]
-    out_shape.append(jax.ShapeDtypeStruct((B, 1), jnp.int32))
-    out_specs.append(pl.BlockSpec((1, 1), lambda i: (i, i - i),
-                                  memory_space=pltpu.SMEM))
+    out_specs = [row_spec(N) for _ in range(comp_planes)]
+    # per-block kept-row count, broadcast over one lane row
+    out_shape.append(jax.ShapeDtypeStruct((B, 1, _LANES), jnp.int32))
+    out_specs.append(row_spec(_LANES))
     outs = pl.pallas_call(
         kernel, out_shape=out_shape, in_specs=in_specs, out_specs=out_specs,
         grid=(B,), interpret=interpret)(*planes)
-    comp, counts = outs[:-1], outs[-1].reshape(-1)
+    comp, counts = outs[:-1], outs[-1][:, 0, 0]
 
     # epilogue: squeeze block-compacted planes into one contiguous relation
     total = int(jnp.sum(counts))               # the one host sync — the same

@@ -87,11 +87,19 @@ def _order_words(table: Table, keys: Sequence[str],
 
 def _topk_kernel_body(k: int, n_words: int, refs):
     in_refs, out_ref = refs[:n_words], refs[n_words]
-    snt = jnp.uint32(0xFFFFFFFF)   # built in-kernel: a module-level jnp
-    #                                constant would be a captured array
-    words = [r[...] for r in in_refs]
-    mask = jnp.ones(words[0].shape, bool)
-    k128 = out_ref.shape[2]
+    # Mosaic has no reductions over unsigned integers: the min-extraction
+    # runs on the order-preserving int32 image of each u32 word (flip the
+    # top bit, reinterpret), mapped back on the way out. Constants are
+    # built in-kernel: a module-level jnp constant would be a captured
+    # array.
+    flip = jnp.uint32(0x80000000)
+    snt = jnp.int32(0x7FFFFFFF)    # image of the u32 sentinel 0xFFFFFFFF
+    words = [jax.lax.bitcast_convert_type(r[...] ^ flip, jnp.int32)
+             for r in in_refs]
+    # the live-candidate mask is carried as int32: Mosaic cannot legalize
+    # an scf.for whose carry is a boolean vector
+    mask = jnp.ones(words[0].shape, jnp.int32)
+    k128 = out_ref.shape[-1]
     lane = jax.lax.broadcasted_iota(jnp.int32, (1, k128), 1)
     init = tuple(jnp.full((1, k128), snt) for _ in range(n_words))
 
@@ -99,7 +107,7 @@ def _topk_kernel_body(k: int, n_words: int, refs):
         mask, sels = carry
         # lexicographic min of the masked tuples: narrow the candidate set
         # word by word (each step is one VPU reduction + one compare)
-        m = mask
+        m = mask != 0
         cur = []
         for w in words:
             mv = jnp.min(jnp.where(m, w, snt))
@@ -107,13 +115,16 @@ def _topk_kernel_body(k: int, n_words: int, refs):
             cur.append(mv)
         # the iota word is unique, so m now holds at most one row; an
         # exhausted mask leaves the all-sentinel tuple (merged away later)
-        mask = mask & ~m
+        mask = jnp.where(m, jnp.int32(0), mask)
         sels = tuple(jnp.where(lane == i, c, s) for c, s in zip(cur, sels))
         return mask, sels
 
-    _, sels = jax.lax.fori_loop(0, k, body, (mask, init))
+    # int32 bounds: under x64 python-int bounds make the index an i64
+    _, sels = jax.lax.fori_loop(jnp.int32(0), jnp.int32(k), body,
+                                (mask, init))
     for wi in range(n_words):
-        out_ref[wi, :, :] = sels[wi]
+        out_ref[wi:wi + 1, :] = \
+            jax.lax.bitcast_convert_type(sels[wi], _U32) ^ flip
 
 
 def _topk_words(words: List[jnp.ndarray], k: int, n: int,
@@ -141,16 +152,18 @@ def _topk_words(words: List[jnp.ndarray], k: int, n: int,
     # guard as ops/hash_pallas.py)
     in_specs = [pl.BlockSpec((TM, _LANES), lambda i: (i, i - i),
                              memory_space=pltpu.VMEM) for _ in tiles]
-    out_spec = pl.BlockSpec((n_words, 1, k128),
-                            lambda i: (i - i, i, i - i),
+    # block-major output: the block's last two dims equal the array's (the
+    # (8, 128) divisibility rule); the leading block axis is squeezed
+    out_spec = pl.BlockSpec((None, n_words, k128),
+                            lambda i: (i, i - i, i - i),
                             memory_space=pltpu.VMEM)
     out = pl.pallas_call(
         kernel,
-        out_shape=[jax.ShapeDtypeStruct((n_words, B, k128), _U32)],
+        out_shape=[jax.ShapeDtypeStruct((B, n_words, k128), _U32)],
         in_specs=in_specs, out_specs=[out_spec],
         grid=(B,), interpret=interpret)(*tiles)[0]
     # cross-block merge: B*k128 candidates (tiny) through one XLA sort
-    cands = [out[wi].reshape(-1) for wi in range(n_words)]
+    cands = [out[:, wi, :].reshape(-1) for wi in range(n_words)]
     merged = jax.lax.sort(cands, num_keys=n_words, is_stable=False)
     return [m[:k] for m in merged]
 

@@ -28,10 +28,7 @@ from typing import List, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
-try:
-    from jax import shard_map
-except ImportError:      # jax < 0.5 keeps it in experimental
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from ..ops.join import expand_spans, join_spans

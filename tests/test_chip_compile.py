@@ -1,0 +1,216 @@
+"""Compile for the chip, without the chip.
+
+Every Pallas call that is registered for `tpu` (fused_select, hash_join,
+topk), plus `hash_pallas`, the partition histogram and the capped q3
+program, lowered and compiled for a DESCRIBED v5e:2x2 device by the TPU
+compiler installed here (`/opt/skills/guides/on-chip-measurement`, §2).
+Interpret-mode parity (tests/test_kernel_registry.py) cannot see what
+Mosaic refuses — block shapes, 64-bit values, boolean loop carries — and
+`interpret = jax.default_backend() != "tpu"` keeps every other test off
+that path. A compile that passes here is not a chip run: `chip_smoke.py`
+is.
+
+The topology is described inside a module-scoped fixture (never at import:
+only one process may hold libtpu, and every xdist worker imports this
+file), the compiles run in the test's own process, and the persistent
+compile cache is off around them (an executable built for a described
+device cannot be read back without one).
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.experimental import pallas as pl
+from jax.sharding import SingleDeviceSharding
+
+import spark_rapids_tpu  # noqa: F401  (x64 on — the regime Mosaic sees)
+from spark_rapids_tpu import Column, Table, dtypes
+
+# the smoke's shapes (chip_smoke.py / benchmarks/bench_nds_q3.py at scale 1)
+N_FACT = 10_000_000
+N_DATES = 3_650
+N_DATES_KEPT = 310          # d_moy == 11: the hash join's build side
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def no_persistent_cache():
+    from jax.experimental.compilation_cache import compilation_cache
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", prev)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture
+def chip(one_chip, no_persistent_cache, monkeypatch):
+    """-> compile_calls(fn): trace `fn` (shapes only), capture every
+    `pl.pallas_call` it makes, and compile each for the described chip with
+    interpret=False. Returns the number of calls compiled."""
+    real = pl.pallas_call
+
+    def compile_calls(fn) -> int:
+        captured = []
+
+        def capturing(kernel, *a, **kw):
+            call = real(kernel, *a, **kw)
+
+            def wrapped(*operands):
+                captured.append((kernel, a, kw, [
+                    jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+                    for x in operands]))
+                return call(*operands)
+            return wrapped
+
+        monkeypatch.setattr(pl, "pallas_call", capturing)
+        try:
+            jax.eval_shape(fn)
+        except jax.errors.ConcretizationTypeError:
+            # eager entry points sync a count to the host after their
+            # kernels ran; the calls before it are already captured
+            pass
+        finally:
+            monkeypatch.setattr(pl, "pallas_call", real)
+        for kernel, a, kw, shapes in captured:
+            call = real(kernel, *a, **{**kw, "interpret": False})
+            jax.jit(call).lower(*shapes).compile()   # raises what Mosaic would
+        return len(captured)
+
+    return compile_calls
+
+
+def _i64(n: int) -> Column:
+    return Column(dtype=dtypes.INT64, length=n,
+                  data=jnp.zeros((n,), jnp.int64))
+
+
+def _i32(n: int) -> Column:
+    return Column(dtype=dtypes.INT32, length=n,
+                  data=jnp.zeros((n,), jnp.int32))
+
+
+def test_registered_tpu_kernels_are_the_ones_compiled_here():
+    """The guard this file exists for: a kernel that lists "tpu" in its
+    `backends=` is auto-selected on the chip, so it must have a compile
+    test below. Adding a TPU registration without one fails here."""
+    from spark_rapids_tpu.ops import (join_pallas, select_pallas,  # noqa
+                                      topk_pallas)
+    from spark_rapids_tpu.ops.registry import REGISTRY
+    on_tpu = {(op, k.name) for op in REGISTRY.ops()
+              for k in REGISTRY.kernels(op)
+              if "tpu" in k.backends and not k.fallback}
+    assert on_tpu == {("fused_select", "pallas"), ("hash_join", "pallas"),
+                      ("topk", "pallas")}
+
+
+def test_fused_select_compiles_for_v5e(chip):
+    # the date dimension, with the int32 predicate column the kernel's
+    # supports() gate admits (an int64 predicate declines to XLA)
+    from spark_rapids_tpu.ops import select_pallas
+    from spark_rapids_tpu.plan import col
+    t = Table([_i64(N_DATES), _i64(N_DATES), _i32(N_DATES)],
+              names=["d_date_sk", "d_year", "d_moy"])
+    n = chip(lambda: select_pallas.fused_select_compact(
+        t, col("d_moy") == 11, ["d_date_sk", "d_year"], interpret=True))
+    assert n == 1
+
+
+@pytest.mark.parametrize("n_build", [N_DATES_KEPT, 512],
+                         ids=["q3-dates", "max-build"])
+def test_hash_join_compiles_for_v5e(chip, n_build):
+    # q3's eager fact x filtered-dates join: build, count-probe and
+    # emit-probe kernels (the capped entry point traces all three)
+    from spark_rapids_tpu.ops import join_pallas
+    n = chip(lambda: join_pallas.inner_join_capped_pallas(
+        [_i64(N_FACT)], [_i64(n_build)], N_FACT // 8, interpret=True))
+    assert n == 3
+
+
+def test_topk_compiles_for_v5e(chip):
+    from spark_rapids_tpu.ops import topk_pallas
+    t = Table([_i64(N_FACT), _i64(N_FACT)], names=["k", "v"])
+    n = chip(lambda: topk_pallas.topk_table(t, ["k"], [False], 100,
+                                            interpret=True))
+    assert n == 1
+
+
+def test_partition_histogram_compiles_for_v5e(chip):
+    from spark_rapids_tpu.parallel.partition_pallas import histogram_pallas
+    n = chip(lambda: histogram_pallas(jnp.zeros((N_FACT,), jnp.int32), 4,
+                                      interpret=True))
+    assert n == 1
+
+
+def test_row_hash_compiles_for_v5e(chip):
+    # bench.py's table: 10M rows x 2 int64, murmur3_32 + xxhash64 fused
+    from spark_rapids_tpu.ops import hash_pallas
+    t = Table([_i64(N_FACT), _i64(N_FACT)], names=["a", "b"])
+    n = chip(lambda: hash_pallas.fused_row_hash(t, interpret=True))
+    assert n == 1
+
+
+def test_capped_q3_program_compiles_for_v5e(one_chip, no_persistent_cache,
+                                            monkeypatch):
+    """The capped tier's one whole-plan program for q3, traced as the chip
+    would trace it (registry and kernels see backend "tpu") and compiled
+    for the described device. At the bench's FLOOR size (8,192 fact rows):
+    XLA's TPU compiler spends minutes on this program's nine sorts at any
+    size, and this is already the slowest test of the file — the smoke
+    compiles it at 10M rows on the chip."""
+    from benchmarks.nds_plans import q3_inputs, q3_plan
+    from spark_rapids_tpu.plan import PlanExecutor
+
+    n = 8192
+    inputs = q3_inputs(
+        Table([_i64(n)] * 3, names=["sold_date_sk", "item_sk",
+                                    "price_cents"]),
+        Table([_i64(N_DATES)] * 3, names=["d_date_sk", "d_year", "d_moy"]),
+        Table([_i64(20_000)] * 3, names=["i_item_sk", "i_brand",
+                                         "i_manufact"]))
+    captured = {}
+    real = PlanExecutor._jitted_capped
+
+    class Captured(Exception):
+        pass
+
+    def capturing(self, plan, schemas, caps, input_key):
+        fn, bm, km, hit = real(self, plan, schemas, caps, input_key)
+
+        def stop(tables):
+            captured["fn"], captured["tables"] = fn, tables
+            raise Captured()
+        return stop, bm, km, hit
+
+    monkeypatch.setattr(PlanExecutor, "_jitted_capped", capturing)
+    ex = PlanExecutor(mode="capped", degrade="off",
+                      caps=dict(row_cap=max(n // 8, 1024), key_cap=4096))
+    with pytest.raises(Captured):
+        ex.execute(q3_plan(), inputs)
+    shapes = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        captured["tables"])
+    # steer the trace from here, not through an option of the program:
+    # code that asks jax.default_backend() must take its TPU branch
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    compiled = captured["fn"].lower(shapes).compile()
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes + mem.argument_size_in_bytes \
+        + mem.output_size_in_bytes < 16 * 1024 ** 3

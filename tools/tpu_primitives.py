@@ -48,7 +48,7 @@ def main(argv=None):
     jax.config.update("jax_enable_x64", True)
     import jax.numpy as jnp
     import numpy as np
-    from benchmarks.common import steady_state_ms, sync
+    from benchmarks.common import steady_state_ms
 
     n = args.n
     platform = jax.default_backend()
@@ -57,8 +57,6 @@ def main(argv=None):
 
     def rec(name, ms, note=""):
         r = {"name": name, "n": n, "ms": round(ms, 3), "backend": platform}
-        if getattr(steady_state_ms, "last_upper_bound", False):
-            r["ms_upper_bound"] = True
         if note:
             r["note"] = note
         print(json.dumps(r), flush=True)
@@ -71,9 +69,9 @@ def main(argv=None):
         try:
             t0 = time.perf_counter()
             out = f(*arrs)
-            sync(out)
+            jax.block_until_ready(out)
             compile_s = time.perf_counter() - t0
-            ms = steady_state_ms(f, arrs, args.iters, platform)
+            ms = steady_state_ms(f, arrs, args.iters)
             rec(name, ms, note=note or f"compile {compile_s:.1f}s")
         except Exception as e:  # keep sweeping on a single failure
             print(json.dumps({"name": name, "n": n, "error": f"{type(e).__name__}: {e}"[:300]}),
