@@ -1,0 +1,610 @@
+"""Groupby hash-aggregate with Spark semantics (BASELINE.json configs[1]:
+"groupby hash-aggregate (sum/count) on single int32 key, 10M rows").
+
+The reference stack gets this from cudf's hash groupby. TPU-first design:
+hash tables are a poor fit for the MXU/VPU, but XLA's on-device sort is
+excellent — so aggregate = ONE multi-operand `lax.sort` over the key
+columns' orderable operands (shared with ops/sort.py, so null rank / NaN
+normalization / -0.0 grouping match Spark comparison semantics for free),
+then fused segment reductions over the sorted runs:
+
+    sort keys (+row iota) → run boundaries → group ids (prefix sum)
+    → jax.ops.segment_{sum,min,max} per aggregation → slice to num_groups
+
+Everything up to the final slice is a single jit; the only host sync is the
+group count, exactly like the reference's JNI ops returning row counts.
+
+Spark agg semantics implemented: sum/min/max ignore nulls (all-null group →
+null); count counts non-nulls; `size` is count(*); mean = double sum/count;
+integer sums widen to INT64 (Spark SUM(int) is LongType) and wrap on
+overflow like Java longs (non-ANSI).
+"""
+from __future__ import annotations
+
+from functools import partial
+from typing import List, Optional, Sequence, Tuple, Union
+
+import jax
+import jax.numpy as jnp
+
+from .. import dtypes
+from ..columnar import Column, Table
+from ..dtypes import Kind
+from .gather import take
+from .sort import NULLS_LAST, _key_operands
+
+AGG_OPS = ("sum", "count", "min", "max", "mean", "size")
+
+
+def _agg_value_dtype(op: str, dt: dtypes.DType) -> dtypes.DType:
+    if op in ("count", "size"):
+        return dtypes.INT64
+    if op == "mean":
+        return dtypes.FLOAT64
+    if op == "sum":
+        if dt.is_integer:
+            return dtypes.INT64
+        if dt.is_floating:
+            return dtypes.FLOAT64
+        raise TypeError(f"sum unsupported for {dt}")
+    return dt  # min/max keep the input type
+
+
+@partial(jax.jit,
+         static_argnames=("n_ops", "agg_kinds", "has_valids", "has_alive"))
+def _groupby_kernel(key_operands, agg_datas, agg_valids, *, n_ops: int,
+                    agg_kinds: Tuple[str, ...], has_valids: Tuple[bool, ...],
+                    has_alive: bool = False):
+    """Scatter-free, gather-free sorted aggregation (round-4 redesign).
+
+    On-chip primitive costs (round-2 TPU measurement, recorded in
+    docs/architecture.md:39-42; the reproducible sweep tool is
+    tools/tpu_primitives.py, whose committed CPU capture is
+    tools/primitives.jsonl — not re-measured on the chip since;
+    10M rows): sort ≈ 38 ms with cheap marginal payload operands, cumsum ≈
+    16 ms, but a RANDOM GATHER ≈ 160 ms and a random scatter ≈ 930 ms. The
+    tradeoff is BACKEND-SPECIFIC: on CPU a random scatter-add costs ~163 ms
+    against ~233 ms per tuple-carry scan (primitives.jsonl), so this design
+    measures ~0.49× the old scatter-based kernel there (tools/
+    ab_relational.jsonl) — the win this layout buys exists on TPU, where
+    scatters are ~25× a cumsum; `_use_scan_kernel` therefore dispatches
+    the segment/scatter design (_groupby_kernel_scatter) on CPU, so CPU
+    users no longer pay the regression. The
+    previous kernel did one value gather per aggregation plus 4 positional
+    gathers per cumsum-difference — gathers dominated (~0.9 s at 10M). This
+    version has zero data-sized gathers:
+
+      * value/validity columns ride the MAIN key sort as payload operands
+        (stable sort ⇒ payload order == the old gather-by-order);
+      * int sums/counts: one exclusive cumsum each; the per-group value is
+        the difference of the cumsum between CONSECUTIVE group starts, read
+        off adjacent entries after compaction — no positional gathers. The
+        compaction pad value is the cumsum total, which makes the adjacent
+        difference correct for the last group for free;
+      * float sums and min/max: one REVERSE segmented associative_scan each
+        (result lands on the group's first row — the row compaction keeps);
+      * ONE boundary-compaction sort packs every group-start row (position,
+        original row id, and all per-agg results) to the front — replacing
+        both the old starts sort and every per-agg gather. searchsorted
+        stays banned (it lowers to ~log2(n) whole-array gather passes).
+
+    Returns (num_groups, starts, first_rows, outs): all n-length, entries
+    past num_groups are padding (positions hold n), sliced/masked by the
+    caller.
+
+    `has_alive`: key_operands[0] is a dead-row flag (0 alive, 1 dead) the
+    caller prepended — the jit-pipeline contract where upstream capped ops
+    emit padded rows. Dead rows sort LAST (behind every alive group, never
+    mixing with one, since the flag operand differs) and num_groups counts
+    only alive groups, so the caller's `iota < num_groups` mask drops the
+    dead tail for free. Group sizes/aggregates need no special-casing: the
+    group after the last alive group starts exactly where the dead region
+    does, so the adjacent-difference reads stay exact.
+    """
+    n = key_operands[0].shape[0]
+    iota = jnp.arange(n, dtype=jnp.int32)
+
+    # ---- payload layout for the main sort --------------------------------
+    payloads: List = []
+    slots: List[Tuple[Optional[int], Optional[int]]] = []  # (data, valid)
+    for data, valid, op, hv in zip(agg_datas, agg_valids, agg_kinds,
+                                   has_valids):
+        d_slot = v_slot = None
+        if op not in ("size", "count"):
+            d_slot = len(payloads)
+            payloads.append(data)
+        if hv:
+            v_slot = len(payloads)
+            payloads.append(valid.astype(jnp.int8))
+        slots.append((d_slot, v_slot))
+
+    sorted_all = jax.lax.sort([*key_operands, iota, *payloads],
+                              num_keys=n_ops, is_stable=True)
+    sorted_ops = sorted_all[:n_ops]
+    order = sorted_all[n_ops]
+    spay = sorted_all[n_ops + 1:]
+
+    neq = jnp.zeros((n,), bool)
+    for o in sorted_ops:
+        neq = neq | (o != jnp.roll(o, 1))
+    boundary = neq.at[0].set(True) if n else neq   # guard: empty scatter OOB
+    ends_flag = jnp.roll(boundary, -1).at[-1].set(True) if n else boundary
+    if has_alive:
+        num_groups = jnp.sum((boundary & (sorted_ops[0] == 0))
+                             .astype(jnp.int32))
+    else:
+        num_groups = jnp.sum(boundary.astype(jnp.int32))
+
+    def rev_segscan(vals, kind: str):
+        """Reverse segmented sum/min/max: resets walking backwards at group
+        ENDS, so each group's reduction lands on its FIRST row (which the
+        compaction keeps). Floats use this for sums too — a global-cumsum
+        difference would let one NaN/Inf poison every group sorted after
+        it."""
+        def combine(a, b):
+            abound, aval = a
+            bbound, bval = b
+            if kind == "sum":
+                merged0 = aval + bval
+            elif kind == "min":
+                merged0 = jnp.minimum(aval, bval)
+            else:
+                merged0 = jnp.maximum(aval, bval)
+            return abound | bbound, jnp.where(bbound, bval, merged0)
+        _, res = jax.lax.associative_scan(combine, (ends_flag, vals),
+                                          reverse=True)
+        return res
+
+    # compaction operands: group-start rows to the front, everything they
+    # need riding along as payloads
+    pad_i32 = jnp.int32(n)
+    comp_pay: List = [jnp.where(boundary, iota, pad_i32),       # position
+                      jnp.where(boundary, order, pad_i32)]      # first row
+    # per-agg: (payload index in comp_pay, mode, pad-side info)
+    agg_comp: List = []
+    totals = {}          # comp_pay slot -> cumsum grand total (traced scalar)
+    for (d_slot, v_slot), op in zip(slots, agg_kinds):
+        ok = (spay[v_slot] == 1) if v_slot is not None else None
+        cnt_slot = None
+        if op != "size":
+            okv = ok if ok is not None else jnp.ones((n,), bool)
+            csum = jnp.cumsum(okv.astype(jnp.int64))
+            excl = csum - okv.astype(jnp.int64)
+            total = csum[-1] if n else jnp.int64(0)
+            cnt_slot = len(comp_pay)
+            totals[cnt_slot] = total
+            comp_pay.append(jnp.where(boundary, excl, total))
+        if op in ("size", "count"):
+            agg_comp.append((None, op, cnt_slot))
+            continue
+        v = spay[d_slot]
+        okv = ok if ok is not None else jnp.ones((n,), bool)
+        if op in ("sum", "mean"):
+            if v.dtype.kind == "f" or op == "mean":
+                acc = jnp.where(okv, v.astype(jnp.float64), 0.0)
+                res = rev_segscan(acc, "sum")
+                slot = len(comp_pay)
+                comp_pay.append(jnp.where(boundary, res, 0.0))
+                agg_comp.append((slot, "fsum" if op == "sum" else "mean",
+                                 cnt_slot))
+            else:
+                acc = jnp.where(okv, v.astype(jnp.int64), jnp.int64(0))
+                csum = jnp.cumsum(acc)
+                excl = csum - acc
+                total = csum[-1] if n else jnp.int64(0)
+                slot = len(comp_pay)
+                totals[slot] = total
+                # pad value = total ⇒ the adjacent difference of the last
+                # real group reads (total - its exclusive prefix) — exact
+                comp_pay.append(jnp.where(boundary, excl, total))
+                agg_comp.append((slot, "isum", cnt_slot))
+            continue
+        # min / max with null-ignoring identities. Floats go through the
+        # total-order transform so NaN behaves like Spark: NaN is greatest,
+        # min returns NaN only for an all-NaN group (plain jnp.minimum would
+        # propagate NaN over smaller real values).
+        if v.dtype.kind == "f":
+            from .sort import _float_total_order
+            tv = _float_total_order(v)
+            info = jnp.iinfo(tv.dtype)
+            ident = jnp.asarray(info.max if op == "min" else info.min,
+                                tv.dtype)
+            masked = jnp.where(okv, tv, ident)
+            ext = rev_segscan(masked, "min" if op == "min" else "max")
+            slot = len(comp_pay)
+            comp_pay.append(jnp.where(boundary, ext, ident))
+            agg_comp.append((slot, "fext:" + str(v.dtype), cnt_slot))
+        else:
+            info = jnp.iinfo(v.dtype)
+            ident = jnp.asarray(info.max if op == "min" else info.min,
+                                v.dtype)
+            masked = jnp.where(okv, v, ident)
+            ext = rev_segscan(masked, "min" if op == "min" else "max")
+            slot = len(comp_pay)
+            comp_pay.append(jnp.where(boundary, ext, ident))
+            agg_comp.append((slot, "ext", cnt_slot))
+
+    flag = jnp.where(boundary, jnp.int32(0), jnp.int32(1))
+    comp = jax.lax.sort([flag, *comp_pay], num_keys=1, is_stable=True)[1:]
+    starts, first_rows = comp[0], comp[1]
+
+    def adj_diff(arr, tail):
+        if n == 0:
+            return arr
+        return jnp.concatenate([arr[1:], jnp.full((1,), tail, arr.dtype)]) - arr
+
+    # sizes from the compacted start positions (pad n makes the last group's
+    # difference read n - start — exact)
+    sizes = adj_diff(starts.astype(jnp.int64), n)
+
+    def adj_diff_total(arr, total):
+        """Adjacent difference whose final element reads against the scalar
+        `total`; pad entries equal `total` so padded diffs are 0."""
+        if n == 0:
+            return arr
+        return jnp.concatenate([arr[1:], total[None]]) - arr
+
+    outs = []
+    for (slot, mode, cnt_slot), op in zip(agg_comp, agg_kinds):
+        cnt = None
+        if cnt_slot is not None:
+            cnt = adj_diff_total(comp[cnt_slot], totals[cnt_slot])
+        if op == "size":
+            outs.append((sizes, None))
+        elif op == "count":
+            outs.append((cnt, None))
+        elif mode == "isum":
+            s = adj_diff_total(comp[slot], totals[slot])
+            outs.append((s, cnt > 0))
+        elif mode == "fsum":
+            outs.append((comp[slot], cnt > 0))
+        elif mode == "mean":
+            s = comp[slot] / jnp.where(cnt == 0, 1, cnt).astype(jnp.float64)
+            outs.append((s, cnt > 0))
+        elif mode.startswith("fext:"):
+            ext = comp[slot]
+            info = jnp.iinfo(ext.dtype)
+            sign_bit = jnp.asarray(info.min, ext.dtype)
+            bits = jnp.where(ext < 0, ~(ext ^ sign_bit), ext)
+            fdt = jnp.dtype(mode.split(":", 1)[1])
+            outs.append((jax.lax.bitcast_convert_type(bits, fdt), cnt > 0))
+        else:   # "ext"
+            outs.append((comp[slot], cnt > 0))
+
+    return num_groups, starts, first_rows, outs
+
+
+@partial(jax.jit,
+         static_argnames=("n_ops", "agg_kinds", "has_valids", "has_alive"))
+def _groupby_kernel_scatter(key_operands, agg_datas, agg_valids, *,
+                            n_ops: int, agg_kinds: Tuple[str, ...],
+                            has_valids: Tuple[bool, ...],
+                            has_alive: bool = False):
+    """Scatter/segment-op groupby kernel — the CPU-preferred design.
+
+    Same contract as _groupby_kernel (the scan design): (num_groups,
+    starts, first_rows, outs), group order = key sort order, padding past
+    num_groups sliced/masked by the caller. The difference is the
+    aggregation step: after the ONE main key sort, per-sorted-row group ids
+    come from a cumsum of the run boundaries and every aggregate is one
+    `jax.ops.segment_{sum,min,max}` — a data-sized random scatter-add.
+    That is the round-3 design this file replaced for TPU, kept here
+    because the tradeoff is BACKEND-SPECIFIC (tools/primitives.jsonl, CPU:
+    scatter-add ~163 ms vs ~233 ms per tuple-carry scan at 10M rows; the
+    scan design measured ~0.49x the scatter kernel on CPU in tools/
+    ab_relational.jsonl). `_use_scan_kernel` picks per backend, like
+    row_conversion's _use_word_kernel.
+
+    Dead rows under `has_alive` sort last as their own groups (the leading
+    flag operand differs), so their segment ids land past every alive
+    group and their results fall in the sliced-away tail."""
+    n = key_operands[0].shape[0]
+    iota = jnp.arange(n, dtype=jnp.int32)
+
+    payloads: List = []
+    slots: List[Tuple[Optional[int], Optional[int]]] = []
+    for data, valid, op, hv in zip(agg_datas, agg_valids, agg_kinds,
+                                   has_valids):
+        d_slot = v_slot = None
+        if op not in ("size", "count"):
+            d_slot = len(payloads)
+            payloads.append(data)
+        if hv:
+            v_slot = len(payloads)
+            payloads.append(valid.astype(jnp.int8))
+        slots.append((d_slot, v_slot))
+
+    sorted_all = jax.lax.sort([*key_operands, iota, *payloads],
+                              num_keys=n_ops, is_stable=True)
+    sorted_ops = sorted_all[:n_ops]
+    order = sorted_all[n_ops]
+    spay = sorted_all[n_ops + 1:]
+
+    neq = jnp.zeros((n,), bool)
+    for o in sorted_ops:
+        neq = neq | (o != jnp.roll(o, 1))
+    boundary = neq.at[0].set(True) if n else neq
+    if has_alive:
+        num_groups = jnp.sum((boundary & (sorted_ops[0] == 0))
+                             .astype(jnp.int32))
+    else:
+        num_groups = jnp.sum(boundary.astype(jnp.int32))
+
+    # group id per sorted row; groups numbered in sorted-key order, so the
+    # per-group results land directly in compaction order
+    seg = jnp.cumsum(boundary.astype(jnp.int32)) - 1
+    # stable sort => order is increasing within a group: min(order) is the
+    # group's FIRST row, and min(position) its start
+    starts = jax.ops.segment_min(iota, seg, num_segments=n)
+    first_rows = jax.ops.segment_min(order, seg, num_segments=n)
+    sizes = jax.ops.segment_sum(jnp.ones((n,), jnp.int64), seg,
+                                num_segments=n)
+
+    outs = []
+    for (d_slot, v_slot), op in zip(slots, agg_kinds):
+        ok = (spay[v_slot] == 1) if v_slot is not None else None
+        okv = ok if ok is not None else jnp.ones((n,), bool)
+        cnt = None
+        if op != "size":
+            cnt = jax.ops.segment_sum(okv.astype(jnp.int64), seg,
+                                      num_segments=n)
+        if op == "size":
+            outs.append((sizes, None))
+            continue
+        if op == "count":
+            outs.append((cnt, None))
+            continue
+        v = spay[d_slot]
+        if op in ("sum", "mean"):
+            if v.dtype.kind == "f" or op == "mean":
+                acc = jnp.where(okv, v.astype(jnp.float64), 0.0)
+                s = jax.ops.segment_sum(acc, seg, num_segments=n)
+                if op == "mean":
+                    s = s / jnp.where(cnt == 0, 1, cnt).astype(jnp.float64)
+                outs.append((s, cnt > 0))
+            else:
+                acc = jnp.where(okv, v.astype(jnp.int64), jnp.int64(0))
+                outs.append((jax.ops.segment_sum(acc, seg, num_segments=n),
+                             cnt > 0))
+            continue
+        # min / max with null-ignoring identities; floats via the same
+        # total-order transform + bit cast back as the scan kernel
+        is_float = v.dtype.kind == "f"
+        if is_float:
+            from .sort import _float_total_order
+            tv = _float_total_order(v)
+        else:
+            tv = v
+        info = jnp.iinfo(tv.dtype)
+        ident = jnp.asarray(info.max if op == "min" else info.min, tv.dtype)
+        masked = jnp.where(okv, tv, ident)
+        ext = (jax.ops.segment_min(masked, seg, num_segments=n)
+               if op == "min"
+               else jax.ops.segment_max(masked, seg, num_segments=n))
+        if is_float:
+            sign_bit = jnp.asarray(info.min, ext.dtype)
+            bits = jnp.where(ext < 0, ~(ext ^ sign_bit), ext)
+            outs.append((jax.lax.bitcast_convert_type(bits, v.dtype),
+                         cnt > 0))
+        else:
+            outs.append((ext, cnt > 0))
+
+    return num_groups, starts, first_rows, outs
+
+
+def _use_scan_kernel() -> bool:
+    """Backend dispatch for the groupby kernel (see _groupby_kernel vs
+    _groupby_kernel_scatter — the scan design wins on TPU where scatters
+    are ~25x a cumsum, the segment/scatter design wins ~2x on CPU).
+    Selection lives in the kernel registry (ops/registry.py,
+    docs/kernels.md): "scan" is the universal fallback, "scatter"
+    registers for the cpu backend. Override:
+    SPARK_RAPIDS_TPU_KERNELS=groupby=scan|scatter (legacy
+    SPARK_RAPIDS_TPU_GROUPBY_KERNEL honored as an alias)."""
+    from .registry import REGISTRY
+    return REGISTRY.select("groupby").name == "scan"
+
+
+def groupby_aggregate(table: Table,
+                      key_names: Sequence[Union[int, str]],
+                      aggs: Sequence[Tuple[Union[int, str], str]],
+                      _cap: Optional[int] = None,
+                      _alive: Optional[jnp.ndarray] = None):
+    """Group by `key_names`, apply `aggs` [(column, op)] with op in
+    sum|count|min|max|mean|size. Returns keys + one column per agg, named
+    "op(col)". Group order = key sort order (deterministic).
+
+    `_cap` is internal (see groupby_aggregate_capped): a static output size
+    that makes the whole aggregation traceable under jax.jit. `_alive` is a
+    (num_rows,) bool excluding padded rows entirely (see
+    groupby_aggregate_capped's `alive`)."""
+    keys = [table[k] for k in key_names]
+    if not keys:
+        raise ValueError("groupby requires at least one key column")
+    for c in keys:
+        if c.dtype.kind in (Kind.LIST, Kind.STRUCT):
+            raise TypeError("nested group keys are not supported")
+
+    operands = []
+    for c in keys:
+        operands.extend(_key_operands(c, True, None))
+    if _alive is not None:
+        # leading dead-flag operand: dead rows sort last as their own
+        # groups, counted out of num_groups by the kernel (has_alive)
+        operands = [jnp.where(_alive, jnp.int32(0), jnp.int32(1))] + operands
+
+    n = table.num_rows
+    agg_datas: List = []
+    agg_valids: List = []
+    agg_kinds: List[str] = []
+    string_extremes: List[Tuple] = []       # (agg idx, col, col_ref, op)
+    for i, (col_ref, op) in enumerate(aggs):
+        if op not in AGG_OPS:
+            raise ValueError(f"unknown aggregation {op!r}")
+        if op in ("size", "count"):
+            # only validity (or nothing) is consumed; data is a placeholder
+            c = keys[0] if op == "size" else table[col_ref]
+            agg_datas.append(jnp.zeros((n,), jnp.int8))
+            agg_valids.append(None if op == "size" else c.validity)
+        elif op in ("min", "max") and table[col_ref].dtype.is_string:
+            # strings: resolved by an extra value-ordered sort (below); the
+            # kernel carries a placeholder so outputs stay index-aligned.
+            # A column's first slot carries the per-group non-null count
+            # (locates max when one shared asc sort serves both extremes).
+            first_for_col = col_ref not in [r for _, _, r, _ in string_extremes]
+            string_extremes.append((i, table[col_ref], col_ref, op))
+            agg_datas.append(jnp.zeros((n,), jnp.int8))
+            agg_valids.append(table[col_ref].validity if first_for_col else None)
+            agg_kinds.append("count" if first_for_col else "size")
+            continue
+        else:
+            c = table[col_ref]
+            if not (c.dtype.is_integer or c.dtype.is_floating
+                    or c.dtype.kind in (Kind.DATE32, Kind.TIMESTAMP_US,
+                                        Kind.TIMESTAMP_S, Kind.TIMESTAMP_MS)):
+                raise TypeError(f"{op} over {c.dtype} values is not supported")
+            agg_datas.append(c.data)
+            agg_valids.append(c.validity)
+        agg_kinds.append(op)
+
+    kernel = _groupby_kernel if _use_scan_kernel() else \
+        _groupby_kernel_scatter
+    num_groups, first_sorted, first_rows_full, outs = kernel(
+        tuple(operands), tuple(agg_datas), tuple(agg_valids),
+        n_ops=len(operands), agg_kinds=tuple(agg_kinds),
+        has_valids=tuple(v is not None for v in agg_valids),
+        has_alive=_alive is not None)
+    if _cap is None:
+        g = int(num_groups)  # the one host sync
+    else:
+        # slice what exists, pad the rest below (a fixed-cap jit pipeline
+        # must accept small batches, and a too-small cap must be retryable
+        # with a bigger one regardless of n)
+        g = min(_cap, n)
+    # padded entries hold n: clip for the gathers — rows past num_groups are
+    # garbage by contract, masked by the capped valid vector
+    first_sorted = jnp.clip(first_sorted, 0, max(n - 1, 0))
+
+    # key columns: row index (original frame) of each group's first sorted
+    # row — carried straight through the compaction sort, no order gather
+    first_rows = jnp.clip(first_rows_full[:g], 0, max(n - 1, 0))
+    # first_rows is non-negative by construction: skip take()'s any<0 sync
+    out_cols = [take(c, first_rows, _has_negative=False) for c in keys]
+    names = [table.names[k] if isinstance(k, int) else k for k in key_names]
+
+    # string min/max: ONE extra value-ordered sort per string column. With
+    # ascending NULLS_LAST order, each group's min sits at its first sorted
+    # row and its max at (start + non-null count - 1); a max-only column
+    # sorts descending so its extreme also sits at the start. take()
+    # propagates the gathered row's validity, so an all-null group (whose
+    # extreme row is null under NULLS_LAST) comes out null — Spark semantics.
+    string_results = {}
+    by_col = {}
+    for agg_idx, c, ref, op in string_extremes:
+        by_col.setdefault(ref, {"col": c, "ops": [], "cnt_idx": None})
+        by_col[ref]["ops"].append((agg_idx, op))
+        if by_col[ref]["cnt_idx"] is None:
+            by_col[ref]["cnt_idx"] = agg_idx        # first slot carries count
+    for ref, info in by_col.items():
+        c = info["col"]
+        wants = {op for _, op in info["ops"]}
+        ascending = "min" in wants                  # max-only sorts desc
+        vops = _key_operands(c, ascending, NULLS_LAST)
+        srt = jax.lax.sort([*operands, *vops,
+                            jnp.arange(n, dtype=jnp.int32)],
+                           num_keys=len(operands) + len(vops), is_stable=True)
+        order2 = srt[-1]
+        starts = first_sorted[:g]
+        at_start = take(c, jnp.take(order2, starts, axis=0),
+                        _has_negative=False)
+        at_last = None
+        if wants == {"min", "max"}:
+            cnt = outs[info["cnt_idx"]][0][:g]       # per-group non-null count
+            last_pos = starts + jnp.maximum(cnt, 1).astype(jnp.int32) - 1
+            at_last = take(c, jnp.take(order2, last_pos, axis=0),
+                           _has_negative=False)
+        for agg_idx, op in info["ops"]:
+            if op == "min" or wants != {"min", "max"}:
+                string_results[agg_idx] = at_start
+            else:
+                string_results[agg_idx] = at_last
+
+    for i, ((data, valid), (col_ref, op)) in enumerate(zip(outs, aggs)):
+        cname = (col_ref if isinstance(col_ref, str)
+                 else table.names[col_ref]) if op != "size" else "*"
+        if i in string_results:
+            out_cols.append(string_results[i])
+            names.append(f"{op}({cname})")
+            continue
+        src_dt = dtypes.INT64 if op == "size" else table[col_ref].dtype
+        dt = _agg_value_dtype(op, src_dt)
+        d = data[:g]
+        if dt.kind == Kind.INT64 and d.dtype != jnp.int64:
+            d = d.astype(jnp.int64)
+        v = None if valid is None else valid[:g]
+        out_cols.append(Column(dtype=dt, length=g,
+                               data=d.astype(dt.storage_dtype()), validity=v))
+        names.append(f"{op}({cname})")
+
+    if _cap is None:
+        return Table(out_cols, names)
+    out_cols = [_pad_column(c, _cap) for c in out_cols]
+    valid = jnp.arange(_cap, dtype=jnp.int32) < num_groups
+    return Table(out_cols, names), valid, num_groups > _cap
+
+
+def _pad_column(col: Column, to: int) -> Column:
+    """Pad a column to `to` rows with masked garbage (capped-output
+    contract: rows past the real group count are selected away by the
+    caller's valid vector)."""
+    n = col.length
+    if n >= to:
+        return col
+    extra = to - n
+    validity = None
+    if col.validity is not None:
+        validity = jnp.concatenate([col.null_mask,
+                                    jnp.zeros((extra,), bool)])
+    if col.dtype.is_string:
+        last = col.offsets[-1] if n else jnp.int32(0)
+        offsets = jnp.concatenate(
+            [col.offsets, jnp.full((extra,), last, jnp.int32)])
+        return Column(dtype=col.dtype, length=to, data=col.data,
+                      offsets=offsets, validity=validity)
+    data = jnp.concatenate(
+        [col.data, jnp.zeros((extra,) + col.data.shape[1:], col.data.dtype)])
+    return Column(dtype=col.dtype, length=to, data=data, validity=validity)
+
+
+def groupby_aggregate_capped(table: Table,
+                             key_names: Sequence[Union[int, str]],
+                             aggs: Sequence[Tuple[Union[int, str], str]],
+                             key_cap: int,
+                             alive: Optional[jnp.ndarray] = None):
+    """Jit-friendly groupby: identical semantics to groupby_aggregate but a
+    static `key_cap` output size instead of the group-count host sync, so
+    whole pipelines fuse into one XLA program (the same padded contract as
+    parallel.distributed_groupby).
+
+    `alive`, if given, is a (num_rows,) bool excluding rows entirely (not
+    null-semantics — the row just isn't there): the contract that lets a
+    capped upstream op (inner_join_capped, a filter-as-mask) feed this
+    groupby inside ONE jit without compaction.
+
+    Returns (Table padded to key_cap rows, valid (key_cap,) bool, overflow
+    scalar). Rows past the real group count are garbage and masked by
+    `valid`; overflow True means key_cap was too small — retry bigger
+    (SplitAndRetry contract)."""
+    return groupby_aggregate(table, key_names, aggs, _cap=key_cap,
+                             _alive=alive)
+
+
+# ---- kernel-registry wiring (ops/registry.py, docs/kernels.md) --------------
+# the scan design is the universal lowering (TPU-first: scatters are ~25x a
+# cumsum there); the scatter/segment design registers for the cpu backend,
+# where it measured ~2x the scan design (tools/ab_relational.jsonl)
+from .registry import REGISTRY as _REGISTRY  # noqa: E402
+
+_REGISTRY.register("groupby", "scan", fn=_groupby_kernel, fallback=True)
+_REGISTRY.register("groupby", "scatter", fn=_groupby_kernel_scatter,
+                   backends=("cpu",))

@@ -1,0 +1,383 @@
+"""Hash-join equivalent: equi-join gather maps with Spark null semantics
+(BASELINE.json configs[2]: "hash inner-join on two int64-keyed tables,
+10M×1M"; the reference stack gets joins from cudf's hash join, returning
+gather maps the plugin applies — the same contract here).
+
+TPU-first design: device hash tables fight the hardware (scatter-heavy,
+dynamic occupancy); XLA's sorter + scans are native. Round-4 redesign is
+SCATTER-FREE end to end — the round-2 on-chip numbers (recorded in
+docs/architecture.md:39-42; reproducible via tools/tpu_primitives.py, CPU
+capture committed as tools/primitives.jsonl) put a random scatter at
+~930 ms for 10M rows under x64 emulation while a 2-operand int32 sort is
+~40 ms and a cumsum ~16 ms, and the previous pipeline spent three scatters
+per join. Measured A/B vs the old design (tools/ab_relational.jsonl,
+10M×1M): 1.14× faster even on CPU, where scatters are cheap. The join
+is ONE union sort + scans + two small routing sorts:
+
+1. union sort: concatenate left+right key columns, ONE multi-operand
+   `lax.sort` over their orderable operands (shared with ops/sort.py, so
+   cross-type normalization — NaN, -0.0, decimal limbs, string words — is
+   consistent), carrying two payloads: the row iota and a "matchable right
+   row" flag. Equal keys form runs.
+2. in-sort span computation: a cumsum of the matchable flag gives, at each
+   sorted position, the count of matchable right rows at or before it.
+   Every row's match span in "matchable-right union order" is then
+       lo = exclusive count at its run START (forward segmented copy)
+       hi = inclusive count at its run END   (reverse segmented copy)
+   — two `lax.associative_scan`s, no searchsorted (which lowers to
+   ~log2(n) whole-array gather passes on TPU, ~2 s at 10M).
+3. routing sorts: `lo`/`hi` ride ONE inverse-permutation sort (keyed by the
+   iota payload) back to original row order — a permutation scatter would
+   be ~20x slower on-chip. The right-side gather map targets come from one
+   boundary-compaction sort that packs matchable right rows (in union
+   order) to the front.
+4. expand: exclusive-scan the counts, then jnp.repeat (cumsum + a
+   sorted-unique scatter under the hood) recovers (left row, k-th match)
+   for every output slot. Both sides come back as gather maps; -1 marks
+   outer-join non-matches (take() turns them into null rows).
+
+Null keys never match (Spark equi-join); null-safe equality (<=>) is the
+`null_equal` flag, like cudf's null_equality::EQUAL — null rows get their
+own leading rank operand (ops/sort.py), so they form their own runs and
+match each other exactly when the validity masks say they may.
+"""
+from __future__ import annotations
+
+from functools import partial
+from typing import List, Sequence, Tuple
+
+import jax
+import jax.numpy as jnp
+
+from .. import dtypes
+from ..columnar import Column, Table
+from .sort import _key_operands
+
+__all__ = ["inner_join", "left_join", "full_join", "left_semi_join",
+           "left_anti_join",
+           "inner_join_capped", "left_join_capped", "semi_join_mask",
+           "join_spans", "expand_spans"]
+
+
+def _concat_columns(a: Column, b: Column) -> Column:
+    """Concatenate two same-dtype key columns. Full dtype equality is
+    required: decimal keys with different scale/precision would otherwise be
+    compared on raw unscaled values (cudf also rejects)."""
+    from .copying import _concat2
+    try:
+        return _concat2(a, b)
+    except TypeError as e:
+        raise TypeError(f"join key {e}") from None
+
+
+def _seg_copy(flag, vals):
+    """Per position: `vals` at the most recent flagged position (forward).
+    Positions before the first flag keep vals[0]; callers guarantee
+    flag[0] is True. The 'latest flagged value' combine is associative, so
+    this is one log-depth associative_scan, not a sequential loop."""
+    def combine(a, b):
+        ab, av = a
+        bb, bv = b
+        return ab | bb, jnp.where(bb, bv, av)
+    return jax.lax.associative_scan(combine, (flag, vals))[1]
+
+
+def _seg_copy_rev(flag, vals):
+    """Per position: `vals` at the nearest flagged position at-or-after it
+    (reverse segmented copy); callers guarantee flag[-1] is True."""
+    def combine(a, b):
+        ab, av = a
+        bb, bv = b
+        return ab | bb, jnp.where(bb, bv, av)
+    return jax.lax.associative_scan(combine, (flag, vals), reverse=True)[1]
+
+
+@partial(jax.jit, static_argnames=("n_ops", "nl", "need_rorder"))
+def _join_kernel(operands, lvalid, rvalid, *, n_ops: int, nl: int,
+                 need_rorder: bool):
+    """Scatter-free span computation over the union sort.
+
+    Returns (counts, lo, rorder) in ORIGINAL left-row order:
+      counts[i] — number of matching (valid) right rows for left row i
+      lo[i]     — first match position in `rorder`
+      rorder    — matchable right-row ids packed to the front, union-sorted
+                  (length n union frame; entries past the matchable count
+                  are n and never addressed: hi <= matchable count)
+    """
+    n = operands[0].shape[0]
+    nr = n - nl
+    iota = jnp.arange(n, dtype=jnp.int32)
+    # matchable = valid right row; carried as a sort payload (a marginal
+    # sort operand is ~4x cheaper on-chip than a post-sort gather)
+    matchable = jnp.concatenate([jnp.zeros((nl,), jnp.int32),
+                                 rvalid.astype(jnp.int32)])
+    out = jax.lax.sort([*operands, iota, matchable], num_keys=n_ops,
+                       is_stable=True)
+    sorted_ops, order, m_s = out[:-2], out[-2], out[-1]
+
+    neq = jnp.zeros((n,), bool)
+    for o in sorted_ops:
+        neq = neq | (o != jnp.roll(o, 1))
+    boundary = neq.at[0].set(True) if n else neq   # guard: empty scatter OOB
+    ends = jnp.roll(boundary, -1).at[-1].set(True) if n else boundary
+
+    rcnt = jnp.cumsum(m_s)                       # inclusive matchable count
+    excl = rcnt - m_s
+    lo_pos = _seg_copy(boundary, excl)           # lo of each row's run
+    hi_pos = _seg_copy_rev(ends, rcnt)           # hi of each row's run
+
+    # route lo/hi back to original row order: ONE 3-operand sort keyed by
+    # the iota payload (order is a permutation, so this inverts it)
+    routed = jax.lax.sort([order, lo_pos, hi_pos], num_keys=1)
+    lo_orig, hi_orig = routed[1][:nl], routed[2][:nl]
+    counts = jnp.where(lvalid, hi_orig - lo_orig, 0)
+
+    if need_rorder:
+        # pack matchable right-row ids (union-sorted order) to the front
+        flag = jnp.where(m_s == 1, jnp.int32(0), jnp.int32(1))
+        rid = jnp.where(m_s == 1, order - nl, jnp.int32(n))
+        rorder = jax.lax.sort([flag, rid], num_keys=1, is_stable=True)[1]
+    else:
+        rorder = jnp.zeros((0,), jnp.int32) if nr == 0 else iota[:0]
+    return counts, lo_orig, rorder
+
+
+@partial(jax.jit, static_argnames=("total", "outer"))
+def _expand(counts, lo, rorder, *, total: int, outer: bool, eff=None):
+    """`eff`, if given, is the per-row EMIT count (overrides the default
+    outer rule of max(counts, 1)): rows with eff 0 produce no output slot,
+    so a caller excluding rows (an alive mask) gets a live-slot prefix with
+    no permute — output slots are allocated to emitting rows in row order
+    by the exclusive scan."""
+    nl = counts.shape[0]
+    if nl == 0:     # static: empty left side expands to all-dead slots
+        return (jnp.zeros((total,), jnp.int32),
+                jnp.full((total,), -1, jnp.int32))
+    if eff is None:
+        eff = jnp.maximum(counts, 1) if outer else counts
+    starts = jnp.cumsum(eff) - eff            # exclusive scan
+    # which left row produced output slot j: repeat row ids by their counts
+    # (jnp.repeat with a static total lowers to cumsum + a sorted-unique
+    # scatter + max-scan — no per-slot binary search, and sorted-unique
+    # scatter is the one fast scatter form on-chip)
+    lsel = jnp.repeat(jnp.arange(nl, dtype=jnp.int32), eff,
+                      total_repeat_length=total)
+    j = jnp.arange(total, dtype=jnp.int32)
+    k = j - jnp.take(starts, lsel, axis=0)
+    matched = jnp.take(counts, lsel, axis=0) > 0
+    if rorder.shape[0] == 0:                  # static shape: empty right side
+        rmap = jnp.full((total,), -1, jnp.int32)
+    else:
+        rpos = jnp.take(lo, lsel, axis=0) + k
+        rmap = jnp.take(rorder, jnp.clip(rpos, 0, rorder.shape[0] - 1), axis=0)
+        rmap = jnp.where(matched, rmap, -1) if outer else rmap
+    return lsel, rmap
+
+
+def join_spans(operands, lvalid, rvalid, *, nl: int, need_rorder: bool = True):
+    """PUBLIC span kernel — the cross-module contract consumed by
+    parallel/relational.py's shard-local join tails (imported at module top
+    there, so a refactor here fails at collection time, not at runtime).
+
+    operands: orderable sort operands of the CONCATENATED left+right keys
+    (raw key words work: the kernel sorts whatever it is given). lvalid
+    (nl,) / rvalid (n-nl,) are the MATCH masks — masked-out left rows get
+    count 0, masked-out right rows are never matched. Returns
+    (counts, lo, rorder) in original left-row order; see _join_kernel."""
+    operands = tuple(operands)
+    return _join_kernel(operands, lvalid, rvalid, n_ops=len(operands),
+                        nl=nl, need_rorder=need_rorder)
+
+
+def expand_spans(counts, lo, rorder, *, total: int, outer: bool = False,
+                 eff=None):
+    """PUBLIC padded span expansion (companion to join_spans): materialize
+    (left row, right row) gather maps into a fixed `total` slots; under
+    `outer` every left row emits >=1 slot and unmatched rows get right -1.
+    `eff` overrides the per-row emit count (rows with eff 0 emit nothing —
+    the alive-mask idiom; see _expand)."""
+    return _expand(counts, lo, rorder, total=total, outer=outer, eff=eff)
+
+
+def _prep(left_keys, right_keys, null_equal: bool, need_rorder: bool = True,
+          lalive=None, ralive=None):
+    lcols, rcols = list(left_keys), list(right_keys)
+    if len(lcols) != len(rcols) or not lcols:
+        raise ValueError("join requires equal, nonzero key column counts")
+    union_ops: List[jnp.ndarray] = []
+    for a, b in zip(lcols, rcols):
+        # operands are built on the CONCATENATED keys: for strings the
+        # operand count depends on the padded width, so building them on the
+        # union guarantees both sides agree on the encoding
+        u = _concat_columns(a, b)
+        union_ops.extend(_key_operands(u, True, None))
+    nl = lcols[0].length
+
+    def side_valid(cols, n):
+        v = jnp.ones((n,), bool)
+        any_mask = False
+        for c in cols:
+            if c.validity is not None:
+                v = v & c.validity
+                any_mask = True
+        return v if (any_mask and not null_equal) else jnp.ones((n,), bool)
+
+    lvalid = side_valid(lcols, nl)
+    rvalid = side_valid(rcols, rcols[0].length)
+    # alive masks exclude rows ENTIRELY (padded rows of a capped upstream
+    # op, filters-as-masks) — unlike null keys they bind even under <=>
+    if lalive is not None:
+        lvalid = lvalid & lalive
+    if ralive is not None:
+        rvalid = rvalid & ralive
+    return _join_kernel(tuple(union_ops), lvalid, rvalid,
+                        n_ops=len(union_ops), nl=nl, need_rorder=need_rorder)
+
+
+def _cols(keys) -> Sequence[Column]:
+    if isinstance(keys, Column):
+        return [keys]
+    if isinstance(keys, Table):
+        return list(keys.columns)
+    return list(keys)
+
+
+def inner_join(left_keys, right_keys,
+               null_equal: bool = False) -> Tuple[Column, Column]:
+    """Gather maps (left_map, right_map) of the inner equi-join."""
+    counts, lo, rorder = _prep(_cols(left_keys), _cols(right_keys), null_equal)
+    total = int(jnp.sum(counts))              # the one host sync
+    lmap, rmap = _expand(counts, lo, rorder, total=total, outer=False)
+    return (Column(dtype=dtypes.INT32, length=total, data=lmap),
+            Column(dtype=dtypes.INT32, length=total, data=rmap))
+
+
+def left_join(left_keys, right_keys,
+              null_equal: bool = False) -> Tuple[Column, Column]:
+    """Left outer join: every left row appears; non-matches get right -1
+    (take() nullifies)."""
+    counts, lo, rorder = _prep(_cols(left_keys), _cols(right_keys), null_equal)
+    total = int(jnp.sum(jnp.maximum(counts, 1)))
+    lmap, rmap = _expand(counts, lo, rorder, total=total, outer=True)
+    return (Column(dtype=dtypes.INT32, length=total, data=lmap),
+            Column(dtype=dtypes.INT32, length=total, data=rmap))
+
+
+def _require_x64(op_name: str) -> None:
+    """The capped joins' total-match guard sums counts in int64; with
+    jax_enable_x64 off, `astype(jnp.int64)` silently degrades to int32 and
+    the overflow flag wraps at 2^31 total matches. The flag is enabled at
+    package import, but a host app embedding this engine can flip it back —
+    fail loudly instead of corrupting the guard."""
+    if not jax.config.jax_enable_x64:
+        raise RuntimeError(
+            f"{op_name} requires jax_enable_x64 (enabled at spark_rapids_tpu "
+            "import): its match-count overflow guard sums in int64 and would "
+            "silently wrap at 2^31 matches under 32-bit mode")
+
+
+def inner_join_capped(left_keys, right_keys, row_cap: int, *,
+                      lalive=None, ralive=None, null_equal: bool = False):
+    """Jit-traceable inner equi-join: a static `row_cap` output instead of
+    the match-count host sync, so whole pipelines (join → join → groupby)
+    fuse into ONE XLA program — the single-chip analogue of
+    parallel.relational's shard-local join tail, sharing its SplitAndRetry
+    contract (overflow True ⇒ retry with a bigger row_cap).
+
+    `lalive`/`ralive` exclude rows entirely (padded rows from a capped
+    upstream op, or dim-table filters applied as masks — the jit tier's
+    filter idiom: a predicate costs one mask AND, not a compaction).
+
+    Returns (lmap, rmap, valid, overflow): (row_cap,) int32 gather maps into
+    the original frames (dead slots hold 0 and are masked by `valid`), a
+    (row_cap,) bool row mask, and a scalar overflow flag."""
+    _require_x64("inner_join_capped")
+    counts, lo, rorder = _prep(_cols(left_keys), _cols(right_keys),
+                               null_equal, lalive=lalive, ralive=ralive)
+    total = jnp.sum(counts.astype(jnp.int64))   # i32 sum could wrap at 10M×
+    lmap, rmap = _expand(counts, lo, rorder, total=row_cap, outer=False)
+    valid = jnp.arange(row_cap, dtype=jnp.int32) < total
+    nr = _cols(right_keys)[0].length
+    # valid slots carry genuine in-range matches; dead slots are clamped to
+    # row 0 so downstream gathers never need a host sync or a fill value
+    lmap = jnp.where(valid, lmap, 0)
+    rmap = jnp.where(valid, jnp.clip(rmap, 0, max(nr - 1, 0)), 0)
+    return lmap, rmap, valid, total > row_cap
+
+
+def left_join_capped(left_keys, right_keys, row_cap: int, *,
+                     lalive=None, ralive=None, null_equal: bool = False):
+    """Jit-traceable left-outer equi-join (the outer sibling of
+    inner_join_capped): every ALIVE left row emits at least one output
+    slot; unmatched rows get right -1, surfaced as `rvalid` False. Rows
+    excluded by `lalive` emit nothing — a zero per-row emit count drops
+    them from the expansion entirely, so live output slots stay a prefix
+    under the static cap with no permute (see _expand's `eff`).
+
+    Returns (lmap, rmap, rvalid, valid, overflow): (row_cap,) int32 gather
+    maps (dead/unmatched slots clamped to 0), rvalid marking slots whose
+    right side is real, valid marking live slots, and the overflow flag."""
+    _require_x64("left_join_capped")
+    counts, lo, rorder = _prep(_cols(left_keys), _cols(right_keys),
+                               null_equal, lalive=lalive, ralive=ralive)
+    eff = jnp.maximum(counts, 1)
+    if lalive is not None:
+        eff = jnp.where(lalive, eff, 0)   # excluded rows emit nothing
+    total = jnp.sum(eff.astype(jnp.int64))
+    lmap, rmap = _expand(counts, lo, rorder, total=row_cap, outer=True,
+                         eff=eff)
+    valid = jnp.arange(row_cap, dtype=jnp.int32) < total
+    rvalid = valid & (rmap >= 0)
+    nr = _cols(right_keys)[0].length
+    lmap = jnp.where(valid, lmap, 0)
+    rmap = jnp.where(rvalid, jnp.clip(rmap, 0, max(nr - 1, 0)), 0)
+    return lmap, rmap, rvalid, valid, total > row_cap
+
+
+def semi_join_mask(left_keys, right_keys, *, lalive=None, ralive=None,
+                   null_equal: bool = False) -> jnp.ndarray:
+    """Jit-traceable semi-join as a MASK: True for (alive) left rows with at
+    least one (alive) right match. The left frame never moves — a semi/anti
+    join inside a jitted pipeline is a mask AND, not a compaction
+    (left_semi_join's nonzero() host sync is the eager-tier form). Anti is
+    the caller's `lalive & ~mask`."""
+    counts, _, _ = _prep(_cols(left_keys), _cols(right_keys), null_equal,
+                         need_rorder=False, lalive=lalive, ralive=ralive)
+    return counts > 0
+
+
+def full_join(left_keys, right_keys,
+              null_equal: bool = False) -> Tuple[Column, Column]:
+    """Full outer join: left_join's output plus one (-1, j) row per
+    UNMATCHED right row j (cudf::full_join's gather-map contract; take()
+    turns the -1s into null rows on either side). The unmatched-right set
+    comes from one swapped-sides span pass (counts only, no expansion)."""
+    lmap, rmap = left_join(left_keys, right_keys, null_equal)
+    extra = left_anti_join(right_keys, left_keys, null_equal).data
+    n_extra = int(extra.shape[0])
+    total = lmap.length + n_extra
+    ldata = jnp.concatenate([lmap.data,
+                             jnp.full((n_extra,), -1, jnp.int32)])
+    rdata = jnp.concatenate([rmap.data, extra])
+    return (Column(dtype=dtypes.INT32, length=total, data=ldata),
+            Column(dtype=dtypes.INT32, length=total, data=rdata))
+
+
+def left_semi_join(left_keys, right_keys,
+                   null_equal: bool = False) -> Column:
+    """Left rows having >=1 match (gather map into the left table)."""
+    counts, _, _ = _prep(_cols(left_keys), _cols(right_keys), null_equal,
+                         need_rorder=False)
+    keep = jnp.nonzero(counts > 0)[0].astype(jnp.int32)
+    return Column(dtype=dtypes.INT32, length=int(keep.shape[0]), data=keep)
+
+
+def left_anti_join(left_keys, right_keys,
+                   null_equal: bool = False) -> Column:
+    """Left rows having no match — Spark NOT IN/anti join. NB: rows with a
+    null key have no match, so they ARE returned (cudf behavior; Spark's
+    NOT IN null semantics are built on top by the plugin)."""
+    counts, _, _ = _prep(_cols(left_keys), _cols(right_keys), null_equal,
+                         need_rorder=False)
+    keep = jnp.nonzero(counts == 0)[0].astype(jnp.int32)
+    return Column(dtype=dtypes.INT32, length=int(keep.shape[0]), data=keep)

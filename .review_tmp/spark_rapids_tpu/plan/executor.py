@@ -1,0 +1,2333 @@
+"""Plan executor: walks the operator DAG and runs it on one of three tiers.
+
+Before tier dispatch, `execute()` runs the rule-based logical optimizer
+(`plan/optimizer.py`, docs/optimizer.md) over the bound plan — column
+pruning, predicate/limit pushdown, constant folding, Filter+Project
+fusion, join build-side selection — and executes the rewritten DAG;
+`SPARK_RAPIDS_TPU_OPTIMIZER=off` or `PlanExecutor(optimize=False)`
+disables it. `PlanResult.optimizer` reports what fired.
+
+- `mode="eager"`: per-operator dispatch through the public `ops` kernels —
+  every operator gets its own wall-clock, rows/bytes metrics, a
+  `utils.tracing` range, a plan-level faultinj interception point, and a
+  bounded, backoff-paced re-run on recoverable injected faults (the
+  plan-level retry that replaces per-query hand-wiring).
+- `mode="capped"`: the whole DAG traces into ONE XLA program with static
+  capacities (`row_cap` for joins, `key_cap` for aggregates — per-node
+  overrides take precedence). A too-small cap raises the overflow flag and
+  `parallel.autoretry.auto_retry_overflow` grows every cap geometrically
+  and re-traces — SplitAndRetry at PLAN granularity, not per-call. The
+  compiled program is cached per (plan FINGERPRINT, caps, input
+  shapes+names) and the final capacities are memoized per fingerprint, so
+  escalated caps are remembered for the rest of the job AND structurally
+  identical plans built independently share compiled programs
+  (`optimizer.plan_fingerprint`).
+- distributed (eager tier only — execute() rejects a mesh with
+  mode="capped" when the plan contains a distributed-lowerable operator):
+  when a device `mesh` is given, the whole plan runs as SPMD over the mesh
+  (plan/distributed.py, docs/distributed.md): Scans shard row-wise,
+  Filter/Project stay elementwise-sharded, joins run shuffle
+  (hash-exchange both sides) or broadcast (replicate the small build
+  side, chosen by the optimizer's `exchange_planning` rule from row
+  estimates), aggregates fuse the two-phase partial→all-to-all→final
+  program behind their `Exchange` (elided entirely when the input is
+  already partitioned by a subset of the group keys), Sort/TopK
+  sample-sort to global order, and the result gathers to one device only
+  at the sink — or at the first operator with no distributed form, the
+  same graceful-boundary pattern as the streaming tier's concat. All
+  static capacities escalate via `parallel.autoretry` and memoize per
+  plan fingerprint.
+
+Admission (`runtime.admission`) applies per operator automatically: the
+executor calls the public `ops` surface through module attribute lookup, so
+the admission wrappers — and any installed faultinj shims — intercept every
+kernel the plan dispatches. Pass `session=` to scope a DeviceSession to the
+execution without touching process-global state.
+
+Failure handling is a *policy*, owned by `runtime.health` (docs/
+robustness.md): transient faults (injected nonfatal asserts, substituted
+return codes, RetryOOM spikes) retry with jittered exponential backoff
+against a per-plan-attempt retry budget; sticky (same op keeps failing) and
+fatal (`DeviceFatalError`) failures trip the circuit breaker and — with the
+default `degrade="cpu"` — the remaining plan re-executes on the CPU backend
+tier, salvaging completed operator outputs through host memory. `explain()`
+is unchanged; `profile()`/`PlanResult` record `degraded`, `backoff_ms`, and
+the breaker snapshot so a degraded run is visible after the fact. While the
+breaker is open the device is quarantined (plans run fully degraded);
+`health.reset_device()` arms a half-open probation and a cheap heartbeat
+probe op decides whether normal execution resumes.
+
+Results carry `profile()` — per-operator rows (live rows in the capped
+tier, computed on-device and returned with the result), output buffer
+bytes, wall time, retry and cap-escalation counts.
+
+Feedback loop (plan/stats.py, docs/adaptive.md): after every successful
+execution the per-op metrics, final caps, and kernel timings record into
+the per-fingerprint stats store under the backend the result ran on
+("cpu" for degraded results). The next execution of the same fingerprint
+consumes them — observed cardinalities re-pick join build sides and
+exchange modes (through `optimize(stats=...)`, every stats-driven
+rewrite re-verified), the capped tier seeds its caps at the observed
+high-water (no escalation ladder on warm runs), the streaming tier sizes
+morsels from observed decode throughput, and the kernel registry demotes
+kernels that benched slower than their fallback. `SPARK_RAPIDS_TPU_STATS
+=off` restores fully static behavior.
+"""
+from __future__ import annotations
+
+import threading
+import time
+from typing import Callable, Dict, List, Optional, Tuple
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from .. import dtypes
+from ..columnar import Column, Table
+from .builder import Plan
+from .metrics import OperatorMetrics, render_profile
+from .nodes import (Exchange, Filter, FusedSelect, HashAggregate, HashJoin,
+                    Limit, PlanNode, PlanValidationError, Project, Scan,
+                    Sort, TopK, Union)
+from .expr import ColumnRef
+
+# The device-fault surface the executor turns into policy (runtime/health):
+# injected nonfatal asserts and substituted return codes plus RetryOOM
+# pressure spikes classify transient (jittered backoff + budgeted retry);
+# DeviceFatalError classifies fatal and is NEVER retried on the device —
+# a dead device must stop the retry loop, that is the whole point of the
+# fatal tier. Sticky/fatal failures trip the breaker; with degrade="cpu"
+# the remaining plan re-executes on the CPU backend tier.
+def _fault_surface():
+    from .. import faultinj
+    from ..runtime.adaptor import CpuRetryOOM, RetryOOM
+    return (faultinj.DeviceFatalError, faultinj.DeviceAssertError,
+            faultinj.InjectedReturnCode, RetryOOM, CpuRetryOOM)
+
+
+def _ops():
+    # attribute lookups on the module keep admission + faultinj shims live
+    from .. import ops
+    return ops
+
+
+def _sessionctx():
+    from ..runtime import sessionctx
+    return sessionctx
+
+
+# one bounded-cache definition for the whole engine (utils/lru.py): the
+# executor's program/caps memos and the optimizer cache share it
+from ..utils.lru import LruDict as _LruDict
+
+
+def bind_scan_sources(plan: Plan, inputs: Optional[Dict]) -> Dict:
+    """The ONE scan-binding prologue: a Scan carrying its own parquet
+    binding needs no inputs= entry; an explicit entry (Table or source)
+    for the same name wins. Shared by execute() and the serving layer's
+    submit path (serving/scheduler.py) — the binding the cache digest and
+    quota charge are computed from must be the binding that executes."""
+    inputs = dict(inputs or {})
+    for s in plan.scans:
+        if s.source not in inputs and s.parquet is not None:
+            inputs[s.source] = s.parquet
+    return inputs
+
+
+def _cpu_device():
+    try:
+        return jax.devices("cpu")[0]
+    except Exception:
+        return None
+
+
+def _table_to_cpu(t: Table, dev) -> Table:
+    """Salvage a table onto the CPU backend through host memory (the
+    degraded tier's handoff for results computed before the breaker
+    tripped). Distributed-tier sharded relations gather + compact first
+    (their live rows ARE the relation). Streaming source bindings pass
+    through untouched — they are host-side handles the CPU tier re-reads
+    directly."""
+    import dataclasses
+
+    if hasattr(t, "to_local_table"):          # plan.distributed.ShardedRel
+        t = t.to_local_table()
+    if not isinstance(t, Table):
+        return t
+
+    def put(a):
+        if a is None:
+            return None
+        try:
+            if a.devices() == {dev}:
+                return a            # already home: no host round-trip
+        except Exception:
+            pass
+        return jax.device_put(np.asarray(a), dev)
+
+    def col_cpu(c: Column) -> Column:
+        return dataclasses.replace(
+            c, data=put(c.data), validity=put(c.validity),
+            offsets=put(c.offsets),
+            children=type(c.children)(col_cpu(k) for k in c.children))
+
+    if dev is None:
+        return t
+    return Table([col_cpu(c) for c in t.columns], names=list(t.names))
+
+
+def _np_dtype_to_dt(np_dt) -> dtypes.DType:
+    m = {"b": dtypes.BOOL, "i1": dtypes.INT8, "i2": dtypes.INT16,
+         "i4": dtypes.INT32, "i8": dtypes.INT64,
+         "f4": dtypes.FLOAT32, "f8": dtypes.FLOAT64}
+    np_dt = np.dtype(np_dt)
+    key = "b" if np_dt.kind == "b" else f"{np_dt.kind}{np_dt.itemsize}"
+    if key not in m:
+        raise PlanValidationError(
+            f"expression produced unsupported dtype {np_dt}")
+    return m[key]
+
+
+def _col_from_array(arr) -> Column:
+    dt = _np_dtype_to_dt(arr.dtype)
+    return Column(dtype=dt, length=int(arr.shape[0]), data=arr)
+
+
+def _input_has_floats(t) -> bool:
+    """Any floating column in a bound Table or streaming source (unknown
+    dtypes count as floats — the conservative direction for every gate
+    that consumes this)."""
+    if isinstance(t, Table):
+        return any(
+            np.issubdtype(np.dtype(c.dtype.storage_dtype()), np.floating)
+            for c in t.columns)
+    return bool(getattr(t, "has_floats", True))
+
+
+# ---- co-placement dispatch (placement rule, docs/optimizer.md#placement) ----
+
+def _subtree_sources(node: PlanNode) -> frozenset:
+    """Scan sources reachable from `node` — invariant under optimizer
+    rewrites (pruning narrows a scan's projection but keeps its source;
+    fusions and Sort+Limit->TopK rebuild nodes but never move a scan
+    across a join boundary), which is what makes it a rewrite-stable
+    subtree identity for the remap below."""
+    out = set()
+    stack, seen = [node], set()
+    while stack:
+        n = stack.pop()
+        if id(n) in seen:
+            continue
+        seen.add(id(n))
+        if isinstance(n, Scan):
+            out.add(n.source)
+        stack.extend(n.children)
+    return frozenset(out)
+
+
+def _remap_placement_labels(authored, plan, labels):
+    """Serving-forced placement labels name AUTHORED subtree roots
+    (serving/scheduler._partial_placement admits against the authored
+    cert); the executed plan may have rebuilt the root under a new label
+    (Sort+Limit fused to TopK, Filter+Project to FusedSelect). Labels
+    present in the executed plan pass through; a renamed one remaps to
+    the unique MAXIMAL executed node reading the same scan-source set —
+    ambiguity (two joins over the same sources) skips the label rather
+    than guessing, so a lost remap costs only the offload, never
+    correctness."""
+    executed = {n.label for n in plan.nodes}
+    by_label = {n.label: n for n in authored.nodes}
+    parents: Dict[int, List[PlanNode]] = {}
+    for n in plan.nodes:
+        for c in n.children:
+            parents.setdefault(id(c), []).append(n)
+    out = []
+    for lbl in labels:
+        if lbl in executed:
+            out.append(lbl)
+            continue
+        a = by_label.get(lbl)
+        if a is None:
+            continue
+        srcs = _subtree_sources(a)
+        matches = [n for n in plan.nodes if n is not plan.root
+                   and _subtree_sources(n) == srcs]
+        ids = {id(n) for n in matches}
+        maximal = [n for n in matches
+                   if all(id(p) not in ids
+                          for p in parents.get(id(n), []))]
+        if len(maximal) == 1:
+            out.append(maximal[0].label)
+    return out
+
+
+class _PendingHostRel:
+    """A host-placed subtree still in flight on a co-placement worker
+    thread (the PendingRel async-resolve shape from plan/distributed.py
+    applied to a WHOLE subtree): the main walk launches every host
+    subtree up front — a placed subtree is self-contained, its leaves
+    bind only to plan inputs — and keeps executing the device side; the
+    consuming operator `resolve()`s at its join point. The host wall
+    that ran while the main thread was NOT blocked waiting here is the
+    consumer's measured `placement_overlap_ms`. The join is LOCK-FREE
+    (a bare timeout-less `Thread.join`, no engine lock held — the
+    lint_concurrency blocking-under-lock rule's contract). A host
+    failure raises the original error ONCE at the consumer, whose
+    fault-retry loop gets REAL re-execution: each later resolve re-runs
+    the subtree synchronously instead of re-raising a cached error."""
+
+    pending = True
+
+    def __init__(self, fn, root_label: str):
+        self._fn = fn
+        self.root_label = root_label
+        self._outputs = None        # id(node) -> Table, whole subtree
+        self._node_metrics = None   # label -> OperatorMetrics
+        self._err = None
+        self._t0 = self._t1 = 0.0
+        self._resolved = False
+
+        def work():
+            self._t0 = time.perf_counter()
+            try:
+                # _run_host_subtree blocks per node, so the subtree has
+                # genuinely COMPLETED on the thread — otherwise "async"
+                # would just defer the host work to the consumer and the
+                # overlap would be fiction
+                self._outputs, self._node_metrics = fn()
+            except BaseException as e:      # surfaces at the consumer
+                self._err = e
+            finally:
+                self._t1 = time.perf_counter()
+
+        self._thread = threading.Thread(
+            target=work, daemon=True, name="spark-rapids-tpu-coplace")
+        self._thread.start()
+
+    def resolve(self, consumer_metric: Optional[OperatorMetrics] = None):
+        """(outputs by node id, metrics by label); stamps the overlap on
+        `consumer_metric` at the first (joining) resolve."""
+        if not self._resolved:
+            w0 = time.perf_counter()
+            self._thread.join()
+            blocked = time.perf_counter() - w0
+            self._resolved = True
+            if consumer_metric is not None:
+                dur = self._t1 - self._t0
+                consumer_metric.placement_overlap_ms = \
+                    max(0.0, dur - blocked) * 1e3
+        if self._outputs is None:
+            err, self._err = self._err, None
+            if err is not None:
+                raise err
+            self._outputs, self._node_metrics = self._fn()
+        return self._outputs, self._node_metrics
+
+
+class _StreamBreaker(Exception):
+    """A streaming chain hit an unrecoverable fault (breaker tripped):
+    carries the original error plus the retry cost already paid, so the
+    degraded re-run still reports it."""
+
+    def __init__(self, error, retries: int, backoff_ms: float):
+        super().__init__(str(error))
+        self.error = error
+        self.retries = retries
+        self.backoff_ms = backoff_ms
+
+
+class _SyncFeed:
+    """Prefetch disabled (SPARK_RAPIDS_TPU_IO_PREFETCH=0): decode inline
+    on the executing thread. Same surface as _ChunkPrefetcher."""
+
+    def __init__(self, gen):
+        self._gen = gen
+        self.decode_intervals = []
+        self.decode_ms = 0.0
+
+    def get(self):
+        t0 = time.perf_counter()
+        try:
+            chunk = next(self._gen)
+        except StopIteration:
+            return None
+        t1 = time.perf_counter()
+        self.decode_intervals.append((t0, t1))
+        self.decode_ms += (t1 - t0) * 1e3
+        return chunk
+
+    def close(self):
+        self._gen.close()
+
+
+class _ChunkPrefetcher:
+    """Bounded host-side prefetch thread: decodes chunk N+1 (up to `depth`
+    ahead) while the consumer executes chunk N — the double-buffer that
+    overlaps host bitstream decode with device execution (StreamBox-HBM's
+    pipelined-chunk shape; the native decode releases the GIL, so the
+    overlap is real CPU concurrency, not just queueing)."""
+
+    _DONE = object()
+
+    def __init__(self, gen, depth: int):
+        import queue
+        self._gen = gen
+        self._q = queue.Queue(maxsize=max(1, depth))
+        self._stop = False
+        self._err = None
+        self.decode_intervals = []      # (start, end) per decoded chunk
+        self.decode_ms = 0.0
+        self._thread = threading.Thread(target=self._run, daemon=True,
+                                        name="spark-rapids-tpu-io-prefetch")
+        self._thread.start()
+
+    def _run(self):
+        try:
+            while not self._stop:
+                t0 = time.perf_counter()
+                try:
+                    chunk = next(self._gen)
+                except StopIteration:
+                    break
+                t1 = time.perf_counter()
+                self.decode_intervals.append((t0, t1))
+                self.decode_ms += (t1 - t0) * 1e3
+                self._q.put(chunk)
+        except BaseException as e:       # surfaces at the consumer's get()
+            self._err = e
+        finally:
+            self._q.put(self._DONE)
+
+    def get(self):
+        """Next decoded chunk, or None at end of stream. Re-raises a
+        decode-thread error on the consumer thread."""
+        item = self._q.get()
+        if item is self._DONE:
+            if self._err is not None:
+                raise self._err
+            return None
+        return item
+
+    def close(self):
+        """Unblock and retire the decode thread (consumer aborted early, or
+        end-of-stream cleanup): keep draining until the thread exits so a
+        put() blocked on a full queue always wakes."""
+        import queue
+        self._stop = True
+        while self._thread.is_alive():
+            try:
+                while True:
+                    self._q.get_nowait()
+            except queue.Empty:
+                pass
+            self._thread.join(timeout=0.05)
+        try:
+            self._gen.close()   # release the reader (mmap/file handle) now,
+        except Exception:       # not at GC — the degraded tier may be about
+            pass                # to re-open the same file
+
+
+def _interval_overlap_ms(decode, process) -> float:
+    """Total wall time decode intervals and processing intervals ran
+    concurrently — the prefetch pipeline's measured win. Linear merge:
+    each list is chronological and internally non-overlapping (sequential
+    decode, sequential execution)."""
+    total = 0.0
+    i = j = 0
+    while i < len(decode) and j < len(process):
+        s1, e1 = decode[i]
+        s2, e2 = process[j]
+        total += max(0.0, min(e1, e2) - max(s1, s2))
+        if e1 < e2:
+            i += 1
+        else:
+            j += 1
+    return total * 1e3
+
+
+# HashAggregate ops that decompose into per-chunk partials + an exact
+# merge over the partial rows (count/size merge by summing counts)
+_STREAM_AGG_MERGE = {"sum": "sum", "count": "sum", "size": "sum",
+                     "min": "min", "max": "max"}
+
+
+class PlanResult:
+    """Output of one plan execution.
+
+    `table` is the result relation; in the capped tier it is PADDED and
+    `valid` marks the live rows (`compact()` materializes just those).
+    `metrics` maps node label -> OperatorMetrics; `profile()` renders them.
+    """
+
+    def __init__(self, plan: Plan, table: Table,
+                 valid: Optional[jnp.ndarray],
+                 metrics: Dict[str, OperatorMetrics],
+                 mode: str, wall_ms: float, attempts: int = 1,
+                 caps: Optional[Dict[str, int]] = None, retries: int = 0,
+                 degraded: bool = False,
+                 breaker: Optional[Dict] = None,
+                 backoff_ms: float = 0.0,
+                 jit_cache_hits: int = 0):
+        self.plan = plan              # the EXECUTED plan (optimized form
+        #                               when the optimizer ran; metric
+        #                               labels refer to its nodes)
+        self.table = table
+        self.valid = valid
+        self.metrics = metrics
+        self.mode = mode
+        self.wall_ms = wall_ms
+        self.attempts = attempts      # capped-tier cap-escalation attempts
+        self.caps = caps              # final (possibly grown) capacities
+        self.retries = retries        # plan-level recoverable-fault re-runs
+        self.degraded = degraded      # finished on the CPU tier (breaker trip)
+        self.breaker = breaker        # {"state","trips","reason","error"
+        #                               [,"worker_id" in a fleet]}
+        self.backoff_ms = backoff_ms  # total retry backoff across the plan
+        self.jit_cache_hits = jit_cache_hits  # capped-tier fingerprint-keyed
+        #                               compiled-program reuses this execute
+        self.optimizer = None         # OptimizeReport.to_dict() when the
+        #                               optimizer ran (set by execute())
+        self.cert = None              # analysis/footprint.ResourceCert for
+        #                               the executed plan (set by execute();
+        #                               None when the certifier declined)
+        self.session = ""             # serving-session stamp (docs/serving
+        #                               .md): set by execute() from the
+        #                               active sessionctx scope, "" outside
+        #                               the serving layer
+        self.worker = ""              # fleet worker stamp (serving/fleet
+        #                               .py): the executor's worker_id, ""
+        #                               outside a fleet — on a cache-hit
+        #                               COPY it names the worker that
+        #                               COMPUTED the entry, which is how
+        #                               the soak proves cross-worker
+        #                               cache locality
+        self.cached = False           # served from the serving result cache
+        #                               (serving/cache.py): True ONLY on a
+        #                               cache-hit COPY — its metrics are
+        #                               deep copies, so profile/bench
+        #                               consumers never double-attribute
+        #                               the original run's wall time
+
+    def compact(self) -> Table:
+        """Live rows only (identity in the eager tier)."""
+        if self.valid is None:
+            return self.table
+        idx = jnp.asarray(np.nonzero(np.asarray(self.valid))[0],
+                          dtype=jnp.int32)
+        return _ops().take_table(self.table, idx, _has_negative=False)
+
+    def profile(self) -> List[Dict]:
+        """Per-operator metric rows (post-run observability artifact)."""
+        return [m.to_dict() for m in self.metrics.values()]
+
+    def profile_text(self) -> str:
+        return render_profile(list(self.metrics.values()),
+                              plan_wall_ms=self.wall_ms,
+                              attempts=self.attempts, caps=self.caps,
+                              degraded=self.degraded, breaker=self.breaker,
+                              optimizer=self.optimizer,
+                              jit_cache_hits=self.jit_cache_hits,
+                              cert=self.cert)
+
+
+class _CappedRel:
+    """A relation inside the capped trace: padded table + live-row mask."""
+
+    __slots__ = ("table", "alive")
+
+    def __init__(self, table: Table, alive: jnp.ndarray):
+        self.table = table
+        self.alive = alive
+
+
+class PlanExecutor:
+    """Executes validated Plans. One executor may run many plans; compiled
+    capped programs are cached per (plan, caps)."""
+
+    def __init__(self, mode: str = "eager",
+                 caps: Optional[Dict[str, int]] = None,
+                 max_cap_attempts: int = 6,
+                 op_retries: int = 2,
+                 mesh=None, mesh_axis: str = "data",
+                 session=None,
+                 block_per_op: bool = True,
+                 health=None,
+                 degrade: Optional[str] = None,
+                 optimize: Optional[bool] = None,
+                 cert_budget: Optional[int] = None,
+                 worker_id: str = ""):
+        if mode not in ("eager", "capped"):
+            raise ValueError(f"unknown executor mode {mode!r}")
+        # mesh + capped is checked PER PLAN in execute(): only a plan that
+        # actually contains a distributed-lowerable operator is rejected
+        # (naming it), so trivial row-wise plans still run capped
+        from .. import config
+        from ..runtime.health import DeviceHealthMonitor
+        self.mode = mode
+        self.caps = dict(caps or {})
+        self.max_cap_attempts = max_cap_attempts
+        self.op_retries = op_retries
+        self.mesh = mesh
+        self.mesh_axis = mesh_axis
+        self.session = session
+        # fleet worker identity (serving/fleet.py): stamped on every
+        # result and per-op metric this executor produces, "" outside a
+        # fleet — failure attribution and the soak's cross-worker
+        # cache-locality proof both need to know WHICH worker ran a plan
+        self.worker_id = str(worker_id)
+        self.block_per_op = block_per_op
+        # health: the degradation policy owner (runtime/health.py). Pass a
+        # shared monitor to give several executors one breaker per device.
+        self.health = health if health is not None else DeviceHealthMonitor()
+        self.degrade = degrade if degrade is not None else config.breaker_degrade()
+        if self.degrade not in ("cpu", "off"):
+            raise ValueError(f"unknown degrade policy {self.degrade!r} "
+                             "(expected cpu or off)")
+        # rule-based logical optimizer (plan/optimizer.py): on by default,
+        # SPARK_RAPIDS_TPU_OPTIMIZER=off or optimize=False disables
+        self.optimize = (config.optimizer_enabled() if optimize is None
+                         else bool(optimize))
+        # admission-time footprint budget (analysis/footprint.py): a plan
+        # whose certified per-operator residency hi-bound exceeds this is
+        # rejected (or degraded, per SPARK_RAPIDS_TPU_CERT_ADMISSION)
+        # before any compilation. None defers to the
+        # SPARK_RAPIDS_TPU_CERT_BUDGET_BYTES knob; 0 disables.
+        self.cert_budget = cert_budget
+        self._opt_cache = _LruDict(64)  # (root, bound sig) -> (plan, schemas,
+        #                                 report): one rewrite per binding
+        self._cert_cache = _LruDict(64)  # (root, binding sig) ->
+        #                                 ResourceCert: one certify walk
+        #                                 per binding, not per execute
+        self._verify_cache = _LruDict(128)  # passed pre-execution-gate
+        #                                 verdicts: repeat executions of a
+        #                                 cached (plan, binding) rewrite
+        #                                 skip re-verification (failures
+        #                                 raise and are never cached)
+        self._jit_cache: Dict[Tuple, Tuple[Callable, Dict]] = _LruDict(64)
+        # escalated capacities survive per plan STRUCTURE (keyed by the
+        # canonical fingerprint — optimizer.plan_fingerprint), so the next
+        # execute() of this plan, or of an equivalent plan built
+        # independently, starts from the grown caps instead of re-paying
+        # the whole overflow ladder
+        self._caps_memo: Dict[str, Dict[str, int]] = _LruDict(256)
+        # distributed-tier capacity memo: (fingerprint, node index) ->
+        # final escalated caps, same contract as _caps_memo
+        self._dist_caps_memo: Dict[Tuple, Dict] = _LruDict(256)
+
+    def _check_capped_mesh(self, plan: Plan) -> None:
+        """mode="capped" with a mesh: reject ONLY plans that contain a
+        distributed-lowerable operator (the capped tier would silently run
+        it on one chip), naming the offending node."""
+        if self.mesh is None or self.mode == "eager":
+            return
+        for n in plan.nodes:
+            if isinstance(n, (Exchange, HashJoin, HashAggregate, Sort,
+                              TopK, Union)):
+                raise PlanValidationError(
+                    f"{n.label}: distributed lowering (mesh=) exists only "
+                    "in the eager tier; a capped executor would silently "
+                    f"run this {n.kind} on one chip — drop the mesh or use "
+                    "mode=\"eager\"")
+
+    # ---- entry point ------------------------------------------------------
+    def execute(self, plan: Plan,
+                inputs: Optional[Dict[str, Table]] = None,
+                tier: Optional[str] = None,
+                placement=None) -> PlanResult:
+        """Run `plan` over `inputs`. `tier` pins the execution tier:
+        None/"device" is the normal path (device with breaker-gated CPU
+        degradation); "cpu" runs the WHOLE plan on the degraded CPU tier
+        without touching the device — the serving layer's route for
+        over-quota admission under the degrade policy and for draining a
+        queue while the breaker is open (docs/serving.md).
+
+        `placement` (iterable of node LABELS) forces those subtrees onto
+        co-placement host worker threads in addition to anything the
+        optimizer's placement rule annotated — the serving layer's
+        partial-placement route (SPARK_RAPIDS_TPU_SERVING_OVER_QUOTA=
+        partial, docs/serving.md#partial-placement): offload enough of an
+        over-quota plan to host threads that the device remainder fits
+        the session quota. Labels that do not survive the optimizer
+        rewrite, or that fail the executor's subtree-exclusivity
+        validation, are silently skipped (execution stays correct; only
+        the offload is lost). Eager tier only — the capped tier traces
+        one XLA program and has no per-subtree dispatch to overlap."""
+        if tier not in (None, "device", "cpu"):
+            raise ValueError(f"unknown execution tier {tier!r} "
+                             "(expected device or cpu)")
+        self._check_capped_mesh(plan)
+        inputs = bind_scan_sources(plan, inputs)
+        missing = [s for s in plan.input_names if s not in inputs]
+        if missing:
+            raise PlanValidationError(f"unbound plan input(s) {missing}")
+        # full validation against the bound tables' actual schemas —
+        # authored-plan errors surface against authored labels, BEFORE any
+        # optimizer rewrite renames nodes (streaming sources expose .names
+        # from the parquet footer, so the same contract applies)
+        bound = {name: tuple(t.names) for name, t in inputs.items()}
+        schemas = plan.resolve_schemas(bound)
+        report = None
+        authored = plan
+        if self.optimize:
+            plan, schemas, report = self._optimized(plan, inputs, bound)
+        from .. import config
+        if config.verify_plans():
+            self._verify_execution(authored, plan, report, inputs, bound)
+        # the AUTHORED fingerprint keys the adaptive feedback loop
+        # (plan/stats.py): cold and warm executions of one authored plan
+        # share it even when a stats-driven rewrite changes the executed
+        # plan's fingerprint (so warm cap seeding survives a build-side
+        # flip via the global cap keys)
+        source_fp = authored.fingerprint
+        # static resource certifier (analysis/footprint.py): sound
+        # per-operator [lo, hi] row and byte bounds over the plan about
+        # to run — stamped on the result, consulted by the capped tier's
+        # cold-run cap seeding, and compared against the device budget
+        # BEFORE any compilation when one is configured
+        cert = self._certify(plan, inputs, bound)
+        # merged co-placement annotations (plan/optimizer.py placement
+        # rule, docs/optimizer.md#placement): the optimizer's observed/
+        # certified host placements plus any serving-forced labels.
+        # Annotation-only — the tree is never mutated; each label is
+        # re-validated against the EXECUTED plan's structure in
+        # _execute_eager (subtree exclusivity, no exchanges, no
+        # streaming-chain overlap) before a worker thread launches.
+        placements: Dict[str, str] = {}
+        if report is not None and not report.fell_back:
+            placements.update(report.placements)
+        if placement:
+            for lbl in _remap_placement_labels(authored, plan, placement):
+                placements[lbl] = "host"
+        res = None
+        if tier == "cpu":
+            # pinned to the degraded tier: same machinery as a breaker
+            # trip, without consulting the device budget (it does not
+            # bind on the CPU tier)
+            self.health.start_plan_attempt()
+            res = self._execute_degraded(
+                plan, inputs, schemas, {}, {}, start=0,
+                t_plan0=time.perf_counter(), mode=self.mode)
+        budget = (self.cert_budget if self.cert_budget is not None
+                  else config.cert_budget_bytes())
+        if res is None and budget and cert is not None:
+            violations = cert.over_budget(budget)
+            if violations:
+                from ..analysis.footprint import ResourceAdmissionError
+                if config.cert_admission() == "reject":
+                    raise ResourceAdmissionError(
+                        violations, "admission gate: certified footprint "
+                        f"exceeds the {budget} B device budget")
+                # degrade: the device budget does not bind on the CPU
+                # tier — run the whole plan there, same machinery as a
+                # breaker trip, without touching the device
+                self.health.start_plan_attempt()
+                res = self._execute_degraded(
+                    plan, inputs, schemas, {}, {}, start=0,
+                    t_plan0=time.perf_counter(), mode=self.mode)
+        if res is None:
+            if self.session is not None:
+                from ..runtime.admission import active_session
+                with active_session(self.session):
+                    res = self._execute(plan, inputs, schemas, source_fp,
+                                        cert, placements)
+            else:
+                res = self._execute(plan, inputs, schemas, source_fp,
+                                    cert, placements)
+        res.cert = cert
+        # serving-session stamp (runtime/sessionctx.py, docs/serving.md):
+        # results and per-op metrics carry the tenant they executed for —
+        # dispatcher worker threads are multiplexed across sessions, so
+        # thread identity cannot answer this after the fact
+        sid = _sessionctx().current_session_id()
+        if sid is not None:
+            res.session = sid
+            for mm in res.metrics.values():
+                mm.session = sid
+        if self.worker_id:
+            res.worker = self.worker_id
+            for mm in res.metrics.values():
+                mm.worker_id = self.worker_id
+        if report is not None:
+            res.optimizer = report.to_dict()
+        from . import stats as stats_mod
+        store = stats_mod.active_store()
+        if store is not None:
+            # record only what actually ran, under the backend it ran
+            # ON: a degraded result finished on the CPU tier and must
+            # never drive device-side decisions (docs/adaptive.md)
+            store.record_result(
+                plan, res,
+                backend="cpu" if res.degraded else jax.default_backend(),
+                source_fp=source_fp)
+        return res
+
+    def _verify_execution(self, authored, plan, report, inputs, bound):
+        """Debug-mode pre-execution gate (SPARK_RAPIDS_TPU_VERIFY_PLANS,
+        on in tests — docs/analysis.md): the plan about to run must pass
+        the static verifier. Schema propagation and (for Table bindings)
+        dtype typing always check; the rewrite-pair invariants check when
+        the optimizer ran; partitioning soundness checks when
+        exchange_planning placed distributed boundaries. Raises
+        PlanVerificationError naming the invariant and operator."""
+        from ..analysis import verifier
+        input_dtypes = {
+            name: {cn: c.dtype for cn, c in zip(t.names, t.columns)}
+            for name, t in inputs.items() if isinstance(t, Table)}
+        floats = any(_input_has_floats(t) for t in inputs.values())
+        planned = (report is not None and not report.fell_back
+                   and self.mesh is not None and self.mode == "eager"
+                   and self.mesh.shape[self.mesh_axis] > 1)
+        # verdicts memoize on everything the checks read — a repeat
+        # execution of the same (plan, binding) pays nothing, the same
+        # contract as the rewrite cache feeding it
+        key = (authored.root, plan.root, tuple(sorted(bound.items())),
+               tuple((n, tuple(repr(d) for d in cols.values()))
+                     for n, cols in sorted(input_dtypes.items())),
+               floats, planned,
+               None if report is None else (report.fingerprint,
+                                            report.fell_back))
+        if self._verify_cache.get(key):
+            return
+        if report is None and plan is authored:
+            rep = verifier.verify(plan, bound=bound,
+                                  input_dtypes=input_dtypes,
+                                  float_inputs=floats)
+        else:
+            rep = verifier.verify_rewrite(authored, plan, bound=bound,
+                                          input_dtypes=input_dtypes,
+                                          float_inputs=floats,
+                                          planned=planned, report=report)
+        rep.raise_if_failed("pre-execution gate")
+        self._verify_cache[key] = True
+
+    def _optimized(self, plan, inputs, bound):
+        """Rewrite `plan` through the rule pipeline, once per (plan,
+        binding): repeat executions reuse the cached rewrite (and through
+        the fingerprint-keyed program cache, the compiled XLA program)."""
+        from .optimizer import optimize as run_optimizer
+        # fp reductions are not reorder-exact: float columns anywhere in
+        # the inputs disable the row-reordering build_side rule. The flag
+        # is part of the cache KEY — a rewrite computed from integer
+        # inputs must not be served to a float binding of the same
+        # names/shapes (the gate would be bypassed by the cache hit)
+        floats = any(_input_has_floats(t) for t in inputs.values())
+        # scans bound to streaming sources: the scan_pruning rule fires
+        # only for these, so the set belongs in the cache key too
+        streaming = frozenset(n for n, t in inputs.items()
+                              if not isinstance(t, Table))
+        # the exchange_planning rule fires only for a meshed eager
+        # executor, and its placements depend on the mesh width AND the
+        # broadcast threshold (read at use time per config.py's
+        # monkeypatch contract) — all of it belongs in the cache key
+        from .. import config
+        mesh_peers = (self.mesh.shape[self.mesh_axis]
+                      if self.mesh is not None and self.mode == "eager"
+                      else None)
+        bc_rows = config.broadcast_rows() if mesh_peers else None
+        bc_bytes = config.broadcast_bytes() if mesh_peers else None
+        # verify mode changes which plan survives a mid-pipeline invalid
+        # rewrite (per-rule fall-back), so it belongs in the cache key too
+        verify_rules = config.verify_plans()
+        # column dtypes feed the resource certifier's byte bounds (the
+        # broadcast byte-legality proof and the certified estimator
+        # tier), so the dtype signature belongs in the cache key: a
+        # rewrite proven over i8 columns must not serve an i64 binding
+        # of the same names/shapes
+        input_dtypes = {
+            name: {cn: c.dtype for cn, c in zip(t.names, t.columns)}
+            for name, t in inputs.items() if isinstance(t, Table)}
+        dtype_sig = tuple(
+            (name, tuple((cn, repr(dt)) for cn, dt in cols.items()))
+            for name, cols in sorted(input_dtypes.items()))
+        # adaptive rewrites consume the stats store's observations, so
+        # the store's generation joins the key: a cached rewrite must not
+        # outlive the observations it ignored (each successful execution
+        # records, so warm executions re-optimize — the rewrite pipeline
+        # is cheap next to execution, and only paid while stats are on)
+        from . import stats as stats_mod
+        store = stats_mod.active_store()
+        stats_gen = None if store is None else (store.uid,
+                                                store.generation)
+        # the placement rule's decisions depend on the knob state AND the
+        # cold-path byte threshold (read at use time per config.py's
+        # monkeypatch contract) — both join the cache key
+        placement_on = config.placement_enabled()
+        placement_bytes = config.placement_bytes() if placement_on else None
+        key = (plan.root, tuple(sorted(bound.items())),
+               tuple(sorted((n, t.num_rows) for n, t in inputs.items())),
+               floats, streaming, mesh_peers, bc_rows, bc_bytes,
+               verify_rules, dtype_sig, stats_gen,
+               placement_on, placement_bytes)
+        hit = self._opt_cache.get(key)
+        if hit is None:
+            bound_rows = {n: t.num_rows for n, t in inputs.items()}
+            backend = jax.default_backend()
+            opt, report = run_optimizer(
+                plan, bound, bound_rows,
+                float_inputs=floats, streaming_sources=streaming,
+                mesh_peers=mesh_peers, verify_rules=verify_rules,
+                stats=store, backend=backend, input_dtypes=input_dtypes,
+                placement=placement_on, placement_bytes=placement_bytes)
+            if (store is not None and not verify_rules
+                    and opt is not plan and not report.fell_back
+                    and report.stats_driven()):
+                # EVERY stats-driven rewrite passes the verify_rewrite
+                # gate, even with SPARK_RAPIDS_TPU_VERIFY_PLANS off
+                # (docs/adaptive.md): observations must never weaken the
+                # static pipeline's guarantees. A violation (defensive —
+                # the same rule guards protect both paths) reverts to
+                # the static rewrite rather than failing the query.
+                from ..analysis import verifier
+                rep = verifier.verify_rewrite(
+                    plan, opt, bound=bound, input_dtypes=input_dtypes,
+                    float_inputs=floats, report=report,
+                    # distributed plans: the partitioning-soundness
+                    # layer must check the very exchange placements the
+                    # observed cardinalities picked (same condition as
+                    # _verify_execution's `planned`)
+                    planned=bool(mesh_peers and mesh_peers > 1))
+                if not rep.ok:
+                    # the static re-run keeps the placement knobs: with
+                    # no stats the rule falls back to its certified-bytes
+                    # cold path, which IS the static placement decision
+                    opt, report = run_optimizer(
+                        plan, bound, bound_rows,
+                        float_inputs=floats, streaming_sources=streaming,
+                        mesh_peers=mesh_peers, verify_rules=verify_rules,
+                        input_dtypes=input_dtypes,
+                        placement=placement_on,
+                        placement_bytes=placement_bytes)
+                    report.stats_reverted = True
+            hit = (opt, opt.resolve_schemas(bound), report)
+            self._opt_cache[key] = hit
+        return hit
+
+    def _certify(self, plan, inputs, bound):
+        """Resource-certify the plan about to run (analysis/footprint.py):
+        bound input cardinalities (Tables and streaming sources both
+        expose num_rows), Table column dtypes for byte widths, validity
+        presence for the keyed-aggregate lo bound. Memoized per (plan,
+        binding) like the rewrite cache feeding it — a hot fingerprint-
+        cached plan must not re-pay the certify walk per execute.
+        Defensive-None on an internal certifier error — sizing is an
+        optimization layer and must never fail a query that would
+        otherwise run."""
+        from ..analysis import footprint
+        try:
+            input_dtypes, input_nullable = footprint.table_metadata(inputs)
+            bound_rows = {n: t.num_rows for n, t in inputs.items()}
+            n_peers = (self.mesh.shape[self.mesh_axis]
+                       if self.mesh is not None and self.mode == "eager"
+                       else 1)
+            key = (plan.root, tuple(sorted(bound.items())),
+                   tuple(sorted(bound_rows.items())),
+                   tuple((n, tuple((cn, repr(dt))
+                                   for cn, dt in cols.items()))
+                         for n, cols in sorted(input_dtypes.items())),
+                   tuple((n, tuple(sorted(cols.items())))
+                         for n, cols in sorted(input_nullable.items())),
+                   n_peers)
+            hit = self._cert_cache.get(key)
+            if hit is None:
+                hit = footprint.certify(
+                    plan, bound=bound, bound_rows=bound_rows,
+                    input_dtypes=input_dtypes,
+                    input_nullable=input_nullable, n_peers=n_peers)
+                self._cert_cache[key] = hit
+            return hit
+        except Exception:
+            return None
+
+    def _execute(self, plan, inputs, schemas, source_fp=None, cert=None,
+                 placements=None):
+        if self.mode == "eager":
+            return self._execute_eager(plan, inputs, schemas, placements)
+        return self._execute_capped(plan, inputs, schemas, source_fp,
+                                    cert)
+
+    def explain(self, plan: Plan, optimized: bool = False,
+                inputs: Optional[Dict[str, Table]] = None) -> str:
+        """The authored operator tree; with `optimized=True`, the authored
+        AND optimizer-rewritten trees plus the per-rule rewrite summary.
+        Pass `inputs` to render the EXACT rewrite execute() runs for that
+        binding (bound schemas/rows + the float build_side gate); without
+        them the rewrite uses declared schemas and est_rows hints only,
+        so bind-time pruning/estimates may differ."""
+        if not optimized:
+            return plan.explain()
+        if inputs is not None:
+            if not self.optimize:
+                # "EXACT rewrite execute() runs" — which, for a disabled
+                # optimizer, is no rewrite at all
+                return (plan.explain() + "\n\noptimizer: disabled for "
+                        "this executor (optimize=False / "
+                        "SPARK_RAPIDS_TPU_OPTIMIZER=off) — the authored "
+                        "plan executes verbatim")
+            bound = {name: tuple(t.names) for name, t in inputs.items()}
+            plan.resolve_schemas(bound)         # validate the binding
+            opt, _, report = self._optimized(plan, inputs, bound)
+            # certified footprint of the EXACT plan execute() would run
+            # for this binding (analysis/footprint.py)
+            cert = self._certify(opt, inputs, bound)
+            cert_block = [cert.render()] if cert is not None else []
+            transport_block = ([self._transport_summary()]
+                               if self.mesh is not None
+                               and self.mode == "eager" else [])
+            return "\n".join(["== authored ==", plan.explain(), "",
+                              "== optimized ==", opt.explain(), "",
+                              report.summary(), *cert_block,
+                              *transport_block,
+                              self._kernel_summary()])
+        from .optimizer import explain_optimized
+        return explain_optimized(plan) + "\n" + self._kernel_summary()
+
+    @staticmethod
+    def _transport_summary() -> str:
+        """One exchange-transport line for a meshed explain(optimized=True)
+        (plan/transport.py, docs/distributed.md#transport): what the
+        exchanges of this plan would ship under the current knobs."""
+        from .. import config
+        pack = config.exchange_pack()
+        codecs = ",".join(sorted(config.exchange_codecs())) if pack else ""
+        return ("transport: pack=" + ("on" if pack else "off")
+                + f" codecs={codecs or 'none'}"
+                + " async=" + ("on" if config.exchange_async() else "off")
+                + " (wire vs logical bytes per edge on profile())")
+
+    @staticmethod
+    def _kernel_summary() -> str:
+        """One registry line for explain(optimized=True): the signature-
+        independent per-op choice on the current backend (docs/kernels.md).
+        Signature-conditional kernels (the Pallas set) resolve per dispatch
+        and show up on OperatorMetrics.kernel / profile_text post-run."""
+        from ..ops.registry import REGISTRY
+        pairs = ", ".join(f"{op}={name}"
+                          for op, name in sorted(REGISTRY.summary().items()))
+        return (f"kernels [{jax.default_backend()}]: {pairs} "
+                "(signature-conditional kernels resolve per dispatch; see "
+                "profile())")
+
+    # ---- faultinj ---------------------------------------------------------
+    @staticmethod
+    def _faultinj_point(node: PlanNode):
+        """Plan-level interception: rules keyed `plan.<Kind>` (or `*`) fire
+        here, in addition to any op-level shims underneath."""
+        from .. import faultinj
+        inj = faultinj.active()
+        if inj is not None:
+            inj.on_compute(f"plan.{node.kind}")
+
+    # ---- health / degradation policy --------------------------------------
+    def _breaker_snapshot(self) -> Dict:
+        br = self.health.breaker
+        snap = {"state": br.state, "trips": br.trips,
+                "reason": br.last_trip_reason, "error": br.last_trip_error}
+        wid = getattr(self.health, "worker_id", "")
+        if wid:
+            snap["worker_id"] = wid
+        return snap
+
+    def _handle_fault(self, err, op_label: str, attempt: int,
+                      metric: OperatorMetrics) -> bool:
+        """One failure on the device path. Returns True when the caller
+        should retry the failed unit (backoff already slept, counters
+        bumped); returns False when the breaker tripped and the caller must
+        degrade (or re-raise under degrade="off")."""
+        from ..runtime import health as _h
+        kind = self.health.record_failure(op_label, err)
+        if kind == _h.TRANSIENT:
+            if attempt < self.op_retries:
+                slept = self.health.try_retry(attempt)
+                if slept is not None:
+                    metric.retries += 1
+                    metric.backoff_ms += slept
+                    self._maybe_rollback(err)
+                    return True
+                kind = _h.STICKY        # shared retry budget exhausted
+            else:
+                kind = _h.STICKY        # per-op retry bound exhausted
+        self.health.trip(kind, err)
+        return False
+
+    def _maybe_rollback(self, err) -> None:
+        """RetryOOM transients: honor the arbiter's rollback contract
+        (block until memory frees) before the backoff retry, best-effort."""
+        from ..runtime.adaptor import CpuRetryOOM, RetryOOM
+        if not isinstance(err, (RetryOOM, CpuRetryOOM)):
+            return
+        sess = self.session
+        if sess is None:
+            from ..runtime.admission import get_active_session
+            sess = get_active_session()
+        if sess is None:
+            return
+        try:
+            sess.arbiter.block_thread_until_ready()
+        except Exception:
+            pass
+
+    # ---- eager tier -------------------------------------------------------
+    def _execute_eager(self, plan, inputs, schemas,
+                       placements=None) -> PlanResult:
+        from ..runtime.admission import operand_nbytes
+        from ..utils import tracing
+        t_plan0 = time.perf_counter()
+        results: Dict[int, Table] = {}
+        metrics: Dict[str, OperatorMetrics] = {}
+        self.health.start_plan_attempt()
+        if self.degrade != "off" and not self.health.admit():
+            # device quarantined (breaker open / failed half-open probe):
+            # run the whole plan on the CPU tier without touching it
+            return self._execute_degraded(plan, inputs, schemas, results,
+                                          metrics, start=0, t_plan0=t_plan0,
+                                          mode="eager")
+        # full-plan SPMD tier (plan/distributed.py): with a mesh, nodes
+        # execute over sharded relations and gather only at the sink (or
+        # the first operator with no distributed form). Streaming prefixes
+        # are a single-chip pipeline shape — the distributed tier
+        # materializes source-bound scans through one pruned read instead.
+        dist = None
+        if self.mesh is not None:
+            from .distributed import DistContext
+            dist = DistContext(self, plan, inputs)
+        # streamable prefixes over source-bound scans run morsel-at-a-time
+        # (decode chunk N+1 on host while chunk N executes); their interior
+        # nodes never materialize a whole relation, only the chain tail does
+        chains = {} if dist is not None else self._stream_chains(plan, inputs)
+        chain_interior = {id(n) for ch in chains.values() for n in ch[:-1]}
+        node_index = {id(n): i for i, n in enumerate(plan.nodes)}
+        # co-placement dispatch (plan/optimizer.py placement rule,
+        # docs/optimizer.md#placement): validated host subtrees launch on
+        # worker threads UP FRONT — a placed subtree is self-contained
+        # (its leaves bind only to plan inputs), so its host execution
+        # overlaps the whole device walk, not just the sibling side. The
+        # consuming operator joins in _resolve_placed. Single-device only:
+        # the distributed tier has its own overlap story (async exchanges).
+        host_roots: Dict[int, List[PlanNode]] = {}
+        host_skip: set = set()
+        if placements and dist is None:
+            host_roots, host_skip = self._placement_subtrees(
+                plan, placements, inputs, chains, chain_interior)
+        for rid, sub in host_roots.items():
+            results[rid] = _PendingHostRel(
+                (lambda s: lambda: self._run_host_subtree(
+                    s, inputs, schemas))(sub),
+                sub[-1].label)
+        try:
+            for i, node in enumerate(plan.nodes):
+                if id(node) in host_skip:
+                    # runs on its co-placement worker thread; outputs and
+                    # metrics merge at the consumer's resolve
+                    continue
+                if id(node) in chain_interior:
+                    continue        # runs inside its chain, at the tail
+                if id(node) in chains:
+                    chain = chains[id(node)]
+                    try:
+                        out = self._exec_stream_chain(chain, inputs,
+                                                      schemas, metrics)
+                    except _StreamBreaker as sb:
+                        if self.degrade == "off":
+                            raise sb.error
+                        # replay the chain's remaining chunks — and the
+                        # whole prefix — on the CPU tier from the scan
+                        return self._execute_degraded(
+                            plan, inputs, schemas, results, metrics,
+                            start=node_index[id(chain[0])],
+                            t_plan0=t_plan0, mode="eager",
+                            carry_retries=sb.retries,
+                            carry_backoff_ms=sb.backoff_ms)
+                    results[id(node)] = out
+                    continue
+                child_tables = [results[id(c)] for c in node.children]
+                m = OperatorMetrics(label=node.label, kind=node.kind,
+                                    describe=node.describe())
+                t0 = time.perf_counter()
+                attempt = 0
+                out = None
+                while True:
+                    try:
+                        with tracing.range_ctx(f"plan.{node.label}"):
+                            self._faultinj_point(node)
+                            if dist is not None:
+                                out = dist.exec_node(node, child_tables,
+                                                     inputs, schemas, m,
+                                                     metrics)
+                            else:
+                                if host_roots:
+                                    child_tables = self._resolve_placed(
+                                        node, child_tables, results, m,
+                                        metrics)
+                                out = self._exec_eager_node(
+                                    node, child_tables, inputs, schemas, m)
+                        break
+                    except _fault_surface() as err:
+                        if self._handle_fault(err, node.label, attempt, m):
+                            attempt += 1
+                            continue
+                        if self.degrade == "off":
+                            raise
+                        return self._execute_degraded(
+                            plan, inputs, schemas, results, metrics,
+                            start=i, t_plan0=t_plan0, mode="eager",
+                            first_metric=m)
+                if attempt:
+                    # retried to success: the fault was genuinely transient,
+                    # so it must not count toward a later sticky trip
+                    self.health.record_success(node.label)
+                if getattr(out, "pending", False):
+                    # async exchange in flight (plan/distributed.PendingRel,
+                    # SPARK_RAPIDS_TPU_EXCHANGE_ASYNC): blocking here would
+                    # forfeit the transfer/compute overlap — wall_ms,
+                    # rows_out, bytes_out, and overlap-ms stamp onto this
+                    # metric row when a consumer resolves it
+                    m.rows_in = sum(t.num_rows for t in child_tables)
+                else:
+                    if self.block_per_op:
+                        jax.block_until_ready([c.data
+                                               for c in out.columns])
+                    # wall is compute (all attempts), NOT the backoff idle
+                    # time — that is reported separately in backoff_ms,
+                    # not double-counted
+                    m.wall_ms = (time.perf_counter() - t0) * 1e3 \
+                        - m.backoff_ms
+                    m.rows_in = sum(t.num_rows for t in child_tables)
+                    m.rows_out = out.num_rows
+                    m.bytes_out = operand_nbytes(
+                        out if isinstance(out, Table) else out.table)
+                metrics[node.label] = m
+                results[id(node)] = out
+        except BaseException as err:
+            # debuggability: a failed plan still surfaces what completed.
+            # First attachment wins — a failed degraded re-run has already
+            # recorded ITS metrics, which the stale device-tier dict here
+            # must not clobber.
+            if not hasattr(err, "plan_metrics"):
+                try:
+                    err.plan_metrics = dict(metrics)
+                except Exception:
+                    pass
+            raise
+        root_out = results[id(plan.root)]
+        if not isinstance(root_out, Table):
+            # sink gather: the single host-facing collect of a distributed
+            # plan (explicit when the optimizer placed Exchange(gather) at
+            # the root; implicit here otherwise)
+            root_out = root_out.to_local_table()
+        wall = (time.perf_counter() - t_plan0) * 1e3
+        return PlanResult(plan, root_out, None, metrics,
+                          "eager", wall,
+                          retries=sum(mm.retries for mm in metrics.values()),
+                          breaker=self._breaker_snapshot(),
+                          backoff_ms=sum(mm.backoff_ms
+                                         for mm in metrics.values()))
+
+    # ---- co-placement host subtrees (docs/optimizer.md#placement) ---------
+    @staticmethod
+    def _placement_subtrees(plan, placements, inputs, chains,
+                            chain_interior):
+        """Re-validate every `label -> "host"` annotation against the
+        EXECUTED plan's structure and return ({id(root): postorder node
+        list}, {all claimed node ids}). Placements are annotations — the
+        optimizer never mutated the tree for them — so the executor owns
+        the safety checks: the subtree must be EXCLUSIVE (every interior
+        node consumed only inside it — its output merges at exactly one
+        join point), free of Exchanges (device-resident by construction),
+        disjoint from streaming chains (their interior never materializes
+        a Table to hand a thread), with every Scan bound to a Table.
+        Labels that fail (e.g. a serving-forced label the rewrite
+        renamed) are skipped, never an error: placement is an
+        optimization and must not fail a query that would otherwise
+        run."""
+        parents: Dict[int, List[PlanNode]] = {}
+        for n in plan.nodes:
+            for c in n.children:
+                parents.setdefault(id(c), []).append(n)
+        by_label = {n.label: n for n in plan.nodes}
+        roots: Dict[int, List[PlanNode]] = {}
+        claimed: set = set()
+        # plan.nodes order makes the claim order deterministic
+        for cand in plan.nodes:
+            if placements.get(cand.label) != "host" or cand is plan.root:
+                continue
+            sub: List[PlanNode] = []
+            seen: set = set()
+
+            def walk(n):
+                if id(n) in seen:
+                    return
+                seen.add(id(n))
+                for c in n.children:
+                    walk(c)
+                sub.append(n)
+
+            walk(cand)
+            ids = {id(s) for s in sub}
+            if ids & claimed:
+                continue
+            ok = True
+            for s in sub:
+                if isinstance(s, Exchange) or id(s) in chain_interior \
+                        or id(s) in chains:
+                    ok = False
+                    break
+                if isinstance(s, Scan) and \
+                        not isinstance(inputs.get(s.source), Table):
+                    ok = False
+                    break
+                if s is not cand and any(id(p) not in ids
+                                         for p in parents.get(id(s), [])):
+                    ok = False   # interior node consumed outside: not
+                    break        # exclusive, no single join point
+            if ok:
+                roots[id(cand)] = sub
+                claimed |= ids
+        return roots, claimed
+
+    def _run_host_subtree(self, sub, inputs, schemas):
+        """Execute one host-placed subtree (postorder node list) — the
+        co-placement worker thread's body, also re-run synchronously on
+        the main thread when a consumer retries after a host failure.
+        Pins JAX dispatch to the CPU device and the kernel registry to
+        the cpu backend (via m.placement, see _kernel_choice); copies the
+        subtree's OWN scan bindings host-side only. Fault injection stays
+        LIVE (thread-local suppression is not set here — host placement
+        is an optimization of a healthy device, not degradation), so
+        injected faults surface at the consumer's retry loop with the
+        same classes as the device walk. Admission wrappers apply as
+        everywhere. Returns (outputs by id(node), metrics by label);
+        every output is blocked-until-ready so the overlap the consumer
+        measures is real completed work."""
+        import contextlib
+        from ..runtime.admission import operand_nbytes
+        from ..utils import tracing
+        cpu = _cpu_device()
+        ctx = (jax.default_device(cpu) if cpu is not None
+               else contextlib.nullcontext())
+        outs: Dict[int, Table] = {}
+        ms: Dict[str, OperatorMetrics] = {}
+        with ctx:
+            host_inputs = dict(inputs)
+            for n in sub:
+                if isinstance(n, Scan):
+                    host_inputs[n.source] = _table_to_cpu(
+                        inputs[n.source], cpu)
+            for n in sub:
+                childs = [outs[id(c)] for c in n.children]
+                m = OperatorMetrics(label=n.label, kind=n.kind,
+                                    describe=n.describe())
+                m.placement = "host"  # set BEFORE dispatch: pins the
+                #                       registry to cpu kernels
+                t0 = time.perf_counter()
+                with tracing.range_ctx(f"plan.{n.label}.host"):
+                    self._faultinj_point(n)
+                    out = self._exec_eager_node(n, childs, host_inputs,
+                                                schemas, m)
+                jax.block_until_ready([c.data for c in out.columns])
+                m.wall_ms = (time.perf_counter() - t0) * 1e3
+                m.rows_in = sum(t.num_rows for t in childs)
+                m.rows_out = out.num_rows
+                m.bytes_out = operand_nbytes(out)
+                ms[n.label] = m
+                outs[id(n)] = out
+        return outs, ms
+
+    @staticmethod
+    def _resolve_placed(node, child_tables, results, m, metrics):
+        """Join point of the co-placement dispatch: resolve any host
+        subtree this operator consumes — a LOCK-FREE, timeout-less
+        Thread.join (no engine lock is held anywhere on this path; the
+        lint_concurrency contract for blocking joins) — merge the
+        subtree's per-op metrics and ALL its node outputs (the degraded
+        tier's salvage walk may need interior outputs too), and stamp
+        the overlapped host wall on THIS consumer's metric row. Runs
+        inside the consumer's fault-retry loop, so a host-subtree
+        failure gets the plan-level retry/degrade policy: the first
+        resolve raises the original error, each retry re-runs the
+        subtree synchronously."""
+        resolved = list(child_tables)
+        for idx, c in enumerate(node.children):
+            r = resolved[idx]
+            if not isinstance(r, _PendingHostRel):
+                continue
+            outs, hms = r.resolve(m)
+            metrics.update(hms)
+            results.update(outs)
+            resolved[idx] = outs[id(c)]
+        return resolved
+
+    @staticmethod
+    def _drain_placed(results, metrics):
+        """Force-resolve every in-flight co-placement handle before the
+        degraded tier salvages `results` — the salvage walk needs real
+        Tables, and a placed subtree's interior outputs must be present
+        for consumers past the degrade point. A host failure raises
+        here; the salvage except treats it like lost device buffers and
+        restarts from the scans."""
+        for r in list(results.values()):
+            if isinstance(r, _PendingHostRel):
+                outs, hms = r.resolve(None)
+                metrics.update(hms)
+                results.update(outs)
+
+    # ---- degraded CPU tier ------------------------------------------------
+    def _execute_degraded(self, plan, inputs, schemas, results, metrics,
+                          start: int, t_plan0: float, mode: str,
+                          first_metric: Optional[OperatorMetrics] = None,
+                          carry_retries: int = 0,
+                          carry_backoff_ms: float = 0.0,
+                          attempts: int = 1,
+                          caps: Optional[Dict[str, int]] = None) -> PlanResult:
+        """Finish the plan on the CPU backend tier after a breaker trip.
+
+        Completed operator outputs are salvaged through host memory onto
+        the CPU backend; the remaining nodes re-execute eagerly with ALL
+        faultinj interception suppressed (`faultinj.suppressed()` — the
+        CPU tier does not touch the quarantined device, so neither the op
+        shims, the MemoryBudget shims, nor the poisoned-device fail-fast
+        may fire here) and no plan-level injection points. If the salvage
+        itself fails (device buffers already lost), the whole plan re-runs
+        from the scans. Admission still applies — degraded work is
+        budgeted like any other."""
+        import contextlib
+        from .. import faultinj
+        from ..runtime.admission import operand_nbytes
+        from ..utils import tracing
+        self.health.note_degraded_plan()
+        cpu = _cpu_device()
+        ctx = (jax.default_device(cpu) if cpu is not None
+               else contextlib.nullcontext())
+        with faultinj.suppressed(), ctx:
+            try:
+                self._drain_placed(results, metrics)
+                cpu_results = {k: _table_to_cpu(t, cpu)
+                               for k, t in results.items()}
+                cpu_inputs = {k: _table_to_cpu(t, cpu)
+                              for k, t in inputs.items()}
+            except Exception:
+                # device buffers unrecoverable: restart from the bound inputs
+                # (host-side numpy survives a dead device; device copies may
+                # not — re-binding is the caller's contract then). The
+                # retries/backoff already paid on the device path survive
+                # into the carry so the result still reports them.
+                carry_retries += sum(mm.retries for mm in metrics.values())
+                carry_backoff_ms += sum(mm.backoff_ms
+                                        for mm in metrics.values())
+                if first_metric is not None:
+                    carry_retries += first_metric.retries
+                    carry_backoff_ms += first_metric.backoff_ms
+                cpu_results, cpu_inputs = {}, inputs
+                metrics = {}
+                start = 0
+                first_metric = None
+            try:
+                for node in plan.nodes[start:]:
+                    childs = [cpu_results[id(c)] for c in node.children]
+                    if first_metric is not None and node is plan.nodes[start]:
+                        m = first_metric  # keep the failed op's retry record
+                    else:
+                        m = OperatorMetrics(label=node.label, kind=node.kind,
+                                            describe=node.describe())
+                    m.degraded = True
+                    t0 = time.perf_counter()
+                    with tracing.range_ctx(f"plan.{node.label}.degraded"):
+                        out = self._exec_eager_node(node, childs, cpu_inputs,
+                                                    schemas, m)
+                    if self.block_per_op:
+                        jax.block_until_ready([c.data for c in out.columns])
+                    m.wall_ms = (time.perf_counter() - t0) * 1e3
+                    m.rows_in = sum(t.num_rows for t in childs)
+                    m.rows_out = out.num_rows
+                    m.bytes_out = operand_nbytes(out)
+                    metrics[node.label] = m
+                    cpu_results[id(node)] = out
+            except BaseException as err:
+                # the debuggability contract holds on THIS tier too: a
+                # failed degraded plan still surfaces what completed
+                try:
+                    err.plan_metrics = dict(metrics)
+                except Exception:
+                    pass
+                raise
+        wall = (time.perf_counter() - t_plan0) * 1e3
+        return PlanResult(plan, cpu_results[id(plan.root)], None, metrics,
+                          mode, wall, degraded=True,
+                          attempts=attempts, caps=caps,
+                          retries=carry_retries + sum(
+                              mm.retries for mm in metrics.values()),
+                          breaker=self._breaker_snapshot(),
+                          backoff_ms=carry_backoff_ms + sum(
+                              mm.backoff_ms for mm in metrics.values()))
+
+    # ---- streaming prefix (docs/io.md) ------------------------------------
+    @staticmethod
+    def _stream_chains(plan, inputs) -> Dict[int, List[PlanNode]]:
+        """id(tail) -> [Scan, op, ...] streamable prefixes. A chain starts
+        at a Scan bound to a streaming source and extends while the node
+        has exactly ONE consumer that is a row-wise Filter/Project/
+        FusedSelect (no scalar aggregates — those reduce over the whole
+        relation); it may terminate INTO a HashAggregate whose ops
+        decompose exactly (sum/count/min/max/size over non-float inputs —
+        fp partial sums are not reorder-exact). Everything else is the
+        concat boundary: the tail materializes one Table and the rest of
+        the plan proceeds normally."""
+        from .expr import has_scalar_agg
+        parents: Dict[int, List[PlanNode]] = {}
+        for n in plan.nodes:
+            for c in n.children:
+                parents.setdefault(id(c), []).append(n)
+        chains: Dict[int, List[PlanNode]] = {}
+        for scan in plan.scans:
+            src = inputs.get(scan.source)
+            if src is None or isinstance(src, Table) or \
+                    not getattr(src, "is_streaming_source", False):
+                continue
+            chain = [scan]
+            node: PlanNode = scan
+            while True:
+                ps = parents.get(id(node), [])
+                if len(ps) != 1:
+                    break
+                p = ps[0]
+                if isinstance(p, Filter) and \
+                        not has_scalar_agg(p.predicate):
+                    chain.append(p)
+                    node = p
+                    continue
+                if isinstance(p, Project) and not any(
+                        has_scalar_agg(e) for _, e in p.exprs):
+                    chain.append(p)
+                    node = p
+                    continue
+                if isinstance(p, FusedSelect) and \
+                        not has_scalar_agg(p.predicate) and not any(
+                            has_scalar_agg(e) for _, e in p.exprs):
+                    chain.append(p)
+                    node = p
+                    continue
+                if (isinstance(p, HashAggregate)
+                        and all(o in _STREAM_AGG_MERGE
+                                for _, o, _ in p.aggs)
+                        and not _input_has_floats(src)):
+                    chain.append(p)     # terminal: partial accumulation
+                break
+            if len(chain) > 1:
+                chains[id(chain[-1])] = chain
+        return chains
+
+    def _stream_op(self, node, t: Table, inputs, schemas,
+                   m: OperatorMetrics, fn=None) -> Table:
+        """One chain operator over one chunk, with the same per-op fault
+        policy as the materialized path (backoff-paced retries; a breaker
+        trip raises _StreamBreaker so the caller can degrade)."""
+        from ..utils import tracing
+        attempt = 0
+        while True:
+            t0 = time.perf_counter()
+            try:
+                with tracing.range_ctx(f"plan.{node.label}"):
+                    self._faultinj_point(node)
+                    out = (fn(t) if fn is not None else
+                           self._exec_eager_node(node, [t], inputs,
+                                                 schemas, m))
+                break
+            except _fault_surface() as err:
+                if self._handle_fault(err, node.label, attempt, m):
+                    attempt += 1
+                    continue
+                raise _StreamBreaker(err, m.retries, m.backoff_ms)
+        if attempt:
+            self.health.record_success(node.label)
+        m.wall_ms = (m.wall_ms or 0.0) + (time.perf_counter() - t0) * 1e3
+        m.rows_in += t.num_rows
+        m.rows_out += out.num_rows
+        return out
+
+    def _exec_stream_chain(self, chain, inputs, schemas,
+                           metrics: Dict[str, OperatorMetrics]) -> Table:
+        """Run one streamable prefix morsel-at-a-time: row-group pruning at
+        the scan, bounded host prefetch decoding chunk N+1 while chunk N
+        executes, per-chunk Filter/Project/FusedSelect, and partial
+        HashAggregate accumulation merged exactly at the end. Fills
+        `metrics` for every chain node; returns the tail's Table."""
+        from .. import config
+        from .optimizer import pruning_conjuncts
+        from ..runtime.admission import operand_nbytes
+        ops = _ops()
+        scan = chain[0]
+        src = inputs[scan.source]
+        ms = {n.label: OperatorMetrics(label=n.label, kind=n.kind,
+                                       describe=n.describe())
+              for n in chain}
+        sm = ms[scan.label]
+        columns = (list(scan.projection) if scan.projection is not None
+                   else None)
+        conjuncts = (pruning_conjuncts(scan.predicate)
+                     if scan.predicate is not None else [])
+        kept, pruned, skipped = src.select_groups(conjuncts, columns)
+        sm.io_row_groups_total = src.num_row_groups
+        sm.io_row_groups_pruned = pruned
+        sm.io_bytes_skipped = skipped
+        agg = chain[-1] if isinstance(chain[-1], HashAggregate) else None
+        body = chain[1:-1] if agg is not None else chain[1:]
+        chunk_rows = src.chunk_rows or config.io_chunk_rows() or None
+        if chunk_rows is None:
+            # adaptive morsel sizing (plan/stats.py, docs/adaptive.md):
+            # with no explicit bound, size chunks from this scan's
+            # OBSERVED decode throughput — the stream's exact two-phase
+            # merge makes the result chunking-invariant, so this only
+            # changes pacing, never bytes. Explicit knobs always win.
+            from . import stats as stats_mod
+            store = stats_mod.active_store()
+            if store is not None:
+                from .optimizer import subtree_fingerprints
+                # a Scan is a leaf: hashing it alone yields the same
+                # fingerprint record_result stored, without re-hashing
+                # the whole plan on the streaming hot path
+                scan_fp = subtree_fingerprints(scan)[id(scan)]
+                chunk_rows = store.suggest_chunk_rows(
+                    jax.default_backend(), scan_fp) or None
+        depth = config.io_prefetch()
+        gen = src.chunks(columns=columns, row_groups=kept,
+                         chunk_rows=chunk_rows)
+        feed = _ChunkPrefetcher(gen, depth) if depth > 0 else _SyncFeed(gen)
+        parts: List[Table] = []         # tail outputs (or agg partials)
+        empty_t: Optional[Table] = None
+        proc_intervals = []
+        try:
+            while True:
+                chunk = feed.get()
+                if chunk is None:
+                    break
+                t0p = time.perf_counter()
+                sm.rows_out += chunk.num_rows
+                sm.bytes_out += operand_nbytes(chunk)
+                t = chunk
+                for node in body:
+                    t = self._stream_op(node, t, inputs, schemas,
+                                        ms[node.label])
+                    ms[node.label].bytes_out += operand_nbytes(t)
+                if agg is not None:
+                    if t.num_rows == 0:
+                        # fully-filtered morsel: contributes nothing, and a
+                        # keyless min/max over a ZERO-ROW frame would raise
+                        # where the table-bound plan (reducing over the
+                        # whole non-empty relation) succeeds — skip it,
+                        # keeping one empty frame for the all-empty case
+                        empty_t = t
+                        proc_intervals.append((t0p, time.perf_counter()))
+                        continue
+                    t = self._stream_op(
+                        agg, t, inputs, schemas, ms[agg.label],
+                        fn=lambda tt: self._stream_partial_agg(agg, tt,
+                                                               schemas))
+                parts.append(t)
+                if self.block_per_op:
+                    jax.block_until_ready([c.data for c in t.columns])
+                proc_intervals.append((t0p, time.perf_counter()))
+        finally:
+            feed.close()
+        sm.io_decode_ms = feed.decode_ms
+        sm.io_overlap_ms = _interval_overlap_ms(feed.decode_intervals,
+                                                proc_intervals)
+        sm.wall_ms = feed.decode_ms     # scan wall = host decode
+        # concatenate ONLY at the first non-streamable boundary
+        tail = chain[-1]
+        tm = ms[tail.label]
+        t0 = time.perf_counter()
+        if agg is not None:
+            if not parts:
+                # every morsel filtered to zero rows: aggregate the empty
+                # frame once — identical semantics (including any keyless
+                # min/max error) to the table-bound plan over an empty
+                # filtered relation
+                parts = [self._stream_op(
+                    agg, empty_t, inputs, schemas, ms[agg.label],
+                    fn=lambda tt: self._stream_partial_agg(agg, tt,
+                                                           schemas))]
+            out = self._finalize_stream_agg(agg, parts, schemas)
+            tm.rows_out = out.num_rows  # partial rows were internal
+        else:
+            out = parts[0] if len(parts) == 1 else ops.concat_tables(parts)
+        if self.block_per_op:
+            jax.block_until_ready([c.data for c in out.columns])
+        tm.wall_ms = (tm.wall_ms or 0.0) + (time.perf_counter() - t0) * 1e3
+        tm.bytes_out = operand_nbytes(out)
+        for n in chain:
+            metrics[n.label] = ms[n.label]
+        return out
+
+    def _stream_partial_agg(self, node: HashAggregate, t: Table,
+                            schemas) -> Table:
+        """Per-chunk partial aggregation (named like the final schema, so
+        the merge groups on the output columns)."""
+        ops = _ops()
+        if not node.keys:
+            return self._global_aggregate(t, node)
+        agg = ops.groupby_aggregate(t, list(node.keys),
+                                    [(c, o) for c, o, _ in node.aggs])
+        return Table(list(agg.columns), names=schemas[id(node)])
+
+    def _finalize_stream_agg(self, node: HashAggregate,
+                             partials: List[Table], schemas) -> Table:
+        """Exact merge of per-chunk partials: counts sum, sums sum, min/max
+        re-reduce — the same two-phase shape as the distributed tier, over
+        chunks instead of mesh peers. The sort-based groupby kernel's
+        key-ordered output makes the merged result row-identical to the
+        single-pass aggregate."""
+        ops = _ops()
+        cat = (partials[0] if len(partials) == 1
+               else ops.concat_tables(partials))
+        merged_aggs = tuple((out, _STREAM_AGG_MERGE[o], out)
+                            for _, o, out in node.aggs)
+        if not node.keys:
+            merge_node = HashAggregate(node.child, (), merged_aggs)
+            return self._global_aggregate(cat, merge_node)
+        agg = ops.groupby_aggregate(cat, list(node.keys),
+                                    [(c, o) for c, o, _ in merged_aggs])
+        return Table(list(agg.columns), names=schemas[id(node)])
+
+    def _materialize_scan(self, node: Scan, src,
+                          m: Optional[OperatorMetrics]) -> Table:
+        """Source-bound Scan outside a streamable prefix (shared scans,
+        join inputs, the capped tier): one admitted read, still with
+        selective decode (projection columns only) and stats-driven
+        row-group pruning."""
+        from .optimizer import pruning_conjuncts
+        columns = (list(node.projection) if node.projection is not None
+                   else None)
+        conjuncts = (pruning_conjuncts(node.predicate)
+                     if node.predicate is not None else [])
+        kept, pruned, skipped = src.select_groups(conjuncts, columns)
+        t0 = time.perf_counter()
+        t = src.read_all(columns=columns, row_groups=kept)
+        if m is not None:
+            m.io_row_groups_total = src.num_row_groups
+            m.io_row_groups_pruned = pruned
+            m.io_bytes_skipped = skipped
+            m.io_decode_ms += (time.perf_counter() - t0) * 1e3
+        return t
+
+    @staticmethod
+    def _kernel_choice(op: str, sig, m: Optional[OperatorMetrics] = None,
+                       pin_degraded: bool = True):
+        """Resolve one registry dispatch (ops/registry.py, docs/kernels.md)
+        and stamp the choice on the operator's metrics. On the degraded CPU
+        tier the backend is pinned to "cpu" (default_backend still reports
+        the quarantined platform under jax.default_device): auto-selection
+        must not hand work back to the device the breaker just isolated.
+        Host-PLACED operators (co-placement worker threads, m.placement ==
+        "host") pin the same way — the whole point of the placement is
+        that the subtree does not touch the device."""
+        from ..ops.registry import REGISTRY
+        backend = "cpu" if (pin_degraded and m is not None
+                            and (m.degraded or m.placement == "host")) \
+            else None
+        choice = REGISTRY.select(op, sig, backend=backend)
+        if m is not None:
+            m.kernel = choice.label
+            if sig is not None:
+                # side-channel for the stats store (plan/stats.py): the
+                # op + signature this metric's wall time was measured
+                # under, consumed by record_result to feed the registry
+                # tie-break. A dynamic attribute, not a dataclass field —
+                # profile()/to_dict() rows must not grow a non-JSON blob.
+                m._kernel_sig = (op, sig)
+        return choice
+
+    def _exec_eager_node(self, node, childs: List[Table], inputs, schemas,
+                         m: OperatorMetrics) -> Table:
+        ops = _ops()
+        if isinstance(node, Scan):
+            t = inputs[node.source]
+            if not isinstance(t, Table):
+                # streaming source outside a streamable prefix: materialize
+                # (pruned + projected) in one read
+                return self._materialize_scan(node, t, m)
+            if node.projection is not None:
+                # pruned scan: unused columns never enter the plan
+                t = t.select(list(node.projection))
+            return t
+        if isinstance(node, Filter):
+            (t,) = childs
+            mask = node.predicate.evaluate(t)
+            return ops.apply_boolean_mask(t, mask)
+        if isinstance(node, FusedSelect):
+            # fused Filter+Project: gather ONLY the projection-referenced
+            # columns through the mask, then project — one pass, instead of
+            # materializing the full filtered child first. The registry
+            # (ops/registry.py) may hand the front half to the Pallas
+            # predicate+compaction kernel; the XLA mask+gather is the
+            # fallback.
+            (t,) = childs
+            from ..ops import select_pallas
+            # one shared definition with make_signature: the supports()
+            # gate must describe exactly the columns the kernel is handed
+            needed = select_pallas.needed_columns(t, node.exprs)
+            choice = self._kernel_choice(
+                "fused_select",
+                select_pallas.make_signature(t, node.predicate, node.exprs,
+                                             "eager"), m)
+            if not choice.fallback:
+                ft = choice.fn(t, node.predicate, needed)
+            else:
+                mask = node.predicate.evaluate(t)
+                ft = ops.apply_boolean_mask(t.select(needed), mask)
+            return self._project(ft, node)
+        if isinstance(node, Project):
+            (t,) = childs
+            return self._project(t, node)
+        if isinstance(node, HashJoin):
+            lt, rt = childs
+            lkeys = [lt[k] for k in node.left_keys]
+            rkeys = [rt[k] for k in node.right_keys]
+            from ..ops import join_pallas
+            choice = self._kernel_choice(
+                "hash_join",
+                join_pallas.make_signature(lkeys, rkeys, node.how, "eager"),
+                m)
+            if node.how == "inner":
+                if not choice.fallback:
+                    lm, rm = choice.fn(lkeys, rkeys)
+                else:
+                    lm, rm = ops.inner_join(lkeys, rkeys)
+                return Table(
+                    list(ops.take_table(lt, lm.data,
+                                        _has_negative=False).columns) +
+                    list(ops.take_table(rt, rm.data,
+                                        _has_negative=False).columns),
+                    names=list(lt.names) + list(rt.names))
+            keep = (ops.left_semi_join(lkeys, rkeys) if node.how == "left_semi"
+                    else ops.left_anti_join(lkeys, rkeys))
+            return ops.take_table(lt, keep.data, _has_negative=False)
+        if isinstance(node, HashAggregate):
+            (t,) = childs
+            if not node.keys:
+                return self._global_aggregate(t, node)
+            # dispatch happens inside groupby_aggregate (registry op
+            # "groupby"); re-selecting here only stamps the choice. Backend
+            # intentionally NOT pinned for the degraded tier: the scan/
+            # scatter pick keys on jax.default_backend(), exactly like the
+            # kernel itself
+            self._kernel_choice("groupby", None, m, pin_degraded=False)
+            agg = ops.groupby_aggregate(t, list(node.keys),
+                                        [(c, o) for c, o, _ in node.aggs])
+            out_names = schemas[id(node)]
+            return Table(list(agg.columns), names=out_names)
+        if isinstance(node, Sort):
+            (t,) = childs
+            return ops.sort_table(t, key_names=list(node.keys),
+                                  ascending=list(node.ascending))
+        if isinstance(node, TopK):
+            (t,) = childs
+            from ..ops import topk_pallas
+            choice = self._kernel_choice(
+                "topk",
+                topk_pallas.make_signature(t, node.keys, node.ascending,
+                                           node.n, "eager"), m)
+            if not choice.fallback:
+                return choice.fn(t, list(node.keys), list(node.ascending),
+                                 node.n)
+            t = ops.sort_table(t, key_names=list(node.keys),
+                               ascending=list(node.ascending))
+            return ops.slice_table(t, 0, min(node.n, t.num_rows))
+        if isinstance(node, Limit):
+            (t,) = childs
+            return ops.slice_table(t, 0, min(node.n, t.num_rows))
+        if isinstance(node, Union):
+            return ops.concat_tables(childs)
+        if isinstance(node, Exchange):
+            # single-chip tier: a no-op distribution marker. With a mesh,
+            # the parent operator consumes it (distributed lowering).
+            return childs[0]
+        raise PlanValidationError(f"no eager lowering for {node.kind}")
+
+    def _project(self, t: Table, node: Project,
+                 alive: Optional[jnp.ndarray] = None) -> Table:
+        cols = []
+        for name, e in node.exprs:
+            if isinstance(e, ColumnRef):
+                cols.append(t[e.name])      # preserve dtype + validity
+            else:
+                v = e.evaluate(t, alive)
+                if getattr(v, "ndim", 1) == 0:
+                    # bare scalar aggregate (or literal fold): broadcast to
+                    # the relation's length, as the Expr contract promises
+                    v = jnp.broadcast_to(v, (t.num_rows,))
+                cols.append(_col_from_array(v))
+        return Table(cols, names=[n for n, _ in node.exprs])
+
+    def _global_aggregate(self, t: Table, node: HashAggregate,
+                          alive: Optional[jnp.ndarray] = None) -> Table:
+        """Keyless (one-row) aggregate; honors `alive` in the capped tier."""
+        from ..ops.aggregate import _agg_value_dtype
+        cols, names = [], []
+        for c, op, out_name in node.aggs:
+            if op == "size":
+                n_live = (jnp.sum(alive.astype(jnp.int64)) if alive is not None
+                          else jnp.asarray(t.num_rows, jnp.int64))
+                dt = dtypes.INT64
+                val = n_live
+            else:
+                src = t[c]
+                v = src.data
+                ok = src.validity
+                if alive is not None:
+                    ok = alive if ok is None else (ok & alive)
+                if op == "count":
+                    val = (jnp.sum(ok.astype(jnp.int64)) if ok is not None
+                           else jnp.asarray(t.num_rows, jnp.int64))
+                    dt = dtypes.INT64
+                else:
+                    dt = _agg_value_dtype(op, src.dtype)
+                    acc = v.astype(dt.storage_dtype())
+                    if op == "sum":
+                        if ok is not None:
+                            acc = jnp.where(ok, acc, 0)
+                        val = jnp.sum(acc)
+                    else:
+                        from .expr import _reduce_identity
+                        if ok is not None:
+                            acc = jnp.where(ok, acc,
+                                            _reduce_identity(op, acc.dtype))
+                        val = jnp.min(acc) if op == "min" else jnp.max(acc)
+            cols.append(Column(dtype=dt, length=1,
+                               data=val[None].astype(dt.storage_dtype())))
+            names.append(out_name)
+        return Table(cols, names=names)
+
+    # ---- capped tier ------------------------------------------------------
+    def _default_caps(self, plan, inputs) -> Dict[str, int]:
+        """Initial capacities: the executor's shared caps (defaulted from
+        the largest input) plus one per-node entry for each node-level
+        override — those ride the SAME escalation dict, so an undersized
+        override grows geometrically like everything else instead of
+        livelocking through identical attempts. Per-node entries key on
+        the toposort INDEX (stable across fingerprint-equal plans, whose
+        labels differ), so the caps memo and program cache stay shared
+        when the same plan is rebuilt."""
+        caps = dict(self.caps)
+        max_rows = max((t.num_rows for t in inputs.values()), default=1)
+        needs_row = needs_key = False
+        for i, n in enumerate(plan.nodes):
+            if isinstance(n, HashJoin) and n.how == "inner":
+                if n.row_cap is None:
+                    needs_row = True
+                else:
+                    caps[f"row_cap:{i}"] = n.row_cap
+            elif isinstance(n, HashAggregate) and n.keys:
+                if n.key_cap is None:
+                    needs_key = True
+                else:
+                    caps[f"key_cap:{i}"] = n.key_cap
+        if needs_row:
+            caps.setdefault("row_cap", max(max_rows, 1))
+        if needs_key:
+            caps.setdefault("key_cap", max(max_rows, 1))
+        return caps
+
+    @staticmethod
+    def _node_cap(caps: Dict[str, int], which: str, idx: int) -> int:
+        return caps.get(f"{which}:{idx}") or caps[which]
+
+    @staticmethod
+    def _cert_caps(plan, caps, cert):
+        """Fold the resource certifier's sound rows-hi bounds
+        (analysis/footprint.py) into the capped tier's capacities:
+
+        - STARTING caps tighten to the certified hi where it is below the
+          static start (a sound bound can never overflow, so a tighter
+          start only shrinks padding and compiles a smaller program —
+          per-node `row_cap:<i>`/`key_cap:<i>` entries, which outrank the
+          shared keys exactly like authored overrides);
+        - the escalation ladder CEILINGS at the certified hi (growing a
+          capacity past a proven bound is wasted memory) — per node where
+          a per-node entry exists, else on the shared key at the max hi
+          over the nodes that fall through to it (an unbounded node
+          poisons the shared ceiling, never the clamp safety).
+
+        Returns (caps, ceil) for `auto_retry_overflow(ceil=...)`; the
+        ceiling is advisory there — a clamped attempt that still
+        overflows drops it (certifier-bug escape hatch)."""
+        caps = dict(caps)
+        ceil: Dict[str, int] = {}
+        shared_hi: Dict[str, Optional[int]] = {"row_cap": 0, "key_cap": 0}
+        for i, n in enumerate(plan.nodes):
+            if isinstance(n, HashJoin) and n.how == "inner":
+                which = "row_cap"
+            elif isinstance(n, HashAggregate) and n.keys:
+                which = "key_cap"
+            else:
+                continue
+            b = cert.by_index.get(i)
+            hi = None if b is None else b.rows_hi
+            key = f"{which}:{i}"
+            if key in caps:
+                if hi is not None:
+                    if hi < caps[key]:
+                        caps[key] = hi
+                    ceil[key] = max(caps[key], hi)
+                continue
+            cur = caps.get(which)
+            if hi is not None and cur is not None and hi < cur:
+                caps[key] = hi
+                ceil[key] = hi
+            elif shared_hi[which] is not None:
+                shared_hi[which] = (None if hi is None
+                                    else max(shared_hi[which], hi))
+        for which, g in shared_hi.items():
+            if g and which in caps:
+                ceil[which] = max(g, caps[which])
+        return caps, ceil
+
+    def _execute_capped(self, plan, inputs, schemas,
+                        source_fp=None, cert=None) -> PlanResult:
+        from ..parallel.autoretry import auto_retry_overflow
+        # the capped tier traces ONE whole-plan program over concrete
+        # shapes, so streaming sources materialize first — still through
+        # the pruned/projected read, so the decode savings carry over
+        scan_io: Dict[str, OperatorMetrics] = {}
+        if any(not isinstance(t, Table) for t in inputs.values()):
+            inputs = dict(inputs)
+            # one Scan per source is a Plan invariant (Plan.__init__
+            # rejects duplicate sources), so materializing per NAME with
+            # that scan's projection/predicate loses nothing
+            by_source = {n.source: n for n in plan.nodes
+                         if isinstance(n, Scan)}
+            for name, v in list(inputs.items()):
+                if isinstance(v, Table):
+                    continue
+                node = by_source.get(name)
+                holder = OperatorMetrics(label=name, kind="Scan")
+                if node is not None:
+                    inputs[name] = self._materialize_scan(node, v, holder)
+                else:
+                    inputs[name] = v.read_all()
+                scan_io[name] = holder
+        # start from the input-derived defaults, floored up by any caps the
+        # plan already escalated to: the memo must never UNDERSIZE a run on
+        # larger inputs than it was learned on (only skip re-learning)
+        caps = self._default_caps(plan, inputs)
+        fp = plan.fingerprint        # canonical structural hash: equivalent
+        #                              plans built independently share the
+        #                              caps memo and compiled programs
+        for k, v in (self._caps_memo.get(fp) or {}).items():
+            caps[k] = max(caps.get(k, 0), v)
+        # adaptive cap seeding (plan/stats.py, docs/adaptive.md): floor
+        # the starting capacities at the observed high-water marks from
+        # prior executions of this authored plan, so a repeat fingerprint
+        # compiles once instead of re-climbing the escalation ladder —
+        # the per-executor memo above, promoted across executor
+        # instances (and processes, with persistence on). Same
+        # floor-only contract: caps are STARTING capacities the overflow
+        # ladder would have grown anyway, so seeding can never change
+        # results, only skip retries. Keyed by the backend about to run:
+        # degraded-run stats recorded under "cpu" never seed a device.
+        from . import stats as stats_mod
+        store = stats_mod.active_store()
+        if store is not None and source_fp is not None:
+            for k, v in store.observed_caps(jax.default_backend(),
+                                            source_fp,
+                                            executed_fp=fp).items():
+                caps[k] = max(caps.get(k, 0), v)
+        # certified cap bounds (analysis/footprint.py, docs/adaptive.md):
+        # with adaptivity on, cold starting caps tighten to the sound
+        # hi-bound and the escalation ladder ceilings at it — the warm
+        # observed high-water (merged above) must always sit at or below
+        # the certified bound; that inequality IS the certifier's
+        # soundness check (fuzz property 5). Stats off stays
+        # byte-identical static: the certifier then only stamps results.
+        from .. import config
+        cert_ceil: Dict[str, int] = {}
+        if store is not None and cert is not None and config.cert_seed():
+            caps, cert_ceil = self._cert_caps(plan, caps, cert)
+        t0 = time.perf_counter()
+        attempts = 0
+        cache_hits = 0
+        bytes_map: Dict[int, int] = {}
+        kernel_map: Dict[int, str] = {}
+        last_caps = dict(caps)
+        self.health.start_plan_attempt()
+        if self.degrade != "off" and not self.health.admit():
+            return self._execute_degraded(plan, inputs, schemas, {}, {},
+                                          start=0, t_plan0=t0, mode="capped")
+
+        def run(**caps_now):
+            nonlocal attempts, cache_hits
+            attempts += 1
+            last_caps.clear()
+            last_caps.update(caps_now)
+            # plan-level faultinj surface: fires every attempt, including
+            # cache-hit runs where the op-level shims never re-trace
+            for node in plan.nodes:
+                self._faultinj_point(node)
+            # shapes AND names in the key: jax retraces per input shape
+            # anyway, a per-shape entry keeps each bytes_map true to ITS
+            # trace, and the names guard fingerprint-shared undeclared
+            # scans bound to differently-named tables
+            fn, bm, km, hit = self._jitted_capped(
+                plan, schemas, caps_now,
+                tuple(sorted((n, tuple(t.names), t.num_rows)
+                             for n, t in inputs.items())))
+            cache_hits += hit
+            out = fn(dict(inputs))
+            bytes_map.clear()
+            bytes_map.update(bm)    # bm fills during the first trace
+            kernel_map.clear()
+            kernel_map.update(km)
+            return out
+
+        retries = 0
+        backoff_total = 0.0
+        plan_metric = OperatorMetrics(label="plan", kind="Plan")
+        while True:
+            try:
+                (table, valid, counts, overflow), final_caps = \
+                    auto_retry_overflow(run, caps, self.max_cap_attempts,
+                                        ceil=cert_ceil)
+                if retries:
+                    self.health.record_success("plan")
+                self._caps_memo[fp] = dict(final_caps)
+                break
+            except _fault_surface() as err:
+                # failures are plan-granular here (one XLA program), so the
+                # sticky window keys on the plan attempt, not an operator
+                if self._handle_fault(err, "plan", retries, plan_metric):
+                    retries += 1
+                    backoff_total = plan_metric.backoff_ms
+                    # resume from the escalated capacities, not the
+                    # originals: growth already paid for must survive
+                    caps = dict(last_caps)
+                    continue
+                if self.degrade == "off":
+                    raise
+                return self._execute_degraded(
+                    plan, inputs, schemas, {}, {}, start=0, t_plan0=t0,
+                    mode="capped", carry_retries=plan_metric.retries,
+                    carry_backoff_ms=plan_metric.backoff_ms,
+                    # escalation history survives the trip: the device path
+                    # DID run `attempts` times over these (grown) caps
+                    attempts=attempts, caps=dict(last_caps))
+        jax.block_until_ready(valid)
+        wall = (time.perf_counter() - t0) * 1e3
+        metrics: Dict[str, OperatorMetrics] = {}
+        # cap growths only: each of the (retries+1) auto_retry runs gets a
+        # free first attempt that is not an escalation
+        escal = max(0, attempts - (retries + 1))
+        counts_np = {k: (int(a), int(b))
+                     for k, (a, b) in zip(counts.keys(),
+                                          np.asarray(list(counts.values()),
+                                                     dtype=np.int64))}
+        for i, node in enumerate(plan.nodes):
+            # counts/bytes key on the toposort INDEX, not the label: a
+            # fingerprint-shared program was traced over an equivalent
+            # plan whose node labels differ, but its toposort lines up 1:1
+            rows_in, rows_out = counts_np[i]
+            uses_cap = (isinstance(node, HashJoin) and node.how == "inner") \
+                or (isinstance(node, HashAggregate) and node.keys)
+            # retries are plan-granular in this tier (one XLA program) and
+            # live on PlanResult.retries — copying them onto every row would
+            # make per-op aggregation overcount N-fold
+            metrics[node.label] = OperatorMetrics(
+                label=node.label, kind=node.kind, describe=node.describe(),
+                rows_in=rows_in, rows_out=rows_out,
+                bytes_out=bytes_map.get(i, 0),
+                escalations=escal if uses_cap else 0,
+                kernel=kernel_map.get(i, ""))
+            if isinstance(node, Scan) and node.source in scan_io:
+                io = scan_io[node.source]
+                mm = metrics[node.label]
+                mm.io_row_groups_total = io.io_row_groups_total
+                mm.io_row_groups_pruned = io.io_row_groups_pruned
+                mm.io_bytes_skipped = io.io_bytes_skipped
+                mm.io_decode_ms = io.io_decode_ms
+        return PlanResult(plan, table, valid, metrics, "capped", wall,
+                          attempts=attempts, caps=final_caps,
+                          retries=retries,
+                          breaker=self._breaker_snapshot(),
+                          backoff_ms=backoff_total,
+                          jit_cache_hits=cache_hits)
+
+    def _jitted_capped(self, plan, schemas, caps, input_key):
+        # the canonical FINGERPRINT is the key: structurally equivalent
+        # plans built independently (same kinds/exprs/schemas/DAG shape)
+        # share one compiled program instead of re-tracing. The backend +
+        # kernel-override knob join the key: registry selection happens at
+        # trace time, so a program compiled under one kernel choice must
+        # never serve another (docs/kernels.md). Returns (jitted_fn,
+        # bytes_map, kernel_map, cache_hit).
+        from .. import config
+        from . import stats as stats_mod
+        store = stats_mod.active_store()
+        # the stats store's kernel tie-break resolves at trace time, so
+        # its epoch (bumped only when a recorded timing changes some
+        # signature's kernel ORDERING) joins the key: compiled programs
+        # stay shared across runs whose picks cannot have changed, and
+        # never alias across a demotion flip (docs/adaptive.md)
+        kern_key = (jax.default_backend(),
+                    tuple(sorted(config.kernel_overrides().items())),
+                    None if store is None else (store.uid,
+                                                store.kernel_epoch))
+        key = (plan.fingerprint, tuple(sorted(caps.items())), input_key,
+               kern_key)
+        hit = self._jit_cache.get(key)
+        if hit is not None:
+            return hit[0], hit[1], hit[2], True
+        bytes_map: Dict[int, int] = {}
+        kernel_map: Dict[int, str] = {}
+
+        def fn(tables: Dict[str, Table]):
+            return self._run_capped(plan, schemas, caps, tables, bytes_map,
+                                    kernel_map)
+
+        jitted = jax.jit(fn)
+        self._jit_cache[key] = (jitted, bytes_map, kernel_map)
+        return jitted, bytes_map, kernel_map, False
+
+    def _run_capped(self, plan, schemas, caps, tables, bytes_map,
+                    kernel_map):
+        from ..runtime.admission import operand_nbytes
+        rels: Dict[int, _CappedRel] = {}
+        # counts/bytes key on the toposort index: stable across
+        # fingerprint-equal plans, whose labels differ (see _jitted_capped)
+        counts: Dict[int, Tuple] = {}
+        overflow = jnp.asarray(False)
+        for i, node in enumerate(plan.nodes):
+            childs = [rels[id(c)] for c in node.children]
+            rel, ovf = self._exec_capped_node(node, i, childs, tables,
+                                              schemas, caps, kernel_map)
+            if ovf is not None:
+                overflow = overflow | ovf
+            bytes_map[i] = operand_nbytes(rel.table)
+            rows_in = sum((jnp.sum(c.alive.astype(jnp.int64))
+                           for c in childs), start=jnp.int64(0))
+            counts[i] = (rows_in, jnp.sum(rel.alive.astype(jnp.int64)))
+            rels[id(node)] = rel
+        root = rels[id(plan.root)]
+        return root.table, root.alive, counts, overflow
+
+    def _exec_capped_node(self, node, idx: int, childs: List[_CappedRel],
+                          tables, schemas, caps, kernel_map):
+        ops = _ops()
+
+        def pick(op: str, sig):
+            # registry dispatch at trace time; choices key on the toposort
+            # index (like counts/bytes) so fingerprint-shared programs stamp
+            # consistently
+            from ..ops.registry import REGISTRY
+            choice = REGISTRY.select(op, sig)
+            kernel_map[idx] = choice.label
+            return choice
+        if isinstance(node, Scan):
+            t = tables[node.source]
+            if node.projection is not None:
+                t = t.select(list(node.projection))
+            return _CappedRel(t, jnp.ones((t.num_rows,), bool)), None
+        if isinstance(node, Filter):
+            (c,) = childs
+            # predicate as a mask AND — the jit tier's filter idiom: no
+            # compaction, dead rows stay and stay dead
+            mask = node.predicate.evaluate(c.table, c.alive)
+            return _CappedRel(c.table, c.alive & mask), None
+        if isinstance(node, FusedSelect):
+            # filter-then-project over the padded frame: the predicate ANDs
+            # into alive and the projection evaluates under the new mask
+            # (scalar aggregates reduce over the filtered live rows). No
+            # compaction happens here, so there is no Pallas form — the
+            # registry consult documents the decline (tier="capped")
+            (c,) = childs
+            from ..ops import select_pallas
+            pick("fused_select",
+                 select_pallas.make_signature(c.table, node.predicate,
+                                              node.exprs, "capped"))
+            mask = node.predicate.evaluate(c.table, c.alive)
+            alive = c.alive & mask
+            return _CappedRel(self._project(c.table, node, alive),
+                              alive), None
+        if isinstance(node, Project):
+            (c,) = childs
+            return _CappedRel(self._project(c.table, node, c.alive),
+                              c.alive), None
+        if isinstance(node, HashJoin):
+            l, r = childs
+            lkeys = [l.table[k] for k in node.left_keys]
+            rkeys = [r.table[k] for k in node.right_keys]
+            from ..ops import join_pallas
+            choice = pick("hash_join",
+                          join_pallas.make_signature(lkeys, rkeys, node.how,
+                                                     "capped"))
+            if node.how == "inner":
+                row_cap = self._node_cap(caps, "row_cap", idx)
+                if not choice.fallback:
+                    lm, rm, valid, ovf = join_pallas.inner_join_capped_pallas(
+                        lkeys, rkeys, row_cap=row_cap, lalive=l.alive,
+                        ralive=r.alive)
+                else:
+                    lm, rm, valid, ovf = ops.inner_join_capped(
+                        lkeys, rkeys, row_cap=row_cap, lalive=l.alive,
+                        ralive=r.alive)
+                cols = [ops.take(col, lm, _has_negative=False)
+                        for col in l.table.columns]
+                cols += [ops.take(col, rm, _has_negative=False)
+                         for col in r.table.columns]
+                t = Table(cols, names=list(l.table.names) +
+                          list(r.table.names))
+                return _CappedRel(t, valid), ovf
+            mask = ops.semi_join_mask(lkeys, rkeys, lalive=l.alive,
+                                      ralive=r.alive)
+            alive = (l.alive & mask if node.how == "left_semi"
+                     else l.alive & ~mask)
+            return _CappedRel(l.table, alive), None
+        if isinstance(node, HashAggregate):
+            (c,) = childs
+            if not node.keys:
+                t = self._global_aggregate(c.table, node, alive=c.alive)
+                return _CappedRel(t, jnp.ones((1,), bool)), None
+            pick("groupby", None)   # dispatch inside groupby_aggregate_capped
+            key_cap = self._node_cap(caps, "key_cap", idx)
+            agg, valid, ovf = ops.groupby_aggregate_capped(
+                c.table, list(node.keys), [(cn, o) for cn, o, _ in node.aggs],
+                key_cap=key_cap, alive=c.alive)
+            t = Table(list(agg.columns), names=schemas[id(node)])
+            return _CappedRel(t, valid), ovf
+        if isinstance(node, Sort):
+            (c,) = childs
+            t, alive = ops.sort_table_capped(
+                c.table, key_names=list(node.keys),
+                ascending=list(node.ascending), alive=c.alive)
+            return _CappedRel(t, alive), None
+        if isinstance(node, TopK):
+            # fused Sort+Limit: dead rows sink in the capped sort, then the
+            # first n LIVE rows survive via the inclusive prefix count. The
+            # Pallas kernel instead returns the top-n live rows directly
+            # (narrower frame, same live set — downstream capped operators
+            # accept any row count)
+            (c,) = childs
+            from ..ops import topk_pallas
+            choice = pick("topk",
+                          topk_pallas.make_signature(c.table, node.keys,
+                                                     node.ascending, node.n,
+                                                     "capped"))
+            if not choice.fallback:
+                t, alive = topk_pallas.topk_capped(
+                    c.table, list(node.keys), list(node.ascending), node.n,
+                    c.alive)
+                return _CappedRel(t, alive), None
+            t, alive = ops.sort_table_capped(
+                c.table, key_names=list(node.keys),
+                ascending=list(node.ascending), alive=c.alive)
+            prefix = jnp.cumsum(alive.astype(jnp.int32))
+            return _CappedRel(t, alive & (prefix <= node.n)), None
+        if isinstance(node, Limit):
+            (c,) = childs
+            # first n LIVE rows: inclusive prefix count over the mask
+            prefix = jnp.cumsum(c.alive.astype(jnp.int32))
+            return _CappedRel(c.table, c.alive & (prefix <= node.n)), None
+        if isinstance(node, Union):
+            t = ops.concat_tables([c.table for c in childs])
+            alive = jnp.concatenate([c.alive for c in childs])
+            return _CappedRel(t, alive), None
+        if isinstance(node, Exchange):
+            return childs[0], None
+        raise PlanValidationError(f"no capped lowering for {node.kind}")

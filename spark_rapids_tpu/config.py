@@ -10,7 +10,6 @@ equivalents, all read at use time (not import time) so tests can monkeypatch:
 |---|---|---|
 | SPARK_RAPIDS_TPU_WATCHDOG_PERIOD_MS | 100 | arbiter deadlock-poll cadence |
 | SPARK_RAPIDS_TPU_RETRY_LIMIT     | 500  | livelock cap before hard OOM   |
-| SPARK_RAPIDS_TPU_TRACE           | 0    | profiler ranges (utils/tracing)|
 | TPU_FAULT_INJECTOR_CONFIG_PATH   | —    | fault injector config (faultinj)|
 | SPARK_RAPIDS_TPU_KERNELS         | —    | kernel-registry overrides, `op=name` pairs (e.g. `fused_select=xla,topk=pallas,groupby=scan`; ops/registry.py, docs/kernels.md) |
 | SPARK_RAPIDS_TPU_ROW_CONVERSION_KERNEL | auto | auto/word/concat (legacy alias for `row_conversion=` in SPARK_RAPIDS_TPU_KERNELS) |
@@ -70,6 +69,10 @@ construct a new monitor/executor, or pass constructor overrides, to
 re-tune); SPARK_RAPIDS_TPU_STATS_CAPACITY/_PATH likewise snapshot when a
 `StatsStore` is constructed (plan/stats.reset_default_store re-reads);
 everything else in the table is read at use time.
+
+Tracing has no knob: the program's spans (utils/tracing.py: `serving.*`,
+`plan.*`, `ops.*`) are recorded whenever a `jax.profiler` session runs and
+are inert otherwise (the reference's `ai.rapids.cudf.nvtx.enabled` slot).
 """
 from __future__ import annotations
 
@@ -100,10 +103,6 @@ def retry_limit() -> int:
     """Consecutive no-progress retries before a hard OOM (reference: 500,
     SparkResourceAdaptorJni.cpp:984-995)."""
     return _int_env("SPARK_RAPIDS_TPU_RETRY_LIMIT", 500)
-
-
-def trace_enabled() -> bool:
-    return os.environ.get("SPARK_RAPIDS_TPU_TRACE", "") == "1"
 
 
 def row_conversion_kernel() -> str:

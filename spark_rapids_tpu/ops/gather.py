@@ -18,6 +18,7 @@ import jax.numpy as jnp
 from ..columnar import Column, Table
 from ..columnar.column import strings_from_padded
 from ..dtypes import Kind
+from ..utils.tracing import span
 
 
 def take(col: Column, idx: jnp.ndarray, check_bounds: bool = False,
@@ -66,8 +67,9 @@ def take(col: Column, idx: jnp.ndarray, check_bounds: bool = False,
         out_lens = jnp.where(nullify, 0, jnp.take(lens, safe, axis=0))
         new_offsets = jnp.concatenate([jnp.zeros((1,), jnp.int32),
                                        jnp.cumsum(out_lens).astype(jnp.int32)])
-        total = int(new_offsets[-1])
-        L = int(jnp.max(lens)) if col.length else 0
+        with span("ops.host_sync", site="gather.list"):
+            total = int(new_offsets[-1])
+            L = int(jnp.max(lens)) if col.length else 0
         # child indexes: for output row i, element j -> old_start[idx[i]] + j
         starts = jnp.take(col.offsets[:-1], safe, axis=0)
         pos = jnp.arange(max(L, 1), dtype=jnp.int32)[None, :]

@@ -359,7 +359,7 @@ def _run_custom(cols, mm_seed, xx_seed, normalize_zero, block_rows, interpret):
                                       memory_space=pltpu.VMEM))
     outs = pl.pallas_call(
         kernel, out_shape=out_shape, in_specs=in_specs, out_specs=out_specs,
-        grid=(M // TM,), interpret=interpret)(*arrays)
+        grid=(M // TM,), interpret=interpret, name="pallas_hash")(*arrays)
     res, i = [], 0
     if emit_mm:
         res.append(Column(dtype=dtypes.INT32, length=n,

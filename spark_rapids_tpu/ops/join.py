@@ -51,6 +51,7 @@ import jax.numpy as jnp
 
 from .. import dtypes
 from ..columnar import Column, Table
+from ..utils.tracing import span
 from .sort import _key_operands
 
 __all__ = ["inner_join", "left_join", "full_join", "left_semi_join",
@@ -246,7 +247,8 @@ def inner_join(left_keys, right_keys,
                null_equal: bool = False) -> Tuple[Column, Column]:
     """Gather maps (left_map, right_map) of the inner equi-join."""
     counts, lo, rorder = _prep(_cols(left_keys), _cols(right_keys), null_equal)
-    total = int(jnp.sum(counts))              # the one host sync
+    with span("ops.host_sync", site="join.inner"):
+        total = int(jnp.sum(counts))          # the one host sync
     lmap, rmap = _expand(counts, lo, rorder, total=total, outer=False)
     return (Column(dtype=dtypes.INT32, length=total, data=lmap),
             Column(dtype=dtypes.INT32, length=total, data=rmap))
@@ -257,7 +259,8 @@ def left_join(left_keys, right_keys,
     """Left outer join: every left row appears; non-matches get right -1
     (take() nullifies)."""
     counts, lo, rorder = _prep(_cols(left_keys), _cols(right_keys), null_equal)
-    total = int(jnp.sum(jnp.maximum(counts, 1)))
+    with span("ops.host_sync", site="join.left"):
+        total = int(jnp.sum(jnp.maximum(counts, 1)))
     lmap, rmap = _expand(counts, lo, rorder, total=total, outer=True)
     return (Column(dtype=dtypes.INT32, length=total, data=lmap),
             Column(dtype=dtypes.INT32, length=total, data=rmap))

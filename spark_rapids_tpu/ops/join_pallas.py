@@ -48,6 +48,7 @@ from jax.experimental.pallas import tpu as pltpu
 from .. import dtypes
 from ..columnar import Column, Table
 from ..dtypes import Kind
+from ..utils.tracing import span
 from .hash_pallas import (_exact_dot, _mm_fmix, _mm_round, _planes,
                           _to_tiles, _u32c, _u16_halves as _halves)
 from .join import _require_x64
@@ -211,7 +212,7 @@ def _build_table(rcols: Sequence[Column], rvalid: jnp.ndarray, C: int,
                  for _ in range(2 + 2 * n_words)]
     outs = pl.pallas_call(
         kernel, out_shape=out_shape, in_specs=in_specs, out_specs=out_specs,
-        interpret=interpret)(*planes, vplane)
+        interpret=interpret, name="pallas_hash_join_build")(*planes, vplane)
     return jnp.stack([o.reshape(-1) for o in outs], axis=1)
 
 
@@ -321,7 +322,7 @@ def _run_probe(body_fn, layout, C, planes, extra_plane, tbl, out_dtype,
         kernel,
         out_shape=[jax.ShapeDtypeStruct((B, 1, _LANES), out_dtype)],
         in_specs=in_specs, out_specs=[row_spec()],
-        grid=(B,), interpret=interpret)(
+        grid=(B,), interpret=interpret, name="pallas_hash_join_probe")(
             *[p.reshape(B, 1, _LANES) for p in planes],
             extra_plane.reshape(B, 1, _LANES), tbl)[0]
     return out.reshape(-1)
@@ -400,8 +401,9 @@ def inner_join_pallas(left_keys, right_keys,
     rvalid = _side_valid(rcols, nr)
     counts, lplanes, layout, C, tbl, interpret = _prep_probe(
         lcols, rcols, lvalid, rvalid, interpret)
-    total = int(jnp.sum(counts))            # the one host sync (same as the
-    #                                         fallback's match-count sync)
+    with span("ops.host_sync", site="join_pallas.inner"):
+        total = int(jnp.sum(counts))        # the one host sync (same as the
+        #                                     fallback's match-count sync)
     if total == 0:
         e = jnp.zeros((0,), jnp.int32)
         return (Column(dtype=dtypes.INT32, length=0, data=e),

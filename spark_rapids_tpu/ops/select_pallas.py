@@ -42,6 +42,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from ..columnar import Column, Table
 from ..dtypes import Kind
+from ..utils.tracing import span
 from .gather import take
 from .hash_pallas import _exact_dot, _to_tiles, _u16_halves
 
@@ -293,13 +294,14 @@ def fused_select_compact(table: Table, predicate, needed: Sequence[str],
     out_specs.append(row_spec(_LANES))
     outs = pl.pallas_call(
         kernel, out_shape=out_shape, in_specs=in_specs, out_specs=out_specs,
-        grid=(B,), interpret=interpret)(*planes)
+        grid=(B,), interpret=interpret, name="pallas_fused_select")(*planes)
     comp, counts = outs[:-1], outs[-1][:, 0, 0]
 
     # epilogue: squeeze block-compacted planes into one contiguous relation
-    total = int(jnp.sum(counts))               # the one host sync — the same
-    #                                            sync the fallback's nonzero()
-    #                                            pays for the keep vector
+    with span("ops.host_sync", site="select_pallas.compact"):
+        total = int(jnp.sum(counts))           # the one host sync — the same
+        #                                        sync the fallback's nonzero()
+        #                                        pays for the keep vector
     if total == 0:
         return Table([take(c, empty, _has_negative=False) for c in cols],
                      names=needed)

@@ -161,7 +161,7 @@ def _topk_words(words: List[jnp.ndarray], k: int, n: int,
         kernel,
         out_shape=[jax.ShapeDtypeStruct((B, n_words, k128), _U32)],
         in_specs=in_specs, out_specs=[out_spec],
-        grid=(B,), interpret=interpret)(*tiles)[0]
+        grid=(B,), interpret=interpret, name="pallas_topk")(*tiles)[0]
     # cross-block merge: B*k128 candidates (tiny) through one XLA sort
     cands = [out[:, wi, :].reshape(-1) for wi in range(n_words)]
     merged = jax.lax.sort(cands, num_keys=n_words, is_stable=False)

@@ -85,5 +85,6 @@ def histogram_pallas(part: jnp.ndarray, num_partitions: int,
         # 0 traces as i64 and Mosaic refuses 64-bit types
         in_specs=[pl.BlockSpec((TM, _LANES), lambda i: (i, i - i))],
         out_specs=pl.BlockSpec((8, _LANES), lambda i: (i - i, i - i)),
-        grid=(m,), interpret=interpret)(planes)
+        grid=(m,), interpret=interpret,
+        name="pallas_partition_counts")(planes)
     return counts[0, :num_partitions]

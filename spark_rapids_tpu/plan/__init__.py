@@ -30,7 +30,7 @@ concerns live in ONE executor:
   at the `Exchange` boundaries the optimizer plans, and gathering to one
   device only at the sink;
   admission (`runtime.admission`), `faultinj` interception and
-  `utils.tracing` ranges apply per operator. Device failures resolve
+  `utils.tracing` spans apply per operator. Device failures resolve
   through the `runtime.health` degradation policy — backoff-paced retries
   for transient faults, circuit-breaker trip + degraded CPU-tier
   completion for sticky/fatal ones (docs/robustness.md).

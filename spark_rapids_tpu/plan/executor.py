@@ -9,9 +9,9 @@ disables it. `PlanResult.optimizer` reports what fired.
 
 - `mode="eager"`: per-operator dispatch through the public `ops` kernels —
   every operator gets its own wall-clock, rows/bytes metrics, a
-  `utils.tracing` range, a plan-level faultinj interception point, and a
-  bounded, backoff-paced re-run on recoverable injected faults (the
-  plan-level retry that replaces per-query hand-wiring).
+  `plan.op` span (`utils.tracing`), a plan-level faultinj interception
+  point, and a bounded, backoff-paced re-run on recoverable injected
+  faults (the plan-level retry that replaces per-query hand-wiring).
 - `mode="capped"`: the whole DAG traces into ONE XLA program with static
   capacities (`row_cap` for joins, `key_cap` for aggregates — per-node
   overrides take precedence). A too-small cap raises the overflow flag and
@@ -75,6 +75,9 @@ kernels that benched slower than their fallback. `SPARK_RAPIDS_TPU_STATS
 """
 from __future__ import annotations
 
+import contextlib
+import itertools
+import re
 import threading
 import time
 from typing import Callable, Dict, List, Optional, Tuple
@@ -91,6 +94,7 @@ from .nodes import (Exchange, Filter, FusedSelect, HashAggregate, HashJoin,
                     Limit, PlanNode, PlanValidationError, Project, Scan,
                     Sort, TopK, Union)
 from .expr import ColumnRef
+from ..utils.tracing import span, text as _span_text
 
 # The device-fault surface the executor turns into policy (runtime/health):
 # injected nonfatal asserts and substituted return codes plus RetryOOM
@@ -115,6 +119,48 @@ def _ops():
 def _sessionctx():
     from ..runtime import sessionctx
     return sessionctx
+
+
+def _op_span(node: PlanNode, idx: int, tier: str = "device"):
+    """The eager tiers' per-operator bracket (utils/tracing.py). `op` is
+    `<toposort index>.<kind>`, the name the operator's scope carries
+    inside a capped program; `tier` is where it ran: device, host
+    (co-placement thread) or degraded (CPU tier)."""
+    return span("plan.op", op=_scope_name(idx, node),
+                label=_span_text(node.label), tier=tier)
+
+
+def _scope_name(idx: int, node: PlanNode) -> str:
+    """`<toposort index>.<kind>`: an operator's name in the program's
+    spans and in a capped program's scopes. The index, not the label:
+    fingerprint-equal plans share one compiled program and their labels
+    differ."""
+    return f"{idx}.{node.kind}"
+
+
+_HLO_INSTRUCTION = re.compile(
+    r"^\s*(?:ROOT )?%(\S+) = .*metadata=\{[^}]*op_name=\"([^\"]*)\"")
+_SCOPE = re.compile(r"^\d+\.[A-Za-z]+$")
+
+
+def _scope_owners(hlo_text: str) -> Dict[str, str]:
+    """{instruction name: its outermost `<idx>.<kind>` scope} over every
+    computation of an executable's text (`_scope_name` wrote the scopes)."""
+    owners: Dict[str, str] = {}
+    for line in hlo_text.splitlines():
+        m = _HLO_INSTRUCTION.match(line)
+        if m:
+            scope = next((p for p in m.group(2).split("/")
+                          if _SCOPE.match(p)), None)
+            if scope:
+                owners[m.group(1)] = scope
+    return owners
+
+
+def _input_key(inputs: Dict[str, Table]) -> Tuple:
+    """The part of the capped program cache's key the inputs give."""
+    return tuple(sorted((n, tuple(t.names), t.num_rows)
+                        for n, t in inputs.items()))
 
 
 # one bounded-cache definition for the whole engine (utils/lru.py): the
@@ -614,6 +660,9 @@ class PlanExecutor:
         # distributed-tier capacity memo: (fingerprint, node index) ->
         # final escalated caps, same contract as _caps_memo
         self._dist_caps_memo: Dict[Tuple, Dict] = _LruDict(256)
+        # request numbers for direct execute() calls (a serving worker
+        # scopes its ticket's instead — runtime/sessionctx.py)
+        self._requests = itertools.count()
 
     def _check_capped_mesh(self, plan: Plan) -> None:
         """mode="capped" with a mesh: reject ONLY plans that contain a
@@ -653,6 +702,18 @@ class PlanExecutor:
         validation, are silently skipped (execution stays correct; only
         the offload is lost). Eager tier only — the capped tier traces
         one XLA program and has no per-subtree dispatch to overlap."""
+        # request identity for the program's spans (utils/tracing.py):
+        # the serving worker has scoped its ticket's number; a direct
+        # call takes this executor's next one
+        ctx = _sessionctx()
+        request = ctx.current_request()
+        if request < 0:
+            request = next(self._requests)
+        with ctx.request_scope(request), span("plan.execute"):
+            return self._execute_request(plan, inputs, tier, placement)
+
+    def _execute_request(self, plan, inputs, tier,
+                         placement) -> PlanResult:
         if tier not in (None, "device", "cpu"):
             raise ValueError(f"unknown execution tier {tier!r} "
                              "(expected device or cpu)")
@@ -670,10 +731,13 @@ class PlanExecutor:
         report = None
         authored = plan
         if self.optimize:
-            plan, schemas, report = self._optimized(plan, inputs, bound)
+            with span("plan.optimize"):
+                plan, schemas, report = self._optimized(plan, inputs, bound)
         from .. import config
         if config.verify_plans():
-            self._verify_execution(authored, plan, report, inputs, bound)
+            with span("plan.verify"):
+                self._verify_execution(authored, plan, report, inputs,
+                                       bound)
         # the AUTHORED fingerprint keys the adaptive feedback loop
         # (plan/stats.py): cold and warm executions of one authored plan
         # share it even when a stats-driven rewrite changes the executed
@@ -726,12 +790,11 @@ class PlanExecutor:
                     plan, inputs, schemas, {}, {}, start=0,
                     t_plan0=time.perf_counter(), mode=self.mode)
         if res is None:
-            if self.session is not None:
-                from ..runtime.admission import active_session
-                with active_session(self.session):
-                    res = self._execute(plan, inputs, schemas, source_fp,
-                                        cert, placements)
-            else:
+            from ..runtime.admission import active_session
+            with span("plan.run"), \
+                    (active_session(self.session)
+                     if self.session is not None
+                     else contextlib.nullcontext()):
                 res = self._execute(plan, inputs, schemas, source_fp,
                                     cert, placements)
         res.cert = cert
@@ -756,10 +819,11 @@ class PlanExecutor:
             # record only what actually ran, under the backend it ran
             # ON: a degraded result finished on the CPU tier and must
             # never drive device-side decisions (docs/adaptive.md)
-            store.record_result(
-                plan, res,
-                backend="cpu" if res.degraded else jax.default_backend(),
-                source_fp=source_fp)
+            with span("plan.stats"):
+                store.record_result(
+                    plan, res,
+                    backend="cpu" if res.degraded else jax.default_backend(),
+                    source_fp=source_fp)
         return res
 
     def _verify_execution(self, authored, plan, report, inputs, bound):
@@ -915,27 +979,30 @@ class PlanExecutor:
         otherwise run."""
         from ..analysis import footprint
         try:
-            input_dtypes, input_nullable = footprint.table_metadata(inputs)
-            bound_rows = {n: t.num_rows for n, t in inputs.items()}
-            n_peers = (self.mesh.shape[self.mesh_axis]
-                       if self.mesh is not None and self.mode == "eager"
-                       else 1)
-            key = (plan.root, tuple(sorted(bound.items())),
-                   tuple(sorted(bound_rows.items())),
-                   tuple((n, tuple((cn, repr(dt))
-                                   for cn, dt in cols.items()))
-                         for n, cols in sorted(input_dtypes.items())),
-                   tuple((n, tuple(sorted(cols.items())))
-                         for n, cols in sorted(input_nullable.items())),
-                   n_peers)
-            hit = self._cert_cache.get(key)
-            if hit is None:
-                hit = footprint.certify(
-                    plan, bound=bound, bound_rows=bound_rows,
-                    input_dtypes=input_dtypes,
-                    input_nullable=input_nullable, n_peers=n_peers)
-                self._cert_cache[key] = hit
-            return hit
+            with span("plan.certify"):
+                input_dtypes, input_nullable = \
+                    footprint.table_metadata(inputs)
+                bound_rows = {n: t.num_rows for n, t in inputs.items()}
+                n_peers = (self.mesh.shape[self.mesh_axis]
+                           if self.mesh is not None
+                           and self.mode == "eager" else 1)
+                key = (plan.root, tuple(sorted(bound.items())),
+                       tuple(sorted(bound_rows.items())),
+                       tuple((n, tuple((cn, repr(dt))
+                                       for cn, dt in cols.items()))
+                             for n, cols in sorted(input_dtypes.items())),
+                       tuple((n, tuple(sorted(cols.items())))
+                             for n, cols
+                             in sorted(input_nullable.items())),
+                       n_peers)
+                hit = self._cert_cache.get(key)
+                if hit is None:
+                    hit = footprint.certify(
+                        plan, bound=bound, bound_rows=bound_rows,
+                        input_dtypes=input_dtypes,
+                        input_nullable=input_nullable, n_peers=n_peers)
+                    self._cert_cache[key] = hit
+                return hit
         except Exception:
             return None
 
@@ -981,6 +1048,38 @@ class PlanExecutor:
                               self._kernel_summary()])
         from .optimizer import explain_optimized
         return explain_optimized(plan) + "\n" + self._kernel_summary()
+
+    def device_op_owners(self, plan: Plan,
+                         inputs: Optional[Dict[str, Table]] = None
+                         ) -> Dict[str, str]:
+        """Who owns `fusion.32`: {HLO instruction name: "<idx>.<kind>"}
+        for the capped program this executor runs for `plan` over
+        `inputs` at the capacities its next execution would start from
+        (after a run: the ones that run ended on). A device trace prints
+        bare instruction names (`jit_capped_plan/fusion.32`); every
+        operator runs under the scope `<toposort index>.<kind>` of the
+        EXECUTED plan (the indices `explain(optimized=True, inputs=...)`
+        lists top-down from the leaves), and the compiled executable's
+        text keeps that scope in each instruction's `op_name`, a fusion
+        under its own. Instructions outside any operator (parameters,
+        copies the compiler added) are left out. On request only: this
+        lowers the program again and reads the executable back through
+        the compile cache; `execute` never calls it."""
+        if self.mode != "capped":
+            raise PlanValidationError(
+                "device_op_owners reads the capped tier's one program; "
+                "the eager tier's operators are separate programs, told "
+                "apart by their plan.op spans")
+        inputs = bind_scan_sources(plan, inputs)
+        bound = {name: tuple(t.names) for name, t in inputs.items()}
+        schemas = plan.resolve_schemas(bound)
+        source_fp = plan.fingerprint
+        if self.optimize:
+            plan, schemas, _ = self._optimized(plan, inputs, bound)
+        caps, _ = self._starting_caps(plan, inputs, source_fp,
+                                      self._certify(plan, inputs, bound))
+        fn = self._jitted_capped(plan, schemas, caps, _input_key(inputs))[0]
+        return _scope_owners(fn.lower(dict(inputs)).compile().as_text())
 
     @staticmethod
     def _transport_summary() -> str:
@@ -1071,7 +1170,6 @@ class PlanExecutor:
     def _execute_eager(self, plan, inputs, schemas,
                        placements=None) -> PlanResult:
         from ..runtime.admission import operand_nbytes
-        from ..utils import tracing
         t_plan0 = time.perf_counter()
         results: Dict[int, Table] = {}
         metrics: Dict[str, OperatorMetrics] = {}
@@ -1109,10 +1207,11 @@ class PlanExecutor:
         if placements and dist is None:
             host_roots, host_skip = self._placement_subtrees(
                 plan, placements, inputs, chains, chain_interior)
+        request = _sessionctx().current_request()
         for rid, sub in host_roots.items():
             results[rid] = _PendingHostRel(
                 (lambda s: lambda: self._run_host_subtree(
-                    s, inputs, schemas))(sub),
+                    s, inputs, schemas, node_index, request))(sub),
                 sub[-1].label)
         try:
             for i, node in enumerate(plan.nodes):
@@ -1126,7 +1225,8 @@ class PlanExecutor:
                     chain = chains[id(node)]
                     try:
                         out = self._exec_stream_chain(chain, inputs,
-                                                      schemas, metrics)
+                                                      schemas, metrics,
+                                                      node_index)
                     except _StreamBreaker as sb:
                         if self.degrade == "off":
                             raise sb.error
@@ -1148,7 +1248,7 @@ class PlanExecutor:
                 out = None
                 while True:
                     try:
-                        with tracing.range_ctx(f"plan.{node.label}"):
+                        with _op_span(node, i):
                             self._faultinj_point(node)
                             if dist is not None:
                                 out = dist.exec_node(node, child_tables,
@@ -1161,6 +1261,14 @@ class PlanExecutor:
                                         metrics)
                                 out = self._exec_eager_node(
                                     node, child_tables, inputs, schemas, m)
+                            # blocked inside the span, so that the span
+                            # holds the operator's device work (an async
+                            # exchange in flight stays unblocked: that
+                            # would forfeit the transfer/compute overlap)
+                            if self.block_per_op \
+                                    and not getattr(out, "pending", False):
+                                jax.block_until_ready(
+                                    [c.data for c in out.columns])
                         break
                     except _fault_surface() as err:
                         if self._handle_fault(err, node.label, attempt, m):
@@ -1184,9 +1292,6 @@ class PlanExecutor:
                     # metric row when a consumer resolves it
                     m.rows_in = sum(t.num_rows for t in child_tables)
                 else:
-                    if self.block_per_op:
-                        jax.block_until_ready([c.data
-                                               for c in out.columns])
                     # wall is compute (all attempts), NOT the backoff idle
                     # time — that is reported separately in backoff_ms,
                     # not double-counted
@@ -1285,7 +1390,8 @@ class PlanExecutor:
                 claimed |= ids
         return roots, claimed
 
-    def _run_host_subtree(self, sub, inputs, schemas):
+    def _run_host_subtree(self, sub, inputs, schemas, node_index,
+                          request: int):
         """Execute one host-placed subtree (postorder node list) — the
         co-placement worker thread's body, also re-run synchronously on
         the main thread when a consumer retries after a host failure.
@@ -1299,15 +1405,14 @@ class PlanExecutor:
         everywhere. Returns (outputs by id(node), metrics by label);
         every output is blocked-until-ready so the overlap the consumer
         measures is real completed work."""
-        import contextlib
         from ..runtime.admission import operand_nbytes
-        from ..utils import tracing
         cpu = _cpu_device()
         ctx = (jax.default_device(cpu) if cpu is not None
                else contextlib.nullcontext())
         outs: Dict[int, Table] = {}
         ms: Dict[str, OperatorMetrics] = {}
-        with ctx:
+        # the worker thread joins the launching request's spans
+        with ctx, _sessionctx().request_scope(request):
             host_inputs = dict(inputs)
             for n in sub:
                 if isinstance(n, Scan):
@@ -1320,11 +1425,11 @@ class PlanExecutor:
                 m.placement = "host"  # set BEFORE dispatch: pins the
                 #                       registry to cpu kernels
                 t0 = time.perf_counter()
-                with tracing.range_ctx(f"plan.{n.label}.host"):
+                with _op_span(n, node_index[id(n)], "host"):
                     self._faultinj_point(n)
                     out = self._exec_eager_node(n, childs, host_inputs,
                                                 schemas, m)
-                jax.block_until_ready([c.data for c in out.columns])
+                    jax.block_until_ready([c.data for c in out.columns])
                 m.wall_ms = (time.perf_counter() - t0) * 1e3
                 m.rows_in = sum(t.num_rows for t in childs)
                 m.rows_out = out.num_rows
@@ -1390,10 +1495,8 @@ class PlanExecutor:
         itself fails (device buffers already lost), the whole plan re-runs
         from the scans. Admission still applies — degraded work is
         budgeted like any other."""
-        import contextlib
         from .. import faultinj
         from ..runtime.admission import operand_nbytes
-        from ..utils import tracing
         self.health.note_degraded_plan()
         cpu = _cpu_device()
         ctx = (jax.default_device(cpu) if cpu is not None
@@ -1422,7 +1525,7 @@ class PlanExecutor:
                 start = 0
                 first_metric = None
             try:
-                for node in plan.nodes[start:]:
+                for i, node in enumerate(plan.nodes[start:], start):
                     childs = [cpu_results[id(c)] for c in node.children]
                     if first_metric is not None and node is plan.nodes[start]:
                         m = first_metric  # keep the failed op's retry record
@@ -1431,11 +1534,12 @@ class PlanExecutor:
                                             describe=node.describe())
                     m.degraded = True
                     t0 = time.perf_counter()
-                    with tracing.range_ctx(f"plan.{node.label}.degraded"):
+                    with _op_span(node, i, "degraded"):
                         out = self._exec_eager_node(node, childs, cpu_inputs,
                                                     schemas, m)
-                    if self.block_per_op:
-                        jax.block_until_ready([c.data for c in out.columns])
+                        if self.block_per_op:
+                            jax.block_until_ready(
+                                [c.data for c in out.columns])
                     m.wall_ms = (time.perf_counter() - t0) * 1e3
                     m.rows_in = sum(t.num_rows for t in childs)
                     m.rows_out = out.num_rows
@@ -1516,17 +1620,16 @@ class PlanExecutor:
                 chains[id(chain[-1])] = chain
         return chains
 
-    def _stream_op(self, node, t: Table, inputs, schemas,
+    def _stream_op(self, node, idx: int, t: Table, inputs, schemas,
                    m: OperatorMetrics, fn=None) -> Table:
         """One chain operator over one chunk, with the same per-op fault
         policy as the materialized path (backoff-paced retries; a breaker
         trip raises _StreamBreaker so the caller can degrade)."""
-        from ..utils import tracing
         attempt = 0
         while True:
             t0 = time.perf_counter()
             try:
-                with tracing.range_ctx(f"plan.{node.label}"):
+                with _op_span(node, idx):
                     self._faultinj_point(node)
                     out = (fn(t) if fn is not None else
                            self._exec_eager_node(node, [t], inputs,
@@ -1545,7 +1648,8 @@ class PlanExecutor:
         return out
 
     def _exec_stream_chain(self, chain, inputs, schemas,
-                           metrics: Dict[str, OperatorMetrics]) -> Table:
+                           metrics: Dict[str, OperatorMetrics],
+                           node_index: Dict[int, int]) -> Table:
         """Run one streamable prefix morsel-at-a-time: row-group pruning at
         the scan, bounded host prefetch decoding chunk N+1 while chunk N
         executes, per-chunk Filter/Project/FusedSelect, and partial
@@ -1605,8 +1709,8 @@ class PlanExecutor:
                 sm.bytes_out += operand_nbytes(chunk)
                 t = chunk
                 for node in body:
-                    t = self._stream_op(node, t, inputs, schemas,
-                                        ms[node.label])
+                    t = self._stream_op(node, node_index[id(node)], t,
+                                        inputs, schemas, ms[node.label])
                     ms[node.label].bytes_out += operand_nbytes(t)
                 if agg is not None:
                     if t.num_rows == 0:
@@ -1619,7 +1723,8 @@ class PlanExecutor:
                         proc_intervals.append((t0p, time.perf_counter()))
                         continue
                     t = self._stream_op(
-                        agg, t, inputs, schemas, ms[agg.label],
+                        agg, node_index[id(agg)], t, inputs, schemas,
+                        ms[agg.label],
                         fn=lambda tt: self._stream_partial_agg(agg, tt,
                                                                schemas))
                 parts.append(t)
@@ -1643,7 +1748,8 @@ class PlanExecutor:
                 # min/max error) to the table-bound plan over an empty
                 # filtered relation
                 parts = [self._stream_op(
-                    agg, empty_t, inputs, schemas, ms[agg.label],
+                    agg, node_index[id(agg)], empty_t, inputs, schemas,
+                    ms[agg.label],
                     fn=lambda tt: self._stream_partial_agg(agg, tt,
                                                            schemas))]
             out = self._finalize_stream_agg(agg, parts, schemas)
@@ -1981,6 +2087,49 @@ class PlanExecutor:
                 ceil[which] = max(g, caps[which])
         return caps, ceil
 
+    def _starting_caps(self, plan, inputs, source_fp, cert):
+        """-> (caps the next capped run of `plan` over `inputs` starts
+        from, the certified ceilings of its escalation ladder)."""
+        # start from the input-derived defaults, floored up by any caps the
+        # plan already escalated to: the memo must never UNDERSIZE a run on
+        # larger inputs than it was learned on (only skip re-learning)
+        caps = self._default_caps(plan, inputs)
+        fp = plan.fingerprint        # canonical structural hash: equivalent
+        #                              plans built independently share the
+        #                              caps memo and compiled programs
+        for k, v in (self._caps_memo.get(fp) or {}).items():
+            caps[k] = max(caps.get(k, 0), v)
+        # adaptive cap seeding (plan/stats.py, docs/adaptive.md): floor
+        # the starting capacities at the observed high-water marks from
+        # prior executions of this authored plan, so a repeat fingerprint
+        # compiles once instead of re-climbing the escalation ladder —
+        # the per-executor memo above, promoted across executor
+        # instances (and processes, with persistence on). Same
+        # floor-only contract: caps are STARTING capacities the overflow
+        # ladder would have grown anyway, so seeding can never change
+        # results, only skip retries. Keyed by the backend about to run:
+        # degraded-run stats recorded under "cpu" never seed a device.
+        from . import stats as stats_mod
+        store = stats_mod.active_store()
+        if store is not None and source_fp is not None:
+            with span("plan.stats"):
+                observed = store.observed_caps(jax.default_backend(),
+                                               source_fp, executed_fp=fp)
+            for k, v in observed.items():
+                caps[k] = max(caps.get(k, 0), v)
+        # certified cap bounds (analysis/footprint.py, docs/adaptive.md):
+        # with adaptivity on, cold starting caps tighten to the sound
+        # hi-bound and the escalation ladder ceilings at it — the warm
+        # observed high-water (merged above) must always sit at or below
+        # the certified bound; that inequality IS the certifier's
+        # soundness check (fuzz property 5). Stats off stays
+        # byte-identical static: the certifier then only stamps results.
+        from .. import config
+        cert_ceil: Dict[str, int] = {}
+        if store is not None and cert is not None and config.cert_seed():
+            caps, cert_ceil = self._cert_caps(plan, caps, cert)
+        return caps, cert_ceil
+
     def _execute_capped(self, plan, inputs, schemas,
                         source_fp=None, cert=None) -> PlanResult:
         from ..parallel.autoretry import auto_retry_overflow
@@ -2005,43 +2154,8 @@ class PlanExecutor:
                 else:
                     inputs[name] = v.read_all()
                 scan_io[name] = holder
-        # start from the input-derived defaults, floored up by any caps the
-        # plan already escalated to: the memo must never UNDERSIZE a run on
-        # larger inputs than it was learned on (only skip re-learning)
-        caps = self._default_caps(plan, inputs)
-        fp = plan.fingerprint        # canonical structural hash: equivalent
-        #                              plans built independently share the
-        #                              caps memo and compiled programs
-        for k, v in (self._caps_memo.get(fp) or {}).items():
-            caps[k] = max(caps.get(k, 0), v)
-        # adaptive cap seeding (plan/stats.py, docs/adaptive.md): floor
-        # the starting capacities at the observed high-water marks from
-        # prior executions of this authored plan, so a repeat fingerprint
-        # compiles once instead of re-climbing the escalation ladder —
-        # the per-executor memo above, promoted across executor
-        # instances (and processes, with persistence on). Same
-        # floor-only contract: caps are STARTING capacities the overflow
-        # ladder would have grown anyway, so seeding can never change
-        # results, only skip retries. Keyed by the backend about to run:
-        # degraded-run stats recorded under "cpu" never seed a device.
-        from . import stats as stats_mod
-        store = stats_mod.active_store()
-        if store is not None and source_fp is not None:
-            for k, v in store.observed_caps(jax.default_backend(),
-                                            source_fp,
-                                            executed_fp=fp).items():
-                caps[k] = max(caps.get(k, 0), v)
-        # certified cap bounds (analysis/footprint.py, docs/adaptive.md):
-        # with adaptivity on, cold starting caps tighten to the sound
-        # hi-bound and the escalation ladder ceilings at it — the warm
-        # observed high-water (merged above) must always sit at or below
-        # the certified bound; that inequality IS the certifier's
-        # soundness check (fuzz property 5). Stats off stays
-        # byte-identical static: the certifier then only stamps results.
-        from .. import config
-        cert_ceil: Dict[str, int] = {}
-        if store is not None and cert is not None and config.cert_seed():
-            caps, cert_ceil = self._cert_caps(plan, caps, cert)
+        caps, cert_ceil = self._starting_caps(plan, inputs, source_fp, cert)
+        fp = plan.fingerprint
         t0 = time.perf_counter()
         attempts = 0
         cache_hits = 0
@@ -2066,12 +2180,14 @@ class PlanExecutor:
             # anyway, a per-shape entry keeps each bytes_map true to ITS
             # trace, and the names guard fingerprint-shared undeclared
             # scans bound to differently-named tables
-            fn, bm, km, hit = self._jitted_capped(
-                plan, schemas, caps_now,
-                tuple(sorted((n, tuple(t.names), t.num_rows)
-                             for n, t in inputs.items())))
-            cache_hits += hit
-            out = fn(dict(inputs))
+            # one pass over the program: an escalation shows as a second
+            # plan.attempt under the same plan.run
+            with span("plan.attempt", attempt=attempts) as sp:
+                fn, bm, km, hit = self._jitted_capped(
+                    plan, schemas, caps_now, _input_key(inputs))
+                sp.set_metadata(hit=int(hit))
+                cache_hits += hit
+                out = fn(dict(inputs))
             bytes_map.clear()
             bytes_map.update(bm)    # bm fills during the first trace
             kernel_map.clear()
@@ -2177,11 +2293,12 @@ class PlanExecutor:
         bytes_map: Dict[int, int] = {}
         kernel_map: Dict[int, str] = {}
 
-        def fn(tables: Dict[str, Table]):
+        def capped_plan(tables: Dict[str, Table]):
+            # the name is the module's (`jit_capped_plan`) in a profile
             return self._run_capped(plan, schemas, caps, tables, bytes_map,
                                     kernel_map)
 
-        jitted = jax.jit(fn)
+        jitted = jax.jit(capped_plan)
         self._jit_cache[key] = (jitted, bytes_map, kernel_map)
         return jitted, bytes_map, kernel_map, False
 
@@ -2195,14 +2312,18 @@ class PlanExecutor:
         overflow = jnp.asarray(False)
         for i, node in enumerate(plan.nodes):
             childs = [rels[id(c)] for c in node.children]
-            rel, ovf = self._exec_capped_node(node, i, childs, tables,
-                                              schemas, caps, kernel_map)
-            if ovf is not None:
-                overflow = overflow | ovf
-            bytes_map[i] = operand_nbytes(rel.table)
-            rows_in = sum((jnp.sum(c.alive.astype(jnp.int64))
-                           for c in childs), start=jnp.int64(0))
-            counts[i] = (rows_in, jnp.sum(rel.alive.astype(jnp.int64)))
+            # the operator's name inside the program, which
+            # device_op_owners reads back
+            with jax.named_scope(_scope_name(i, node)):
+                rel, ovf = self._exec_capped_node(node, i, childs, tables,
+                                                  schemas, caps, kernel_map)
+                if ovf is not None:
+                    overflow = overflow | ovf
+                bytes_map[i] = operand_nbytes(rel.table)
+                rows_in = sum((jnp.sum(c.alive.astype(jnp.int64))
+                               for c in childs), start=jnp.int64(0))
+                counts[i] = (rows_in,
+                             jnp.sum(rel.alive.astype(jnp.int64)))
             rels[id(node)] = rel
         root = rels[id(plan.root)]
         return root.table, root.alive, counts, overflow

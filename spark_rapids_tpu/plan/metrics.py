@@ -7,10 +7,13 @@ device-assert recoveries, the RetryOOM analogue) and `escalations` (cap
 growth attempts charged to the node whose capacity overflowed — the
 SplitAndRetry analogue at plan granularity).
 
-`profile()` on a PlanResult returns these rows; the executor additionally
-brackets every operator with `utils.tracing.range_ctx("plan.<label>")`, so
-the same names show up in the xplane/perfetto timeline when
-SPARK_RAPIDS_TPU_TRACE=1.
+`profile()` on a PlanResult returns these rows. The eager tier additionally
+brackets every operator with a `plan.op` span (`utils/tracing.py`; `op` =
+`<toposort index>.<kind>`, the name the operator's scope carries inside a
+capped program), recorded whenever a profiler session runs; to read a
+device profile of the capped tier, `PlanExecutor.device_op_owners` maps
+its instruction names to those operators (docs/plan.md "Reading a
+profile").
 """
 from __future__ import annotations
 

@@ -17,6 +17,12 @@ thread identity. This module is the one place that question is asked:
   even when several tenants share one worker thread (or one tenant
   spans several).
 
+- `request_scope(n)` / `current_request()` do the same for the REQUEST
+  number the program's spans carry (utils/tracing.py): the scheduler
+  numbers its tickets and scopes the worker's execution, a direct
+  `PlanExecutor.execute` scopes its own counter's next number; -1
+  outside any scope.
+
 Kept deliberately tiny and dependency-free: runtime/health.py must be
 importable without the serving package.
 """
@@ -52,5 +58,24 @@ def session_scope(session_id: str) -> Iterator[str]:
     stack.append(str(session_id))
     try:
         yield session_id
+    finally:
+        stack.pop()
+
+
+def current_request() -> int:
+    """The innermost request number scoped on this thread, or -1."""
+    stack = getattr(_ctx, "requests", None)
+    return stack[-1] if stack else -1
+
+
+@contextlib.contextmanager
+def request_scope(request: int) -> Iterator[int]:
+    """Attribute the dynamic extent to request `request` on this thread."""
+    stack = getattr(_ctx, "requests", None)
+    if stack is None:
+        stack = _ctx.requests = []
+    stack.append(int(request))
+    try:
+        yield request
     finally:
         stack.pop()

@@ -46,6 +46,16 @@ from typing import Dict, Optional, Tuple
 import numpy as np
 
 
+_hashed = threading.local()     # .n: buffer bytes this thread has digested
+
+
+def bytes_hashed() -> int:
+    """Running total of buffer bytes the calling thread has copied to the
+    host and hashed; the `serving.digest` span reports the difference
+    across one submit (0 when every table's digest was memoized)."""
+    return getattr(_hashed, "n", 0)
+
+
 def _hash_array(h, a) -> None:
     if a is None:
         h.update(b"\x00none")
@@ -54,6 +64,7 @@ def _hash_array(h, a) -> None:
     h.update(str(arr.dtype).encode())
     h.update(str(arr.shape).encode())
     h.update(arr.tobytes())
+    _hashed.n = bytes_hashed() + arr.nbytes
 
 
 def _hash_column(h, col) -> None:

@@ -101,8 +101,11 @@ class Ticket:
     (re-raising the execution error, if any); `queue_wait_ms` and
     `cached` are the serving-side observability stamps."""
 
-    def __init__(self, session_id: str):
+    def __init__(self, session_id: str, request: int = -1):
         self.session = session_id
+        self.request = request    # monotonic per scheduler: the number
+        #                           this submission's spans carry
+        #                           (utils/tracing.py)
         self.queue_wait_ms: float = 0.0
         self.cached = False
         self.charge_source = ""   # "observed" | "certified" | "default"
@@ -341,6 +344,7 @@ class ServingScheduler:
         self.cache = cache_mod.ResultCache(entries=cache_entries,
                                            ttl_s=cache_ttl_s, clock=clock)
         self._clock = clock
+        self._requests = itertools.count()    # Ticket.request numbers
         self._lock = threading.Lock()
         self._lock_cond = threading.Condition(self._lock)
         self._sessions: Dict[str, _SessionState] = {}
@@ -547,33 +551,64 @@ class ServingScheduler:
     def _submit(self, state: _SessionState, plan, inputs: Optional[Dict],
                 *, block: Optional[bool], timeout: Optional[float],
                 pin_cpu: bool = False) -> Ticket:
+        """One submission, under the spans that say where its host time
+        goes (utils/tracing.py): `serving.submit` > `serving.digest`
+        (input digest + cache consult), `serving.admit` (certify, charge,
+        over-quota resolution), `serving.enqueue` (the bounded queue)."""
+        from ..runtime.sessionctx import request_scope
+        from ..utils.tracing import span
+        request = next(self._requests)
+        with request_scope(request), span("serving.submit"):
+            if self._closed or state.closed:
+                # early unlocked read: a submit racing close() is still
+                # caught by the locked re-check at enqueue below; this
+                # just keeps cache hits from serving through a closed
+                # front door
+                raise ServingRejectedError(
+                    "closed", "session or scheduler is shut down",
+                    session=state.id)
+            if block is None:
+                block = self.block_default
+            inputs = self._bind(plan, inputs)
+            ticket = Ticket(state.id, request)
+            with span("serving.digest") as sp:
+                hashed0 = cache_mod.bytes_hashed()
+                key = cache_mod.cache_key(plan, inputs) \
+                    if self.cache.entries > 0 else None
+                hit = self.cache.get(key)
+                sp.set_metadata(bytes=cache_mod.bytes_hashed() - hashed0,
+                                hit=int(hit is not None))
+            if hit is not None:
+                # a hit consumes nothing: no queue slot, no quota, no
+                # worker
+                hit.session = state.id
+                for m in hit.metrics.values():
+                    m.session = state.id
+                ticket.cached = True
+                with self._lock:
+                    state.submitted += 1
+                    state.completed += 1
+                    state.cache_hits += 1
+                ticket._complete(result=hit)
+                return ticket
+            with span("serving.admit"):
+                charge, source, op_label, tier, placement = self._admit(
+                    state, plan, inputs, pin_cpu)
+            ticket.charge_source = source
+            deadline = None if timeout is None else self._clock() + timeout
+            job = _Job(plan, inputs, state, ticket, charge, source,
+                       op_label, tier, key, self._clock(),
+                       deadline=deadline, placement=placement)
+            with span("serving.enqueue"):
+                self._enqueue(job, block, timeout)
+        return ticket
+
+    def _admit(self, state: _SessionState, plan, inputs: Dict,
+               pin_cpu: bool):
+        """Size the submission against the session quota BEFORE any
+        compilation -> (charge, charge source, operator label, tier,
+        placement); raises the typed over-quota rejection."""
         from ..analysis.footprint import quota_charge
-        if self._closed or state.closed:
-            # early unlocked read: a submit racing close() is still
-            # caught by the locked re-check at enqueue below; this just
-            # keeps cache hits from serving through a closed front door
-            raise ServingRejectedError(
-                "closed", "session or scheduler is shut down",
-                session=state.id)
-        if block is None:
-            block = self.block_default
-        inputs = self._bind(plan, inputs)
-        ticket = Ticket(state.id)
-        key = cache_mod.cache_key(plan, inputs) \
-            if self.cache.entries > 0 else None
-        hit = self.cache.get(key)
-        if hit is not None:
-            # a hit consumes nothing: no queue slot, no quota, no worker
-            hit.session = state.id
-            for m in hit.metrics.values():
-                m.session = state.id
-            ticket.cached = True
-            with self._lock:
-                state.submitted += 1
-                state.completed += 1
-                state.cache_hits += 1
-            ticket._complete(result=hit)
-            return ticket
         cert = self._certify(plan, inputs)
         charge, source, op_label = quota_charge(cert,
                                                 self.default_charge_bytes)
@@ -586,7 +621,6 @@ class ServingScheduler:
             charge = min(observed, charge) if source == "certified" \
                 else observed
             source = "observed"
-        ticket.charge_source = source
         tier = "device"
         placement = None
         if pin_cpu:
@@ -619,10 +653,17 @@ class ServingScheduler:
                 # (docs/serving.md#partial-placement); the job stays on
                 # the device tier instead of the whole-plan CPU pin
                 placement, charge = split
-                ticket.charge_source = source = "partial"
+                source = "partial"
             else:
                 tier, charge = "cpu", 0
-        deadline = None if timeout is None else self._clock() + timeout
+        return charge, source, op_label, tier, placement
+
+    def _enqueue(self, job: _Job, block: bool,
+                 timeout: Optional[float]) -> None:
+        """Append `job` to its session's queue, blocking (or fast-
+        rejecting, per the backpressure policy) while the bounded queue
+        is full; the queue wait counts from the append."""
+        state, deadline = job.state, job.deadline
         with self._lock_cond:
             if self._closed or state.closed:
                 raise ServingRejectedError(
@@ -652,15 +693,12 @@ class ServingScheduler:
                     raise ServingRejectedError(
                         "closed", "session or scheduler shut down while "
                         "submit was blocked", session=state.id)
-            job = _Job(plan, inputs, state, ticket, charge, source,
-                       op_label, tier, key, self._clock(),
-                       deadline=deadline, placement=placement)
+            job.enqueued_at = self._clock()
             state.queue.append(job)
             state.submitted += 1
             self._queued += 1
             self._queued_hiwater = max(self._queued_hiwater, self._queued)
             self._lock_cond.notify_all()
-        return ticket
 
     # ---- dispatch ----------------------------------------------------------
 
@@ -774,9 +812,18 @@ class ServingScheduler:
 
     def _run_job(self, job: _Job) -> None:
         from ..runtime import sessionctx
-        state = job.state
+        from ..utils.tracing import span
         wait_ms = (self._clock() - job.enqueued_at) * 1e3
         job.ticket.queue_wait_ms = wait_ms
+        # the worker's side of the request: the number published here is
+        # what joins plan.* and ops.* spans to the submitter's serving.*
+        with sessionctx.request_scope(job.ticket.request), \
+                span("serving.dispatch", queue_wait_ms=round(wait_ms, 3)):
+            self._run_dispatched(job, wait_ms)
+
+    def _run_dispatched(self, job: _Job, wait_ms: float) -> None:
+        from ..runtime import sessionctx
+        state = job.state
         result = error = None
         served_hit = False
         # EVERYTHING between dispatch and the finally must leave the

@@ -1,73 +1,39 @@
-"""Tracing hooks — the reference's NVTX integration, TPU-style.
+"""Program spans — the reference's NVTX ranges (`CUDF_FUNC_RANGE()`,
+NativeParquetJni.cpp:136), TPU-style.
 
-The reference brackets native ops with NVTX ranges (`CUDF_FUNC_RANGE()`,
-NativeParquetJni.cpp:136) behind a jar flag (`ai.rapids.cudf.nvtx.enabled`,
-pom.xml:87) so nsight can attribute GPU time; its de-facto execution trace is
-the arbiter's CSV state log (SURVEY.md §5). The JAX equivalents:
+One mechanism: `span(name, **attrs)` is a `jax.profiler.TraceAnnotation`.
+"Tracing on" means "a profiler session is running" (`jax.profiler.
+start_trace`, XProf, the benchmark's `--trace 1`) and nothing else: the
+profiler keeps the spans in memory and writes them into the same
+`.xplane.pb` as the device trace, on its clock, on the `/host:` plane's
+line of the calling thread. With no session a span is inert (under a
+microsecond). There is no environment knob, no second recorder and no
+exporter.
 
-- `func_range` / `range_ctx`: `jax.profiler.TraceAnnotation` ranges that show
-  up in the xplane/perfetto trace, gated by SPARK_RAPIDS_TPU_TRACE=1 (zero
-  overhead when off, like the nvtx flag).
-- `start_trace`/`stop_trace`: wrap `jax.profiler` to capture a device trace
-  directory viewable in XProf/TensorBoard (the nsight-systems slot).
-- the arbiter CSV state log lives in runtime/adaptor.py (`log_loc=`).
+Names are `serving.*`, `plan.*` and `ops.*`; every span carries the
+`request=` scoped on its thread (`runtime/sessionctx.request_scope`: the
+scheduler scopes a ticket's number around submit and around the worker's
+execution, a direct `PlanExecutor.execute` its own counter's), which joins
+the submitting thread's spans to the worker's; nesting on a thread gives
+the parent. Attributes known
+only at the end go in through the span object's `set_metadata(**attrs)`.
+Attribute text must hold none of `#`, `,` and `=` (the profiler's own
+encoding): `text()` makes a plan label safe. docs/plan.md lists every
+span, its attributes and what reads them.
 """
 from __future__ import annotations
 
-import contextlib
-import functools
-from typing import Callable, TypeVar
+from jax.profiler import TraceAnnotation
 
-F = TypeVar("F", bound=Callable)
-
-ENV_FLAG = "SPARK_RAPIDS_TPU_TRACE"
+from ..runtime.sessionctx import current_request
 
 
-def enabled() -> bool:
-    from ..config import trace_enabled
-    return trace_enabled()
+def span(name: str, **attrs) -> TraceAnnotation:
+    """A named range on the profiler's timeline, as a context manager,
+    stamped with the request scoped on this thread (-1 outside any)."""
+    return TraceAnnotation(name, request=current_request(), **attrs)
 
 
-@contextlib.contextmanager
-def range_ctx(name: str):
-    """Named range in the profiler timeline (CUDF_FUNC_RANGE analogue)."""
-    if not enabled():
-        yield
-        return
-    import jax.profiler
-    with jax.profiler.TraceAnnotation(name):
-        yield
-
-
-def func_range(fn: F) -> F:
-    """Decorator form: wraps the call in a TraceAnnotation named after the
-    function, only when tracing is enabled."""
-    @functools.wraps(fn)
-    def wrapper(*args, **kwargs):
-        if not enabled():
-            return fn(*args, **kwargs)
-        import jax.profiler
-        with jax.profiler.TraceAnnotation(fn.__qualname__):
-            return fn(*args, **kwargs)
-    return wrapper  # type: ignore[return-value]
-
-
-def start_trace(log_dir: str) -> None:
-    """Begin capturing a device trace (XProf/TensorBoard-viewable)."""
-    import jax.profiler
-    jax.profiler.start_trace(log_dir)
-
-
-def stop_trace() -> None:
-    import jax.profiler
-    jax.profiler.stop_trace()
-
-
-@contextlib.contextmanager
-def trace(log_dir: str):
-    """Capture a device trace around a block."""
-    start_trace(log_dir)
-    try:
-        yield
-    finally:
-        stop_trace()
+def text(label: str) -> str:
+    """`HashJoin#12` -> `HashJoin:12`: safe as an attribute value."""
+    return label.replace("#", ":")

@@ -1,46 +1,419 @@
-"""Tracing hook tests (reference: NVTX ranges behind the nvtx.enabled flag,
-SURVEY.md §5)."""
+"""Program spans and operator names (utils/tracing.py, docs/plan.md
+"Reading a profile"): one span helper that records whenever a profiler
+session runs, request numbers that join the submitting thread's spans to
+the worker's, operator scopes inside the capped program, and
+`PlanExecutor.device_op_owners`. All on the CPU with tiny plans — what the
+chip's trace prints is checked in chipbench/tests/test_program_spans.py on
+a trace recorded there."""
+import ast
+import glob
 import os
+import threading
+import time
 
+import jax
+import jax.numpy as jnp
 import numpy as np
+import pytest
 
 import spark_rapids_tpu  # noqa: F401
-from spark_rapids_tpu.utils import func_range, range_ctx, trace
+from spark_rapids_tpu import Column, Table, dtypes
+from spark_rapids_tpu.plan import PlanBuilder, PlanExecutor, col
+from spark_rapids_tpu.plan import stats as stats_mod
+from spark_rapids_tpu.plan.executor import _scope_owners
+from spark_rapids_tpu.runtime import sessionctx
+from spark_rapids_tpu.serving import ServingScheduler
+from spark_rapids_tpu.utils import span
+from spark_rapids_tpu.utils.tracing import text
+
+from benchmarks.nds_plans import q3_inputs, q3_plan
+
+PKG = os.path.dirname(os.path.abspath(spark_rapids_tpu.__file__))
 
 
-def test_disabled_is_passthrough(monkeypatch):
-    monkeypatch.delenv("SPARK_RAPIDS_TPU_TRACE", raising=False)
+# ---- reading a session back ---------------------------------------------------
 
-    @func_range
-    def f(x):
-        return x + 1
+class Spans(list):
+    """The program's spans of one profiler session: dicts with name,
+    thread, t0, t1 (ns on the profiler's clock) and the attributes."""
 
-    assert f(1) == 2
-    with range_ctx("block"):
-        pass
+    def named(self, name):
+        return [s for s in self if s["name"] == name]
 
-
-def test_enabled_annotates(monkeypatch):
-    monkeypatch.setenv("SPARK_RAPIDS_TPU_TRACE", "1")
-
-    @func_range
-    def f(x):
-        import jax.numpy as jnp
-        return jnp.sum(jnp.asarray(x))
-
-    assert int(f(np.arange(10))) == 45
-    with range_ctx("block"):
-        assert True
+    def one(self, name):
+        found = self.named(name)
+        assert len(found) == 1, (name, len(found))
+        return found[0]
 
 
-def test_device_trace_capture(tmp_path, monkeypatch):
-    import jax
-    import jax.numpy as jnp
-    d = str(tmp_path / "trace")
-    with trace(d):
-        jax.block_until_ready(jnp.arange(1000) * 2)
-    # a trace directory with at least one xplane artifact appears
+def inside(child, parent) -> bool:
+    return (child["thread"] == parent["thread"]
+            and parent["t0"] <= child["t0"] and child["t1"] <= parent["t1"])
+
+
+@pytest.fixture
+def session(tmp_path):
+    """-> record(fn): run `fn` under a profiler session started with the
+    benchmark's options and give back the spans it left."""
+    def record(fn) -> Spans:
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        opts.host_tracer_level = 2
+        opts.enable_hlo_proto = False
+        out = str(tmp_path / f"trace{len(os.listdir(tmp_path))}")
+        jax.profiler.start_trace(out, profiler_options=opts)
+        try:
+            fn()
+        finally:
+            jax.profiler.stop_trace()
+        (path,) = glob.glob(os.path.join(out, "plugins", "profile", "*",
+                                         "*.xplane.pb"))
+        spans = Spans()
+        data = jax.profiler.ProfileData.from_file(path)
+        for plane in data.planes:
+            if not plane.name.startswith("/host:"):
+                continue
+            # a thread is a line; lines have no id, their names repeat
+            for thread, line in enumerate(plane.lines):
+                for e in line.events:
+                    if e.name.startswith(("serving.", "plan.", "ops.",
+                                          "test.")):
+                        t0 = int(e.start_ns)
+                        spans.append(dict(
+                            dict(e.stats), name=e.name, thread=thread,
+                            t0=t0, t1=t0 + int(e.duration_ns)))
+        return spans
+    return record
+
+
+# ---- tiny plans -----------------------------------------------------------------
+
+def _col(a):
+    a = np.asarray(a, dtype=np.int64)
+    return Column(dtype=dtypes.INT64, length=len(a), data=jnp.asarray(a))
+
+
+def _fact(n=400, seed=0):
+    rng = np.random.default_rng(seed)
+    return Table([_col(rng.integers(0, 50, n)),
+                  _col(rng.integers(1, 100, n))], names=["k", "v"])
+
+
+def _dim():
+    return Table([_col(np.arange(50)), _col(np.arange(50) % 7)],
+                 names=["dk", "g"])
+
+
+def _join_plan():
+    b = PlanBuilder()
+    fact = b.scan("t", schema=["k", "v"]).filter(col("v") > 10)
+    dim = b.scan("d", schema=["dk", "g"])
+    return (fact.join(dim, left_on="k", right_on="dk")
+            .aggregate(["g"], [("v", "sum", "total")])
+            .sort(["g"]).build())
+
+
+def _tiny_q3(n=2000):
+    from benchmarks.bench_nds_q3 import build_tables
+    return q3_plan(), q3_inputs(*build_tables(n, seed=7))
+
+
+# ---- the helper -------------------------------------------------------------------
+
+def test_span_without_a_session_is_inert_and_cheap():
+    n = 20_000
+    t0 = time.perf_counter()
+    for i in range(n):
+        with span("test.inert", i=i) as sp:
+            pass
+    per_span_us = (time.perf_counter() - t0) / n * 1e6
+    sp.set_metadata(late=1)                 # no session: nothing to record
+    # 0.7-0.9 us here; the bound only has to catch a recorder sneaking in
+    assert per_span_us < 20, per_span_us
+    assert not hasattr(spark_rapids_tpu.utils.tracing, "enabled")
+
+
+def test_session_records_name_request_and_attributes(session):
+    def work():
+        with sessionctx.request_scope(7), span("test.outer", rows=3) as sp:
+            with span("test.inner", site="a.b"):
+                time.sleep(0.001)
+            sp.set_metadata(bytes=36_000_000, hit=0)
+        with span("test.unscoped"):
+            pass
+    spans = session(work)
+    outer, inner = spans.one("test.outer"), spans.one("test.inner")
+    assert (outer["request"], outer["rows"], outer["bytes"],
+            outer["hit"]) == (7, 3, 36_000_000, 0)
+    assert inner["request"] == 7 and inner["site"] == "a.b"
+    assert inside(inner, outer) and inner["t1"] - inner["t0"] >= 1_000_000
+    assert spans.one("test.unscoped")["request"] == -1
+
+
+def test_text_keeps_a_plan_label_from_cutting_the_attributes(session):
+    assert text("HashJoin#12") == "HashJoin:12"
+    def work():
+        with span("test.label", label=text("HashJoin#12"), tier="host"):
+            pass
+    got = session(work).one("test.label")
+    assert (got["label"], got["tier"]) == ("HashJoin:12", "host")
+
+
+def test_request_scope_nests_and_is_per_thread():
+    seen = []
+    assert sessionctx.current_request() == -1
+    with sessionctx.request_scope(3):
+        with sessionctx.request_scope(4):
+            assert sessionctx.current_request() == 4
+        t = threading.Thread(
+            target=lambda: seen.append(sessionctx.current_request()))
+        t.start()
+        t.join()
+        assert sessionctx.current_request() == 3
+    assert seen == [-1] and sessionctx.current_request() == -1
+
+
+# ---- one serving request -------------------------------------------------------------
+
+NESTING = [("serving.submit", ["serving.digest", "serving.admit",
+                               "serving.enqueue"]),
+           ("serving.dispatch", ["plan.execute"]),
+           ("plan.execute", ["plan.optimize", "plan.verify", "plan.certify",
+                             "plan.stats", "plan.run"]),
+           ("plan.run", ["plan.attempt"])]
+
+
+def test_serving_request_spans_nest_and_share_one_request(session):
+    plan, inputs = _join_plan(), {"t": _fact(), "d": _dim()}
+    sched = ServingScheduler(PlanExecutor(mode="capped"), workers=1,
+                             stats_store=stats_mod.StatsStore())
+    sess = sched.open_session("tenant")
+    try:
+        sess.submit(plan, inputs).result(timeout=120)     # compile outside
+        tickets = []
+        spans = session(lambda: tickets.append(
+            sess.submit(plan, {"t": _fact(seed=1), "d": inputs["d"]}))
+            or tickets[0].result(timeout=120))
+    finally:
+        sess.close()
+        sched.close()
+    request = tickets[0].request
+    assert request == 1                      # the second ticket of this scheduler
+    assert {s["request"] for s in spans} == {request}
+    for parent, children in NESTING:
+        p = spans.one(parent)
+        for child in children:
+            found = [c for c in spans.named(child) if inside(c, p)]
+            assert found, f"no {child} inside {parent}"
+    # the submitter's and the worker's spans lie on different threads
+    assert spans.one("serving.submit")["thread"] \
+        != spans.one("serving.dispatch")["thread"]
+    # admission certifies on the submitting thread, execute on the worker
+    certs = spans.named("plan.certify")
+    assert len(certs) == 2
+    assert sum(inside(c, spans.one("serving.admit")) for c in certs) == 1
+    digest = spans.one("serving.digest")
+    assert digest["hit"] == 0 and digest["bytes"] == 400 * 8 * 2
+    assert spans.one("serving.dispatch")["queue_wait_ms"] >= 0
+    attempt = spans.one("plan.attempt")
+    assert (attempt["attempt"], attempt["hit"]) == (1, 1)
+
+
+def test_repeat_submit_digests_nothing_and_hits(session):
+    plan, inputs = _join_plan(), {"t": _fact(), "d": _dim()}
+    sched = ServingScheduler(PlanExecutor(mode="eager"), workers=1)
+    sess = sched.open_session("tenant")
+    try:
+        sess.submit(plan, inputs).result(timeout=120)
+        spans = session(lambda: sess.submit(plan, inputs).result(timeout=60))
+    finally:
+        sess.close()
+        sched.close()
+    digest = spans.one("serving.digest")
+    assert (digest["hit"], digest["bytes"]) == (1, 0)
+    assert not spans.named("serving.admit") and not spans.named("plan.execute")
+
+
+def test_concurrent_requests_get_different_numbers(session):
+    plan, dim = _join_plan(), _dim()
+    sched = ServingScheduler(PlanExecutor(mode="capped"), workers=2)
+    sess = sched.open_session("tenant")
+    tickets = []
+
+    def submit(seed):
+        t = sess.submit(plan, {"t": _fact(seed=seed), "d": dim})
+        tickets.append(t)
+        t.result(timeout=120)
+
+    def both():
+        threads = [threading.Thread(target=submit, args=(s,)) for s in (1, 2)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join()
+    try:
+        submit(0)                                         # compile outside
+        del tickets[:]
+        spans = session(both)
+    finally:
+        sess.close()
+        sched.close()
+    numbers = {t.request for t in tickets}
+    assert len(numbers) == 2
+    for name in ("serving.submit", "serving.dispatch", "plan.execute",
+                 "plan.attempt"):
+        assert {s["request"] for s in spans.named(name)} == numbers, name
+
+
+def test_direct_execute_takes_the_executors_own_numbers(session):
+    plan, inputs = _join_plan(), {"t": _fact(), "d": _dim()}
+    ex = PlanExecutor(mode="capped")
+    ex.execute(plan, inputs)
+    spans = session(lambda: [ex.execute(plan, inputs) for _ in range(2)])
+    assert [s["request"] for s in spans.named("plan.execute")] == [1, 2]
+    assert {s["request"] for s in spans} == {1, 2}
+    # under a scope (a serving worker's) the scoped number wins
+    with sessionctx.request_scope(41):
+        scoped = session(lambda: ex.execute(plan, inputs))
+    assert {s["request"] for s in scoped} == {41}
+
+
+# ---- the tiers ------------------------------------------------------------------------
+
+def test_escalated_capped_run_shows_two_attempts_under_one_run(session):
+    plan, inputs = _join_plan(), {"t": _fact(), "d": _dim()}
+    ex = PlanExecutor(mode="capped", caps={"row_cap": 128, "key_cap": 4})
+    res = []
+    spans = session(lambda: res.append(ex.execute(plan, inputs)))
+    assert res[0].attempts >= 2
+    run = spans.one("plan.run")
+    attempts = spans.named("plan.attempt")
+    assert [a["attempt"] for a in attempts] \
+        == list(range(1, res[0].attempts + 1))
+    assert all(inside(a, run) for a in attempts)
+    assert all(a["hit"] == 0 for a in attempts)      # each cap set compiled
+
+
+def test_eager_tier_one_op_span_per_operator_and_the_joins_host_sync(session):
+    plan, inputs = _join_plan(), {"t": _fact(), "d": _dim()}
+    ex = PlanExecutor(mode="eager")
+    res = []
+    spans = session(lambda: res.append(ex.execute(plan, inputs)))
+    ops = spans.named("plan.op")
+    nodes = res[0].plan.nodes
+    assert [o["op"] for o in ops] == [f"{i}.{n.kind}"
+                                      for i, n in enumerate(nodes)]
+    assert [o["label"] for o in ops] == [text(n.label) for n in nodes]
+    assert {o["tier"] for o in ops} == {"device"}
+    assert all(inside(o, spans.one("plan.run")) for o in ops)
+    (join,) = [o for o in ops if o["op"].endswith(".HashJoin")]
+    syncs = spans.named("ops.host_sync")
+    assert syncs and all(inside(s, join) for s in syncs)
+    assert {s["site"] for s in syncs} <= {"join.inner", "join_pallas.inner"}
+    assert {s["request"] for s in syncs} == {join["request"]}
+
+
+def test_cpu_tier_op_spans_say_degraded(session):
+    plan, inputs = _join_plan(), {"t": _fact(), "d": _dim()}
+    ex = PlanExecutor(mode="eager")
+    spans = session(lambda: ex.execute(plan, inputs, tier="cpu"))
+    ops = spans.named("plan.op")
+    assert ops and {o["tier"] for o in ops} == {"degraded"}
+
+
+# ---- names inside the capped program ------------------------------------------------------
+
+@pytest.fixture(scope="module")
+def capped_q3():
+    plan, inputs = _tiny_q3()
+    ex = PlanExecutor(mode="capped")
+    res = ex.execute(plan, inputs)
+    return ex, plan, inputs, res
+
+
+def test_capped_program_names_every_operator_of_q3(capped_q3):
+    ex, plan, inputs, res = capped_q3
+    ((fn, _, _),) = [v for v in ex._jit_cache.values()]
+    hlo = fn.lower(dict(inputs)).compile().as_text()
+    assert hlo.startswith("HloModule jit_capped_plan")
+    for i, node in enumerate(res.plan.nodes):
+        scope = f"jit(capped_plan)/{i}.{node.kind}/"
+        if node.kind in ("Scan", "Project"):
+            continue     # binding a table and renaming columns compute
+            #              nothing: no instruction carries their scope
+        assert scope in hlo, scope
+
+
+def test_device_op_owners_gives_a_sort_and_a_fusion_to_a_join(capped_q3):
+    ex, plan, inputs, res = capped_q3
+    lowered = len(ex._jit_cache)
+    owners = ex.device_op_owners(plan, inputs)
+    assert len(ex._jit_cache) == lowered      # the program execute() ran
+    scopes = {f"{i}.{n.kind}" for i, n in enumerate(res.plan.nodes)}
+    assert owners and set(owners.values()) <= scopes
+    joins = {name for name, o in owners.items() if o.endswith(".HashJoin")}
+    assert any(n.startswith("sort") for n in joins), sorted(joins)[:20]
+    assert any("fusion" in n for n in joins), sorted(joins)[:20]
+    assert any(o.endswith(".HashAggregate") for o in owners.values())
+
+
+def test_device_op_owners_is_the_capped_tiers():
+    plan, inputs = _join_plan(), {"t": _fact(), "d": _dim()}
+    with pytest.raises(Exception, match="capped tier"):
+        PlanExecutor(mode="eager").device_op_owners(plan, inputs)
+
+
+@pytest.mark.parametrize("line, owner", [
+    ('  %fusion.32 = s64[8]{0} fusion(%p), kind=kLoop, calls=%fc, '
+     'metadata={op_name="jit(capped_plan)/3.HashJoin/jit(take)/gather" '
+     'stack_frame_id=4}', ("fusion.32", "3.HashJoin")),
+    ('  ROOT %sort.90 = (s64[8]{0}) sort(%a), dimensions={0}, '
+     'metadata={op_name="jit(capped_plan)/5.HashAggregate/jit(sort)/sort"}',
+     ("sort.90", "5.HashAggregate")),
+    ('  %pallas_hash_join_probe.1 = u32[4]{0} custom-call(%x), '
+     'custom_call_target="tpu_custom_call", metadata={op_name='
+     '"jit(capped_plan)/4.HashJoin/pallas_hash_join_probe/pallas_call"}',
+     ("pallas_hash_join_probe.1", "4.HashJoin")),
+    ('  %copy.3 = s64[8]{0} copy(%p), metadata={op_name='
+     '"jit(capped_plan)/jit(main)/copy"}', None),
+    ('  %param.1 = s64[8]{0} parameter(0)', None),
+])
+def test_scope_owners_reads_an_executables_text(line, owner):
+    assert _scope_owners("HloModule jit_capped_plan\n" + line + "\n") \
+        == (dict([owner]) if owner else {})
+
+
+PALLAS_SITES = {
+    ("ops/join_pallas.py", "pallas_hash_join_build"),
+    ("ops/join_pallas.py", "pallas_hash_join_probe"),
+    ("ops/select_pallas.py", "pallas_fused_select"),
+    ("ops/topk_pallas.py", "pallas_topk"),
+    ("ops/hash_pallas.py", "pallas_hash"),
+    ("parallel/partition_pallas.py", "pallas_partition_counts"),
+}
+
+
+def _pallas_call_names():
+    """(file, name=) of every `pallas_call(...)` in the package; the name
+    is None where the call passes none or not a literal."""
     found = []
-    for root, _, files in os.walk(d):
-        found.extend(files)
-    assert found, "no trace artifacts written"
+    for path in glob.glob(os.path.join(PKG, "**", "*.py"), recursive=True):
+        with open(path) as f:
+            tree = ast.parse(f.read())
+        for node in ast.walk(tree):
+            if isinstance(node, ast.Call) \
+                    and getattr(node.func, "attr", "") == "pallas_call":
+                name = next((k.value.value for k in node.keywords
+                             if k.arg == "name"
+                             and isinstance(k.value, ast.Constant)), None)
+                found.append((os.path.relpath(path, PKG), name))
+    return found
+
+
+@pytest.mark.parametrize("site", sorted(PALLAS_SITES))
+def test_pallas_call_site_has_its_stable_name(site):
+    assert site in _pallas_call_names()
+
+
+def test_no_pallas_call_is_unnamed():
+    assert set(_pallas_call_names()) == PALLAS_SITES
