@@ -12,14 +12,17 @@ compiled-program cache shares (structurally identical plans built
 independently hit together), crossed with a digest of the DATA the plan
 was bound to. A fingerprint alone must never serve: the same plan over
 new rows is a different answer, so the digest covers every input's
-content (Table bindings hash their buffers; parquet-path sources hash
-the path + size + mtime_ns identity — re-written files change identity;
-in-memory byte sources hash the bytes). Any input the digest cannot
-prove stable makes the plan UNCACHEABLE (sound-but-incomplete, the
-certifier's philosophy) rather than cached on a guess. Table digests
-memoize per object identity (weakref-guarded — Tables are immutable by
-contract), so repeat submissions over the same binding pay the
-device->host hash once, not per submit.
+content (Table bindings fold each buffer to 128 bits on the device that
+holds it and hash names, types, shapes and the folds on the host — no
+buffer crosses to the host; parquet-path sources hash the path + size +
+mtime_ns identity — re-written files change identity; in-memory byte
+sources hash the bytes). Any input the digest cannot prove stable makes
+the plan UNCACHEABLE (sound-but-incomplete, the certifier's philosophy)
+rather than cached on a guess. Table digests memoize per object
+identity (weakref-guarded — Tables are immutable by contract), so
+repeat submissions over the same binding fold once, not per submit. The
+fold is keyed per process: keys mean nothing outside it and must not
+leave it (docs/serving.md#what-the-key-is-made-of).
 
 Only DEVICE-tier results enter the cache (the scheduler guards put):
 a degraded result is a transient-condition artifact whose
@@ -41,47 +44,108 @@ import os
 import threading
 import time
 import weakref
-from typing import Dict, Optional, Tuple
+from typing import Dict, List, Optional, Tuple
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 
-
-_hashed = threading.local()     # .n: buffer bytes this thread has digested
-
-
-def bytes_hashed() -> int:
-    """Running total of buffer bytes the calling thread has copied to the
-    host and hashed; the `serving.digest` span reports the difference
-    across one submit (0 when every table's digest was memoized)."""
-    return getattr(_hashed, "n", 0)
+from ..ops.hash import _mm_fmix, f64_bits_u64
 
 
-def _hash_array(h, a) -> None:
-    if a is None:
-        h.update(b"\x00none")
-        return
-    arr = np.asarray(a)
-    h.update(str(arr.dtype).encode())
-    h.update(str(arr.shape).encode())
-    h.update(arr.tobytes())
-    _hashed.n = bytes_hashed() + arr.nbytes
+# ---- the fold: a buffer -> 128 bits, where the buffer lives --------------
+#
+# Four 32-bit lanes, each a wrap-around sum over the buffer's elements of
+# a keyed mix of (element index, element bits). The key is drawn once per
+# process: a collision cannot be prepared by anyone who cannot read this
+# process's memory, which is why cache keys must never leave it
+# (docs/serving.md#what-the-key-is-made-of).
+_SEEDS = np.frombuffer(os.urandom(16), dtype=np.uint32)
 
 
-def _hash_column(h, col) -> None:
+def _f64_bits(x):
+    # XLA:TPU lowers no f64 bitcast (ops/hash.py); every NaN folds as the
+    # canonical quiet one there, the CPU sees the bits themselves
+    return jnp.where(jnp.isnan(x), jnp.uint64(0x7FF8000000000000),
+                     f64_bits_u64(x))
+
+
+def _words(x) -> Tuple[jnp.ndarray, ...]:
+    """The elements of a buffer as flat uint32 word arrays, low word
+    first: every bit of every element is in exactly one of them."""
+    x = x.reshape(-1)
+    if x.dtype == jnp.float64:
+        x = jax.lax.platform_dependent(
+            x, cpu=lambda v: jax.lax.bitcast_convert_type(v, jnp.uint64),
+            default=_f64_bits)
+    elif x.dtype == jnp.float32:
+        x = jax.lax.bitcast_convert_type(x, jnp.uint32)
+    if x.dtype.itemsize == 8:
+        x = x.astype(jnp.uint64)
+        return x.astype(jnp.uint32), (x >> jnp.uint64(32)).astype(jnp.uint32)
+    return (x.astype(jnp.uint32),)
+
+
+def _mix(seed, words):
+    """One lane's term per element: the murmur3 finalizer chained over
+    the words, keyed at both ends. A word meets the key only through a
+    full finalizer round (murmur3's block round lets a top-bit difference
+    in one word cancel against the next whatever the seed), and passes at
+    least two rounds before the sum."""
+    h = seed
+    for w in words:
+        h = _mm_fmix(h ^ w)
+    return _mm_fmix(h ^ seed)
+
+
+def _fold(seeds, x):
+    """uint32[4] of one buffer. Position counts: the element's index
+    (both halves, so an index beyond 2**32 differs too) goes into the mix
+    before its value. All of it fuses into the reductions: nothing of the
+    buffer's size is allocated (tests/test_chip_compile.py)."""
+    words = _words(x)
+    idx = jax.lax.iota(jnp.uint64, words[0].shape[0])
+    at = (idx.astype(jnp.uint32), (idx >> jnp.uint64(32)).astype(jnp.uint32))
+    return jnp.stack([jnp.sum(_mix(seeds[k], at + words), dtype=jnp.uint32)
+                      for k in range(4)])
+
+
+@jax.jit
+def _fold_buffers(seeds, *buffers):
+    """(len(buffers), 4) uint32: one program per table signature, so a
+    digest waits for the device once."""
+    return jnp.stack([_fold(seeds, b) for b in buffers])
+
+
+_digested = threading.local()   # .n: buffer bytes this thread has folded
+
+
+def bytes_digested() -> int:
+    """Running total of Table buffer bytes the calling thread has folded;
+    the `serving.digest` span reports the difference across one submit (0
+    when every table's digest was memoized)."""
+    return getattr(_digested, "n", 0)
+
+
+def _describe_column(h, col, buffers: List) -> None:
+    """Everything of a column but its buffers' contents goes to the host
+    hash; the buffers line up in `buffers` for the fold."""
     h.update(repr(col.dtype).encode())
-    _hash_array(h, col.data)
-    _hash_array(h, col.validity)
-    _hash_array(h, col.offsets)
+    for a in (col.data, col.validity, col.offsets):
+        if a is None:
+            h.update(b"\x00none")
+            continue
+        h.update(f"{a.dtype}{tuple(a.shape)}".encode())
+        buffers.append(a)
     for c in col.children:
-        _hash_column(h, c)
+        _describe_column(h, c, buffers)
 
 
-# per-Table digest memo: hashing a Table's buffers costs a device->host
-# copy of every buffer plus blake2b over the bytes — on every submit.
-# Tables are immutable by contract, so the digest is a function of
-# object identity; memoize it keyed by id() with a weakref guard (id()
-# reuse after GC must not serve a dead table's digest) so repeat
-# submissions over the same binding hash once, not per submit.
+# per-Table digest memo: Tables are immutable by contract, so the digest
+# is a function of object identity; memoize it keyed by id() with a
+# weakref guard (id() reuse after GC must not serve a dead table's
+# digest) so repeat submissions over the same binding fold once, not per
+# submit.
 _table_digests: Dict[int, Tuple[object, str]] = {}
 _digest_lock = threading.Lock()
 
@@ -94,8 +158,13 @@ def _table_digest(t) -> str:
             return ent[1]
     h = hashlib.blake2b(digest_size=16)
     h.update(",".join(t.names).encode())
+    buffers: List = []
     for c in t.columns:
-        _hash_column(h, c)
+        _describe_column(h, c, buffers)
+    if buffers:
+        # the one read-back: 16 bytes a buffer
+        h.update(jax.device_get(_fold_buffers(_SEEDS, *buffers)).tobytes())
+        _digested.n = bytes_digested() + sum(a.nbytes for a in buffers)
     digest = h.hexdigest()
     try:
         ref = weakref.ref(t, lambda _r, k=key: _evict_digest(k))

@@ -572,12 +572,15 @@ class ServingScheduler:
             inputs = self._bind(plan, inputs)
             ticket = Ticket(state.id, request)
             with span("serving.digest") as sp:
-                hashed0 = cache_mod.bytes_hashed()
+                folded0 = cache_mod.bytes_digested()
                 key = cache_mod.cache_key(plan, inputs) \
                     if self.cache.entries > 0 else None
                 hit = self.cache.get(key)
-                sp.set_metadata(bytes=cache_mod.bytes_hashed() - hashed0,
-                                hit=int(hit is not None))
+                # host_bytes: buffer bytes copied to the host to be
+                # hashed. The fold runs where the buffer lives and 16
+                # bytes a buffer come back, so there are none
+                sp.set_metadata(bytes=cache_mod.bytes_digested() - folded0,
+                                host_bytes=0, hit=int(hit is not None))
             if hit is not None:
                 # a hit consumes nothing: no queue slot, no quota, no
                 # worker

@@ -1,9 +1,10 @@
 """Compile for the chip, without the chip.
 
 Every Pallas call that is registered for `tpu` (fused_select, hash_join,
-topk), plus `hash_pallas`, the partition histogram and the capped q3
-program, lowered and compiled for a DESCRIBED v5e:2x2 device by the TPU
-compiler installed here (`/opt/skills/guides/on-chip-measurement`, §2).
+topk), plus `hash_pallas`, the partition histogram, the input digest's
+fold and the capped q3 program, lowered and compiled for a DESCRIBED
+v5e:2x2 device by the TPU compiler installed here
+(`/opt/skills/guides/on-chip-measurement`, §2).
 Interpret-mode parity (tests/test_kernel_registry.py) cannot see what
 Mosaic refuses — block shapes, 64-bit values, boolean loop carries — and
 `interpret = jax.default_backend() != "tpu"` keeps every other test off
@@ -165,6 +166,31 @@ def test_row_hash_compiles_for_v5e(chip):
     t = Table([_i64(N_FACT), _i64(N_FACT)], names=["a", "b"])
     n = chip(lambda: hash_pallas.fused_row_hash(t, interpret=True))
     assert n == 1
+
+
+# the task cells' fresh tables (chipbench/configs): q3's store_sales batch,
+# q72's catalog_sales and inventory
+DIGEST_ROWS = [1_440_000, 720_000, 1_996_650]
+
+
+@pytest.mark.parametrize("n", DIGEST_ROWS)
+def test_digest_fold_allocates_nothing_of_a_buffers_size(one_chip,
+                                                         no_persistent_cache,
+                                                         n):
+    """The input digest's fold (serving/cache.py) at the cells' shapes: an
+    int64 column and its validity reduce inside one fusion each. A
+    materialized widened copy of a column would be 11.5 MB of the
+    0.86 GB whose 1% bounds `peak_hbm_gb`."""
+    from spark_rapids_tpu.serving import cache
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+    compiled = cache._fold_buffers.lower(
+        shape((4,), jnp.uint32), shape((n,), jnp.int64),
+        shape((n,), jnp.bool_)).compile()
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 1 << 20
+    assert mem.output_size_in_bytes < 1 << 12        # 2 x 16 bytes, tiled
 
 
 def test_capped_q3_program_compiles_for_v5e(one_chip, no_persistent_cache,

@@ -213,7 +213,10 @@ def test_serving_request_spans_nest_and_share_one_request(session):
     assert len(certs) == 2
     assert sum(inside(c, spans.one("serving.admit")) for c in certs) == 1
     digest = spans.one("serving.digest")
+    # the fresh fact table is folded where it lives: its bytes are
+    # counted as before, none of them crosses to the host
     assert digest["hit"] == 0 and digest["bytes"] == 400 * 8 * 2
+    assert digest["host_bytes"] == 0
     assert spans.one("serving.dispatch")["queue_wait_ms"] >= 0
     attempt = spans.one("plan.attempt")
     assert (attempt["attempt"], attempt["hit"]) == (1, 1)
@@ -230,7 +233,7 @@ def test_repeat_submit_digests_nothing_and_hits(session):
         sess.close()
         sched.close()
     digest = spans.one("serving.digest")
-    assert (digest["hit"], digest["bytes"]) == (1, 0)
+    assert (digest["hit"], digest["bytes"], digest["host_bytes"]) == (1, 0, 0)
     assert not spans.named("serving.admit") and not spans.named("plan.execute")
 
 
