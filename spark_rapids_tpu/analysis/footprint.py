@@ -359,14 +359,23 @@ def _agg_widths(node: HashAggregate, child_types) -> Optional[int]:
     """Output bytes/row of a HashAggregate: group keys keep their column
     widths; aggregate outputs certify at the 64-bit accumulator width
     (sums/counts/means accumulate in 64-bit regardless of the input
-    column's width — certifying the typed width would under-bound)."""
+    column's width — certifying the typed width would under-bound). A
+    decimal sum or mean is charged its planes: one 64-bit accumulator per
+    32 bits of the input (the kernels sum a decimal plane by plane) or
+    the 16 bytes of limbs it comes out as, whichever is more."""
     total = 0
     for k in node.keys:
         w = _col_width(child_types.get(k))
         if w is None:
             return None
         total += w
-    return total + len(node.aggs) * (_ACC_BYTES + _VALIDITY_BYTES)
+    for c, op, _ in node.aggs:
+        dt = child_types.get(c) if op in ("sum", "mean") else None
+        acc = _ACC_BYTES
+        if dt is not None and dt.is_decimal:
+            acc = max(16, _ACC_BYTES * (dt.itemsize() // 4))
+        total += acc + _VALIDITY_BYTES
+    return total
 
 
 def certify_nodes(nodes: List[PlanNode], *, bound=None, bound_rows=None,

@@ -209,14 +209,49 @@ _FLOAT64 = dtypes.FLOAT64
 
 
 def _expr_addressable(dt: Optional[dtypes.DType]) -> bool:
-    """Whether `Expr.evaluate` can read the column: it reads the raw
-    `data` buffer, so STRING (chars buffer), nested and DECIMAL128
-    ((n, 4) limbs) columns are out — their buffer length/shape is not the
-    row count."""
+    """Whether `Expr.evaluate` can read the column: it reads the `data`
+    buffer row by row, so STRING (chars buffer) and nested columns are
+    out — their buffer's length is not the row count. A DECIMAL128
+    column's (n, 4) limbs are read by the typed decimal path
+    (plan/expr.py `decimal_type`)."""
     if dt is None:
         return True
-    return not (dt.is_string or dt.is_nested
-                or dt.kind == dtypes.Kind.DECIMAL128)
+    return not (dt.is_string or dt.is_nested)
+
+
+def _decimal_typing(e: Expr, coltypes, node, report: "VerifyReport"):
+    """(known, type): Spark's decimal type of `e` by the rule the
+    evaluation uses (plan/expr.py). known False: a decimal reaches an
+    operator that is not lowered (flagged here)."""
+    from ..plan.expr import decimal_sides, decimal_type
+    try:
+        if isinstance(e, BinOp) and e.op in _CMP_OPS:
+            sides = decimal_sides(e, coltypes.get)
+            if sides is not None and sides[0].scale != sides[1].scale:
+                raise TypeError(
+                    f"{e!r} compares decimals of scales {sides[0].scale} "
+                    f"and {sides[1].scale}; decimals compare at equal "
+                    "scales (state the cast)")
+            if sides is not None and any(
+                    s.kind == dtypes.Kind.DECIMAL128 for s in sides):
+                raise TypeError(f"{e!r}: a comparison over decimal128 "
+                                "limbs is not lowered")
+            return True, None
+        return True, decimal_type(e, coltypes.get)
+    except TypeError as err:
+        report.add("typing.decimal-not-lowered", node,
+                   f"{node.label}: {err}")
+        return False, None
+
+
+def _same_storage(bound: dtypes.DType, declared: dtypes.DType) -> bool:
+    """A declared logical type fits a bound buffer of the same fixed-width
+    storage: same element dtype, and limbs only over limbs."""
+    if any(dt.is_string or dt.is_nested for dt in (bound, declared)):
+        return bound == declared
+    return bound.storage_dtype() == declared.storage_dtype() and (
+        (bound.kind == dtypes.Kind.DECIMAL128)
+        == (declared.kind == dtypes.Kind.DECIMAL128))
 
 
 def _lit_dtype(v) -> Optional[dtypes.DType]:
@@ -244,13 +279,16 @@ def type_expr(e: Expr, coltypes: Dict[str, Optional[dtypes.DType]],
         if not _expr_addressable(dt):
             report.add("typing.column-not-expr-addressable", node,
                        f"{node.label}: column {e.name!r} is {dt!r} — "
-                       "expressions read the raw data buffer, which for "
-                       "string/nested/decimal128 columns is not "
-                       "row-shaped")
+                       "expressions read the data buffer row by row, "
+                       "which a string or nested column's is not")
             return None
         return dt
     if isinstance(e, Literal):
         return _lit_dtype(e.value)
+    if isinstance(e, (BinOp, UnaryOp, ScalarAgg)):
+        known, dec = _decimal_typing(e, coltypes, node, report)
+        if not known or dec is not None:
+            return dec          # Spark's decimal type, or flagged
     if isinstance(e, BinOp):
         lt = type_expr(e.left, coltypes, node, report)
         rt = type_expr(e.right, coltypes, node, report)
@@ -305,6 +343,11 @@ def _agg_out_dtype(op: str, child_dt: Optional[dtypes.DType]
                    ) -> Optional[dtypes.DType]:
     if op in ("count", "size"):
         return _INT64
+    if child_dt is not None and child_dt.is_decimal \
+            and op in ("sum", "mean"):
+        # Spark's Sum / Average types (ops/aggregate.py)
+        from ..ops.aggregate import _agg_value_dtype
+        return _agg_value_dtype(op, child_dt)
     if op == "mean":
         return _FLOAT64
     if child_dt is None:
@@ -340,6 +383,17 @@ def _check_types(nodes, schemas, input_dtypes, report: VerifyReport
         kids = [types[id(c)] for c in node.children]
         if isinstance(node, Scan):
             src = dict(input_dtypes.get(node.source) or {})
+            for name, declared in node.types or ():
+                bound_dt = src.get(name)
+                if bound_dt is not None and not _same_storage(bound_dt,
+                                                              declared):
+                    report.add(
+                        "typing.scan-type-storage", node,
+                        f"{node.label}: column {name!r} is declared "
+                        f"{declared!r} over a bound {bound_dt!r} buffer — "
+                        "a logical type re-tags a buffer of its own "
+                        "storage (kind and width), it converts nothing")
+                src[name] = declared
             types[id(node)] = {n: src.get(n) for n in schemas[id(node)]}
             continue
         if isinstance(node, Filter):
@@ -375,12 +429,21 @@ def _check_types(nodes, schemas, input_dtypes, report: VerifyReport
                 # consumes validity only)
                 reads_data = o in ("sum", "mean") or (
                     not node.keys and o in ("min", "max"))
-                if reads_data and not _expr_addressable(cdt):
+                # the keyless path reduces the buffer as one array: no
+                # decimal there; the grouped kernels sum a decimal as
+                # planes and keep min/max to its 64-bit storage
+                decimal_out = cdt is not None and cdt.is_decimal and (
+                    (reads_data and not node.keys)
+                    or (o in ("min", "max")
+                        and cdt.kind == dtypes.Kind.DECIMAL128))
+                if (reads_data and not _expr_addressable(cdt)) \
+                        or decimal_out:
                     report.add(
                         "typing.agg-over-non-scalar", node,
-                        f"{node.label}: {o}({c}) reduces a {cdt!r} "
-                        "column's data buffer, which is not row-shaped "
-                        "for string/nested/decimal128 layouts")
+                        f"{node.label}: {o}({c}) over a {cdt!r} column "
+                        "is not lowered: a string or nested buffer is "
+                        "not row-shaped, and decimals aggregate in "
+                        "grouped sum/mean (min/max up to 18 digits)")
                 out[n] = _agg_out_dtype(o, cdt)
             types[id(node)] = out
             continue

@@ -916,6 +916,12 @@ class ParquetSource:
                    for dt in self._dtypes.values())
 
     @property
+    def column_dtypes(self) -> dict:
+        """Column name -> DType as the footer declares it (None: a type
+        the reader has no DType for)."""
+        return dict(self._dtypes)
+
+    @property
     def stats(self):
         """Per-row-group footer statistics, read once; None when the footer
         stats cannot be parsed (pruning then keeps every group)."""

@@ -18,6 +18,21 @@ Spark agg semantics implemented: sum/min/max ignore nulls (all-null group →
 null); count counts non-nulls; `size` is count(*); mean = double sum/count;
 integer sums widen to INT64 (Spark SUM(int) is LongType) and wrap on
 overflow like Java longs (non-ANSI).
+
+Decimals: `sum` and `mean` over a decimal(p, s) column have Spark's types
+(Sum: decimal(p + 10, s) bounded; Average: that sum over the count at the
+divide rule's type, cast HALF_UP to decimal(p + 4, s + 4)) and are null on
+overflow. The kernels never see a limb: a decimal is summed as its 32-bit
+planes (`decimal_utils.limb_planes`), each an ordinary int64 sum, and
+`decimal_utils.finish_sum` / `finish_mean` put a group's planes together
+in 256 bits. With decimal payloads the sort kernels sort the keys and a
+row iota alone and gather the planes afterwards.
+
+A third kernel, `direct`, sorts nothing: for a key cap of at most
+`DIRECT_KEY_CAP` groups and exact (integer, plane) aggregates it finds the
+distinct keys by repeated lexicographic minima and reduces each aggregate
+under a one-hot of the group's slot. The key cap the plan carries selects
+it (`groupby_signature`); the same contract as the other two.
 """
 from __future__ import annotations
 
@@ -36,9 +51,26 @@ from .sort import NULLS_LAST, _key_operands
 AGG_OPS = ("sum", "count", "min", "max", "mean", "size")
 
 
+# Most groups the sort-free kernel is chosen for. Its cost grows with the
+# slots: Q1's 6M-row batch with 26 plane and count payloads ran 167 M rows/s
+# at a key cap of 8 and 110 M at 64 on a v5e, 0.33 ms a slot or 1% of the
+# request each, while the sort kernels' program for the same batch was
+# still compiling after 11 minutes (PERF.md, PR 28). An int64 shape, whose
+# payloads ride the sort, has not been swept: the limit stays at the
+# smaller measured point until one is.
+DIRECT_KEY_CAP = 8
+# fixed-width kinds min/max read as plain integers
+_EXACT_KINDS = (Kind.DATE32, Kind.TIMESTAMP_US, Kind.TIMESTAMP_S,
+                Kind.TIMESTAMP_MS, Kind.DECIMAL32, Kind.DECIMAL64)
+
+
 def _agg_value_dtype(op: str, dt: dtypes.DType) -> dtypes.DType:
     if op in ("count", "size"):
         return dtypes.INT64
+    if dt.is_decimal and op in ("sum", "mean"):
+        from . import decimal_utils
+        return (decimal_utils.sum_type(dt) if op == "sum"
+                else decimal_utils.average_types(dt)[2])
     if op == "mean":
         return dtypes.FLOAT64
     if op == "sum":
@@ -50,11 +82,26 @@ def _agg_value_dtype(op: str, dt: dtypes.DType) -> dtypes.DType:
     return dt  # min/max keep the input type
 
 
+def _sort_with_payloads(key_operands, iota, payloads, n_ops: int,
+                        gather: bool):
+    """The main key sort -> (sorted key operands, order, payloads in that
+    order). The payloads ride the sort as operands, or, with `gather`
+    (decimal planes: a dozen operands would ride), the sort moves the keys
+    and the iota alone and the payloads are gathered by it afterwards."""
+    operands = [*key_operands, iota] + ([] if gather else list(payloads))
+    sorted_all = jax.lax.sort(operands, num_keys=n_ops, is_stable=True)
+    order = sorted_all[n_ops]
+    spay = ([jnp.take(p, order, axis=0) for p in payloads] if gather
+            else sorted_all[n_ops + 1:])
+    return sorted_all[:n_ops], order, spay
+
+
 @partial(jax.jit,
-         static_argnames=("n_ops", "agg_kinds", "has_valids", "has_alive"))
+         static_argnames=("n_ops", "agg_kinds", "has_valids", "has_alive",
+                          "gather_payloads"))
 def _groupby_kernel(key_operands, agg_datas, agg_valids, *, n_ops: int,
                     agg_kinds: Tuple[str, ...], has_valids: Tuple[bool, ...],
-                    has_alive: bool = False):
+                    has_alive: bool = False, gather_payloads: bool = False):
     """Scatter-free, gather-free sorted aggregation (round-4 redesign).
 
     On-chip primitive costs (round-2 TPU measurement, recorded in
@@ -118,11 +165,8 @@ def _groupby_kernel(key_operands, agg_datas, agg_valids, *, n_ops: int,
             payloads.append(valid.astype(jnp.int8))
         slots.append((d_slot, v_slot))
 
-    sorted_all = jax.lax.sort([*key_operands, iota, *payloads],
-                              num_keys=n_ops, is_stable=True)
-    sorted_ops = sorted_all[:n_ops]
-    order = sorted_all[n_ops]
-    spay = sorted_all[n_ops + 1:]
+    sorted_ops, order, spay = _sort_with_payloads(
+        key_operands, iota, payloads, n_ops, gather_payloads)
 
     neq = jnp.zeros((n,), bool)
     for o in sorted_ops:
@@ -275,11 +319,13 @@ def _groupby_kernel(key_operands, agg_datas, agg_valids, *, n_ops: int,
 
 
 @partial(jax.jit,
-         static_argnames=("n_ops", "agg_kinds", "has_valids", "has_alive"))
+         static_argnames=("n_ops", "agg_kinds", "has_valids", "has_alive",
+                          "gather_payloads"))
 def _groupby_kernel_scatter(key_operands, agg_datas, agg_valids, *,
                             n_ops: int, agg_kinds: Tuple[str, ...],
                             has_valids: Tuple[bool, ...],
-                            has_alive: bool = False):
+                            has_alive: bool = False,
+                            gather_payloads: bool = False):
     """Scatter/segment-op groupby kernel — the CPU-preferred design.
 
     Same contract as _groupby_kernel (the scan design): (num_groups,
@@ -314,11 +360,8 @@ def _groupby_kernel_scatter(key_operands, agg_datas, agg_valids, *,
             payloads.append(valid.astype(jnp.int8))
         slots.append((d_slot, v_slot))
 
-    sorted_all = jax.lax.sort([*key_operands, iota, *payloads],
-                              num_keys=n_ops, is_stable=True)
-    sorted_ops = sorted_all[:n_ops]
-    order = sorted_all[n_ops]
-    spay = sorted_all[n_ops + 1:]
+    sorted_ops, order, spay = _sort_with_payloads(
+        key_operands, iota, payloads, n_ops, gather_payloads)
 
     neq = jnp.zeros((n,), bool)
     for o in sorted_ops:
@@ -392,6 +435,101 @@ def _groupby_kernel_scatter(key_operands, agg_datas, agg_valids, *,
     return num_groups, starts, first_rows, outs
 
 
+@partial(jax.jit,
+         static_argnames=("n_ops", "agg_kinds", "has_valids", "has_alive",
+                          "cap"))
+def _groupby_kernel_direct(key_operands, agg_datas, agg_valids, *,
+                           n_ops: int, agg_kinds: Tuple[str, ...],
+                           has_valids: Tuple[bool, ...],
+                           has_alive: bool = False, cap: int = 1):
+    """Sort-free groupby for a handful of groups (`cap` <= DIRECT_KEY_CAP).
+
+    Same contract as the sort kernels: (num_groups, starts, first_rows,
+    outs), groups in key sort order — but every array holds `cap + 1`
+    slots, not n rows, and `starts` is unused (no sorted frame exists;
+    string extremes, its one reader, keep the sort kernels). Aggregates
+    are exact ones only: integer sum/min/max, count, size.
+
+    Slot g's key is the lexicographic minimum of the live keys above slot
+    g - 1's: one masked min per key operand, `cap + 1` times, so the slot
+    past the cap tells an overflow from a full house. A row's slot is then
+    a one-hot against `arange(cap + 1)`, and each aggregate one reduction
+    of the (n, cap + 1) product over the rows. A few dozen passes over the
+    key columns and one over each payload, against a sort of every row
+    plus a gather per payload."""
+    n = key_operands[0].shape[0]
+    slots = cap + 1
+    keys = key_operands[1:] if has_alive else key_operands
+    alive = (key_operands[0] == 0) if has_alive else jnp.ones((n,), bool)
+    iota = jnp.arange(n, dtype=jnp.int32)
+
+    slot = jnp.full((n,), slots, jnp.int32)       # no slot: matches none
+    found = []
+    above = alive                                 # keys above the last slot's
+    for g in range(slots):
+        cand = above
+        for o in keys:
+            top = jnp.iinfo(o.dtype).max
+            m = jnp.min(jnp.where(cand, o, top), initial=top)
+            cand = cand & (o == m)                # rows holding the minimum
+        found.append(jnp.any(cand))
+        slot = jnp.where(cand, jnp.int32(g), slot)
+        above = above & (slot == slots)           # cand took every equal key
+    num_groups = jnp.sum(jnp.stack(found).astype(jnp.int32))
+    hot = slot[:, None] == jnp.arange(slots, dtype=jnp.int32)[None, :]
+
+    def reduce(vals, ok, ident, fn):
+        mask = hot if ok is None else hot & ok[:, None]
+        return fn(jnp.where(mask, vals[:, None], ident), axis=0,
+                  initial=ident)      # an empty batch reduces to it
+
+    first_rows = reduce(iota, None, jnp.int32(n), jnp.min)
+    sizes = reduce(jnp.ones((n,), jnp.int64), None, jnp.int64(0), jnp.sum)
+    outs = []
+    for data, valid, op, hv in zip(agg_datas, agg_valids, agg_kinds,
+                                   has_valids):
+        ok = valid if hv else None
+        if op == "size":
+            outs.append((sizes, None))
+            continue
+        cnt = sizes if ok is None else reduce(
+            jnp.ones((n,), jnp.int64), ok, jnp.int64(0), jnp.sum)
+        if op == "count":
+            outs.append((cnt, None))
+        elif op == "sum":
+            outs.append((reduce(data.astype(jnp.int64), ok, jnp.int64(0),
+                                jnp.sum), cnt > 0))
+        else:       # min / max over integers
+            info = jnp.iinfo(data.dtype)
+            ident = jnp.asarray(info.max if op == "min" else info.min,
+                                data.dtype)
+            outs.append((reduce(data, ok, ident,
+                                jnp.min if op == "min" else jnp.max),
+                         cnt > 0))
+    return num_groups, jnp.zeros((slots,), jnp.int32), first_rows, outs
+
+
+def _direct_supports(sig) -> bool:
+    return 0 < sig.extra("key_cap", 0) <= DIRECT_KEY_CAP \
+        and bool(sig.extra("exact", False))
+
+
+def groupby_signature(table: Table, key_names, aggs,
+                      key_cap: Optional[int] = None):
+    """What the registry's `groupby` kernels may condition on: the key
+    columns, the key cap the plan carries (0: none, the eager tier) and
+    whether every aggregate is exact as a plain integer reduction."""
+    from .registry import Signature
+    exact = all(
+        op in ("size", "count")
+        or (op in ("sum", "mean") and table[c].dtype.is_decimal)
+        or (op in ("sum", "min", "max") and (
+            table[c].dtype.is_integer or table[c].dtype.kind in _EXACT_KINDS))
+        for c, op in aggs)
+    return Signature.of([table[k] for k in key_names],
+                        key_cap=int(key_cap or 0), exact=exact)
+
+
 def _use_scan_kernel() -> bool:
     """Backend dispatch for the groupby kernel (see _groupby_kernel vs
     _groupby_kernel_scatter — the scan design wins on TPU where scatters
@@ -433,19 +571,30 @@ def groupby_aggregate(table: Table,
         # groups, counted out of num_groups by the kernel (has_alive)
         operands = [jnp.where(_alive, jnp.int32(0), jnp.int32(1))] + operands
 
+    from . import decimal_utils
     n = table.num_rows
     agg_datas: List = []
     agg_valids: List = []
     agg_kinds: List[str] = []
+
+    def push(data, valid, kind: str) -> int:
+        agg_datas.append(data)
+        agg_valids.append(valid)
+        agg_kinds.append(kind)
+        return len(agg_kinds) - 1
+
+    slot_of: List[int] = []                 # agg -> its first kernel slot
+    decimal_parts = {}                      # agg -> (plane slots, count slot)
     string_extremes: List[Tuple] = []       # (agg idx, col, col_ref, op)
+    placeholder = jnp.zeros((n,), jnp.int8)
     for i, (col_ref, op) in enumerate(aggs):
         if op not in AGG_OPS:
             raise ValueError(f"unknown aggregation {op!r}")
         if op in ("size", "count"):
             # only validity (or nothing) is consumed; data is a placeholder
             c = keys[0] if op == "size" else table[col_ref]
-            agg_datas.append(jnp.zeros((n,), jnp.int8))
-            agg_valids.append(None if op == "size" else c.validity)
+            slot_of.append(push(placeholder,
+                                None if op == "size" else c.validity, op))
         elif op in ("min", "max") and table[col_ref].dtype.is_string:
             # strings: resolved by an extra value-ordered sort (below); the
             # kernel carries a placeholder so outputs stay index-aligned.
@@ -453,27 +602,38 @@ def groupby_aggregate(table: Table,
             # (locates max when one shared asc sort serves both extremes).
             first_for_col = col_ref not in [r for _, _, r, _ in string_extremes]
             string_extremes.append((i, table[col_ref], col_ref, op))
-            agg_datas.append(jnp.zeros((n,), jnp.int8))
-            agg_valids.append(table[col_ref].validity if first_for_col else None)
-            agg_kinds.append("count" if first_for_col else "size")
-            continue
+            slot_of.append(push(
+                placeholder,
+                table[col_ref].validity if first_for_col else None,
+                "count" if first_for_col else "size"))
+        elif op in ("sum", "mean") and table[col_ref].dtype.is_decimal:
+            # a decimal is summed as its 32-bit planes, each an int64 sum;
+            # finish_sum / finish_mean put a group's planes together
+            c = table[col_ref]
+            planes = [push(p, c.validity, "sum")
+                      for p in decimal_utils.limb_planes(c)]
+            decimal_parts[i] = (planes, push(placeholder, c.validity,
+                                             "count"))
+            slot_of.append(planes[0])
         else:
             c = table[col_ref]
             if not (c.dtype.is_integer or c.dtype.is_floating
-                    or c.dtype.kind in (Kind.DATE32, Kind.TIMESTAMP_US,
-                                        Kind.TIMESTAMP_S, Kind.TIMESTAMP_MS)):
+                    or c.dtype.kind in _EXACT_KINDS) or (
+                        c.dtype.is_decimal and op not in ("min", "max")):
                 raise TypeError(f"{op} over {c.dtype} values is not supported")
-            agg_datas.append(c.data)
-            agg_valids.append(c.validity)
-        agg_kinds.append(op)
+            slot_of.append(push(c.data, c.validity, op))
 
-    kernel = _groupby_kernel if _use_scan_kernel() else \
-        _groupby_kernel_scatter
-    num_groups, first_sorted, first_rows_full, outs = kernel(
+    from .registry import REGISTRY
+    choice = REGISTRY.select("groupby",
+                             groupby_signature(table, key_names, aggs, _cap))
+    # "direct" is chosen only for exact aggregates: no string extreme
+    extra = ({"cap": _cap} if choice.name == "direct"
+             else {"gather_payloads": bool(decimal_parts)})
+    num_groups, first_sorted, first_rows_full, outs = choice.fn(
         tuple(operands), tuple(agg_datas), tuple(agg_valids),
         n_ops=len(operands), agg_kinds=tuple(agg_kinds),
         has_valids=tuple(v is not None for v in agg_valids),
-        has_alive=_alive is not None)
+        has_alive=_alive is not None, **extra)
     if _cap is None:
         g = int(num_groups)  # the one host sync
     else:
@@ -519,7 +679,7 @@ def groupby_aggregate(table: Table,
                         _has_negative=False)
         at_last = None
         if wants == {"min", "max"}:
-            cnt = outs[info["cnt_idx"]][0][:g]       # per-group non-null count
+            cnt = outs[slot_of[info["cnt_idx"]]][0][:g]   # non-null count
             last_pos = starts + jnp.maximum(cnt, 1).astype(jnp.int32) - 1
             at_last = take(c, jnp.take(order2, last_pos, axis=0),
                            _has_negative=False)
@@ -529,12 +689,21 @@ def groupby_aggregate(table: Table,
             else:
                 string_results[agg_idx] = at_last
 
-    for i, ((data, valid), (col_ref, op)) in enumerate(zip(outs, aggs)):
+    for i, (col_ref, op) in enumerate(aggs):
+        data, valid = outs[slot_of[i]]
         cname = (col_ref if isinstance(col_ref, str)
                  else table.names[col_ref]) if op != "size" else "*"
+        names.append(f"{op}({cname})")
         if i in string_results:
             out_cols.append(string_results[i])
-            names.append(f"{op}({cname})")
+            continue
+        if i in decimal_parts:
+            planes, cnt_slot = decimal_parts[i]
+            finish = (decimal_utils.finish_sum if op == "sum"
+                      else decimal_utils.finish_mean)
+            out_cols.append(finish([outs[k][0][:g] for k in planes],
+                                   outs[cnt_slot][0][:g],
+                                   table[col_ref].dtype))
             continue
         src_dt = dtypes.INT64 if op == "size" else table[col_ref].dtype
         dt = _agg_value_dtype(op, src_dt)
@@ -544,7 +713,6 @@ def groupby_aggregate(table: Table,
         v = None if valid is None else valid[:g]
         out_cols.append(Column(dtype=dt, length=g,
                                data=d.astype(dt.storage_dtype()), validity=v))
-        names.append(f"{op}({cname})")
 
     if _cap is None:
         return Table(out_cols, names)
@@ -606,5 +774,9 @@ def groupby_aggregate_capped(table: Table,
 from .registry import REGISTRY as _REGISTRY  # noqa: E402
 
 _REGISTRY.register("groupby", "scan", fn=_groupby_kernel, fallback=True)
+# before "scatter": where the signature allows it (a key cap of at most
+# DIRECT_KEY_CAP, exact aggregates) it outranks both sort designs
+_REGISTRY.register("groupby", "direct", fn=_groupby_kernel_direct,
+                   backends=("cpu", "tpu"), supports=_direct_supports)
 _REGISTRY.register("groupby", "scatter", fn=_groupby_kernel_scatter,
                    backends=("cpu",))

@@ -181,10 +181,16 @@ class PlanBuilder:
     def scan(self, source: str,
              schema: Optional[Sequence[str]] = None,
              est_rows: Optional[int] = None,
-             parquet=None) -> Rel:
+             parquet=None, types: Optional[Dict[str, object]] = None) -> Rel:
         """`est_rows` is an optional cardinality hint threaded to the
         optimizer's build-side selection; bound tables' actual row counts
         take precedence at execute().
+
+        `types={column: DType}` declares logical types over the bound
+        physical buffers, e.g. `dtypes.decimal(15, 2)` over an int64
+        column of unscaled values (`Scan.types`): the column is re-tagged
+        at the scan, not copied, and expressions over it take Spark's
+        decimal types (docs/plan.md "Typed expressions").
 
         `parquet=` binds the scan to a STREAMING source instead of a
         materialized Table: a path, whole-file bytes, or an
@@ -196,7 +202,7 @@ class PlanBuilder:
         if parquet is None:
             return Rel(Scan(source,
                             None if schema is None else tuple(schema),
-                            est_rows=est_rows))
+                            est_rows=est_rows, types=types))
         from ..io.parquet import ParquetSource
         src = (parquet if isinstance(parquet, ParquetSource)
                else ParquetSource(parquet))
@@ -207,7 +213,7 @@ class PlanBuilder:
         return Rel(Scan(source, tuple(src.names),
                         est_rows=src.num_rows if est_rows is None
                         else est_rows,
-                        parquet=src))
+                        parquet=src, types=types))
 
     @staticmethod
     def union(rels: Sequence[Rel]) -> Rel:

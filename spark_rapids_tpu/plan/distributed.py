@@ -660,7 +660,10 @@ class DistContext:
             t = self.ex._materialize_scan(node, t, m)
         elif node.projection is not None:
             t = t.select(list(node.projection))
-        if t.num_rows == 0 or not table_shardable(t):
+        if t.num_rows == 0 or not table_shardable(t) or node.types:
+            # a scan that declares logical types (decimals over int64
+            # buffers) stays local, like a decimal128 column: the typed
+            # decimal path is not lowered over shards
             return None
         return self.lift(t)
 
@@ -675,10 +678,14 @@ class DistContext:
 
     def _dist_project(self, node, childs):
         from .executor import _col_from_array
-        from .expr import ColumnRef
+        from .expr import ColumnRef, decimal_type
         (c,) = childs
         if not isinstance(c, ShardedRel) or c.replicated:
             return None
+        if any(decimal_type(e, lambda n: c.table[n].dtype) is not None
+               for _, e in node.exprs if not isinstance(e, ColumnRef)):
+            return None     # typed decimal results are not lowered over
+            #                 shards: the gather boundary, as decimal128
         valid = c.valid
         if isinstance(node, FusedSelect):
             mask = node.predicate.evaluate(c.table, valid)

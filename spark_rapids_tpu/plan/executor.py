@@ -130,6 +130,9 @@ def _op_span(node: PlanNode, idx: int, tier: str = "device"):
                 label=_span_text(node.label), tier=tier)
 
 
+_DECIMAL_OVERFLOW = -1      # key of `_run_capped`'s counts, see there
+
+
 def _scope_name(idx: int, node: PlanNode) -> str:
     """`<toposort index>.<kind>`: an operator's name in the program's
     spans and in a capped program's scopes. The index, not the label:
@@ -143,17 +146,22 @@ _HLO_INSTRUCTION = re.compile(
 _SCOPE = re.compile(r"^\d+\.[A-Za-z]+$")
 
 
-def _scope_owners(hlo_text: str) -> Dict[str, str]:
+def _scope_owners(hlo_text: str, nested: bool = False) -> Dict[str, str]:
     """{instruction name: its outermost `<idx>.<kind>` scope} over every
-    computation of an executable's text (`_scope_name` wrote the scopes)."""
+    computation of an executable's text (`_scope_name` wrote the scopes).
+    `nested`: followed by `/decimal.<op>`, the innermost scope a decimal
+    kernel (ops/decimal_utils.py) opened below the operator's."""
     owners: Dict[str, str] = {}
     for line in hlo_text.splitlines():
         m = _HLO_INSTRUCTION.match(line)
         if m:
-            scope = next((p for p in m.group(2).split("/")
-                          if _SCOPE.match(p)), None)
-            if scope:
-                owners[m.group(1)] = scope
+            parts = m.group(2).split("/")
+            at = next((i for i, p in enumerate(parts)
+                       if _SCOPE.match(p)), None)
+            if at is not None:
+                inner = [p for p in parts[at + 1:]
+                         if p.startswith("decimal.")] if nested else []
+                owners[m.group(1)] = "/".join(parts[at:at + 1] + inner[-1:])
     return owners
 
 
@@ -548,6 +556,9 @@ class PlanResult:
         #                               COMPUTED the entry, which is how
         #                               the soak proves cross-worker
         #                               cache locality
+        self.decimal_overflow_rows = 0   # rows or groups a decimal kernel
+        #                               nulled by overflow (Spark's
+        #                               non-ANSI rule; docs/plan.md)
         self.cached = False           # served from the serving result cache
         #                               (serving/cache.py): True ONLY on a
         #                               cache-hit COPY — its metrics are
@@ -709,8 +720,14 @@ class PlanExecutor:
         request = ctx.current_request()
         if request < 0:
             request = next(self._requests)
-        with ctx.request_scope(request), span("plan.execute"):
-            return self._execute_request(plan, inputs, tier, placement)
+        from ..ops.decimal_utils import overflow_counts
+        with ctx.request_scope(request), span("plan.execute") as sp, \
+                overflow_counts() as nulled:
+            res = self._execute_request(plan, inputs, tier, placement)
+            if nulled:      # eager tiers: one read-back, decimal plans only
+                res.decimal_overflow_rows += int(sum(nulled))
+            sp.set_metadata(decimal_overflow_rows=res.decimal_overflow_rows)
+            return res
 
     def _execute_request(self, plan, inputs, tier,
                          placement) -> PlanResult:
@@ -1050,8 +1067,8 @@ class PlanExecutor:
         return explain_optimized(plan) + "\n" + self._kernel_summary()
 
     def device_op_owners(self, plan: Plan,
-                         inputs: Optional[Dict[str, Table]] = None
-                         ) -> Dict[str, str]:
+                         inputs: Optional[Dict[str, Table]] = None,
+                         nested: bool = False) -> Dict[str, str]:
         """Who owns `fusion.32`: {HLO instruction name: "<idx>.<kind>"}
         for the capped program this executor runs for `plan` over
         `inputs` at the capacities its next execution would start from
@@ -1062,7 +1079,9 @@ class PlanExecutor:
         lists top-down from the leaves), and the compiled executable's
         text keeps that scope in each instruction's `op_name`, a fusion
         under its own. Instructions outside any operator (parameters,
-        copies the compiler added) are left out. On request only: this
+        copies the compiler added) are left out. `nested=True` appends
+        `/decimal.<op>` where a decimal kernel's scope (mul, add, sub,
+        rescale, div, sum) lies below the operator's. On request only: this
         lowers the program again and reads the executable back through
         the compile cache; `execute` never calls it."""
         if self.mode != "capped":
@@ -1079,7 +1098,8 @@ class PlanExecutor:
         caps, _ = self._starting_caps(plan, inputs, source_fp,
                                       self._certify(plan, inputs, bound))
         fn = self._jitted_capped(plan, schemas, caps, _input_key(inputs))[0]
-        return _scope_owners(fn.lower(dict(inputs)).compile().as_text())
+        return _scope_owners(fn.lower(dict(inputs)).compile().as_text(),
+                             nested)
 
     @staticmethod
     def _transport_summary() -> str:
@@ -1573,10 +1593,11 @@ class PlanExecutor:
         FusedSelect (no scalar aggregates — those reduce over the whole
         relation); it may terminate INTO a HashAggregate whose ops
         decompose exactly (sum/count/min/max/size over non-float inputs —
-        fp partial sums are not reorder-exact). Everything else is the
+        fp partial sums are not reorder-exact — and over no decimal
+        column: its sum has another type than its input). Everything else is the
         concat boundary: the tail materializes one Table and the rest of
         the plan proceeds normally."""
-        from .expr import has_scalar_agg
+        from .expr import decimal_type, has_scalar_agg
         parents: Dict[int, List[PlanNode]] = {}
         for n in plan.nodes:
             for c in n.children:
@@ -1587,6 +1608,10 @@ class PlanExecutor:
             if src is None or isinstance(src, Table) or \
                     not getattr(src, "is_streaming_source", False):
                 continue
+            # name -> DType down the chain (None: not known), for the
+            # terminal aggregate's one question: does it read a decimal
+            types = dict(getattr(src, "column_dtypes", None) or {})
+            types.update(scan.types or ())
             chain = [scan]
             node: PlanNode = scan
             while True:
@@ -1599,21 +1624,30 @@ class PlanExecutor:
                     chain.append(p)
                     node = p
                     continue
-                if isinstance(p, Project) and not any(
-                        has_scalar_agg(e) for _, e in p.exprs):
-                    chain.append(p)
-                    node = p
-                    continue
-                if isinstance(p, FusedSelect) and \
-                        not has_scalar_agg(p.predicate) and not any(
+                if isinstance(p, (Project, FusedSelect)) and not (
+                        isinstance(p, FusedSelect)
+                        and has_scalar_agg(p.predicate)) and not any(
                             has_scalar_agg(e) for _, e in p.exprs):
+                    try:
+                        types = {n: (types.get(e.name)
+                                     if isinstance(e, ColumnRef)
+                                     else decimal_type(e, types.get))
+                                 for n, e in p.exprs}
+                    except TypeError:
+                        break   # not lowered: the whole-table path says so
                     chain.append(p)
                     node = p
                     continue
+                # a decimal aggregate widens its type (Sum: p + 10), so a
+                # partial's merge is not the aggregate again: it runs whole
+                # over the chain's concatenated tail
                 if (isinstance(p, HashAggregate)
                         and all(o in _STREAM_AGG_MERGE
                                 for _, o, _ in p.aggs)
-                        and not _input_has_floats(src)):
+                        and not _input_has_floats(src)
+                        and not any(
+                            o != "size" and types.get(c) is not None
+                            and types[c].is_decimal for c, o, _ in p.aggs)):
                     chain.append(p)     # terminal: partial accumulation
                 break
             if len(chain) > 1:
@@ -1707,7 +1741,7 @@ class PlanExecutor:
                 t0p = time.perf_counter()
                 sm.rows_out += chunk.num_rows
                 sm.bytes_out += operand_nbytes(chunk)
-                t = chunk
+                t = scan.typed(chunk)
                 for node in body:
                     t = self._stream_op(node, node_index[id(node)], t,
                                         inputs, schemas, ms[node.label])
@@ -1813,7 +1847,7 @@ class PlanExecutor:
             m.io_row_groups_pruned = pruned
             m.io_bytes_skipped = skipped
             m.io_decode_ms += (time.perf_counter() - t0) * 1e3
-        return t
+        return node.typed(t)
 
     @staticmethod
     def _kernel_choice(op: str, sig, m: Optional[OperatorMetrics] = None,
@@ -1854,7 +1888,7 @@ class PlanExecutor:
             if node.projection is not None:
                 # pruned scan: unused columns never enter the plan
                 t = t.select(list(node.projection))
-            return t
+            return node.typed(t)
         if isinstance(node, Filter):
             (t,) = childs
             mask = node.predicate.evaluate(t)
@@ -1951,10 +1985,14 @@ class PlanExecutor:
 
     def _project(self, t: Table, node: Project,
                  alive: Optional[jnp.ndarray] = None) -> Table:
+        from .expr import decimal_type
         cols = []
         for name, e in node.exprs:
             if isinstance(e, ColumnRef):
                 cols.append(t[e.name])      # preserve dtype + validity
+            elif decimal_type(e, lambda n: t[n].dtype) is not None:
+                # Spark's result type, validity, overflow rows null
+                cols.append(e.column(t, alive))
             else:
                 v = e.evaluate(t, alive)
                 if getattr(v, "ndim", 1) == 0:
@@ -2258,12 +2296,14 @@ class PlanExecutor:
                 mm.io_row_groups_pruned = io.io_row_groups_pruned
                 mm.io_bytes_skipped = io.io_bytes_skipped
                 mm.io_decode_ms = io.io_decode_ms
-        return PlanResult(plan, table, valid, metrics, "capped", wall,
-                          attempts=attempts, caps=final_caps,
-                          retries=retries,
-                          breaker=self._breaker_snapshot(),
-                          backoff_ms=backoff_total,
-                          jit_cache_hits=cache_hits)
+        res = PlanResult(plan, table, valid, metrics, "capped", wall,
+                         attempts=attempts, caps=final_caps,
+                         retries=retries,
+                         breaker=self._breaker_snapshot(),
+                         backoff_ms=backoff_total,
+                         jit_cache_hits=cache_hits)
+        res.decimal_overflow_rows = counts_np[_DECIMAL_OVERFLOW][0]
+        return res
 
     def _jitted_capped(self, plan, schemas, caps, input_key):
         # the canonical FINGERPRINT is the key: structurally equivalent
@@ -2308,23 +2348,29 @@ class PlanExecutor:
         rels: Dict[int, _CappedRel] = {}
         # counts/bytes key on the toposort index: stable across
         # fingerprint-equal plans, whose labels differ (see _jitted_capped)
+        from ..ops.decimal_utils import overflow_counts
         counts: Dict[int, Tuple] = {}
         overflow = jnp.asarray(False)
-        for i, node in enumerate(plan.nodes):
-            childs = [rels[id(c)] for c in node.children]
-            # the operator's name inside the program, which
-            # device_op_owners reads back
-            with jax.named_scope(_scope_name(i, node)):
-                rel, ovf = self._exec_capped_node(node, i, childs, tables,
-                                                  schemas, caps, kernel_map)
-                if ovf is not None:
-                    overflow = overflow | ovf
-                bytes_map[i] = operand_nbytes(rel.table)
-                rows_in = sum((jnp.sum(c.alive.astype(jnp.int64))
-                               for c in childs), start=jnp.int64(0))
-                counts[i] = (rows_in,
-                             jnp.sum(rel.alive.astype(jnp.int64)))
-            rels[id(node)] = rel
+        with overflow_counts() as nulled:
+            for i, node in enumerate(plan.nodes):
+                childs = [rels[id(c)] for c in node.children]
+                # the operator's name inside the program, which
+                # device_op_owners reads back
+                with jax.named_scope(_scope_name(i, node)):
+                    rel, ovf = self._exec_capped_node(
+                        node, i, childs, tables, schemas, caps, kernel_map)
+                    if ovf is not None:
+                        overflow = overflow | ovf
+                    bytes_map[i] = operand_nbytes(rel.table)
+                    rows_in = sum((jnp.sum(c.alive.astype(jnp.int64))
+                                   for c in childs), start=jnp.int64(0))
+                    counts[i] = (rows_in,
+                                 jnp.sum(rel.alive.astype(jnp.int64)))
+                rels[id(node)] = rel
+        # beside the row counts, under an index no operator has: the rows
+        # and groups the program's decimal kernels nulled by overflow
+        counts[_DECIMAL_OVERFLOW] = (sum(nulled, start=jnp.int64(0)),
+                                     jnp.int64(0))
         root = rels[id(plan.root)]
         return root.table, root.alive, counts, overflow
 
@@ -2344,6 +2390,7 @@ class PlanExecutor:
             t = tables[node.source]
             if node.projection is not None:
                 t = t.select(list(node.projection))
+            t = node.typed(t)
             return _CappedRel(t, jnp.ones((t.num_rows,), bool)), None
         if isinstance(node, Filter):
             (c,) = childs
@@ -2405,11 +2452,16 @@ class PlanExecutor:
             if not node.keys:
                 t = self._global_aggregate(c.table, node, alive=c.alive)
                 return _CappedRel(t, jnp.ones((1,), bool)), None
-            pick("groupby", None)   # dispatch inside groupby_aggregate_capped
             key_cap = self._node_cap(caps, "key_cap", idx)
+            aggs = [(cn, o) for cn, o, _ in node.aggs]
+            # dispatch happens inside groupby_aggregate_capped, on this
+            # signature: the key cap picks the sort-free kernel or a sort
+            from ..ops.aggregate import groupby_signature
+            pick("groupby", groupby_signature(c.table, node.keys, aggs,
+                                              key_cap))
             agg, valid, ovf = ops.groupby_aggregate_capped(
-                c.table, list(node.keys), [(cn, o) for cn, o, _ in node.aggs],
-                key_cap=key_cap, alive=c.alive)
+                c.table, list(node.keys), aggs, key_cap=key_cap,
+                alive=c.alive)
             t = Table(list(agg.columns), names=schemas[id(node)])
             return _CappedRel(t, valid), ovf
         if isinstance(node, Sort):

@@ -15,6 +15,15 @@ Null semantics: expressions read the data buffer only; rows whose inputs
 are null must be dropped by validity-aware operators (the NDS tier is
 null-free). This matches the capped kernels, which also carry validity
 out-of-band.
+
+Typed expressions: where a decimal column reaches a `+ - *` or a
+comparison, the expression has Spark's result type (`decimal_type`; the
+rules live in `ops/decimal_utils.py`) and `Expr.column` evaluates it to a
+typed Column through that file's kernels: an integer literal beside a
+decimal is `decimal(digits, 0)`, an integral column `decimal(p, 0)`, an
+overflow nulls the row. `evaluate` of such an expression is that column's
+data. Expressions no decimal reaches keep the untyped x64 semantics
+above. docs/plan.md "Typed expressions" has the table.
 """
 from __future__ import annotations
 
@@ -34,6 +43,12 @@ class Expr:
         """Array of the expression over `table` ((n,) jnp array; scalar
         aggregates reduce over `alive` rows when a mask is given)."""
         raise NotImplementedError
+
+    def column(self, table, alive: Optional[jnp.ndarray] = None):
+        """The typed Column of a decimal-valued expression (`decimal_type`
+        is not None): Spark's result type, the inputs' validity, overflow
+        rows null."""
+        raise TypeError(f"{self!r} is not a decimal-valued expression")
 
     # ---- operator sugar ---------------------------------------------------
     def _bin(self, op: str, other) -> "BinOp":
@@ -104,6 +119,9 @@ class ColumnRef(Expr):
     def evaluate(self, table, alive=None):
         return table[self.name].data
 
+    def column(self, table, alive=None):
+        return table[self.name]
+
     def __repr__(self):
         return self.name
 
@@ -143,8 +161,26 @@ class BinOp(Expr):
         return self.left.references() | self.right.references()
 
     def evaluate(self, table, alive=None):
+        if decimal_sides(self, _types_of(table)) is not None:
+            return self.column(table, alive).data
         return _BIN_FNS[self.op](self.left.evaluate(table, alive),
                                  self.right.evaluate(table, alive))
+
+    def column(self, table, alive=None):
+        from .. import dtypes
+        from ..columnar import Column
+        from ..ops import decimal_utils
+        if decimal_sides(self, _types_of(table)) is None:
+            return super().column(table, alive)
+        n = table.num_rows
+        l, r = (decimal_utils.literal_column(e.value, n)
+                if isinstance(e, Literal) else e.column(table, alive)
+                for e in (self.left, self.right))
+        if self.op in _CMP_OPS:
+            x, y = decimal_utils.compare_operands(l, r)
+            return Column(dtype=dtypes.BOOL, length=n,
+                          data=_BIN_FNS[self.op](x, y))
+        return decimal_utils.arithmetic(self.op, l, r, alive)
 
     def __repr__(self):
         return f"({self.left!r} {self.op} {self.right!r})"
@@ -159,8 +195,21 @@ class UnaryOp(Expr):
         return self.child.references()
 
     def evaluate(self, table, alive=None):
+        if decimal_type(self, _types_of(table)) is not None:
+            return self.column(table, alive).data
         v = self.child.evaluate(table, alive)
         return ~v if self.op == "~" else -v
+
+    def column(self, table, alive=None):
+        from ..columnar import Column
+        from ..ops import decimal256 as d256
+        if decimal_type(self, _types_of(table)) is None:
+            return super().column(table, alive)
+        c = self.child.column(table, alive)
+        data = (-c.data if c.data.ndim == 1 else d256.to_i128_limbs(
+            d256.negate(d256.from_i128_limbs(c.data))))
+        return Column(dtype=c.dtype, length=c.length, data=data,
+                      validity=c.validity)
 
     def __repr__(self):
         return f"{self.op}{self.child!r}"
@@ -196,6 +245,66 @@ def _reduce_identity(op: str, dtype):
         return -inf if op == "max" else inf
     info = jnp.iinfo(dtype)
     return jnp.asarray(info.min if op == "max" else info.max, dtype)
+
+
+# ---- Spark's types of decimal expressions -------------------------------------
+
+_CMP_OPS = ("==", "!=", "<", "<=", ">", ">=")
+
+
+def _types_of(table):
+    """name -> DType over a bound table's columns."""
+    return lambda name: table[name].dtype
+
+
+def decimal_type(e: Expr, col_type):
+    """The Spark decimal type of `e` where it is decimal-valued, else
+    None: untyped x64 semantics apply, as they do to a comparison's
+    boolean. `col_type(name)` is a column's DType (None: unknown). Raises
+    TypeError for what is not lowered: a decimal under `& | ~` or a
+    scalar aggregate, a float or a computed integer beside a decimal."""
+    if isinstance(e, ColumnRef):
+        dt = col_type(e.name)
+        return dt if dt is not None and dt.is_decimal else None
+    if isinstance(e, BinOp):
+        sides = decimal_sides(e, col_type)
+        if sides is None or e.op in _CMP_OPS:
+            return None
+        from ..ops.decimal_utils import arithmetic_type
+        return arithmetic_type(e.op, *sides)
+    if isinstance(e, (UnaryOp, ScalarAgg)):
+        ct = decimal_type(e.child, col_type)
+        if ct is not None and not (isinstance(e, UnaryOp) and e.op == "-"):
+            raise TypeError(f"{e!r}: {e.op!r} over a decimal is not lowered")
+        return ct
+    return None
+
+
+def decimal_sides(e: BinOp, col_type):
+    """(left type, right type) as decimals where a decimal reaches either
+    side of `e`, else None."""
+    from ..ops.decimal_utils import as_decimal_type, literal_type
+    lt = decimal_type(e.left, col_type)
+    rt = decimal_type(e.right, col_type)
+    if lt is None and rt is None:
+        return None
+    if e.op not in _CMP_OPS and e.op not in ("+", "-", "*"):
+        raise TypeError(f"{e!r}: {e.op!r} over a decimal is not lowered")
+
+    def beside(side: Expr):
+        if isinstance(side, Literal) and isinstance(side.value, int) \
+                and not isinstance(side.value, bool):
+            return literal_type(side.value)
+        dt = (as_decimal_type(col_type(side.name))
+              if isinstance(side, ColumnRef) else None)
+        if dt is None:
+            raise TypeError(
+                f"{e!r}: {side!r} beside a decimal must be a decimal, an "
+                "integer literal or an integral column (Spark would cast "
+                "it; a plan states that cast)")
+        return dt
+    return (lt if lt is not None else beside(e.left),
+            rt if rt is not None else beside(e.right))
 
 
 # ---- structural helpers (the optimizer's expression toolkit) ----------------

@@ -77,13 +77,19 @@ class Scan(PlanNode):
     it matches nothing are skipped, while the authoring Filter stays
     above for exact semantics — it never changes the result, only the
     bytes decoded. `est_rows` is an optional cardinality hint for the
-    optimizer's build-side selection when no table is bound yet."""
+    optimizer's build-side selection when no table is bound yet.
+    `types` declares columns' LOGICAL types over the bound physical
+    buffers ((name, DType) pairs): `decimal(15,2)` over an int64 buffer is
+    DECIMAL64's own layout, what a parquet reader does with an INT64 page
+    annotated DECIMAL. `typed()` re-tags the bound columns, no copy; the
+    verifier checks that each buffer has the declared type's storage."""
     source: str
     schema: Optional[Tuple[str, ...]] = None
     projection: Optional[Tuple[str, ...]] = None
     est_rows: Optional[int] = None
     predicate: Optional[Expr] = None
     parquet: Optional[object] = None    # io.ParquetSource (not fingerprinted)
+    types: Optional[Tuple[Tuple[str, object], ...]] = None
 
     def __post_init__(self):
         super().__post_init__()
@@ -91,6 +97,22 @@ class Scan(PlanNode):
             object.__setattr__(self, "schema", tuple(self.schema))
         if self.projection is not None:
             object.__setattr__(self, "projection", tuple(self.projection))
+        if self.types is not None:
+            pairs = (self.types.items() if isinstance(self.types, dict)
+                     else self.types)
+            object.__setattr__(self, "types",
+                               tuple(sorted((str(n), dt) for n, dt in pairs)))
+
+    def typed(self, table):
+        """`table` with the declared logical types over its buffers."""
+        if not self.types:
+            return table
+        from ..columnar import Column, Table
+        declared = dict(self.types)
+        cols = [c if declared.get(n, c.dtype) == c.dtype else Column(
+            dtype=declared[n], length=c.length, data=c.data,
+            validity=c.validity) for n, c in zip(table.names, table.columns)]
+        return Table(cols, names=list(table.names))
 
     def output_names(self, child_schemas):
         _require(self.schema is not None,
@@ -121,6 +143,9 @@ class Scan(PlanNode):
             out += " (parquet)"
         if self.projection is not None:
             out += f" [{', '.join(self.projection)}]"
+        if self.types:
+            out += " types[" + ", ".join(f"{n}: {dt!r}"
+                                         for n, dt in self.types) + "]"
         if self.predicate is not None:
             out += f" prune[{self.predicate!r}]"
         return out

@@ -119,7 +119,26 @@ def test_registered_tpu_kernels_are_the_ones_compiled_here():
               for k in REGISTRY.kernels(op)
               if "tpu" in k.backends and not k.fallback}
     assert on_tpu == {("fused_select", "pallas"), ("hash_join", "pallas"),
-                      ("topk", "pallas")}
+                      ("topk", "pallas"), ("groupby", "direct")}
+
+
+def test_direct_groupby_compiles_for_v5e(one_chip, no_persistent_cache):
+    """The sort-free group-by (plain XLA, registered for the chip): two
+    int64 keys under an alive flag, a decimal's four plane sums, a count
+    and a min, at a quarter of `q1.tasks`' rows and its key cap of 8."""
+    from spark_rapids_tpu.ops.aggregate import _groupby_kernel_direct
+    n = 1_500_000
+
+    def shape(dt):
+        return jax.ShapeDtypeStruct((n,), dt, sharding=one_chip)
+    kinds = ("sum",) * 4 + ("count", "min", "size")
+    compiled = _groupby_kernel_direct.lower(
+        (shape(jnp.int32), shape(jnp.int64), shape(jnp.int64)),
+        tuple(shape(jnp.int64) for _ in range(6)) + (shape(jnp.int8),),
+        tuple(shape(jnp.bool_) for _ in kinds),
+        n_ops=3, agg_kinds=kinds, has_valids=(True,) * len(kinds),
+        has_alive=True, cap=8).compile()
+    assert "sort(" not in compiled.as_text()
 
 
 def test_fused_select_compiles_for_v5e(chip):
