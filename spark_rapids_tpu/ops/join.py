@@ -4,37 +4,46 @@
 gather maps the plugin applies — the same contract here).
 
 TPU-first design: device hash tables fight the hardware (scatter-heavy,
-dynamic occupancy); XLA's sorter + scans are native. Round-4 redesign is
-SCATTER-FREE end to end — the round-2 on-chip numbers (recorded in
-docs/architecture.md:39-42; reproducible via tools/tpu_primitives.py, CPU
-capture committed as tools/primitives.jsonl) put a random scatter at
-~930 ms for 10M rows under x64 emulation while a 2-operand int32 sort is
-~40 ms and a cumsum ~16 ms, and the previous pipeline spent three scatters
-per join. Measured A/B vs the old design (tools/ab_relational.jsonl,
-10M×1M): 1.14× faster even on CPU, where scatters are cheap. The join
-is ONE union sort + scans + two small routing sorts:
+dynamic occupancy); XLA's sorter and scans are native. Every join here is
+ONE union sort, then a tail that reads the matches off the sorted frame.
+What each step costs on the chip is in PERF.md (section 5, by source
+line), and nowhere else.
 
-1. union sort: concatenate left+right key columns, ONE multi-operand
-   `lax.sort` over their orderable operands (shared with ops/sort.py, so
-   cross-type normalization — NaN, -0.0, decimal limbs, string words — is
-   consistent), carrying two payloads: the row iota and a "matchable right
-   row" flag. Equal keys form runs.
-2. in-sort span computation: a cumsum of the matchable flag gives, at each
-   sorted position, the count of matchable right rows at or before it.
-   Every row's match span in "matchable-right union order" is then
-       lo = exclusive count at its run START (forward segmented copy)
-       hi = inclusive count at its run END   (reverse segmented copy)
-   — two `lax.associative_scan`s, no searchsorted (which lowers to
-   ~log2(n) whole-array gather passes on TPU, ~2 s at 10M).
-3. routing sorts: `lo`/`hi` ride ONE inverse-permutation sort (keyed by the
-   iota payload) back to original row order — a permutation scatter would
-   be ~20x slower on-chip. The right-side gather map targets come from one
-   boundary-compaction sort that packs matchable right rows (in union
-   order) to the front.
-4. expand: exclusive-scan the counts, then jnp.repeat (cumsum + a
-   sorted-unique scatter under the hood) recovers (left row, k-th match)
-   for every output slot. Both sides come back as gather maps; -1 marks
-   outer-join non-matches (take() turns them into null rows).
+1. union sort (`_union_sort`): concatenate left+right key columns, ONE
+   multi-operand `lax.sort` over their orderable operands (shared with
+   ops/sort.py, so cross-type normalization — NaN, -0.0, decimal limbs,
+   string words — is consistent), carrying two payloads: the row iota and
+   a per-row flag (a payload rides the sort far cheaper than a gather
+   after it). Equal keys form runs; in a run the left rows come first.
+
+2a. the general tail (`_span_tail`, then `_expand`): a left row may match
+   any number of right rows.
+   - spans: a cumsum of the "matchable right row" flag gives, at each
+     sorted position, the count of matchable right rows at or before it.
+     Every row's match span in "matchable-right union order" is then
+         lo = exclusive count at its run START (a running maximum)
+         hi = inclusive count at its run END   (a reverse running minimum)
+     — two more prefix scans, since the counts only grow.
+   - routing sorts: `lo`/`hi` ride ONE inverse-permutation sort (keyed by
+     the iota payload) back to original row order. The right-side gather
+     map targets come from one boundary-compaction sort that packs
+     matchable right rows (in union order) to the front.
+   - expand: exclusive-scan the counts, then jnp.repeat (cumsums and a
+     scatter-add under the hood) recovers (left row, k-th match) for every
+     output slot. Both sides come back as gather maps; -1 marks outer-join
+     non-matches (take() turns them into null rows).
+   Every join but the capped inner join always takes it.
+
+2b. the many-to-one tail (`_capped_inner_kernel`, the capped inner join
+   only): when no key of the right side has two matchable rows — every
+   dimension join on a primary key — a left row has at most one match, and
+   nothing needs expanding. One reverse `cummin` over the sorted frame
+   hands every left row its run's matchable right row, ONE 32-bit sort
+   packs the emitting left rows to the front in left-row order, and one
+   gather at the output cap reads the right row ids. The same pass decides
+   `unique` on the device, and a `lax.cond` picks the tail: no plan
+   annotation, argument or switch says which join is which. Both tails
+   return the same arrays, slot for slot.
 
 Null keys never match (Spark equi-join); null-safe equality (<=>) is the
 `null_equal` flag, like cudf's null_equality::EQUAL — null rows get their
@@ -56,7 +65,8 @@ from .sort import _key_operands
 
 __all__ = ["inner_join", "left_join", "full_join", "left_semi_join",
            "left_anti_join",
-           "inner_join_capped", "left_join_capped", "semi_join_mask",
+           "inner_join_capped", "inner_join_capped_tail", "left_join_capped",
+           "semi_join_mask",
            "join_spans", "expand_spans"]
 
 
@@ -71,26 +81,60 @@ def _concat_columns(a: Column, b: Column) -> Column:
         raise TypeError(f"join key {e}") from None
 
 
-def _seg_copy(flag, vals):
-    """Per position: `vals` at the most recent flagged position (forward).
-    Positions before the first flag keep vals[0]; callers guarantee
-    flag[0] is True. The 'latest flagged value' combine is associative, so
-    this is one log-depth associative_scan, not a sequential loop."""
-    def combine(a, b):
-        ab, av = a
-        bb, bv = b
-        return ab | bb, jnp.where(bb, bv, av)
-    return jax.lax.associative_scan(combine, (flag, vals))[1]
+def _union_sort(operands, iota, flags, *, n_ops: int):
+    """The union sort every join starts from: ONE stable multi-operand sort
+    of the concatenated keys carrying two payloads, the row `iota` and a
+    per-row int32 `flags`. Returns (boundary, order, flags_sorted): where a
+    run of equal keys starts, each sorted position's original row, and its
+    flag. Within a run the left rows come first (lower iota), then the
+    right rows, each side in its original order."""
+    n = operands[0].shape[0]
+    # a marginal sort operand is cheaper on-chip than a post-sort gather
+    out = jax.lax.sort([*operands, iota, flags], num_keys=n_ops,
+                       is_stable=True)
+    sorted_ops, order, f_s = out[:-2], out[-2], out[-1]
+    neq = jnp.zeros((n,), bool)
+    for o in sorted_ops:
+        neq = neq | (o != jnp.roll(o, 1))
+    boundary = neq.at[0].set(True) if n else neq   # guard: empty scatter OOB
+    return boundary, order, f_s
 
 
-def _seg_copy_rev(flag, vals):
-    """Per position: `vals` at the nearest flagged position at-or-after it
-    (reverse segmented copy); callers guarantee flag[-1] is True."""
-    def combine(a, b):
-        ab, av = a
-        bb, bv = b
-        return ab | bb, jnp.where(bb, bv, av)
-    return jax.lax.associative_scan(combine, (flag, vals), reverse=True)[1]
+def _run_ends(boundary):
+    """Where a run of equal keys ends: the position before the next start."""
+    n = boundary.shape[0]
+    return jnp.roll(boundary, -1).at[-1].set(True) if n else boundary
+
+
+def _span_tail(boundary, order, m_s, lvalid, *, nl: int, need_rorder: bool):
+    """The general tail over the union sort (`m_s`: 1 at a matchable right
+    row): every left row's match span, for `_expand`. See _join_kernel."""
+    n = order.shape[0]
+    ends = _run_ends(boundary)
+    rcnt = jnp.cumsum(m_s)                       # inclusive matchable count
+    excl = rcnt - m_s
+    # the counts only grow along the frame, so "the value at my run's
+    # start" is a running maximum over the starts, and "at my run's end" a
+    # reverse running minimum over the ends: two prefix scans (an unrolled
+    # `associative_scan` of the same copies was 120 MB of a join's code)
+    lo_pos = jax.lax.cummax(jnp.where(boundary, excl, 0))
+    hi_pos = jax.lax.cummin(jnp.where(ends, rcnt, jnp.int32(n)),
+                            reverse=True)
+
+    # route lo/hi back to original row order: ONE 3-operand sort keyed by
+    # the iota payload (order is a permutation, so this inverts it)
+    routed = jax.lax.sort([order, lo_pos, hi_pos], num_keys=1)
+    lo_orig, hi_orig = routed[1][:nl], routed[2][:nl]
+    counts = jnp.where(lvalid, hi_orig - lo_orig, 0)
+
+    if need_rorder:
+        # pack matchable right-row ids (union-sorted order) to the front
+        flag = jnp.where(m_s == 1, jnp.int32(0), jnp.int32(1))
+        rid = jnp.where(m_s == 1, order - nl, jnp.int32(n))
+        rorder = jax.lax.sort([flag, rid], num_keys=1, is_stable=True)[1]
+    else:
+        rorder = jnp.zeros((0,), jnp.int32)
+    return counts, lo_orig, rorder
 
 
 @partial(jax.jit, static_argnames=("n_ops", "nl", "need_rorder"))
@@ -105,42 +149,14 @@ def _join_kernel(operands, lvalid, rvalid, *, n_ops: int, nl: int,
                   (length n union frame; entries past the matchable count
                   are n and never addressed: hi <= matchable count)
     """
-    n = operands[0].shape[0]
-    nr = n - nl
-    iota = jnp.arange(n, dtype=jnp.int32)
-    # matchable = valid right row; carried as a sort payload (a marginal
-    # sort operand is ~4x cheaper on-chip than a post-sort gather)
+    iota = jnp.arange(operands[0].shape[0], dtype=jnp.int32)
+    # matchable = valid right row, the union sort's flag payload
     matchable = jnp.concatenate([jnp.zeros((nl,), jnp.int32),
                                  rvalid.astype(jnp.int32)])
-    out = jax.lax.sort([*operands, iota, matchable], num_keys=n_ops,
-                       is_stable=True)
-    sorted_ops, order, m_s = out[:-2], out[-2], out[-1]
-
-    neq = jnp.zeros((n,), bool)
-    for o in sorted_ops:
-        neq = neq | (o != jnp.roll(o, 1))
-    boundary = neq.at[0].set(True) if n else neq   # guard: empty scatter OOB
-    ends = jnp.roll(boundary, -1).at[-1].set(True) if n else boundary
-
-    rcnt = jnp.cumsum(m_s)                       # inclusive matchable count
-    excl = rcnt - m_s
-    lo_pos = _seg_copy(boundary, excl)           # lo of each row's run
-    hi_pos = _seg_copy_rev(ends, rcnt)           # hi of each row's run
-
-    # route lo/hi back to original row order: ONE 3-operand sort keyed by
-    # the iota payload (order is a permutation, so this inverts it)
-    routed = jax.lax.sort([order, lo_pos, hi_pos], num_keys=1)
-    lo_orig, hi_orig = routed[1][:nl], routed[2][:nl]
-    counts = jnp.where(lvalid, hi_orig - lo_orig, 0)
-
-    if need_rorder:
-        # pack matchable right-row ids (union-sorted order) to the front
-        flag = jnp.where(m_s == 1, jnp.int32(0), jnp.int32(1))
-        rid = jnp.where(m_s == 1, order - nl, jnp.int32(n))
-        rorder = jax.lax.sort([flag, rid], num_keys=1, is_stable=True)[1]
-    else:
-        rorder = jnp.zeros((0,), jnp.int32) if nr == 0 else iota[:0]
-    return counts, lo_orig, rorder
+    boundary, order, m_s = _union_sort(operands, iota, matchable,
+                                       n_ops=n_ops)
+    return _span_tail(boundary, order, m_s, lvalid, nl=nl,
+                      need_rorder=need_rorder)
 
 
 @partial(jax.jit, static_argnames=("total", "outer"))
@@ -200,8 +216,9 @@ def expand_spans(counts, lo, rorder, *, total: int, outer: bool = False,
     return _expand(counts, lo, rorder, total=total, outer=outer, eff=eff)
 
 
-def _prep(left_keys, right_keys, null_equal: bool, need_rorder: bool = True,
-          lalive=None, ralive=None):
+def _union_operands(left_keys, right_keys, null_equal: bool, lalive, ralive):
+    """-> (sort operands of the concatenated keys, lvalid, rvalid, nl): the
+    match masks hold null keys (unless `null_equal`) and the alive masks."""
     lcols, rcols = list(left_keys), list(right_keys)
     if len(lcols) != len(rcols) or not lcols:
         raise ValueError("join requires equal, nonzero key column counts")
@@ -231,8 +248,15 @@ def _prep(left_keys, right_keys, null_equal: bool, need_rorder: bool = True,
         lvalid = lvalid & lalive
     if ralive is not None:
         rvalid = rvalid & ralive
-    return _join_kernel(tuple(union_ops), lvalid, rvalid,
-                        n_ops=len(union_ops), nl=nl, need_rorder=need_rorder)
+    return tuple(union_ops), lvalid, rvalid, nl
+
+
+def _prep(left_keys, right_keys, null_equal: bool, need_rorder: bool = True,
+          lalive=None, ralive=None):
+    operands, lvalid, rvalid, nl = _union_operands(
+        left_keys, right_keys, null_equal, lalive, ralive)
+    return _join_kernel(operands, lvalid, rvalid, n_ops=len(operands),
+                        nl=nl, need_rorder=need_rorder)
 
 
 def _cols(keys) -> Sequence[Column]:
@@ -294,18 +318,98 @@ def inner_join_capped(left_keys, right_keys, row_cap: int, *,
     Returns (lmap, rmap, valid, overflow): (row_cap,) int32 gather maps into
     the original frames (dead slots hold 0 and are masked by `valid`), a
     (row_cap,) bool row mask, and a scalar overflow flag."""
+    return inner_join_capped_tail(left_keys, right_keys, row_cap,
+                                  lalive=lalive, ralive=ralive,
+                                  null_equal=null_equal)[:4]
+
+
+def inner_join_capped_tail(left_keys, right_keys, row_cap: int, *,
+                           lalive=None, ralive=None,
+                           null_equal: bool = False):
+    """`inner_join_capped` and which tail answered: (lmap, rmap, valid,
+    overflow, unique). `unique` (a scalar bool, decided on the device from
+    the union sort) says that no key of the right side had two matchable
+    rows, so the many-to-one tail ran; False: the expansion did. Both
+    return the same arrays (see _capped_inner_kernel)."""
     _require_x64("inner_join_capped")
-    counts, lo, rorder = _prep(_cols(left_keys), _cols(right_keys),
-                               null_equal, lalive=lalive, ralive=ralive)
-    total = jnp.sum(counts.astype(jnp.int64))   # i32 sum could wrap at 10M×
-    lmap, rmap = _expand(counts, lo, rorder, total=row_cap, outer=False)
+    operands, lvalid, rvalid, nl = _union_operands(
+        _cols(left_keys), _cols(right_keys), null_equal, lalive, ralive)
+    return _capped_inner_kernel(operands, lvalid, rvalid,
+                                n_ops=len(operands), nl=nl, row_cap=row_cap)
+
+
+def _fit(x, length: int):
+    """The first `length` entries of `x`, zero-padded where it is shorter
+    (the padding lies past every live slot)."""
+    if x.shape[0] >= length:
+        return x[:length]
+    return jnp.pad(x, (0, length - x.shape[0]))
+
+
+@partial(jax.jit, static_argnames=("n_ops", "nl", "row_cap"))
+def _capped_inner_kernel(operands, lvalid, rvalid, *, n_ops: int, nl: int,
+                         row_cap: int):
+    """One union sort, then one of two tails, chosen on the device.
+
+    The sort's flag payload is the match mask of BOTH sides, so a left
+    row's `lvalid` arrives in sorted order without a gather. One reverse
+    `cummin` over the sorted frame then gives every position its nearest
+    EVENT at or after it, an event being a matchable right row (word 2p)
+    or the last row of a run (2p + 1; the matchable row wins where a row is
+    both). Right rows close their run, so a left row has a match iff its
+    event is even, and that event is the match when the run holds one
+    matchable row. `unique`: no matchable right row is followed, inside its
+    run, by another (dead and null-keyed right rows between them are no
+    events, so they hide nothing).
+
+    unique  -> the many-to-one tail: ONE 32-bit sort packs the emitting left
+               rows to the front in left-row order with their event as
+               payload, cut to `row_cap`, and one gather at the cap reads
+               the right row id off the union sort's iota.
+    else    -> the general tail: spans, routing sorts and `_expand`.
+    Pair for pair the same (lmap, rmap, valid, overflow)."""
+    n = operands[0].shape[0]
+    nr = n - nl
+    if nl == 0 or nr == 0:      # static: nothing can match, nothing to sort
+        z = jnp.zeros((row_cap,), jnp.int32)
+        return z, z, jnp.zeros((row_cap,), bool), jnp.bool_(False), \
+            jnp.bool_(True)
+    flags = jnp.concatenate([lvalid, rvalid]).astype(jnp.int32)
+    iota = jnp.arange(n, dtype=jnp.int32)
+    boundary, order, f_s = _union_sort(operands, iota, flags, n_ops=n_ops)
+    right = order >= nl
+    m_s = jnp.where(right, f_s, 0)           # matchable right rows
+    ends = _run_ends(boundary)
+    pos2 = jnp.arange(n, dtype=jnp.uint32) * 2
+    none = jnp.uint32(2 * n + 1)             # odd: matches nothing
+    event = jax.lax.cummin(
+        jnp.where(m_s == 1, pos2, jnp.where(ends, pos2 + 1, none)),
+        reverse=True)
+    after = jnp.concatenate([event[1:], none[None]])
+    unique = ~jnp.any((m_s == 1) & ~ends & (after % 2 == 0))
+
+    def many_to_one(_):
+        emit = ~right & (f_s == 1) & (event % 2 == 0)
+        key = jnp.where(emit, order, jnp.int32(n))     # left row, or past all
+        lrow, hit = jax.lax.sort([key, event], num_keys=1)
+        at = (_fit(hit, row_cap) >> 1).astype(jnp.int32)
+        rmap = jnp.take(order, at, axis=0, mode="clip") - nl
+        return _fit(lrow, row_cap), rmap, jnp.sum(emit.astype(jnp.int64))
+
+    def general(_):
+        counts, lo, rorder = _span_tail(boundary, order, m_s, lvalid, nl=nl,
+                                        need_rorder=True)
+        total = jnp.sum(counts.astype(jnp.int64))  # i32 sum could wrap at 10M×
+        lmap, rmap = _expand(counts, lo, rorder, total=row_cap, outer=False)
+        return lmap, rmap, total
+
+    lmap, rmap, total = jax.lax.cond(unique, many_to_one, general, None)
     valid = jnp.arange(row_cap, dtype=jnp.int32) < total
-    nr = _cols(right_keys)[0].length
     # valid slots carry genuine in-range matches; dead slots are clamped to
     # row 0 so downstream gathers never need a host sync or a fill value
     lmap = jnp.where(valid, lmap, 0)
-    rmap = jnp.where(valid, jnp.clip(rmap, 0, max(nr - 1, 0)), 0)
-    return lmap, rmap, valid, total > row_cap
+    rmap = jnp.where(valid, jnp.clip(rmap, 0, nr - 1), 0)
+    return lmap, rmap, valid, total > row_cap, unique
 
 
 def left_join_capped(left_keys, right_keys, row_cap: int, *,

@@ -131,6 +131,8 @@ def _op_span(node: PlanNode, idx: int, tier: str = "device"):
 
 
 _DECIMAL_OVERFLOW = -1      # key of `_run_capped`'s counts, see there
+_JOIN_UNIQUE = -2           # minus the join's index: key of the flag that
+#                             says which tail its sort join took
 
 
 def _scope_name(idx: int, node: PlanNode) -> str:
@@ -163,6 +165,47 @@ def _scope_owners(hlo_text: str, nested: bool = False) -> Dict[str, str]:
                          if p.startswith("decimal.")] if nested else []
                 owners[m.group(1)] = "/".join(parts[at:at + 1] + inner[-1:])
     return owners
+
+
+_HLO_FRAME = re.compile(r"stack_frame_id=(\d+)")
+_HLO_TABLE_ROW = re.compile(r"^(\d+) (?:\"(.*)\"|\{(.*)\})$")
+
+
+def _op_sources(hlo_text: str) -> Dict[str, Tuple[str, str]]:
+    """{instruction name: (primitive, "file:line function")} from an
+    executable's text: the last part of the instruction's `op_name`
+    (`scatter-add`, `gather`, `sort`) and the innermost Python frame that
+    traced it, resolved through the text's own tables (FileNames,
+    FunctionNames, FileLocations, StackFrames). A fusion reads as its
+    root does. Instructions without a frame are left out."""
+    tables: Dict[str, Dict[int, object]] = {}
+    section = None
+    sources: Dict[str, Tuple[str, str]] = {}
+    for line in hlo_text.splitlines():
+        text = line.strip()
+        if text in ("FileNames", "FunctionNames", "FileLocations",
+                    "StackFrames"):
+            section = tables.setdefault(text, {})
+            continue
+        row = _HLO_TABLE_ROW.match(text) if section is not None else None
+        if row:
+            section[int(row.group(1))] = row.group(2) if row.group(3) is None \
+                else {k: int(v) for k, v in
+                      (kv.split("=") for kv in row.group(3).split())}
+            continue
+        if text:
+            section = None
+        m = _HLO_INSTRUCTION.match(line)
+        frame = _HLO_FRAME.search(line) if m else None
+        if frame and "StackFrames" in tables:
+            loc = tables["FileLocations"][
+                tables["StackFrames"][int(frame.group(1))]["file_location_id"]]
+            path = tables["FileNames"][loc["file_name_id"]]
+            sources[m.group(1)] = (
+                m.group(2).rsplit("/", 1)[-1],
+                f"{path.rsplit('spark_rapids_tpu/', 1)[-1]}:{loc['line']} "
+                f"{tables['FunctionNames'][loc['function_name_id']]}")
+    return sources
 
 
 def _input_key(inputs: Dict[str, Table]) -> Tuple:
@@ -559,6 +602,9 @@ class PlanResult:
         self.decimal_overflow_rows = 0   # rows or groups a decimal kernel
         #                               nulled by overflow (Spark's
         #                               non-ANSI rule; docs/plan.md)
+        self.unique_joins = 0         # capped tier: sort joins that took the
+        self.expand_joins = 0         # many-to-one tail / the expansion
+        #                               (ops/join.py decides on the device)
         self.cached = False           # served from the serving result cache
         #                               (serving/cache.py): True ONLY on a
         #                               cache-hit COPY — its metrics are
@@ -589,13 +635,16 @@ class PlanResult:
 
 
 class _CappedRel:
-    """A relation inside the capped trace: padded table + live-row mask."""
+    """A relation inside the capped trace: padded table + live-row mask;
+    `unique`, on a sort join's output, the scalar that says which tail the
+    join took (ops/join.py:inner_join_capped_tail)."""
 
-    __slots__ = ("table", "alive")
+    __slots__ = ("table", "alive", "unique")
 
-    def __init__(self, table: Table, alive: jnp.ndarray):
+    def __init__(self, table: Table, alive: jnp.ndarray, unique=None):
         self.table = table
         self.alive = alive
+        self.unique = unique
 
 
 class PlanExecutor:
@@ -726,7 +775,9 @@ class PlanExecutor:
             res = self._execute_request(plan, inputs, tier, placement)
             if nulled:      # eager tiers: one read-back, decimal plans only
                 res.decimal_overflow_rows += int(sum(nulled))
-            sp.set_metadata(decimal_overflow_rows=res.decimal_overflow_rows)
+            sp.set_metadata(decimal_overflow_rows=res.decimal_overflow_rows,
+                            unique_joins=res.unique_joins,
+                            expand_joins=res.expand_joins)
             return res
 
     def _execute_request(self, plan, inputs, tier,
@@ -1084,6 +1135,22 @@ class PlanExecutor:
         rescale, div, sum) lies below the operator's. On request only: this
         lowers the program again and reads the executable back through
         the compile cache; `execute` never calls it."""
+        return _scope_owners(self._capped_program_text(plan, inputs), nested)
+
+    def device_op_sources(self, plan: Plan,
+                          inputs: Optional[Dict[str, Table]] = None
+                          ) -> Dict[str, Tuple[str, str, str]]:
+        """What `fusion.32` is, beside whose: {HLO instruction name:
+        (owner, primitive, "file:line function")} for the program
+        `device_op_owners` reads, from the same text (see `_op_sources`;
+        owner "" outside every operator). `tools/device_ops.py` prints a
+        trace's largest ops and source lines with it."""
+        text = self._capped_program_text(plan, inputs)
+        owners = _scope_owners(text)
+        return {name: (owners.get(name, ""), prim, where)
+                for name, (prim, where) in _op_sources(text).items()}
+
+    def _capped_program_text(self, plan: Plan, inputs) -> str:
         if self.mode != "capped":
             raise PlanValidationError(
                 "device_op_owners reads the capped tier's one program; "
@@ -1098,8 +1165,7 @@ class PlanExecutor:
         caps, _ = self._starting_caps(plan, inputs, source_fp,
                                       self._certify(plan, inputs, bound))
         fn = self._jitted_capped(plan, schemas, caps, _input_key(inputs))[0]
-        return _scope_owners(fn.lower(dict(inputs)).compile().as_text(),
-                             nested)
+        return fn.lower(dict(inputs)).compile().as_text()
 
     @staticmethod
     def _transport_summary() -> str:
@@ -2205,11 +2271,15 @@ class PlanExecutor:
             return self._execute_degraded(plan, inputs, schemas, {}, {},
                                           start=0, t_plan0=t0, mode="capped")
 
+        tried: List[Tuple] = []     # the program cache's key, per attempt
+
         def run(**caps_now):
             nonlocal attempts, cache_hits
             attempts += 1
             last_caps.clear()
             last_caps.update(caps_now)
+            tried.append(self._capped_key(plan, caps_now,
+                                          _input_key(inputs)))
             # plan-level faultinj surface: fires every attempt, including
             # cache-hit runs where the op-level shims never re-trace
             for node in plan.nodes:
@@ -2243,6 +2313,13 @@ class PlanExecutor:
                 if retries:
                     self.health.record_success("plan")
                 self._caps_memo[fp] = dict(final_caps)
+                # a program that overflowed never runs for this plan
+                # again (the memo floors every later start at the caps
+                # that held), and an executable's code lies in HBM beside
+                # the data: let it go with its cache entry
+                for key in tried[:-1]:
+                    if key != tried[-1]:
+                        self._jit_cache.discard(key)
                 break
             except _fault_surface() as err:
                 # failures are plan-granular here (one XLA program), so the
@@ -2278,6 +2355,10 @@ class PlanExecutor:
             # fingerprint-shared program was traced over an equivalent
             # plan whose node labels differ, but its toposort lines up 1:1
             rows_in, rows_out = counts_np[i]
+            kernel = kernel_map.get(i, "")
+            if _JOIN_UNIQUE - i in counts_np:
+                kernel += ("/unique" if counts_np[_JOIN_UNIQUE - i][0]
+                           else "/expand")
             uses_cap = (isinstance(node, HashJoin) and node.how == "inner") \
                 or (isinstance(node, HashAggregate) and node.keys)
             # retries are plan-granular in this tier (one XLA program) and
@@ -2288,7 +2369,7 @@ class PlanExecutor:
                 rows_in=rows_in, rows_out=rows_out,
                 bytes_out=bytes_map.get(i, 0),
                 escalations=escal if uses_cap else 0,
-                kernel=kernel_map.get(i, ""))
+                kernel=kernel)
             if isinstance(node, Scan) and node.source in scan_io:
                 io = scan_io[node.source]
                 mm = metrics[node.label]
@@ -2303,16 +2384,19 @@ class PlanExecutor:
                          backoff_ms=backoff_total,
                          jit_cache_hits=cache_hits)
         res.decimal_overflow_rows = counts_np[_DECIMAL_OVERFLOW][0]
+        tails = [flag for k, (flag, _) in counts_np.items()
+                 if k <= _JOIN_UNIQUE]
+        res.unique_joins = sum(tails)
+        res.expand_joins = len(tails) - res.unique_joins
         return res
 
-    def _jitted_capped(self, plan, schemas, caps, input_key):
+    def _capped_key(self, plan, caps, input_key) -> Tuple:
         # the canonical FINGERPRINT is the key: structurally equivalent
         # plans built independently (same kinds/exprs/schemas/DAG shape)
         # share one compiled program instead of re-tracing. The backend +
         # kernel-override knob join the key: registry selection happens at
         # trace time, so a program compiled under one kernel choice must
-        # never serve another (docs/kernels.md). Returns (jitted_fn,
-        # bytes_map, kernel_map, cache_hit).
+        # never serve another (docs/kernels.md).
         from .. import config
         from . import stats as stats_mod
         store = stats_mod.active_store()
@@ -2325,8 +2409,12 @@ class PlanExecutor:
                     tuple(sorted(config.kernel_overrides().items())),
                     None if store is None else (store.uid,
                                                 store.kernel_epoch))
-        key = (plan.fingerprint, tuple(sorted(caps.items())), input_key,
-               kern_key)
+        return (plan.fingerprint, tuple(sorted(caps.items())), input_key,
+                kern_key)
+
+    def _jitted_capped(self, plan, schemas, caps, input_key):
+        # -> (jitted_fn, bytes_map, kernel_map, cache_hit)
+        key = self._capped_key(plan, caps, input_key)
         hit = self._jit_cache.get(key)
         if hit is not None:
             return hit[0], hit[1], hit[2], True
@@ -2361,6 +2449,9 @@ class PlanExecutor:
                         node, i, childs, tables, schemas, caps, kernel_map)
                     if ovf is not None:
                         overflow = overflow | ovf
+                    if rel.unique is not None:
+                        counts[_JOIN_UNIQUE - i] = (
+                            rel.unique.astype(jnp.int64), jnp.int64(0))
                     bytes_map[i] = operand_nbytes(rel.table)
                     rows_in = sum((jnp.sum(c.alive.astype(jnp.int64))
                                    for c in childs), start=jnp.int64(0))
@@ -2427,12 +2518,13 @@ class PlanExecutor:
                                                      "capped"))
             if node.how == "inner":
                 row_cap = self._node_cap(caps, "row_cap", idx)
+                unique = None
                 if not choice.fallback:
                     lm, rm, valid, ovf = join_pallas.inner_join_capped_pallas(
                         lkeys, rkeys, row_cap=row_cap, lalive=l.alive,
                         ralive=r.alive)
                 else:
-                    lm, rm, valid, ovf = ops.inner_join_capped(
+                    lm, rm, valid, ovf, unique = ops.inner_join_capped_tail(
                         lkeys, rkeys, row_cap=row_cap, lalive=l.alive,
                         ralive=r.alive)
                 cols = [ops.take(col, lm, _has_negative=False)
@@ -2441,7 +2533,7 @@ class PlanExecutor:
                          for col in r.table.columns]
                 t = Table(cols, names=list(l.table.names) +
                           list(r.table.names))
-                return _CappedRel(t, valid), ovf
+                return _CappedRel(t, valid, unique), ovf
             mask = ops.semi_join_mask(lkeys, rkeys, lalive=l.alive,
                                       ralive=r.alive)
             alive = (l.alive & mask if node.how == "left_semi"

@@ -9,9 +9,10 @@ Semantics (deliberately narrow — the callers use exactly this surface):
 - `get(key)` refreshes recency (the hit becomes most-recently-used);
 - `d[key] = value` inserts as most-recent (overwriting refreshes) and
   evicts the least-recently-used entries beyond `maxsize`;
-- plain `d[key]` reads do NOT refresh (dict semantics, cheap probes).
+- plain `d[key]` reads do NOT refresh (dict semantics, cheap probes);
+- `discard(key)` drops an entry its owner knows to be dead.
 
-Thread safety: `get`/`__setitem__` are internally locked. The serving
+Thread safety: `get`/`__setitem__`/`discard` are internally locked. The serving
 layer (serving/scheduler.py) runs N dispatcher workers through ONE
 PlanExecutor, so its memo caches see genuinely concurrent get/insert —
 the unlocked pop-then-reinsert recency dance would drop a live entry
@@ -41,6 +42,10 @@ class LruDict(dict):
                 super().__setitem__(key, val)   # re-insert = most recent
                 return val
             return default
+
+    def discard(self, key) -> None:
+        with self._lru_lock:
+            super().pop(key, None)
 
     def __setitem__(self, key, value):
         with self._lru_lock:

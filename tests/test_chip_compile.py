@@ -259,3 +259,29 @@ def test_capped_q3_program_compiles_for_v5e(one_chip, no_persistent_cache,
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes + mem.argument_size_in_bytes \
         + mem.output_size_in_bytes < 16 * 1024 ** 3
+
+
+def test_capped_join_compiles_with_both_tails_for_v5e(one_chip,
+                                                      no_persistent_cache):
+    """The capped inner join (ops/join.py:_capped_inner_kernel) at a small
+    shape: one conditional whose branches are both in the executable, the
+    expansion (its two sorts and jnp.repeat's scatter-add) and the
+    many-to-one tail (its one sort, its one gather); the union sort is
+    shared, outside."""
+    from spark_rapids_tpu.ops import join
+    nl, nr, cap = 4096, 512, 1024
+
+    def shape(n, dtype):
+        return jax.ShapeDtypeStruct((n,), dtype, sharding=one_chip)
+    text = join._capped_inner_kernel.lower(
+        (shape(nl + nr, jnp.int64),), shape(nl, jnp.bool_),
+        shape(nr, jnp.bool_), n_ops=1, nl=nl, row_cap=cap).compile().as_text()
+    names = [line.split("op_name=\"")[1].split("\"")[0]
+             for line in text.splitlines()
+             if " sort(" in line and "op_name=\"" in line]
+    kernel = "jit(_capped_inner_kernel)/"
+    assert text.count(" conditional(") == 1
+    assert sorted(names) == [kernel + "cond/branch_0_fun/sort"] * 2 \
+        + [kernel + "cond/branch_1_fun/sort", kernel + "sort"]
+    assert kernel + "cond/branch_0_fun/jit(_expand)/scatter-add" in text
+    assert kernel + "cond/branch_1_fun/jit(_take)/gather" in text

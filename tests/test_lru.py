@@ -46,3 +46,13 @@ def test_plain_getitem_does_not_refresh():
     assert d["a"] == 1                         # dict semantics: no refresh
     d["c"] = 3
     assert "a" not in d                        # "a" was still the oldest
+
+
+def test_discard_drops_an_entry_and_ignores_a_miss():
+    d = LruDict(maxsize=2)
+    d["a"] = 1
+    d["b"] = 2
+    d.discard("a")
+    d.discard("zz")                            # not there: nothing happens
+    d["c"] = 3                                 # room again: "b" stays
+    assert list(d) == ["b", "c"]

@@ -245,9 +245,12 @@ def test_capped_escalated_caps_remembered_across_executes():
                       max_cap_attempts=8)
     r1 = ex.execute(plan, {"sales": sales, "dims": dims})
     assert r1.attempts > 1
+    # the programs that overflowed went with their cache entries (their
+    # code lies in device memory and they never start again): one is left
+    assert [dict(k[1]) for k in ex._jit_cache] == [r1.caps]
     r2 = ex.execute(plan, {"sales": sales, "dims": dims})
     assert r2.attempts == 1                   # grown caps were remembered
-    assert r2.caps == r1.caps
+    assert r2.caps == r1.caps and r2.jit_cache_hits == 1
     assert r2.compact().to_pydict() == r1.compact().to_pydict()
 
 

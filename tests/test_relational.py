@@ -717,3 +717,189 @@ def test_capped_join_x64_guard():
     # with the flag restored the op works
     lm, rm, valid, overflow = inner_join_capped([l], [r], row_cap=8)
     assert int(np.asarray(valid).sum()) == 2 and not bool(overflow)
+
+
+# ---- the capped inner join's two tails (ops/join.py:_capped_inner_kernel) ----
+
+def _general_tail(lk, rk, cap, lalive=None, ralive=None, null_equal=False):
+    """`inner_join_capped` as it was before the many-to-one tail: the span
+    kernel and the expansion through their public forms."""
+    import jax.numpy as jnp
+    from spark_rapids_tpu.ops import join as J
+    operands, lvalid, rvalid, nl = J._union_operands(
+        [lk], [rk], null_equal, lalive, ralive)
+    counts, lo, rorder = J.join_spans(operands, lvalid, rvalid, nl=nl)
+    total = jnp.sum(counts.astype(jnp.int64))
+    lmap, rmap = J.expand_spans(counts, lo, rorder, total=cap)
+    valid = jnp.arange(cap, dtype=jnp.int32) < total
+    rmap = jnp.where(valid, jnp.clip(rmap, 0, max(rk.length - 1, 0)), 0)
+    return jnp.where(valid, lmap, 0), rmap, valid, total > cap
+
+
+def _same_as_general(lk, rk, cap, unique, **kw):
+    from spark_rapids_tpu.ops import inner_join_capped_tail
+    got = inner_join_capped_tail([lk], [rk], cap, **kw)
+    for name, g, w in zip(("lmap", "rmap", "valid", "overflow"), got,
+                          _general_tail(lk, rk, cap, **kw)):
+        assert np.array_equal(np.asarray(g), np.asarray(w)), name
+    assert bool(got[4]) is unique
+    return got
+
+
+@pytest.mark.parametrize("seed", range(6))
+def test_capped_join_unique_build_side_takes_many_to_one_tail(seed):
+    """(a) random keys, nulls on both sides, alive masks, caps below and
+    above the match count, null_equal on the odd seeds: slot for slot the
+    general tail's answer, and the flag reads unique."""
+    import jax.numpy as jnp
+    rng = np.random.default_rng(290 + seed)
+    nl, nr = 300, 70
+    rkeys = rng.permutation(120)[:nr].astype(np.int64)
+    lkeys = rng.integers(0, 120, nl).astype(np.int64)
+    lk = col(lkeys, nulls=rng.random(nl) < 0.15)
+    rk = col(rkeys, nulls=rng.random(nr) < 0.03)   # 0-3 nulls: one null run
+    kw = dict(lalive=jnp.asarray(rng.random(nl) > 0.2),
+              ralive=jnp.asarray(rng.random(nr) > 0.2),
+              null_equal=bool(seed % 2))
+    if kw["null_equal"]:           # <=>: two null right rows share a key
+        rk = col(rkeys, nulls=np.arange(nr) == 5)
+    for cap in (40, 512):
+        lm, rm, valid, ovf, _ = _same_as_general(lk, rk, cap, True, **kw)
+    assert not bool(ovf) and 0 < int(np.asarray(valid).sum()) < nl
+
+
+@pytest.mark.parametrize("where", ["first_rows", "last_rows", "far_apart"])
+def test_capped_join_duplicate_build_key_takes_expansion(where):
+    """(b) one duplicated build key: the flag reads expand, the answer is
+    the general tail's (the duplicated key's left rows fan out)."""
+    rng = np.random.default_rng(7)
+    rkeys = np.arange(40, dtype=np.int64)
+    i, j = {"first_rows": (0, 1), "last_rows": (38, 39),
+            "far_apart": (3, 31)}[where]
+    rkeys[j] = rkeys[i]
+    lk = col(rng.integers(0, 40, 200).astype(np.int64))
+    lm, rm, valid, ovf, _ = _same_as_general(lk, col(rkeys), 512, False)
+    assert int(np.asarray(valid).sum()) > 200 * 0.9
+
+
+@pytest.mark.parametrize("twin", ["dead", "null", "live_beyond_dead",
+                                  "live_beyond_null"])
+def test_capped_join_flag_counts_matchable_rows_only(twin):
+    """(c) a duplicate whose twin is dead or null-keyed does not count:
+    unique, and right; two live twins with a dead or null-keyed row of the
+    same key between them: expand."""
+    import jax.numpy as jnp
+    rkeys = np.array([5, 9, 7, 7, 7, 2], np.int64)
+    ralive = np.ones(6, bool)
+    rnull = np.zeros(6, bool)
+    if twin == "dead":
+        ralive[[3, 4]] = False
+    elif twin == "null":
+        rnull[[3, 4]] = True
+    elif twin == "live_beyond_dead":
+        ralive[3] = False
+    else:
+        # a null-keyed row sorts into the null run, so the twin between is
+        # dead and the null lies elsewhere: still two live 7s
+        ralive[3], rnull[0] = False, True
+    lk = col(np.array([7, 7, 1, 9, 7, 5], np.int64))
+    rk = col(rkeys, nulls=rnull)
+    lm, rm, valid, _, _ = _same_as_general(
+        lk, rk, 16, twin in ("dead", "null"), ralive=jnp.asarray(ralive))
+    v = np.asarray(valid)
+    pairs = list(zip(np.asarray(lm)[v].tolist(), np.asarray(rm)[v].tolist()))
+    sevens = [2] if twin in ("dead", "null") else [2, 4]
+    want = sorted([(l, r) for l in (0, 1, 4) for r in sevens]
+                  + [(3, 1)] + ([(5, 0)] if not rnull[0] else []))
+    assert sorted(pairs) == want
+
+
+@pytest.mark.parametrize("dup", [False, True])
+def test_capped_join_overflow_on_both_tails_and_auto_retry(dup):
+    """(d) more matches than the cap: overflow on either tail, the first
+    `row_cap` pairs are still the general tail's, and auto_retry_overflow
+    climbs to a cap that holds them."""
+    from spark_rapids_tpu.ops import inner_join_capped_tail
+    from spark_rapids_tpu.parallel.autoretry import auto_retry_overflow
+    rng = np.random.default_rng(11)
+    rkeys = np.arange(30, dtype=np.int64)
+    if dup:
+        rkeys[17] = rkeys[4]
+    lkeys = rng.integers(0, 30, 500).astype(np.int64)
+    lk, rk = col(lkeys), col(rkeys)
+    *_, ovf, _ = _same_as_general(lk, rk, 64, not dup)
+    assert bool(ovf)
+    seen = []
+
+    def attempt(row_cap):
+        seen.append(row_cap)
+        return inner_join_capped_tail([lk], [rk], row_cap)[:4]
+    (lm, rm, valid, ovf), caps = auto_retry_overflow(attempt,
+                                                     {"row_cap": 64})
+    total = int(np.asarray(valid).sum())
+    ladder = [64, 128, 256, 512, 1024]
+    # the duplicate took key 17's row: its left rows lose their match,
+    # key 4's left rows have two
+    want = 500 + dup * int((lkeys == 4).sum() - (lkeys == 17).sum())
+    assert not bool(ovf) and total == want > 512 * dup
+    assert seen == ladder[:4 + dup] and caps == {"row_cap": seen[-1]}
+
+
+@pytest.mark.parametrize("shape", ["empty_left", "empty_right", "both_empty",
+                                   "cap_over_frame", "no_match"])
+def test_capped_join_tail_edges(shape):
+    """(e) empty sides, a cap larger than the whole union frame, no match."""
+    nl, nr = {"empty_left": (0, 5), "empty_right": (5, 0),
+              "both_empty": (0, 0), "cap_over_frame": (6, 4),
+              "no_match": (6, 4)}[shape]
+    lk = col(np.arange(nl, dtype=np.int64) % 3)
+    rk = col(np.arange(nr, dtype=np.int64)
+             + (100 if shape == "no_match" else 0))
+    lm, rm, valid, ovf, _ = _same_as_general(lk, rk, 64, True)
+    assert lm.shape == rm.shape == valid.shape == (64,) and not bool(ovf)
+    assert int(np.asarray(valid).sum()) == (6 if shape == "cap_over_frame"
+                                            else 0)
+
+
+@pytest.mark.parametrize("fan_out", [True, False])
+def test_capped_plan_stamps_each_join_with_its_tail(fan_out):
+    """(f) a q72-shaped plan in the capped tier: the dimension joins read
+    unique, the inventory join (several rows a key) expand, and the result
+    counts both; with one inventory row a key every join is many-to-one."""
+    from spark_rapids_tpu.plan import PlanBuilder, PlanExecutor, col as pcol
+    rng = np.random.default_rng(72)
+    n, items, per_item = 240, 12, 4 if fan_out else 1
+
+    def table(**cols):
+        return Table([col(np.asarray(v, np.int64)) for v in cols.values()],
+                     names=list(cols))
+    inputs = {
+        "cs": table(item_sk=rng.integers(0, items, n),
+                    hd_sk=rng.integers(0, 20, n), qty=rng.integers(1, 9, n)),
+        "hd": table(hd_demo_sk=np.arange(20), potential=np.arange(20) % 4),
+        "items": table(i_item_sk=np.arange(items), i_brand=np.arange(items)),
+        "inv": table(inv_item_sk=np.repeat(np.arange(items), per_item),
+                     inv_qty=rng.integers(0, 9, items * per_item))}
+    b = PlanBuilder()
+    cs = b.scan("cs", schema=["item_sk", "hd_sk", "qty"])
+    hd = b.scan("hd", schema=["hd_demo_sk", "potential"]) \
+        .filter(pcol("potential") < 3)
+    plan = (cs.join(hd, "hd_sk", "hd_demo_sk")
+              .join(b.scan("items", schema=["i_item_sk", "i_brand"]),
+                    "item_sk", "i_item_sk")
+              .join(b.scan("inv", schema=["inv_item_sk", "inv_qty"]),
+                    "i_item_sk", "inv_item_sk")
+              .filter(pcol("inv_qty") < pcol("qty"))
+              .aggregate(["i_brand"], [("qty", "size", "cnt")])
+              .sort(["i_brand"]).build())
+    ref = PlanExecutor(mode="eager").execute(plan, inputs)
+    # optimize=False: the authored sides stay (the build-side rule would
+    # put the larger inventory table on the left)
+    res = PlanExecutor(mode="capped", optimize=False).execute(plan, inputs)
+    assert res.compact().to_pydict() == ref.table.to_pydict()
+    tails = [m.kernel for m in res.metrics.values() if m.kind == "HashJoin"]
+    last = "xla:hash_join/expand" if fan_out else "xla:hash_join/unique"
+    assert tails == ["xla:hash_join/unique", "xla:hash_join/unique", last]
+    assert (res.unique_joins, res.expand_joins) == \
+        ((2, 1) if fan_out else (3, 0))
+    assert "kernel: " + last in res.profile_text()

@@ -360,6 +360,43 @@ def test_device_op_owners_gives_a_sort_and_a_fusion_to_a_join(capped_q3):
     assert any(o.endswith(".HashAggregate") for o in owners.values())
 
 
+def test_device_op_sources_names_the_line_that_traced_a_sort(capped_q3):
+    """What an instruction is, beside whose: the union sort of a join is
+    `sort` traced in ops/join.py:_union_sort, under its join's scope."""
+    ex, plan, inputs, res = capped_q3
+    sources = ex.device_op_sources(plan, inputs)
+    owners = ex.device_op_owners(plan, inputs)
+    assert {n: o for n, (o, _, _) in sources.items() if o} \
+        == {n: o for n, o in owners.items() if n in sources}
+    union = [(owner, prim, where) for owner, prim, where in sources.values()
+             if where.endswith(" _union_sort") and prim == "sort"]
+    assert union and all(o.endswith(".HashJoin") for o, _, _ in union)
+    file_line = union[0][2].split(" ")[0]
+    assert file_line.startswith("ops/join.py:") \
+        and int(file_line.split(":")[1]) > 0
+
+
+def test_plan_execute_span_counts_the_joins_by_tail(session):
+    """Which tail a capped sort join took (ops/join.py decides on the
+    device) lands on `plan.execute` beside `decimal_overflow_rows`, and on
+    the join's kernel label: a dimension with one row a key is
+    many-to-one, the same dimension twice over fans out."""
+    plan, ex = _join_plan(), PlanExecutor(mode="capped")
+    twice = Table([_col(np.arange(100) % 50), _col(np.arange(100) % 7)],
+                  names=["dk", "g"])
+    for dim, tails, label in ((_dim(), (1, 0), "xla:hash_join/unique"),
+                              (twice, (0, 1), "xla:hash_join/expand")):
+        inputs = {"t": _fact(), "d": dim}
+        ex.execute(plan, inputs)                          # compile outside
+        done = []
+        spans = session(lambda: done.append(ex.execute(plan, inputs)))
+        got = spans.one("plan.execute")
+        assert (got["unique_joins"], got["expand_joins"]) == tails
+        assert (done[0].unique_joins, done[0].expand_joins) == tails
+        assert [m.kernel for m in done[0].metrics.values()
+                if m.kind == "HashJoin"] == [label]
+
+
 def test_device_op_owners_is_the_capped_tiers():
     plan, inputs = _join_plan(), {"t": _fact(), "d": _dim()}
     with pytest.raises(Exception, match="capped tier"):
