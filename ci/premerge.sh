@@ -1,6 +1,7 @@
 #!/usr/bin/env bash
 # Premerge gate (reference: ci/premerge-build.sh runs `mvn verify` with tests
-# on). Full unit suite on the 8-device CPU mesh + native build + bench smoke.
+# on). Linters, the fixed fuzz corpus, the full unit suite on the 8-device
+# CPU mesh (native build included) and the arbiter Monte Carlo.
 set -euo pipefail
 cd "$(dirname "$0")/.."
 
@@ -13,10 +14,6 @@ python -c "import spark_rapids_tpu; print('import ok:', spark_rapids_tpu.__name_
 # vetted exceptions live in tools/lint_hazards_allowlist.txt with
 # one-line justifications — STALE entries fail the run, prune them
 python tools/lint_hazards.py spark_rapids_tpu
-# bench-JSONL stamp linter (tools/lint_metrics.py): every emit_record/
-# run_config call site stamps `kernels`, every raw JSONL record carries
-# backend/n_devices/kernels — the ROADMAP cross-cutting rule, enforced
-python tools/lint_metrics.py
 # concurrency linter (tools/lint_concurrency.py, docs/analysis.md#
 # concurrency-invariants): whole-tree lock-order graph (interprocedural
 # "calls F while holding L" edges, any cycle fails with a witness path),
@@ -31,7 +28,6 @@ python tools/lint_concurrency.py
 # certifier soundness/monotonicity; the nightly runs the deep sweep
 JAX_PLATFORMS=cpu python -m spark_rapids_tpu.analysis.fuzz --start 0 --count 24 --cpu
 python -m pytest tests/ -x -q
-python benchmarks/run_all.py --scale 0.002 --iters 2 --cpu
 python tools/monte_carlo.py --tasks 16 --parallelism 4 --gpu-mib 512 \
     --task-max-mib 384 --shuffle-threads 2 --seed 1
 echo "premerge OK"

@@ -31,7 +31,7 @@ The AST-level sibling is `tools/lint_hazards.py`: the codebase linter for
 the known JAX hazard patterns (self capture in jit closure caches,
 host-sync on traced values, tracer branches, env reads outside config.py,
 nondeterministic iteration feeding fingerprints, unlocked shared-state
-mutation), plus `tools/lint_metrics.py` for the bench-JSONL stamp rule.
+mutation), and `tools/lint_concurrency.py` for the lock-order graph.
 """
 from .footprint import (ResourceAdmissionError, ResourceCert, certify,
                         certify_nodes)

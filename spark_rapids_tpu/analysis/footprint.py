@@ -44,9 +44,9 @@ slack, so their byte observations measure padding, not live data — rows
 remain comparable everywhere). The fuzzer's property 5
 (`analysis/fuzz.py`) asserts this on every seeded random DAG, cold and
 warm, plus MONOTONICITY: an optimizer rewrite may only keep or tighten
-the root's certified bound. `benchmarks/footprint_bench.py` asserts it
-nightly on NDS q5/q72 and reports the bound-tightness ratio
-(certified/observed) to JSONL.
+the root's certified bound. tests/test_footprint.py asserts it on a
+join-aggregate plan in both tiers; how tight the bounds are
+(certified/observed) is reported nowhere yet.
 
 Three consumers (docs/analysis.md#resource-certifier):
 
@@ -535,8 +535,8 @@ def check_observed(cert: ResourceCert, result) -> Optional[str]:
     against (the cert must have been built with the run's n_peers, as
     `PlanExecutor.execute` does for the cert it stamps on the result).
     Returns the first violation as a string, None when sound — fuzz
-    property 5, the nightly footprint gate, and the exchange-transport
-    gate (benchmarks/exchange_bench.py) all call this."""
+    property 5 and the exchange-transport tests
+    (tests/test_plan_distributed.py) call this."""
     for lbl, m in result.metrics.items():
         b = cert.by_label.get(lbl)
         if b is None:

@@ -39,7 +39,7 @@ only, no global RNG, no time — so the premerge corpus (fixed seeds, see
 ci/premerge.sh) is reproducible and a nightly failure replays from its
 seed alone. CI knobs: `python -m spark_rapids_tpu.analysis.fuzz --start S
 --count N [--max-ops K] [--no-exec] [--cpu]`; the nightly deep sweep
-(benchmarks/plan_fuzz.py) runs >=200 seeds and emits a JSONL summary.
+(ci/nightly.sh) runs 200 seeds from 1000 through this same entry point.
 """
 from __future__ import annotations
 

@@ -674,9 +674,9 @@ def lockdep() -> bool:
     wraps every engine-constructed lock in a tracing proxy that records
     per-thread held-set -> acquired edges and raises LockOrderViolation
     on the first observed ordering cycle. Armed suite-wide by
-    tests/conftest and in the fleet chaos soak; off (default) means zero
+    tests/conftest when the variable is set; off (default) means zero
     overhead. Note the knob is latched where the witness is INSTALLED
-    (conftest / chaos_soak read it once before importing the engine, so
+    (conftest reads it once before importing the engine, so
     module-level locks get wrapped) — flipping it mid-process does not
     re-wrap existing locks."""
     return os.environ.get("SPARK_RAPIDS_TPU_LOCKDEP", "0") not in (
@@ -689,7 +689,7 @@ def place_compile_cache() -> str:
     other directory is set in code; where it is not, the cache is
     `<checkout>/.jax_cache` — a fixed path (the path is part of the cache
     key: one built from a temp name, pid or time never hits). The one
-    placement rule shared by chip_smoke.py, bench.py and tests/conftest."""
+    placement rule shared by chipbench, chip_smoke.py and tests/conftest."""
     env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if env:
         return env

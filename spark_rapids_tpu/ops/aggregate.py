@@ -105,15 +105,15 @@ def _groupby_kernel(key_operands, agg_datas, agg_valids, *, n_ops: int,
     """Scatter-free, gather-free sorted aggregation (round-4 redesign).
 
     On-chip primitive costs (round-2 TPU measurement, recorded in
-    docs/architecture.md:39-42; the reproducible sweep tool is
-    tools/tpu_primitives.py, whose committed CPU capture is
-    tools/primitives.jsonl — not re-measured on the chip since;
+    docs/architecture.md "Sorts, cumsums and gathers"; the sweep
+    tool and its CPU capture left the tree with PR 30 and are in git
+    history — the chip numbers were not re-measured since;
     10M rows): sort ≈ 38 ms with cheap marginal payload operands, cumsum ≈
     16 ms, but a RANDOM GATHER ≈ 160 ms and a random scatter ≈ 930 ms. The
     tradeoff is BACKEND-SPECIFIC: on CPU a random scatter-add costs ~163 ms
-    against ~233 ms per tuple-carry scan (primitives.jsonl), so this design
-    measures ~0.49× the old scatter-based kernel there (tools/
-    ab_relational.jsonl) — the win this layout buys exists on TPU, where
+    against ~233 ms per tuple-carry scan (same CPU capture), so this design
+    measures ~0.49× the old scatter-based kernel there (an A/B of the
+    same vintage) — the win this layout buys exists on TPU, where
     scatters are ~25× a cumsum; `_use_scan_kernel` therefore dispatches
     the segment/scatter design (_groupby_kernel_scatter) on CPU, so CPU
     users no longer pay the regression. The
@@ -335,10 +335,10 @@ def _groupby_kernel_scatter(key_operands, agg_datas, agg_valids, *,
     come from a cumsum of the run boundaries and every aggregate is one
     `jax.ops.segment_{sum,min,max}` — a data-sized random scatter-add.
     That is the round-3 design this file replaced for TPU, kept here
-    because the tradeoff is BACKEND-SPECIFIC (tools/primitives.jsonl, CPU:
-    scatter-add ~163 ms vs ~233 ms per tuple-carry scan at 10M rows; the
-    scan design measured ~0.49x the scatter kernel on CPU in tools/
-    ab_relational.jsonl). `_use_scan_kernel` picks per backend, like
+    because the tradeoff is BACKEND-SPECIFIC (the CPU capture cited at
+    _groupby_kernel: scatter-add ~163 ms vs ~233 ms per tuple-carry scan
+    at 10M rows; the scan design measured ~0.49x the scatter kernel on
+    CPU in the same A/B). `_use_scan_kernel` picks per backend, like
     row_conversion's _use_word_kernel.
 
     Dead rows under `has_alive` sort last as their own groups (the leading
@@ -770,7 +770,7 @@ def groupby_aggregate_capped(table: Table,
 # ---- kernel-registry wiring (ops/registry.py, docs/kernels.md) --------------
 # the scan design is the universal lowering (TPU-first: scatters are ~25x a
 # cumsum there); the scatter/segment design registers for the cpu backend,
-# where it measured ~2x the scan design (tools/ab_relational.jsonl)
+# where it measured ~2x the scan design (a CPU A/B, see _groupby_kernel)
 from .registry import REGISTRY as _REGISTRY  # noqa: E402
 
 _REGISTRY.register("groupby", "scan", fn=_groupby_kernel, fallback=True)

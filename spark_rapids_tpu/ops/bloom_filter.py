@@ -97,8 +97,8 @@ def bloom_filter_put(bf: BloomFilter, col: Column,
     into a VMEM-resident bits buffer is exactly what XLA's sorted-scatter
     lowering already emits, minus its run-length coalescing of duplicate
     words. The sort+scatter formulation IS the TPU-native atomicOr
-    (benchmarks/bench_bloom_filter.py carries the A/B of both scatter
-    modes)."""
+    (tests/test_bloom_filter.py holds both scatter modes to the same
+    bits; neither is measured on the chip)."""
     if col.dtype.kind != Kind.INT64:
         raise TypeError("bloom filter input must be INT64")
     idx = _spark_bit_indexes(col.data, bf.num_hashes, bf.num_bits)

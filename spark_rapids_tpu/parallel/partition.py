@@ -19,7 +19,7 @@ replacement for `build_partition_map` (one int32 set-scatter builds the
 the emulated-u64 add-scatter the measurement flagged).
 
 The Pallas explicit-kernel tier of the same histogram lives in
-parallel/partition_pallas.py; benchmarks/bench_partition.py A/Bs all three.
+parallel/partition_pallas.py; tests/test_partition.py holds all three equal.
 """
 from __future__ import annotations
 

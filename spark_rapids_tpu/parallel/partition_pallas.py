@@ -15,8 +15,8 @@ measurement flagged at 10M rows.
 
 A/B status: CPU-validated (interpret mode) against partition_histogram and
 compiled for v5e by tests/test_chip_compile.py; not measured on the chip.
-benchmarks/bench_partition.py captures sort-based vs scan vs this kernel
-when run on hardware.
+No plan reaches this kernel and no pair of it against the sort-based or
+scan design has run on hardware (ROADMAP D4a).
 """
 from __future__ import annotations
 

@@ -32,8 +32,8 @@ consumer feeds decisions the engine already guards for semantic
 neutrality (build-side swaps re-verify through `verify_rewrite`, caps
 are starting capacities the overflow ladder would have grown anyway,
 chunking is merge-exact, kernels are parity-gated), and the fuzzer's
-two-run check (`analysis/fuzz.py`) plus the nightly adaptive gate
-(`benchmarks/adaptive_bench.py`) hold that line bit-exactly.
+two-run check (`analysis/fuzz.py`) plus tests/test_adaptive.py's
+cold == warm == adaptivity-off parity hold that line bit-exactly.
 
 Backend isolation is a correctness rule, not bookkeeping: a degraded
 (breaker-tripped) plan finishes on the CPU tier, and its stats record

@@ -2,7 +2,7 @@
 soundness tier (docs/analysis.md#concurrency-invariants).
 
 `install()` (armed by ``SPARK_RAPIDS_TPU_LOCKDEP=1`` — tests/conftest
-for tier-1, benchmarks/chaos_soak for the fleet storm) monkeypatches
+for tier-1, when the variable is exported around pytest) monkeypatches
 the ``threading.Lock``/``RLock`` factories so every lock CONSTRUCTED
 from engine code is wrapped in a tracing proxy. Like kernel lockdep,
 locks are bucketed into CLASSES by construction site (``path:line`` —
@@ -271,7 +271,7 @@ def _caller_site() -> Optional[str]:
     here = os.path.abspath(__file__)
     f = sys._getframe(2)
     while f is not None:
-        # normalize: a relative sys.path entry (benchmarks insert ".")
+        # normalize: a relative sys.path entry (a script's insert of ".")
         # leaves "/repo/./pkg/..." in co_filename, defeating the
         # prefix check below
         fn = os.path.abspath(f.f_code.co_filename)

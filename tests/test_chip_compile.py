@@ -8,8 +8,8 @@ v5e:2x2 device by the TPU compiler installed here
 Interpret-mode parity (tests/test_kernel_registry.py) cannot see what
 Mosaic refuses — block shapes, 64-bit values, boolean loop carries — and
 `interpret = jax.default_backend() != "tpu"` keeps every other test off
-that path. A compile that passes here is not a chip run: `chip_smoke.py`
-is.
+that path. A compile that passes here is not a chip run: a cell of
+`chipbench` is.
 
 The topology is described inside a module-scoped fixture (never at import:
 only one process may hold libtpu, and every xdist worker imports this
@@ -28,7 +28,7 @@ from jax.sharding import SingleDeviceSharding
 import spark_rapids_tpu  # noqa: F401  (x64 on — the regime Mosaic sees)
 from spark_rapids_tpu import Column, Table, dtypes
 
-# the smoke's shapes (chip_smoke.py / benchmarks/bench_nds_q3.py at scale 1)
+# a 10M-row fact table against examples/nds.py's q3 date dimension
 N_FACT = 10_000_000
 N_DATES = 3_650
 N_DATES_KEPT = 310          # d_moy == 11: the hash join's build side
@@ -180,7 +180,7 @@ def test_partition_histogram_compiles_for_v5e(chip):
 
 
 def test_row_hash_compiles_for_v5e(chip):
-    # bench.py's table: 10M rows x 2 int64, murmur3_32 + xxhash64 fused
+    # 10M rows x 2 int64, murmur3_32 + xxhash64 fused
     from spark_rapids_tpu.ops import hash_pallas
     t = Table([_i64(N_FACT), _i64(N_FACT)], names=["a", "b"])
     n = chip(lambda: hash_pallas.fused_row_hash(t, interpret=True))
@@ -216,11 +216,11 @@ def test_capped_q3_program_compiles_for_v5e(one_chip, no_persistent_cache,
                                             monkeypatch):
     """The capped tier's one whole-plan program for q3, traced as the chip
     would trace it (registry and kernels see backend "tpu") and compiled
-    for the described device. At the bench's FLOOR size (8,192 fact rows):
-    XLA's TPU compiler spends minutes on this program's nine sorts at any
-    size, and this is already the slowest test of the file — the smoke
-    compiles it at 10M rows on the chip."""
-    from benchmarks.nds_plans import q3_inputs, q3_plan
+    for the described device. At 8,192 fact rows: XLA's TPU compiler
+    spends minutes on this program's nine sorts at any size, and this is
+    already the slowest test of the file — the cell `q3.tasks` compiles
+    it at 1.44M rows on the chip."""
+    from examples.nds import q3_inputs, q3_plan
     from spark_rapids_tpu.plan import PlanExecutor
 
     n = 8192

@@ -3,11 +3,11 @@ the full pipeline on the four NDS plans with optimizer-on/off parity in
 both executor tiers, idempotence, and fingerprint-keyed program reuse.
 
 Parity chains: test_plan_nds.py already runs the NDS plans with the
-optimizer ON (the default) against the hand-wired pandas-oracled
-pipelines; here the OFF runs close the loop (on == off == oracle). The
-full 4-query capped on/off matrix is `slow` (one XLA trace per variant)
-and runs in the nightly tier plus benchmarks/optimizer_parity.py; the
-timed tier keeps the cheaper eager matrix and one capped query.
+optimizer ON (the default) against the pandas references of
+examples/nds.py; here the OFF runs close the loop (on == off == pandas).
+The full 4-query capped on/off matrix is `slow` (one XLA trace per
+variant) and runs in ci/premerge.sh and ci/nightly.sh; the timed tier
+keeps the cheaper eager matrix and one capped query.
 """
 import numpy as np
 import pytest
@@ -582,13 +582,10 @@ N = 2500
 
 
 def _nds_cases():
-    from benchmarks.bench_nds_q3 import build_tables as bt3
-    from benchmarks.bench_nds_q5 import build_tables as bt5
-    from benchmarks.bench_nds_q23 import build_tables as bt23
-    from benchmarks.bench_nds_q72 import build_tables as bt72
-    from benchmarks.nds_plans import (q3_inputs, q3_plan, q5_inputs,
-                                      q5_plan, q23_inputs, q23_plan,
-                                      q72_inputs, q72_plan)
+    from examples.nds import (q3_inputs, q3_plan, q3_tables as bt3,
+                              q5_inputs, q5_plan, q5_tables as bt5,
+                              q23_inputs, q23_plan, q23_tables as bt23,
+                              q72_inputs, q72_plan, q72_tables as bt72)
     return {
         "q3": (q3_plan, lambda: q3_inputs(*bt3(N, seed=7)), None),
         "q5": (q5_plan, lambda: q5_inputs(*bt5(N, seed=3)),
@@ -616,7 +613,7 @@ def test_nds_eager_parity_and_rules_fired(q):
 
 
 @pytest.mark.slow   # q23/q72 eager = many per-op dispatches x 4 runs; the
-# nightly tier runs these and the optimizer-parity stage re-runs all 4
+# nightly tier runs these
 @pytest.mark.parametrize("q", ["q23", "q72"])
 def test_nds_eager_parity_and_rules_fired_slow(q):
     _eager_parity(q)
@@ -631,7 +628,7 @@ def test_nds_capped_parity_on_vs_off(q):
 
 
 @pytest.mark.slow   # two whole-plan XLA traces per query: the timed tier
-# covers q3 above and the nightly optimizer-parity stage re-runs all 4
+# covers q3 above and the nightly tier runs all 4
 @pytest.mark.parametrize("q", ["q5", "q23", "q72"])
 def test_nds_capped_parity_on_vs_off_slow(q):
     mk_plan, mk_inputs, caps = _nds_cases()[q]

@@ -193,8 +193,8 @@ class TestCoPlacementExecution:
         join = next(m for m in res.metrics.values()
                     if m.kind == "HashJoin")
         # the join consumed the pending handle: overlap is measured
-        # there (>= 0 by construction; > 0 is the bench's gate —
-        # benchmarks/coplace_bench.py — not a unit-test timing assert)
+        # there (>= 0 by construction; > 0 is a timing, which no unit
+        # test asserts)
         assert join.placement_overlap_ms >= 0.0
         assert res.optimizer["rules_fired"].get("placement", 0) >= 1
 

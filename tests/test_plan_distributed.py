@@ -513,11 +513,8 @@ def test_nds_q72_distributed_parity_pack_on_and_off():
     """NDS q72 through the distributed tier with packing forced on and
     forced off: identical results both ways (and identical to the
     single-device tier), with the packed run compressing at least one
-    edge. q5 runs in the nightly exchange gate
-    (benchmarks/exchange_bench.py) — one NDS plan keeps this inside the
-    tier-1 budget."""
-    from benchmarks.bench_nds_q72 import build_tables as bt72
-    from benchmarks.nds_plans import q72_inputs, q72_plan
+    edge. One NDS plan keeps this inside the tier-1 budget."""
+    from examples.nds import q72_inputs, q72_plan, q72_tables as bt72
     mesh = _mesh()
     inputs = q72_inputs(*bt72(4000, seed=5))
     plan = q72_plan()

@@ -1,114 +1,94 @@
-"""NDS q3/q5/q23/q72 through the plan engine, with parity against the
-hand-wired pipelines (the same functions test_nds_query.py oracles against
-pandas — so plan-engine parity chains to the pandas oracle transitively).
-Each query runs BOTH tiers: eager (per-operator dispatch) and capped (one
-XLA program, plan-granularity cap escalation)."""
+"""NDS q3/q5/q23/q72 through the plan engine, each plan in BOTH tiers —
+eager (per-operator dispatch) and capped (one XLA program,
+plan-granularity cap escalation) — against the pandas reference of
+examples/nds.py, which shares no line with the engine."""
 import json
 
-import numpy as np
+import pandas as pd
 import pytest
 
 import spark_rapids_tpu  # noqa: F401
 from spark_rapids_tpu import faultinj
 from spark_rapids_tpu.plan import PlanExecutor
 
-from benchmarks.nds_plans import (q3_inputs, q3_plan, q5_inputs, q5_plan,
-                                  q23_inputs, q23_plan, q72_inputs,
-                                  q72_plan)
+from examples import nds
 
 # 15k keeps this file inside the timed tier-1 budget now that every
 # executor run also optimizes (and capped runs trace the larger rewritten
 # DAGs); parity at this N exercises the same shapes and assertions
 N = 15_000
 
+# query -> (seed, capped-tier caps, presentation-sort columns)
+QUERIES = {
+    "q3": (7, {}, ["d_year", "revenue"]),
+    "q5": (3, {"key_cap": 2048}, ["channel", "sales"]),
+    "q23": (11, {"key_cap": 8192, "row_cap": N}, ["total"]),
+    "q72": (5, {}, ["cnt", "i_item_sk", "w_warehouse_sk", "d_week"]),
+}
 
-def test_nds_q3_plan_parity():
-    from benchmarks.bench_nds_q3 import build_tables, q3
-    sales, dates, items = build_tables(N, seed=7)
-    ref = q3(sales, dates, items).to_pydict()
-    plan = q3_plan()
-    inputs = q3_inputs(sales, dates, items)
 
-    res = PlanExecutor(mode="eager").execute(plan, inputs)
-    assert res.table.to_pydict() == ref
+def bound(query, n, seed):
+    """-> (plan, inputs) of one query over its generated tables."""
+    tables = getattr(nds, f"{query}_tables")(n, seed)
+    return (getattr(nds, f"{query}_plan")(),
+            getattr(nds, f"{query}_inputs")(*tables))
 
-    resc = PlanExecutor(mode="capped").execute(plan, inputs)
-    assert resc.compact().to_pydict() == ref
 
-    # per-operator metrics are real numbers, in both tiers. Metrics cover
-    # the EXECUTED plan (res.plan) — the optimizer rewrites the authored
-    # tree (e.g. pruning q3's unused item columns), so node counts differ
-    for r in (res, resc):
-        prof = {m["label"]: m for m in r.profile()}
-        assert len(prof) == len(r.plan.nodes)
+def assert_matches(res, ref, ordered):
+    got = res.compact() if res.mode == "capped" else res.table
+    assert list(got.names) == list(ref.columns)
+    nds.assert_rows_equal(pd.DataFrame(got.to_pydict()), ref, ordered,
+                          res.mode)
+
+
+@pytest.mark.parametrize("mode", ["eager", "capped"])
+@pytest.mark.parametrize("query", list(QUERIES))
+def test_nds_plan_matches_pandas(query, mode):
+    seed, caps, ordered = QUERIES[query]
+    ref = getattr(nds, f"{query}_reference")(N, seed)
+    res = PlanExecutor(mode=mode, caps=caps).execute(*bound(query, N, seed))
+    assert_matches(res, ref, ordered)
+    if query == "q23":
+        assert ref.total[0] > 0           # the HAVING clauses selected rows
+    if query == "q72" and mode == "capped":
+        assert res.attempts == 1          # default caps fit: no escalation
+    if query == "q3":
+        # per-operator metrics are real numbers, in both tiers. Metrics
+        # cover the EXECUTED plan (res.plan) — the optimizer rewrites the
+        # authored tree (e.g. pruning q3's unused item columns), so node
+        # counts differ
+        prof = {m["label"]: m for m in res.profile()}
+        assert len(prof) == len(res.plan.nodes)
         agg = next(m for m in prof.values() if m["kind"] == "HashAggregate")
-        assert agg["rows_out"] == len(ref["revenue"])
+        assert agg["rows_out"] == len(ref)
         assert agg["bytes_out"] > 0
-    assert res.optimizer is not None and res.optimizer["rules_fired"]
-    join1 = next(m for m in res.profile() if m["kind"] == "HashJoin")
-    assert join1["wall_ms"] is not None and join1["wall_ms"] > 0
+        if mode == "eager":
+            assert res.optimizer is not None and res.optimizer["rules_fired"]
+            join1 = next(m for m in res.profile() if m["kind"] == "HashJoin")
+            assert join1["wall_ms"] is not None and join1["wall_ms"] > 0
 
 
-def test_nds_q5_plan_parity():
-    from benchmarks.bench_nds_q5 import build_tables, q5
-    tabs, dates = build_tables(N, seed=3)
-    ref = q5(tabs, dates).to_pydict()
-    plan = q5_plan()
-    inputs = q5_inputs(tabs, dates)
-    assert PlanExecutor().execute(plan, inputs).table.to_pydict() == ref
-    resc = PlanExecutor(mode="capped", caps={"key_cap": 2048}).execute(
-        plan, inputs)
-    assert resc.compact().to_pydict() == ref
-
-
-def test_nds_q23_plan_parity_and_subquery_reuse():
-    from benchmarks.bench_nds_q23 import build_tables, q23_detail
-    store, sides = build_tables(N, seed=11)
-    det = q23_detail(store, sides)
-    plan = q23_plan()
-    inputs = q23_inputs(store, sides)
-
-    res = PlanExecutor().execute(plan, inputs)
-    assert res.table.to_pydict()["total"] == [int(det["total"])]
+def test_nds_q23_plan_subquery_reuse():
+    res = PlanExecutor().execute(*bound("q23", N, 11))
     # the two HAVING subqueries are SHARED DAG nodes: both sides reuse the
     # same Aggregate/Filter objects, so the executor ran each exactly once
     kinds = [m.kind for m in res.metrics.values()]
     assert kinds.count("HashAggregate") == 2 + 2 + 1  # freq, best, 2 side
     #                                                  totals, grand total
 
-    resc = PlanExecutor(mode="capped",
-                        caps={"key_cap": 8192, "row_cap": N}).execute(
-        plan, inputs)
-    assert resc.compact().to_pydict()["total"] == [int(det["total"])]
-
-
-def test_nds_q72_plan_parity():
-    from benchmarks.bench_nds_q72 import build_tables, q72
-    tabs = build_tables(N, seed=5)
-    ref = q72(*tabs).to_pydict()
-    plan = q72_plan()
-    inputs = q72_inputs(*tabs)
-    assert PlanExecutor().execute(plan, inputs).table.to_pydict() == ref
-    resc = PlanExecutor(mode="capped").execute(plan, inputs)
-    assert resc.compact().to_pydict() == ref
-    assert resc.attempts == 1          # default caps fit: no escalation
-
 
 def test_nds_q3_plan_cap_escalation():
     """Tiny caps on the real q3 shape: the plan executor escalates every
     capacity geometrically (SplitAndRetry at plan granularity) and the
     result still matches — never truncated output."""
-    from benchmarks.bench_nds_q3 import build_tables, q3
     # small n: each escalation attempt re-traces the whole plan at the new
     # caps, so the data size prices the test's compile bill
-    sales, dates, items = build_tables(5_000, seed=7)
-    ref = q3(sales, dates, items).to_pydict()
     ex = PlanExecutor(mode="capped", caps={"row_cap": 128, "key_cap": 16},
                       max_cap_attempts=10)
-    res = ex.execute(q3_plan(), q3_inputs(sales, dates, items))
+    res = ex.execute(*bound("q3", 5_000, 7))
     assert res.attempts > 1
     assert res.caps["row_cap"] > 128 and res.caps["key_cap"] > 16
-    assert res.compact().to_pydict() == ref
+    assert_matches(res, nds.q3_reference(5_000, 7), ["d_year", "revenue"])
     escal = [m.escalations for m in res.metrics.values()
              if m.kind in ("HashJoin", "HashAggregate")]
     assert all(e == res.attempts - 1 for e in escal)
@@ -117,20 +97,16 @@ def test_nds_q3_plan_cap_escalation():
 def test_nds_q3_plan_injected_fault_retries(tmp_path):
     """An injected operator fault on the NDS plan surfaces as a plan-level
     retry (bounded re-run, correct result), not corruption."""
-    from benchmarks.bench_nds_q3 import build_tables, q3
-    sales, dates, items = build_tables(5_000, seed=7)
-    ref = q3(sales, dates, items).to_pydict()
     cfg = tmp_path / "faultinj.json"
     cfg.write_text(json.dumps({"computeFaults": {
         "plan.HashAggregate": {"percent": 100, "injectionType": 1,
                                "interceptionCount": 1}}}))
     faultinj.install(str(cfg))
     try:
-        res = PlanExecutor().execute(q3_plan(),
-                                     q3_inputs(sales, dates, items))
+        res = PlanExecutor().execute(*bound("q3", 5_000, 7))
     finally:
         faultinj.uninstall()
-    assert res.table.to_pydict() == ref
+    assert_matches(res, nds.q3_reference(5_000, 7), ["d_year", "revenue"])
     agg = next(m for m in res.metrics.values()
                if m.kind == "HashAggregate")
     assert agg.retries == 1

@@ -94,7 +94,7 @@ class TestTyping:
         a plan carrying a STRING column through a bare-ref Project into
         such an aggregate is VALID and must ride the gate untouched;
         only data-buffer reductions (sum/mean) flag."""
-        from benchmarks.common import strings_column_from_list
+        from examples.nds import strings_column_from_list
         monkeypatch.setenv("SPARK_RAPIDS_TPU_VERIFY_PLANS", "1")
         s = strings_column_from_list([b"bb", b"aa", b"cc", b"aa"])
         k = Column(dtype=dtypes.INT64, length=4,
@@ -423,8 +423,11 @@ class TestFuzzer:
             kinds.update(gen_case(s).kinds)
         assert kinds == set(ALL_KINDS)
 
-    def test_small_corpus_verify_and_parity(self):
-        summary = run_corpus(range(8), execute=True)
+    @pytest.mark.parametrize("start", [0, 8, 16])
+    def test_corpus_verify_and_parity(self, start):
+        """The premerge corpus (seeds 0..23, every node kind: the test
+        above), eight seeds a case."""
+        summary = run_corpus(range(start, start + 8), execute=True)
         assert summary["cases"] == summary["executed"] == 8
         assert not summary["failures"], summary["failures"]
 
@@ -615,51 +618,3 @@ class TestHazardLinter:
         assert not open_findings, "\n".join(map(str, open_findings))
         stale = set(allow) - {f.key() for f in findings}
         assert not stale, f"stale allowlist entries: {sorted(stale)}"
-
-
-# ---------------------------------------------------------------------------
-# bench-JSONL stamp linter (tools/lint_metrics.py)
-# ---------------------------------------------------------------------------
-
-def _load_metrics_linter():
-    import sys
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    spec = importlib.util.spec_from_file_location(
-        "lint_metrics", os.path.join(root, "tools", "lint_metrics.py"))
-    mod = importlib.util.module_from_spec(spec)
-    sys.modules["lint_metrics"] = mod
-    spec.loader.exec_module(mod)
-    return mod
-
-
-class TestMetricsLinter:
-    def test_missing_kernels_stamp(self, tmp_path):
-        lint = _load_metrics_linter()
-        f = tmp_path / "bmod.py"
-        f.write_text(
-            "from benchmarks.common import emit_record, run_config\n"
-            "emit_record('b', {}, 1.0, 10)\n"
-            "run_config('b', {}, None, (), n_rows=1, kernels='fallback')\n")
-        findings = []
-        lint._lint_file(str(f), "benchmarks/bmod.py", findings)
-        assert len(findings) == 1 and "missing-kernels-stamp" in findings[0]
-        assert ":2:" in findings[0]
-
-    def test_raw_jsonl_stamp_and_error_exemption(self, tmp_path):
-        lint = _load_metrics_linter()
-        f = tmp_path / "raw.py"
-        f.write_text(
-            "import json\n"
-            "print(json.dumps({'bench': 'x', 'ms': 1}))\n"
-            "print(json.dumps({'bench': 'x', 'error': 'boom'}))\n"
-            "print(json.dumps({'bench': 'x', 'backend': 'cpu',\n"
-            "                  'kernels': 'fallback'}))\n")
-        findings = []
-        lint._lint_file(str(f), "benchmarks/raw.py", findings)
-        assert len(findings) == 1 and "raw-jsonl-missing-stamp" in \
-            findings[0]
-
-    def test_tree_is_clean(self):
-        """The premerge contract: benchmarks/ + bench.py fully stamped."""
-        lint = _load_metrics_linter()
-        assert lint.main([]) == 0

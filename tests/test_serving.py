@@ -548,10 +548,8 @@ def test_eight_sessions_mixed_nds_chaos_soak(tmp_path, _clean_faultinj):
     per-session bit-exact parity vs solo execution, bounded queue wait
     for every session, an over-quota reject labelled with operator +
     session before compilation, and >= 1 parity-checked cache hit."""
-    from benchmarks.bench_nds_q3 import build_tables as q3_tables
-    from benchmarks.bench_nds_q5 import build_tables as q5_tables
-    from benchmarks.nds_plans import (q3_inputs, q3_plan, q5_inputs,
-                                      q5_plan)
+    from examples.nds import (q3_inputs, q3_plan, q3_tables, q5_inputs,
+                              q5_plan, q5_tables)
     sales, dates3, items = q3_tables(2000, seed=7)
     tabs, dates5 = q5_tables(2000, seed=3)
     workload = {"q3": (q3_plan(), q3_inputs(sales, dates3, items)),

@@ -42,8 +42,7 @@ def _result(res):
 # ---- NDS streaming-vs-materialized parity -----------------------------------
 
 def test_nds_q5_parquet_parity_eager_and_capped(tmp_path):
-    from benchmarks.bench_nds_q5 import build_tables
-    from benchmarks.nds_plans import q5_inputs, q5_plan
+    from examples.nds import q5_inputs, q5_plan, q5_tables as build_tables
     tabs, dates = build_tables(N, seed=3)
     inputs = q5_inputs(tabs, dates)
     plan = q5_plan()
@@ -55,8 +54,8 @@ def test_nds_q5_parquet_parity_eager_and_capped(tmp_path):
 
 
 def test_nds_q72_parquet_parity_eager_and_capped(tmp_path):
-    from benchmarks.bench_nds_q72 import build_tables
-    from benchmarks.nds_plans import q72_inputs, q72_plan
+    from examples.nds import (q72_inputs, q72_plan,
+                              q72_tables as build_tables)
     inputs = q72_inputs(*build_tables(N, seed=5))
     plan = q72_plan()
     sources = _write_sources(tmp_path, inputs)

@@ -26,7 +26,7 @@ from spark_rapids_tpu.serving import ServingScheduler
 from spark_rapids_tpu.utils import span
 from spark_rapids_tpu.utils.tracing import text
 
-from benchmarks.nds_plans import q3_inputs, q3_plan
+from examples.nds import q3_inputs, q3_plan
 
 PKG = os.path.dirname(os.path.abspath(spark_rapids_tpu.__file__))
 
@@ -114,7 +114,7 @@ def _join_plan():
 
 
 def _tiny_q3(n=2000):
-    from benchmarks.bench_nds_q3 import build_tables
+    from examples.nds import q3_tables as build_tables
     return q3_plan(), q3_inputs(*build_tables(n, seed=7))
 
 
