@@ -27,7 +27,7 @@ from .parse_uri import (parse_uri_to_protocol, parse_uri_to_host,
                         parse_uri_to_query_column)
 from .histogram import create_histogram_if_valid, percentile_from_histogram
 from .map_utils import from_json
-from .gather import take, take_table, apply_boolean_mask
+from .gather import take, take_live, take_table, apply_boolean_mask
 from .sort import sort_table_capped, sorted_order, sort_table
 from .aggregate import groupby_aggregate, groupby_aggregate_capped
 from .join import (full_join, inner_join, inner_join_capped,
@@ -67,7 +67,8 @@ _ADMITTED_FACTORS = {
     "parse_uri_to_query_column": 2.0,
     "create_histogram_if_valid": 2.0, "percentile_from_histogram": 2.0,
     "from_json": 3.0,
-    "take": 2.0, "take_table": 2.0, "apply_boolean_mask": 2.0,
+    "take": 2.0, "take_live": 2.0, "take_table": 2.0,
+    "apply_boolean_mask": 2.0,
     "sorted_order": 2.0, "sort_table": 3.0, "sort_table_capped": 3.0,
     "groupby_aggregate": 2.0, "groupby_aggregate_capped": 2.0,
     "inner_join": 3.0, "inner_join_capped": 3.0,
@@ -109,7 +110,8 @@ __all__ = [
     "parse_uri_to_query_literal", "parse_uri_to_query_column",
     "create_histogram_if_valid", "percentile_from_histogram",
     "from_json",
-    "take", "take_table", "apply_boolean_mask", "sorted_order", "sort_table",
+    "take", "take_live", "take_table", "apply_boolean_mask", "sorted_order",
+    "sort_table",
     "sort_table_capped",
     "groupby_aggregate", "groupby_aggregate_capped",
     "inner_join", "inner_join_capped", "inner_join_capped_tail",

@@ -7,6 +7,11 @@ map_utils.cu:539-647; join gather maps in the plugin). TPU-first: one fused
 An index of -1 (OOB_NULL policy, like cudf's out-of-bounds-policy
 NULLIFY) yields a null output row — hash joins use this for outer-join
 non-matches.
+
+The capped tier's joins gather at a static cap of which a prefix is live:
+`take_live` / `gather_live` do that gather in chunks over the live rows
+only, the count read on the device (what a chunk and a slot cost on the
+chip is in PERF.md, PR 31).
 """
 from __future__ import annotations
 
@@ -83,6 +88,81 @@ def take(col: Column, idx: jnp.ndarray, check_bounds: bool = False,
     # fixed-width (incl. decimal128 limbs: take along axis 0 of (n,4))
     data = jnp.take(col.data, safe, axis=0)
     return Column(dtype=col.dtype, length=m, data=data, validity=validity)
+
+
+def live_chunk(m: int) -> int:
+    """Slots one step of `gather_live` gathers over an `m`-slot frame: a
+    sixty-fourth of the frame, rounded up to whole (8, 128) tiles of 32-bit
+    words, and never more than the frame. Measured on the chip at 360,000
+    slots (PERF.md, PR 31): a step costs about 4 us beside 8 ns a slot, so
+    of a sixteenth, a thirty-second and a sixty-fourth the finest wastes
+    least on the last chunk and loses nothing at a full frame."""
+    return min(-(-m // (64 * 1024)) * 1024, m)
+
+
+def live_slots(live: int, m: int) -> int:
+    """Slots `gather_live` touches for `live` live rows of an `m`-slot
+    frame (host arithmetic over counts already read back: the executor's
+    `gather_slots`)."""
+    c = live_chunk(m)
+    return min(-(-min(live, m) // c) * c, m) if m else 0
+
+
+def gather_live(planes, idx: jnp.ndarray, live) -> tuple:
+    """`[jnp.take(p, idx, axis=0) for p in planes]` where only the prefix
+    `idx[:live]` is wanted: the gather a capped join pays at its static
+    cap, done in proportion to the rows that are live. `live` is a device
+    scalar (a traced count, no host sync); the output keeps the static
+    shape `(m, ...)`.
+
+    A `fori_loop` over `ceil(live / C)` steps of `C = live_chunk(m)` slots:
+    each step slices `C` indices, gathers every plane there and writes the
+    chunk into the carried output. Slots of a touched chunk past `live`
+    gather whatever `idx` holds there (a capped join leaves 0); slots past
+    the last touched chunk are ZERO (False in a validity plane), never a
+    row of the source. When `m` is not a multiple of `C` the last step's
+    slice clamps back onto the frame's end (`dynamic_slice` and
+    `dynamic_update_slice` clamp alike) and gathers a few slots twice.
+    One loop per index vector: the compiler drops the planes nobody reads
+    from the loop's carry as it drops a dead `take`
+    (tests/test_chip_compile.py holds the count)."""
+    m = int(idx.shape[0])
+    planes = tuple(planes)
+    if m == 0 or not planes:
+        return tuple(jnp.take(p, idx, axis=0) for p in planes)
+    c = live_chunk(m)
+    steps = (jnp.clip(live, 0, m).astype(jnp.int32) + (c - 1)) // c
+
+    def step(i, outs):
+        at = i * jnp.int32(c)
+        ix = jax.lax.dynamic_slice_in_dim(idx, at, c)
+        return tuple(
+            jax.lax.dynamic_update_slice_in_dim(
+                o, jnp.take(p, ix, axis=0), at, axis=0)
+            for o, p in zip(outs, planes))
+
+    init = tuple(jnp.zeros((m,) + p.shape[1:], p.dtype) for p in planes)
+    return jax.lax.fori_loop(jnp.int32(0), steps, step, init)
+
+
+def take_live(cols, idx: jnp.ndarray, live) -> list:
+    """`[take(c, idx) for c in cols]` for a capped join's output: `idx`
+    is a (row_cap,) gather map without negatives whose live entries are
+    the prefix `[0, live)`, `live` a device scalar. The fixed-width
+    columns (decimal128's (n, 4) limbs too) go through ONE `gather_live`,
+    data and validity planes together; slots past the last touched chunk
+    come back zero and, in a nullable column, invalid. Strings, lists and
+    structs have no plane to chunk and take the plain gather."""
+    cols = list(cols)
+    chunked = [c.dtype.kind not in (Kind.STRING, Kind.STRUCT, Kind.LIST)
+               for c in cols]
+    got = iter(gather_live(
+        [p for c, ok in zip(cols, chunked) if ok
+         for p in (c.data, c.validity) if p is not None], idx, live))
+    return [Column(dtype=c.dtype, length=int(idx.shape[0]), data=next(got),
+                   validity=None if c.validity is None else next(got))
+            if ok else take(c, idx, _has_negative=False)
+            for c, ok in zip(cols, chunked)]
 
 
 def apply_boolean_mask(table_or_col, mask) -> Union[Table, Column]:

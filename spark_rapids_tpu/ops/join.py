@@ -40,7 +40,8 @@ line), and nowhere else.
    nothing needs expanding. One reverse `cummin` over the sorted frame
    hands every left row its run's matchable right row, ONE 32-bit sort
    packs the emitting left rows to the front in left-row order, and one
-   gather at the output cap reads the right row ids. The same pass decides
+   gather over the emitting rows (`gather_live`: whole chunks of the live
+   prefix, not the output cap) reads the right row ids. The same pass decides
    `unique` on the device, and a `lax.cond` picks the tail: no plan
    annotation, argument or switch says which join is which. Both tails
    return the same arrays, slot for slot.
@@ -61,6 +62,7 @@ import jax.numpy as jnp
 from .. import dtypes
 from ..columnar import Column, Table
 from ..utils.tracing import span
+from .gather import gather_live
 from .sort import _key_operands
 
 __all__ = ["inner_join", "left_join", "full_join", "left_semi_join",
@@ -317,7 +319,9 @@ def inner_join_capped(left_keys, right_keys, row_cap: int, *,
 
     Returns (lmap, rmap, valid, overflow): (row_cap,) int32 gather maps into
     the original frames (dead slots hold 0 and are masked by `valid`), a
-    (row_cap,) bool row mask, and a scalar overflow flag."""
+    (row_cap,) bool row mask whose live slots are the prefix `[0, total)`
+    (so `take_live` gathers the output columns over that prefix alone),
+    and a scalar overflow flag."""
     return inner_join_capped_tail(left_keys, right_keys, row_cap,
                                   lalive=lalive, ralive=ralive,
                                   null_equal=null_equal)[:4]
@@ -364,8 +368,9 @@ def _capped_inner_kernel(operands, lvalid, rvalid, *, n_ops: int, nl: int,
 
     unique  -> the many-to-one tail: ONE 32-bit sort packs the emitting left
                rows to the front in left-row order with their event as
-               payload, cut to `row_cap`, and one gather at the cap reads
-               the right row id off the union sort's iota.
+               payload, cut to `row_cap`, and one gather over the live
+               prefix (ops/gather.py:gather_live) reads the right row id
+               off the union sort's iota.
     else    -> the general tail: spans, routing sorts and `_expand`.
     Pair for pair the same (lmap, rmap, valid, overflow)."""
     n = operands[0].shape[0]
@@ -392,9 +397,12 @@ def _capped_inner_kernel(operands, lvalid, rvalid, *, n_ops: int, nl: int,
         emit = ~right & (f_s == 1) & (event % 2 == 0)
         key = jnp.where(emit, order, jnp.int32(n))     # left row, or past all
         lrow, hit = jax.lax.sort([key, event], num_keys=1)
-        at = (_fit(hit, row_cap) >> 1).astype(jnp.int32)
-        rmap = jnp.take(order, at, axis=0, mode="clip") - nl
-        return _fit(lrow, row_cap), rmap, jnp.sum(emit.astype(jnp.int64))
+        total = jnp.sum(emit.astype(jnp.int64))
+        # the emitting rows are the frame's prefix: read their right row
+        # ids, not the cap's (a dead slot's event may lie past the frame)
+        at = jnp.minimum(_fit(hit, row_cap) >> 1, n - 1).astype(jnp.int32)
+        (rrow,) = gather_live([order], at, total)
+        return _fit(lrow, row_cap), rrow - nl, total
 
     def general(_):
         counts, lo, rorder = _span_tail(boundary, order, m_s, lvalid, nl=nl,

@@ -605,6 +605,11 @@ class PlanResult:
         self.unique_joins = 0         # capped tier: sort joins that took the
         self.expand_joins = 0         # many-to-one tail / the expansion
         #                               (ops/join.py decides on the device)
+        self.gather_slots = 0         # capped tier, over the request's inner
+        self.cap_slots = 0            # joins: output slots their column
+        #                               gathers touched (whole chunks over
+        #                               the live rows, ops/gather.py) and
+        #                               the caps they would have paid
         self.cached = False           # served from the serving result cache
         #                               (serving/cache.py): True ONLY on a
         #                               cache-hit COPY — its metrics are
@@ -777,7 +782,9 @@ class PlanExecutor:
                 res.decimal_overflow_rows += int(sum(nulled))
             sp.set_metadata(decimal_overflow_rows=res.decimal_overflow_rows,
                             unique_joins=res.unique_joins,
-                            expand_joins=res.expand_joins)
+                            expand_joins=res.expand_joins,
+                            gather_slots=res.gather_slots,
+                            cap_slots=res.cap_slots)
             return res
 
     def _execute_request(self, plan, inputs, tier,
@@ -2388,6 +2395,12 @@ class PlanExecutor:
                  if k <= _JOIN_UNIQUE]
         res.unique_joins = sum(tails)
         res.expand_joins = len(tails) - res.unique_joins
+        from ..ops.gather import live_slots
+        for i, node in enumerate(plan.nodes):
+            if isinstance(node, HashJoin) and node.how == "inner":
+                cap = self._node_cap(final_caps, "row_cap", i)
+                res.gather_slots += live_slots(counts_np[i][1], cap)
+                res.cap_slots += cap
         return res
 
     def _capped_key(self, plan, caps, input_key) -> Tuple:
@@ -2527,10 +2540,11 @@ class PlanExecutor:
                     lm, rm, valid, ovf, unique = ops.inner_join_capped_tail(
                         lkeys, rkeys, row_cap=row_cap, lalive=l.alive,
                         ralive=r.alive)
-                cols = [ops.take(col, lm, _has_negative=False)
-                        for col in l.table.columns]
-                cols += [ops.take(col, rm, _has_negative=False)
-                         for col in r.table.columns]
+                # the live rows are a prefix of the capped frame: gather
+                # that prefix, whatever the cap (ops/gather.py:take_live)
+                live = jnp.sum(valid.astype(jnp.int32))
+                cols = ops.take_live(l.table.columns, lm, live) \
+                    + ops.take_live(r.table.columns, rm, live)
                 t = Table(cols, names=list(l.table.names) +
                           list(r.table.names))
                 return _CappedRel(t, valid, unique), ovf

@@ -212,13 +212,20 @@ def test_digest_fold_allocates_nothing_of_a_buffers_size(one_chip,
     assert mem.output_size_in_bytes < 1 << 12        # 2 x 16 bytes, tiled
 
 
-def test_capped_q3_program_compiles_for_v5e(one_chip, no_persistent_cache,
-                                            monkeypatch):
+def _op_names(text, opcode):
+    """The `op_name` of every `opcode` instruction of an executable's text."""
+    return [line.split("op_name=\"")[1].split("\"")[0]
+            for line in text.splitlines()
+            if f" {opcode}(" in line and "op_name=\"" in line]
+
+
+@pytest.fixture(scope="module")
+def capped_q3_compiled(one_chip, no_persistent_cache):
     """The capped tier's one whole-plan program for q3, traced as the chip
     would trace it (registry and kernels see backend "tpu") and compiled
     for the described device. At 8,192 fact rows: XLA's TPU compiler
     spends minutes on this program's nine sorts at any size, and this is
-    already the slowest test of the file — the cell `q3.tasks` compiles
+    already the slowest compile of the file — the cell `q3.tasks` compiles
     it at 1.44M rows on the chip."""
     from examples.nds import q3_inputs, q3_plan
     from spark_rapids_tpu.plan import PlanExecutor
@@ -244,21 +251,52 @@ def test_capped_q3_program_compiles_for_v5e(one_chip, no_persistent_cache,
             raise Captured()
         return stop, bm, km, hit
 
-    monkeypatch.setattr(PlanExecutor, "_jitted_capped", capturing)
-    ex = PlanExecutor(mode="capped", degrade="off",
-                      caps=dict(row_cap=max(n // 8, 1024), key_cap=4096))
-    with pytest.raises(Captured):
-        ex.execute(q3_plan(), inputs)
-    shapes = jax.tree.map(
-        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
-        captured["tables"])
-    # steer the trace from here, not through an option of the program:
-    # code that asks jax.default_backend() must take its TPU branch
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    compiled = captured["fn"].lower(shapes).compile()
-    mem = compiled.memory_analysis()
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(PlanExecutor, "_jitted_capped", capturing)
+        ex = PlanExecutor(mode="capped", degrade="off",
+                          caps=dict(row_cap=max(n // 8, 1024), key_cap=4096))
+        with pytest.raises(Captured):
+            ex.execute(q3_plan(), inputs)
+        shapes = jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=one_chip),
+            captured["tables"])
+        # steer the trace from here, not through an option of the program:
+        # code that asks jax.default_backend() must take its TPU branch
+        patch.setattr(jax, "default_backend", lambda: "tpu")
+        return captured["fn"].lower(shapes).compile()
+
+
+def test_capped_q3_program_compiles_for_v5e(capped_q3_compiled):
+    mem = capped_q3_compiled.memory_analysis()
     assert mem.temp_size_in_bytes + mem.argument_size_in_bytes \
         + mem.output_size_in_bytes < 16 * 1024 ** 3
+
+
+# the same program of the commit before the joins gathered their live
+# prefix (b742d4c, compiled here the same way): every column of a join's
+# output was its own flat gather, and the compiler dropped the unread ones
+Q3_GATHERS_FLAT = 34
+Q3_CODE_BYTES_FLAT = 21_528_064
+Q3_TASKS_PEAK_HBM = 0.497e9         # the cell's `peak_hbm_gb` (ledger, PR 30)
+
+
+def test_capped_q3_joins_gather_in_loops_and_unread_columns_stay_pruned(
+        capped_q3_compiled):
+    """Each join gathers its output columns in one loop per side, and the
+    many-to-one tail its right row ids in another (ops/gather.py:
+    gather_live): six `while`s under the two joins' scopes. A loop carries
+    every column of its side, and the compiler still drops the ones no
+    later operator reads (no more gathers than the flat form had). Code
+    lies in HBM beside the data: the loops may not cost 1% of the cell's
+    peak."""
+    text = capped_q3_compiled.as_text()
+    assert text.count(" gather(") <= Q3_GATHERS_FLAT
+    loops = _op_names(text, "while")
+    assert len(loops) == 6
+    assert all(".HashJoin/" in name for name in loops), loops
+    code = capped_q3_compiled.memory_analysis().generated_code_size_in_bytes
+    assert code - Q3_CODE_BYTES_FLAT < 0.01 * Q3_TASKS_PEAK_HBM
 
 
 def test_capped_join_compiles_with_both_tails_for_v5e(one_chip,
@@ -266,8 +304,8 @@ def test_capped_join_compiles_with_both_tails_for_v5e(one_chip,
     """The capped inner join (ops/join.py:_capped_inner_kernel) at a small
     shape: one conditional whose branches are both in the executable, the
     expansion (its two sorts and jnp.repeat's scatter-add) and the
-    many-to-one tail (its one sort, its one gather); the union sort is
-    shared, outside."""
+    many-to-one tail (its one sort, its one gather in a loop over the
+    live prefix); the union sort is shared, outside."""
     from spark_rapids_tpu.ops import join
     nl, nr, cap = 4096, 512, 1024
 
@@ -276,12 +314,11 @@ def test_capped_join_compiles_with_both_tails_for_v5e(one_chip,
     text = join._capped_inner_kernel.lower(
         (shape(nl + nr, jnp.int64),), shape(nl, jnp.bool_),
         shape(nr, jnp.bool_), n_ops=1, nl=nl, row_cap=cap).compile().as_text()
-    names = [line.split("op_name=\"")[1].split("\"")[0]
-             for line in text.splitlines()
-             if " sort(" in line and "op_name=\"" in line]
+    names = _op_names(text, "sort")
     kernel = "jit(_capped_inner_kernel)/"
     assert text.count(" conditional(") == 1
     assert sorted(names) == [kernel + "cond/branch_0_fun/sort"] * 2 \
         + [kernel + "cond/branch_1_fun/sort", kernel + "sort"]
     assert kernel + "cond/branch_0_fun/jit(_expand)/scatter-add" in text
-    assert kernel + "cond/branch_1_fun/jit(_take)/gather" in text
+    # the many-to-one tail's one gather runs in chunks over the live rows
+    assert kernel + "cond/branch_1_fun/while/body/jit(_take)/gather" in text

@@ -861,6 +861,78 @@ def test_capped_join_tail_edges(shape):
                                             else 0)
 
 
+# ---- take_live: a capped join's column gathers over its live prefix --------
+
+LIVE_CAP = 2560             # live_chunk(2560) is 1024: two whole chunks and
+#                             one that clamps back onto the frame's end
+LIVE_TOTALS = {"none": 0, "one": 1, "chunk_less_one": 1023, "chunk": 1024,
+               "chunk_and_one": 1025, "cap_less_one": 2559, "cap": 2560,
+               "over_cap": 3000}
+
+
+@pytest.mark.parametrize("total", list(LIVE_TOTALS))
+@pytest.mark.parametrize("maps", ["unique", "expand", "pallas"])
+def test_take_live_is_take_over_the_live_prefix(maps, total):
+    """The gather maps of a capped inner join (both tails of
+    `_capped_inner_kernel`, and the Pallas join in interpret mode) over an
+    int64, a nullable, a decimal128 and a string column: `take_live` equals
+    `take` on `[0, live)`, and every slot of a fixed-width column past the
+    last touched chunk is zero and, in a nullable one, invalid (never row 0
+    of the source)."""
+    import jax.numpy as jnp
+    from spark_rapids_tpu.ops import inner_join_capped_tail, take_live
+    from spark_rapids_tpu.ops.gather import live_chunk, live_slots
+    from spark_rapids_tpu.ops.join_pallas import inner_join_capped_pallas
+    want_total = LIVE_TOTALS[total]
+    rng = np.random.default_rng(want_total)
+    nl, nr, c = 3200, 50, live_chunk(LIVE_CAP)
+    assert c == 1024
+    rkeys = np.arange(nr, dtype=np.int64)
+    twice = 0
+    if maps == "expand":        # key 0 has two right rows: its left rows
+        rkeys = np.append(rkeys, 0)             # match twice
+        twice = 1 if want_total > 1 else 0
+    lkeys = np.full(nl, 1000, np.int64)         # matches nothing
+    hits = rng.permutation(nl)[:want_total - twice]
+    lkeys[hits] = rng.integers(1, nr, hits.size)
+    lkeys[hits[:twice]] = 0
+    lk, rk = col(lkeys), col(rkeys)
+    if maps == "pallas":
+        lm, rm, valid, ovf = inner_join_capped_pallas(
+            [lk], [rk], row_cap=LIVE_CAP, interpret=True)
+    else:
+        lm, rm, valid, ovf, unique = inner_join_capped_tail(
+            [lk], [rk], LIVE_CAP)
+        assert bool(unique) is (maps == "unique")
+    live = int(np.asarray(valid).sum())
+    assert live == min(want_total, LIVE_CAP)
+    assert bool(ovf) is (want_total > LIVE_CAP)
+    touched = live_slots(live, LIVE_CAP)
+    assert touched == min(-(-live // c) * c, LIVE_CAP)
+
+    def side(n, seed):
+        r = np.random.default_rng(seed)
+        return [col(r.integers(1, 1 << 40, n)),
+                col(r.integers(1, 99, n), nulls=r.random(n) < 0.3),
+                Column.from_pylist(
+                    [None if v % 5 == 0 else int(v) << 70 | 1
+                     for v in r.integers(1, 1 << 30, n)],
+                    dtypes.decimal(38, 2)),
+                scol([None if v % 7 == 0 else "s%d" % v
+                      for v in r.integers(1, 99, n)])]
+    for cols, idx in ((side(nl, 1), lm), (side(len(rkeys), 2), rm)):
+        got = take_live(cols, idx, jnp.sum(valid))
+        for g, src in zip(got, cols):
+            assert g.length == LIVE_CAP and g.dtype == src.dtype
+            assert g.to_pylist()[:live] == take(src, idx).to_pylist()[:live]
+            if src.dtype.is_string:     # no plane to chunk: the plain take
+                continue
+            assert not np.asarray(g.data)[touched:].any()
+            assert (g.validity is None) == (src.validity is None)
+            if g.validity is not None:
+                assert not np.asarray(g.validity)[touched:].any()
+
+
 @pytest.mark.parametrize("fan_out", [True, False])
 def test_capped_plan_stamps_each_join_with_its_tail(fan_out):
     """(f) a q72-shaped plan in the capped tier: the dimension joins read

@@ -397,6 +397,42 @@ def test_plan_execute_span_counts_the_joins_by_tail(session):
                 if m.kind == "HashJoin"] == [label]
 
 
+def test_plan_execute_span_counts_the_slots_the_joins_gathered(session):
+    """A capped join gathers its output columns over whole chunks of its
+    live rows (ops/gather.py:gather_live), not over its cap: the first
+    join of this plan keeps every fact row (three chunks of four), the
+    second under one chunk, and `gather_slots` / `cap_slots` say so on the
+    result and on `plan.execute`."""
+    from spark_rapids_tpu.ops.gather import live_chunk
+    cap = 4096
+    assert live_chunk(cap) == 1024
+    fact = _fact(n=3000)
+    few = int((np.asarray(fact["k"].data) % 7 == 0).sum())
+    assert 0 < few < 1024
+    b = PlanBuilder()
+    plan = (b.scan("t", schema=["k", "v"])
+             .join(b.scan("d", schema=["dk", "g"]), left_on="k",
+                   right_on="dk")
+             .join(b.scan("z", schema=["zk", "w"]), left_on="g",
+                   right_on="zk")
+             .aggregate(["w"], [("v", "sum", "total")]).build())
+    inputs = {"t": fact, "d": _dim(),
+              "z": Table([_col([0]), _col([5])], names=["zk", "w"])}
+    ex = PlanExecutor(mode="capped", optimize=False,
+                      caps=dict(row_cap=cap, key_cap=64))
+    ex.execute(plan, inputs)                              # compile outside
+    done = []
+    spans = session(lambda: done.append(ex.execute(plan, inputs)))
+    (res,), got = done, spans.one("plan.execute")
+    assert [m.rows_out for m in res.metrics.values()
+            if m.kind == "HashJoin"] == [3000, few]
+    want = (3072 + 1024, 2 * cap)
+    assert (res.gather_slots, res.cap_slots) == want
+    assert (got["gather_slots"], got["cap_slots"]) == want
+    assert int(np.asarray(res.compact()["total"].data)[0]) == int(
+        np.asarray(fact["v"].data)[np.asarray(fact["k"].data) % 7 == 0].sum())
+
+
 def test_device_op_owners_is_the_capped_tiers():
     plan, inputs = _join_plan(), {"t": _fact(), "d": _dim()}
     with pytest.raises(Exception, match="capped tier"):
