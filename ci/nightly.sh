@@ -40,6 +40,6 @@ JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8 \
 # Multi-PROCESS mesh proof (jax.distributed, 2 procs x 4 CPU devices) runs
 # in the pytest tier above: tests/test_multiproc_mesh.py.
 # The chip is not reached from here: the benchmark (`python3 -m
-# chipbench.run`) and `python chip_smoke.py --chips 4` run through the
+# chipbench.run`, the four-chip cell `q5.shuffle` too) runs through the
 # builder's chip tool (README "Testing & benchmarking").
 echo "nightly OK"

@@ -9,7 +9,7 @@ q3, q5, q23 and q72, each as
 - `qN_reference(n_sales, seed)`: the answer by pandas on the host over the
   same arrays. It shares no line with the engine: a plan run through
   `PlanExecutor`, in any tier, is held to it with `assert_rows_equal`
-  (tests/test_plan_nds.py, chip_smoke.py).
+  (tests/test_plan_nds.py).
 
 The benchmark's q3 and q72 at their measured sizes live in `chipbench/`
 and import nothing from here.
@@ -19,9 +19,11 @@ Shapes worth noticing:
   the COMPOSITE (item, week) key, the physical plan a CBO picks and the
   shape that keeps the capped tier fan-out-free. Its reference joins on
   the item alone and filters the week afterwards: same rows, other route.
-- q5: per-channel Union → semi-join date window → rollup via a shared
-  Union feeding two aggregates (channel subtotals + the const-key grand
-  total).
+- q5: the query template whole: per-channel Union → join to the 15-day
+  date window → join to the channel's dimension → sums by business id;
+  web returns take their site from `web_sales` by the composite
+  (item, order) key; ROLLUP (channel, id) as three aggregates over one
+  shared Union, ORDER BY channel, id, LIMIT 100.
 - q23: the two expensive subqueries are SHARED DAG nodes — both sides
   semi-join the same `freq`/`best` objects, so the executor computes each
   once per run (the subquery-reuse that is the whole point of q23); the
@@ -141,118 +143,214 @@ def q3_reference(n_sales: int, seed=0):
              [["d_year", "i_brand", "revenue"]])
 
 
-# ---- q5: multi-channel union → date window → rollup -------------------------
+# ---- q5: the query template's full shape ------------------------------------
+#
+# TPC-DS query5.tpl: per channel, sales UNION ALL returns (each with the
+# other's measures as zeros) joined to the 15-day date window and to the
+# channel's dimension, summed by the dimension's BUSINESS id (several
+# surrogate keys map to one); a web return takes its site from the sale it
+# returns (`web_returns LEFT OUTER JOIN web_sales ON (item, order)`, which
+# the `web_site` join above it turns into an inner join: the plan joins
+# inner, the reference merges `how="left"` as written); then
+# ROLLUP (channel, id), ORDER BY channel, id, LIMIT 100. Channel names are
+# codes in the template's alphabetical order and the subtotal rows' NULLs
+# are -1, which sorts where Spark puts nulls: first.
 
-DATE_LO, DATE_HI = 700, 714          # the 14-day window of the real q5
+Q5_CHANNELS = ("catalog", "store", "web")       # codes 0, 1, 2
+Q5_SALES_DATE = np.datetime64("2000-08-23")
+Q5_DATE_LO = int(Q5_SALES_DATE.astype("datetime64[D]").astype(np.int64))
+Q5_DATE_HI = Q5_DATE_LO + 14                    # BETWEEN is inclusive
+Q5_JULIAN = 2440588                             # d_date_sk of 1970-01-01
+# channel -> (sales table, its four columns: dimension key, date, price,
+# profit; returns table, its four; dimension table, its key and its id)
+Q5_SHAPE = {
+    "store": ("store_sales", ["ss_store_sk", "ss_sold_date_sk",
+                              "ss_ext_sales_price", "ss_net_profit"],
+              "store_returns", ["sr_store_sk", "sr_returned_date_sk",
+                                "sr_return_amt", "sr_net_loss"],
+              "store", "s_store_sk", "s_store_id"),
+    "catalog": ("catalog_sales", ["cs_catalog_page_sk", "cs_sold_date_sk",
+                                  "cs_ext_sales_price", "cs_net_profit"],
+                "catalog_returns", ["cr_catalog_page_sk",
+                                    "cr_returned_date_sk",
+                                    "cr_return_amount", "cr_net_loss"],
+                "catalog_page", "cp_catalog_page_sk", "cp_catalog_page_id"),
+    "web": ("web_sales", ["ws_web_site_sk", "ws_sold_date_sk",
+                          "ws_ext_sales_price", "ws_net_profit"],
+            "web_returns", ["ws_web_site_sk", "wr_returned_date_sk",
+                            "wr_return_amt", "wr_net_loss"],
+            "web_site", "web_site_sk", "web_site_id"),
+}
+Q5_ROWS = ["sk", "date_sk", "sales_price", "profit", "return_amt", "net_loss"]
+Q5_RESULT = ["channel", "id", "sales", "returns", "profit"]
 
 
 def q5_plan():
     b = PlanBuilder()
-    dates = (b.scan("dates", schema=["d_date_sk"])
-             .filter((col("d_date_sk") >= DATE_LO) &
-                     (col("d_date_sk") < DATE_HI)))
-    sums = [("sales", "sum", "sales"), ("returns", "sum", "returns"),
-            ("profit", "sum", "profit"), ("loss", "sum", "loss")]
+    dates = (b.scan("date_dim", schema=["d_date_sk", "d_date"])
+             .filter((col("d_date") >= Q5_DATE_LO) &
+                     (col("d_date") <= Q5_DATE_HI)))
+    web_sales = b.scan("web_sales", schema=[
+        "ws_sold_date_sk", "ws_web_site_sk", "ws_ext_sales_price",
+        "ws_net_profit", "ws_item_sk", "ws_order_number"])
+    web_returns = b.scan("web_returns", schema=[
+        "wr_returned_date_sk", "wr_item_sk", "wr_order_number",
+        "wr_return_amt", "wr_net_loss"])
+    sums = [("sales_price", "sum", "sales"), ("profit", "sum", "profit"),
+            ("return_amt", "sum", "returns"),
+            ("net_loss", "sum", "profit_loss")]
     per = []
+    for ci, name in enumerate(Q5_CHANNELS):
+        s_name, s_cols, r_name, r_cols, d_name, d_sk, d_id = Q5_SHAPE[name]
+        if name == "web":
+            s, r = web_sales, web_returns.join(
+                web_sales, left_on=["wr_item_sk", "wr_order_number"],
+                right_on=["ws_item_sk", "ws_order_number"])
+        else:
+            s = b.scan(s_name, schema=[s_cols[1], s_cols[0], *s_cols[2:]])
+            r = b.scan(r_name, schema=[r_cols[1], r_cols[0], *r_cols[2:]])
+        s_rows = s.project(
+            [(o, col(c)) for o, c in zip(Q5_ROWS[:4], s_cols)]
+            + [("return_amt", lit(0)), ("net_loss", lit(0))])
+        r_rows = r.project(
+            [(o, col(c)) for o, c in zip(Q5_ROWS[:2], r_cols)]
+            + [("sales_price", lit(0)), ("profit", lit(0))]
+            + [(o, col(c)) for o, c in zip(Q5_ROWS[4:], r_cols[2:])])
+        dim = b.scan(d_name, schema=[d_sk, d_id])
+        g = (s_rows.union(r_rows)
+             .join(dates, left_on="date_sk", right_on="d_date_sk")
+             .join(dim, left_on="sk", right_on=d_sk)
+             .aggregate([d_id], sums))
+        per.append(g.project([("channel", lit(ci)), ("id", col(d_id)),
+                              ("sales", col("sales")),
+                              ("returns", col("returns")),
+                              ("profit", col("profit")
+                               - col("profit_loss"))]))
+    x = PlanBuilder.union(per)
+    measures = [(n, "sum", n) for n in Q5_RESULT[2:]]
+    kept = [(n, col(n)) for n in Q5_RESULT[2:]]
+    by_id = x.aggregate(["channel", "id"], measures)
+    by_channel = (x.aggregate(["channel"], measures)
+                  .project([("channel", col("channel")), ("id", lit(-1))]
+                           + kept))
+    total = (x.aggregate([], measures)
+             .project([("channel", lit(-1)), ("id", lit(-1))] + kept))
+    return (PlanBuilder.union([by_id, by_channel, total])
+            .sort(["channel", "id"]).limit(100).build())
+
+
+def q5_inputs(facts, dims):
+    return {**facts, **dims}
+
+
+def _q5_business_ids(n_sk: int):
+    """Two surrogate keys to one business id for about half the rows (a
+    history-keeping dimension), one to one for the rest."""
+    pairs = n_sk // 4
+    return np.concatenate([np.repeat(np.arange(pairs), 2),
+                           np.arange(pairs, n_sk - pairs)]).astype(np.int64)
+
+
+def q5_datagen(n_sales: int, seed=0, n_pages: int = 300, empty=()):
+    """-> {table: {column: int64 array}}. Sale and return dates are drawn
+    independently over seven months around the window, so most returns'
+    sales lie outside it; every web return has its sale and
+    (ws_item_sk, ws_order_number) is unique; the channels named in `empty`
+    keep their rows but none of them falls in the window."""
+    rng = np.random.default_rng(seed)
+    day0 = int(np.datetime64("2000-06-01").astype(np.int64))
+    n_days = 214
+
+    def date_sk(n, name):
+        d = day0 + rng.integers(0, n_days, n)
+        if name in empty:
+            d = np.where((d >= Q5_DATE_LO) & (d <= Q5_DATE_HI), day0, d)
+        return (d + Q5_JULIAN).astype(np.int64)
+
+    def money(lo, hi, n):
+        return rng.integers(lo, hi, n).astype(np.int64)
+
+    n_dim = {"store": 12, "catalog": n_pages, "web": 6}
+    out = {}
     for ci, name in enumerate(("store", "catalog", "web")):
-        s = b.scan(f"{name}_sales",
-                   schema=["sk", "date_sk", "sales_price", "profit"])
-        r = b.scan(f"{name}_returns",
-                   schema=["sk", "date_sk", "return_amt", "net_loss"])
-        s_rows = s.project([("sk", col("sk")), ("date_sk", col("date_sk")),
-                            ("sales", col("sales_price")),
-                            ("profit", col("profit")),
-                            ("returns", lit(0)), ("loss", lit(0))])
-        r_rows = r.project([("sk", col("sk")), ("date_sk", col("date_sk")),
-                            ("sales", lit(0)), ("profit", lit(0)),
-                            ("returns", col("return_amt")),
-                            ("loss", col("net_loss"))])
-        u = (s_rows.union(r_rows)
-             .join(dates, left_on="date_sk", right_on="d_date_sk",
-                   how="left_semi"))
-        g = (u.aggregate(["sk"], sums)
-              .project([("channel", lit(ci))] +
-                       [(n, col(n)) for n in ("sk", "sales", "returns",
-                                              "profit", "loss")]))
-        per.append(g)
-    allch = PlanBuilder.union(per)
-    sub = allch.aggregate(["channel"], sums)
-    tot = (allch.project([("channel", lit(-1))] +
-                         [(n, col(n)) for n in ("sales", "returns",
-                                                "profit", "loss")])
-                .aggregate(["channel"], sums))
-    return (sub.union(tot)
-               .sort(["channel", "sales"], ascending=[True, False])
-               .build())
-
-
-def q5_inputs(tabs, dates):
-    out = {"dates": dates}
-    for name, (s, r) in tabs.items():
-        out[f"{name}_sales"] = s
-        out[f"{name}_returns"] = r
+        s_name, s_cols, r_name, r_cols, d_name, d_sk, d_id = Q5_SHAPE[name]
+        n_s = max(n_sales // (ci + 1), 8)       # store biggest, web smallest
+        n_r = max(n_s // 10, 1)
+        k = n_dim[name]
+        out[d_name] = {d_sk: np.arange(1, k + 1, dtype=np.int64),
+                       d_id: (np.arange(k, dtype=np.int64) if name == "catalog"
+                              else _q5_business_ids(k))}
+        out[s_name] = {s_cols[1]: date_sk(n_s, name),
+                       s_cols[0]: money(1, k + 1, n_s),
+                       s_cols[2]: money(1, 10_000, n_s),
+                       s_cols[3]: money(-2_000, 5_000, n_s)}
+        ret = {r_cols[1]: date_sk(n_r, name)}
+        if name == "web":
+            order = np.arange(n_s, dtype=np.int64) // 4
+            item = (rng.integers(1, 50, n_s // 4 + 1)[order]
+                    + np.arange(n_s, dtype=np.int64) % 4 * 50)
+            out[s_name].update(ws_item_sk=item, ws_order_number=order)
+            sold = rng.choice(n_s, n_r, replace=False)
+            ret.update(wr_item_sk=item[sold], wr_order_number=order[sold])
+        else:
+            ret[r_cols[0]] = money(1, k + 1, n_r)
+        ret.update({r_cols[2]: money(1, 8_000, n_r),
+                    r_cols[3]: money(1, 3_000, n_r)})
+        out[r_name] = ret
+    d = np.arange(int(np.datetime64("1998-01-01").astype(np.int64)),
+                  int(np.datetime64("2003-01-01").astype(np.int64)),
+                  dtype=np.int64)
+    out["date_dim"] = {"d_date_sk": d + Q5_JULIAN, "d_date": d}
     return out
 
 
-def q5_datagen(n_sales: int, seed=0):
-    """Three channels; returns are ~10% of sales volume."""
-    rng = np.random.default_rng(seed)
-    n_dates = 365 * 5
-    chans = {}
-    for ci, name in enumerate(("store", "catalog", "web")):
-        n_s = n_sales // (ci + 1)           # store biggest, web smallest
-        n_r = max(n_s // 10, 1)
-        chans[name] = {
-            "s_sk": rng.integers(0, 1000, n_s).astype(np.int64),
-            "s_date": rng.integers(0, n_dates, n_s).astype(np.int64),
-            "s_price": rng.integers(1, 10_000, n_s).astype(np.int64),
-            "s_profit": rng.integers(-2_000, 5_000, n_s).astype(np.int64),
-            "r_sk": rng.integers(0, 1000, n_r).astype(np.int64),
-            "r_date": rng.integers(0, n_dates, n_r).astype(np.int64),
-            "r_amt": rng.integers(1, 8_000, n_r).astype(np.int64),
-            "r_loss": rng.integers(1, 3_000, n_r).astype(np.int64),
-        }
-    date_sk = np.arange(n_dates, dtype=np.int64)
-    return chans, date_sk
+def q5_tables(n_sales: int, seed=0, **kw):
+    """-> (fact tables, dimension tables), each {name: Table}."""
+    t = {name: _tab(cols) for name, cols in
+         q5_datagen(n_sales, seed, **kw).items()}
+    dims = ("date_dim", "store", "catalog_page", "web_site")
+    return ({n: v for n, v in t.items() if n not in dims},
+            {n: t[n] for n in dims})
 
 
-def q5_tables(n_sales: int, seed=0):
-    """-> ({channel: (sales, returns)}, dates)"""
-    chans, date_sk = q5_datagen(n_sales, seed)
-    tabs = {}
-    for name, c in chans.items():
-        tabs[name] = (
-            _tab({"sk": c["s_sk"], "date_sk": c["s_date"],
-                  "sales_price": c["s_price"], "profit": c["s_profit"]}),
-            _tab({"sk": c["r_sk"], "date_sk": c["r_date"],
-                  "return_amt": c["r_amt"], "net_loss": c["r_loss"]}))
-    return tabs, _tab({"d_date_sk": date_sk})
-
-
-def q5_reference(n_sales: int, seed=0):
-    """-> DataFrame (channel, sales, returns, profit, loss): the grand
-    total as channel -1, then one row per channel."""
-    chans, _ = q5_datagen(n_sales, seed)
-    measures = dict(sales=("sales", "sum"), returns=("returns", "sum"),
-                    profit=("profit", "sum"), loss=("loss", "sum"))
+def q5_reference(n_sales: int, seed=0, **kw):
+    """-> DataFrame (channel, id, sales, returns, profit): the template in
+    pandas, the first 100 rows by (channel, id)."""
+    t = {name: pd.DataFrame(cols) for name, cols in
+         q5_datagen(n_sales, seed, **kw).items()}
+    d = t["date_dim"]
+    d = d[(d.d_date >= Q5_DATE_LO) & (d.d_date <= Q5_DATE_HI)]
     frames = []
-    for ci, c in enumerate(chans.values()):
-        s = pd.DataFrame({"sk": c["s_sk"], "date_sk": c["s_date"],
-                          "sales": c["s_price"], "profit": c["s_profit"],
-                          "returns": 0, "loss": 0})
-        r = pd.DataFrame({"sk": c["r_sk"], "date_sk": c["r_date"],
-                          "sales": 0, "profit": 0, "returns": c["r_amt"],
-                          "loss": c["r_loss"]})
-        u = pd.concat([s, r])
-        u = u[(u.date_sk >= DATE_LO) & (u.date_sk < DATE_HI)]
-        g = u.groupby("sk", as_index=False).agg(**measures)
-        g.insert(0, "channel", ci)
-        frames.append(g)
-    sub = pd.concat(frames).groupby("channel", as_index=False).agg(**measures)
-    tot = sub.drop(columns="channel").sum()
-    ref = pd.concat([sub, pd.DataFrame([{"channel": -1, **tot}])])
-    return (ref.sort_values(["channel", "sales"], ascending=[True, False])
-            [["channel", "sales", "returns", "profit", "loss"]])
+    for ci, name in enumerate(Q5_CHANNELS):
+        s_name, s_cols, r_name, r_cols, d_name, d_sk, d_id = Q5_SHAPE[name]
+        r = t[r_name]
+        if name == "web":
+            r = r.merge(t["web_sales"][["ws_item_sk", "ws_order_number",
+                                        "ws_web_site_sk"]], how="left",
+                        left_on=["wr_item_sk", "wr_order_number"],
+                        right_on=["ws_item_sk", "ws_order_number"])
+        s = t[s_name][s_cols].set_axis(Q5_ROWS[:4], axis=1) \
+            .assign(return_amt=0, net_loss=0)
+        r = r[r_cols].set_axis(Q5_ROWS[:2] + Q5_ROWS[4:], axis=1) \
+            .assign(sales_price=0, profit=0)
+        u = (pd.concat([s, r[Q5_ROWS]])
+             .merge(d, left_on="date_sk", right_on="d_date_sk")
+             .merge(t[d_name], left_on="sk", right_on=d_sk))
+        g = u.groupby(d_id, as_index=False).agg(
+            sales=("sales_price", "sum"), profit=("profit", "sum"),
+            returns=("return_amt", "sum"), profit_loss=("net_loss", "sum"))
+        frames.append(pd.DataFrame({
+            "channel": ci, "id": g[d_id].astype(np.int64), "sales": g.sales,
+            "returns": g.returns, "profit": g.profit - g.profit_loss}))
+    x = pd.concat(frames)
+    by_id = x.groupby(["channel", "id"], as_index=False).sum()
+    by_channel = (x.drop(columns="id").groupby("channel", as_index=False)
+                  .sum().assign(id=-1))
+    total = pd.DataFrame([{"channel": -1, "id": -1,
+                           **x[Q5_RESULT[2:]].sum().to_dict()}])
+    return (pd.concat([by_id, by_channel, total])[Q5_RESULT]
+            .astype(np.int64).sort_values(["channel", "id"]).head(100)
+            .reset_index(drop=True))
 
 
 # ---- q23: two shared HAVING subqueries, semi-joined on both sides -----------

@@ -254,10 +254,12 @@ def cert_seed() -> bool:
 
 
 def dist_slack() -> float:
-    """Distributed tier: initial slack factor sizing the fixed-capacity
-    exchange buckets (capacity = rows/peer x slack). Skew past the slack
-    raises the overflow flag and the executor retries with geometrically
-    grown slack (SplitAndRetry contract, parallel/autoretry.py)."""
+    """Distributed tier: initial slack factor sizing the sample sort's
+    fixed-capacity range buckets (capacity = rows/peer x slack). Skew past
+    the slack raises the overflow flag and the executor retries with
+    geometrically grown slack (SplitAndRetry contract,
+    parallel/autoretry.py). The hash exchange needs none: it counts its
+    buckets first and ships them at the fullest one's size."""
     return _float_env("SPARK_RAPIDS_TPU_DIST_SLACK", 2.0)
 
 
@@ -689,7 +691,7 @@ def place_compile_cache() -> str:
     other directory is set in code; where it is not, the cache is
     `<checkout>/.jax_cache` — a fixed path (the path is part of the cache
     key: one built from a temp name, pid or time never hits). The one
-    placement rule shared by chipbench, chip_smoke.py and tests/conftest."""
+    placement rule shared by chipbench and tests/conftest."""
     env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if env:
         return env

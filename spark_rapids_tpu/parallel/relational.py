@@ -62,13 +62,49 @@ def _fit(x: jnp.ndarray, cap: int, fill) -> jnp.ndarray:
     return jnp.concatenate([x, jnp.full((cap - n,), fill, x.dtype)])
 
 
+# `_running` scans in two levels from 16 blocks of 4,096 on. Compiled for
+# a described v5e (no chip; PERF.md, PR 32), an int64 `cumsum` of 65,535
+# elements takes 6.6 s flat, of 65,536 16.7 s flat and 2.0 s in two
+# levels, of 131,072 28.3 s and 3.5 s, of 425,984 121.6 s and 1.9 s; on
+# the chip the count of 39.6 M rows runs in 10.1 ms flat and 5.2 ms in
+# two levels. The block is the one size tried.
+_SCAN_BLOCK = 4096
+
+
+def _running(x: jnp.ndarray, op: str = "sum") -> jnp.ndarray:
+    """Inclusive running sum (or maximum) of a 1-D array, in two levels
+    from 16 blocks on: a scan inside blocks of 4,096, a scan over the
+    blocks' totals, one elementwise merge. The chip's compiler takes a
+    64-bit scan of a long vector as one emulated reduce-window, and its
+    compile time grows with the length."""
+    scan = jnp.cumsum if op == "sum" else jax.lax.cummax
+    n = x.shape[0]
+    if n < 16 * _SCAN_BLOCK:
+        return scan(x, axis=0)
+    pad = (-n) % _SCAN_BLOCK
+    if pad:
+        fill = 0 if op == "sum" else jnp.iinfo(x.dtype).min
+        x = jnp.concatenate([x, jnp.full((pad,), fill, x.dtype)])
+    inner = scan(x.reshape(-1, _SCAN_BLOCK), axis=1)
+    totals = inner[:, -1]
+    if op == "sum":
+        before = jnp.cumsum(totals) - totals
+        out = inner + before[:, None]
+    else:
+        lowest = jnp.full((1,), jnp.iinfo(x.dtype).min, x.dtype)
+        before = jnp.concatenate([lowest, jax.lax.cummax(totals)[:-1]])
+        out = jnp.maximum(inner, before[:, None])
+    return out.reshape(-1)[:n]
+
+
 def _identity(op: str) -> int:
     info = jnp.iinfo(jnp.int64)
     return {"sum": 0, "min": info.max, "max": info.min}[op]
 
 
 def _bucket_exchange(axis: str, n_peers: int, cap: int, part: jnp.ndarray,
-                     payloads: Sequence[Tuple[jnp.ndarray, object]]):
+                     payloads: Sequence[Tuple[jnp.ndarray, object]],
+                     how: str = "hash"):
     """Shared bucket-then-all-to-all body (the shape of shuffle.py's
     _exchange_local): bucket rows by `part` into (n_peers, cap) slots, ship
     each bucket to its peer, and — like _exchange_local — ship only the (P,)
@@ -76,16 +112,20 @@ def _bucket_exchange(axis: str, n_peers: int, cap: int, part: jnp.ndarray,
     ICI traffic than a full bool mask).
 
     payloads: [(array, dead-slot fill)]. Returns (received arrays (P*cap,),
-    recv_valid (P*cap,), spilled scalar bool)."""
+    recv_valid (P*cap,), spilled scalar bool). The collectives run under
+    the scope `exchange.<how>`, so a device trace can tell them from
+    their neighbours by name."""
     gi, bvalid, counts = build_partition_map(part, n_peers, cap)
     spilled = jnp.any(counts > cap)
     outs = []
-    for x, fill in payloads:
-        b = jnp.where(bvalid, jnp.take(x, gi, axis=0),
-                      jnp.asarray(fill, x.dtype))
-        outs.append(jax.lax.all_to_all(b, axis, 0, 0, tiled=True).reshape(-1))
     sent = jnp.minimum(counts, cap)
-    sent_recv = jax.lax.all_to_all(sent, axis, 0, 0, tiled=True)
+    with jax.named_scope("exchange." + how):
+        for x, fill in payloads:
+            b = jnp.where(bvalid, jnp.take(x, gi, axis=0),
+                          jnp.asarray(fill, x.dtype))
+            outs.append(jax.lax.all_to_all(b, axis, 0, 0,
+                                           tiled=True).reshape(-1))
+        sent_recv = jax.lax.all_to_all(sent, axis, 0, 0, tiled=True)
     slot = jnp.arange(cap, dtype=jnp.int32)[None, :]
     recv_valid = (slot < sent_recv[:, None]).reshape(-1)
     return outs, recv_valid, spilled
@@ -106,7 +146,9 @@ def _merge_groups(keys, alive: jnp.ndarray,
     key_list = list(keys) if multi else [keys]
     n = key_list[0].shape[0]
     iota = jnp.arange(n, dtype=jnp.int32)
-    ks = [jnp.where(alive, k, _DEAD_KEY) for k in key_list]  # dead rows last
+    # dead rows last; the sentinel in the key's own width (a caller may
+    # pass keys narrowed to 32 bits: parallel.relational.narrow_keys)
+    ks = [jnp.where(alive, k, jnp.iinfo(k.dtype).max) for k in key_list]
     sorted_all = jax.lax.sort([*ks, iota], num_keys=len(ks), is_stable=True)
     sks, order = sorted_all[:-1], sorted_all[-1]
     salive = jnp.take(alive, order, axis=0)
@@ -115,7 +157,7 @@ def _merge_groups(keys, alive: jnp.ndarray,
     for o in sks:
         neq = neq | (o != jnp.roll(o, 1))
     boundary = neq.at[0].set(True) if n else neq
-    gid = jnp.cumsum(boundary.astype(jnp.int32)) - 1
+    gid = _running(boundary.astype(jnp.int32)) - 1
     # boundary-compaction sort for group starts (see ops/aggregate.py)
     flag = jnp.where(boundary, jnp.int32(0), jnp.int32(1))
     payload = jnp.where(boundary, iota, jnp.int32(n))
@@ -128,7 +170,7 @@ def _merge_groups(keys, alive: jnp.ndarray,
     prev = starts - 1
 
     def span_sum(x):
-        c = jnp.cumsum(x)
+        c = _running(x)
         hi = jnp.take(c, last, axis=0)
         lo = jnp.where(prev >= 0, jnp.take(c, jnp.maximum(prev, 0), axis=0), 0)
         return hi - lo
@@ -142,13 +184,9 @@ def _merge_groups(keys, alive: jnp.ndarray,
         else:
             ident = jnp.int64(_identity(op))
             masked = jnp.where(salive, sc.astype(jnp.int64), ident)
-
-            def combine(a, b, op=op):
-                ab, av = a
-                bb, bv = b
-                m = jnp.minimum(av, bv) if op == "min" else jnp.maximum(av, bv)
-                return ab | bb, jnp.where(bb, bv, m)
-            _, res = jax.lax.associative_scan(combine, (boundary, masked))
+            res = _segmented_cummax(gid, boundary,
+                                    ~masked if op == "min" else masked)
+            res = ~res if op == "min" else res
             outs.append(jnp.take(res, last, axis=0))
 
     n_groups = (gid[-1] + 1) if n else jnp.int32(0)
@@ -160,9 +198,29 @@ def _merge_groups(keys, alive: jnp.ndarray,
     valid = (_fit(alive_cnt, key_cap, 0) > 0) & \
         (jnp.arange(key_cap, dtype=jnp.int32) < n_groups)
     gkeys = [_fit(jnp.take(k, starts, axis=0, mode="clip"), key_cap,
-                  _DEAD_KEY) for k in sks]
+                  jnp.iinfo(k.dtype).max) for k in sks]
     out_keys = gkeys if multi else gkeys[0]
     return (out_keys, [_fit(o, key_cap, 0) for o in outs], valid, n_real)
+
+
+def _segmented_cummax(gid, boundary, x):
+    """Running maximum of int64 `x` within runs (`gid`: the run's number,
+    never falling; `boundary`: where a run starts), as three prefix scans
+    and no gather: an unrolled `associative_scan` of the same merge was
+    most of a program's code at fact scale (PERF.md, PR 29). A run's
+    number packed above a 32-bit half makes one `cummax` restart at every
+    run; the high halves go first, then the low halves of the rows that
+    hold their run's high half so far (whenever that changes, a new
+    sub-run starts: earlier low halves no longer count)."""
+    hi = (x >> 32) + (1 << 31)                       # 0 .. 2**32 - 1
+    lo = x & 0xFFFFFFFF
+    run_hi = _running((gid.astype(jnp.int64) << 32) | hi, "max")
+    rises = boundary | (run_hi != jnp.roll(run_hi, 1))
+    sub = _running(rises.astype(jnp.int32)) - 1
+    holds = (run_hi & 0xFFFFFFFF) == hi
+    run_lo = _running((sub.astype(jnp.int64) << 32)
+                      | jnp.where(holds, lo, 0), "max")
+    return (((run_hi & 0xFFFFFFFF) - (1 << 31)) << 32) | (run_lo & 0xFFFFFFFF)
 
 
 def distributed_groupby(mesh: Mesh, keys: jnp.ndarray, vals: jnp.ndarray,
@@ -315,7 +373,7 @@ def distributed_local_groupby(mesh: Mesh, key_words: Sequence[jnp.ndarray],
 def distributed_repartition_keyed(mesh: Mesh,
                                   key_words: Sequence[jnp.ndarray],
                                   key_specs, vals: Sequence[jnp.ndarray],
-                                  slack: float = 2.0, axis: str = "data",
+                                  cap: int, axis: str = "data",
                                   alive=None, word_codecs=None,
                                   word_refs=None):
     """Standalone hash-partition exchange of one relation — the physical
@@ -335,10 +393,15 @@ def distributed_repartition_keyed(mesh: Mesh,
     as traced arrays (replicated specs), not baked constants, so one
     compiled program serves every execution of the same layout.
 
+    `cap` is the bucket capacity (the caller counts the buckets with
+    `distributed_partition_counts`, so no slot beyond the fullest bucket
+    is shipped and no slack is guessed).
+
     Returns ([key words], [vals], valid, overflow); the key words come
     back in the wire form they were passed (the caller widens). overflow
-    means a bucket spilled its slack-sized capacity — retry with bigger
-    slack (SplitAndRetry contract)."""
+    (one bool a shard) means a bucket held more than `cap` rows and lost
+    the rest: the count and the exchange disagreed, and the caller
+    raises."""
     from .keys import spark_partition_hash
     n_peers = mesh.shape[axis]
     hash_fn = lambda ws: spark_partition_hash(ws, key_specs)  # noqa: E731
@@ -359,11 +422,11 @@ def distributed_repartition_keyed(mesh: Mesh,
                     for w, c in zip(ws, codecs_t)]
             fills = [_DEAD_KEY if c == "raw" else 0 for c in codecs_t]
             Ws, Vs, recv_alive, spilled = _hash_exchange(
-                axis, n_peers, slack, ws, vs, hash_fn, alive=live,
-                hash_keys=ws64, key_fills=fills)
+                axis, n_peers, 0.0, ws, vs, hash_fn, alive=live,
+                hash_keys=ws64, key_fills=fills, cap=cap)
         else:
             Ws, Vs, recv_alive, spilled = _hash_exchange(
-                axis, n_peers, slack, ws, vs, hash_fn, alive=live)
+                axis, n_peers, 0.0, ws, vs, hash_fn, alive=live, cap=cap)
         return (tuple(Ws), tuple(Vs), recv_alive, spilled.reshape(1))
 
     spec = P(axis)
@@ -380,85 +443,132 @@ def distributed_colocated_join_keyed(mesh: Mesh,
                                      l_words: Sequence[jnp.ndarray],
                                      lvals: Sequence[jnp.ndarray],
                                      r_words: Sequence[jnp.ndarray],
-                                     rvals: Sequence[jnp.ndarray],
-                                     key_specs, row_cap: int = 0,
-                                     axis: str = "data", how: str = "inner",
+                                     key_specs, axis: str = "data",
+                                     how: str = "left_semi",
                                      lalive=None, ralive=None,
                                      r_replicated: bool = False):
-    """Equi-join of two ALREADY-ALIGNED sides with no exchange: both sides
-    are either hash-partitioned by the positionally-matching key tuples
-    (the explicit `Exchange(hash)` ran upstream, so matching rows are
-    co-located), or the right side is REPLICATED (`r_replicated=True`: the
-    `Exchange(broadcast)` replicated the small build side onto every
-    shard, the probe side never moves). Each shard then joins locally —
-    the plan tier's counterpart of Spark executing a join above its
-    exchanges.
+    """Semi- or anti-join of two ALREADY-ALIGNED sides with no exchange:
+    both sides are either hash-partitioned by the positionally-matching
+    key tuples (the explicit `Exchange(hash)` ran upstream, so matching
+    rows are co-located), or the right side is REPLICATED
+    (`r_replicated=True`: the `Exchange(broadcast)` replicated the small
+    build side onto every shard, the probe side never moves). Each shard
+    then joins locally — the plan tier's counterpart of Spark executing a
+    join above its exchanges. The output stays left-shaped; an inner join
+    of aligned sides is `distributed_colocated_join_spans` / `_emit`.
 
-    `how`: inner (padded row_cap output), left_semi / left_anti (output
-    stays left-shaped, no row_cap). `lalive`/`ralive` mark live rows of
-    padded sharded relations; NULL keys never match (Spark equi-join
-    semantics).
+    `lalive`/`ralive` mark live rows of padded sharded relations; NULL
+    keys never match (Spark equi-join semantics).
 
-    Returns: inner -> ([l key words], [lvals], [rvals], valid, overflow);
-    semi/anti -> ([l key words], [lvals], keep, overflow)."""
+    Returns ([l key words], [lvals], keep)."""
     from .keys import keys_null_mask
-    l_words, lvals = list(l_words), list(lvals)
-    r_words, rvals = list(r_words), list(rvals)
+    l_words, lvals, r_words = list(l_words), list(lvals), list(r_words)
     _check_word_counts(l_words, r_words)
-    nw, nlv, nrv = len(l_words), len(lvals), len(rvals)
+    nw, nlv = len(l_words), len(lvals)
     has_lal, has_ral = lalive is not None, ralive is not None
-    semi_anti = how in ("left_semi", "left_anti")
-    if how not in ("inner", "left_semi", "left_anti"):
+    if how not in ("left_semi", "left_anti"):
         raise ValueError(f"unsupported colocated join type {how!r}")
 
     def local(*arrs):
-        i = 0
-        lw = list(arrs[i:i + nw]); i += nw
-        lv = list(arrs[i:i + nlv]); i += nlv
-        rw = list(arrs[i:i + nw]); i += nw
-        rv = list(arrs[i:i + nrv]); i += nrv
+        lw, lv = list(arrs[:nw]), list(arrs[nw:nw + nlv])
+        rw = list(arrs[nw + nlv:2 * nw + nlv])
+        i = 2 * nw + nlv
         Lal = arrs[i] if has_lal else jnp.ones(lw[0].shape, bool)
         i += int(has_lal)
         Ral = arrs[i] if has_ral else jnp.ones(rw[0].shape, bool)
         lmatch = Lal & ~keys_null_mask(lw, key_specs)
         rmatch = Ral & ~keys_null_mask(rw, key_specs)
-        if semi_anti:
-            nl = lw[0].shape[0]
-            operands = tuple(jnp.concatenate([a, b])
-                             for a, b in zip(lw, rw))
-            counts, _, _ = join_spans(operands, lmatch, rmatch, nl=nl,
-                                      need_rorder=False)
-            hit = counts > 0
-            keep = Lal & (hit if how == "left_semi" else ~hit)
-            out_lw = [jnp.where(keep, w, jnp.asarray(0, w.dtype))
-                      for w in lw]
-            out_lv = [jnp.where(keep, v, jnp.asarray(0, v.dtype))
-                      for v in lv]
-            return (tuple(out_lw), tuple(out_lv), keep,
-                    jnp.zeros((1,), bool))
-        out_lw, out_lv, out_rv, _, live, ovf = _local_join_tail(
-            lw, lv, Lal, rw, rv, Ral, row_cap, outer=False,
-            lmatch=lmatch, rmatch=rmatch)
-        return (tuple(out_lw), tuple(out_lv), tuple(out_rv), live,
-                ovf.reshape(1))
+        operands = tuple(jnp.concatenate([a, b]) for a, b in zip(lw, rw))
+        counts, _, _ = join_spans(operands, lmatch, rmatch,
+                                  nl=lw[0].shape[0], need_rorder=False)
+        hit = counts > 0
+        keep = Lal & (hit if how == "left_semi" else ~hit)
+        return (tuple(jnp.where(keep, w, jnp.asarray(0, w.dtype))
+                      for w in lw),
+                tuple(jnp.where(keep, v, jnp.asarray(0, v.dtype))
+                      for v in lv), keep)
 
     spec = P(axis)
     rspec = P() if r_replicated else spec
-    in_specs = ((spec,) * (nw + nlv) + (rspec,) * (nw + nrv)
+    in_specs = ((spec,) * (nw + nlv) + (rspec,) * nw
                 + (spec,) * int(has_lal) + (rspec,) * int(has_ral))
-    if semi_anti:
-        out_specs = (tuple(spec for _ in l_words),
-                     tuple(spec for _ in lvals), spec, spec)
-    else:
-        out_specs = (tuple(spec for _ in l_words),
-                     tuple(spec for _ in lvals),
-                     tuple(spec for _ in rvals), spec, spec)
     fn = shard_map(local, mesh=mesh, in_specs=in_specs,
-                   out_specs=out_specs)
-    args = (l_words + lvals + r_words + rvals
+                   out_specs=(tuple(spec for _ in l_words),
+                              tuple(spec for _ in lvals), spec))
+    args = (l_words + lvals + r_words
             + ([lalive] if has_lal else [])
             + ([ralive] if has_ral else []))
     return fn(*args)
+
+
+def distributed_colocated_join_spans(mesh: Mesh, l_words, r_words, key_specs,
+                                     axis: str = "data", lalive=None,
+                                     ralive=None, r_replicated: bool = False):
+    """The first half of an inner join of two aligned sides (see
+    `distributed_colocated_join_keyed`): each shard's match spans over its
+    union sort, and HOW MANY rows it will emit. The eager walk reads that
+    count and sizes the second half (`distributed_colocated_join_emit`) by
+    it: an output frame is then never a guess that a fan-out overflows
+    (each escalation is another compile of a three-sort program), nor a
+    probe side's worth of slots for a join that keeps a tenth of it.
+    Returns (counts, lo, rorder, total): the spans in left-row order,
+    `total` one int32 a shard."""
+    from .keys import keys_null_mask
+    l_words, r_words = list(l_words), list(r_words)
+    _check_word_counts(l_words, r_words)
+    nw = len(l_words)
+
+    def local(*arrs):
+        lw, rw = list(arrs[:nw]), list(arrs[nw:2 * nw])
+        lmatch = arrs[-2] & ~keys_null_mask(lw, key_specs)
+        rmatch = arrs[-1] & ~keys_null_mask(rw, key_specs)
+        operands = tuple(jnp.concatenate([a, b]) for a, b in zip(lw, rw))
+        counts, lo, rorder = join_spans(operands, lmatch, rmatch,
+                                        nl=lw[0].shape[0])
+        return counts, lo, rorder, jnp.sum(counts).astype(
+            jnp.int32).reshape(1)
+
+    spec = P(axis)
+    rspec = P() if r_replicated else spec
+    return shard_map(local, mesh=mesh,
+                     in_specs=(spec,) * nw + (rspec,) * nw + (spec, rspec),
+                     out_specs=(spec,) * 4)(*l_words, *r_words, lalive,
+                                            ralive)
+
+
+def distributed_colocated_join_emit(mesh: Mesh, l_words, lvals, rvals,
+                                    counts, lo, rorder, row_cap: int,
+                                    axis: str = "data",
+                                    r_replicated: bool = False):
+    """The second half: expand the spans into `row_cap` slots a shard (at
+    least the fullest shard's `total`) and gather both sides' columns.
+    Returns ([l key words], [lvals], [rvals], valid)."""
+    l_words, lvals, rvals = list(l_words), list(lvals), list(rvals)
+    nw, nlv, nrv = len(l_words), len(lvals), len(rvals)
+
+    def local(*arrs):
+        lw, lv = list(arrs[:nw]), list(arrs[nw:nw + nlv])
+        rv = list(arrs[nw + nlv:nw + nlv + nrv])
+        counts, lo, rorder = arrs[-3:]
+        lsel, rsel = expand_spans(counts, lo, rorder, total=row_cap)
+        live = jnp.arange(row_cap, dtype=jnp.int32) < jnp.sum(counts)
+
+        def take(x, sel):
+            return jnp.where(live, jnp.take(x, sel, axis=0),
+                             jnp.asarray(0, x.dtype))
+        rsel = jnp.maximum(rsel, 0)
+        return (tuple(take(w, lsel) for w in lw),
+                tuple(take(v, lsel) for v in lv),
+                tuple(take(v, rsel) for v in rv), live)
+
+    spec = P(axis)
+    rspec = P() if r_replicated else spec
+    return shard_map(
+        local, mesh=mesh,
+        in_specs=(spec,) * (nw + nlv) + (rspec,) * nrv + (spec,) * 3,
+        out_specs=(tuple(spec for _ in l_words), tuple(spec for _ in lvals),
+                   tuple(spec for _ in rvals), spec))(
+        *l_words, *lvals, *rvals, counts, lo, rorder)
 
 
 def distributed_sort(mesh: Mesh, keys: jnp.ndarray, vals: jnp.ndarray,
@@ -536,7 +646,8 @@ def distributed_sort_keyed(mesh: Mesh, key_words: Sequence[jnp.ndarray],
         pools = []
         for w in sws:
             samples = jnp.take(w, pos, axis=0, mode="clip")
-            pools.append(jax.lax.all_gather(samples, axis).reshape(-1))
+            with jax.named_scope("exchange.range"):
+                pools.append(jax.lax.all_gather(samples, axis).reshape(-1))
         pool_sorted = jax.lax.sort(pools, num_keys=nw, is_stable=True)
         m = pool_sorted[0].shape[0]
         spl_pos = (jnp.arange(1, n_peers, dtype=jnp.int32) * m) // n_peers
@@ -556,7 +667,8 @@ def distributed_sort_keyed(mesh: Mesh, key_words: Sequence[jnp.ndarray],
         part = jnp.where(salive, part, jnp.int32(n_peers))  # drop dead rows
         recv, ralive_, spilled = _bucket_exchange(
             axis, n_peers, cap, part,
-            [(w, _DEAD_KEY) for w in sws] + [(sv, 0) for sv in svs])
+            [(w, _DEAD_KEY) for w in sws] + [(sv, 0) for sv in svs],
+            how="range")
         spilled = jax.lax.all_gather(spilled.reshape(1), axis).any()
         rws, rvs = recv[:nw], recv[nw:]
         # final local sort; dead slots carry the sentinel and sink last
@@ -628,7 +740,7 @@ def _local_join_tail(lk, lv, lalive, rk, rv, ralive, row_cap: int,
 
 def _hash_exchange(axis: str, n_peers: int, slack: float,
                    keys, vals, hash_fn=None, alive=None,
-                   hash_keys=None, key_fills=None):
+                   hash_keys=None, key_fills=None, cap: int = 0):
     """Hash-partition by Spark murmur pmod and all-to-all one table side
     (the shared shuffle wiring of every distributed join). `keys` may be a
     single int64 array or a word list (typed keys); `vals` may be None
@@ -647,7 +759,7 @@ def _hash_exchange(axis: str, n_peers: int, slack: float,
     key_list = _as_list(keys)
     val_list = [] if vals is None else _as_list(vals)
     nloc = key_list[0].shape[0]
-    cap = max(1, math.ceil(nloc / n_peers * slack))
+    cap = cap or max(1, math.ceil(nloc / n_peers * slack))
     hash_list = key_list if hash_keys is None else _as_list(hash_keys)
     part = partition_ids((hash_fn or _spark_murmur_i64)(hash_list), n_peers)
     if alive is not None:
@@ -729,8 +841,9 @@ def _distributed_join_keyed(mesh, l_words, lvals, r_words, rvals, key_specs,
         if broadcast:
             # build side replicated over ICI; probe side stays in place
             Lw, Lv = lw, lv
-            Rw = [jax.lax.all_gather(w, axis, tiled=True) for w in rw]
-            Rv = [jax.lax.all_gather(v, axis, tiled=True) for v in rv]
+            with jax.named_scope("exchange.broadcast"):
+                Rw = [jax.lax.all_gather(w, axis, tiled=True) for w in rw]
+                Rv = [jax.lax.all_gather(v, axis, tiled=True) for v in rv]
             Lalive = jnp.ones((Lw[0].shape[0],), jnp.bool_)
             Ralive = jnp.ones((Rw[0].shape[0],), jnp.bool_)
             lspill = rspill = jnp.zeros((), jnp.bool_)
@@ -967,3 +1080,249 @@ def distributed_left_anti_join(mesh: Mesh, lkeys: jnp.ndarray,
                                slack: float = 2.0, axis: str = "data"):
     """Left rows with no match. Same contract as the semi join."""
     return _distributed_semi_anti(mesh, lkeys, lvals, rkeys, False, slack, axis)
+
+
+# ---- what keeps the walk at the size of its live rows -----------------------
+# (the eager SPMD walk reads a count from the device between two programs,
+# so each of these takes a capacity the caller has just observed)
+
+def distributed_live_counts(mesh: Mesh, alive: jnp.ndarray,
+                            axis: str = "data") -> jnp.ndarray:
+    """(n_peers,) int32: live rows on each shard of a row-sharded mask."""
+    def local(live):
+        return jnp.sum(live.astype(jnp.int32)).reshape(1)
+    return shard_map(local, mesh=mesh, in_specs=(P(axis),),
+                     out_specs=P(axis))(alive)
+
+
+_MASK_WORD = 32
+
+
+def _live_positions(live, cap: int):
+    """(idx, keep): the positions of the first `cap` live rows of a mask,
+    in their order, and which of the `cap` slots hold one. The mask is
+    read as 32-row words: a running count of the words' populations, a
+    binary search per OUTPUT slot over that count (a table a 32nd of the
+    frame: the 21 steps over 39.6 M rows gather from 5 MB, not from the
+    158 MB a per-row count takes), one gather of the word, and the slot's
+    bit found in it by arithmetic. Third: whether more than `cap` rows
+    are live (the rest would be lost)."""
+    n = live.shape[0]
+    pad = (-n) % _MASK_WORD
+    if pad:
+        live = jnp.concatenate([live, jnp.zeros((pad,), live.dtype)])
+    lanes = jnp.arange(_MASK_WORD, dtype=jnp.uint32)
+    words = jnp.sum(live.reshape(-1, _MASK_WORD).astype(jnp.uint32) << lanes,
+                    axis=1, dtype=jnp.uint32)
+    cum = _running(jax.lax.population_count(words).astype(jnp.int32))
+    slot = jnp.arange(cap, dtype=jnp.int32)
+    at = jnp.searchsorted(cum, slot, side="right", method="scan")
+    at = jnp.minimum(at, cum.shape[0] - 1).astype(jnp.int32)
+    word = jnp.take(words, at, axis=0)
+    rank = slot - (jnp.take(cum, at, axis=0)
+                   - jax.lax.population_count(word).astype(jnp.int32))
+    bit = jnp.zeros_like(slot)
+    for width in (16, 8, 4, 2, 1):      # the rank-th set bit of the word
+        low = word & jnp.uint32((1 << width) - 1)
+        below = jax.lax.population_count(low).astype(jnp.int32)
+        high = rank >= below
+        rank = jnp.where(high, rank - below, rank)
+        word = jnp.where(high, word >> width, low)
+        bit = bit + jnp.where(high, width, 0)
+    keep = slot < cum[-1]
+    idx = jnp.minimum(at * _MASK_WORD + bit, n - 1)
+    return jnp.where(keep, idx, 0).astype(jnp.int32), keep, cum[-1] > cap
+
+
+def _compact_local(arrs, live, cap: int):
+    """The first `cap` live rows of one shard, in their order: the price
+    follows what is kept, not the frame (`_live_positions`, then a gather
+    of `cap` slots a column). -> ([arrays], keep, overflow)."""
+    idx, keep, over = _live_positions(live, cap)
+    return [jnp.where(keep, jnp.take(a, idx, axis=0),
+                      jnp.asarray(0, a.dtype)) for a in arrs], keep, over
+
+
+def distributed_compact(mesh: Mesh, arrays: Sequence[jnp.ndarray],
+                        alive: jnp.ndarray, cap: int, axis: str = "data",
+                        replicated: bool = False):
+    """Each shard's live rows packed to the front of `cap` slots (at least
+    the fullest shard's count: `distributed_live_counts`), order and
+    placement kept, so the hash-partitioning property survives. No
+    collective. A replicated relation compacts as one array.
+    Returns ([arrays], alive, overflow): overflow (one bool a shard) says
+    that a shard holds more than `cap` live rows and lost the rest; the
+    caller raises."""
+    arrays = list(arrays)
+    if replicated:
+        outs, keep, over = _compact_local(arrays, alive, cap)
+        return tuple(outs), keep, over.reshape(1)
+
+    def local(*arrs):
+        outs, keep, over = _compact_local(list(arrs[:-1]), arrs[-1], cap)
+        return tuple(outs), keep, over.reshape(1)
+
+    spec = P(axis)
+    return shard_map(local, mesh=mesh, in_specs=(spec,) * (len(arrays) + 1),
+                     out_specs=(tuple(spec for _ in arrays), spec, spec)
+                     )(*arrays, alive)
+
+
+def distributed_head(mesh: Mesh, arrays: Sequence[jnp.ndarray], cap: int,
+                     axis: str = "data"):
+    """The first `cap` slots of every shard (a relation whose live rows are
+    already a prefix of each shard: a group-by's output). No collective."""
+    arrays = list(arrays)
+    spec = P(axis)
+    return shard_map(lambda *xs: tuple(x[:cap] for x in xs), mesh=mesh,
+                     in_specs=(spec,) * len(arrays),
+                     out_specs=tuple(spec for _ in arrays))(*arrays)
+
+
+def distributed_concat(mesh: Mesh, sides: Sequence[Sequence[jnp.ndarray]],
+                       axis: str = "data"):
+    """UNION ALL of row-sharded relations with no collective: every shard
+    appends its own rows of each side (`sides[k][j]` is side k's j-th
+    array). A logical concatenation would reshard nearly every row."""
+    k, width = len(sides), len(sides[0])
+
+    def local(*arrs):
+        return tuple(jnp.concatenate([arrs[s * width + j] for s in range(k)])
+                     for j in range(width))
+
+    spec = P(axis)
+    flat = [a for side in sides for a in side]
+    return shard_map(local, mesh=mesh, in_specs=(spec,) * len(flat),
+                     out_specs=tuple(spec for _ in range(width)))(*flat)
+
+
+def distributed_partition_counts(mesh: Mesh, key_words, key_specs, alive,
+                                 axis: str = "data") -> jnp.ndarray:
+    """(n_peers * n_peers,) int32: how many live rows each shard would send
+    to each peer under the Spark-exact hash of the key words — the buckets
+    of `distributed_repartition_keyed`, counted before it runs, so the
+    exchange ships buckets of the fullest one's size and cannot spill."""
+    from .keys import spark_partition_hash
+    n_peers = mesh.shape[axis]
+    key_words = list(key_words)
+
+    def local(*arrs):
+        part = partition_ids(spark_partition_hash(list(arrs[:-1]),
+                                                  key_specs), n_peers)
+        part = jnp.where(arrs[-1], part, jnp.int32(n_peers))
+        peers = jnp.arange(n_peers, dtype=jnp.int32)
+        return jnp.sum(part[:, None] == peers[None, :], axis=0,
+                       dtype=jnp.int32)
+
+    spec = P(axis)
+    return shard_map(local, mesh=mesh,
+                     in_specs=(spec,) * (len(key_words) + 1),
+                     out_specs=spec)(*key_words, alive)
+
+
+def distributed_lookup_join(mesh: Mesh, l_words, r_words, rvals, key_specs,
+                            lalive, ralive, axis: str = "data"):
+    """Many-to-one join of a sharded probe side against a REPLICATED build
+    side of few rows with distinct keys (a filtered dimension: 15 days of
+    a calendar; a small dimension whole: 402 stores): every probe row is
+    compared with every build row, one elementwise pass per build row, and
+    takes the one that matches. No sort, no gather, no output capacity:
+    the result keeps the probe side's slots and marks the rows that found
+    a partner. Null keys match nothing.
+    Returns ([rvals at the probe rows], matched)."""
+    from .keys import keys_null_mask
+    l_words, r_words, rvals = list(l_words), list(r_words), list(rvals)
+    nw, nrv = len(l_words), len(rvals)
+
+    def local(*arrs):
+        lw, rw = list(arrs[:nw]), list(arrs[nw:2 * nw])
+        rv = list(arrs[2 * nw:2 * nw + nrv])
+        lmatch = arrs[-2] & ~keys_null_mask(lw, key_specs)
+        rmatch = arrs[-1] & ~keys_null_mask(rw, key_specs)
+        def step(j, carry):
+            matched, outs = carry
+            eq = lmatch & rmatch[j]
+            for a, b in zip(lw, rw):
+                eq = eq & (a == b[j])
+            return (matched | eq,
+                    tuple(jnp.where(eq, v[j], o) for v, o in zip(rv, outs)))
+
+        nothing = lw[0] - lw[0]      # zeros that vary over the mesh's axis
+        matched, outs = jax.lax.fori_loop(
+            0, rw[0].shape[0], step,
+            (nothing != 0, tuple(nothing.astype(v.dtype) for v in rv)))
+        return tuple(outs), matched
+
+    spec, rep = P(axis), P()
+    return shard_map(local, mesh=mesh,
+                     in_specs=(spec,) * nw + (rep,) * (nw + nrv)
+                     + (spec, rep),
+                     out_specs=(tuple(spec for _ in rvals), spec)
+                     )(*l_words, *r_words, *rvals, lalive, ralive)
+
+
+def distributed_reduce(mesh: Mesh, vals: Sequence[jnp.ndarray],
+                       aggs: Sequence[Tuple[int, str]], alive,
+                       axis: str = "data"):
+    """A keyless aggregate over a row-sharded relation: each shard reduces
+    its live rows, an all-reduce merges the partials, and the one result
+    row lives in shard 0's first slot (one slot a shard, the others dead).
+    `aggs`: [(value index, sum|count|min|max)], exact in int64.
+    Returns ([(n_peers,) arrays], valid)."""
+    vals = list(vals)
+    aggs = tuple((int(i), a) for i, a in aggs)
+
+    def local(*arrs):
+        live = arrs[-1]
+        outs = []
+        with jax.named_scope("exchange.reduce"):
+            for i, a in aggs:
+                if a == "count":
+                    r = jax.lax.psum(jnp.sum(live.astype(jnp.int64)), axis)
+                elif a == "sum":
+                    r = jax.lax.psum(jnp.sum(jnp.where(
+                        live, arrs[i].astype(jnp.int64), 0)), axis)
+                else:
+                    x = jnp.where(live, arrs[i].astype(jnp.int64),
+                                  jnp.int64(_identity(a)))
+                    r = (jax.lax.pmin(jnp.min(x), axis) if a == "min"
+                         else jax.lax.pmax(jnp.max(x), axis))
+                outs.append(r.reshape(1))
+        first = (jax.lax.axis_index(axis) == 0).reshape(1)
+        return tuple(outs), first
+
+    spec = P(axis)
+    return shard_map(local, mesh=mesh, in_specs=(spec,) * (len(vals) + 1),
+                     out_specs=(tuple(spec for _ in aggs), spec)
+                     )(*vals, alive)
+
+
+def narrow_keys(words_by_side, alive_by_side, lo):
+    """Key words as 32-bit offsets from `lo` (one int64 per word: the least
+    live value over every side, which the caller has read and checked:
+    no word spans 2**31 or more). A sort's compile time and its run time
+    go by the width of its keys (a 64-bit key is two 32-bit operands to
+    the chip's sort: 56 s against 20 s to compile one at 262,144 rows),
+    and a surrogate key, an order number or a business id spans a few
+    million values. Dead rows keep whatever the subtraction leaves: every
+    consumer masks them. `widen_keys` is the way back."""
+    return [[jnp.where(alive, w - lo[i], 0).astype(jnp.int32)
+             for i, w in enumerate(words)]
+            for words, alive in zip(words_by_side, alive_by_side)]
+
+
+def widen_keys(words, lo):
+    return [lo[i] + w.astype(jnp.int64) for i, w in enumerate(words)]
+
+
+def key_ranges(words_by_side, alive_by_side):
+    """(n_words, 2) int64: the least and the greatest live value of each
+    key word over every side (int64 max / min where nothing is live)."""
+    info = jnp.iinfo(jnp.int64)
+    sides = list(zip(words_by_side, alive_by_side))
+    return jnp.stack([jnp.stack([
+        jnp.min(jnp.stack([jnp.min(jnp.where(a, ws[i], info.max))
+                           for ws, a in sides])),
+        jnp.max(jnp.stack([jnp.max(jnp.where(a, ws[i], info.min))
+                           for ws, a in sides]))])
+        for i in range(len(words_by_side[0]))])

@@ -12,7 +12,8 @@ rows currently satisfy) — and data crosses the ICI only at explicit
 inside the two-phase aggregate and sample-sort primitives:
 
 - Scan: the bound table pads to a multiple of the mesh size and shards
-  row-wise (`NamedSharding(mesh, P(axis))`); padding rows are dead.
+  row-wise (`NamedSharding(mesh, P(axis))`); padding rows are dead. A
+  buffer born on the mesh with that sharding is adopted in place.
 - Filter / Project / FusedSelect: elementwise over the sharded columns —
   sharding propagates through plain jnp, no collective; scalar-aggregate
   expressions reduce over live rows (GSPMD all-reduce).
@@ -33,9 +34,14 @@ inside the two-phase aggregate and sample-sort primitives:
 - Sort / TopK: `distributed_sort_keyed` sample-sorts to global order
   (range partitioning; descending keys ride bitwise-inverted words);
   TopK masks the global rank prefix.
-- Union: logical concatenation resharded across the mesh.
+- Union: every shard appends its own rows of each side (no collective).
 
-Static capacities (row_cap / key_cap / slack) escalate geometrically via
+Frames follow their live rows: after an operator that drops rows
+(`_settle`) and before a hash exchange (`_repartition_rel`) the walk reads
+a count from the device and sizes the next program by it (`bucket`), so a
+date window's 1% is sorted, gathered and shipped as 1%. Static capacities
+that cannot be counted ahead (a final merge's key_cap, the sample sort's
+slack) escalate geometrically via
 `parallel.autoretry.auto_retry_overflow` and the final values memoize per
 (plan fingerprint, node) on the executor, exactly like the capped tier's
 caps memo. Every primitive call goes through a bounded cache of
@@ -44,8 +50,9 @@ the jitted form re-traces only per (program, shapes).
 
 Runtime gates (a node that fails one gathers its inputs and runs on the
 local eager path): fixed-width 1-D columns only, aggregate value columns
-non-null and non-float (the exchange accumulates in int64), no `mean`,
-keyless aggregates and Limit have no distributed form. Join emission
+non-null and non-float (the exchange accumulates in int64), no `mean`;
+Limit has no distributed form (a keyless aggregate reduces on the mesh).
+What ran through that fallback is counted (`local_ops`). Join emission
 order and aggregate output placement differ from the single-device
 kernels, so relations carry `order_keys` — the gather re-sorts a
 distributed aggregate's output by its group keys to match the local
@@ -70,6 +77,7 @@ transfer fault then surfaces (and degrades) at the consuming operator.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import threading
 import time
@@ -85,6 +93,7 @@ from ..columnar import Column, Table
 from ..parallel.keys import (KeySpec, _ONE_WORD_KINDS, decode_key_columns,
                              encode_key_column)
 from ..utils.lru import LruDict
+from ..utils.tracing import span, text as _span_text
 from . import transport
 from .nodes import (Exchange, Filter, FusedSelect, HashAggregate, HashJoin,
                     Limit, PlanNode, Project, Scan, Sort, TopK, Union)
@@ -103,16 +112,20 @@ _DIST_AGGS = ("sum", "count", "min", "max", "size")
 _JIT_PRIMS = LruDict(256)
 
 
-def _jitted(key, builder):
-    """Bounded cache of compiled primitive callables; `builder()` returns
-    the final (already jit-wrapped) function. Safe under concurrent async
-    exchange workers: a lost race builds one redundant (cheap, un-traced)
-    wrapper, never corrupts the cache."""
-    fn = _JIT_PRIMS.get(key)
-    if fn is None:
-        fn = builder()
-        _JIT_PRIMS[key] = fn
-    return fn
+def _jitted(key, fn, **jit_kw):
+    """Bounded cache of compiled primitive callables: `fn` jitted (with
+    `jit_kw`) under the name `key[0]`, so that a device trace says
+    `jit_compact/fusion.3` and not `jit__lambda/fusion.3` for every program
+    of the walk. Safe under concurrent async exchange workers: a lost race
+    builds one redundant (cheap, un-traced) wrapper, never corrupts the
+    cache."""
+    prog = _JIT_PRIMS.get(key)
+    if prog is None:
+        def named(*args):
+            return fn(*args)
+        named.__name__ = named.__qualname__ = key[0]
+        prog = _JIT_PRIMS[key] = jax.jit(named, **jit_kw)
+    return prog
 
 
 class ShardedRel:
@@ -325,7 +338,10 @@ def shard_table(mesh, axis: str, t: Table,
     """Pad a bound Table to a multiple of the mesh size and shard it
     row-wise across the peers (dead padding rows carry zeros and a False
     live mask) — the mesh-sharded Scan. An empty table becomes one dead
-    slot per shard so the SPMD shapes stay non-degenerate."""
+    slot per shard so the SPMD shapes stay non-degenerate. A buffer that
+    was born on the mesh (it already carries this row sharding and needs
+    no padding) is adopted in place: a deployment's tables live on their
+    chips, and nothing is copied or moved."""
     n_peers = mesh.shape[axis]
     n = t.num_rows
     pad = (-n) % n_peers if n else n_peers
@@ -334,6 +350,9 @@ def shard_table(mesh, axis: str, t: Table,
     def put(a, fill):
         if pad:
             a = jnp.concatenate([a, jnp.full((pad,), fill, a.dtype)])
+        elif isinstance(a, jax.Array) and \
+                a.sharding.is_equivalent_to(spec, a.ndim):
+            return a
         return jax.device_put(a, spec)
 
     cols = []
@@ -343,8 +362,62 @@ def shard_table(mesh, axis: str, t: Table,
             validity = put(validity, False)
         cols.append(dataclasses.replace(c, data=put(c.data, 0),
                                         validity=validity, length=n + pad))
-    valid = put(jnp.ones((n,), bool), False)
-    return ShardedRel(Table(cols, names=list(t.names)), valid, part=part)
+    # the mask is made on the shards, not shipped from the first device
+    valid = _jitted(("live", mesh, axis, n, n + pad),
+        lambda: jnp.arange(n + pad, dtype=jnp.int32) < n,
+        out_shardings=spec)()
+    rel = ShardedRel(Table(cols, names=list(t.names)), valid, part=part)
+    rel._num_rows = n
+    return rel
+
+
+# ---- capacities from counts the walk has just read --------------------------
+
+# Three thresholds, each with the reading it was set from (PERF.md, PR 32:
+# my chip runs on one TPU v5 lite unless it says otherwise).
+#
+# A sharded relation is packed to its live rows when that frees a quarter
+# of a frame of at least this many slots a shard. NOT measured on the
+# chip: it is set by what a compaction costs to build, one program of 2
+# to 5 s of cold compile for each (columns, capacity) met, against the
+# 273 programs and 444 s of the cell's cold set-up; a sort over 4,096
+# slots is some 0.05 ms (12 ns a slot in PR 31's traced joins), so below
+# it there is nothing to win back. A replicated relation has no such
+# floor (`_settle`).
+_COMPACT_MIN_SLOTS = 4096
+# A replicated build side of at most `_LOOKUP_SLOTS` slots with distinct
+# keys is probed by comparison (parallel/relational.distributed_lookup_join)
+# as long as probe slots a shard x build slots stays under `_LOOKUP_WORK`.
+# Readings: 16 build slots x 39.6 M probe rows (634 M compares) 9.5 ms,
+# 448 x 425,984 (191 M) 3.8 ms, so 2**30 compares are some 16 to 20 ms;
+# the sort join it replaces took 647 ms over 39.6 M + 15 rows (three
+# sorts of 183 to 233 ms) plus 78 ms of emission, and 120 s to compile
+# at 425,984 rows. 1,024 build slots is the loop's length at which a
+# 1 M-row probe side reaches the work bound; no larger build side was
+# timed.
+_LOOKUP_SLOTS = 1024
+_LOOKUP_WORK = 1 << 30
+
+
+def _raise_if_lost(lost, what: str, cap: int) -> None:
+    """`lost`: one bool a shard from a program that was given a capacity
+    the walk had counted. Set, it means rows were dropped: the program
+    that counted and the one that moved disagree, which is a fault of the
+    engine and never a capacity to escalate."""
+    if bool(np.asarray(lost).any()):
+        from ..parallel.autoretry import CapacityOverflowError
+        raise CapacityOverflowError(
+            f"{what}: a shard held more rows than the {cap} slots counted "
+            "for it; rows would have been lost")
+
+
+def bucket(n: int) -> int:
+    """The capacity for `n` observed rows: `n` rounded up to four binary
+    digits (at most an eighth above it), so that nearby counts share a
+    compiled program and a frame is never twice its rows."""
+    n = max(int(n), 8)
+    step = 1 << max(n.bit_length() - 4, 0)
+    return -(-n // step) * step
 
 
 # ---- value packing (columns <-> primitive payload arrays) -------------------
@@ -535,6 +608,14 @@ class DistContext:
         self.async_on = config.exchange_async()
         self.spec = NamedSharding(self.mesh, P(self.axis))
         self.rep_spec = NamedSharding(self.mesh, P())
+        # what one execution did, for `plan.execute` (docs/plan.md):
+        # operators that ran over the mesh and through the local fallback
+        # below a sharded input, edges and wire bytes moved, capacity
+        # escalations
+        self.dist_ops = self.local_ops = 0
+        self.exchange_edges = self.exchange_bytes = 0
+        self.cap_escalations = 0
+        self._off_mesh: set = set()
         parents: Dict[int, List[PlanNode]] = {}
         for n in plan.nodes:
             for c in n.children:
@@ -555,9 +636,10 @@ class DistContext:
     # -- caps memo (fingerprint x node index x primitive, like the capped
     # tier's fingerprint-keyed memo) -----------------------------------------
     def _memo_key(self, node, tag: str):
-        # `tag` separates the primitives one node may drive (a join's
-        # implicit side repartitions escalate slack; the join itself
-        # escalates row_cap — their caps must not merge)
+        # `tag` separates the primitives one node may drive (an
+        # aggregate's final merge escalates key_cap, a sort its slack —
+        # their caps must not merge); joins and hash exchanges count
+        # their capacities and escalate nothing
         return (self.plan.fingerprint, self._node_index[id(node)], tag)
 
     def _caps(self, node, tag: str, defaults: Dict) -> Dict:
@@ -580,6 +662,7 @@ class DistContext:
                                          self.ex.max_cap_attempts)
         if m is not None:
             m.escalations += attempts[0] - 1
+        self.cap_escalations += attempts[0] - 1
         self.ex._dist_caps_memo[self._memo_key(node, tag)] = \
             dict(final)
         return out
@@ -605,8 +688,40 @@ class DistContext:
         return jax.device_put(arr, self.spec)
 
     def _default_cap(self, *padded_lens) -> int:
-        per_shard = max(max(padded_lens, default=1) // self.n_peers, 1)
-        return max(64, 2 * per_shard)
+        """A shard's slots of the largest input: what a group-by can at
+        most emit. The walk keeps its frames at the size of their live
+        rows (`_settle`), so this is no loose bound."""
+        return max(64, max(padded_lens, default=1) // self.n_peers)
+
+    def _narrowed(self, specs, words_by_side, alive_by_side):
+        """Key words of one or two sides as 32-bit offsets where every
+        word's live range allows (`parallel/relational.narrow_keys`), and
+        the offsets to widen by; (the sides as they were, None) where a
+        key is nullable (its null word is read as such), wide, or a side
+        holds no live row. One pass over the keys, 2 numbers a word read
+        back."""
+        from ..parallel.relational import key_ranges, narrow_keys
+        if any(sp.nullable or sp.n_words != 1 for sp in specs):
+            return words_by_side, None
+        shape = tuple(len(ws) for ws in words_by_side)
+        ranges = np.asarray(_jitted(
+            ("key_ranges", self.mesh, self.axis, shape),
+            key_ranges)(words_by_side, alive_by_side))
+        if any(int(hi) < int(lo) or int(hi) - int(lo) >= (1 << 31) - 1
+               for lo, hi in ranges):
+            return words_by_side, None
+        lo = jnp.asarray(ranges[:, 0])
+        narrowed = _jitted(
+            ("narrow_keys", self.mesh, self.axis, shape),
+            narrow_keys)(words_by_side, alive_by_side, lo)
+        return narrowed, lo
+
+    def _exchange(self):
+        """The span of one movement of data between chips (or to the host,
+        at the sink): opened inside the `plan.op` of the operator that
+        moves it, closed when the data has arrived; `_edge` says what
+        moved."""
+        return span("plan.exchange", peers=self.n_peers)
 
     # -- node dispatch -------------------------------------------------------
     def exec_node(self, node, childs, inputs, schemas, m, metrics):
@@ -622,10 +737,76 @@ class DistContext:
             local = [self.localize(c) for c in childs]
             out = self.ex._exec_eager_node(node, local, inputs, schemas, m)
         if isinstance(out, (ShardedRel, PendingRel)):
+            if isinstance(out, ShardedRel) and \
+                    isinstance(node, (Filter, FusedSelect, HashJoin)):
+                out = self._settle(out)
             m.sharding = out.sharding_str(self.n_peers)
             m.n_peers = self.n_peers
-        elif any(isinstance(c, ShardedRel) for c in childs):
-            m.sharding = "local"
+            self.dist_ops += 1
+        else:
+            below_mesh = any(isinstance(c, ShardedRel) for c in childs)
+            if below_mesh:
+                m.sharding = "local"
+            if below_mesh or any(id(c) in self._off_mesh
+                                 for c in node.children):
+                # an operator that left the mesh, and all above it; the
+                # gather itself is the boundary, not a fallback
+                self._off_mesh.add(id(node))
+                if not (isinstance(node, Exchange) and node.how == "gather"):
+                    self.local_ops += 1
+        return out
+
+    # -- frames at the size of their live rows -------------------------------
+    def _live_counts(self, valid) -> np.ndarray:
+        """(n_peers,) live rows of each shard of a row-sharded mask: the
+        one number a shard the walk reads back to size its next program."""
+        from ..parallel.relational import distributed_live_counts
+        mesh, axis = self.mesh, self.axis
+        return np.asarray(_jitted(
+            ("live_counts", mesh, axis),
+                lambda v: distributed_live_counts(mesh, v, axis))(valid))
+
+    def _settle(self, rel: ShardedRel) -> ShardedRel:
+        """After an operator that drops rows (a filter, a join): read each
+        shard's live count (which the metric loop would read anyway) and,
+        where a quarter of the frame or more is dead, pack every shard's
+        live rows into `bucket(fullest shard)` slots. Everything above then
+        runs at that length: a date window keeps under 1% of a fact
+        table's rows, and a sort or a gather over the other 99% is the
+        cost the capped tier shed in PR 31. A replicated relation is
+        packed whatever its size: it is a build side, every probe row
+        meets every one of its slots, and the 15 days of a calendar
+        filtered on four shards arrive as 60 slots (all 15 on one shard,
+        so each shard keeps 15 slots); at 60 the date joins of 39.6 M and
+        19.8 M probe rows a chip passed `_LOOKUP_WORK` and ran as sort
+        joins, 1.08 s and 0.50 s a request (PERF.md, PR 32)."""
+        from ..parallel.relational import distributed_compact
+        mesh, axis = self.mesh, self.axis
+        if rel.replicated:
+            slots = rel.padded_rows
+            need = rel.num_rows
+        else:
+            slots = rel.padded_rows // self.n_peers
+            counts = self._live_counts(rel.valid)
+            rel._num_rows = int(counts.sum())
+            need = int(counts.max())
+        cap = bucket(need)
+        if 4 * cap > 3 * slots:
+            return rel
+        if slots < _COMPACT_MIN_SLOTS and not rel.replicated:
+            return rel
+        arrays, layout = _pack_cols(rel.table, list(rel.table.names))
+        replicated = rel.replicated
+        fn = _jitted(("compact", mesh, axis, len(arrays), cap, replicated),
+                     lambda *xs: distributed_compact(
+                         mesh, xs[:-1], xs[-1], cap, axis, replicated))
+        outs, valid, lost = fn(*arrays, rel.valid)
+        _raise_if_lost(lost, "compaction", cap)
+        out = ShardedRel(Table(_unpack_cols(list(outs), layout),
+                               names=list(rel.table.names)), valid,
+                         part=rel.part, replicated=replicated,
+                         order_keys=rel.order_keys)
+        out._num_rows = rel._num_rows
         return out
 
     def _try_dist(self, node, childs, inputs, schemas, m, metrics):
@@ -672,9 +853,30 @@ class DistContext:
         (c,) = childs
         if not isinstance(c, ShardedRel) or c.replicated:
             return None
-        mask = node.predicate.evaluate(c.table, c.valid)
+        mask = self._eval(node.predicate, c.table, c.valid)
         return ShardedRel(c.table, c.valid & mask, part=c.part,
                           order_keys=c.order_keys)
+
+    def _eval(self, e, table: Table, valid):
+        """An expression over a sharded relation, as ONE program whose
+        result is born row-sharded. Evaluated eagerly a literal is an
+        array of the relation's whole length on the first device (1.27 GB
+        for a zero column of the store channel), and the next operator
+        reshards it."""
+        from .optimizer import _fp_expr
+        names = list(table.names)
+        arrays, layout = _pack_cols(table, names)
+        n, spec = table.num_rows, self.spec
+
+        def run(valid, *arrays):      # closes over no buffer: it is cached
+            t = Table(_unpack_cols(list(arrays), layout), names=names)
+            v = e.evaluate(t, valid)
+            return jnp.broadcast_to(v, (n,)) if v.ndim == 0 else v
+
+        key = ("expr", self.mesh, self.axis, _fp_expr(e), n,
+               tuple((nm, repr(dt), has_v) for nm, dt, has_v in layout))
+        return _jitted(key, run, out_shardings=spec)(
+            valid, *arrays)
 
     def _dist_project(self, node, childs):
         from .executor import _col_from_array
@@ -688,17 +890,15 @@ class DistContext:
             #                 shards: the gather boundary, as decimal128
         valid = c.valid
         if isinstance(node, FusedSelect):
-            mask = node.predicate.evaluate(c.table, valid)
+            mask = self._eval(node.predicate, c.table, valid)
             valid = valid & mask
         cols = []
         for name, e in node.exprs:
             if isinstance(e, ColumnRef):
                 cols.append(c.table[e.name])
             else:
-                v = e.evaluate(c.table, valid)
-                if getattr(v, "ndim", 1) == 0:
-                    v = jnp.broadcast_to(v, (c.table.num_rows,))
-                cols.append(_col_from_array(v))
+                cols.append(_col_from_array(
+                    self._eval(e, c.table, valid)))
         part = transfer_part(node, [c.part])
         order = None
         if c.order_keys:
@@ -750,17 +950,26 @@ class DistContext:
         return self._repartition(node, c, m)
 
     def _edge(self, m, how: str, logical: int, wire: int, codec: str,
-              copies: int = 1):
+              copies: int = 1, sp=None):
         """Stamp one exchange edge's movement on a metric row: logical =
         unpacked per-column payload, wire = packed bytes actually shipped
         (== logical with packing off). Live payload only, each edge
-        counted once; broadcast passes copies = n_peers-1."""
+        counted once; broadcast passes copies = n_peers-1. `sp`, the
+        edge's `plan.exchange` span, takes the same numbers, and the
+        execution's totals grow by them."""
         m.exchange_how = how
         m.exchange_bytes_logical += logical * copies
         m.exchange_bytes += wire * copies
         if codec:
             m.exchange_codecs = (m.exchange_codecs + ";" + codec
                                  if m.exchange_codecs else codec)
+        self.exchange_edges += 1
+        self.exchange_bytes += wire * copies
+        if sp is not None:
+            sp.set_metadata(how=how, bytes=int(wire * copies),
+                            bytes_logical=int(logical * copies),
+                            codec=_span_text(codec).replace(",", "+")
+                            .replace("=", ":") or "raw")
 
     @staticmethod
     def _reset_edge(m):
@@ -785,12 +994,14 @@ class DistContext:
         live = c.num_rows
         cols = list(c.table.columns)
         logical = live * transport.logical_row_bytes(cols)
-        if self.pack:
-            t, wire_row, codec = self._gather_packed(c)
-            self._edge(m, "gather", logical, live * wire_row, codec)
-        else:
-            t = c.to_local_table()
-            self._edge(m, "gather", logical, logical, "")
+        with self._exchange() as sp:
+            if self.pack:
+                t, wire_row, codec = self._gather_packed(c)
+                self._edge(m, "gather", logical, live * wire_row, codec,
+                           sp=sp)
+            else:
+                t = c.to_local_table()
+                self._edge(m, "gather", logical, logical, "", sp=sp)
         return t
 
     def _gather_packed(self, c: ShardedRel):
@@ -826,26 +1037,28 @@ class DistContext:
         def put(a):
             return jax.device_put(a, rep)
 
-        if self.pack:
-            # host-materialized payload: the dynamic-size codecs
-            # (dict/rle) apply here, and the decode runs on the lifted
-            # (replicated) planes — unpack on the receiving shard
-            hp = transport.pack_host(list(t.columns), list(t.names),
-                                     self.codecs)
-            cols = transport.unpack_host_device(hp, put)
-            self._edge(m, "broadcast", logical, hp.wire_bytes,
-                       hp.codec_str, copies=copies)
-        else:
-            cols = []
-            for c in t.columns:
-                validity = c.validity
-                if validity is not None:
-                    validity = put(validity)
-                cols.append(dataclasses.replace(c, data=put(c.data),
-                                                validity=validity))
-            self._edge(m, "broadcast", logical, logical, "",
-                       copies=copies)
-        valid = put(jnp.ones((t.num_rows,), bool))
+        with self._exchange() as sp:
+            if self.pack:
+                # host-materialized payload: the dynamic-size codecs
+                # (dict/rle) apply here, and the decode runs on the lifted
+                # (replicated) planes — unpack on the receiving shard
+                hp = transport.pack_host(list(t.columns), list(t.names),
+                                         self.codecs)
+                cols = transport.unpack_host_device(hp, put)
+                self._edge(m, "broadcast", logical, hp.wire_bytes,
+                           hp.codec_str, copies=copies, sp=sp)
+            else:
+                cols = []
+                for c in t.columns:
+                    validity = c.validity
+                    if validity is not None:
+                        validity = put(validity)
+                    cols.append(dataclasses.replace(c, data=put(c.data),
+                                                    validity=validity))
+                self._edge(m, "broadcast", logical, logical, "",
+                           copies=copies, sp=sp)
+            valid = put(jnp.ones((t.num_rows,), bool))
+            jax.block_until_ready(valid)
         return ShardedRel(Table(cols, names=list(t.names)), valid,
                           replicated=True)
 
@@ -868,38 +1081,54 @@ class DistContext:
             arrays, layout = _pack_cols(c.table, names)
             wire, codec = logical, ""
         key = ("broadcast", self.mesh, self.axis, len(arrays) + 1)
-        fn = _jitted(key, lambda: jax.jit(
-            lambda *xs: xs, out_shardings=self.rep_spec))
-        outs = fn(*arrays, c.valid)
+
+        fn = _jitted(key,
+            lambda *xs: xs, out_shardings=self.rep_spec)
+        with self._exchange() as sp:
+            outs = jax.block_until_ready(fn(*arrays, c.valid))
+            self._edge(m, "broadcast", logical, wire, codec, copies=copies,
+                       sp=sp)
         if dp is not None:
             out_cols = transport.unpack_device(outs[:-1], dp)
         else:
             out_cols = _unpack_cols(outs[:-1], layout)
-        self._edge(m, "broadcast", logical, wire, codec, copies=copies)
-        return ShardedRel(Table(out_cols, names=names),
-                          outs[-1].astype(jnp.bool_), replicated=True)
+        out = ShardedRel(Table(out_cols, names=names),
+                         outs[-1].astype(jnp.bool_), replicated=True)
+        out._num_rows = live
+        # a filtered dimension arrives as a frame of mostly dead slots
+        return self._settle(out)
 
     def _repartition(self, node, c: ShardedRel, m) -> ShardedRel:
         self._reset_edge(m)
-        rel, logical, wire, codec = self._repartition_rel(
-            node, c, list(node.keys), m, "repart")
-        self._edge(m, "hash", logical, wire, codec)
-        return rel
+        return self._repartition_rel(node, c, list(node.keys), m)
 
-    def _repartition_rel(self, node, c: ShardedRel, keys, m, tag: str):
-        """Hash-exchange a sharded relation by `keys`; returns
-        (repartitioned rel, logical payload bytes, wire bytes, codec
-        string). Key columns ride their 64-bit order-preserving word
-        encoding — logically 8 B x total_words each; with packing on
-        the shipped planes FOR-narrow (transport.narrow_words) and the
-        collective body widens them back for the Spark-exact hash, so
-        placement stays bit-identical while the wire shrinks. Value
-        columns ship packed."""
-        from ..parallel.relational import distributed_repartition_keyed
+    def _repartition_rel(self, node, c: ShardedRel, keys, m) -> ShardedRel:
+        """Hash-exchange a sharded relation by `keys` and stamp the edge
+        on `m` and on its `plan.exchange` span. Key columns ride their
+        64-bit order-preserving word encoding — logically 8 B x
+        total_words each; with packing on the shipped planes FOR-narrow
+        (transport.narrow_words) and the collective body widens them back
+        for the Spark-exact hash, so placement stays bit-identical while
+        the wire shrinks. Value columns ship packed. The buckets are
+        counted first (one pass of compares, 16 numbers read back) and
+        shipped at the size of the fullest: no slack is guessed and none
+        escalates into a second program. The exchange still says whether
+        a bucket spilled, and the walk raises if one did: the count and
+        the exchange are two programs that must hash alike."""
+        from ..parallel.relational import (distributed_partition_counts,
+                                           distributed_repartition_keyed)
         specs = _key_specs(c.table, keys)
         if specs is None or not table_shardable(c.table):
             raise NotImplementedError
         words = _encode_keys(c.table, keys, specs)
+        mesh, axis = self.mesh, self.axis
+        n_words = len(words)
+        counts = np.asarray(_jitted(
+            ("part_counts", mesh, axis, tuple(specs), n_words),
+            lambda *xs: distributed_partition_counts(
+                mesh, xs[:-1], specs, xs[-1], axis))(*words, c.valid))
+        cap = bucket(int(counts.max()))
+        c._num_rows = int(counts.sum())
         vnames = [nm for nm in c.table.names if nm not in set(keys)]
         val_cols = [c.table[nm] for nm in vnames]
         live = c.num_rows
@@ -933,22 +1162,20 @@ class DistContext:
         # the cached jitted callables must close over LOCALS only: a
         # `self` capture would pin the executor (and its plan/LRU graph)
         # in the process-global cache long after the session ends
-        mesh, axis = self.mesh, self.axis
-
-        def run(slack):
-            key = ("repart", mesh, axis, tuple(specs), nw, nv, slack,
-                   word_codecs)
-            fn = _jitted(key, lambda: jax.jit(
-                lambda *arrs: distributed_repartition_keyed(
-                    mesh, list(arrs[:nw]), specs,
-                    list(arrs[nw:nw + nv]), slack=slack, axis=axis,
-                    alive=arrs[nw + nv],
-                    word_codecs=word_codecs or None,
-                    word_refs=list(arrs[nw + nv + 1:]) or None)))
-            return fn(*words, *vals, c.valid, *refs)
-
-        ws, vs, alive, _ = self._retry(
-            node, tag, run, self._caps(node, tag, {"slack": self.slack}), m)
+        key = ("repart", mesh, axis, tuple(specs), nw, nv, cap, word_codecs)
+        fn = _jitted(key,
+            lambda *arrs: distributed_repartition_keyed(
+                mesh, list(arrs[:nw]), specs,
+                list(arrs[nw:nw + nv]), cap, axis=axis,
+                alive=arrs[nw + nv],
+                word_codecs=word_codecs or None,
+                word_refs=list(arrs[nw + nv + 1:]) or None))
+        with self._exchange() as sp:
+            ws, vs, alive, lost = jax.block_until_ready(
+                fn(*words, *vals, c.valid, *refs))
+            _raise_if_lost(lost, "hash exchange", cap)
+            self._edge(m, "hash", live * logical_row, live * wire_row,
+                       codec, sp=sp)
         alive = alive.astype(jnp.bool_)
         if wplans is not None:
             ws = transport.widen_words(list(ws), wplans)
@@ -960,8 +1187,9 @@ class DistContext:
         cols.update({nm: col for nm, col in zip(vnames, unpacked)})
         table = Table([cols[nm] for nm in c.table.names],
                       names=list(c.table.names))
-        return (ShardedRel(table, alive, part=frozenset({tuple(keys)})),
-                live * logical_row, live * wire_row, codec)
+        out = ShardedRel(table, alive, part=frozenset({tuple(keys)}))
+        out._num_rows = live
+        return out
 
     # -- joins ---------------------------------------------------------------
     def _dist_join(self, node, childs, m, metrics):
@@ -994,6 +1222,11 @@ class DistContext:
         lk, rk = list(node.left_keys), list(node.right_keys)
         inner = node.how == "inner"
         l_moved = False
+        if r.replicated and r.padded_rows <= _LOOKUP_SLOTS and \
+                l.padded_rows // self.n_peers * r.padded_rows <= _LOOKUP_WORK:
+            out = self._lookup_join(node, l, r, lk, rk, specs)
+            if out is not None:
+                return out
         # align the sides: already-aligned parts (explicit exchanges ran,
         # or upstream operators preserved a suitable partitioning) join
         # co-located; a replicated right side probes locally; anything
@@ -1003,23 +1236,33 @@ class DistContext:
             # a fault-retried attempt re-describes its implicit edges
             self._reset_edge(m)
             if tuple(lk) not in l.part:
-                l, lg, lwb, lc = self._repartition_rel(node, l, lk, m,
-                                                       "repart_l")
-                self._edge(m, "hash", lg, lwb, lc)
+                l = self._repartition_rel(node, l, lk, m)
                 l_moved = True
             if tuple(rk) not in r.part:
-                r, rg, rwb, rc = self._repartition_rel(node, r, rk, m,
-                                                       "repart_r")
-                self._edge(m, "hash", rg, rwb, rc)
+                r = self._repartition_rel(node, r, rk, m)
         # the output's placement claim must name the tuples the rows are
         # ACTUALLY placed by — the aligned permutation, not the join-key
         # order (hash(b,a) placement claimed as (a,b) would let a
         # downstream consumer elide a required exchange)
         aligned = (None if r.replicated
                    else join_alignment(l.part, r.part, lk, rk))
+        out_names = list(l.table.names) + \
+            (list(r.table.names) if inner else [])
+        if inner and not r.replicated and r.padded_rows < l.padded_rows:
+            # two partitioned sides of an inner join: the shorter one
+            # probes. The optimizer's build_side rule puts the SMALLER
+            # side on the right, as a hash join wants it; this sort-merge
+            # join sorts both sides whichever probes, and sizes its output
+            # frame, its expansion and every column gather by the probe
+            # side's slots
+            l, r, lk, rk = r, l, rk, lk
+            if aligned is not None:
+                aligned = (aligned[1], aligned[0])
 
         l_words = _encode_keys(l.table, lk, specs)
         r_words = _encode_keys(r.table, rk, specs)
+        (l_words, r_words), key_lo = self._narrowed(
+            specs, [l_words, r_words], [l.valid, r.valid])
         lvnames = [nm for nm in l.table.names if nm not in set(lk)]
         lvals, l_layout = _pack_cols(l.table, lvnames)
         if inner:
@@ -1033,43 +1276,60 @@ class DistContext:
         rrep = r.replicated
         mesh, axis, how = self.mesh, self.axis, node.how  # no self capture
 
-        def run(row_cap):
-            key = ("cojoin", mesh, axis, tuple(specs), how,
-                   nlw, nlv, nrv, rrep, row_cap)
-            fn = _jitted(key, lambda: jax.jit(
-                lambda *arrs: distributed_colocated_join_keyed(
-                    mesh, list(arrs[:nlw]),
-                    list(arrs[nlw:nlw + nlv]),
-                    list(arrs[nlw + nlv:2 * nlw + nlv]),
-                    list(arrs[2 * nlw + nlv:2 * nlw + nlv + nrv]),
-                    specs, row_cap=row_cap, axis=axis, how=how,
-                    lalive=arrs[-2], ralive=arrs[-1],
-                    r_replicated=rrep)))
-            return fn(*l_words, *lvals, *r_words, *rvals, l.valid, r.valid)
-
         if inner:
-            cap0 = self._default_cap(l.padded_rows, r.padded_rows
-                                     * (self.n_peers if r.replicated else 1))
-            out = self._retry(node, "join", run,
-                              self._caps(node, "join", {"row_cap": cap0}), m)
-            ws, lvs, rvs, live, _ = out
+            # spans first, then the emission at the size the spans give:
+            # the walk READS each shard's output count between the two
+            # programs, so the frame is the join's rows (no guess for a
+            # fan-out to overflow into another compile, no probe side's
+            # worth of slots for a join that keeps a tenth of it)
+            from ..parallel.relational import (
+                distributed_colocated_join_emit,
+                distributed_colocated_join_spans)
+            spans = _jitted(
+                ("cojoin_spans", mesh, axis, tuple(specs), nlw, rrep),
+                lambda *xs: distributed_colocated_join_spans(
+                    mesh, xs[:nlw], xs[nlw:2 * nlw], specs, axis,
+                    xs[-2], xs[-1], rrep))
+            counts, lo, rorder, totals = spans(*l_words, *r_words,
+                                               l.valid, r.valid)
+            totals = np.asarray(totals)
+            row_cap = bucket(int(totals.max()))
+            emit = _jitted(
+                ("cojoin_emit", mesh, axis, nlw, nlv, nrv, rrep, row_cap),
+                lambda *xs: distributed_colocated_join_emit(
+                    mesh, xs[:nlw], xs[nlw:nlw + nlv],
+                    xs[nlw + nlv:nlw + nlv + nrv], *xs[-3:], row_cap, axis,
+                    rrep))
+            ws, lvs, rvs, live = emit(*l_words, *lvals, *rvals,
+                                      counts, lo, rorder)
+            n_out = int(totals.sum())
         else:
-            ws, lvs, live, _ = run(row_cap=0)
+            key = ("cojoin", mesh, axis, tuple(specs), how, nlw, nlv, rrep)
+            fn = _jitted(key,
+                lambda *arrs: distributed_colocated_join_keyed(
+                    mesh, list(arrs[:nlw]), list(arrs[nlw:nlw + nlv]),
+                    list(arrs[nlw + nlv:2 * nlw + nlv]), specs,
+                    axis=axis, how=how, lalive=arrs[-2], ralive=arrs[-1],
+                    r_replicated=rrep))
+            ws, lvs, live = fn(*l_words, *lvals, *r_words,
+                               l.valid, r.valid)
+            n_out = None
         live = live.astype(jnp.bool_)
+        if key_lo is not None:
+            from ..parallel.relational import widen_keys
+            ws = widen_keys(list(ws), key_lo)
         cols = dict(_decode_keys(ws, specs, lk, live))
         cols.update({nm: col for nm, col
                      in zip(lvnames, _unpack_cols(lvs, l_layout))})
-        names = list(l.table.names)
         if inner:
             # right key columns equal the left keys on every matched row
-            for nm, sp, lkey in zip(rk, specs, lk):
+            for nm, lkey in zip(rk, lk):
                 rc = r.table[nm]
                 cols[nm] = dataclasses.replace(
                     cols[lkey], dtype=rc.dtype,
                     data=cols[lkey].data.astype(rc.dtype.storage_dtype()))
             cols.update({nm: col for nm, col
                          in zip(rvnames, _unpack_cols(rvs, r_layout))})
-            names = names + list(r.table.names)
         if r.replicated:
             part = l.part              # probe side never moved
         elif aligned is None:
@@ -1084,8 +1344,52 @@ class DistContext:
         # (inner emission, shuffled placement) re-orders
         order = l.order_keys if (not inner and r.replicated
                                  and not l_moved) else None
+        out = ShardedRel(Table([cols[nm] for nm in out_names],
+                               names=out_names),
+                         live, part=part, order_keys=order)
+        out._num_rows = n_out
+        return out
+
+    def _lookup_join(self, node, l, r, lk, rk, specs):
+        """A replicated build side of a handful of rows with distinct live
+        keys (what is left of a dimension under a narrow filter): the
+        probe side keeps its frame and its buffers, every row is compared
+        with each build row, and the rows without a partner go dead
+        (`_settle` then packs the rest). None where two build rows share
+        a key: that join fans out, and takes the general path."""
+        from ..parallel.relational import distributed_lookup_join
+        r_words = _encode_keys(r.table, rk, specs)
+        live = np.asarray(r.valid)
+        keys = np.stack([np.asarray(w) for w in r_words], axis=1)[live]
+        if len(np.unique(keys, axis=0)) != len(keys):
+            return None
+        inner = node.how == "inner"
+        l_words = _encode_keys(l.table, lk, specs)
+        rvnames = [nm for nm in r.table.names if nm not in set(rk)] \
+            if inner else []
+        rvals, r_layout = _pack_cols(r.table, rvnames)
+        nw, nrv = len(l_words), len(rvals)
+        mesh, axis = self.mesh, self.axis
+        fn = _jitted(("lookup", mesh, axis, tuple(specs), nw, nrv),
+                     lambda *xs: distributed_lookup_join(
+                         mesh, xs[:nw], xs[nw:2 * nw],
+                         xs[2 * nw:2 * nw + nrv], specs, xs[-2], xs[-1],
+                         axis))
+        outs, matched = fn(*l_words, *r_words, *rvals, l.valid, r.valid)
+        cols = {nm: l.table[nm] for nm in l.table.names}
+        names = list(l.table.names)
+        if inner:
+            for nm, lkey in zip(rk, lk):
+                rc = r.table[nm]
+                cols[nm] = dataclasses.replace(
+                    cols[lkey], dtype=rc.dtype, validity=None,
+                    data=cols[lkey].data.astype(rc.dtype.storage_dtype()))
+            cols.update(zip(rvnames, _unpack_cols(list(outs), r_layout)))
+            names = names + list(r.table.names)
+        valid = (l.valid & ~matched) if node.how == "left_anti" else matched
         return ShardedRel(Table([cols[nm] for nm in names], names=names),
-                          live, part=part, order_keys=order)
+                          valid, part=l.part,
+                          order_keys=None if inner else l.order_keys)
 
     # -- aggregates ----------------------------------------------------------
     def _dist_aggregate(self, node, childs, schemas, m, metrics):
@@ -1096,10 +1400,10 @@ class DistContext:
                        and id(node.child) in self.fused_exchanges)
         if not isinstance(c, ShardedRel) or c.replicated:
             return None
-        if not node.keys:
-            return None       # global aggregate: gather boundary
         if any(o not in _DIST_AGGS for _, o, _ in node.aggs):
             return None
+        if not node.keys and c.num_rows == 0:
+            return None       # SQL's aggregates of no rows: the local tier
         specs = _key_specs(c.table, node.keys)
         if specs is None:
             return None
@@ -1117,8 +1421,10 @@ class DistContext:
                 val_names.append(cn)
             agg_pairs.append((val_names.index(cn),
                               "count" if o == "count" else o))
-        words = _encode_keys(c.table, list(node.keys), specs)
         vals = [c.table[v].data for v in val_names]
+        if not node.keys:
+            return self._reduce(node, c, vals, agg_pairs, schemas, m)
+        words = _encode_keys(c.table, list(node.keys), specs)
         key_cap0 = node.key_cap or self.ex.caps.get("key_cap") or \
             self._default_cap(c.padded_rows)
         elide = (not fused_child) and part_satisfies(c.part, node.keys)
@@ -1127,36 +1433,75 @@ class DistContext:
 
         nw, nv = len(words), len(vals)
         mesh, axis, n_peers = self.mesh, self.axis, self.n_peers
+        valid_in = c.valid
+        slots = c.padded_rows // n_peers
+        groups_in = None
+        if not elide and slots >= _COMPACT_MIN_SLOTS and key_cap0 == slots:
+            # a large frame: merge each shard's rows first, READ how many
+            # groups the fullest shard holds, and run the exchange and
+            # the final merge at that size. One program at key_cap =
+            # the frame would ship and sort n_peers frames for what may
+            # be a few hundred groups.
+            from ..parallel.relational import distributed_head
+            pairs1 = tuple(agg_pairs)
+            fn1 = _jitted(("lgroup", mesh, axis, tuple(specs), nw, nv,
+                           pairs1, slots),
+                lambda *arrs: distributed_local_groupby(
+                    mesh, list(arrs[:nw]), list(arrs[nw:-1]), list(pairs1),
+                    key_cap=slots, axis=axis, alive=arrs[-1]))
+            (words1,), key_lo = self._narrowed(specs, [words], [valid_in])
+            gws1, outs1, gvalid1, _ = fn1(*words1, *vals, valid_in)
+            if key_lo is not None:
+                from ..parallel.relational import widen_keys
+                gws1 = widen_keys(list(gws1), key_lo)
+            counts = self._live_counts(gvalid1)
+            key_cap0 = bucket(int(counts.max()))
+            groups_in = int(counts.sum())
+            k = nw + len(pairs1) + 1
+            heads = _jitted(("head", mesh, axis, k, key_cap0),
+                            lambda *xs: distributed_head(
+                                mesh, xs, key_cap0, axis))(
+                *gws1, *outs1, gvalid1)
+            words, vals = list(heads[:nw]), list(heads[nw:-1])
+            valid_in = heads[-1]
+            agg_pairs = [(j, "sum" if a in ("sum", "count") else a)
+                         for j, (_, a) in enumerate(pairs1)]
+            nv = len(vals)
 
         def run(key_cap):
             if elide:
                 key = ("lgroup", mesh, axis, tuple(specs),
                        nw, nv, tuple(agg_pairs), key_cap)
-                fn = _jitted(key, lambda: jax.jit(
+                fn = _jitted(key,
                     lambda *arrs: distributed_local_groupby(
                         mesh, list(arrs[:nw]),
                         list(arrs[nw:-1]), list(agg_pairs),
-                        key_cap=key_cap, axis=axis, alive=arrs[-1])))
+                        key_cap=key_cap, axis=axis, alive=arrs[-1]))
             else:
                 key = ("group", mesh, axis, tuple(specs),
                        nw, nv, tuple(agg_pairs), key_cap)
-                fn = _jitted(key, lambda: jax.jit(
+                fn = _jitted(key,
                     lambda *arrs: distributed_groupby_keyed(
                         mesh, list(arrs[:nw]), specs,
                         list(arrs[nw:-1]), list(agg_pairs),
-                        key_cap=key_cap, axis=axis, alive=arrs[-1])))
+                        key_cap=key_cap, axis=axis, alive=arrs[-1]))
                 # the all-to-all ships per-group PARTIALS, not rows: one
                 # int64 per key word and per agg partial, for at most
                 # min(live input rows, key_cap per shard) groups — the
                 # payload, counted once (bucket padding/slack excluded,
                 # like every other edge)
                 nbytes[0] = (8 * (nw + len(agg_pairs))
-                             * min(live_in, n_peers * key_cap))
-            return fn(*words, *vals, c.valid)
+                             * (min(live_in, n_peers * key_cap)
+                                if groups_in is None else groups_in))
+            return fn(*words, *vals, valid_in)
 
-        gws, outs, gvalid, _ = self._retry(
-            node, "group", run,
-            self._caps(node, "group", {"key_cap": key_cap0}), m)
+        with (contextlib.nullcontext() if elide else self._exchange()) as sp:
+            gws, outs, gvalid, _ = jax.block_until_ready(self._retry(
+                node, "group", run,
+                self._caps(node, "group", {"key_cap": key_cap0}), m))
+            if not elide:
+                sp.set_metadata(how="hash", bytes=nbytes[0],
+                                bytes_logical=nbytes[0], codec="raw")
         gvalid = gvalid.astype(jnp.bool_)
         if not elide:
             # the fused program's all-to-all ships per-group partials; the
@@ -1174,6 +1519,8 @@ class DistContext:
             tgt.exchange_how = "hash"
             tgt.exchange_bytes = nbytes[0]
             tgt.exchange_bytes_logical = nbytes[0]
+            self.exchange_edges += 1
+            self.exchange_bytes += nbytes[0]
         from ..ops.aggregate import _agg_value_dtype
         cols = dict(_decode_keys(gws, specs, list(node.keys), gvalid))
         for (i, op), arr, (cn, o, out_name) in zip(agg_pairs, outs,
@@ -1196,6 +1543,33 @@ class DistContext:
         return ShardedRel(Table([cols[nm] for nm in names],
                                 names=list(names)),
                           gvalid, part=part, order_keys=list(node.keys))
+
+    def _reduce(self, node, c, vals, agg_pairs, schemas, m):
+        """The keyless aggregate (a rollup's grand total): per-shard
+        reductions of the live rows merged by an all-reduce, one 64-bit
+        partial per aggregate and peer on the wire; the one result row
+        stays on the mesh, so the operators above it do too."""
+        from ..ops.aggregate import _agg_value_dtype
+        from ..parallel.relational import distributed_reduce
+        mesh, axis = self.mesh, self.axis
+        pairs = tuple(agg_pairs)
+        fn = _jitted(("reduce", mesh, axis, len(vals), pairs),
+                     lambda *xs: distributed_reduce(
+                         mesh, xs[:-1], pairs, xs[-1], axis))
+        self._reset_edge(m)
+        with self._exchange() as sp:
+            outs, valid = jax.block_until_ready(fn(*vals, c.valid))
+            nbytes = 8 * len(pairs) * (self.n_peers - 1)
+            self._edge(m, "reduce", nbytes, nbytes, "", sp=sp)
+        cols = []
+        for arr, (cn, o, out_name) in zip(outs, node.aggs):
+            dt = _agg_value_dtype(o, c.table[cn].dtype
+                                  if o != "size" else dtypes.INT64)
+            cols.append(Column(dtype=dt, length=int(arr.shape[0]),
+                               data=arr.astype(dt.storage_dtype())))
+        out = ShardedRel(Table(cols, names=list(schemas[id(node)])), valid)
+        out._num_rows = 1
+        return out
 
     # -- sort / topk ---------------------------------------------------------
     def _dist_sort(self, node, childs, m):
@@ -1239,21 +1613,23 @@ class DistContext:
         def run(slack):
             key = ("sort", mesh, axis, tuple(specs),
                    tuple(node.ascending), nw, nv, slack)
-            fn = _jitted(key, lambda: jax.jit(
+            fn = _jitted(key,
                 lambda *arrs: distributed_sort_keyed(
                     mesh, list(arrs[:nw]), None, list(arrs[nw:-1]),
-                    slack=slack, axis=axis, alive=arrs[-1])))
+                    slack=slack, axis=axis, alive=arrs[-1]))
             return fn(*words, *vals, c.valid)
 
-        ws, vs, valid, _ = self._retry(
-            node, "sort", run, self._caps(node, "sort",
-                                          {"slack": self.slack}), m)
-        valid = valid.astype(jnp.bool_)
         # each live row crosses the range partition once; splitter
         # samples/pool are metadata (uncounted, like bucket counts). A
         # fault-retried attempt re-describes the edge, not accumulates
         self._reset_edge(m)
-        self._edge(m, "range", live * logical_row, live * wire_row, codec)
+        with self._exchange() as sp:
+            ws, vs, valid, _ = jax.block_until_ready(self._retry(
+                node, "sort", run, self._caps(node, "sort",
+                                              {"slack": self.slack}), m))
+            self._edge(m, "range", live * logical_row, live * wire_row,
+                       codec, sp=sp)
+        valid = valid.astype(jnp.bool_)
         # un-invert descending words before decode
         i = 0
         dec_words = []
@@ -1285,20 +1661,29 @@ class DistContext:
         if not all(isinstance(c, ShardedRel) and not c.replicated
                    for c in childs):
             return None
+        from ..parallel.relational import distributed_concat
         names = list(childs[0].table.names)
-        k = len(childs)
-        key = ("concat", self.mesh, self.axis, k)
-        fn = _jitted(key, lambda: jax.jit(
-            lambda *xs: jnp.concatenate(xs), out_shardings=self.spec))
-        cols = []
-        for i, nm in enumerate(names):
-            parts = [c.table.columns[i] for c in childs]
-            data = fn(*[p.data for p in parts])
-            validity = None
-            if any(p.validity is not None for p in parts):
-                validity = fn(*[p.null_mask for p in parts])
-            cols.append(dataclasses.replace(parts[0], data=data,
-                                            validity=validity,
-                                            length=int(data.shape[0])))
-        valid = fn(*[c.valid for c in childs])
+        mesh, axis = self.mesh, self.axis
+        # every shard appends its own rows of each side: UNION ALL owes no
+        # order, and a logical concatenation would reshard the rows
+        fn = _jitted(("concat", mesh, axis, len(childs)),
+            lambda *sides: distributed_concat(mesh, sides, axis))
+        nullable = [any(c.table.columns[i].validity is not None
+                        for c in childs) for i in range(len(names))]
+        sides = []
+        for c in childs:
+            side = [c.valid]
+            for col, nul in zip(c.table.columns, nullable):
+                side.append(col.data)
+                if nul:
+                    side.append(col.null_mask)
+            sides.append(tuple(side))
+        outs = list(fn(*sides))
+        valid, cols, at = outs[0], [], 1
+        for i, nul in enumerate(nullable):
+            data, validity = outs[at], (outs[at + 1] if nul else None)
+            at += 2 if nul else 1
+            cols.append(dataclasses.replace(
+                childs[0].table.columns[i], data=data, validity=validity,
+                length=int(data.shape[0])))
         return ShardedRel(Table(cols, names=names), valid)

@@ -610,6 +610,14 @@ class PlanResult:
         #                               gathers touched (whole chunks over
         #                               the live rows, ops/gather.py) and
         #                               the caps they would have paid
+        self.dist_ops = 0             # SPMD walk (plan/distributed.py):
+        self.local_ops = 0            # operators that ran over the mesh /
+        #                               through the local fallback below a
+        #                               sharded input (the sink's gather
+        #                               apart);
+        self.exchange_edges = 0       # edges that moved data and their
+        self.exchange_bytes = 0       # wire bytes; capacity escalations
+        self.dist_cap_escalations = 0  # of the distributed primitives
         self.cached = False           # served from the serving result cache
         #                               (serving/cache.py): True ONLY on a
         #                               cache-hit COPY — its metrics are
@@ -679,6 +687,11 @@ class PlanExecutor:
         self.caps = dict(caps or {})
         self.max_cap_attempts = max_cap_attempts
         self.op_retries = op_retries
+        if isinstance(mesh, int) and not isinstance(mesh, bool):
+            # a device count: all that a configuration file or a Spark
+            # conf can say of a mesh. More than `jax.devices()` raises.
+            from ..parallel.shuffle import make_mesh
+            mesh = make_mesh(mesh, axis=mesh_axis)
         self.mesh = mesh
         self.mesh_axis = mesh_axis
         self.session = session
@@ -785,6 +798,12 @@ class PlanExecutor:
                             expand_joins=res.expand_joins,
                             gather_slots=res.gather_slots,
                             cap_slots=res.cap_slots)
+            if self.mesh is not None:
+                sp.set_metadata(exchange_edges=res.exchange_edges,
+                                exchange_bytes=res.exchange_bytes,
+                                dist_ops=res.dist_ops,
+                                local_ops=res.local_ops,
+                                dist_cap_escalations=res.dist_cap_escalations)
             return res
 
     def _execute_request(self, plan, inputs, tier,
@@ -940,6 +959,43 @@ class PlanExecutor:
         rep.raise_if_failed("pre-execution gate")
         self._verify_cache[key] = True
 
+    # a bound table of at most this many rows is a dimension whose filter
+    # is counted before exchanges are planned (TPC-DS's largest dimension
+    # that a query filters and broadcasts, `date_dim`, has 73,049 rows; a
+    # million rows are one predicate pass of about a millisecond)
+    _COUNTED_FILTER_ROWS = 1 << 20
+
+    def _counted_filters(self, plan, inputs, store, backend
+                         ) -> Dict[str, int]:
+        """{"filter:<subtree fingerprint>": rows} of every Filter directly
+        over a scan of a small bound table that the stats store has not
+        observed yet, COUNTED (one predicate pass over at most a million
+        rows, one number read back). They go to the optimizer among the
+        bound row counts: what a bound dimension keeps under its filter is
+        as much a fact about the binding as its length. The estimator's
+        guess for a filter is half its input; 15 days of a 73,049-row
+        calendar guessed at 36,524 rows plan a hash exchange of the whole
+        fact side where a broadcast of 15 rows is due, and the first
+        execution of a plan has no observation to correct it."""
+        from .optimizer import subtree_fingerprints
+        out: Dict[str, int] = {}
+        fps = None
+        for node in plan.nodes:
+            if not (isinstance(node, Filter) and isinstance(node.child, Scan)
+                    and not node.child.types):
+                continue
+            t = inputs.get(node.child.source)
+            if not isinstance(t, Table) \
+                    or not 0 < t.num_rows <= self._COUNTED_FILTER_ROWS:
+                continue
+            fps = fps or subtree_fingerprints(plan.root)
+            fp = fps[id(node)]
+            if store is not None and \
+                    store.observed_rows(backend, fp) is not None:
+                continue        # an execution has counted it since
+            out["filter:" + fp] = int(jnp.sum(node.predicate.evaluate(t)))
+        return out
+
     def _optimized(self, plan, inputs, bound):
         """Rewrite `plan` through the rule pipeline, once per (plan,
         binding): repeat executions reuse the cached rewrite (and through
@@ -1002,6 +1058,9 @@ class PlanExecutor:
         if hit is None:
             bound_rows = {n: t.num_rows for n, t in inputs.items()}
             backend = jax.default_backend()
+            if mesh_peers and mesh_peers > 1:
+                bound_rows.update(self._counted_filters(plan, inputs, store,
+                                                        backend))
             opt, report = run_optimizer(
                 plan, bound, bound_rows,
                 float_inputs=floats, streaming_sources=streaming,
@@ -1414,12 +1473,18 @@ class PlanExecutor:
             # the root; implicit here otherwise)
             root_out = root_out.to_local_table()
         wall = (time.perf_counter() - t_plan0) * 1e3
-        return PlanResult(plan, root_out, None, metrics,
-                          "eager", wall,
-                          retries=sum(mm.retries for mm in metrics.values()),
-                          breaker=self._breaker_snapshot(),
-                          backoff_ms=sum(mm.backoff_ms
-                                         for mm in metrics.values()))
+        res = PlanResult(plan, root_out, None, metrics,
+                         "eager", wall,
+                         retries=sum(mm.retries for mm in metrics.values()),
+                         breaker=self._breaker_snapshot(),
+                         backoff_ms=sum(mm.backoff_ms
+                                        for mm in metrics.values()))
+        if dist is not None:
+            res.dist_ops, res.local_ops = dist.dist_ops, dist.local_ops
+            res.exchange_edges = dist.exchange_edges
+            res.exchange_bytes = dist.exchange_bytes
+            res.dist_cap_escalations = dist.cap_escalations
+        return res
 
     # ---- co-placement host subtrees (docs/optimizer.md#placement) ---------
     @staticmethod

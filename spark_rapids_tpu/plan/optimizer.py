@@ -450,6 +450,10 @@ class _Estimator:
 
     def __init__(self, bound_rows: Optional[Dict[str, int]] = None,
                  stats=None, backend: Optional[str] = None, cert=None):
+        # scan source -> rows of the bound table; "filter:<subtree
+        # fingerprint>" -> rows a filter keeps of a small bound table,
+        # where the meshed executor counted it before planning exchanges
+        # (PlanExecutor._counted_filters)
         self.bound = dict(bound_rows or {})
         self.stats = stats          # plan/stats.StatsStore or None
         self.backend = backend
@@ -498,6 +502,10 @@ class _Estimator:
         return got
 
     def _observed(self, node: PlanNode) -> Optional[Tuple[int, int]]:
+        if isinstance(node, Filter) and isinstance(node.child, Scan):
+            rows = self.bound.get("filter:" + self._subtree_fp(node))
+            if rows is not None:
+                return rows, 1
         if self.stats is None or self.backend is None:
             return None
         return self.stats.observed_rows(self.backend,
@@ -1014,13 +1022,14 @@ _RULES = (
 def _statically_distributable(n: PlanNode, float_inputs: bool) -> bool:
     """Whether a node kind CAN run on the mesh — the static half of the
     gate (the executor re-checks runtime properties like column dtypes and
-    gathers gracefully when they fail). Limit and global aggregates have
-    no distributed form; `mean` and any-float inputs disable aggregates
-    (the exchange accumulates partials in exact int64)."""
+    gathers gracefully when they fail). Limit has no distributed form;
+    `mean` and any-float inputs disable aggregates (the exchange
+    accumulates partials in exact int64). A keyless aggregate reduces on
+    the mesh (an all-reduce of per-shard partials)."""
     if isinstance(n, Limit):
         return False
     if isinstance(n, HashAggregate):
-        if not n.keys or any(o == "mean" for _, o, _ in n.aggs):
+        if any(o == "mean" for _, o, _ in n.aggs):
             return False
         if float_inputs:
             return False
@@ -1127,7 +1136,7 @@ def _plan_exchanges(root: PlanNode, ctx: "_Ctx", n_peers: int):
                 else:
                     r_new = add_exchange(r_new, n.right_keys, "hash")
             kids = [l_new, r_new]
-        elif isinstance(n, HashAggregate):
+        elif isinstance(n, HashAggregate) and n.keys:
             (c_new,) = kids
             if isinstance(c_new, Exchange) and c_new.how == "hash":
                 pass                    # authored boundary, keep it
@@ -1384,8 +1393,10 @@ def optimize(plan: Plan,
              ) -> Tuple[Plan, OptimizeReport]:
     """Run the rule pipeline to fixpoint over `plan`. `bound` maps scan
     source -> actual column names and `bound_rows` -> actual row counts
-    (execute() passes both; explain-time callers may pass neither and the
-    schema/estimate-dependent rules degrade gracefully). `float_inputs`
+    (and "filter:<subtree fingerprint>" -> the rows a filter keeps of a
+    small bound table, where the caller counted them; execute() passes
+    both; explain-time callers may pass neither and the schema/estimate-
+    dependent rules degrade gracefully). `float_inputs`
     disables the build_side rule (execute() sets it when any bound column
     is floating point — fp reductions are not reorder-exact).
     `streaming_sources` names the scans bound to streaming (parquet)

@@ -2,9 +2,8 @@
 
 Tests run on a virtual 8-device CPU mesh (the reference's tests likewise never
 need a cluster — SURVEY.md §4 "they don't need to"; multi-tenancy/multi-device
-is simulated). The chip is reached only by `python3 -m chipbench.run` and
-`python chip_smoke.py --chips 4`, through the builder's chip tool — never
-by this suite; what the suite can say about the chip is
+is simulated). The chip is reached only by `python3 -m chipbench.run`,
+through the builder's chip tool — never by this suite; what the suite can say about the chip is
 tests/test_chip_compile.py, which compiles the main path's kernels and
 capped program for a described v5e.
 """

@@ -213,23 +213,44 @@ def test_agg_without_sort_reorders_to_local_kernel_order():
 
 # ---- graceful boundaries ----------------------------------------------------
 
-def test_gather_boundary_below_global_aggregate():
-    """A keyless (global) aggregate has no distributed form: the plan
-    runs distributed up to it, gathers once, and finishes locally."""
-    mesh = _mesh()
-    sales, dims = _tables(seed=13)
-    b = PlanBuilder()
+def _semi_joined(b):
     s = b.scan("sales", schema=["k", "v"]).filter(col("v") > 0)
     d = b.scan("dims", schema=["dk", "grp"]).filter(col("grp") == 1)
-    plan = (s.join(d, left_on="k", right_on="dk", how="left_semi")
-             .aggregate([], [("v", "sum", "total"), ("v", "count", "n")])
-             .build())
+    return s.join(d, left_on="k", right_on="dk", how="left_semi")
+
+
+def test_global_aggregate_reduces_on_the_mesh():
+    """A keyless (global) aggregate reduces each shard's live rows and
+    merges the partials by an all-reduce: its one row stays on the mesh,
+    and the only gather is the sink's."""
+    mesh = _mesh()
+    sales, dims = _tables(seed=13)
+    plan = (_semi_joined(PlanBuilder())
+            .aggregate([], [("v", "sum", "total"), ("v", "count", "n"),
+                            ("v", "min", "lo"), ("v", "max", "hi")])
+            .build())
+    res = _parity(plan, {"sales": sales, "dims": dims}, mesh)
+    agg = next(m for m in res.metrics.values() if m.kind == "HashAggregate")
+    assert agg.sharding.startswith("rows@")
+    assert agg.exchange_how == "reduce" and agg.rows_out == 1
+    gathers = [m for m in res.metrics.values() if m.exchange_how == "gather"]
+    assert len(gathers) == 1 and res.local_ops == 0
+
+
+def test_gather_boundary_below_a_mean():
+    """`mean` has no distributed form: the plan runs distributed up to
+    it, gathers once, and finishes locally; the fallback is counted."""
+    mesh = _mesh()
+    sales, dims = _tables(seed=13)
+    plan = (_semi_joined(PlanBuilder())
+            .aggregate(["k"], [("v", "mean", "avg")]).sort(["k"]).build())
     res = _parity(plan, {"sales": sales, "dims": dims}, mesh)
     agg = next(m for m in res.metrics.values() if m.kind == "HashAggregate")
     # the aggregate ran after the planned gather boundary: its input is a
     # plain local table, never a sharded relation
     assert not agg.sharding.startswith(("hash", "rows", "replicated"))
     assert any(m.exchange_how == "gather" for m in res.metrics.values())
+    assert res.local_ops == 2           # the aggregate and the sort above it
 
 
 def test_float_inputs_keep_aggregate_local_with_parity():

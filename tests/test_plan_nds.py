@@ -21,7 +21,7 @@ N = 15_000
 # query -> (seed, capped-tier caps, presentation-sort columns)
 QUERIES = {
     "q3": (7, {}, ["d_year", "revenue"]),
-    "q5": (3, {"key_cap": 2048}, ["channel", "sales"]),
+    "q5": (3, {"key_cap": 2048}, ["channel", "id"]),
     "q23": (11, {"key_cap": 8192, "row_cap": N}, ["total"]),
     "q72": (5, {}, ["cnt", "i_item_sk", "w_warehouse_sk", "d_week"]),
 }

@@ -493,3 +493,56 @@ def test_pallas_call_site_has_its_stable_name(site):
 
 def test_no_pallas_call_is_unnamed():
     assert set(_pallas_call_names()) == PALLAS_SITES
+
+
+# ---- the SPMD walk's exchanges ---------------------------------------------------
+
+def test_exchange_spans_carry_their_edges_and_sum_to_the_execution(
+        session, monkeypatch):
+    """Every movement of data between devices is one `plan.exchange` span
+    inside the `plan.op` of the operator that moves it, with what
+    `OperatorMetrics` says of the edge; `plan.execute` carries the
+    execution's totals."""
+    from examples.nds import q5_inputs, q5_plan, q5_tables
+    monkeypatch.setenv("SPARK_RAPIDS_TPU_BROADCAST_ROWS", "64")
+    ex = PlanExecutor(mesh=4)
+    plan, inputs = q5_plan(), q5_inputs(*q5_tables(3000, 3))
+    ex.execute(plan, inputs)
+    out = {}
+    spans = session(lambda: out.update(res=ex.execute(plan, inputs)))
+    res = out["res"]
+    execute = spans.one("plan.execute")
+    edges = spans.named("plan.exchange")
+    ops = spans.named("plan.op")
+    assert edges and all(any(inside(e, op) for op in ops) for e in edges)
+    assert {e["how"] for e in edges} == {"hash", "broadcast", "reduce",
+                                         "range", "gather"}
+    for e in edges:
+        assert e["peers"] == 4 and e["request"] == execute["request"]
+        assert 0 < e["bytes"] <= e["bytes_logical"] * 4 and e["codec"]
+    assert sum(e["bytes"] for e in edges) == execute["exchange_bytes"] \
+        == res.exchange_bytes
+    assert execute["exchange_edges"] == len(edges) == res.exchange_edges
+    assert execute["dist_ops"] == res.dist_ops == len(res.plan.nodes) - 1
+    assert execute["local_ops"] == 0 and execute["dist_cap_escalations"] == 0
+    by_metric = sum(m.exchange_bytes for m in res.metrics.values())
+    assert by_metric == res.exchange_bytes
+
+
+def test_collectives_run_under_an_exchange_scope():
+    """The all-to-all of a hash exchange carries `exchange.hash` in the
+    compiled program's op names, as the decimal kernels carry theirs."""
+    from spark_rapids_tpu.parallel import make_mesh
+    from spark_rapids_tpu.parallel.keys import KeySpec
+    from spark_rapids_tpu.parallel.relational import (
+        distributed_reduce, distributed_repartition_keyed)
+    mesh = make_mesh(4)
+    k = jnp.arange(64, dtype=jnp.int64)
+    spec = [KeySpec(dtypes.INT64, 1, False)]
+    text_of = lambda fn, *a: jax.jit(fn).lower(*a).compile().as_text()  # noqa
+    hashed = text_of(lambda k, v: distributed_repartition_keyed(
+        mesh, [k], spec, [v], cap=32), k, k)
+    assert "exchange.hash" in hashed and "all-to-all" in hashed
+    reduced = text_of(lambda v, a: distributed_reduce(
+        mesh, [v], [(0, "sum")], a), k, k > 3)
+    assert "exchange.reduce" in reduced and "all-reduce" in reduced
