@@ -108,6 +108,15 @@ def live_slots(live: int, m: int) -> int:
     return min(-(-min(live, m) // c) * c, m) if m else 0
 
 
+def loop_zeros(shape, dtype, *like):
+    """Zeros to carry through a `fori_loop` whose body writes values read
+    off `like` into them: inside a `shard_map` a carry must enter the loop
+    varying over the mesh axes it leaves varying over."""
+    zeros = jnp.zeros(shape, dtype)
+    axes = frozenset().union(*(jax.typeof(x).vma for x in like))
+    return jax.lax.pcast(zeros, tuple(axes), to="varying") if axes else zeros
+
+
 def gather_live(planes, idx: jnp.ndarray, live) -> tuple:
     """`[jnp.take(p, idx, axis=0) for p in planes]` where only the prefix
     `idx[:live]` is wanted: the gather a capped join pays at its static
@@ -141,7 +150,8 @@ def gather_live(planes, idx: jnp.ndarray, live) -> tuple:
                 o, jnp.take(p, ix, axis=0), at, axis=0)
             for o, p in zip(outs, planes))
 
-    init = tuple(jnp.zeros((m,) + p.shape[1:], p.dtype) for p in planes)
+    init = tuple(loop_zeros((m,) + p.shape[1:], p.dtype, p, idx)
+                 for p in planes)
     return jax.lax.fori_loop(jnp.int32(0), steps, step, init)
 
 

@@ -28,10 +28,17 @@ line), and nowhere else.
      the iota payload) back to original row order. The right-side gather
      map targets come from one boundary-compaction sort that packs
      matchable right rows (in union order) to the front.
-   - expand: exclusive-scan the counts, then jnp.repeat (cumsums and a
-     scatter-add under the hood) recovers (left row, k-th match) for every
-     output slot. Both sides come back as gather maps; -1 marks outer-join
-     non-matches (take() turns them into null rows).
+   - expand (`expand_rows`): exclusive-scan the counts; every left row
+     that emits writes its index at its first output slot, and a running
+     maximum carries it over the row's slots: (left row, k-th match) for
+     every output slot. The writes run in chunks over the left rows up to
+     the last that emits, the two gathers that follow (`lo - starts` at the
+     slot's row, `rorder` at the match) in chunks over the live slots
+     (`gather_live`): both counts are read on the device. The capped
+     inner join's routing sort brings the rows that emit to the front as
+     it routes, so there the writes visit those rows and no other. Both
+     sides come back as gather maps; -1 marks outer-join non-matches
+     (take() turns them into null rows).
    Every join but the capped inner join always takes it.
 
 2b. the many-to-one tail (`_capped_inner_kernel`, the capped inner join
@@ -62,7 +69,7 @@ import jax.numpy as jnp
 from .. import dtypes
 from ..columnar import Column, Table
 from ..utils.tracing import span
-from .gather import gather_live
+from .gather import gather_live, live_chunk, loop_zeros
 from .sort import _key_operands
 
 __all__ = ["inner_join", "left_join", "full_join", "left_semi_join",
@@ -108,10 +115,12 @@ def _run_ends(boundary):
     return jnp.roll(boundary, -1).at[-1].set(True) if n else boundary
 
 
-def _span_tail(boundary, order, m_s, lvalid, *, nl: int, need_rorder: bool):
-    """The general tail over the union sort (`m_s`: 1 at a matchable right
-    row): every left row's match span, for `_expand`. See _join_kernel."""
-    n = order.shape[0]
+def _sorted_spans(boundary, m_s):
+    """Every sorted position's match span in "matchable-right union order"
+    (`m_s`: 1 at a matchable right row): (lo, hi), the exclusive count of
+    matchable right rows at its run's start and the inclusive count at its
+    run's end."""
+    n = boundary.shape[0]
     ends = _run_ends(boundary)
     rcnt = jnp.cumsum(m_s)                       # inclusive matchable count
     excl = rcnt - m_s
@@ -122,20 +131,28 @@ def _span_tail(boundary, order, m_s, lvalid, *, nl: int, need_rorder: bool):
     lo_pos = jax.lax.cummax(jnp.where(boundary, excl, 0))
     hi_pos = jax.lax.cummin(jnp.where(ends, rcnt, jnp.int32(n)),
                             reverse=True)
+    return lo_pos, hi_pos
 
+
+def _matchable_rows(order, m_s, *, nl: int):
+    """Matchable right-row ids packed to the front, in union-sorted order."""
+    n = order.shape[0]
+    flag = jnp.where(m_s == 1, jnp.int32(0), jnp.int32(1))
+    rid = jnp.where(m_s == 1, order - nl, jnp.int32(n))
+    return jax.lax.sort([flag, rid], num_keys=1, is_stable=True)[1]
+
+
+def _span_tail(boundary, order, m_s, lvalid, *, nl: int, need_rorder: bool):
+    """The general tail over the union sort (`m_s`: 1 at a matchable right
+    row): every left row's match span, for `_expand`. See _join_kernel."""
+    lo_pos, hi_pos = _sorted_spans(boundary, m_s)
     # route lo/hi back to original row order: ONE 3-operand sort keyed by
     # the iota payload (order is a permutation, so this inverts it)
     routed = jax.lax.sort([order, lo_pos, hi_pos], num_keys=1)
     lo_orig, hi_orig = routed[1][:nl], routed[2][:nl]
     counts = jnp.where(lvalid, hi_orig - lo_orig, 0)
-
-    if need_rorder:
-        # pack matchable right-row ids (union-sorted order) to the front
-        flag = jnp.where(m_s == 1, jnp.int32(0), jnp.int32(1))
-        rid = jnp.where(m_s == 1, order - nl, jnp.int32(n))
-        rorder = jax.lax.sort([flag, rid], num_keys=1, is_stable=True)[1]
-    else:
-        rorder = jnp.zeros((0,), jnp.int32)
+    rorder = _matchable_rows(order, m_s, nl=nl) if need_rorder \
+        else jnp.zeros((0,), jnp.int32)
     return counts, lo_orig, rorder
 
 
@@ -161,36 +178,105 @@ def _join_kernel(operands, lvalid, rvalid, *, n_ops: int, nl: int,
                       need_rorder=need_rorder)
 
 
+def expand_rows(eff, total: int):
+    """Which left row owns each of `total` output slots, when row `i`
+    emits `eff[i]` of them in row order: `numpy.repeat(arange(nl),
+    eff)[:total]` over the live slots, in proportion to the rows that emit. -> (lsel, starts, live): the owner of every slot, each
+    row's first slot (the exclusive scan of `eff`), and the live slots
+    `min(sum(eff), total)`, a device scalar. `nl` is at least 1.
+
+    An emitting row owns the slots from its `starts` on, and `starts`
+    strictly grows over the emitting rows. So every emitting row writes
+    its own index at its first slot (a row that emits nothing, or starts
+    at or past `total`, writes nowhere), and a running maximum carries
+    the index to the next write. The writes run in a `fori_loop` over
+    whole chunks of the left frame's prefix that ends at the last emitting
+    row (`gather_live`'s chunking; the bound is read on the device), so a
+    frame whose emitting rows are a short prefix pays for the prefix. A
+    slot past the live ones holds the last owner; callers mask it."""
+    nl = eff.shape[0]
+    starts = jnp.cumsum(eff) - eff            # exclusive scan
+    live = jnp.minimum(jnp.sum(eff.astype(jnp.int64)), total)
+    iota = jnp.arange(nl, dtype=jnp.int32)
+    emits = eff > 0
+    c = live_chunk(nl)
+    steps = (jnp.max(jnp.where(emits, iota, -1)) + c) // c
+    at_slot = jnp.where(emits, starts, total).astype(jnp.int32)
+
+    def step(i, owner):
+        at = jnp.minimum(i * jnp.int32(c), nl - c)  # the last chunk clamps
+        return owner.at[jax.lax.dynamic_slice_in_dim(at_slot, at, c)].set(
+            at + jnp.arange(c, dtype=jnp.int32), mode="drop")
+
+    owner = jax.lax.fori_loop(jnp.int32(0), steps, step,
+                              loop_zeros((total,), jnp.int32, eff))
+    return jax.lax.cummax(owner), starts, live
+
+
+def expansion_slots(lmap, live, nl: int, planes: int, packed: bool):
+    """What a capped join's expansion touched, and its frames' size, as
+    device scalars read off the join's own outputs (the executor's
+    `expand_slots` / `expand_cap_slots`): the left rows `expand_rows`'
+    scatter visited plus the slots `planes` gathers over the `live` slots
+    touched, each in whole chunks, against `nl + planes * row_cap`. The
+    live prefix of `lmap` is in left-row order: the scatter visited the
+    rows up to its last entry or, where the join `packed` the rows that
+    emit to the front, as many rows as it names (a join that overflowed
+    is run again)."""
+    row_cap = lmap.shape[0]
+    if nl == 0 or row_cap == 0:
+        return jnp.int64(0), jnp.int64(0)
+    live = live.astype(jnp.int32)
+
+    def touched(rows, m: int):      # ops/gather.py:live_slots, on the device
+        c = jnp.int32(live_chunk(m))
+        return jnp.minimum((rows + c - 1) // c * c, m).astype(jnp.int64)
+    if packed:
+        slot = jnp.arange(row_cap, dtype=jnp.int32)
+        emitting = jnp.sum(((slot == 0) | (lmap != jnp.roll(lmap, 1)))
+                           & (slot < live), dtype=jnp.int32)
+    else:
+        emitting = jnp.where(live > 0,
+                             lmap[jnp.maximum(live - 1, 0)] + 1, 0)
+    return (touched(emitting, nl) + planes * touched(live, row_cap),
+            jnp.int64(nl + planes * row_cap))
+
+
 @partial(jax.jit, static_argnames=("total", "outer"))
-def _expand(counts, lo, rorder, *, total: int, outer: bool, eff=None):
+def _expand(counts, lo, rorder, *, total: int, outer: bool, eff=None,
+            rows=None):
     """`eff`, if given, is the per-row EMIT count (overrides the default
     outer rule of max(counts, 1)): rows with eff 0 produce no output slot,
     so a caller excluding rows (an alive mask) gets a live-slot prefix with
     no permute — output slots are allocated to emitting rows in row order
-    by the exclusive scan."""
+    by the exclusive scan. `rows`, if given, names the left row behind each
+    entry of the spans (a caller that packed them), and the left map reads
+    it. Slots past `min(sum(eff), total)` hold whatever the chunked
+    gathers leave there (ops/gather.py:gather_live)."""
     nl = counts.shape[0]
-    if nl == 0:     # static: empty left side expands to all-dead slots
+    if nl == 0 or total == 0:   # static: nothing expands to all-dead slots
         return (jnp.zeros((total,), jnp.int32),
                 jnp.full((total,), -1, jnp.int32))
     if eff is None:
         eff = jnp.maximum(counts, 1) if outer else counts
-    starts = jnp.cumsum(eff) - eff            # exclusive scan
-    # which left row produced output slot j: repeat row ids by their counts
-    # (jnp.repeat with a static total lowers to cumsum + a sorted-unique
-    # scatter + max-scan — no per-slot binary search, and sorted-unique
-    # scatter is the one fast scatter form on-chip)
-    lsel = jnp.repeat(jnp.arange(nl, dtype=jnp.int32), eff,
-                      total_repeat_length=total)
-    j = jnp.arange(total, dtype=jnp.int32)
-    k = j - jnp.take(starts, lsel, axis=0)
-    matched = jnp.take(counts, lsel, axis=0) > 0
+    lsel, starts, live = expand_rows(eff, total)
+    # slot j is its row's k-th match, k = j - starts[lsel]: rorder's entry
+    # lo[lsel] + k, so ONE gather of (lo - starts) over the live slots
+    planes = {"first": lo - starts}
+    if outer:
+        planes["matches"] = counts
+    if rows is not None:
+        planes["rows"] = rows
+    got = dict(zip(planes, gather_live(planes.values(), lsel, live)))
+    lmap = got.get("rows", lsel)
     if rorder.shape[0] == 0:                  # static shape: empty right side
-        rmap = jnp.full((total,), -1, jnp.int32)
-    else:
-        rpos = jnp.take(lo, lsel, axis=0) + k
-        rmap = jnp.take(rorder, jnp.clip(rpos, 0, rorder.shape[0] - 1), axis=0)
-        rmap = jnp.where(matched, rmap, -1) if outer else rmap
-    return lsel, rmap
+        return lmap, jnp.full((total,), -1, jnp.int32)
+    rpos = jnp.arange(total, dtype=jnp.int32) + got["first"]
+    (rmap,) = gather_live(
+        [rorder], jnp.clip(rpos, 0, rorder.shape[0] - 1), live)
+    if outer:
+        rmap = jnp.where(got["matches"] > 0, rmap, -1)
+    return lmap, rmap
 
 
 def join_spans(operands, lvalid, rvalid, *, nl: int, need_rorder: bool = True):
@@ -371,7 +457,9 @@ def _capped_inner_kernel(operands, lvalid, rvalid, *, n_ops: int, nl: int,
                payload, cut to `row_cap`, and one gather over the live
                prefix (ops/gather.py:gather_live) reads the right row id
                off the union sort's iota.
-    else    -> the general tail: spans, routing sorts and `_expand`.
+    else    -> the general tail: spans, routing sorts (the one that routes
+               the spans also packs the rows that emit to the front) and
+               `_expand`.
     Pair for pair the same (lmap, rmap, valid, overflow)."""
     n = operands[0].shape[0]
     nr = n - nl
@@ -405,10 +493,19 @@ def _capped_inner_kernel(operands, lvalid, rvalid, *, n_ops: int, nl: int,
         return _fit(lrow, row_cap), rrow - nl, total
 
     def general(_):
-        counts, lo, rorder = _span_tail(boundary, order, m_s, lvalid, nl=nl,
-                                        need_rorder=True)
+        lo_pos, hi_pos = _sorted_spans(boundary, m_s)
+        # the routing sort packs as it routes: keyed by the left row where
+        # a row emits and past every row where it does not, it brings the
+        # spans of the rows that emit to the front in left-row order, so
+        # the expansion's scatter visits those rows and no other
+        emit = ~right & (f_s == 1) & (hi_pos > lo_pos)
+        rows, lo, hi = (x[:nl] for x in jax.lax.sort(
+            [jnp.where(emit, order, jnp.int32(n)), lo_pos, hi_pos],
+            num_keys=1))
+        counts = jnp.where(rows < n, hi - lo, 0)
         total = jnp.sum(counts.astype(jnp.int64))  # i32 sum could wrap at 10M×
-        lmap, rmap = _expand(counts, lo, rorder, total=row_cap, outer=False)
+        lmap, rmap = _expand(counts, lo, _matchable_rows(order, m_s, nl=nl),
+                             total=row_cap, outer=False, rows=rows)
         return lmap, rmap, total
 
     lmap, rmap, total = jax.lax.cond(unique, many_to_one, general, None)

@@ -38,6 +38,7 @@ the registry parity suite.
 """
 from __future__ import annotations
 
+from functools import partial
 from typing import List, Optional, Sequence, Tuple
 
 import jax
@@ -51,7 +52,8 @@ from ..dtypes import Kind
 from ..utils.tracing import span
 from .hash_pallas import (_exact_dot, _mm_fmix, _mm_round, _planes,
                           _to_tiles, _u32c, _u16_halves as _halves)
-from .join import _require_x64
+from .gather import gather_live
+from .join import _require_x64, expand_rows
 
 _LANES = 128
 _U32 = jnp.uint32
@@ -386,6 +388,24 @@ def _prep_probe(lcols, rcols, lvalid, rvalid, interpret):
     return counts, lplanes, layout, C, tbl, interpret
 
 
+def emit_planes(lcols: Sequence[Column]) -> int:
+    """Planes `_emit_rows` gathers at every live slot: `starts` and the
+    probe side's key words."""
+    return 1 + sum(_layout_of(lcols)) // 4
+
+
+@partial(jax.jit, static_argnames=("total",))
+def _emit_rows(counts, lplanes, total: int):
+    """-> (lsel, ktgt, sel_planes) for the emit pass's `total` slots: the
+    slot's left row, which of that row's matches it is, and the row's
+    probe planes, gathered over the live slots (ops/join.py:expand_rows,
+    ops/gather.py:gather_live). One program a `total`: called eagerly, the
+    loops' fresh closures would be lowered again on every call."""
+    lsel, starts, live = expand_rows(counts, total)
+    first, *sel_planes = gather_live([starts, *lplanes], lsel, live)
+    return lsel, jnp.arange(total, dtype=jnp.int32) - first, sel_planes
+
+
 def inner_join_pallas(left_keys, right_keys,
                       interpret: Optional[bool] = None):
     """Eager inner equi-join via hash build/probe: gather maps
@@ -408,12 +428,7 @@ def inner_join_pallas(left_keys, right_keys,
         e = jnp.zeros((0,), jnp.int32)
         return (Column(dtype=dtypes.INT32, length=0, data=e),
                 Column(dtype=dtypes.INT32, length=0, data=e))
-    starts = jnp.cumsum(counts) - counts
-    lsel = jnp.repeat(jnp.arange(nl, dtype=jnp.int32), counts,
-                      total_repeat_length=total)
-    ktgt = jnp.arange(total, dtype=jnp.int32) - jnp.take(starts, lsel,
-                                                         axis=0)
-    sel_planes = [jnp.take(p, lsel, axis=0) for p in lplanes]
+    lsel, ktgt, sel_planes = _emit_rows(counts, lplanes, total)
     rmap = _probe_emit(sel_planes, ktgt, layout, C, tbl, interpret)
     return (Column(dtype=dtypes.INT32, length=total, data=lsel),
             Column(dtype=dtypes.INT32, length=total, data=rmap))
@@ -436,12 +451,7 @@ def inner_join_capped_pallas(left_keys, right_keys, row_cap: int, *,
     counts, lplanes, layout, C, tbl, interpret = _prep_probe(
         lcols, rcols, lvalid, rvalid, interpret)
     total = jnp.sum(counts.astype(jnp.int64))
-    starts = jnp.cumsum(counts) - counts
-    lsel = jnp.repeat(jnp.arange(nl, dtype=jnp.int32), counts,
-                      total_repeat_length=row_cap)
-    ktgt = jnp.arange(row_cap, dtype=jnp.int32) - jnp.take(starts, lsel,
-                                                           axis=0)
-    sel_planes = [jnp.take(p, lsel, axis=0) for p in lplanes]
+    lsel, ktgt, sel_planes = _emit_rows(counts, lplanes, row_cap)
     rmap = _probe_emit(sel_planes, ktgt, layout, C, tbl, interpret)
     valid = jnp.arange(row_cap, dtype=jnp.int32) < total
     lmap = jnp.where(valid, lsel, 0)

@@ -131,8 +131,10 @@ def _op_span(node: PlanNode, idx: int, tier: str = "device"):
 
 
 _DECIMAL_OVERFLOW = -1      # key of `_run_capped`'s counts, see there
-_JOIN_UNIQUE = -2           # minus the join's index: key of the flag that
-#                             says which tail its sort join took
+_JOIN_UNIQUE = -2           # minus twice the join's index: key of the flag
+#                             that says which tail its sort join took
+_JOIN_EXPAND = -3           # minus twice the join's index: key of the slots
+#                             its expansion touched, and its frames'
 
 
 def _scope_name(idx: int, node: PlanNode) -> str:
@@ -610,6 +612,12 @@ class PlanResult:
         #                               gathers touched (whole chunks over
         #                               the live rows, ops/gather.py) and
         #                               the caps they would have paid
+        self.expand_slots = 0         # capped tier, over the joins that
+        self.expand_cap_slots = 0     # expanded (the general tail, the
+        #                               Pallas join): left rows the scatter
+        #                               visited plus slots the expansion's
+        #                               gathers touched, and the frames'
+        #                               (ops/join.py:expansion_slots)
         self.dist_ops = 0             # SPMD walk (plan/distributed.py):
         self.local_ops = 0            # operators that ran over the mesh /
         #                               through the local fallback below a
@@ -650,14 +658,17 @@ class PlanResult:
 class _CappedRel:
     """A relation inside the capped trace: padded table + live-row mask;
     `unique`, on a sort join's output, the scalar that says which tail the
-    join took (ops/join.py:inner_join_capped_tail)."""
+    join took (ops/join.py:inner_join_capped_tail); `expanded`, on an inner
+    join's, what its expansion touched (ops/join.py:expansion_slots)."""
 
-    __slots__ = ("table", "alive", "unique")
+    __slots__ = ("table", "alive", "unique", "expanded")
 
-    def __init__(self, table: Table, alive: jnp.ndarray, unique=None):
+    def __init__(self, table: Table, alive: jnp.ndarray, unique=None,
+                 expanded=None):
         self.table = table
         self.alive = alive
         self.unique = unique
+        self.expanded = expanded
 
 
 class PlanExecutor:
@@ -797,7 +808,9 @@ class PlanExecutor:
                             unique_joins=res.unique_joins,
                             expand_joins=res.expand_joins,
                             gather_slots=res.gather_slots,
-                            cap_slots=res.cap_slots)
+                            cap_slots=res.cap_slots,
+                            expand_slots=res.expand_slots,
+                            expand_cap_slots=res.expand_cap_slots)
             if self.mesh is not None:
                 sp.set_metadata(exchange_edges=res.exchange_edges,
                                 exchange_bytes=res.exchange_bytes,
@@ -2428,8 +2441,8 @@ class PlanExecutor:
             # plan whose node labels differ, but its toposort lines up 1:1
             rows_in, rows_out = counts_np[i]
             kernel = kernel_map.get(i, "")
-            if _JOIN_UNIQUE - i in counts_np:
-                kernel += ("/unique" if counts_np[_JOIN_UNIQUE - i][0]
+            if _JOIN_UNIQUE - 2 * i in counts_np:
+                kernel += ("/unique" if counts_np[_JOIN_UNIQUE - 2 * i][0]
                            else "/expand")
             uses_cap = (isinstance(node, HashJoin) and node.how == "inner") \
                 or (isinstance(node, HashAggregate) and node.keys)
@@ -2457,9 +2470,13 @@ class PlanExecutor:
                          jit_cache_hits=cache_hits)
         res.decimal_overflow_rows = counts_np[_DECIMAL_OVERFLOW][0]
         tails = [flag for k, (flag, _) in counts_np.items()
-                 if k <= _JOIN_UNIQUE]
+                 if k <= _JOIN_UNIQUE and k % 2 == 0]
         res.unique_joins = sum(tails)
         res.expand_joins = len(tails) - res.unique_joins
+        for k, (slots, frames) in counts_np.items():
+            if k <= _JOIN_EXPAND and k % 2:
+                res.expand_slots += slots
+                res.expand_cap_slots += frames
         from ..ops.gather import live_slots
         for i, node in enumerate(plan.nodes):
             if isinstance(node, HashJoin) and node.how == "inner":
@@ -2528,8 +2545,10 @@ class PlanExecutor:
                     if ovf is not None:
                         overflow = overflow | ovf
                     if rel.unique is not None:
-                        counts[_JOIN_UNIQUE - i] = (
+                        counts[_JOIN_UNIQUE - 2 * i] = (
                             rel.unique.astype(jnp.int64), jnp.int64(0))
+                    if rel.expanded is not None:
+                        counts[_JOIN_EXPAND - 2 * i] = rel.expanded
                     bytes_map[i] = operand_nbytes(rel.table)
                     rows_in = sum((jnp.sum(c.alive.astype(jnp.int64))
                                    for c in childs), start=jnp.int64(0))
@@ -2601,18 +2620,26 @@ class PlanExecutor:
                     lm, rm, valid, ovf = join_pallas.inner_join_capped_pallas(
                         lkeys, rkeys, row_cap=row_cap, lalive=l.alive,
                         ralive=r.alive)
+                    planes = join_pallas.emit_planes(lkeys)
                 else:
                     lm, rm, valid, ovf, unique = ops.inner_join_capped_tail(
                         lkeys, rkeys, row_cap=row_cap, lalive=l.alive,
                         ralive=r.alive)
+                    planes = 3      # the spans' rows, `lo - starts`, `rorder`
                 # the live rows are a prefix of the capped frame: gather
                 # that prefix, whatever the cap (ops/gather.py:take_live)
                 live = jnp.sum(valid.astype(jnp.int32))
                 cols = ops.take_live(l.table.columns, lm, live) \
                     + ops.take_live(r.table.columns, rm, live)
+                from ..ops.join import expansion_slots
+                expanded = expansion_slots(lm, live, l.table.num_rows, planes,
+                                           packed=unique is not None)
+                if unique is not None:  # the many-to-one tail expands nothing
+                    expanded = tuple(jnp.where(unique, 0, x)
+                                     for x in expanded)
                 t = Table(cols, names=list(l.table.names) +
                           list(r.table.names))
-                return _CappedRel(t, valid, unique), ovf
+                return _CappedRel(t, valid, unique, expanded), ovf
             mask = ops.semi_join_mask(lkeys, rkeys, lalive=l.alive,
                                       ralive=r.alive)
             alive = (l.alive & mask if node.how == "left_semi"

@@ -278,36 +278,53 @@ def test_capped_q3_program_compiles_for_v5e(capped_q3_compiled):
 # output was its own flat gather, and the compiler dropped the unread ones
 Q3_GATHERS_FLAT = 34
 Q3_CODE_BYTES_FLAT = 21_528_064
+# and of the commit before the expansion ran in loops (5541a3e): each
+# join's general branch held jnp.repeat's scatter-add and four flat gathers
+Q3_CODE_BYTES_REPEAT = 21_646_848
 Q3_TASKS_PEAK_HBM = 0.497e9         # the cell's `peak_hbm_gb` (ledger, PR 30)
 
 
 def test_capped_q3_joins_gather_in_loops_and_unread_columns_stay_pruned(
         capped_q3_compiled):
-    """Each join gathers its output columns in one loop per side, and the
+    """Each join gathers its output columns in one loop per side, the
     many-to-one tail its right row ids in another (ops/gather.py:
-    gather_live): six `while`s under the two joins' scopes. A loop carries
-    every column of its side, and the compiler still drops the ones no
-    later operator reads (no more gathers than the flat form had). Code
-    lies in HBM beside the data: the loops may not cost 1% of the cell's
-    peak."""
+    gather_live), and the expansion scatters and gathers in three more
+    (ops/join.py:expand_rows): twelve `while`s under the two joins'
+    scopes. A loop carries every column of its side, and the compiler
+    still drops the ones no later operator reads (no more gathers than
+    the flat form had). Code lies in HBM beside the data: the loops may
+    not cost 1% of the cell's peak, and the expansion in loops is no
+    larger than `jnp.repeat` and its flat gathers were."""
     text = capped_q3_compiled.as_text()
     assert text.count(" gather(") <= Q3_GATHERS_FLAT
     loops = _op_names(text, "while")
-    assert len(loops) == 6
+    assert len(loops) == 12
     assert all(".HashJoin/" in name for name in loops), loops
+    assert sum("jit(_expand)/while" in name for name in loops) == 6
     code = capped_q3_compiled.memory_analysis().generated_code_size_in_bytes
     assert code - Q3_CODE_BYTES_FLAT < 0.01 * Q3_TASKS_PEAK_HBM
+    assert code <= Q3_CODE_BYTES_REPEAT
+
+
+def _gather_slots(text):
+    """Output slots of every `gather` instruction of an executable's text."""
+    import re
+    return [int(re.search(r"\[(\d+)", line.split(" gather(")[0]).group(1))
+            for line in text.splitlines() if " gather(" in line]
 
 
 def test_capped_join_compiles_with_both_tails_for_v5e(one_chip,
                                                       no_persistent_cache):
     """The capped inner join (ops/join.py:_capped_inner_kernel) at a small
     shape: one conditional whose branches are both in the executable, the
-    expansion (its two sorts and jnp.repeat's scatter-add) and the
-    many-to-one tail (its one sort, its one gather in a loop over the
-    live prefix); the union sort is shared, outside."""
+    expansion (its two sorts; its scatter and its gathers in loops over
+    the rows that emit and the slots that are live) and the many-to-one
+    tail (its one sort, its one gather in a loop over the live prefix);
+    the union sort is shared, outside. No gather runs at the cap."""
     from spark_rapids_tpu.ops import join
-    nl, nr, cap = 4096, 512, 1024
+    from spark_rapids_tpu.ops.gather import live_chunk
+    nl, nr, cap = 4096, 512, 2048
+    assert live_chunk(nl) == live_chunk(cap) == 1024
 
     def shape(n, dtype):
         return jax.ShapeDtypeStruct((n,), dtype, sharding=one_chip)
@@ -319,6 +336,39 @@ def test_capped_join_compiles_with_both_tails_for_v5e(one_chip,
     assert text.count(" conditional(") == 1
     assert sorted(names) == [kernel + "cond/branch_0_fun/sort"] * 2 \
         + [kernel + "cond/branch_1_fun/sort", kernel + "sort"]
-    assert kernel + "cond/branch_0_fun/jit(_expand)/scatter-add" in text
+    expand = kernel + "cond/branch_0_fun/jit(_expand)/while"
+    assert _op_names(text, "while").count(expand) == 3
+    assert _op_names(text, "scatter") == [expand + "/body/scatter"]
+    assert expand + "/body/jit(_take)/gather" in text
     # the many-to-one tail's one gather runs in chunks over the live rows
     assert kernel + "cond/branch_1_fun/while/body/jit(_take)/gather" in text
+    assert set(_gather_slots(text)) == {1024}
+
+
+def test_pallas_capped_join_expands_in_loops_for_v5e(one_chip,
+                                                     no_persistent_cache):
+    """The Pallas capped join whole (ops/join_pallas.py), its kernels
+    compiled by Mosaic: between the count pass and the emit pass the
+    expansion is the shared one, a scatter in a loop over the left rows
+    that emit and ONE loop that gathers `starts` and both probe planes
+    over the live slots. No gather runs at the cap."""
+    from spark_rapids_tpu.ops import join_pallas
+    from spark_rapids_tpu.ops.gather import live_chunk
+    nl, nr, cap = 8192, 512, 4096
+    assert live_chunk(nl) == live_chunk(cap) == 1024
+
+    def column(n):
+        return Column(dtype=dtypes.INT64, length=n,
+                      data=jax.ShapeDtypeStruct((n,), jnp.int64,
+                                                sharding=one_chip))
+    text = jax.jit(lambda l, r, alive: join_pallas.inner_join_capped_pallas(
+        [l], [r], cap, lalive=alive, interpret=False)).lower(
+            column(nl), column(nr),
+            jax.ShapeDtypeStruct((nl,), jnp.bool_, sharding=one_chip)
+        ).compile().as_text()
+    assert len(_op_names(text, "while")) == 2
+    assert [n.split("/", 1)[1] for n in _op_names(text, "scatter")] == \
+        ["jit(_emit_rows)/while/body/scatter"]
+    assert _gather_slots(text) == [1024] * 3
+    assert sum("pallas_hash_join_probe/pallas_call" in n
+               for n in _op_names(text, "custom-call")) == 2

@@ -861,6 +861,68 @@ def test_capped_join_tail_edges(shape):
                                             else 0)
 
 
+# ---- expand_spans: the general tail's expansion over its live rows ---------
+
+EXPAND_NL = 3200            # live_chunk(3200) is 1024: three whole chunks of
+#                             left rows and one that clamps back onto the end
+EXPAND_PREFIX = {"empty": 0, "one_chunk": 1024, "mid_chunk": 1500,
+                 "whole_frame": EXPAND_NL}
+
+
+@pytest.mark.parametrize("room", ["under", "at", "over"])
+@pytest.mark.parametrize("mode", ["inner", "outer", "alive"])
+@pytest.mark.parametrize("fan_out", ["0", "1", "15", "mixed"])
+@pytest.mark.parametrize("prefix", list(EXPAND_PREFIX))
+def test_expand_spans_is_numpy_repeat_over_the_live_slots(prefix, fan_out,
+                                                          mode, room):
+    """`expand_spans` against `numpy.repeat`: the rows that match end at
+    `prefix` (no row, a whole chunk of the left frame, inside a chunk, the
+    frame's last row), each with `fan_out` matches; inner, outer, and
+    outer under an alive mask that is no prefix (`eff`); the pairs sum to
+    less than, exactly, and more than `total`, and the first `total` of
+    them come back, slot for slot."""
+    import jax.numpy as jnp
+    from spark_rapids_tpu.ops.gather import live_chunk
+    from spark_rapids_tpu.ops.join import expand_spans
+    assert live_chunk(EXPAND_NL) == 1024
+    rng = np.random.default_rng(
+        [list(EXPAND_PREFIX).index(prefix), len(fan_out), len(mode)])
+    nl, matchable, last = EXPAND_NL, 4000, EXPAND_PREFIX[prefix]
+    counts = np.zeros(nl, np.int32)
+    hit = rng.random(last) < 0.5
+    if last:
+        hit[last - 1] = True            # the prefix ends where it says
+    counts[:last][hit] = {"0": 0, "1": 1, "15": 15}.get(fan_out) \
+        if fan_out != "mixed" else rng.integers(0, 16, int(hit.sum()))
+    lo = rng.integers(0, matchable - 15, nl).astype(np.int32)
+    rorder = np.concatenate([rng.permutation(matchable),
+                             np.full(nl, nl + matchable)]).astype(np.int32)
+    eff = counts
+    if mode != "inner":
+        eff = np.maximum(counts, 1)
+    alive = None
+    if mode == "alive":                 # dead rows emit nothing, wherever
+        alive = rng.random(nl) < 0.6    # they lie
+        eff = np.where(alive, eff, 0).astype(np.int32)
+    pairs = int(eff.sum())
+    total = {"under": pairs + 37, "at": pairs,
+             "over": pairs - pairs // 3 - (pairs > 0)}[room]
+    lsel, rmap = expand_spans(
+        jnp.asarray(counts), jnp.asarray(lo), jnp.asarray(rorder),
+        total=total, outer=mode != "inner",
+        eff=None if alive is None else jnp.asarray(eff))
+    assert lsel.shape == rmap.shape == (total,)
+    want_l = np.repeat(np.arange(nl, dtype=np.int32), eff)
+    k = np.arange(pairs) - np.repeat(np.cumsum(eff) - eff, eff)
+    want_r = np.where(counts[want_l] > 0, rorder[lo[want_l] + k], -1)
+    live = min(pairs, total)
+    np.testing.assert_array_equal(np.asarray(lsel)[:live], want_l[:live])
+    np.testing.assert_array_equal(np.asarray(rmap)[:live], want_r[:live])
+    # a dead slot holds a row of the frame, never an index outside it
+    assert live == total or (0 <= np.asarray(lsel)[live:].min()
+                             and np.asarray(lsel)[live:].max() < nl)
+
+
 # ---- take_live: a capped join's column gathers over its live prefix --------
 
 LIVE_CAP = 2560             # live_chunk(2560) is 1024: two whole chunks and

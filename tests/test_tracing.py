@@ -433,6 +433,54 @@ def test_plan_execute_span_counts_the_slots_the_joins_gathered(session):
         np.asarray(fact["v"].data)[np.asarray(fact["k"].data) % 7 == 0].sum())
 
 
+@pytest.mark.parametrize("join", ["expand", "unique", "pallas"])
+def test_plan_execute_span_counts_the_slots_the_joins_expanded(
+        session, monkeypatch, join):
+    """A join that fans out expands over the left rows that emit and the
+    output slots that are live (ops/join.py:expand_rows), in whole chunks:
+    1,100 of 3,000 left rows match, every second one up to row 2,198, and
+    match twice (three chunks of eight output slots). `expand_slots` /
+    `expand_cap_slots` say so on the result and on `plan.execute`. The
+    sort join's general tail packs the rows that emit (two chunks of left
+    rows) and gathers three planes over the slots; the Pallas join visits
+    the frame up to the last row that emits (all three chunks) and gathers
+    `starts` and an int64 key's two words; a many-to-one join expands
+    nothing."""
+    from spark_rapids_tpu.ops.gather import live_chunk
+    n, cap, hits = 3000, 8192, 1100
+    assert live_chunk(n) == live_chunk(cap) == 1024
+    if join == "pallas":
+        monkeypatch.setenv("SPARK_RAPIDS_TPU_KERNELS", "hash_join=pallas")
+    row = np.arange(n)
+    match = (row < 2 * hits) & (row % 2 == 0)
+    fact = Table([_col(np.where(match, row % 50, 1000)), _col(row)],
+                 names=["k", "v"])
+    dim = _dim() if join == "unique" else Table(
+        [_col(np.arange(100) % 50), _col(np.arange(100) % 7)],
+        names=["dk", "g"])
+    b = PlanBuilder()
+    plan = (b.scan("t", schema=["k", "v"])
+             .join(b.scan("d", schema=["dk", "g"]), left_on="k",
+                   right_on="dk")
+             .aggregate(["g"], [("v", "sum", "total")]).build())
+    inputs = {"t": fact, "d": dim}
+    ex = PlanExecutor(mode="capped", optimize=False,
+                      caps=dict(row_cap=cap, key_cap=64))
+    ex.execute(plan, inputs)                              # compile outside
+    done = []
+    spans = session(lambda: done.append(ex.execute(plan, inputs)))
+    (res,), got = done, spans.one("plan.execute")
+    (rows_out,) = [m.rows_out for m in res.metrics.values()
+                   if m.kind == "HashJoin"]
+    assert rows_out == hits * (1 if join == "unique" else 2)
+    want = {"unique": (0, 0), "expand": (2048 + 3 * 3072, n + 3 * cap),
+            "pallas": (n + 3 * 3072, n + 3 * cap)}[join]
+    assert (res.expand_slots, res.expand_cap_slots) == want
+    assert (got["expand_slots"], got["expand_cap_slots"]) == want
+    assert int(np.asarray(res.compact()["total"].data).sum()) == \
+        rows_out // hits * int(row[match].sum())
+
+
 def test_device_op_owners_is_the_capped_tiers():
     plan, inputs = _join_plan(), {"t": _fact(), "d": _dim()}
     with pytest.raises(Exception, match="capped tier"):
