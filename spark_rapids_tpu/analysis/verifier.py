@@ -227,15 +227,9 @@ def _decimal_typing(e: Expr, coltypes, node, report: "VerifyReport"):
     try:
         if isinstance(e, BinOp) and e.op in _CMP_OPS:
             sides = decimal_sides(e, coltypes.get)
-            if sides is not None and sides[0].scale != sides[1].scale:
-                raise TypeError(
-                    f"{e!r} compares decimals of scales {sides[0].scale} "
-                    f"and {sides[1].scale}; decimals compare at equal "
-                    "scales (state the cast)")
-            if sides is not None and any(
-                    s.kind == dtypes.Kind.DECIMAL128 for s in sides):
-                raise TypeError(f"{e!r}: a comparison over decimal128 "
-                                "limbs is not lowered")
+            if sides is not None:
+                from ..ops.decimal_utils import comparison_scale
+                comparison_scale(*sides)    # TypeError: not lowered
             return True, None
         return True, decimal_type(e, coltypes.get)
     except TypeError as err:

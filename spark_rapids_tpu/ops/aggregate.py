@@ -25,8 +25,9 @@ divide rule's type, cast HALF_UP to decimal(p + 4, s + 4)) and are null on
 overflow. The kernels never see a limb: a decimal is summed as its 32-bit
 planes (`decimal_utils.limb_planes`), each an ordinary int64 sum, and
 `decimal_utils.finish_sum` / `finish_mean` put a group's planes together
-in 256 bits. With decimal payloads the sort kernels sort the keys and a
-row iota alone and gather the planes afterwards.
+in 256 bits. With decimal payloads the sort kernels let up to
+`RIDE_PAYLOADS` planes ride the key sort and, past that, sort the keys and
+a row iota alone and gather the planes afterwards.
 
 A third kernel, `direct`, sorts nothing: for a key cap of at most
 `DIRECT_KEY_CAP` groups and exact (integer, plane) aggregates it finds the
@@ -45,7 +46,9 @@ import jax.numpy as jnp
 from .. import dtypes
 from ..columnar import Column, Table
 from ..dtypes import Kind
+from ..utils.tracing import span
 from .gather import take
+from .scans import running
 from .sort import NULLS_LAST, _key_operands
 
 AGG_OPS = ("sum", "count", "min", "max", "mean", "size")
@@ -55,9 +58,13 @@ AGG_OPS = ("sum", "count", "min", "max", "mean", "size")
 # slots: Q1's 6M-row batch with 26 plane and count payloads ran 167 M rows/s
 # at a key cap of 8 and 110 M at 64 on a v5e, 0.33 ms a slot or 1% of the
 # request each, while the sort kernels' program for the same batch was
-# still compiling after 11 minutes (PERF.md, PR 28). An int64 shape, whose
-# payloads ride the sort, has not been swept: the limit stays at the
-# smaller measured point until one is.
+# still compiling after 11 minutes (PERF.md, PR 28: flat 64-bit scans and
+# 26 gathered planes). Since PR 34 the `scan` kernel's scans run in two
+# levels and it compiles at 60 M rows in 107 s on the chip (`q18.batch`:
+# 15 M groups, two planes riding the sort, 1.76 s a request, 34 M rows/s);
+# Q1's shape under it has not been tried again, and an int64 shape at a
+# handful of groups has not been swept: the limit stays at the smaller
+# measured point until one is.
 DIRECT_KEY_CAP = 8
 # fixed-width kinds min/max read as plain integers
 _EXACT_KINDS = (Kind.DATE32, Kind.TIMESTAMP_US, Kind.TIMESTAMP_S,
@@ -82,12 +89,22 @@ def _agg_value_dtype(op: str, dt: dtypes.DType) -> dtypes.DType:
     return dt  # min/max keep the input type
 
 
+# Most payloads that ride the main sort where a caller asks for gathers
+# (decimal planes). A 32-bit operand that rides costs the sort one more
+# word a row; gathered by the sort's order it costs 7.0-7.1 ns a row
+# (PERF.md, PR 31). Q18's two planes ride; Q1's 26 would make a sort of
+# 28 operands, which is the program that did not compile (PR 28).
+RIDE_PAYLOADS = 4
+
+
 def _sort_with_payloads(key_operands, iota, payloads, n_ops: int,
                         gather: bool):
     """The main key sort -> (sorted key operands, order, payloads in that
     order). The payloads ride the sort as operands, or, with `gather`
-    (decimal planes: a dozen operands would ride), the sort moves the keys
-    and the iota alone and the payloads are gathered by it afterwards."""
+    (decimal planes: a dozen operands would ride) and more than
+    `RIDE_PAYLOADS` of them, the sort moves the keys and the iota alone
+    and the payloads are gathered by it afterwards."""
+    gather = gather and len(payloads) > RIDE_PAYLOADS
     operands = [*key_operands, iota] + ([] if gather else list(payloads))
     sorted_all = jax.lax.sort(operands, num_keys=n_ops, is_stable=True)
     order = sorted_all[n_ops]
@@ -107,9 +124,16 @@ def _groupby_kernel(key_operands, agg_datas, agg_valids, *, n_ops: int,
     On-chip primitive costs (round-2 TPU measurement, recorded in
     docs/architecture.md "Sorts, cumsums and gathers"; the sweep
     tool and its CPU capture left the tree with PR 30 and are in git
-    history — the chip numbers were not re-measured since;
-    10M rows): sort ≈ 38 ms with cheap marginal payload operands, cumsum ≈
-    16 ms, but a RANDOM GATHER ≈ 160 ms and a random scatter ≈ 930 ms. The
+    history; 10M rows): sort ≈ 38 ms with cheap marginal payload operands,
+    cumsum ≈ 16 ms, but a RANDOM GATHER ≈ 160 ms and a random scatter ≈
+    930 ms. `q18.batch`'s traced run (PERF.md, PR 34; 60 M rows into 15 M
+    groups, per 10 M rows) bears the order out and corrects the sizes:
+    the key sort of an int64 key, the iota and two 32-bit planes 65 ms,
+    the compaction sort with its seven payload words 101 ms, a 64-bit
+    cumsum in two levels 8.5 ms, a gather of int64 keys 183 ms per 10 M
+    slots (18 ns a slot at this size; 7 ns at 360,000 slots, PR 31), a
+    scatter 7 ns a row where no two rows write one slot (PR 33) and 87
+    ns where they collide (`jnp.nonzero`'s, PR 34). The
     tradeoff is BACKEND-SPECIFIC: on CPU a random scatter-add costs ~163 ms
     against ~233 ms per tuple-carry scan (same CPU capture), so this design
     measures ~0.49× the old scatter-based kernel there (an A/B of the
@@ -209,12 +233,14 @@ def _groupby_kernel(key_operands, agg_datas, agg_valids, *, n_ops: int,
     totals = {}          # comp_pay slot -> cumsum grand total (traced scalar)
     for (d_slot, v_slot), op in zip(slots, agg_kinds):
         ok = (spay[v_slot] == 1) if v_slot is not None else None
+        # the non-null count: without a validity it is the group's size
+        # (read off the compacted starts, no scan); with one, a running
+        # count in 32 bits (n rows fit an int32 iota already)
         cnt_slot = None
-        if op != "size":
-            okv = ok if ok is not None else jnp.ones((n,), bool)
-            csum = jnp.cumsum(okv.astype(jnp.int64))
-            excl = csum - okv.astype(jnp.int64)
-            total = csum[-1] if n else jnp.int64(0)
+        if ok is not None:
+            csum = running(ok.astype(jnp.int32))
+            excl = csum - ok.astype(jnp.int32)
+            total = csum[-1] if n else jnp.int32(0)
             cnt_slot = len(comp_pay)
             totals[cnt_slot] = total
             comp_pay.append(jnp.where(boundary, excl, total))
@@ -233,7 +259,8 @@ def _groupby_kernel(key_operands, agg_datas, agg_valids, *, n_ops: int,
                                  cnt_slot))
             else:
                 acc = jnp.where(okv, v.astype(jnp.int64), jnp.int64(0))
-                csum = jnp.cumsum(acc)
+                # a frame-long 64-bit scan, in two levels (ops/scans.py)
+                csum = running(acc)
                 excl = csum - acc
                 total = csum[-1] if n else jnp.int64(0)
                 slot = len(comp_pay)
@@ -290,9 +317,10 @@ def _groupby_kernel(key_operands, agg_datas, agg_valids, *, n_ops: int,
 
     outs = []
     for (slot, mode, cnt_slot), op in zip(agg_comp, agg_kinds):
-        cnt = None
+        cnt = sizes
         if cnt_slot is not None:
-            cnt = adj_diff_total(comp[cnt_slot], totals[cnt_slot])
+            cnt = adj_diff_total(comp[cnt_slot], totals[cnt_slot]) \
+                .astype(jnp.int64)
         if op == "size":
             outs.append((sizes, None))
         elif op == "count":
@@ -555,7 +583,27 @@ def groupby_aggregate(table: Table,
     `_cap` is internal (see groupby_aggregate_capped): a static output size
     that makes the whole aggregation traceable under jax.jit. `_alive` is a
     (num_rows,) bool excluding padded rows entirely (see
-    groupby_aggregate_capped's `alive`)."""
+    groupby_aggregate_capped's `alive`).
+
+    The kernel and its finish run in an `ops.groupby` span (with `rows`,
+    `groups`: the count once it is read, the key cap under a cap; `kernel`
+    and `planes`, the 32-bit decimal planes summed) and under the scope of
+    that name, which a capped program's `device_op_owners(nested=True)`
+    reads back below its operator's."""
+    with span("ops.groupby", rows=table.num_rows) as sp, \
+            jax.named_scope("ops.groupby"):
+        out = _groupby(table, key_names, aggs, _cap, _alive, sp)
+        if _cap is None:
+            # the eager tier: blocked inside the span, so that the span
+            # holds the finish's device work too (the group count's read
+            # has waited for the kernel already)
+            jax.block_until_ready([c.data for c in out.columns])
+        return out
+
+
+def _groupby(table, key_names, aggs, _cap, _alive, sp):
+    """`groupby_aggregate` inside its span `sp`, which it stamps with the
+    groups, the kernel and the planes once they are known."""
     keys = [table[k] for k in key_names]
     if not keys:
         raise ValueError("groupby requires at least one key column")
@@ -641,6 +689,8 @@ def groupby_aggregate(table: Table,
         # must accept small batches, and a too-small cap must be retryable
         # with a bigger one regardless of n)
         g = min(_cap, n)
+    sp.set_metadata(groups=g if _cap is None else _cap, kernel=choice.name,
+                    planes=sum(len(p) for p, _ in decimal_parts.values()))
     # padded entries hold n: clip for the gathers — rows past num_groups are
     # garbage by contract, masked by the capped valid vector
     first_sorted = jnp.clip(first_sorted, 0, max(n - 1, 0))

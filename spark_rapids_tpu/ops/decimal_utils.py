@@ -445,16 +445,55 @@ def arithmetic(op: str, a: Column, b: Column, alive=None) -> Column:
         return _null_overflow(res, ovf.data, alive)
 
 
-def compare_operands(a: Column, b: Column):
-    """The two unscaled values a comparison reads. Equal scales only (Spark
-    would cast both sides to one type first; a plan states that cast)."""
+def comparison_scale(lt: dtypes.DType, rt: dtypes.DType) -> int:
+    """The scale a comparison casts its two decimal sides to: Spark's
+    wider common type has the larger scale (`DecimalPrecision`), and a
+    side of the smaller scale is rescaled, which is exact. TypeError where
+    that is not lowered: the rescaled side must stay within 18 digits (a
+    literal, an int64 column; a plan states any other cast). The verifier
+    asks here too."""
+    scale = max(lt.scale, rt.scale)
+    for dt in (lt, rt):
+        if dt.scale < scale and (
+                dt.precision + scale - dt.scale > dtypes.MAX_DEC64_PRECISION):
+            raise TypeError(
+                f"a comparison between {lt} and {rt} rescales the side of "
+                "the smaller scale past 18 digits (state the cast)")
+    return scale
+
+
+def compare(op: str, a: Column, b: Column) -> jnp.ndarray:
+    """`a op b` (a comparison) over two decimal or integral columns, as a
+    bool array, both sides at `comparison_scale`. A DECIMAL128 side is
+    compared limb by limb, the top one signed. A column of one row (a
+    literal) broadcasts."""
     lt, rt = as_decimal_type(a.dtype), as_decimal_type(b.dtype)
-    if lt is None or rt is None or lt.scale != rt.scale:
-        raise TypeError(f"comparison between {a.dtype} and {b.dtype}: "
-                        "decimals compare at equal scales")
-    if Kind.DECIMAL128 in (a.dtype.kind, b.dtype.kind):
-        raise TypeError("comparison over decimal128 limbs is not lowered")
-    return a.data.astype(jnp.int64), b.data.astype(jnp.int64)
+    if lt is None or rt is None:
+        raise TypeError(f"comparison between {a.dtype} and {b.dtype}")
+    scale = comparison_scale(lt, rt)
+
+    def at_scale(col: Column, dt: dtypes.DType) -> Column:
+        up = scale - dt.scale
+        if not up:
+            return col
+        with jax.named_scope("decimal.rescale"):
+            data = col.data.astype(jnp.int64) * (10 ** up)
+        return Column(dtype=dtypes.decimal(dt.precision + up, scale),
+                      length=col.length, data=data, validity=col.validity)
+    a, b = at_scale(a, lt), at_scale(b, rt)
+    if Kind.DECIMAL128 not in (a.dtype.kind, b.dtype.kind):
+        x, y = a.data.astype(jnp.int64), b.data.astype(jnp.int64)
+        less, equal = x < y, x == y
+    else:
+        x, y = widen(a).data, widen(b).data           # (n, 4) uint32
+        signed = lambda w: jax.lax.bitcast_convert_type(w, jnp.int32)
+        less = signed(x[:, 3]) < signed(y[:, 3])
+        equal = x[:, 3] == y[:, 3]
+        for j in (2, 1, 0):
+            less = less | (equal & (x[:, j] < y[:, j]))
+            equal = equal & (x[:, j] == y[:, j])
+    return {"<": less, "<=": less | equal, ">": ~(less | equal),
+            ">=": ~less, "==": equal, "!=": ~equal}[op]
 
 
 def literal_column(value: int, n: int) -> Column:
@@ -466,19 +505,19 @@ def literal_column(value: int, n: int) -> Column:
 # ---- decimal aggregates --------------------------------------------------------
 
 def limb_planes(col: Column) -> List[jnp.ndarray]:
-    """The unscaled value as sum(plane_j * 2**(32 j)), every plane an int64
-    array of 32-bit magnitude (the top one signed), so that an int64 sum
-    of a plane over up to 2**31 rows is exact. A group-by sums planes;
-    `finish_sum` puts them together."""
+    """The unscaled value as sum(plane_j * 2**(32 j)), every plane a
+    32-bit array (unsigned, the top one signed) that a group-by kernel
+    widens to int64 as it sums, so that the sum of a plane over up to
+    2**31 rows is exact and a plane costs a sort or a gather one word a
+    row. A group-by sums planes; `finish_sum` puts them together."""
     if col.dtype.kind == Kind.DECIMAL128:
         d = col.data
-        return [d[:, j].astype(jnp.int64) for j in range(3)] + [
-            jax.lax.bitcast_convert_type(d[:, 3], jnp.int32)
-            .astype(jnp.int64)]
-    v = col.data.astype(jnp.int64)
+        return [d[:, j] for j in range(3)] + [
+            jax.lax.bitcast_convert_type(d[:, 3], jnp.int32)]
     if col.dtype.kind == Kind.DECIMAL32:
-        return [v]
-    return [v & jnp.int64(0xFFFFFFFF), v >> 32]
+        return [col.data.astype(jnp.int32)]
+    v = col.data.astype(jnp.int64)
+    return [v.astype(jnp.uint32), (v >> 32).astype(jnp.int32)]
 
 
 def _planes_total(planes) -> jnp.ndarray:
@@ -495,18 +534,25 @@ def _planes_total(planes) -> jnp.ndarray:
     return total
 
 
-def _sum_column(planes, count, dt: dtypes.DType):
-    """-> (the sum as a DECIMAL128-layout column of Sum's type, null for
-    an all-null group and on overflow; its (g, 8) limbs)."""
-    st = sum_type(dt)
+@functools.partial(jax.jit, static_argnames=("precision",))
+def _total_limbs(planes, precision: int):
+    """Per-group plane sums -> (the total's low 128 bits as (g, 4) uint32
+    limbs, whether it passes `precision` digits). One program: called
+    eagerly over millions of groups, the 256-bit intermediates ((g, 8)
+    uint64, 64 bytes a group each) would each be an array in memory."""
     with jax.named_scope("decimal.sum"):
         total = _planes_total(planes)
-        overflow = _exceeds(total, st.precision)
-    g = total.shape[0]
+        return d256.to_i128_limbs(total), _exceeds(total, precision)
+
+
+def _sum_column(planes, count, dt: dtypes.DType):
+    """-> (the sum as a DECIMAL128-layout column of Sum's type, null for
+    an all-null group and on overflow; Sum's type)."""
+    st = sum_type(dt)
+    limbs, overflow = _total_limbs(tuple(planes), st.precision)
     col = Column(dtype=dtypes.DType(Kind.DECIMAL128, precision=st.precision,
                                     scale=st.scale),
-                 length=g, data=d256.to_i128_limbs(total),
-                 validity=count > 0)
+                 length=limbs.shape[0], data=limbs, validity=count > 0)
     return _null_overflow(col, overflow), st
 
 

@@ -69,7 +69,7 @@ import jax.numpy as jnp
 from .. import dtypes
 from ..columnar import Column, Table
 from ..utils.tracing import span
-from .gather import gather_live, live_chunk, loop_zeros
+from .gather import gather_live, kept_rows, live_chunk, loop_zeros
 from .sort import _key_operands
 
 __all__ = ["inner_join", "left_join", "full_join", "left_semi_join",
@@ -580,7 +580,7 @@ def left_semi_join(left_keys, right_keys,
     """Left rows having >=1 match (gather map into the left table)."""
     counts, _, _ = _prep(_cols(left_keys), _cols(right_keys), null_equal,
                          need_rorder=False)
-    keep = jnp.nonzero(counts > 0)[0].astype(jnp.int32)
+    keep = kept_rows(counts > 0)
     return Column(dtype=dtypes.INT32, length=int(keep.shape[0]), data=keep)
 
 
@@ -591,5 +591,5 @@ def left_anti_join(left_keys, right_keys,
     NOT IN null semantics are built on top by the plugin)."""
     counts, _, _ = _prep(_cols(left_keys), _cols(right_keys), null_equal,
                          need_rorder=False)
-    keep = jnp.nonzero(counts == 0)[0].astype(jnp.int32)
+    keep = kept_rows(counts == 0)
     return Column(dtype=dtypes.INT32, length=int(keep.shape[0]), data=keep)

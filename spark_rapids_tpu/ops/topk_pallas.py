@@ -168,6 +168,12 @@ def _topk_words(words: List[jnp.ndarray], k: int, n: int,
     return [m[:k] for m in merged]
 
 
+# The eager tier's entry: one program a (k, rows). Called outside a jit,
+# `pl.pallas_call` wraps a fresh closure and is lowered again on every
+# request (ROADMAP S4; `q18.batch` compiled once a request, PR 34).
+_topk_words_once = jax.jit(_topk_words, static_argnums=(1, 2, 3, 4))
+
+
 def topk_table(table: Table, keys: Sequence[str],
                ascending: Sequence[bool], n: int,
                block_rows: int = 128 * 128,
@@ -181,7 +187,7 @@ def topk_table(table: Table, keys: Sequence[str],
         return Table([take(c, empty, _has_negative=False)
                       for c in table.columns], names=table.names)
     words = _order_words(table, keys, ascending, alive=None)
-    merged = _topk_words(words, m, rows, block_rows, interpret)
+    merged = _topk_words_once(words, m, rows, block_rows, interpret)
     idx = merged[-1].astype(jnp.int32)      # iota word; no sentinels in the
     #                                         first m entries: real rows
     #                                         always precede padding

@@ -32,6 +32,7 @@ from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from ..ops.join import expand_spans, join_spans
+from ..ops.scans import running as _running
 from .shuffle import build_partition_map, partition_ids
 
 _AGGS = ("sum", "count", "min", "max")
@@ -60,41 +61,6 @@ def _fit(x: jnp.ndarray, cap: int, fill) -> jnp.ndarray:
     if n >= cap:
         return x[:cap]
     return jnp.concatenate([x, jnp.full((cap - n,), fill, x.dtype)])
-
-
-# `_running` scans in two levels from 16 blocks of 4,096 on. Compiled for
-# a described v5e (no chip; PERF.md, PR 32), an int64 `cumsum` of 65,535
-# elements takes 6.6 s flat, of 65,536 16.7 s flat and 2.0 s in two
-# levels, of 131,072 28.3 s and 3.5 s, of 425,984 121.6 s and 1.9 s; on
-# the chip the count of 39.6 M rows runs in 10.1 ms flat and 5.2 ms in
-# two levels. The block is the one size tried.
-_SCAN_BLOCK = 4096
-
-
-def _running(x: jnp.ndarray, op: str = "sum") -> jnp.ndarray:
-    """Inclusive running sum (or maximum) of a 1-D array, in two levels
-    from 16 blocks on: a scan inside blocks of 4,096, a scan over the
-    blocks' totals, one elementwise merge. The chip's compiler takes a
-    64-bit scan of a long vector as one emulated reduce-window, and its
-    compile time grows with the length."""
-    scan = jnp.cumsum if op == "sum" else jax.lax.cummax
-    n = x.shape[0]
-    if n < 16 * _SCAN_BLOCK:
-        return scan(x, axis=0)
-    pad = (-n) % _SCAN_BLOCK
-    if pad:
-        fill = 0 if op == "sum" else jnp.iinfo(x.dtype).min
-        x = jnp.concatenate([x, jnp.full((pad,), fill, x.dtype)])
-    inner = scan(x.reshape(-1, _SCAN_BLOCK), axis=1)
-    totals = inner[:, -1]
-    if op == "sum":
-        before = jnp.cumsum(totals) - totals
-        out = inner + before[:, None]
-    else:
-        lowest = jnp.full((1,), jnp.iinfo(x.dtype).min, x.dtype)
-        before = jnp.concatenate([lowest, jax.lax.cummax(totals)[:-1]])
-        out = jnp.maximum(inner, before[:, None])
-    return out.reshape(-1)[:n]
 
 
 def _identity(op: str) -> int:

@@ -153,8 +153,10 @@ _SCOPE = re.compile(r"^\d+\.[A-Za-z]+$")
 def _scope_owners(hlo_text: str, nested: bool = False) -> Dict[str, str]:
     """{instruction name: its outermost `<idx>.<kind>` scope} over every
     computation of an executable's text (`_scope_name` wrote the scopes).
-    `nested`: followed by `/decimal.<op>`, the innermost scope a decimal
-    kernel (ops/decimal_utils.py) opened below the operator's."""
+    `nested`: followed by the innermost scope a kernel opened below the
+    operator's: `/decimal.<op>` (ops/decimal_utils.py) or, outside any
+    of those, `/ops.groupby` (ops/aggregate.py: the group-by's kernel and
+    the rest of its finish)."""
     owners: Dict[str, str] = {}
     for line in hlo_text.splitlines():
         m = _HLO_INSTRUCTION.match(line)
@@ -164,7 +166,8 @@ def _scope_owners(hlo_text: str, nested: bool = False) -> Dict[str, str]:
                        if _SCOPE.match(p)), None)
             if at is not None:
                 inner = [p for p in parts[at + 1:]
-                         if p.startswith("decimal.")] if nested else []
+                         if p.startswith("decimal.") or p == "ops.groupby"] \
+                    if nested else []
                 owners[m.group(1)] = "/".join(parts[at:at + 1] + inner[-1:])
     return owners
 
@@ -612,6 +615,11 @@ class PlanResult:
         #                               gathers touched (whole chunks over
         #                               the live rows, ops/gather.py) and
         #                               the caps they would have paid
+        self.group_rows = 0           # over the request's keyed aggregates:
+        self.groups = 0               # rows in, groups out, and the slots
+        self.group_slots = 0          # their finish ran over (the groups in
+        #                               the eager tier, the key caps in the
+        #                               capped)
         self.expand_slots = 0         # capped tier, over the joins that
         self.expand_cap_slots = 0     # expanded (the general tail, the
         #                               Pallas join): left rows the scatter
@@ -804,7 +812,10 @@ class PlanExecutor:
             res = self._execute_request(plan, inputs, tier, placement)
             if nulled:      # eager tiers: one read-back, decimal plans only
                 res.decimal_overflow_rows += int(sum(nulled))
+            self._count_groups(res)
             sp.set_metadata(decimal_overflow_rows=res.decimal_overflow_rows,
+                            group_rows=res.group_rows, groups=res.groups,
+                            group_slots=res.group_slots,
                             unique_joins=res.unique_joins,
                             expand_joins=res.expand_joins,
                             gather_slots=res.gather_slots,
@@ -818,6 +829,22 @@ class PlanExecutor:
                                 local_ops=res.local_ops,
                                 dist_cap_escalations=res.dist_cap_escalations)
             return res
+
+    def _count_groups(self, res: PlanResult) -> None:
+        """`group_rows`, `groups`, `group_slots` of a result, from its
+        operators' metrics (a cached or degraded result keeps its own)."""
+        if res.cached or res.group_slots:
+            return
+        for i, node in enumerate(res.plan.nodes):
+            m = res.metrics.get(node.label)
+            if not isinstance(node, HashAggregate) or not node.keys \
+                    or m is None:
+                continue
+            res.group_rows += int(m.rows_in)
+            res.groups += int(m.rows_out)
+            res.group_slots += (self._node_cap(res.caps, "key_cap", i)
+                                if res.mode == "capped" and res.caps
+                                else int(m.rows_out))
 
     def _execute_request(self, plan, inputs, tier,
                          placement) -> PlanResult:

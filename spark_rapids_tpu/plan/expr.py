@@ -173,13 +173,15 @@ class BinOp(Expr):
         if decimal_sides(self, _types_of(table)) is None:
             return super().column(table, alive)
         n = table.num_rows
-        l, r = (decimal_utils.literal_column(e.value, n)
+        compares = self.op in _CMP_OPS
+        # a literal beside a comparison is one row, which broadcasts
+        l, r = (decimal_utils.literal_column(e.value, 1 if compares else n)
                 if isinstance(e, Literal) else e.column(table, alive)
                 for e in (self.left, self.right))
-        if self.op in _CMP_OPS:
-            x, y = decimal_utils.compare_operands(l, r)
+        if compares:
             return Column(dtype=dtypes.BOOL, length=n,
-                          data=_BIN_FNS[self.op](x, y))
+                          data=jnp.broadcast_to(
+                              decimal_utils.compare(self.op, l, r), (n,)))
         return decimal_utils.arithmetic(self.op, l, r, alive)
 
     def __repr__(self):

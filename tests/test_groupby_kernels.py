@@ -134,3 +134,73 @@ def test_dispatch_default_is_scatter_on_cpu(monkeypatch):
     monkeypatch.setenv("SPARK_RAPIDS_TPU_GROUPBY_KERNEL", "bogus")
     with pytest.raises(ValueError, match="bogus"):
         _use_scan_kernel()
+
+
+# ---- the scan kernel at a frame its scans take in two levels -------------------
+
+def _decimal_frame(n=300_000, groups=100_000, seed=34):
+    rng = np.random.default_rng(seed)
+    keys = rng.integers(0, groups, n).astype(np.int64)
+    # unscaled values on both sides of 2**32, so both planes carry
+    cents = rng.integers(-2 ** 40, 2 ** 40, n).astype(np.int64)
+    valid = rng.random(n) > 0.1
+    alive = rng.random(n) > 0.2
+    t = Table([Column.from_numpy(keys),
+               Column(dtype=dtypes.decimal(15, 2), length=n,
+                      data=jnp.asarray(cents), validity=jnp.asarray(valid))],
+              names=["k", "d"])
+    return t, keys, cents, valid, alive
+
+
+def _decimal_sums(t, alive, key_cap):
+    out, live, overflow = groupby_aggregate_capped(
+        t, ["k"], [("d", "sum"), ("d", "count"), ("d", "size")],
+        key_cap=key_cap, alive=None if alive is None else jnp.asarray(alive))
+    assert not bool(overflow)
+    m = np.asarray(live)
+    limbs = np.asarray(out["sum(d)"].data)[m].astype(object)
+    total = sum(limbs[:, j] << (32 * j) for j in range(4))
+    total = np.where(total >= 1 << 127, total - (1 << 128), total)
+    nulls = ~np.asarray(out["sum(d)"].null_mask)[m]
+    return (np.asarray(out["k"].data)[m], total, nulls,
+            np.asarray(out["count(d)"].data)[m],
+            np.asarray(out["size(*)"].data)[m])
+
+
+@pytest.mark.parametrize("has_alive", [False, True])
+@pytest.mark.parametrize("planes", ["ride", "gathered"])
+def test_scan_kernel_in_two_levels_equals_the_flat_scans(monkeypatch, planes,
+                                                         has_alive):
+    """300,000 rows (73 blocks of 4,096: `ops/scans.py:running` scans in
+    two levels) into some 95,000 groups, a decimal(15,2) summed as its two
+    32-bit planes with nulls and dead rows: the kernel's sums, counts and
+    sizes equal those of the same kernel over flat `cumsum`s, and exact
+    integer sums computed here. The planes ride the key sort or are
+    gathered by its order (`RIDE_PAYLOADS`)."""
+    from spark_rapids_tpu.ops import aggregate, scans
+    monkeypatch.setenv("SPARK_RAPIDS_TPU_GROUPBY_KERNEL", "scan")
+    if planes == "gathered":
+        monkeypatch.setattr(aggregate, "RIDE_PAYLOADS", 0)
+    aggregate._groupby_kernel.clear_cache()
+    t, keys, cents, valid, alive = _decimal_frame()
+    assert t.num_rows > 16 * scans.SCAN_BLOCK
+    alive = alive if has_alive else None
+    two = _decimal_sums(t, alive, key_cap=100_000)
+    flat_scan = lambda x, op="sum": jnp.cumsum(x)
+    monkeypatch.setattr(aggregate, "running", flat_scan)
+    aggregate._groupby_kernel.clear_cache()
+    flat = _decimal_sums(t, alive, key_cap=100_000)
+    aggregate._groupby_kernel.clear_cache()
+    for a, b in zip(two, flat):
+        assert np.array_equal(a, b)
+    rows = np.ones(len(keys), bool) if alive is None else alive
+    order = np.argsort(keys[rows], kind="stable")
+    k, v, ok = keys[rows][order], cents[rows][order], valid[rows][order]
+    starts = np.flatnonzero(np.concatenate([[True], k[1:] != k[:-1]]))
+    got_keys, total, nulls, counts, sizes = two
+    assert 90_000 < len(starts) and np.array_equal(got_keys, k[starts])
+    assert np.array_equal(sizes, np.diff(np.append(starts, len(k))))
+    assert np.array_equal(counts, np.add.reduceat(ok.astype(np.int64), starts))
+    want = np.add.reduceat(np.where(ok, v, 0), starts)
+    assert np.array_equal(nulls, counts == 0)
+    assert [int(x) for x in total[~nulls]] == want[~nulls].tolist()

@@ -215,6 +215,31 @@ def test_decimal_comparison_at_equal_scales(tier):
     assert out["k"].to_pylist() == [1, 3]
 
 
+@pytest.mark.parametrize("op", ["<", "<=", ">", ">=", "==", "!="])
+@pytest.mark.parametrize("tier", TIERS)
+def test_decimal_comparison_casts_to_the_wider_type(tier, op):
+    """Spark compares at the larger scale: decimal(15,2) beside
+    decimal(12,4), a DECIMAL128 sum beside an integer literal (Q18's
+    HAVING) and beside a DECIMAL64 column, limb by limb and signed."""
+    import operator
+    fn = {"<": operator.lt, "<=": operator.le, ">": operator.gt,
+          ">=": operator.ge, "==": operator.eq, "!=": operator.ne}[op]
+    a = [500, -700, 30000, 29999, 0, -1]
+    b = [50000, -70001, 2999900, 3000000, 0, -100]
+    wide = [30000, -30000, 2 ** 70, -(2 ** 70), 30001, 29999]
+    t = Table([dcol(a, 15, 2), dcol(b, 12, 4), dcol(wide, 25, 2),
+               icol(list(range(6)))], names=["a", "b", "w", "k"])
+    cases = ((fn(col("a"), col("b")),
+              [fn(x * 100, y) for x, y in zip(a, b)]),
+             (fn(col("w"), 300), [fn(x, 30000) for x in wide]),
+             (fn(col("w"), col("a")), [fn(x, y) for x, y in zip(wide, a)]))
+    for predicate, want in cases:
+        plan = (PlanBuilder().scan("t", schema=["a", "b", "w", "k"])
+                .filter(predicate).select(["k"]).build())
+        _, out = run(plan, {"t": t}, tier)
+        assert out["k"].to_pylist() == [k for k, w in enumerate(want) if w]
+
+
 # ---- aggregates ------------------------------------------------------------------------
 
 AGG_CASES = {
@@ -408,15 +433,17 @@ def test_verifier_accepts_q1_and_still_rejects_a_string_expression(q1):
                                   "keyless_sum"])
 def test_verifier_rejects_what_is_not_lowered(case):
     t = Table([Column.from_numpy(np.arange(3, dtype=np.int32)),
-               dcol([1, 2, 3], 15, 2), dcol([1, 2, 3], 15, 4)],
-              names=["i", "a", "b"])
+               dcol([1, 2, 3], 15, 2), dcol([1, 2, 3], 15, 4),
+               dcol([1, 2, 3], 18, 2)],
+              names=["i", "a", "b", "c"])
     b = PlanBuilder()
     if case == "narrow_buffer":
-        plan = b.scan("t", schema=["i", "a", "b"],
+        plan = b.scan("t", schema=["i", "a", "b", "c"],
                       types={"i": dtypes.decimal(15, 2)}).build()
         want = "typing.scan-type-storage"
     elif case == "unequal_scales":
-        plan = b.scan("t").filter(col("a") < col("b")).build()
+        # 18 digits at scale 2 would be 20 at scale 4: past an int64
+        plan = b.scan("t").filter(col("c") < col("b")).build()
         want = "typing.decimal-not-lowered"
     elif case == "decimal_under_and":
         plan = b.scan("t").project([("x", col("a") & col("b"))]).build()

@@ -249,6 +249,28 @@ def test_semi_and_anti_join():
     assert left_anti_join([lk], [rk]).to_pylist() == [0, 3]
 
 
+@pytest.mark.parametrize("n, share", [(0, 0.5), (1, 1.0), (7, 0.0),
+                                      (100_000, 0.3), (200_000, 1e-4),
+                                      (70_000, 1.0)])
+def test_kept_rows_is_nonzero(n, share):
+    """The filter's and the semi / anti joins' row ids: `jnp.nonzero` in
+    int32, ascending, by a rank scan and one scatter in which only kept
+    rows write (past 16 blocks of 4,096 the scan runs in two levels); an
+    integer mask reads as its truth."""
+    import jax.numpy as jnp
+    from spark_rapids_tpu.ops import apply_boolean_mask
+    from spark_rapids_tpu.ops.gather import kept_rows
+    mask = np.random.default_rng(n).random(n) < share
+    for m in (mask, mask.astype(np.int64) * 3):
+        got = np.asarray(kept_rows(jnp.asarray(m)))
+        assert got.dtype == np.int32
+        assert np.array_equal(got, np.flatnonzero(mask))
+    col = Column.from_numpy(np.arange(n, dtype=np.int64))
+    assert np.array_equal(
+        np.asarray(apply_boolean_mask(col, jnp.asarray(mask)).data),
+        np.flatnonzero(mask))
+
+
 def test_join_multi_key_and_strings():
     lk1 = col([1, 1, 2], np.int32)
     lk2 = scol(["a", "b", "a"])

@@ -397,6 +397,44 @@ def test_plan_execute_span_counts_the_joins_by_tail(session):
                 if m.kind == "HashJoin"] == [label]
 
 
+@pytest.mark.parametrize("tier", ["eager", "capped"])
+def test_groupby_span_and_the_requests_group_counters(session, tier):
+    """A keyed aggregate's kernel and finish run inside `ops.groupby`
+    (rows, groups, kernel, planes), below the operator; `plan.execute`
+    carries the rows in, the groups out and the slots the finish ran
+    over, summed over the request's keyed aggregates: the groups in the
+    eager tier, the key cap in the capped one. The capped tier's program
+    is traced once, so its span is a trace-time one and its name is the
+    scope `device_op_owners(nested=True)` reads back."""
+    plan, inputs = _join_plan(), {"t": _fact(), "d": _dim()}
+    caps = dict(row_cap=512, key_cap=16)
+    ex = PlanExecutor(mode=tier, **({"caps": caps} if tier == "capped"
+                                    else {}))
+    ex.execute(plan, inputs)                              # compile outside
+    done = []
+    spans = session(lambda: done.append(ex.execute(plan, inputs)))
+    res, got = done[0], spans.one("plan.execute")
+    (agg,) = [m for m in res.metrics.values() if m.kind == "HashAggregate"]
+    assert (res.group_rows, res.groups) == (agg.rows_in, agg.rows_out) \
+        and res.groups == 7
+    assert res.group_slots == (7 if tier == "eager" else 16)
+    assert (got["group_rows"], got["groups"], got["group_slots"]) \
+        == (res.group_rows, res.groups, res.group_slots)
+    if tier == "eager":
+        (op,) = [o for o in spans.named("plan.op")
+                 if o["op"].endswith(".HashAggregate")]
+        g = spans.one("ops.groupby")
+        assert inside(g, op) and g["request"] == op["request"]
+        assert (g["rows"], g["groups"], g["planes"]) == (agg.rows_in, 7, 0)
+        assert g["kernel"] in ("scan", "scatter")
+    else:
+        assert not spans.named("ops.groupby")     # one program, run warm
+        owners = ex.device_op_owners(plan, inputs, nested=True)
+        held = {o for o in owners.values() if o.endswith("/ops.groupby")}
+        assert len(held) == 1 and held.pop().split("/")[0] \
+            .endswith(".HashAggregate")
+
+
 def test_plan_execute_span_counts_the_slots_the_joins_gathered(session):
     """A capped join gathers its output columns over whole chunks of its
     live rows (ops/gather.py:gather_live), not over its cap: the first
@@ -498,6 +536,9 @@ def test_device_op_owners_is_the_capped_tiers():
      'custom_call_target="tpu_custom_call", metadata={op_name='
      '"jit(capped_plan)/4.HashJoin/pallas_hash_join_probe/pallas_call"}',
      ("pallas_hash_join_probe.1", "4.HashJoin")),
+    ('  %fusion.7 = s64[8]{0} fusion(%p), kind=kLoop, calls=%fc, '
+     'metadata={op_name="jit(capped_plan)/5.HashAggregate/ops.groupby/'
+     'jit(_groupby_kernel)/cumsum"}', ("fusion.7", "5.HashAggregate")),
     ('  %copy.3 = s64[8]{0} copy(%p), metadata={op_name='
      '"jit(capped_plan)/jit(main)/copy"}', None),
     ('  %param.1 = s64[8]{0} parameter(0)', None),
@@ -505,6 +546,21 @@ def test_device_op_owners_is_the_capped_tiers():
 def test_scope_owners_reads_an_executables_text(line, owner):
     assert _scope_owners("HloModule jit_capped_plan\n" + line + "\n") \
         == (dict([owner]) if owner else {})
+
+
+@pytest.mark.parametrize("path, owner", [
+    ("5.HashAggregate/ops.groupby/jit(_groupby_kernel)/sort",
+     "5.HashAggregate/ops.groupby"),
+    ("5.HashAggregate/ops.groupby/jit(_total_limbs)/decimal.sum/add",
+     "5.HashAggregate/decimal.sum"),
+    ("2.Project/decimal.mul/mul", "2.Project/decimal.mul"),
+    ("4.HashJoin/jit(take)/gather", "4.HashJoin"),
+])
+def test_scope_owners_nested_gives_the_innermost_kernel_scope(path, owner):
+    line = ('  %fusion.1 = s64[8]{0} fusion(%p), kind=kLoop, calls=%fc, '
+            'metadata={op_name="jit(capped_plan)/' + path + '"}')
+    assert _scope_owners("HloModule jit_capped_plan\n" + line + "\n",
+                         nested=True) == {"fusion.1": owner}
 
 
 PALLAS_SITES = {
