@@ -53,6 +53,17 @@ line), and nowhere else.
    annotation, argument or switch says which join is which. Both tails
    return the same arrays, slot for slot.
 
+3. the small-side path (eager `inner_join`, `left_semi_join`,
+   `left_anti_join` only, which hold both sides' row counts on the host):
+   with one side of at most `LOOKUP_SMALL` rows and the other of at least
+   `LOOKUP_LARGE`, integer keys, the large side is not sorted. Its rows
+   that carry a key of the small side are found by comparison in one pass
+   (ops/join_lookup.py), their positions taken at a price that follows
+   what is kept, and the sort join above runs over the small side and
+   those survivors. Survivors keep their row order, so the maps come out
+   pair for pair in the order the sort join of the whole sides gives.
+   Where more than a row in `_LOOKUP_KEEP` survives, the sort join runs.
+
 Null keys never match (Spark equi-join); null-safe equality (<=>) is the
 `null_equal` flag, like cudf's null_equality::EQUAL — null rows get their
 own leading rank operand (ops/sort.py), so they form their own runs and
@@ -70,6 +81,8 @@ from .. import dtypes
 from ..columnar import Column, Table
 from ..utils.tracing import span
 from .gather import gather_live, kept_rows, live_chunk, loop_zeros
+from .join_lookup import (lookup_side, member_mask, note_lookup,
+                          survivor_rows)
 from .sort import _key_operands
 
 __all__ = ["inner_join", "left_join", "full_join", "left_semi_join",
@@ -77,6 +90,16 @@ __all__ = ["inner_join", "left_join", "full_join", "left_semi_join",
            "inner_join_capped", "inner_join_capped_tail", "left_join_capped",
            "semi_join_mask",
            "join_spans", "expand_spans"]
+
+
+# The small-side path stops where more than one large-side row in
+# `_LOOKUP_KEEP` carries a key of the small side: the join of the survivors
+# would be the sort join over again, and their positions cost what a pack
+# of the frame costs (PERF.md section 6, PR 37, my chip runs:
+# `live_positions` against `_pack_rows` over 60 M rows, 5.4 against 359.7
+# ms at 4,739 rows kept, 46.9 against 359.8 at one row in 256, 320.3
+# against 359.8 at one in 32; over 15 M rows 73.0 against 90.2 at one in 32).
+_LOOKUP_KEEP = 32
 
 
 def _concat_columns(a: Column, b: Column) -> Column:
@@ -355,13 +378,61 @@ def _cols(keys) -> Sequence[Column]:
     return list(keys)
 
 
-def inner_join(left_keys, right_keys,
-               null_equal: bool = False) -> Tuple[Column, Column]:
-    """Gather maps (left_map, right_map) of the inner equi-join."""
-    counts, lo, rorder = _prep(_cols(left_keys), _cols(right_keys), null_equal)
+def _sort_inner_join(lcols, rcols, null_equal: bool):
+    counts, lo, rorder = _prep(lcols, rcols, null_equal)
     with span("ops.host_sync", site="join.inner"):
         total = int(jnp.sum(counts))          # the one host sync
     lmap, rmap = _expand(counts, lo, rorder, total=total, outer=False)
+    return lmap, rmap, total
+
+
+def _survivors(small, large):
+    """Steps 1 and 2 of the small-side path (ops/join_lookup.py): the rows
+    of `large` that carry a key of `small`, ascending, and their keys as
+    columns (no row of them is null or dead). None where so many rows pass
+    that the join of the survivors would be the sort join over again."""
+    mask, count = member_mask(small, large)
+    if count * _LOOKUP_KEEP > large[0].length:
+        return None
+    note_lookup(small[0].length, large[0].length)
+    rows, keys = survivor_rows(mask, [c.data for c in large], count=count)
+    return rows, [Column(dtype=c.dtype, length=count, data=d)
+                  for c, d in zip(large, keys)]
+
+
+@jax.jit
+def _map_back(rows, idx):
+    return jnp.take(rows, idx, axis=0)
+
+
+def _lookup_inner_join(lcols, rcols, null_equal: bool):
+    """The inner join by the small-side path, or None where it declines."""
+    side = lookup_side(lcols, rcols, null_equal)
+    if side is None:
+        return None
+    small, large = (lcols, rcols) if side == "left" else (rcols, lcols)
+    found = _survivors(small, large)
+    if found is None:
+        return None
+    rows, keys = found
+    if side == "left":
+        lmap, rmap, total = _sort_inner_join(lcols, keys, null_equal)
+        return lmap, _map_back(rows, rmap), total
+    lmap, rmap, total = _sort_inner_join(keys, rcols, null_equal)
+    return _map_back(rows, lmap), rmap, total
+
+
+def inner_join(left_keys, right_keys,
+               null_equal: bool = False) -> Tuple[Column, Column]:
+    """Gather maps (left_map, right_map) of the inner equi-join, ordered by
+    (left row, right row). With one side of a few hundred rows and the
+    other large, the large side is not sorted: its rows that carry a key
+    of the small side are found by comparison, and the sort join runs over
+    the small side and those survivors, which keep their row order, so the
+    pairs come out as the sort join of the whole sides gives them."""
+    lcols, rcols = _cols(left_keys), _cols(right_keys)
+    lmap, rmap, total = _lookup_inner_join(lcols, rcols, null_equal) \
+        or _sort_inner_join(lcols, rcols, null_equal)
     return (Column(dtype=dtypes.INT32, length=total, data=lmap),
             Column(dtype=dtypes.INT32, length=total, data=rmap))
 
@@ -575,13 +646,42 @@ def full_join(left_keys, right_keys,
             Column(dtype=dtypes.INT32, length=total, data=rdata))
 
 
+def _sort_semi_anti(lcols, rcols, null_equal: bool, semi: bool):
+    counts, _, _ = _prep(lcols, rcols, null_equal, need_rorder=False)
+    return kept_rows(counts > 0 if semi else counts == 0)
+
+
+def _lookup_semi_anti(lcols, rcols, null_equal: bool, semi: bool):
+    """The semi or anti join's rows by the small-side path, or None."""
+    side = lookup_side(lcols, rcols, null_equal)
+    if side == "left":
+        found = _survivors(lcols, rcols)
+        return None if found is None \
+            else _sort_semi_anti(lcols, found[1], null_equal, semi)
+    if side is None:
+        return None
+    # the membership mask IS the semi join; the anti join keeps the rest
+    mask, count = member_mask(rcols, lcols)
+    note_lookup(rcols[0].length, lcols[0].length)
+    if not semi:
+        return kept_rows(~mask)
+    if count * _LOOKUP_KEEP > lcols[0].length:
+        return kept_rows(mask)
+    return survivor_rows(mask, [], count=count)[0]
+
+
+def _semi_anti(left_keys, right_keys, null_equal: bool, semi: bool) -> Column:
+    lcols, rcols = _cols(left_keys), _cols(right_keys)
+    keep = _lookup_semi_anti(lcols, rcols, null_equal, semi)
+    if keep is None:
+        keep = _sort_semi_anti(lcols, rcols, null_equal, semi)
+    return Column(dtype=dtypes.INT32, length=int(keep.shape[0]), data=keep)
+
+
 def left_semi_join(left_keys, right_keys,
                    null_equal: bool = False) -> Column:
     """Left rows having >=1 match (gather map into the left table)."""
-    counts, _, _ = _prep(_cols(left_keys), _cols(right_keys), null_equal,
-                         need_rorder=False)
-    keep = kept_rows(counts > 0)
-    return Column(dtype=dtypes.INT32, length=int(keep.shape[0]), data=keep)
+    return _semi_anti(left_keys, right_keys, null_equal, True)
 
 
 def left_anti_join(left_keys, right_keys,
@@ -589,7 +689,4 @@ def left_anti_join(left_keys, right_keys,
     """Left rows having no match — Spark NOT IN/anti join. NB: rows with a
     null key have no match, so they ARE returned (cudf behavior; Spark's
     NOT IN null semantics are built on top by the plugin)."""
-    counts, _, _ = _prep(_cols(left_keys), _cols(right_keys), null_equal,
-                         need_rorder=False)
-    keep = kept_rows(counts == 0)
-    return Column(dtype=dtypes.INT32, length=int(keep.shape[0]), data=keep)
+    return _semi_anti(left_keys, right_keys, null_equal, False)

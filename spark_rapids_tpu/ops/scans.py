@@ -11,7 +11,9 @@ levels. The block is the one size tried.
 
 `running` is the one definition: the SPMD walk's sorted group-by
 (`parallel/relational.py`) and the `scan` group-by kernel
-(`ops/aggregate.py`) both call it.
+(`ops/aggregate.py`) both call it. `live_positions` (PR 32, moved here
+unchanged in PR 37) is the one definition too: the SPMD walk's compaction
+and the eager joins' small-side path (`ops/join_lookup.py`) call it.
 """
 from __future__ import annotations
 
@@ -43,3 +45,42 @@ def running(x: jnp.ndarray, op: str = "sum") -> jnp.ndarray:
         before = jnp.concatenate([lowest, jax.lax.cummax(totals)[:-1]])
         out = jnp.maximum(inner, before[:, None])
     return out.reshape(-1)[:n]
+
+
+_MASK_WORD = 32
+
+
+def live_positions(live, cap: int):
+    """(idx, keep): the positions of the first `cap` live rows of a mask,
+    in their order, and which of the `cap` slots hold one. The mask is
+    read as 32-row words: a running count of the words' populations, a
+    binary search per OUTPUT slot over that count (a table a 32nd of the
+    frame: the 21 steps over 39.6 M rows gather from 5 MB, not from the
+    158 MB a per-row count takes), one gather of the word, and the slot's
+    bit found in it by arithmetic. Third: whether more than `cap` rows
+    are live (the rest would be lost)."""
+    n = live.shape[0]
+    pad = (-n) % _MASK_WORD
+    if pad:
+        live = jnp.concatenate([live, jnp.zeros((pad,), live.dtype)])
+    lanes = jnp.arange(_MASK_WORD, dtype=jnp.uint32)
+    words = jnp.sum(live.reshape(-1, _MASK_WORD).astype(jnp.uint32) << lanes,
+                    axis=1, dtype=jnp.uint32)
+    cum = running(jax.lax.population_count(words).astype(jnp.int32))
+    slot = jnp.arange(cap, dtype=jnp.int32)
+    at = jnp.searchsorted(cum, slot, side="right", method="scan")
+    at = jnp.minimum(at, cum.shape[0] - 1).astype(jnp.int32)
+    word = jnp.take(words, at, axis=0)
+    rank = slot - (jnp.take(cum, at, axis=0)
+                   - jax.lax.population_count(word).astype(jnp.int32))
+    bit = jnp.zeros_like(slot)
+    for width in (16, 8, 4, 2, 1):      # the rank-th set bit of the word
+        low = word & jnp.uint32((1 << width) - 1)
+        below = jax.lax.population_count(low).astype(jnp.int32)
+        high = rank >= below
+        rank = jnp.where(high, rank - below, rank)
+        word = jnp.where(high, word >> width, low)
+        bit = bit + jnp.where(high, width, 0)
+    keep = slot < cum[-1]
+    idx = jnp.minimum(at * _MASK_WORD + bit, n - 1)
+    return jnp.where(keep, idx, 0).astype(jnp.int32), keep, cum[-1] > cap

@@ -32,6 +32,7 @@ from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from ..ops.join import expand_spans, join_spans
+from ..ops.scans import live_positions as _live_positions
 from ..ops.scans import running as _running
 from .shuffle import build_partition_map, partition_ids
 
@@ -1059,45 +1060,6 @@ def distributed_live_counts(mesh: Mesh, alive: jnp.ndarray,
         return jnp.sum(live.astype(jnp.int32)).reshape(1)
     return shard_map(local, mesh=mesh, in_specs=(P(axis),),
                      out_specs=P(axis))(alive)
-
-
-_MASK_WORD = 32
-
-
-def _live_positions(live, cap: int):
-    """(idx, keep): the positions of the first `cap` live rows of a mask,
-    in their order, and which of the `cap` slots hold one. The mask is
-    read as 32-row words: a running count of the words' populations, a
-    binary search per OUTPUT slot over that count (a table a 32nd of the
-    frame: the 21 steps over 39.6 M rows gather from 5 MB, not from the
-    158 MB a per-row count takes), one gather of the word, and the slot's
-    bit found in it by arithmetic. Third: whether more than `cap` rows
-    are live (the rest would be lost)."""
-    n = live.shape[0]
-    pad = (-n) % _MASK_WORD
-    if pad:
-        live = jnp.concatenate([live, jnp.zeros((pad,), live.dtype)])
-    lanes = jnp.arange(_MASK_WORD, dtype=jnp.uint32)
-    words = jnp.sum(live.reshape(-1, _MASK_WORD).astype(jnp.uint32) << lanes,
-                    axis=1, dtype=jnp.uint32)
-    cum = _running(jax.lax.population_count(words).astype(jnp.int32))
-    slot = jnp.arange(cap, dtype=jnp.int32)
-    at = jnp.searchsorted(cum, slot, side="right", method="scan")
-    at = jnp.minimum(at, cum.shape[0] - 1).astype(jnp.int32)
-    word = jnp.take(words, at, axis=0)
-    rank = slot - (jnp.take(cum, at, axis=0)
-                   - jax.lax.population_count(word).astype(jnp.int32))
-    bit = jnp.zeros_like(slot)
-    for width in (16, 8, 4, 2, 1):      # the rank-th set bit of the word
-        low = word & jnp.uint32((1 << width) - 1)
-        below = jax.lax.population_count(low).astype(jnp.int32)
-        high = rank >= below
-        rank = jnp.where(high, rank - below, rank)
-        word = jnp.where(high, word >> width, low)
-        bit = bit + jnp.where(high, width, 0)
-    keep = slot < cum[-1]
-    idx = jnp.minimum(at * _MASK_WORD + bit, n - 1)
-    return jnp.where(keep, idx, 0).astype(jnp.int32), keep, cum[-1] > cap
 
 
 def _compact_local(arrs, live, cap: int):

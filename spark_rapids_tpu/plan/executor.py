@@ -615,6 +615,9 @@ class PlanResult:
         #                               gathers touched (whole chunks over
         #                               the live rows, ops/gather.py) and
         #                               the caps they would have paid
+        self.lookup_joins = 0         # eager tier: joins that took the
+        self.lookup_compares = 0      # small-side path, and small rows x
+        #                               large rows over them (ops/join.py)
         self.group_rows = 0           # over the request's keyed aggregates:
         self.groups = 0               # rows in, groups out, and the slots
         self.group_slots = 0          # their finish ran over (the groups in
@@ -813,11 +816,14 @@ class PlanExecutor:
             if nulled:      # eager tiers: one read-back, decimal plans only
                 res.decimal_overflow_rows += int(sum(nulled))
             self._count_groups(res)
+            self._count_lookups(res)
             sp.set_metadata(decimal_overflow_rows=res.decimal_overflow_rows,
                             group_rows=res.group_rows, groups=res.groups,
                             group_slots=res.group_slots,
                             unique_joins=res.unique_joins,
                             expand_joins=res.expand_joins,
+                            lookup_joins=res.lookup_joins,
+                            lookup_compares=res.lookup_compares,
                             gather_slots=res.gather_slots,
                             cap_slots=res.cap_slots,
                             expand_slots=res.expand_slots,
@@ -845,6 +851,18 @@ class PlanExecutor:
             res.group_slots += (self._node_cap(res.caps, "key_cap", i)
                                 if res.mode == "capped" and res.caps
                                 else int(m.rows_out))
+
+    @staticmethod
+    def _count_lookups(res: PlanResult) -> None:
+        """`lookup_joins`, `lookup_compares` of a result, from its
+        operators' metrics (a cached result keeps its own)."""
+        from ..ops.join_lookup import KERNEL_LABEL
+        if res.cached or res.lookup_joins:
+            return
+        took = [m for m in res.metrics.values()
+                if m.kernel == KERNEL_LABEL]
+        res.lookup_joins = len(took)
+        res.lookup_compares = sum(m.lookup_compares for m in took)
 
     def _execute_request(self, plan, inputs, tier,
                          placement) -> PlanResult:
@@ -2105,19 +2123,28 @@ class PlanExecutor:
                 "hash_join",
                 join_pallas.make_signature(lkeys, rkeys, node.how, "eager"),
                 m)
-            if node.how == "inner":
-                if not choice.fallback:
+            from ..ops.join_lookup import KERNEL_LABEL, lookup_counts
+            with lookup_counts() as looked:
+                if node.how != "inner":
+                    keep = (ops.left_semi_join(lkeys, rkeys)
+                            if node.how == "left_semi"
+                            else ops.left_anti_join(lkeys, rkeys))
+                elif not choice.fallback:
                     lm, rm = choice.fn(lkeys, rkeys)
                 else:
                     lm, rm = ops.inner_join(lkeys, rkeys)
+            if looked:      # the small-side path answered (ops/join.py)
+                m.kernel = KERNEL_LABEL
+                m.lookup_compares = sum(a * b for a, b in looked)
+                # its wall is no timing of a registered hash_join kernel
+                m.__dict__.pop("_kernel_sig", None)
+            if node.how == "inner":
                 return Table(
                     list(ops.take_table(lt, lm.data,
                                         _has_negative=False).columns) +
                     list(ops.take_table(rt, rm.data,
                                         _has_negative=False).columns),
                     names=list(lt.names) + list(rt.names))
-            keep = (ops.left_semi_join(lkeys, rkeys) if node.how == "left_semi"
-                    else ops.left_anti_join(lkeys, rkeys))
             return ops.take_table(lt, keep.data, _has_negative=False)
         if isinstance(node, HashAggregate):
             (t,) = childs

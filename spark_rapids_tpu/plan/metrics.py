@@ -49,6 +49,9 @@ class OperatorMetrics:
     # silently compare kernel backends (same rule as the bench `backend`
     # stamp). Empty for operators with no registry dispatch.
     kernel: str = ""
+    # small rows x large rows an eager join compared on the small-side path
+    # (ops/join.py; `kernel` then reads "<backend>:lookup"), else 0
+    lookup_compares: int = 0
     # streaming-scan IO metrics (Scan nodes bound to a parquet source;
     # docs/io.md). Decode wall is host-side bitstream decode; overlap is
     # the time decode of chunk N+1 ran concurrently with executing chunk N

@@ -372,3 +372,37 @@ def test_pallas_capped_join_expands_in_loops_for_v5e(one_chip,
     assert _gather_slots(text) == [1024] * 3
     assert sum("pallas_hash_join_probe/pallas_call" in n
                for n in _op_names(text, "custom-call")) == 2
+
+
+Q18_LINES = 59_998_501      # `q18.batch`: lineitem's rows at SF10
+
+
+@pytest.mark.parametrize("layout", ["int64_key", "int32_and_int64_keys"])
+def test_lookup_membership_compiles_for_v5e(one_chip, no_persistent_cache,
+                                            layout):
+    """Step 1 of the eager joins' small-side path (ops/join_lookup.py) at
+    the padded small side against `lineitem`'s rows at SF10: ONE loop whose
+    body compares a chunk of small keys in one fusion over the frame, the
+    one sort of the program is the small side's, and beside its arguments
+    and the mask it needs the frame's 32-bit key words and the loop's mask,
+    never a frame's sort buffers (the 60 M-row span kernel it replaces
+    needed 2.5 GB, PERF.md, PR 34)."""
+    import re
+    from spark_rapids_tpu.ops import join_lookup
+    kinds = [jnp.int64] if layout == "int64_key" else [jnp.int32, jnp.int64]
+    words = sum(jnp.dtype(k).itemsize // 4 for k in kinds)
+
+    def shape(n, dtype):
+        return jax.ShapeDtypeStruct((n,), dtype, sharding=one_chip)
+    small = join_lookup.LOOKUP_SMALL
+    compiled = join_lookup._member.lower(
+        [shape(small, k) for k in kinds], shape(small, jnp.bool_),
+        [shape(Q18_LINES, k) for k in kinds], [shape(Q18_LINES, jnp.bool_)]
+    ).compile()
+    text = compiled.as_text()
+    assert len(_op_names(text, "while")) == 1
+    sorted_rows = [int(re.search(r"\[(\d+)", line.split(" sort(")[0]).group(1))
+                   for line in text.splitlines() if " sort(" in line]
+    assert sorted_rows == [small]
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < (4 * words + 2) * (Q18_LINES + (1 << 20)), temp

@@ -86,6 +86,41 @@ def test_q18_equals_the_plain_reference(q18, draws, executors, tier, seed):
                                else 2 * BATCH["orders_rows"])
 
 
+@pytest.mark.parametrize("floor", ["the_modules", "under_the_rehearsal"])
+def test_q18_eager_joins_take_the_small_side_path(q18, draws, monkeypatch,
+                                                  floor):
+    """At the cell's size the few orders the HAVING keeps meet 15 M orders,
+    1.5 M customers and 60 M lines, and each of the three joins compares
+    instead of sorting (ops/join.py). The rehearsal's tables lie under the
+    path's floor, so none takes it; with the floor under them all three
+    do, `lookup_compares` is the kept orders times the three large sides,
+    and the answer is the reference's either way."""
+    from spark_rapids_tpu.ops import join_lookup
+    if floor == "under_the_rehearsal":
+        monkeypatch.setattr(join_lookup, "LOOKUP_LARGE",
+                            SIZES["customer_rows"])
+    inputs, tables = draws[SEEDS[0]]
+    res = PlanExecutor(mode="eager").execute(q18.plan(QUANTITY), inputs)
+    assert _compare(q18, res, q18.reference(tables, quantity=QUANTITY)) \
+        == EXACT
+    joins = [m for m in res.metrics.values() if m.kind == "HashJoin"]
+    assert len(joins) == 3
+    if floor == "the_modules":
+        assert BATCH["lineitem_rows"] < join_lookup.LOOKUP_LARGE
+        assert (res.lookup_joins, res.lookup_compares) == (0, 0)
+        assert all(m.kernel == "xla:hash_join" for m in joins)
+        return
+    kept = joins[0].rows_out            # the semi join: the large orders
+    assert 5 < kept < 100
+    large_sides = (BATCH["orders_rows"] + SIZES["customer_rows"]
+                   + BATCH["lineitem_rows"])
+    assert (res.lookup_joins, res.lookup_compares) == (3, kept * large_sides)
+    assert [m.kernel for m in joins] == ["xla:lookup"] * 3
+    assert [m.lookup_compares for m in joins] == [
+        kept * BATCH["orders_rows"], kept * SIZES["customer_rows"],
+        kept * BATCH["lineitem_rows"]]
+
+
 @pytest.mark.parametrize("control", ["float64", "having_ge", "ascending"])
 def test_q18_reference_controls_fail_the_comparison(q18, draws, control):
     """Each of the reference's wrong forms, put in the program's place,

@@ -397,6 +397,41 @@ def test_plan_execute_span_counts_the_joins_by_tail(session):
                 if m.kind == "HashJoin"] == [label]
 
 
+@pytest.mark.parametrize("plan_is", ["a_few_keys_against_a_fact", "q3"])
+def test_plan_execute_span_counts_the_small_side_joins(session, plan_is):
+    """An eager join with a side of a few rows against a large one takes
+    the small-side path (ops/join.py): `lookup_joins` and `lookup_compares`
+    (small rows x large rows) on `plan.execute`, on the result, and
+    `xla:lookup` as the join's kernel. q3's shape (a fact of 2,000
+    rows, under the path's floor) takes none: both read 0 and the join
+    keeps the registry's label."""
+    from spark_rapids_tpu.ops.join_lookup import LOOKUP_LARGE
+    if plan_is == "q3":
+        (plan, inputs), want = _tiny_q3(), (0, 0)
+        labels = ["xla:hash_join"] * 2
+    else:
+        n = LOOKUP_LARGE + 10           # rows the plan's filter leaves
+        plan, want = _join_plan(), (1, 50 * n)
+        inputs = {"t": Table([_col(np.arange(n) % 5000),
+                              _col(np.arange(n) + 11)],
+                             names=["k", "v"]), "d": _dim()}
+        labels = ["xla:lookup"]
+    ex = PlanExecutor(mode="eager")
+    ex.execute(plan, inputs)                              # compile outside
+    done = []
+    spans = session(lambda: done.append(ex.execute(plan, inputs)))
+    res, got = done[0], spans.one("plan.execute")
+    assert (got["lookup_joins"], got["lookup_compares"]) == want
+    assert (res.lookup_joins, res.lookup_compares) == want
+    joins = [m for m in res.metrics.values() if m.kind == "HashJoin"]
+    assert [m.kernel for m in joins] == labels
+    assert sum(m.lookup_compares for m in joins) == want[1]
+    if want[0]:
+        assert "kernel: xla:lookup" in res.profile_text()
+        assert spans.named("ops.host_sync") and any(
+            s.get("site") == "join.lookup" for s in spans.named("ops.host_sync"))
+
+
 @pytest.mark.parametrize("tier", ["eager", "capped"])
 def test_groupby_span_and_the_requests_group_counters(session, tier):
     """A keyed aggregate's kernel and finish run inside `ops.groupby`
