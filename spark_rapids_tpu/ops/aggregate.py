@@ -597,7 +597,8 @@ def groupby_aggregate(table: Table,
             # the eager tier: blocked inside the span, so that the span
             # holds the finish's device work too (the group count's read
             # has waited for the kernel already)
-            jax.block_until_ready([c.data for c in out.columns])
+            with span("plan.wait", site="groupby"):
+                jax.block_until_ready([c.data for c in out.columns])
         return out
 
 
@@ -683,7 +684,8 @@ def _groupby(table, key_names, aggs, _cap, _alive, sp):
         has_valids=tuple(v is not None for v in agg_valids),
         has_alive=_alive is not None, **extra)
     if _cap is None:
-        g = int(num_groups)  # the one host sync
+        with span("ops.host_sync", site="groupby.groups"):
+            g = int(num_groups)  # the one host sync: waits for the kernel
     else:
         # slice what exists, pad the rest below (a fixed-cap jit pipeline
         # must accept small batches, and a too-small cap must be retryable

@@ -32,7 +32,6 @@ import jax.numpy as jnp
 from .. import dtypes
 from ..columnar import Column
 from ..dtypes import Kind
-from ..utils.tracing import span
 from . import decimal256 as d256
 
 
@@ -419,30 +418,28 @@ def arithmetic(op: str, a: Column, b: Column, alive=None) -> Column:
         raise TypeError(f"decimal {op!r} over {a.dtype} and {b.dtype}")
     out = arithmetic_type(op, lt, rt)
     name = {"+": "add", "-": "sub", "*": "mul"}[op]
-    with span("ops.decimal", op=name, precision=out.precision,
-              scale=out.scale, rows=a.length):
-        if out.precision <= dtypes.MAX_DEC64_PRECISION:
-            x, y = a.data.astype(jnp.int64), b.data.astype(jnp.int64)
-            if op != "*":
-                with jax.named_scope("decimal.rescale"):
-                    x = x * (10 ** (out.scale - lt.scale))
-                    y = y * (10 ** (out.scale - rt.scale))
-            with jax.named_scope("decimal." + name):
-                r = x * y if op == "*" else (x + y if op == "+" else x - y)
-            return Column(dtype=out, length=a.length,
-                          data=r.astype(out.storage_dtype()),
-                          validity=_combined_validity(a, b))
-        wa, wb = widen(a), widen(b)
-        if op == "*":
-            ovf, res = multiply_decimal128(wa, wb, out.scale,
-                                           cast_interim_result=False)
-        else:
-            ovf, res = add_decimal128(wa, wb, out.scale, is_sub=op == "-")
-        res = Column(dtype=out, length=res.length, data=res.data,
-                     validity=res.validity)
-        if out.precision < MAX_PRECISION:
-            return res      # an unadjusted type holds every result
-        return _null_overflow(res, ovf.data, alive)
+    if out.precision <= dtypes.MAX_DEC64_PRECISION:
+        x, y = a.data.astype(jnp.int64), b.data.astype(jnp.int64)
+        if op != "*":
+            with jax.named_scope("decimal.rescale"):
+                x = x * (10 ** (out.scale - lt.scale))
+                y = y * (10 ** (out.scale - rt.scale))
+        with jax.named_scope("decimal." + name):
+            r = x * y if op == "*" else (x + y if op == "+" else x - y)
+        return Column(dtype=out, length=a.length,
+                      data=r.astype(out.storage_dtype()),
+                      validity=_combined_validity(a, b))
+    wa, wb = widen(a), widen(b)
+    if op == "*":
+        ovf, res = multiply_decimal128(wa, wb, out.scale,
+                                       cast_interim_result=False)
+    else:
+        ovf, res = add_decimal128(wa, wb, out.scale, is_sub=op == "-")
+    res = Column(dtype=out, length=res.length, data=res.data,
+                 validity=res.validity)
+    if out.precision < MAX_PRECISION:
+        return res      # an unadjusted type holds every result
+    return _null_overflow(res, ovf.data, alive)
 
 
 def comparison_scale(lt: dtypes.DType, rt: dtypes.DType) -> int:

@@ -28,6 +28,12 @@ from ..utils.tracing import span
 from .scans import running
 
 
+def _any_negative(idx) -> bool:
+    """Whether a gather map holds a -1 (a null row): one host sync."""
+    with span("ops.host_sync", site="gather.has_negative"):
+        return bool(jnp.any(idx < 0))
+
+
 def take(col: Column, idx: jnp.ndarray, check_bounds: bool = False,
          _has_negative: bool = None) -> Column:
     """New column with rows col[idx]. idx: (m,) int32/int64; -1 → null row.
@@ -40,12 +46,13 @@ def take(col: Column, idx: jnp.ndarray, check_bounds: bool = False,
         raise ValueError("gather map must be 1-D")
     m = int(idx.shape[0])
     if check_bounds and m:
-        lo, hi = (int(x) for x in jax.device_get(
-            (jnp.min(idx), jnp.max(idx))))        # one fused sync
+        with span("ops.host_sync", site="gather.bounds"):
+            lo, hi = (int(x) for x in jax.device_get(
+                (jnp.min(idx), jnp.max(idx))))    # one fused sync
         if hi >= col.length or lo < -1:
             raise IndexError(f"gather index out of bounds for {col.length} rows")
     if _has_negative is None:
-        _has_negative = m > 0 and bool(jnp.any(idx < 0))
+        _has_negative = m > 0 and _any_negative(idx)
     nullify = idx < 0
     safe = jnp.where(nullify, 0, idx)
 
@@ -195,7 +202,9 @@ def kept_rows(mask) -> jnp.ndarray:
     a row where 677 of 15 M rows are kept, PERF.md, PR 34). One host sync
     for the result's size, as there."""
     mask = jnp.asarray(mask).astype(bool)
-    return _pack_rows(mask, int(jnp.sum(mask)))
+    with span("ops.host_sync", site="gather.kept_rows"):
+        total = int(jnp.sum(mask))
+    return _pack_rows(mask, total)
 
 
 def apply_boolean_mask(table_or_col, mask) -> Union[Table, Column]:
@@ -222,6 +231,6 @@ def take_table(table: Table, idx: jnp.ndarray,
                _has_negative: bool = None) -> Table:
     idx = jnp.asarray(idx)
     if _has_negative is None:
-        _has_negative = int(idx.shape[0]) > 0 and bool(jnp.any(idx < 0))
+        _has_negative = int(idx.shape[0]) > 0 and _any_negative(idx)
     return Table([take(c, idx, _has_negative=_has_negative)
                   for c in table.columns], names=table.names)

@@ -20,6 +20,7 @@ from typing import Callable, Dict, Tuple
 
 import jax.numpy as jnp
 
+from ..utils.tracing import span
 from .relational import (distributed_broadcast_join,
                          distributed_broadcast_join_keyed,
                          distributed_groupby, distributed_groupby_keyed,
@@ -64,7 +65,11 @@ def auto_retry_overflow(attempt: Callable[..., Tuple], caps: Dict,
     clamped_last = False
     for i in range(max_attempts):
         out = attempt(**caps)
-        if not bool(jnp.any(out[-1])):
+        # the program runs to its end before its flag can be read: in the
+        # capped tier this is where the host waits for the device
+        with span("ops.host_sync", site="autoretry.overflow"):
+            overflowed = bool(jnp.any(out[-1]))
+        if not overflowed:
             return out, caps
         if clamped_last:
             ceil = {}           # distrust: a clamped attempt overflowed

@@ -166,7 +166,9 @@ class ShardedRel:
             # reads this per operator, and pulling the whole global mask
             # to host (np.asarray) would serialize the walk on a
             # full-mask transfer every node
-            self._num_rows = int(jnp.sum(self.valid.astype(jnp.int64)))
+            with span("ops.host_sync", site="dist.num_rows"):
+                self._num_rows = int(
+                    jnp.sum(self.valid.astype(jnp.int64)))
         return self._num_rows
 
     @property
@@ -182,16 +184,19 @@ class ShardedRel:
         boundary. Cached: DAG-shared consumers gather once."""
         if self._local is not None:
             return self._local
-        mask = np.asarray(self.valid)
-        idx = np.nonzero(mask)[0]
-        cols = []
-        for c in self.table.columns:
-            data = jnp.asarray(np.asarray(c.data)[idx])
-            validity = c.validity
-            if validity is not None:
-                validity = jnp.asarray(np.asarray(validity)[idx])
-            cols.append(dataclasses.replace(c, data=data, validity=validity,
-                                            length=int(idx.shape[0])))
+        # every buffer crosses to the host whole and comes back compacted
+        with span("ops.host_sync", site="dist.to_local"):
+            mask = np.asarray(self.valid)
+            idx = np.nonzero(mask)[0]
+            cols = []
+            for c in self.table.columns:
+                data = jnp.asarray(np.asarray(c.data)[idx])
+                validity = c.validity
+                if validity is not None:
+                    validity = jnp.asarray(np.asarray(validity)[idx])
+                cols.append(dataclasses.replace(
+                    c, data=data, validity=validity,
+                    length=int(idx.shape[0])))
         t = Table(cols, names=list(self.table.names))
         if self.order_keys:
             from .executor import _ops
@@ -245,7 +250,9 @@ class PendingRel:
                 # the transfer must COMPLETE on the thread — otherwise
                 # "async" would just defer the device work to the
                 # consumer and the overlap would be fiction
-                jax.block_until_ready([c.data for c in out.table.columns])
+                with span("plan.wait", site="dist.async_exchange"):
+                    jax.block_until_ready(
+                        [c.data for c in out.table.columns])
                 self._result = out
             except BaseException as e:    # surfaces at the consumer
                 self._err = e
@@ -284,7 +291,8 @@ class PendingRel:
                 raise err
             t0 = time.perf_counter()
             out = self._fn()
-            jax.block_until_ready([c.data for c in out.table.columns])
+            with span("plan.wait", site="dist.rerun_exchange"):
+                jax.block_until_ready([c.data for c in out.table.columns])
             self._result = out
             self._stamp(time.perf_counter() - t0)
         return self._result
@@ -404,7 +412,9 @@ def _raise_if_lost(lost, what: str, cap: int) -> None:
     the walk had counted. Set, it means rows were dropped: the program
     that counted and the one that moved disagree, which is a fault of the
     engine and never a capacity to escalate."""
-    if bool(np.asarray(lost).any()):
+    with span("ops.host_sync", site="dist.lost"):
+        dropped = bool(np.asarray(lost).any())
+    if dropped:
         from ..parallel.autoretry import CapacityOverflowError
         raise CapacityOverflowError(
             f"{what}: a shard held more rows than the {cap} slots counted "
@@ -704,9 +714,10 @@ class DistContext:
         if any(sp.nullable or sp.n_words != 1 for sp in specs):
             return words_by_side, None
         shape = tuple(len(ws) for ws in words_by_side)
-        ranges = np.asarray(_jitted(
-            ("key_ranges", self.mesh, self.axis, shape),
-            key_ranges)(words_by_side, alive_by_side))
+        ranges = _jitted(("key_ranges", self.mesh, self.axis, shape),
+                         key_ranges)(words_by_side, alive_by_side)
+        with span("ops.host_sync", site="dist.key_ranges"):
+            ranges = np.asarray(ranges)
         if any(int(hi) < int(lo) or int(hi) - int(lo) >= (1 << 31) - 1
                for lo, hi in ranges):
             return words_by_side, None
@@ -762,9 +773,11 @@ class DistContext:
         one number a shard the walk reads back to size its next program."""
         from ..parallel.relational import distributed_live_counts
         mesh, axis = self.mesh, self.axis
-        return np.asarray(_jitted(
+        counts = _jitted(
             ("live_counts", mesh, axis),
-                lambda v: distributed_live_counts(mesh, v, axis))(valid))
+                lambda v: distributed_live_counts(mesh, v, axis))(valid)
+        with span("ops.host_sync", site="dist.live_counts"):
+            return np.asarray(counts)
 
     def _settle(self, rel: ShardedRel) -> ShardedRel:
         """After an operator that drops rows (a filter, a join): read each
@@ -1009,8 +1022,10 @@ class DistContext:
         dp = transport.pack_device(list(c.table.columns), names, c.valid,
                                    self.codecs)
         mask_plane, n = transport.pack_bits_device(c.valid)
-        planes = [np.asarray(p) for p in dp.planes]
-        mask = transport.unpack_bits_np(np.asarray(mask_plane), n)
+        with span("ops.host_sync", site="dist.gather"):
+            planes = [np.asarray(p) for p in dp.planes]
+            mask_plane = np.asarray(mask_plane)
+        mask = transport.unpack_bits_np(mask_plane, n)
         idx = np.nonzero(mask)[0]
         decoded = transport.unpack_device_np(planes, dp)
         cols = []
@@ -1058,7 +1073,8 @@ class DistContext:
                 self._edge(m, "broadcast", logical, logical, "",
                            copies=copies, sp=sp)
             valid = put(jnp.ones((t.num_rows,), bool))
-            jax.block_until_ready(valid)
+            with span("plan.wait", site="dist.replicate"):
+                jax.block_until_ready(valid)
         return ShardedRel(Table(cols, names=list(t.names)), valid,
                           replicated=True)
 
@@ -1085,7 +1101,9 @@ class DistContext:
         fn = _jitted(key,
             lambda *xs: xs, out_shardings=self.rep_spec)
         with self._exchange() as sp:
-            outs = jax.block_until_ready(fn(*arrays, c.valid))
+            outs = fn(*arrays, c.valid)
+            with span("plan.wait", site="dist.broadcast"):
+                jax.block_until_ready(outs)
             self._edge(m, "broadcast", logical, wire, codec, copies=copies,
                        sp=sp)
         if dp is not None:
@@ -1123,10 +1141,12 @@ class DistContext:
         words = _encode_keys(c.table, keys, specs)
         mesh, axis = self.mesh, self.axis
         n_words = len(words)
-        counts = np.asarray(_jitted(
+        counts = _jitted(
             ("part_counts", mesh, axis, tuple(specs), n_words),
             lambda *xs: distributed_partition_counts(
-                mesh, xs[:-1], specs, xs[-1], axis))(*words, c.valid))
+                mesh, xs[:-1], specs, xs[-1], axis))(*words, c.valid)
+        with span("ops.host_sync", site="dist.part_counts"):
+            counts = np.asarray(counts)
         cap = bucket(int(counts.max()))
         c._num_rows = int(counts.sum())
         vnames = [nm for nm in c.table.names if nm not in set(keys)]
@@ -1171,8 +1191,9 @@ class DistContext:
                 word_codecs=word_codecs or None,
                 word_refs=list(arrs[nw + nv + 1:]) or None))
         with self._exchange() as sp:
-            ws, vs, alive, lost = jax.block_until_ready(
-                fn(*words, *vals, c.valid, *refs))
+            ws, vs, alive, lost = fn(*words, *vals, c.valid, *refs)
+            with span("plan.wait", site="dist.repartition"):
+                jax.block_until_ready((ws, vs, alive, lost))
             _raise_if_lost(lost, "hash exchange", cap)
             self._edge(m, "hash", live * logical_row, live * wire_row,
                        codec, sp=sp)
@@ -1292,7 +1313,8 @@ class DistContext:
                     xs[-2], xs[-1], rrep))
             counts, lo, rorder, totals = spans(*l_words, *r_words,
                                                l.valid, r.valid)
-            totals = np.asarray(totals)
+            with span("ops.host_sync", site="dist.join_totals"):
+                totals = np.asarray(totals)
             row_cap = bucket(int(totals.max()))
             emit = _jitted(
                 ("cojoin_emit", mesh, axis, nlw, nlv, nrv, rrep, row_cap),
@@ -1359,8 +1381,9 @@ class DistContext:
         a key: that join fans out, and takes the general path."""
         from ..parallel.relational import distributed_lookup_join
         r_words = _encode_keys(r.table, rk, specs)
-        live = np.asarray(r.valid)
-        keys = np.stack([np.asarray(w) for w in r_words], axis=1)[live]
+        with span("ops.host_sync", site="dist.lookup_keys"):
+            live = np.asarray(r.valid)
+            keys = np.stack([np.asarray(w) for w in r_words], axis=1)[live]
         if len(np.unique(keys, axis=0)) != len(keys):
             return None
         inner = node.how == "inner"
@@ -1496,9 +1519,11 @@ class DistContext:
             return fn(*words, *vals, valid_in)
 
         with (contextlib.nullcontext() if elide else self._exchange()) as sp:
-            gws, outs, gvalid, _ = jax.block_until_ready(self._retry(
+            gws, outs, gvalid, _ = self._retry(
                 node, "group", run,
-                self._caps(node, "group", {"key_cap": key_cap0}), m))
+                self._caps(node, "group", {"key_cap": key_cap0}), m)
+            with span("plan.wait", site="dist.groupby"):
+                jax.block_until_ready((gws, outs, gvalid))
             if not elide:
                 sp.set_metadata(how="hash", bytes=nbytes[0],
                                 bytes_logical=nbytes[0], codec="raw")
@@ -1558,7 +1583,9 @@ class DistContext:
                          mesh, xs[:-1], pairs, xs[-1], axis))
         self._reset_edge(m)
         with self._exchange() as sp:
-            outs, valid = jax.block_until_ready(fn(*vals, c.valid))
+            outs, valid = fn(*vals, c.valid)
+            with span("plan.wait", site="dist.reduce"):
+                jax.block_until_ready((outs, valid))
             nbytes = 8 * len(pairs) * (self.n_peers - 1)
             self._edge(m, "reduce", nbytes, nbytes, "", sp=sp)
         cols = []
@@ -1624,9 +1651,11 @@ class DistContext:
         # fault-retried attempt re-describes the edge, not accumulates
         self._reset_edge(m)
         with self._exchange() as sp:
-            ws, vs, valid, _ = jax.block_until_ready(self._retry(
+            ws, vs, valid, _ = self._retry(
                 node, "sort", run, self._caps(node, "sort",
-                                              {"slack": self.slack}), m))
+                                              {"slack": self.slack}), m)
+            with span("plan.wait", site="dist.sort"):
+                jax.block_until_ready((ws, vs, valid))
             self._edge(m, "range", live * logical_row, live * wire_row,
                        codec, sp=sp)
         valid = valid.astype(jnp.bool_)

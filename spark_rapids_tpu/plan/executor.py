@@ -94,7 +94,7 @@ from .nodes import (Exchange, Filter, FusedSelect, HashAggregate, HashJoin,
                     Limit, PlanNode, PlanValidationError, Project, Scan,
                     Sort, TopK, Union)
 from .expr import ColumnRef
-from ..utils.tracing import span, text as _span_text
+from ..utils.tracing import bracket, span, text as _span_text
 
 # The device-fault surface the executor turns into policy (runtime/health):
 # injected nonfatal asserts and substituted return codes plus RetryOOM
@@ -126,8 +126,8 @@ def _op_span(node: PlanNode, idx: int, tier: str = "device"):
     `<toposort index>.<kind>`, the name the operator's scope carries
     inside a capped program; `tier` is where it ran: device, host
     (co-placement thread) or degraded (CPU tier)."""
-    return span("plan.op", op=_scope_name(idx, node),
-                label=_span_text(node.label), tier=tier)
+    return bracket("plan.op", op=_scope_name(idx, node),
+                   label=_span_text(node.label), tier=tier)
 
 
 _DECIMAL_OVERFLOW = -1      # key of `_run_capped`'s counts, see there
@@ -637,6 +637,12 @@ class PlanResult:
         self.exchange_edges = 0       # edges that moved data and their
         self.exchange_bytes = 0       # wire bytes; capacity escalations
         self.dist_cap_escalations = 0  # of the distributed primitives
+        self.lowerings = 0            # jit lowerings on the request's thread
+        self.lowering_ms = 0.0        # (in-memory program cache misses) and
+        #                               what they took: 0 once a plan is
+        #                               warm (utils/tracing.py; the spans
+        #                               `plan.execute`, `plan.attempt` and
+        #                               `plan.op` say which part lowered)
         self.cached = False           # served from the serving result cache
         #                               (serving/cache.py): True ONLY on a
         #                               cache-hit COPY — its metrics are
@@ -810,13 +816,12 @@ class PlanExecutor:
         if request < 0:
             request = next(self._requests)
         from ..ops.decimal_utils import overflow_counts
-        with ctx.request_scope(request), span("plan.execute") as sp, \
+        with ctx.request_scope(request), bracket("plan.execute") as sp, \
                 overflow_counts() as nulled:
-            res = self._execute_request(plan, inputs, tier, placement)
-            if nulled:      # eager tiers: one read-back, decimal plans only
-                res.decimal_overflow_rows += int(sum(nulled))
-            self._count_groups(res)
-            self._count_lookups(res)
+            res = self._execute_request(plan, inputs, tier, placement,
+                                        nulled)
+            # what the request lowered (utils/tracing.py): 0 once warm
+            res.lowerings, res.lowering_ms = sp.lowered()
             sp.set_metadata(decimal_overflow_rows=res.decimal_overflow_rows,
                             group_rows=res.group_rows, groups=res.groups,
                             group_slots=res.group_slots,
@@ -864,22 +869,24 @@ class PlanExecutor:
         res.lookup_joins = len(took)
         res.lookup_compares = sum(m.lookup_compares for m in took)
 
-    def _execute_request(self, plan, inputs, tier,
-                         placement) -> PlanResult:
+    def _execute_request(self, plan, inputs, tier, placement,
+                         nulled=()) -> PlanResult:
         if tier not in (None, "device", "cpu"):
             raise ValueError(f"unknown execution tier {tier!r} "
                              "(expected device or cpu)")
-        self._check_capped_mesh(plan)
-        inputs = bind_scan_sources(plan, inputs)
-        missing = [s for s in plan.input_names if s not in inputs]
-        if missing:
-            raise PlanValidationError(f"unbound plan input(s) {missing}")
-        # full validation against the bound tables' actual schemas —
-        # authored-plan errors surface against authored labels, BEFORE any
-        # optimizer rewrite renames nodes (streaming sources expose .names
-        # from the parquet footer, so the same contract applies)
-        bound = {name: tuple(t.names) for name, t in inputs.items()}
-        schemas = plan.resolve_schemas(bound)
+        with span("plan.bind"):
+            self._check_capped_mesh(plan)
+            inputs = bind_scan_sources(plan, inputs)
+            missing = [s for s in plan.input_names if s not in inputs]
+            if missing:
+                raise PlanValidationError(
+                    f"unbound plan input(s) {missing}")
+            # full validation against the bound tables' actual schemas —
+            # authored-plan errors surface against authored labels, BEFORE
+            # any optimizer rewrite renames nodes (streaming sources expose
+            # .names from the parquet footer, so the same contract applies)
+            bound = {name: tuple(t.names) for name, t in inputs.items()}
+            schemas = plan.resolve_schemas(bound)
         report = None
         authored = plan
         if self.optimize:
@@ -949,22 +956,29 @@ class PlanExecutor:
                      else contextlib.nullcontext()):
                 res = self._execute(plan, inputs, schemas, source_fp,
                                     cert, placements)
-        res.cert = cert
-        # serving-session stamp (runtime/sessionctx.py, docs/serving.md):
-        # results and per-op metrics carry the tenant they executed for —
-        # dispatcher worker threads are multiplexed across sessions, so
-        # thread identity cannot answer this after the fact
-        sid = _sessionctx().current_session_id()
-        if sid is not None:
-            res.session = sid
-            for mm in res.metrics.values():
-                mm.session = sid
-        if self.worker_id:
-            res.worker = self.worker_id
-            for mm in res.metrics.values():
-                mm.worker_id = self.worker_id
-        if report is not None:
-            res.optimizer = report.to_dict()
+        with span("plan.result"):
+            res.cert = cert
+            if nulled:  # eager tiers: one read-back, decimal plans only
+                with span("ops.host_sync", site="decimal.overflow"):
+                    res.decimal_overflow_rows += int(sum(nulled))
+            self._count_groups(res)
+            self._count_lookups(res)
+            # serving-session stamp (runtime/sessionctx.py,
+            # docs/serving.md): results and per-op metrics carry the tenant
+            # they executed for — dispatcher worker threads are multiplexed
+            # across sessions, so thread identity cannot answer this after
+            # the fact
+            sid = _sessionctx().current_session_id()
+            if sid is not None:
+                res.session = sid
+                for mm in res.metrics.values():
+                    mm.session = sid
+            if self.worker_id:
+                res.worker = self.worker_id
+                for mm in res.metrics.values():
+                    mm.worker_id = self.worker_id
+            if report is not None:
+                res.optimizer = report.to_dict()
         from . import stats as stats_mod
         store = stats_mod.active_store()
         if store is not None:
@@ -1051,7 +1065,9 @@ class PlanExecutor:
             if store is not None and \
                     store.observed_rows(backend, fp) is not None:
                 continue        # an execution has counted it since
-            out["filter:" + fp] = int(jnp.sum(node.predicate.evaluate(t)))
+            with span("ops.host_sync", site="optimize.counted_filter"):
+                out["filter:" + fp] = int(
+                    jnp.sum(node.predicate.evaluate(t)))
         return out
 
     def _optimized(self, plan, inputs, bound):
@@ -1477,8 +1493,9 @@ class PlanExecutor:
                             # would forfeit the transfer/compute overlap)
                             if self.block_per_op \
                                     and not getattr(out, "pending", False):
-                                jax.block_until_ready(
-                                    [c.data for c in out.columns])
+                                with span("plan.wait", site="op"):
+                                    jax.block_until_ready(
+                                        [c.data for c in out.columns])
                         break
                     except _fault_surface() as err:
                         if self._handle_fault(err, node.label, attempt, m):
@@ -1524,24 +1541,26 @@ class PlanExecutor:
                 except Exception:
                     pass
             raise
-        root_out = results[id(plan.root)]
-        if not isinstance(root_out, Table):
-            # sink gather: the single host-facing collect of a distributed
-            # plan (explicit when the optimizer placed Exchange(gather) at
-            # the root; implicit here otherwise)
-            root_out = root_out.to_local_table()
-        wall = (time.perf_counter() - t_plan0) * 1e3
-        res = PlanResult(plan, root_out, None, metrics,
-                         "eager", wall,
-                         retries=sum(mm.retries for mm in metrics.values()),
-                         breaker=self._breaker_snapshot(),
-                         backoff_ms=sum(mm.backoff_ms
-                                        for mm in metrics.values()))
-        if dist is not None:
-            res.dist_ops, res.local_ops = dist.dist_ops, dist.local_ops
-            res.exchange_edges = dist.exchange_edges
-            res.exchange_bytes = dist.exchange_bytes
-            res.dist_cap_escalations = dist.cap_escalations
+        with span("plan.result"):
+            root_out = results[id(plan.root)]
+            if not isinstance(root_out, Table):
+                # sink gather: the single host-facing collect of a
+                # distributed plan (explicit when the optimizer placed
+                # Exchange(gather) at the root; implicit here otherwise)
+                root_out = root_out.to_local_table()
+            wall = (time.perf_counter() - t_plan0) * 1e3
+            res = PlanResult(plan, root_out, None, metrics,
+                             "eager", wall,
+                             retries=sum(mm.retries
+                                         for mm in metrics.values()),
+                             breaker=self._breaker_snapshot(),
+                             backoff_ms=sum(mm.backoff_ms
+                                            for mm in metrics.values()))
+            if dist is not None:
+                res.dist_ops, res.local_ops = dist.dist_ops, dist.local_ops
+                res.exchange_edges = dist.exchange_edges
+                res.exchange_bytes = dist.exchange_bytes
+                res.dist_cap_escalations = dist.cap_escalations
         return res
 
     # ---- co-placement host subtrees (docs/optimizer.md#placement) ---------
@@ -1645,7 +1664,9 @@ class PlanExecutor:
                     self._faultinj_point(n)
                     out = self._exec_eager_node(n, childs, host_inputs,
                                                 schemas, m)
-                    jax.block_until_ready([c.data for c in out.columns])
+                    with span("plan.wait", site="host_op"):
+                        jax.block_until_ready(
+                            [c.data for c in out.columns])
                 m.wall_ms = (time.perf_counter() - t0) * 1e3
                 m.rows_in = sum(t.num_rows for t in childs)
                 m.rows_out = out.num_rows
@@ -1754,8 +1775,9 @@ class PlanExecutor:
                         out = self._exec_eager_node(node, childs, cpu_inputs,
                                                     schemas, m)
                         if self.block_per_op:
-                            jax.block_until_ready(
-                                [c.data for c in out.columns])
+                            with span("plan.wait", site="degraded_op"):
+                                jax.block_until_ready(
+                                    [c.data for c in out.columns])
                     m.wall_ms = (time.perf_counter() - t0) * 1e3
                     m.rows_in = sum(t.num_rows for t in childs)
                     m.rows_out = out.num_rows
@@ -1770,15 +1792,17 @@ class PlanExecutor:
                 except Exception:
                     pass
                 raise
-        wall = (time.perf_counter() - t_plan0) * 1e3
-        return PlanResult(plan, cpu_results[id(plan.root)], None, metrics,
-                          mode, wall, degraded=True,
-                          attempts=attempts, caps=caps,
-                          retries=carry_retries + sum(
-                              mm.retries for mm in metrics.values()),
-                          breaker=self._breaker_snapshot(),
-                          backoff_ms=carry_backoff_ms + sum(
-                              mm.backoff_ms for mm in metrics.values()))
+        with span("plan.result"):
+            wall = (time.perf_counter() - t_plan0) * 1e3
+            return PlanResult(
+                plan, cpu_results[id(plan.root)], None, metrics,
+                mode, wall, degraded=True,
+                attempts=attempts, caps=caps,
+                retries=carry_retries + sum(
+                    mm.retries for mm in metrics.values()),
+                breaker=self._breaker_snapshot(),
+                backoff_ms=carry_backoff_ms + sum(
+                    mm.backoff_ms for mm in metrics.values()))
 
     # ---- streaming prefix (docs/io.md) ------------------------------------
     @staticmethod
@@ -1959,7 +1983,8 @@ class PlanExecutor:
                                                                schemas))
                 parts.append(t)
                 if self.block_per_op:
-                    jax.block_until_ready([c.data for c in t.columns])
+                    with span("plan.wait", site="stream_chunk"):
+                        jax.block_until_ready([c.data for c in t.columns])
                 proc_intervals.append((t0p, time.perf_counter()))
         finally:
             feed.close()
@@ -1987,7 +2012,8 @@ class PlanExecutor:
         else:
             out = parts[0] if len(parts) == 1 else ops.concat_tables(parts)
         if self.block_per_op:
-            jax.block_until_ready([c.data for c in out.columns])
+            with span("plan.wait", site="stream_tail"):
+                jax.block_until_ready([c.data for c in out.columns])
         tm.wall_ms = (tm.wall_ms or 0.0) + (time.perf_counter() - t0) * 1e3
         tm.bytes_out = operand_nbytes(out)
         for n in chain:
@@ -2397,7 +2423,9 @@ class PlanExecutor:
                 else:
                     inputs[name] = v.read_all()
                 scan_io[name] = holder
-        caps, cert_ceil = self._starting_caps(plan, inputs, source_fp, cert)
+        with span("plan.caps"):
+            caps, cert_ceil = self._starting_caps(plan, inputs, source_fp,
+                                                  cert)
         fp = plan.fingerprint
         t0 = time.perf_counter()
         attempts = 0
@@ -2417,24 +2445,30 @@ class PlanExecutor:
             attempts += 1
             last_caps.clear()
             last_caps.update(caps_now)
-            tried.append(self._capped_key(plan, caps_now,
-                                          _input_key(inputs)))
-            # plan-level faultinj surface: fires every attempt, including
-            # cache-hit runs where the op-level shims never re-trace
-            for node in plan.nodes:
-                self._faultinj_point(node)
-            # shapes AND names in the key: jax retraces per input shape
-            # anyway, a per-shape entry keeps each bytes_map true to ITS
-            # trace, and the names guard fingerprint-shared undeclared
-            # scans bound to differently-named tables
             # one pass over the program: an escalation shows as a second
             # plan.attempt under the same plan.run
-            with span("plan.attempt", attempt=attempts) as sp:
-                fn, bm, km, hit = self._jitted_capped(
-                    plan, schemas, caps_now, _input_key(inputs))
+            with bracket("plan.attempt", attempt=attempts) as sp:
+                with span("plan.program"):
+                    # shapes AND names in the key: jax retraces per input
+                    # shape anyway, a per-shape entry keeps each bytes_map
+                    # true to ITS trace, and the names guard
+                    # fingerprint-shared undeclared scans bound to
+                    # differently-named tables
+                    tried.append(self._capped_key(plan, caps_now,
+                                                  _input_key(inputs)))
+                    # plan-level faultinj surface: fires every attempt,
+                    # including cache-hit runs where the op-level shims
+                    # never re-trace
+                    for node in plan.nodes:
+                        self._faultinj_point(node)
+                    fn, bm, km, hit = self._jitted_capped(
+                        plan, schemas, caps_now, _input_key(inputs))
                 sp.set_metadata(hit=int(hit))
                 cache_hits += hit
-                out = fn(dict(inputs))
+                # flattening the tables and enqueueing; on a miss the
+                # trace and the compile too (the bracket's `lowerings`)
+                with span("plan.launch", hit=int(hit)):
+                    out = fn(dict(inputs))
             bytes_map.clear()
             bytes_map.update(bm)    # bm fills during the first trace
             kernel_map.clear()
@@ -2479,64 +2513,74 @@ class PlanExecutor:
                     # escalation history survives the trip: the device path
                     # DID run `attempts` times over these (grown) caps
                     attempts=attempts, caps=dict(last_caps))
-        jax.block_until_ready(valid)
+        with span("plan.wait", site="capped"):
+            jax.block_until_ready(valid)
         wall = (time.perf_counter() - t0) * 1e3
-        metrics: Dict[str, OperatorMetrics] = {}
-        # cap growths only: each of the (retries+1) auto_retry runs gets a
-        # free first attempt that is not an escalation
-        escal = max(0, attempts - (retries + 1))
-        counts_np = {k: (int(a), int(b))
-                     for k, (a, b) in zip(counts.keys(),
-                                          np.asarray(list(counts.values()),
-                                                     dtype=np.int64))}
-        for i, node in enumerate(plan.nodes):
-            # counts/bytes key on the toposort INDEX, not the label: a
-            # fingerprint-shared program was traced over an equivalent
-            # plan whose node labels differ, but its toposort lines up 1:1
-            rows_in, rows_out = counts_np[i]
-            kernel = kernel_map.get(i, "")
-            if _JOIN_UNIQUE - 2 * i in counts_np:
-                kernel += ("/unique" if counts_np[_JOIN_UNIQUE - 2 * i][0]
-                           else "/expand")
-            uses_cap = (isinstance(node, HashJoin) and node.how == "inner") \
-                or (isinstance(node, HashAggregate) and node.keys)
-            # retries are plan-granular in this tier (one XLA program) and
-            # live on PlanResult.retries — copying them onto every row would
-            # make per-op aggregation overcount N-fold
-            metrics[node.label] = OperatorMetrics(
-                label=node.label, kind=node.kind, describe=node.describe(),
-                rows_in=rows_in, rows_out=rows_out,
-                bytes_out=bytes_map.get(i, 0),
-                escalations=escal if uses_cap else 0,
-                kernel=kernel)
-            if isinstance(node, Scan) and node.source in scan_io:
-                io = scan_io[node.source]
-                mm = metrics[node.label]
-                mm.io_row_groups_total = io.io_row_groups_total
-                mm.io_row_groups_pruned = io.io_row_groups_pruned
-                mm.io_bytes_skipped = io.io_bytes_skipped
-                mm.io_decode_ms = io.io_decode_ms
-        res = PlanResult(plan, table, valid, metrics, "capped", wall,
-                         attempts=attempts, caps=final_caps,
-                         retries=retries,
-                         breaker=self._breaker_snapshot(),
-                         backoff_ms=backoff_total,
-                         jit_cache_hits=cache_hits)
-        res.decimal_overflow_rows = counts_np[_DECIMAL_OVERFLOW][0]
-        tails = [flag for k, (flag, _) in counts_np.items()
-                 if k <= _JOIN_UNIQUE and k % 2 == 0]
-        res.unique_joins = sum(tails)
-        res.expand_joins = len(tails) - res.unique_joins
-        for k, (slots, frames) in counts_np.items():
-            if k <= _JOIN_EXPAND and k % 2:
-                res.expand_slots += slots
-                res.expand_cap_slots += frames
-        from ..ops.gather import live_slots
-        for i, node in enumerate(plan.nodes):
-            if isinstance(node, HashJoin) and node.how == "inner":
-                cap = self._node_cap(final_caps, "row_cap", i)
-                res.gather_slots += live_slots(counts_np[i][1], cap)
-                res.cap_slots += cap
+        # the operators' row counts: two device scalars an operator, each
+        # its own transfer
+        with span("plan.readback", scalars=2 * len(counts)):
+            counts_np = {k: (int(a), int(b))
+                         for k, (a, b) in zip(
+                             counts.keys(),
+                             np.asarray(list(counts.values()),
+                                        dtype=np.int64))}
+        # the tier's epilogue: a metrics row an operator from the counts
+        # read back, and the result's counters
+        with span("plan.result"):
+            metrics: Dict[str, OperatorMetrics] = {}
+            # cap growths only: each of the (retries+1) auto_retry runs gets a
+            # free first attempt that is not an escalation
+            escal = max(0, attempts - (retries + 1))
+            for i, node in enumerate(plan.nodes):
+                # counts/bytes key on the toposort INDEX, not the label: a
+                # fingerprint-shared program was traced over an equivalent
+                # plan whose node labels differ, but its toposort lines up
+                # 1:1
+                rows_in, rows_out = counts_np[i]
+                kernel = kernel_map.get(i, "")
+                if _JOIN_UNIQUE - 2 * i in counts_np:
+                    kernel += ("/unique" if counts_np[_JOIN_UNIQUE - 2 * i][0]
+                               else "/expand")
+                uses_cap = (isinstance(node, HashJoin)
+                            and node.how == "inner") \
+                    or (isinstance(node, HashAggregate) and node.keys)
+                # retries are plan-granular in this tier (one XLA program)
+                # and live on PlanResult.retries — copying them onto every
+                # row would make per-op aggregation overcount N-fold
+                metrics[node.label] = OperatorMetrics(
+                    label=node.label, kind=node.kind, describe=node.describe(),
+                    rows_in=rows_in, rows_out=rows_out,
+                    bytes_out=bytes_map.get(i, 0),
+                    escalations=escal if uses_cap else 0,
+                    kernel=kernel)
+                if isinstance(node, Scan) and node.source in scan_io:
+                    io = scan_io[node.source]
+                    mm = metrics[node.label]
+                    mm.io_row_groups_total = io.io_row_groups_total
+                    mm.io_row_groups_pruned = io.io_row_groups_pruned
+                    mm.io_bytes_skipped = io.io_bytes_skipped
+                    mm.io_decode_ms = io.io_decode_ms
+            res = PlanResult(plan, table, valid, metrics, "capped", wall,
+                             attempts=attempts, caps=final_caps,
+                             retries=retries,
+                             breaker=self._breaker_snapshot(),
+                             backoff_ms=backoff_total,
+                             jit_cache_hits=cache_hits)
+            res.decimal_overflow_rows = counts_np[_DECIMAL_OVERFLOW][0]
+            tails = [flag for k, (flag, _) in counts_np.items()
+                     if k <= _JOIN_UNIQUE and k % 2 == 0]
+            res.unique_joins = sum(tails)
+            res.expand_joins = len(tails) - res.unique_joins
+            for k, (slots, frames) in counts_np.items():
+                if k <= _JOIN_EXPAND and k % 2:
+                    res.expand_slots += slots
+                    res.expand_cap_slots += frames
+            from ..ops.gather import live_slots
+            for i, node in enumerate(plan.nodes):
+                if isinstance(node, HashJoin) and node.how == "inner":
+                    cap = self._node_cap(final_caps, "row_cap", i)
+                    res.gather_slots += live_slots(counts_np[i][1], cap)
+                    res.cap_slots += cap
         return res
 
     def _capped_key(self, plan, caps, input_key) -> Tuple:

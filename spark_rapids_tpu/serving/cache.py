@@ -51,6 +51,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..ops.hash import _mm_fmix, f64_bits_u64
+from ..utils.tracing import span
 
 
 # ---- the fold: a buffer -> 128 bits, where the buffer lives --------------
@@ -162,8 +163,11 @@ def _table_digest(t) -> str:
     for c in t.columns:
         _describe_column(h, c, buffers)
     if buffers:
-        # the one read-back: 16 bytes a buffer
-        h.update(jax.device_get(_fold_buffers(_SEEDS, *buffers)).tobytes())
+        # the one read-back: 16 bytes a buffer, behind whatever the
+        # device's queue holds
+        folded = _fold_buffers(_SEEDS, *buffers)
+        with span("ops.host_sync", site="digest"):
+            h.update(jax.device_get(folded).tobytes())
         _digested.n = bytes_digested() + sum(a.nbytes for a in buffers)
     digest = h.hexdigest()
     try:

@@ -825,118 +825,135 @@ class ServingScheduler:
             self._run_dispatched(job, wait_ms)
 
     def _run_dispatched(self, job: _Job, wait_ms: float) -> None:
-        from ..runtime import sessionctx
-        state = job.state
+        from ..utils.tracing import span
         result = error = None
         served_hit = False
-        # EVERYTHING between dispatch and the finally must leave the
-        # worker alive and the ticket completed: an unguarded raise here
-        # (cache copy under memory pressure, say) would kill the
+        # EVERYTHING between dispatch and the ticket's completion must
+        # leave the worker alive and the ticket completed: an unguarded
+        # raise here (cache copy under memory pressure, say) would kill the
         # dispatcher thread, leak _active/in_flight accounting (close()
         # then never drains), and strand the submitter's result() forever
         try:
-            # deadline enforcement at dispatch: a job whose submit-side
-            # deadline expired while QUEUED completes with the typed
-            # rejection before certification or compilation — nobody is
-            # waiting for the result, and executing it anyway would
-            # charge quota and burn a dispatcher slot for dead traffic.
-            # queue_wait_ms is already stamped above: the wait that
-            # killed the job is exactly the number worth reporting.
-            if job.deadline is not None and self._clock() >= job.deadline:
-                raise ServingRejectedError(
-                    "deadline",
-                    f"submit-side deadline expired after "
-                    f"{wait_ms:.0f} ms queued", session=state.id)
-            # dispatch-time cache consult: a repeat plan that QUEUED
-            # behind its twin (both submitted before either completed —
-            # the common shape of a burst of identical traffic) still
-            # serves the first completion's result instead of
-            # re-executing
-            # count_miss=False: submit() already counted this key's
-            # miss once — the dispatch-time re-consult is burst dedup,
-            # not new traffic, and must not halve the reported hit rate
-            hit = self.cache.get(job.cache_key, count_miss=False)
-            if hit is not None:
-                hit.session = state.id
-                for m in hit.metrics.values():
-                    m.session = state.id
-                job.ticket.cached = True
-                served_hit = True
-                result = hit
-            else:
-                import contextlib
+            result, served_hit = self._consult_and_execute(job, wait_ms)
+        except BaseException as e:
+            error = e
+        with span("serving.complete"):
+            if error is None and not served_hit \
+                    and job.cache_key is not None and not result.degraded:
+                # device-tier results only: a degraded result is a
+                # transient-condition artifact (breaker open, quota pin)
+                # whose degraded=True stamp would keep reporting CPU-tier
+                # completions to healthy-device traffic for the whole TTL.
+                # The cache is an optimization — failing to store must not
+                # fail the job.
+                try:
+                    self.cache.put(job.cache_key, result)
+                except Exception:
+                    pass
+                except BaseException as e:
+                    error = e
+            self._complete_job(job, wait_ms, result, error, served_hit)
+
+    def _consult_and_execute(self, job: _Job, wait_ms: float):
+        """-> (result, whether the dispatch-time cache consult served it).
+        `serving.consult` is the worker's part before `plan.execute`."""
+        import contextlib
+        from ..runtime import sessionctx
+        from ..utils.tracing import span
+        state = job.state
+        with contextlib.ExitStack() as scopes:
+            with span("serving.consult"):
+                # deadline enforcement at dispatch: a job whose submit-side
+                # deadline expired while QUEUED completes with the typed
+                # rejection before certification or compilation — nobody
+                # is waiting for the result, and executing it anyway would
+                # charge quota and burn a dispatcher slot for dead traffic.
+                # queue_wait_ms is already stamped: the wait that killed
+                # the job is exactly the number worth reporting.
+                if job.deadline is not None \
+                        and self._clock() >= job.deadline:
+                    raise ServingRejectedError(
+                        "deadline",
+                        f"submit-side deadline expired after "
+                        f"{wait_ms:.0f} ms queued", session=state.id)
+                # dispatch-time cache consult: a repeat plan that QUEUED
+                # behind its twin (both submitted before either completed
+                # — the common shape of a burst of identical traffic)
+                # still serves the first completion's result instead of
+                # re-executing
+                # count_miss=False: submit() already counted this key's
+                # miss once — the dispatch-time re-consult is burst dedup,
+                # not new traffic, and must not halve the reported hit rate
+                hit = self.cache.get(job.cache_key, count_miss=False)
+                if hit is not None:
+                    hit.session = state.id
+                    for m in hit.metrics.values():
+                        m.session = state.id
+                    job.ticket.cached = True
+                    return hit, True
                 from ..plan import stats as stats_mod
-                scope = (stats_mod.scoped_store(self.stats_store)
-                         if self.stats_store is not None
-                         else contextlib.nullcontext())
+                scopes.enter_context(sessionctx.session_scope(state.id))
+                if self.stats_store is not None:
+                    scopes.enter_context(
+                        stats_mod.scoped_store(self.stats_store))
                 # attribution scope: a breaker trip fired by THIS
                 # execution is stamped with this plan's fingerprint in
                 # the health monitor's trip log, which is what lets the
                 # fleet's poison-plan quarantine (serving/fleet.py)
                 # attribute trips to fingerprints instead of guessing
+                scopes.enter_context(self.executor.health.attribution(
+                    job.plan.fingerprint))
                 # placement= is only forwarded when a partial split is
                 # actually armed: executor doubles (tests, shims) that
                 # stub execute() keep working unchanged on the default
                 # path, and the kwarg's absence IS the default anyway
                 kw = ({"placement": job.placement}
                       if job.placement is not None else {})
-                with sessionctx.session_scope(state.id), scope, \
-                        self.executor.health.attribution(
-                            job.plan.fingerprint):
-                    result = self.executor.execute(
-                        job.plan, job.inputs,
-                        tier="cpu" if job.tier == "cpu" else None,
-                        **kw)
-                if job.cache_key is not None and not result.degraded:
-                    # device-tier results only: a degraded result is a
-                    # transient-condition artifact (breaker open, quota
-                    # pin) whose degraded=True stamp would keep reporting
-                    # CPU-tier completions to healthy-device traffic for
-                    # the whole TTL. The cache is an optimization —
-                    # failing to store must not fail the job.
-                    try:
-                        self.cache.put(job.cache_key, result)
-                    except Exception:
-                        pass
-        except BaseException as e:
-            error = e
-        finally:
-            with self._lock:
-                if job.tier != "cpu":
-                    state.in_flight_bytes -= job.charge
-                state.active_jobs -= 1
-                self._active -= 1
-                state.wait_ms.append(wait_ms)
-                if len(state.wait_ms) > 10_000:
-                    del state.wait_ms[:5_000]     # bounded sample memory
-                if error is None and result is not None:
-                    state.completed += 1
-                    if served_hit:
-                        state.cache_hits += 1
-                    else:
-                        state.retries += result.retries
-                        if result.degraded or job.tier == "cpu":
-                            state.degraded += 1
-                        if self.feedback:
-                            if state.cost_score == 0.0:
-                                # anchor the decay clock: an untouched
-                                # cost_at of 0 would decay the first
-                                # accrual away instantly
-                                state.cost_at = self._clock()
-                            state.cost_score += float(result.wall_ms) + \
-                                self._FEEDBACK_RETRY_MS * result.retries
-                elif (isinstance(error, ServingRejectedError)
-                      and error.reason == "deadline"):
-                    # expired-in-queue is an admission outcome, not an
-                    # execution failure: count it with the rejects so
-                    # `failed` keeps meaning "execution broke"
-                    state.rejected += 1
-                    state.deadline_rejects += 1
+            return self.executor.execute(
+                job.plan, job.inputs,
+                tier="cpu" if job.tier == "cpu" else None, **kw), False
+
+    def _complete_job(self, job: _Job, wait_ms: float, result, error,
+                      served_hit: bool) -> None:
+        """The accounting of a finished job, the quota's release and the
+        ticket's completion."""
+        state = job.state
+        with self._lock:
+            if job.tier != "cpu":
+                state.in_flight_bytes -= job.charge
+            state.active_jobs -= 1
+            self._active -= 1
+            state.wait_ms.append(wait_ms)
+            if len(state.wait_ms) > 10_000:
+                del state.wait_ms[:5_000]     # bounded sample memory
+            if error is None and result is not None:
+                state.completed += 1
+                if served_hit:
+                    state.cache_hits += 1
                 else:
-                    state.failed += 1
-                self._maybe_reap_locked(state)
-                self._lock_cond.notify_all()
-            job.ticket._complete(result=result, error=error)
+                    state.retries += result.retries
+                    if result.degraded or job.tier == "cpu":
+                        state.degraded += 1
+                    if self.feedback:
+                        if state.cost_score == 0.0:
+                            # anchor the decay clock: an untouched
+                            # cost_at of 0 would decay the first
+                            # accrual away instantly
+                            state.cost_at = self._clock()
+                        state.cost_score += float(result.wall_ms) + \
+                            self._FEEDBACK_RETRY_MS * result.retries
+            elif (isinstance(error, ServingRejectedError)
+                  and error.reason == "deadline"):
+                # expired-in-queue is an admission outcome, not an
+                # execution failure: count it with the rejects so
+                # `failed` keeps meaning "execution broke"
+                state.rejected += 1
+                state.deadline_rejects += 1
+            else:
+                state.failed += 1
+            self._maybe_reap_locked(state)
+            self._lock_cond.notify_all()
+        job.ticket._complete(result=result, error=error)
 
     # ---- lifecycle / observability -----------------------------------------
 

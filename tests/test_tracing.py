@@ -311,8 +311,14 @@ def test_eager_tier_one_op_span_per_operator_and_the_joins_host_sync(session):
     assert all(inside(o, spans.one("plan.run")) for o in ops)
     (join,) = [o for o in ops if o["op"].endswith(".HashJoin")]
     syncs = spans.named("ops.host_sync")
-    assert syncs and all(inside(s, join) for s in syncs)
-    assert {s["site"] for s in syncs} <= {"join.inner", "join_pallas.inner"}
+    assert syncs and all(any(inside(s, o) for o in ops) for s in syncs)
+    in_join = [s for s in syncs if inside(s, join)]
+    assert in_join and {s["site"] for s in in_join} \
+        <= {"join.inner", "join_pallas.inner", "gather.has_negative"}
+    # the filter packs the rows it keeps and the aggregate reads its group
+    # count: each a number the next shape waits for
+    assert {"gather.kept_rows", "groupby.groups"} \
+        <= {s["site"] for s in syncs}
     assert {s["request"] for s in syncs} == {join["request"]}
 
 
@@ -322,6 +328,220 @@ def test_cpu_tier_op_spans_say_degraded(session):
     spans = session(lambda: ex.execute(plan, inputs, tier="cpu"))
     ops = spans.named("plan.op")
     assert ops and {o["tier"] for o in ops} == {"degraded"}
+
+
+# ---- the leaves of a request (PR 38) ----------------------------------------------
+
+CAPPED_LEAVES = ["plan.bind", "plan.caps", "plan.program", "plan.launch",
+                 "plan.wait", "plan.readback", "plan.result", "plan.result"]
+LEAF_PARENT = {"plan.bind": "plan.execute", "plan.caps": "plan.run",
+               "plan.program": "plan.attempt", "plan.launch": "plan.attempt",
+               "plan.wait": "plan.run", "plan.readback": "plan.run"}
+
+
+def _leaves_of_a_capped_request(spans):
+    """The capped tier's leaves in the order they ran, each inside the
+    bracket docs/plan.md names; `plan.result` twice: the tier's epilogue
+    inside `plan.run`, the stamps after it."""
+    leaves = sorted((s for s in spans if s["name"] in set(CAPPED_LEAVES)),
+                    key=lambda s: s["t0"])
+    assert [s["name"] for s in leaves] == CAPPED_LEAVES
+    for leaf in leaves[:-2]:
+        assert inside(leaf, spans.one(LEAF_PARENT[leaf["name"]])), leaf
+    run, execute = spans.one("plan.run"), spans.one("plan.execute")
+    epilogue, stamps = leaves[-2:]
+    assert inside(epilogue, run) and inside(stamps, execute)
+    assert stamps["t0"] >= run["t1"]
+    assert all(inside(st, execute) and st["t0"] >= stamps["t1"]
+               for st in spans.named("plan.stats") if st["t0"] > run["t1"])
+    nodes = len(_join_plan().nodes)
+    # two scalars an operator, the decimal overflow's pair and a join's tail
+    assert spans.one("plan.readback")["scalars"] >= 2 * nodes + 2
+    assert spans.one("plan.wait")["site"] == "capped"
+    # the overflow flag is the read that waits for the program
+    (flag,) = [s for s in spans.named("ops.host_sync")
+               if s["site"] == "autoretry.overflow"]
+    assert inside(flag, run) and spans.one("plan.launch")["t1"] \
+        <= flag["t0"] and flag["t1"] <= spans.one("plan.wait")["t0"]
+    return leaves
+
+
+def test_capped_request_shows_its_leaves_once_and_in_order(session):
+    plan, inputs = _join_plan(), {"t": _fact(), "d": _dim()}
+    ex = PlanExecutor(mode="capped")
+    ex.execute(plan, inputs)                              # compile outside
+    spans = session(lambda: ex.execute(plan, inputs))
+    leaves = _leaves_of_a_capped_request(spans)
+    assert {s["request"] for s in leaves} \
+        == {spans.one("plan.execute")["request"]}
+    assert spans.one("plan.launch")["hit"] == 1
+
+
+def test_served_request_has_consult_and_complete_around_execute(session):
+    plan, inputs = _join_plan(), {"t": _fact(), "d": _dim()}
+    sched = ServingScheduler(PlanExecutor(mode="capped"), workers=1,
+                             stats_store=stats_mod.StatsStore())
+    sess = sched.open_session("tenant")
+    try:
+        sess.submit(plan, inputs).result(timeout=120)     # compile outside
+        tickets = []
+        spans = session(lambda: tickets.append(
+            sess.submit(plan, {"t": _fact(seed=1), "d": inputs["d"]}))
+            or tickets[0].result(timeout=120))
+    finally:
+        sess.close()
+        sched.close()
+    _leaves_of_a_capped_request(spans)
+    dispatch, execute = spans.one("serving.dispatch"), \
+        spans.one("plan.execute")
+    consult, complete = spans.one("serving.consult"), \
+        spans.one("serving.complete")
+    assert inside(consult, dispatch) and inside(complete, dispatch)
+    assert consult["t1"] <= execute["t0"] and execute["t1"] <= complete["t0"]
+    assert {consult["request"], complete["request"], execute["request"]} \
+        == {tickets[0].request}
+    assert consult["thread"] != spans.one("serving.submit")["thread"]
+    # the digest's 16 bytes are a wait on the device like any other
+    (read,) = [s for s in spans.named("ops.host_sync")
+               if s["site"] == "digest"]
+    assert inside(read, spans.one("serving.digest"))
+
+
+def test_a_cache_hit_at_dispatch_still_completes_under_its_span(session):
+    """The worker's two leaves hold the whole of `serving.dispatch` when
+    the dispatch-time consult answers and nothing executes."""
+    plan, inputs = _join_plan(), {"t": _fact(), "d": _dim()}
+    sched = ServingScheduler(PlanExecutor(mode="eager"), workers=1)
+    sess = sched.open_session("tenant")
+    try:
+        sess.submit(plan, inputs).result(timeout=120)
+        job_key = []
+        real_get = sched.cache.get
+
+        def miss_at_submit(key, **kw):     # the twin was still queued
+            job_key.append(key)
+            return real_get(key, **kw) if kw else None
+        sched.cache.get = miss_at_submit
+        done = []
+        spans = session(lambda: done.append(
+            sess.submit(plan, inputs)) or done[0].result(timeout=60))
+    finally:
+        sess.close()
+        sched.close()
+    assert done[0].cached and not spans.named("plan.execute")
+    dispatch = spans.one("serving.dispatch")
+    assert inside(spans.one("serving.consult"), dispatch)
+    assert inside(spans.one("serving.complete"), dispatch)
+
+
+def test_eager_request_has_one_wait_under_every_operator(session):
+    plan, inputs = _join_plan(), {"t": _fact(), "d": _dim()}
+    ex = PlanExecutor(mode="eager")
+    ex.execute(plan, inputs)
+    spans = session(lambda: ex.execute(plan, inputs))
+    ops, waits = spans.named("plan.op"), spans.named("plan.wait")
+    assert ops
+    for op in ops:
+        mine = [w for w in waits if inside(w, op) and w["site"] == "op"]
+        assert len(mine) == 1, op["op"]
+        # the operator's last act: nothing of its own follows the wait
+        assert op["t1"] - mine[0]["t1"] < 2_000_000, op["op"]
+    # the aggregate's kernel blocks inside its own span as well
+    (g,) = spans.named("ops.groupby")
+    assert [w["site"] for w in waits if inside(w, g)] == ["groupby"]
+    assert len(waits) == len(ops) + 1
+    # the epilogue and the stamps: the eager tier's two `plan.result`
+    results = spans.named("plan.result")
+    assert len(results) == 2 and inside(results[0], spans.one("plan.run"))
+    assert inside(spans.one("plan.bind"), spans.one("plan.execute"))
+    assert not spans.named("plan.caps") and not spans.named("plan.readback")
+
+
+def test_cpu_tier_waits_and_result_take_the_same_names(session):
+    plan, inputs = _join_plan(), {"t": _fact(), "d": _dim()}
+    ex = PlanExecutor(mode="eager")
+    spans = session(lambda: ex.execute(plan, inputs, tier="cpu"))
+    ops = spans.named("plan.op")
+    assert [w["site"] for w in spans.named("plan.wait")
+            if w["site"] != "groupby"] == ["degraded_op"] * len(ops)
+    assert len(spans.named("plan.result")) == 2
+
+
+def _fresh_capped_plan(tag: int):
+    """A plan no executor has seen: the literal is part of its
+    fingerprint."""
+    b = PlanBuilder()
+    return (b.scan("t", schema=["k", "v"]).filter(col("v") > 10 + tag)
+            .aggregate(["k"], [("v", "sum", "total")]).build())
+
+
+def test_lowerings_land_on_the_span_that_caused_them(session):
+    plan, inputs = _fresh_capped_plan(1), {"t": _fact()}
+    ex = PlanExecutor(mode="capped")
+    done = []
+    cold = session(lambda: done.append(ex.execute(plan, inputs)))
+    warm = session(lambda: done.append(ex.execute(plan, inputs)))
+    first, second = done
+    assert first.lowerings >= 1 and first.lowering_ms > 0
+    assert (second.lowerings, second.lowering_ms) == (0, 0.0)
+    execute = cold.one("plan.execute")
+    assert execute["lowerings"] == first.lowerings
+    assert abs(execute["lowering_ms"] - first.lowering_ms) < 0.01
+    missed = [a for a in cold.named("plan.attempt") if a["hit"] == 0]
+    assert missed and sum(a["lowerings"] for a in missed) >= 1
+    assert any("capped_plan" in a["lowered"] for a in missed)
+    # a bracket's numbers include its children's, and no more
+    assert sum(a["lowerings"] for a in cold.named("plan.attempt")) \
+        <= execute["lowerings"]
+    assert "lowered" in execute and "," not in execute["lowered"]
+    w = warm.one("plan.execute")
+    assert (w["lowerings"], w["lowering_ms"]) == (0, 0) and "lowered" not in w
+    (a,) = warm.named("plan.attempt")
+    assert (a["hit"], a["lowerings"]) == (1, 0)
+
+
+def test_an_eager_operator_carries_the_lowering_of_its_new_program(session):
+    """A shape no program of this process has seen: the per-operator
+    program of the filter is lowered under that operator's `plan.op`."""
+    n = 1237                                    # no other test's length
+    plan, inputs = _fresh_capped_plan(2), {"t": _fact(n=n)}
+    ex = PlanExecutor(mode="eager")
+    done = []
+    spans = session(lambda: done.append(ex.execute(plan, inputs)))
+    ops = spans.named("plan.op")
+    assert all("lowerings" in o for o in ops)
+    assert sum(o["lowerings"] for o in ops) >= 1
+    assert done[0].lowerings >= sum(o["lowerings"] for o in ops)
+    assert done[0].lowerings == spans.one("plan.execute")["lowerings"]
+    again = ex.execute(plan, inputs)
+    assert again.lowerings == 0
+
+
+def test_two_threads_lowerings_do_not_mix():
+    """The pair is per thread: a request that compiles beside one that
+    does not leaves the other's result at 0 (no profiler needed)."""
+    warm_plan, inputs = _fresh_capped_plan(3), {"t": _fact()}
+    ex = PlanExecutor(mode="capped")
+    ex.execute(warm_plan, inputs)
+    cold, warm, stop = [], [], threading.Event()
+
+    def compile_one():
+        try:
+            cold.append(ex.execute(_fresh_capped_plan(4), inputs))
+        finally:
+            stop.set()
+
+    def run_warm():
+        while not stop.is_set() or not warm:
+            warm.append(ex.execute(warm_plan, inputs))
+    threads = [threading.Thread(target=f) for f in (run_warm, compile_one)]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join()
+    assert cold[0].lowerings >= 1
+    assert warm and {r.lowerings for r in warm} == {0}
+    assert {r.lowering_ms for r in warm} == {0.0}
 
 
 # ---- names inside the capped program ------------------------------------------------------
@@ -685,3 +905,222 @@ def test_collectives_run_under_an_exchange_scope():
     reduced = text_of(lambda v, a: distributed_reduce(
         mesh, [v], [(0, "sum")], a), k, k > 3)
     assert "exchange.reduce" in reduced and "all-reduce" in reduced
+
+
+# ---- every wait on the device has one of three names ----------------------------------
+#
+# The rule that keeps PR 38's account whole: in the modules a request runs
+# through, a call that makes the host wait for the device (`block_until_
+# ready`, `device_get`, `.item()`, `.tolist()`, and `int(` / `bool(` /
+# `float(` / `np.asarray(` over a device value) lies inside a `with
+# span("ops.host_sync" | "plan.wait" | "plan.readback", ...)`, or is listed
+# in EXEMPT with its reason. What holds a device value is decided from the
+# syntax alone: a `jnp.` / `lax.` / `jax.` call, a program from `_jitted`,
+# a column's or a relation's buffers, and the names assigned from those
+# earlier in the same function.
+
+WALKED = ["plan/executor.py", "plan/distributed.py", "parallel/relational.py",
+          "parallel/autoretry.py", "ops/join.py", "ops/join_lookup.py",
+          "ops/join_pallas.py", "ops/select_pallas.py", "ops/gather.py",
+          "ops/aggregate.py", "serving/scheduler.py", "serving/cache.py"]
+HOLDERS = {"ops.host_sync", "plan.wait", "plan.readback"}
+DEVICE_ROOTS = {"jnp", "lax", "jax"}
+HOST_ATTRS = {"shape", "dtype", "ndim", "num_rows", "length", "nbytes", "size", "names", "padded_rows"}
+DEVICE_ATTRS = {"valid", "data", "validity", "offsets", "planes", "alive"}
+DEVICE_CALLS = {"fn", "fn1", "spans", "emit", "_retry", "_fold_buffers",
+                "_member", "auto_retry_overflow"}
+DEVICE_PARAMS = {"lost", "valid", "mask", "idx", "nulled"}
+PASS_THROUGH = {"list", "tuple", "sum", "zip", "max", "min"}
+CONVERT = {"int", "bool", "float"}
+
+
+def _root(expr):
+    while isinstance(expr, (ast.Attribute, ast.Subscript, ast.Call)):
+        expr = expr.func if isinstance(expr, ast.Call) else expr.value
+    return expr.id if isinstance(expr, ast.Name) else None
+
+
+def _reads(call):
+    """The kind of host read a Call is, or None."""
+    f = call.func
+    if isinstance(f, ast.Attribute):
+        if f.attr in ("block_until_ready", "device_get"):
+            return f.attr
+        if f.attr in ("item", "tolist") and not call.args:
+            return f.attr
+        if f.attr in ("asarray", "array") and _root(f) == "np":
+            return "np." + f.attr
+    elif isinstance(f, ast.Name) and f.id in CONVERT:
+        return f.id
+    return None
+
+
+class Func:
+    """One function's assignments in line order, to say whether an
+    expression holds a device value at a line."""
+    def __init__(self, fn):
+        self.assigned = []      # (line, name, value expr)
+        self.params = {a.arg for a in fn.args.args} & DEVICE_PARAMS
+        for node in ast.walk(fn):
+            if isinstance(node, ast.Assign):
+                for t in node.targets:
+                    self._bind(t, node.value, node.lineno)
+            elif isinstance(node, (ast.For, ast.comprehension)):
+                # a comprehension binds before the line that uses it
+                line = getattr(node, "lineno", node.iter.lineno - 1)
+                self._bind(node.target, node.iter, line)
+        self.assigned.sort(key=lambda a: a[0])
+
+    def _bind(self, target, value, line):
+        for n in ast.walk(target):
+            if isinstance(n, ast.Name):
+                self.assigned.append((line, n.id, value))
+
+    def device(self, expr, line, depth=0) -> bool:
+        if depth > 6:
+            return False
+        d = lambda e: self.device(e, line, depth + 1)
+        if isinstance(expr, ast.Name):
+            last = [(l, v) for l, n, v in self.assigned
+                    if n == expr.id and l < line]
+            if not last:
+                return expr.id in self.params
+            return self.device(last[-1][1], last[-1][0], depth + 1)
+        if isinstance(expr, ast.Attribute):
+            if expr.attr in HOST_ATTRS:
+                return False
+            return expr.attr in DEVICE_ATTRS or d(expr.value)
+        if isinstance(expr, ast.Subscript):
+            return d(expr.value)
+        if isinstance(expr, ast.Call):
+            if _reads(expr) not in (None, "block_until_ready"):
+                return False            # a conversion's result is the host's
+            f = expr.func
+            if _root(f) in DEVICE_ROOTS:
+                return True
+            if isinstance(f, ast.Call) and _root(f) == "_jitted":
+                return True
+            name = f.attr if isinstance(f, ast.Attribute) else getattr(f, "id", "")
+            if name in DEVICE_CALLS:
+                return True
+            if name in PASS_THROUGH:
+                return any(d(a) for a in expr.args)
+            if isinstance(f, ast.Attribute) and _root(f) != "np":
+                return d(f.value)       # a method of a device value
+            return False
+        if isinstance(expr, (ast.Tuple, ast.List)):
+            return any(d(e) for e in expr.elts)
+        if isinstance(expr, ast.BinOp):
+            return d(expr.left) or d(expr.right)
+        if isinstance(expr, ast.UnaryOp):
+            return d(expr.operand)
+        if isinstance(expr, ast.Compare):
+            return d(expr.left) or any(d(c) for c in expr.comparators)
+        if isinstance(expr, ast.IfExp):
+            return d(expr.body) or d(expr.orelse)
+        if isinstance(expr, (ast.GeneratorExp, ast.ListComp)):
+            return d(expr.elt)
+        if isinstance(expr, ast.Starred):
+            return d(expr.value)
+        return False
+
+
+def blocking_reads(rel, source=None):
+    """[(file, function, kind, nth of its kind there, held)] of one module:
+    every call that makes the host wait for a device value, and whether a
+    `with span(<one of HOLDERS>, ...)` holds it."""
+    if source is None:
+        with open(os.path.join(PKG, rel)) as f:
+            source = f.read()
+    tree = ast.parse(source)
+    parents = {}
+    for node in ast.walk(tree):
+        for child in ast.iter_child_nodes(node):
+            parents[child] = node
+    out, seen = [], {}
+    funcs = {}
+    for node in ast.walk(tree):
+        if not isinstance(node, ast.Call):
+            continue
+        kind = _reads(node)
+        if kind is None:
+            continue
+        chain, p = [], node
+        while p in parents:
+            p = parents[p]
+            chain.append(p)
+        fns = [c for c in chain if isinstance(c, (ast.FunctionDef, ast.AsyncFunctionDef))]
+        if not fns:
+            continue
+        outer = fns[-1]
+        if kind not in ("block_until_ready", "device_get"):
+            flow = funcs.setdefault(outer, Func(outer))
+            arg = node.func.value if kind in ("item", "tolist") else (node.args[0] if node.args else None)
+            if arg is None or not flow.device(arg, node.lineno):
+                continue
+        held = any(isinstance(c, ast.With) and any(
+            isinstance(i.context_expr, ast.Call)
+            and getattr(i.context_expr.func, "id", "") == "span"
+            and i.context_expr.args
+            and getattr(i.context_expr.args[0], "value", None) in HOLDERS
+            for i in c.items) for c in chain)
+        name = ".".join(f.name for f in reversed(fns))
+        n = seen[(name, kind)] = seen.get((name, kind), 0) + 1
+        out.append((rel, name, kind, n, held))
+    return out
+
+
+# (file, function, kind, nth) -> why it may wait outside the three names
+EXEMPT = {
+    ("plan/executor.py", "compact", "np.asarray", 1):
+        "PlanResult.compact is the client's call after execute() returned: "
+        "not on the request's path",
+}
+
+SITES = [site for rel in WALKED for site in blocking_reads(rel)]
+
+
+@pytest.mark.parametrize(
+    "site", SITES, ids=[f"{f}:{fn}:{kind}:{n}" for f, fn, kind, n, _ in SITES])
+def test_blocking_read_is_held_by_a_wait_span(site):
+    *where, held = site
+    assert held or tuple(where) in EXEMPT, (
+        f"{where}: a read that waits for the device outside ops.host_sync, "
+        "plan.wait and plan.readback (docs/plan.md, Reading a profile)")
+
+
+def test_the_walk_finds_what_the_request_path_is_known_to_read():
+    found = {(f, fn, kind) for f, fn, kind, _, _ in SITES}
+    for known in [("plan/executor.py", "_execute_capped", "block_until_ready"),
+                  ("plan/executor.py", "_execute_capped", "np.asarray"),
+                  ("plan/executor.py", "_execute_eager", "block_until_ready"),
+                  ("plan/distributed.py", "num_rows", "int"),
+                  ("plan/distributed.py", "_repartition_rel", "np.asarray"),
+                  ("parallel/autoretry.py", "auto_retry_overflow", "bool"),
+                  ("ops/join.py", "_sort_inner_join", "int"),
+                  ("ops/join_lookup.py", "member_mask", "int"),
+                  ("ops/gather.py", "kept_rows", "int"),
+                  ("ops/gather.py", "take", "device_get"),
+                  ("ops/aggregate.py", "_groupby", "int"),
+                  ("serving/cache.py", "_table_digest", "device_get")]:
+        assert known in found, known
+    assert len(SITES) >= 40
+    assert set(EXEMPT) <= {tuple(s[:4]) for s in SITES if not s[4]}, \
+        "an exemption that exempts nothing"
+
+
+@pytest.mark.parametrize("body, want", [
+    ("n = int(jnp.sum(mask))", [("int", False)]),
+    ("with span('ops.host_sync', site='x'):\n        n = int(jnp.sum(mask))",
+     [("int", True)]),
+    ("with span('plan.exchange'):\n        jax.block_until_ready(out)",
+     [("block_until_ready", False)]),
+    ("counts = fn(mask)\n    host = np.asarray(counts)\n"
+     "    return int(host.max())", [("np.asarray", False)]),
+    ("total = jnp.sum(mask)\n    return total.item()", [("item", False)]),
+    ("return int(mask.shape[0]) + len(mask)", []),
+])
+def test_the_walk_tells_a_device_read_from_a_host_number(body, want):
+    source = "def f(mask, out, fn):\n    " + body + "\n"
+    assert [(kind, held) for _, _, kind, _, held
+            in blocking_reads("x.py", source)] == want
