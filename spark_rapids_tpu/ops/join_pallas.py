@@ -9,24 +9,35 @@ IN-KERNEL (the exact "planes already split" reuse case hash_pallas's
 module docstring identifies as its win condition — no standalone hash
 materialization pass):
 
-- **build** (one `pallas_call`): murmur3 bucket hashes of the build keys
-  computed from the word planes on the VPU, then a VMEM-resident open-
-  addressing table (capacity = 2x rows rounded to a power of two, linear
-  probing) filled by PARALLEL insertion rounds: every unplaced row
-  proposes `(h + probe_distance) & (C-1)`, the winner per free slot is the
-  minimum row id (a masked sublane reduction), winners' key words land in
-  the table via one-hot matrix products on the MXU (u16 halves, one term
-  per slot — bit-exact in f32), losers advance their probe distance.
-  Insertion therefore lands equal keys in ascending-row chain order, which
-  is what makes probe emission order match the sort-based fallback
-  exactly.
-- **probe** (two `pallas_call`s): per 128-row block, bucket hashes from
-  the probe planes in-kernel, then a vectorized chain walk — each round
-  gathers 128 slots in one one-hot matmul against the table matrix and
-  compares raw key words; counting stops per-lane at the first empty slot
-  (the linear-probing invariant). A count pass sizes the output exactly
-  like the fallback's span kernel; an emit pass re-walks to the k-th match
-  per output slot.
+- **build** (one `pallas_call`, `pallas_hash_join_build`): murmur3 bucket
+  hashes of the build keys computed from the word planes on the VPU, then
+  a VMEM-resident open-addressing table (capacity = 2x rows rounded to a
+  power of two, linear probing) filled by PARALLEL insertion rounds: every
+  unplaced row proposes `(h + probe_distance) & (C-1)`, the winner per free
+  slot is the minimum row id (a masked sublane reduction), winners' key
+  words land in the table via one-hot matrix products on the MXU (u16
+  halves, one term per slot — bit-exact in f32), losers advance their
+  probe distance. Insertion therefore lands equal keys in ascending-row
+  chain order, which is what makes probe emission order match the
+  sort-based fallback exactly.
+- **probe** (two `pallas_call`s, both `pallas_hash_join_probe`): per
+  128-row block, bucket hashes from the probe planes in-kernel, then a
+  vectorized chain walk — each round gathers 128 slots in one one-hot
+  matmul against the table matrix and compares raw key words; counting
+  stops per-lane at the first empty slot (the linear-probing invariant). A
+  count pass sizes the output exactly like the fallback's span kernel; an
+  emit pass re-walks to the k-th match per output slot.
+
+The three `pallas_call`s never run as programs of their own. The capped
+entry (`inner_join_capped_pallas`) is traced inside its caller's program
+(`jit_capped_plan`). The eager entry (`inner_join_pallas`) is TWO jitted
+programs with the match count's read between them: `_count_matches` (both
+sides' validity, the build call, the probe planes, the count call and the
+sum of the counts) and, `total` known, `_emit_matches` (the expansion and
+the emit call). Called outside a jit, `pl.pallas_call` wraps a fresh
+`kernel` closure: the kernel bodies were traced to jaxprs and lowered again
+on every request, with some dozens of single-op programs dispatched around
+them (225 of `q3.share`'s 333 ms a request until PR 40, PERF.md).
 
 Nulls never match (Spark equi-join): invalid build rows are never
 inserted, invalid probe rows count zero — the same lvalid/rvalid masks the
@@ -399,17 +410,47 @@ def _emit_rows(counts, lplanes, total: int):
     """-> (lsel, ktgt, sel_planes) for the emit pass's `total` slots: the
     slot's left row, which of that row's matches it is, and the row's
     probe planes, gathered over the live slots (ops/join.py:expand_rows,
-    ops/gather.py:gather_live). One program a `total`: called eagerly, the
-    loops' fresh closures would be lowered again on every call."""
+    ops/gather.py:gather_live). Its jit names a scope (`jit(_emit_rows)`)
+    in its callers' programs and is no program of its own: the eager entry
+    reaches it inside `_emit_matches`, the capped one inside its caller's."""
     lsel, starts, live = expand_rows(counts, total)
     first, *sel_planes = gather_live([starts, *lplanes], lsel, live)
     return lsel, jnp.arange(total, dtype=jnp.int32) - first, sel_planes
 
 
+# The eager entry's two programs (module docstring): what depends on shapes
+# and dtypes alone is done once per shape, and `jax.jit`'s cache is the cache.
+
+@partial(jax.jit, static_argnames=("interpret",))
+def _count_matches(lcols, rcols, interpret: bool):
+    """Stage A, from the key columns to the match count: both sides'
+    validity, the build table, the probe planes, the count pass.
+    -> (counts, probe planes, table matrix, sum of counts). One program a
+    (key layout, left rows, right rows)."""
+    lvalid = _side_valid(lcols, lcols[0].length)
+    rvalid = _side_valid(rcols, rcols[0].length)
+    counts, lplanes, _, _, tbl, _ = _prep_probe(lcols, rcols, lvalid, rvalid,
+                                                interpret)
+    return counts, lplanes, tbl, jnp.sum(counts)
+
+
+@partial(jax.jit, static_argnames=("total", "layout", "interpret"))
+def _emit_matches(counts, lplanes, tbl, total: int, layout: Tuple[int, ...],
+                  interpret: bool):
+    """Stage B, the expansion and the emit pass -> (lsel, rmap) of `total`
+    pairs. One program a (key layout, `total`, table capacity): called
+    eagerly, the expansion's loops and the emit kernel are fresh closures
+    and would be lowered again on every call."""
+    lsel, ktgt, sel_planes = _emit_rows(counts, lplanes, total)
+    rmap = _probe_emit(sel_planes, ktgt, layout, tbl.shape[0], tbl, interpret)
+    return lsel, rmap
+
+
 def inner_join_pallas(left_keys, right_keys,
                       interpret: Optional[bool] = None):
     """Eager inner equi-join via hash build/probe: gather maps
-    (left_map, right_map), pair-for-pair identical to `ops.inner_join`."""
+    (left_map, right_map), pair-for-pair identical to `ops.inner_join`.
+    Two cached programs with the match count's read between them."""
     from .join import _cols
     lcols, rcols = _cols(left_keys), _cols(right_keys)
     nl, nr = lcols[0].length, rcols[0].length
@@ -417,19 +458,18 @@ def inner_join_pallas(left_keys, right_keys,
         e = jnp.zeros((0,), jnp.int32)
         return (Column(dtype=dtypes.INT32, length=0, data=e),
                 Column(dtype=dtypes.INT32, length=0, data=e))
-    lvalid = _side_valid(lcols, nl)
-    rvalid = _side_valid(rcols, nr)
-    counts, lplanes, layout, C, tbl, interpret = _prep_probe(
-        lcols, rcols, lvalid, rvalid, interpret)
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    counts, lplanes, tbl, matches = _count_matches(lcols, rcols, interpret)
     with span("ops.host_sync", site="join_pallas.inner"):
-        total = int(jnp.sum(counts))        # the one host sync (same as the
-        #                                     fallback's match-count sync)
+        total = int(jax.device_get(matches))    # the one host sync (same
+        #                                         as the fallback's)
     if total == 0:
         e = jnp.zeros((0,), jnp.int32)
         return (Column(dtype=dtypes.INT32, length=0, data=e),
                 Column(dtype=dtypes.INT32, length=0, data=e))
-    lsel, ktgt, sel_planes = _emit_rows(counts, lplanes, total)
-    rmap = _probe_emit(sel_planes, ktgt, layout, C, tbl, interpret)
+    lsel, rmap = _emit_matches(counts, lplanes, tbl, total,
+                               tuple(_layout_of(rcols)), interpret)
     return (Column(dtype=dtypes.INT32, length=total, data=lsel),
             Column(dtype=dtypes.INT32, length=total, data=rmap))
 

@@ -17,7 +17,10 @@ file), the compiles run in the test's own process, and the persistent
 compile cache is off around them (an executable built for a described
 device cannot be read back without one).
 """
+import base64
+import hashlib
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -308,7 +311,6 @@ def test_capped_q3_joins_gather_in_loops_and_unread_columns_stay_pruned(
 
 def _gather_slots(text):
     """Output slots of every `gather` instruction of an executable's text."""
-    import re
     return [int(re.search(r"\[(\d+)", line.split(" gather(")[0]).group(1))
             for line in text.splitlines() if " gather(" in line]
 
@@ -345,6 +347,22 @@ def test_capped_join_compiles_with_both_tails_for_v5e(one_chip,
     assert set(_gather_slots(text)) == {1024}
 
 
+def _capped_pallas_join_lowered(one_chip, kinds):
+    """`inner_join_capped_pallas` over keys of `kinds`, 8,192 rows against
+    512 under a cap of 4,096 and an alive mask, lowered for the described
+    chip with its kernels for Mosaic."""
+    from spark_rapids_tpu.ops import join_pallas
+    nl, nr, cap = 8192, 512, 4096
+
+    def columns(n):
+        return [Column(dtype=d, length=n, data=jax.ShapeDtypeStruct(
+            (n,), d.storage_dtype(), sharding=one_chip)) for d in kinds]
+    return jax.jit(lambda l, r, alive: join_pallas.inner_join_capped_pallas(
+        l, r, cap, lalive=alive, interpret=False)).lower(
+            columns(nl), columns(nr),
+            jax.ShapeDtypeStruct((nl,), jnp.bool_, sharding=one_chip))
+
+
 def test_pallas_capped_join_expands_in_loops_for_v5e(one_chip,
                                                      no_persistent_cache):
     """The Pallas capped join whole (ops/join_pallas.py), its kernels
@@ -352,26 +370,98 @@ def test_pallas_capped_join_expands_in_loops_for_v5e(one_chip,
     expansion is the shared one, a scatter in a loop over the left rows
     that emit and ONE loop that gathers `starts` and both probe planes
     over the live slots. No gather runs at the cap."""
-    from spark_rapids_tpu.ops import join_pallas
     from spark_rapids_tpu.ops.gather import live_chunk
-    nl, nr, cap = 8192, 512, 4096
-    assert live_chunk(nl) == live_chunk(cap) == 1024
-
-    def column(n):
-        return Column(dtype=dtypes.INT64, length=n,
-                      data=jax.ShapeDtypeStruct((n,), jnp.int64,
-                                                sharding=one_chip))
-    text = jax.jit(lambda l, r, alive: join_pallas.inner_join_capped_pallas(
-        [l], [r], cap, lalive=alive, interpret=False)).lower(
-            column(nl), column(nr),
-            jax.ShapeDtypeStruct((nl,), jnp.bool_, sharding=one_chip)
-        ).compile().as_text()
+    assert live_chunk(8192) == live_chunk(4096) == 1024
+    text = _capped_pallas_join_lowered(one_chip, [dtypes.INT64]) \
+        .compile().as_text()
     assert len(_op_names(text, "while")) == 2
     assert [n.split("/", 1)[1] for n in _op_names(text, "scatter")] == \
         ["jit(_emit_rows)/while/body/scatter"]
     assert _gather_slots(text) == [1024] * 3
     assert sum("pallas_hash_join_probe/pallas_call" in n
                for n in _op_names(text, "custom-call")) == 2
+
+
+Q3_SHARE_ROWS = 2_250_000   # `q3.share`: the resident batch's rows
+
+
+def test_eager_pallas_join_programs_compile_for_v5e(one_chip,
+                                                    no_persistent_cache):
+    """The eager entry's two programs (ops/join_pallas.py: `_count_matches`,
+    `_emit_matches`) whole, at `q3.share`'s frame against the filtered
+    dates: the build and the count kernel in the first, the expansion's
+    two loops and the emit kernel in the second, under the names
+    `pallas_join_bw_share` reads them by."""
+    from spark_rapids_tpu.ops import join_pallas
+
+    def shape(n, dtype):
+        return jax.ShapeDtypeStruct((n,), dtype, sharding=one_chip)
+    left = [Column(dtype=dtypes.INT64, length=Q3_SHARE_ROWS,
+                   data=shape(Q3_SHARE_ROWS, jnp.int64),
+                   validity=shape(Q3_SHARE_ROWS, jnp.bool_))]
+    right = [Column(dtype=dtypes.INT64, length=N_DATES_KEPT,
+                    data=shape(N_DATES_KEPT, jnp.int64))]
+    count = join_pallas._count_matches.lower(left, right, interpret=False)
+    counts, planes, tbl, _ = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        count.out_info)
+    emit = join_pallas._emit_matches.lower(
+        counts, planes, tbl, total=Q3_SHARE_ROWS // 12, layout=(8,),
+        interpret=False)
+    count, emit = count.compile().as_text(), emit.compile().as_text()
+    assert [n for n in _op_names(count, "custom-call") if "pallas" in n] == [
+        "jit(_count_matches)/pallas_hash_join_build/pallas_call",
+        "jit(_count_matches)/pallas_hash_join_probe/pallas_call"]
+    assert len(_op_names(emit, "while")) == 2
+    assert sum("pallas_hash_join_probe/pallas_call" in n
+               for n in _op_names(emit, "custom-call")) == 1
+
+
+def _without_locations(text):
+    """A lowered program's text with every Mosaic payload (bytecode that
+    carries its callers' file names and line numbers, so it differs from
+    checkout to checkout) replaced by the kernel's own text without them."""
+    from jax._src.lib.mlir import ir
+
+    def kernel(m):
+        ctx = ir.Context()
+        ctx.allow_unregistered_dialects = True
+        with ctx:
+            body = ir.Module.parse(base64.b64decode(m.group(2)))
+            return m.group(1) + body.operation.get_asm(
+                enable_debug_info=False) + m.group(3)
+    return re.sub(r'(\\22body\\22: \\22)([A-Za-z0-9+/=]+)(\\22)', kernel,
+                  text)
+
+
+# the capped Pallas join's program as 5d88d9f (PR 38) lowers it, before the
+# eager entry became two jitted programs (PR 40): StableHLO ops, and the
+# digest of the text with its kernels' source locations stripped
+PALLAS_CAPPED_JOIN_AT_PR38 = {
+    "int64_key": (272, "77315d7f3d6ee662394958c9c9ce9fb6c79333277cb64473"
+                       "bf16bcfd574b170d"),
+    "int32_and_int64_keys": (294, "5d270b4ecacd9344c592cc076161673df3db1e6e"
+                                  "389a5138d2a9349c35251cad"),
+}
+
+
+@pytest.mark.parametrize("layout", sorted(PALLAS_CAPPED_JOIN_AT_PR38))
+def test_pallas_capped_join_lowers_to_the_text_it_had(one_chip, layout):
+    """`inner_join_capped_pallas` shares its helpers and kernel bodies with
+    the eager entry. A change meant for the eager entry alone leaves this
+    program's text as it was: a program whose text changes is a cold
+    compile of `q72.tasks` (470-500 s on the chip host). Whoever changes
+    the capped join on purpose pins the new text here."""
+    kinds = {"int64_key": [dtypes.INT64],
+             "int32_and_int64_keys": [dtypes.INT32, dtypes.INT64]}[layout]
+    text = _capped_pallas_join_lowered(one_chip, kinds).as_text()
+    assert re.findall(r"pallas_hash_join_[a-z]+", text) == [
+        "pallas_hash_join_build", "pallas_hash_join_probe",
+        "pallas_hash_join_probe"]
+    ops, digest = PALLAS_CAPPED_JOIN_AT_PR38[layout]
+    assert len(re.findall(r"stablehlo\.\w+", text)) == ops
+    assert hashlib.sha256(
+        _without_locations(text).encode()).hexdigest() == digest
 
 
 Q18_LINES = 59_998_501      # `q18.batch`: lineitem's rows at SF10
@@ -387,7 +477,6 @@ def test_lookup_membership_compiles_for_v5e(one_chip, no_persistent_cache,
     and the mask it needs the frame's 32-bit key words and the loop's mask,
     never a frame's sort buffers (the 60 M-row span kernel it replaces
     needed 2.5 GB, PERF.md, PR 34)."""
-    import re
     from spark_rapids_tpu.ops import join_lookup
     kinds = [jnp.int64] if layout == "int64_key" else [jnp.int32, jnp.int64]
     words = sum(jnp.dtype(k).itemsize // 4 for k in kinds)

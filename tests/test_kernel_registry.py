@@ -382,6 +382,53 @@ def test_hash_join_all_null_and_empty():
     assert gl.length == 0
 
 
+def _hj_sides(layout, seed, nl=1100, nr=230):
+    """Both sides' key columns for `layout`, one draw a seed: the same
+    shapes, dtypes and validity planes whatever the seed."""
+    rng = np.random.default_rng(seed)
+    kinds = {"int32_key": [np.int32], "int64_key": [np.int64],
+             "int32_and_int64_keys": [np.int32, np.int64],
+             "int64_key_with_nulls": [np.int64]}[layout]
+    nulls = layout.endswith("with_nulls")
+    hi = 160 if len(kinds) == 1 else 14
+
+    def side(n):
+        return [Column.from_numpy(
+            rng.integers(0, hi, n).astype(k),
+            validity=(rng.random(n) > 0.1) if nulls else None)
+            for k in kinds]
+    return side(nl), side(nr)
+
+
+@pytest.mark.parametrize("layout", ["int32_key", "int64_key",
+                                    "int32_and_int64_keys",
+                                    "int64_key_with_nulls"])
+def test_eager_hash_join_is_two_cached_programs(layout):
+    """`inner_join_pallas` runs as two jitted programs with the match
+    count's read between them: the same shapes again lower nothing, other
+    key values (another `total`) lower the emit stage alone, and every
+    result is `ops.inner_join`'s pair for pair."""
+    from spark_rapids_tpu.utils import tracing
+
+    def joined(sides):
+        with tracing.bracket("test.join") as b:
+            gl, gr = join_pallas.inner_join_pallas(*sides)
+        n, _ = b.lowered()
+        names = list(tracing._lowered.names)[-n:] if n else []
+        rl, rr = inner_join(*sides)
+        npt.assert_array_equal(np.asarray(rl.data), np.asarray(gl.data))
+        npt.assert_array_equal(np.asarray(rr.data), np.asarray(gr.data))
+        return gl.length, names
+
+    first, other = _hj_sides(layout, seed=40), _hj_sides(layout, seed=41)
+    total, _ = joined(first)
+    assert total > 0
+    assert joined(first) == (total, [])
+    other_total, names = joined(other)
+    assert other_total not in (0, total)
+    assert names == ["jit(_emit_matches)"]
+
+
 def test_hash_join_signature_declines():
     f = [Column.from_numpy(np.zeros(4, np.float32))]
     i = [Column.from_numpy(np.zeros(4, np.int64))]

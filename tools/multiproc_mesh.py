@@ -111,9 +111,13 @@ def worker(pid: int, port: int) -> None:
     # only -> exactly the even-vocab half of the left side matches
     assert int(srows) == n // 2, int(srows)
 
-    print(f"MULTIPROC MESH OK proc={pid}/{N_PROCS} devices={n_dev} "
-          f"groups={int(groups)} join_rows={int(jrows)} semi={int(srows)}",
-          flush=True)
+    # one write, newline included: the workers share the orchestrator's
+    # stdout, and `print` writes its end apart (two workers' lines merged
+    # into one under an unbuffered stdout and the test counted one)
+    sys.stdout.write(
+        f"MULTIPROC MESH OK proc={pid}/{N_PROCS} devices={n_dev} "
+        f"groups={int(groups)} join_rows={int(jrows)} semi={int(srows)}\n")
+    sys.stdout.flush()
 
 
 def _free_port() -> int:
