@@ -268,7 +268,8 @@ def _rows_interval(node: PlanNode, kids: List[Tuple[int, Optional[int]]],
                    ) -> Tuple[int, Optional[int]]:
     """The transfer function: [lo, hi] of this operator's output rows from
     its children's intervals. Sound for every tier: filters/semijoins
-    collapse lo to 0 and never raise hi; inner joins bound by the cross
+    collapse lo to 0 and never raise hi; a left outer join holds its left
+    side's lo; inner joins bound by the cross
     product; keyed aggregates by their input (distinct groups <= rows)."""
     if isinstance(node, Scan):
         n = _scan_rows(node, bound_rows)
@@ -293,6 +294,11 @@ def _rows_interval(node: PlanNode, kids: List[Tuple[int, Optional[int]]],
     if isinstance(node, HashJoin):
         if node.how == "inner":
             return 0, _mul(his[0], his[1])
+        if node.how == "left_outer":
+            # every left row comes out once at least (null-extended where
+            # nothing matches) and once a match at most
+            return los[0], _mul(his[0], None if his[1] is None
+                                else max(his[1], 1))
         return 0, his[0]                     # semi/anti: left-row subset
     if isinstance(node, HashAggregate):
         if not node.keys:
@@ -412,6 +418,9 @@ def certify_nodes(nodes: List[PlanNode], *, bound=None, bound_rows=None,
             out = dict(kids_n[0])
             if node.how == "inner":
                 out.update(kids_n[1])
+            elif node.how == "left_outer":
+                # a left row without a match is null in every one
+                out.update(dict.fromkeys(kids_n[1], True))
             nullable[id(node)] = out
         elif isinstance(node, HashAggregate):
             out = {k: kids_n[0].get(k, True) for k in node.keys}

@@ -49,7 +49,8 @@ from typing import Dict, List, Optional, Tuple
 
 from ..plan.expr import Expr, col, lit, scalar_max, scalar_min, scalar_sum
 from ..plan.nodes import (Exchange, Filter, FusedSelect, HashAggregate,
-                          HashJoin, Limit, PlanNode, Scan, Sort, TopK,
+                          HashJoin, JOIN_TYPES, Limit, PAIRING_JOINS,
+                          PlanNode, Scan, Sort, TopK,
                           Union)
 
 ALL_KINDS = ("Scan", "Filter", "Project", "FusedSelect", "HashJoin",
@@ -303,12 +304,13 @@ def gen_case(seed: int, *, max_ops: int = 8,
                 continue
             lk = (rng.choice(rel.cols("i")),)
             rk = (rng.choice(other.cols("i")),)
-            how = rng.choices(("inner", "left_semi", "left_anti"),
-                              weights=(3, 1, 1))[0]
-            schema = (rel.schema + other.schema if how == "inner"
+            how = rng.choices(JOIN_TYPES, weights=(3, 1, 1, 1))[0]
+            schema = (rel.schema + other.schema if how in PAIRING_JOINS
                       else list(rel.schema))
-            est = (rel.est * other.est / 4 if how == "inner"
+            est = (rel.est * other.est / 4 if how in PAIRING_JOINS
                    else rel.est * 0.6)
+            if how == "left_outer":     # every left row comes out
+                est = max(est, rel.est)
             out = _Rel(HashJoin(rel.node, other.node, lk, rk, how=how),
                        schema, max(est, 1.0))
         else:   # exchange: hash on an int column, or the identity marker

@@ -49,9 +49,10 @@ from typing import Dict, List, Optional, Tuple
 from .. import dtypes
 from ..plan.expr import (BinOp, ColumnRef, Expr, Literal, ScalarAgg,
                          UnaryOp)
-from ..plan.nodes import (Exchange, Filter, FusedSelect, HashAggregate,
-                          HashJoin, Limit, PlanNode, PlanValidationError,
-                          Project, Scan, Sort, TopK, Union)
+from ..plan.nodes import (PAIRING_JOINS, Exchange, Filter, FusedSelect,
+                          HashAggregate, HashJoin, Limit, PlanNode,
+                          PlanValidationError, Project, Scan, Sort, TopK,
+                          Union)
 
 __all__ = ["Violation", "VerifyReport", "PlanVerificationError",
            "verify", "verify_rewrite", "check_build", "resolve_schemas",
@@ -408,7 +409,7 @@ def _check_types(nodes, schemas, input_dtypes, report: VerifyReport
             continue
         if isinstance(node, HashJoin):
             out = dict(kids[0])
-            if node.how == "inner":
+            if node.how in PAIRING_JOINS:
                 out.update(kids[1])
             types[id(node)] = out
             continue

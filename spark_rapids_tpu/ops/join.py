@@ -85,7 +85,8 @@ from .join_lookup import (lookup_side, member_mask, note_lookup,
                           survivor_rows)
 from .sort import _key_operands
 
-__all__ = ["inner_join", "left_join", "full_join", "left_semi_join",
+__all__ = ["inner_join", "left_join", "left_join_counted", "full_join",
+           "left_semi_join",
            "left_anti_join",
            "inner_join_capped", "inner_join_capped_tail", "left_join_capped",
            "semi_join_mask",
@@ -437,16 +438,35 @@ def inner_join(left_keys, right_keys,
             Column(dtype=dtypes.INT32, length=total, data=rmap))
 
 
+@jax.jit
+def _outer_totals(counts):
+    """-> (matched pairs, left rows without a match), in 64 bits."""
+    return (jnp.sum(counts.astype(jnp.int64)),
+            jnp.sum((counts == 0).astype(jnp.int64)))
+
+
+def left_join_counted(left_keys, right_keys, null_equal: bool = False):
+    """`left_join` and what its one host sync read: (left_map, right_map,
+    matched, unmatched), the pairs that matched and the left rows that
+    came out null-extended (a null key among them). The caller that
+    gathers the right side's columns knows from `unmatched` that the map
+    holds a -1 (take(_has_negative=...)) and need not ask the device."""
+    counts, lo, rorder = _prep(_cols(left_keys), _cols(right_keys), null_equal)
+    with span("ops.host_sync", site="join.left"):
+        matched, unmatched = (int(x) for x in jax.device_get(
+            _outer_totals(counts)))           # the one host sync
+    total = matched + unmatched
+    lmap, rmap = _expand(counts, lo, rorder, total=total, outer=True)
+    return (Column(dtype=dtypes.INT32, length=total, data=lmap),
+            Column(dtype=dtypes.INT32, length=total, data=rmap),
+            matched, unmatched)
+
+
 def left_join(left_keys, right_keys,
               null_equal: bool = False) -> Tuple[Column, Column]:
     """Left outer join: every left row appears; non-matches get right -1
     (take() nullifies)."""
-    counts, lo, rorder = _prep(_cols(left_keys), _cols(right_keys), null_equal)
-    with span("ops.host_sync", site="join.left"):
-        total = int(jnp.sum(jnp.maximum(counts, 1)))
-    lmap, rmap = _expand(counts, lo, rorder, total=total, outer=True)
-    return (Column(dtype=dtypes.INT32, length=total, data=lmap),
-            Column(dtype=dtypes.INT32, length=total, data=rmap))
+    return left_join_counted(left_keys, right_keys, null_equal)[:2]
 
 
 def _require_x64(op_name: str) -> None:

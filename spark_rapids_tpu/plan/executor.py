@@ -90,9 +90,9 @@ from .. import dtypes
 from ..columnar import Column, Table
 from .builder import Plan
 from .metrics import OperatorMetrics, render_profile
-from .nodes import (Exchange, Filter, FusedSelect, HashAggregate, HashJoin,
-                    Limit, PlanNode, PlanValidationError, Project, Scan,
-                    Sort, TopK, Union)
+from .nodes import (PAIRING_JOINS, Exchange, Filter, FusedSelect,
+                    HashAggregate, HashJoin, Limit, PlanNode,
+                    PlanValidationError, Project, Scan, Sort, TopK, Union)
 from .expr import ColumnRef
 from ..utils.tracing import bracket, span, text as _span_text
 
@@ -125,14 +125,17 @@ def _op_span(node: PlanNode, idx: int, tier: str = "device"):
     """The eager tiers' per-operator bracket (utils/tracing.py). `op` is
     `<toposort index>.<kind>`, the name the operator's scope carries
     inside a capped program; `tier` is where it ran: device, host
-    (co-placement thread) or degraded (CPU tier)."""
+    (co-placement thread) or degraded (CPU tier); a join's says `how`."""
+    how = {"how": node.how} if isinstance(node, HashJoin) else {}
     return bracket("plan.op", op=_scope_name(idx, node),
-                   label=_span_text(node.label), tier=tier)
+                   label=_span_text(node.label), tier=tier, **how)
 
 
 _DECIMAL_OVERFLOW = -1      # key of `_run_capped`'s counts, see there
 _JOIN_UNIQUE = -2           # minus twice the join's index: key of the flag
-#                             that says which tail its sort join took
+#                             that says which tail its sort join took (an
+#                             outer join: 0, it expands, and beside it its
+#                             null-extended rows)
 _JOIN_EXPAND = -3           # minus twice the join's index: key of the slots
 #                             its expansion touched, and its frames'
 
@@ -143,6 +146,15 @@ def _scope_name(idx: int, node: PlanNode) -> str:
     fingerprint-equal plans share one compiled program and their labels
     differ."""
     return f"{idx}.{node.kind}"
+
+
+def _null_row(table: Table) -> Table:
+    """One row of nulls in `table`'s schema: what a `left_outer` join puts
+    in an EMPTY right side's place. Its null key matches nothing, so every
+    left row comes out null-extended, and the gathers of the right side's
+    columns have a row to read (a gather from no rows has none)."""
+    return Table([Column.from_pylist([None], c.dtype)
+                  for c in table.columns], names=list(table.names))
 
 
 _HLO_INSTRUCTION = re.compile(
@@ -615,6 +627,9 @@ class PlanResult:
         #                               gathers touched (whole chunks over
         #                               the live rows, ops/gather.py) and
         #                               the caps they would have paid
+        self.outer_joins = 0          # the request's `left_outer` joins and
+        self.outer_unmatched_rows = 0  # the left rows they put out
+        #                               null-extended (no match, a null key)
         self.lookup_joins = 0         # eager tier: joins that took the
         self.lookup_compares = 0      # small-side path, and small rows x
         #                               large rows over them (ops/join.py)
@@ -676,16 +691,19 @@ class _CappedRel:
     """A relation inside the capped trace: padded table + live-row mask;
     `unique`, on a sort join's output, the scalar that says which tail the
     join took (ops/join.py:inner_join_capped_tail); `expanded`, on an inner
-    join's, what its expansion touched (ops/join.py:expansion_slots)."""
+    or outer join's, what its expansion touched
+    (ops/join.py:expansion_slots); `unmatched`, on a `left_outer` join's,
+    its null-extended rows (a device scalar)."""
 
-    __slots__ = ("table", "alive", "unique", "expanded")
+    __slots__ = ("table", "alive", "unique", "expanded", "unmatched")
 
     def __init__(self, table: Table, alive: jnp.ndarray, unique=None,
-                 expanded=None):
+                 expanded=None, unmatched=None):
         self.table = table
         self.alive = alive
         self.unique = unique
         self.expanded = expanded
+        self.unmatched = unmatched
 
 
 class PlanExecutor:
@@ -770,6 +788,16 @@ class PlanExecutor:
         # scopes its ticket's instead — runtime/sessionctx.py)
         self._requests = itertools.count()
 
+    def _mesh_of(self, plan: Plan):
+        """The mesh `plan` runs over, or None: the executor has none, or
+        the plan holds an operator that keeps it on one chip whole
+        (plan/optimizer.py:mesh_local_reason: a `left_outer` join; the
+        optimize report names it under `<label>/mesh`)."""
+        if self.mesh is None:
+            return None
+        from .optimizer import mesh_local_reason
+        return None if mesh_local_reason(plan.nodes) else self.mesh
+
     def _check_capped_mesh(self, plan: Plan) -> None:
         """mode="capped" with a mesh: reject ONLY plans that contain a
         distributed-lowerable operator (the capped tier would silently run
@@ -829,6 +857,8 @@ class PlanExecutor:
                             expand_joins=res.expand_joins,
                             lookup_joins=res.lookup_joins,
                             lookup_compares=res.lookup_compares,
+                            outer_joins=res.outer_joins,
+                            outer_unmatched_rows=res.outer_unmatched_rows,
                             gather_slots=res.gather_slots,
                             cap_slots=res.cap_slots,
                             expand_slots=res.expand_slots,
@@ -868,6 +898,19 @@ class PlanExecutor:
                 if m.kernel == KERNEL_LABEL]
         res.lookup_joins = len(took)
         res.lookup_compares = sum(m.lookup_compares for m in took)
+
+    @staticmethod
+    def _count_outer(res: PlanResult) -> None:
+        """`outer_joins`, `outer_unmatched_rows` of a result, from its
+        operators' metrics (a cached result keeps its own)."""
+        if res.cached or res.outer_joins:
+            return
+        for node in res.plan.nodes:
+            m = res.metrics.get(node.label)
+            if isinstance(node, HashJoin) and node.how == "left_outer" \
+                    and m is not None:
+                res.outer_joins += 1
+                res.outer_unmatched_rows += int(m.unmatched_rows)
 
     def _execute_request(self, plan, inputs, tier, placement,
                          nulled=()) -> PlanResult:
@@ -963,6 +1006,7 @@ class PlanExecutor:
                     res.decimal_overflow_rows += int(sum(nulled))
             self._count_groups(res)
             self._count_lookups(res)
+            self._count_outer(res)
             # serving-session stamp (runtime/sessionctx.py,
             # docs/serving.md): results and per-op metrics carry the tenant
             # they executed for — dispatcher worker threads are multiplexed
@@ -1005,9 +1049,10 @@ class PlanExecutor:
             name: {cn: c.dtype for cn, c in zip(t.names, t.columns)}
             for name, t in inputs.items() if isinstance(t, Table)}
         floats = any(_input_has_floats(t) for t in inputs.values())
+        mesh = self._mesh_of(plan)
         planned = (report is not None and not report.fell_back
-                   and self.mesh is not None and self.mode == "eager"
-                   and self.mesh.shape[self.mesh_axis] > 1)
+                   and mesh is not None and self.mode == "eager"
+                   and mesh.shape[self.mesh_axis] > 1)
         # verdicts memoize on everything the checks read — a repeat
         # execution of the same (plan, binding) pays nothing, the same
         # contract as the rewrite cache feeding it
@@ -1191,8 +1236,9 @@ class PlanExecutor:
                 input_dtypes, input_nullable = \
                     footprint.table_metadata(inputs)
                 bound_rows = {n: t.num_rows for n, t in inputs.items()}
-                n_peers = (self.mesh.shape[self.mesh_axis]
-                           if self.mesh is not None
+                mesh = self._mesh_of(plan)
+                n_peers = (mesh.shape[self.mesh_axis]
+                           if mesh is not None
                            and self.mode == "eager" else 1)
                 key = (plan.root, tuple(sorted(bound.items())),
                        tuple(sorted(bound_rows.items())),
@@ -1412,7 +1458,7 @@ class PlanExecutor:
         # are a single-chip pipeline shape — the distributed tier
         # materializes source-bound scans through one pruned read instead.
         dist = None
-        if self.mesh is not None:
+        if self._mesh_of(plan) is not None:
             from .distributed import DistContext
             dist = DistContext(self, plan, inputs)
         # streamable prefixes over source-bound scans run morsel-at-a-time
@@ -2098,6 +2144,69 @@ class PlanExecutor:
                 m._kernel_sig = (op, sig)
         return choice
 
+    def _eager_join(self, node: HashJoin, lt: Table, rt: Table,
+                    m: OperatorMetrics) -> Table:
+        """The eager tiers' join, in an `ops.join` span that holds its
+        device work (the maps, the output columns' gathers and, where the
+        executor blocks per operator, the wait for them). `inner` and
+        `left_outer` share the gathers: the outer join's right map holds a
+        -1 at every left row without a match, which `take` turns into a
+        null row, and the join's own count says so (no read for it)."""
+        ops = _ops()
+        outer = node.how == "left_outer"
+        rows_right = rt.num_rows
+        if outer and not rows_right:
+            rt = _null_row(rt)      # a null key matches nothing
+        lkeys = [lt[k] for k in node.left_keys]
+        rkeys = [rt[k] for k in node.right_keys]
+        from ..ops import join_pallas
+        choice = self._kernel_choice(
+            "hash_join",
+            join_pallas.make_signature(lkeys, rkeys, node.how, "eager"), m)
+        from ..ops.join_lookup import KERNEL_LABEL, lookup_counts
+        with span("ops.join", how=node.how, rows_left=lt.num_rows,
+                  rows_right=rows_right) as sp:
+            matched = unmatched = 0
+            with lookup_counts() as looked:
+                if outer:
+                    lm, rm, matched, unmatched = ops.left_join_counted(
+                        lkeys, rkeys)
+                elif node.how != "inner":
+                    keep = (ops.left_semi_join(lkeys, rkeys)
+                            if node.how == "left_semi"
+                            else ops.left_anti_join(lkeys, rkeys))
+                elif not choice.fallback:
+                    lm, rm = choice.fn(lkeys, rkeys)
+                else:
+                    lm, rm = ops.inner_join(lkeys, rkeys)
+            if looked:      # the small-side path answered (ops/join.py)
+                m.kernel = KERNEL_LABEL
+                m.lookup_compares = sum(a * b for a, b in looked)
+                # its wall is no timing of a registered hash_join kernel
+                m.__dict__.pop("_kernel_sig", None)
+            if node.how in PAIRING_JOINS:
+                if not outer:
+                    matched = lm.length
+                out = Table(
+                    list(ops.take_table(lt, lm.data,
+                                        _has_negative=False).columns) +
+                    list(ops.take_table(rt, rm.data,
+                                        _has_negative=outer).columns),
+                    names=list(lt.names) + list(rt.names))
+            else:
+                out = ops.take_table(lt, keep.data, _has_negative=False)
+                if node.how == "left_semi":
+                    matched = keep.length
+                else:
+                    unmatched = keep.length
+            m.unmatched_rows = unmatched if outer else 0
+            sp.set_metadata(matched=matched, unmatched=unmatched,
+                            kernel=_span_text(m.kernel))
+            if self.block_per_op:
+                with span("plan.wait", site="join"):
+                    jax.block_until_ready([c.data for c in out.columns])
+        return out
+
     def _exec_eager_node(self, node, childs: List[Table], inputs, schemas,
                          m: OperatorMetrics) -> Table:
         ops = _ops()
@@ -2141,37 +2250,7 @@ class PlanExecutor:
             (t,) = childs
             return self._project(t, node)
         if isinstance(node, HashJoin):
-            lt, rt = childs
-            lkeys = [lt[k] for k in node.left_keys]
-            rkeys = [rt[k] for k in node.right_keys]
-            from ..ops import join_pallas
-            choice = self._kernel_choice(
-                "hash_join",
-                join_pallas.make_signature(lkeys, rkeys, node.how, "eager"),
-                m)
-            from ..ops.join_lookup import KERNEL_LABEL, lookup_counts
-            with lookup_counts() as looked:
-                if node.how != "inner":
-                    keep = (ops.left_semi_join(lkeys, rkeys)
-                            if node.how == "left_semi"
-                            else ops.left_anti_join(lkeys, rkeys))
-                elif not choice.fallback:
-                    lm, rm = choice.fn(lkeys, rkeys)
-                else:
-                    lm, rm = ops.inner_join(lkeys, rkeys)
-            if looked:      # the small-side path answered (ops/join.py)
-                m.kernel = KERNEL_LABEL
-                m.lookup_compares = sum(a * b for a, b in looked)
-                # its wall is no timing of a registered hash_join kernel
-                m.__dict__.pop("_kernel_sig", None)
-            if node.how == "inner":
-                return Table(
-                    list(ops.take_table(lt, lm.data,
-                                        _has_negative=False).columns) +
-                    list(ops.take_table(rt, rm.data,
-                                        _has_negative=False).columns),
-                    names=list(lt.names) + list(rt.names))
-            return ops.take_table(lt, keep.data, _has_negative=False)
+            return self._eager_join(node, *childs, m)
         if isinstance(node, HashAggregate):
             (t,) = childs
             if not node.keys:
@@ -2286,7 +2365,7 @@ class PlanExecutor:
         max_rows = max((t.num_rows for t in inputs.values()), default=1)
         needs_row = needs_key = False
         for i, n in enumerate(plan.nodes):
-            if isinstance(n, HashJoin) and n.how == "inner":
+            if isinstance(n, HashJoin) and n.how in PAIRING_JOINS:
                 if n.row_cap is None:
                     needs_row = True
                 else:
@@ -2329,7 +2408,7 @@ class PlanExecutor:
         ceil: Dict[str, int] = {}
         shared_hi: Dict[str, Optional[int]] = {"row_cap": 0, "key_cap": 0}
         for i, n in enumerate(plan.nodes):
-            if isinstance(n, HashJoin) and n.how == "inner":
+            if isinstance(n, HashJoin) and n.how in PAIRING_JOINS:
                 which = "row_cap"
             elif isinstance(n, HashAggregate) and n.keys:
                 which = "key_cap"
@@ -2542,7 +2621,7 @@ class PlanExecutor:
                     kernel += ("/unique" if counts_np[_JOIN_UNIQUE - 2 * i][0]
                                else "/expand")
                 uses_cap = (isinstance(node, HashJoin)
-                            and node.how == "inner") \
+                            and node.how in PAIRING_JOINS) \
                     or (isinstance(node, HashAggregate) and node.keys)
                 # retries are plan-granular in this tier (one XLA program)
                 # and live on PlanResult.retries — copying them onto every
@@ -2553,6 +2632,9 @@ class PlanExecutor:
                     bytes_out=bytes_map.get(i, 0),
                     escalations=escal if uses_cap else 0,
                     kernel=kernel)
+                if isinstance(node, HashJoin) and node.how == "left_outer":
+                    metrics[node.label].unmatched_rows = int(
+                        counts_np[_JOIN_UNIQUE - 2 * i][1])
                 if isinstance(node, Scan) and node.source in scan_io:
                     io = scan_io[node.source]
                     mm = metrics[node.label]
@@ -2577,7 +2659,8 @@ class PlanExecutor:
                     res.expand_cap_slots += frames
             from ..ops.gather import live_slots
             for i, node in enumerate(plan.nodes):
-                if isinstance(node, HashJoin) and node.how == "inner":
+                if isinstance(node, HashJoin) \
+                        and node.how in PAIRING_JOINS:
                     cap = self._node_cap(final_caps, "row_cap", i)
                     res.gather_slots += live_slots(counts_np[i][1], cap)
                     res.cap_slots += cap
@@ -2645,6 +2728,11 @@ class PlanExecutor:
                     if rel.unique is not None:
                         counts[_JOIN_UNIQUE - 2 * i] = (
                             rel.unique.astype(jnp.int64), jnp.int64(0))
+                    elif rel.unmatched is not None:
+                        # an outer join always expands: flag 0, and its
+                        # null-extended rows beside it
+                        counts[_JOIN_UNIQUE - 2 * i] = (jnp.int64(0),
+                                                        rel.unmatched)
                     if rel.expanded is not None:
                         counts[_JOIN_EXPAND - 2 * i] = rel.expanded
                     bytes_map[i] = operand_nbytes(rel.table)
@@ -2705,16 +2793,24 @@ class PlanExecutor:
                               c.alive), None
         if isinstance(node, HashJoin):
             l, r = childs
+            if node.how == "left_outer" and not r.table.num_rows:
+                r = _CappedRel(_null_row(r.table), jnp.zeros((1,), bool))
             lkeys = [l.table[k] for k in node.left_keys]
             rkeys = [r.table[k] for k in node.right_keys]
             from ..ops import join_pallas
             choice = pick("hash_join",
                           join_pallas.make_signature(lkeys, rkeys, node.how,
                                                      "capped"))
-            if node.how == "inner":
+            if node.how in PAIRING_JOINS:
                 row_cap = self._node_cap(caps, "row_cap", idx)
-                unique = None
-                if not choice.fallback:
+                unique = rvalid = None
+                if node.how == "left_outer":
+                    lm, rm, rvalid, valid, ovf = ops.left_join_capped(
+                        lkeys, rkeys, row_cap=row_cap, lalive=l.alive,
+                        ralive=r.alive)
+                    # `lo - starts`, the match counts, `rorder`
+                    planes = 3
+                elif not choice.fallback:
                     lm, rm, valid, ovf = join_pallas.inner_join_capped_pallas(
                         lkeys, rkeys, row_cap=row_cap, lalive=l.alive,
                         ralive=r.alive)
@@ -2727,8 +2823,14 @@ class PlanExecutor:
                 # the live rows are a prefix of the capped frame: gather
                 # that prefix, whatever the cap (ops/gather.py:take_live)
                 live = jnp.sum(valid.astype(jnp.int32))
-                cols = ops.take_live(l.table.columns, lm, live) \
-                    + ops.take_live(r.table.columns, rm, live)
+                rcols = ops.take_live(r.table.columns, rm, live)
+                if rvalid is not None:
+                    # a left row without a match: its slot read right row 0
+                    # and is null in every column of the right side
+                    rcols = [c.with_validity(
+                        rvalid if c.validity is None
+                        else c.validity & rvalid) for c in rcols]
+                cols = ops.take_live(l.table.columns, lm, live) + rcols
                 from ..ops.join import expansion_slots
                 expanded = expansion_slots(lm, live, l.table.num_rows, planes,
                                            packed=unique is not None)
@@ -2737,7 +2839,10 @@ class PlanExecutor:
                                      for x in expanded)
                 t = Table(cols, names=list(l.table.names) +
                           list(r.table.names))
-                return _CappedRel(t, valid, unique, expanded), ovf
+                unmatched = None if rvalid is None else jnp.sum(
+                    valid & ~rvalid, dtype=jnp.int64)
+                return _CappedRel(t, valid, unique, expanded,
+                                  unmatched), ovf
             mask = ops.semi_join_mask(lkeys, rkeys, lalive=l.alive,
                                       ralive=r.alive)
             alive = (l.alive & mask if node.how == "left_semi"

@@ -52,6 +52,9 @@ class OperatorMetrics:
     # small rows x large rows an eager join compared on the small-side path
     # (ops/join.py; `kernel` then reads "<backend>:lookup"), else 0
     lookup_compares: int = 0
+    # left rows a `left_outer` join put out null-extended (no match, or a
+    # null key), else 0
+    unmatched_rows: int = 0
     # streaming-scan IO metrics (Scan nodes bound to a parquet source;
     # docs/io.md). Decode wall is host-side bitstream decode; overlap is
     # the time decode of chunk N+1 ran concurrently with executing chunk N

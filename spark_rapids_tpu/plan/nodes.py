@@ -21,7 +21,12 @@ from typing import Optional, Tuple
 
 from .expr import Expr
 
-JOIN_TYPES = ("inner", "left_semi", "left_anti")
+JOIN_TYPES = ("inner", "left_semi", "left_anti", "left_outer")
+# the join types whose output is left ++ right columns (and whose row count
+# a match count decides): everything that asks "does this join expand"
+# reads this, so that no walk takes an outer join for a filter of its left
+# side, nor for an inner join
+PAIRING_JOINS = ("inner", "left_outer")
 AGG_OPS = ("sum", "count", "min", "max", "mean", "size")   # ops.aggregate.AGG_OPS
 
 _ids = itertools.count()
@@ -246,10 +251,13 @@ class FusedSelect(PlanNode):
 
 @dataclasses.dataclass(frozen=True, eq=False)
 class HashJoin(PlanNode):
-    """Equi-join on key column lists. `inner` outputs left++right columns;
-    semi/anti output the left columns only (the right side is a filter).
-    `row_cap`, when set, overrides the executor's shared row cap for this
-    node in the capped tier."""
+    """Equi-join on key column lists. `inner` and `left_outer` output
+    left++right columns (`left_outer` keeps every left row: one without a
+    match, a null key included, comes out once with the right side's
+    columns null, so those columns are nullable after the join); semi/anti
+    output the left columns only (the right side is a filter). `row_cap`,
+    when set, overrides the executor's shared row cap for this node in the
+    capped tier."""
     left: PlanNode
     right: PlanNode
     left_keys: Tuple[str, ...]
@@ -279,7 +287,7 @@ class HashJoin(PlanNode):
         missing = set(self.right_keys) - set(rschema)
         _require(not missing, f"{self.label}: right key(s) {sorted(missing)} "
                               f"not in {list(rschema)}")
-        if self.how != "inner":
+        if self.how not in PAIRING_JOINS:
             return lschema
         dup = set(lschema) & set(rschema)
         _require(not dup,

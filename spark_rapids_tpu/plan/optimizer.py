@@ -65,7 +65,8 @@ from typing import Dict, List, Optional, Tuple
 from .builder import Plan, _toposort
 from .expr import (BinOp, ColumnRef, Expr, Literal, ScalarAgg, UnaryOp,
                    col, fold, has_scalar_agg, substitute)
-from .nodes import (Exchange, Filter, FusedSelect, HashAggregate, HashJoin,
+from .nodes import (PAIRING_JOINS, Exchange, Filter, FusedSelect,
+                    HashAggregate, HashJoin,
                     Limit, PlanNode, PlanValidationError, Project, Scan,
                     Sort, TopK, Union)
 
@@ -563,7 +564,8 @@ class _Estimator:
         if isinstance(node, Union):
             return sum(kids), src, runs
         if isinstance(node, HashJoin):
-            if node.how == "inner":
+            if node.how in PAIRING_JOINS:
+                # (an outer join holds its left rows at least: the same)
                 return max(kids), src, runs
             return 0.5 * kids[0], src, runs
         if isinstance(node, HashAggregate):
@@ -692,11 +694,17 @@ def _rule_predicate_pushdown(root, ctx):
             ls = ctx.schemas.of(child.left)
             rs = ctx.schemas.of(child.right)
             if child.how == "inner" and rs is not None and refs <= set(rs):
+                # NOT below a `left_outer` join: over its output the
+                # predicate drops the null-extended rows (or keeps them
+                # alone, `is_null`); below its null-supplying side it
+                # would turn the dropped matches INTO null-extended rows
                 hits[0] += 1
                 return dataclasses.replace(
                     child, right=Filter(child.right, p))
             if ls is not None and refs <= set(ls):
-                # inner: left-only columns; semi/anti: output IS the left
+                # inner, left_outer: left-only columns (an outer join
+                # keeps or drops a left row's outputs together); semi/
+                # anti: output IS the left
                 # schema, so a row filter always commutes to the left side
                 hits[0] += 1
                 return dataclasses.replace(child, left=Filter(child.left, p))
@@ -779,6 +787,8 @@ def _rule_build_side(root, ctx):
         kids = tuple(go(c) for c in n.children)
         node2 = (_with_children(n, kids)
                  if any(k is not c for k, c in zip(kids, n.children)) else n)
+        # inner alone: a `left_outer` join with its sides exchanged is a
+        # right outer join, another answer
         if (isinstance(n, HashJoin) and n.how == "inner"
                 and id(n) in safe):
             le = ctx.est.of(n.left)
@@ -881,7 +891,7 @@ def _rule_column_pruning(root, ctx):
             elif isinstance(n, HashJoin):
                 ls = schemas[id(n.left)]
                 rs = schemas[id(n.right)]
-                if n.how == "inner":
+                if n.how in PAIRING_JOINS:
                     push(n, 0, (req & set(ls)) | set(n.left_keys))
                     push(n, 1, (req & set(rs)) | set(n.right_keys))
                 else:
@@ -1019,6 +1029,21 @@ _RULES = (
 
 # ---- exchange planning (distributed tier, docs/distributed.md) --------------
 
+def mesh_local_reason(nodes) -> Optional[Tuple[str, str]]:
+    """(label, why) of the first node that keeps a WHOLE plan on one chip
+    although its executor has a mesh, or None. A `left_outer` join has a
+    shard-local kernel (parallel/relational.py) and no lowering in
+    plan/distributed.py's walk; a walk that met one half way would run it
+    through the one-chip fallback above a gather, so the plan is not put on
+    the mesh at all, and the optimize report says so under
+    `<label>/mesh`."""
+    for n in nodes:
+        if isinstance(n, HashJoin) and n.how == "left_outer":
+            return n.label, ("local (left_outer has no distributed "
+                             "lowering: the whole plan runs on one chip)")
+    return None
+
+
 def _statically_distributable(n: PlanNode, float_inputs: bool) -> bool:
     """Whether a node kind CAN run on the mesh — the static half of the
     gate (the executor re-checks runtime properties like column dtypes and
@@ -1027,6 +1052,8 @@ def _statically_distributable(n: PlanNode, float_inputs: bool) -> bool:
     accumulates partials in exact int64). A keyless aggregate reduces on
     the mesh (an all-reduce of per-shard partials)."""
     if isinstance(n, Limit):
+        return False
+    if mesh_local_reason((n,)) is not None:
         return False
     if isinstance(n, HashAggregate):
         if any(o == "mean" for _, o, _ in n.aggs):
@@ -1460,7 +1487,11 @@ def optimize(plan: Plan,
             report.passes = p + 1
             if not pass_hits:
                 break
-        if mesh_peers is not None and mesh_peers > 1:
+        meshed = mesh_peers is not None and mesh_peers > 1
+        stays = mesh_local_reason(_toposort(root)) if meshed else None
+        if stays is not None:
+            report.decision_sources[f"{stays[0]}/mesh"] = stays[1]
+        elif meshed:
             ctx = _Ctx(root, bound, bound_rows, report, float_inputs,
                        streaming, stats, backend, input_dtypes)
             new_root, n = _plan_exchanges(root, ctx, mesh_peers)

@@ -449,7 +449,10 @@ def test_eager_request_has_one_wait_under_every_operator(session):
     # the aggregate's kernel blocks inside its own span as well
     (g,) = spans.named("ops.groupby")
     assert [w["site"] for w in waits if inside(w, g)] == ["groupby"]
-    assert len(waits) == len(ops) + 1
+    # and so does the join, inside its
+    (j,) = spans.named("ops.join")
+    assert [w["site"] for w in waits if inside(w, j)] == ["join"]
+    assert len(waits) == len(ops) + 2
     # the epilogue and the stamps: the eager tier's two `plan.result`
     results = spans.named("plan.result")
     assert len(results) == 2 and inside(results[0], spans.one("plan.run"))
@@ -463,7 +466,8 @@ def test_cpu_tier_waits_and_result_take_the_same_names(session):
     spans = session(lambda: ex.execute(plan, inputs, tier="cpu"))
     ops = spans.named("plan.op")
     assert [w["site"] for w in spans.named("plan.wait")
-            if w["site"] != "groupby"] == ["degraded_op"] * len(ops)
+            if w["site"] not in ("groupby", "join")] \
+        == ["degraded_op"] * len(ops)
     assert len(spans.named("plan.result")) == 2
 
 
@@ -688,6 +692,130 @@ def test_groupby_span_and_the_requests_group_counters(session, tier):
         held = {o for o in owners.values() if o.endswith("/ops.groupby")}
         assert len(held) == 1 and held.pop().split("/")[0] \
             .endswith(".HashAggregate")
+
+
+def _outer_plan():
+    b = PlanBuilder()
+    return (b.scan("d", schema=["dk", "g"])
+            .join(b.scan("t", schema=["k", "v"]).filter(col("v") > 10),
+                  left_on="dk", right_on="k", how="left_outer")
+            .aggregate(["dk"], [("v", "count", "n")])
+            .sort(["dk"]).build())
+
+
+def _outer_inputs():
+    # keys 0..49 on the left, 0..29 on the right: twenty rows null-extended
+    # at least
+    fact = _fact()
+    return {"d": _dim(), "t": Table([_col(np.asarray(fact["k"].data) % 30),
+                                     fact["v"]], names=["k", "v"])}
+
+
+@pytest.mark.parametrize("tier", ["eager", "capped", "cpu"])
+def test_join_span_and_the_requests_outer_join_counters(session, tier):
+    """An eager join's maps, its output columns' gathers and the wait for
+    them run inside `ops.join` (how, rows_left, rows_right, matched,
+    unmatched, kernel), below the operator, whose `plan.op` says `how`
+    too; the request's `plan.execute` carries `outer_joins` and
+    `outer_unmatched_rows` in every tier; the capped tier's join is the
+    scope `<idx>.HashJoin` of its one program, as before."""
+    plan, inputs = _outer_plan(), _outer_inputs()
+    ex = PlanExecutor(mode="capped" if tier == "capped" else "eager",
+                      **({"caps": dict(row_cap=1024, key_cap=64)}
+                         if tier == "capped" else {}))
+    run = lambda: ex.execute(plan, inputs,
+                             tier="cpu" if tier == "cpu" else None)
+    run()                                                 # compile outside
+    done = []
+    spans = session(lambda: done.append(run()))
+    res, got = done[0], spans.one("plan.execute")
+    (join,) = [m for m in res.metrics.values() if m.kind == "HashJoin"]
+    assert res.outer_joins == 1 and res.outer_unmatched_rows >= 20
+    assert join.unmatched_rows == res.outer_unmatched_rows
+    assert (got["outer_joins"], got["outer_unmatched_rows"]) \
+        == (1, res.outer_unmatched_rows)
+    if tier == "capped":
+        assert not spans.named("ops.join")        # one program, run warm
+        owners = set(ex.device_op_owners(plan, inputs).values())
+        assert sum(o.endswith(".HashJoin") for o in owners) == 1
+        return
+    (op,) = [o for o in spans.named("plan.op")
+             if o["op"].endswith(".HashJoin")]
+    j = spans.one("ops.join")
+    assert inside(j, op) and j["request"] == op["request"]
+    assert op["how"] == "left_outer"
+    assert (j["how"], j["rows_left"], j["rows_right"]) \
+        == ("left_outer", 50, join.rows_in - 50)
+    assert (j["matched"], j["unmatched"]) \
+        == (join.rows_out - join.unmatched_rows, join.unmatched_rows)
+    assert j["kernel"] == join.kernel and "hash_join" in j["kernel"]
+    # the join's one read and its wait lie inside the span
+    assert [s["site"] for s in spans.named("ops.host_sync")
+            if inside(s, j)] == ["join.left"]
+    assert [w["site"] for w in spans.named("plan.wait")
+            if inside(w, j)] == ["join"]
+
+
+def test_join_span_of_an_inner_and_a_semi_join(session):
+    """Every eager join has the span; `how` tells them apart."""
+    inputs = {"t": _fact(), "d": _dim()}
+    b = PlanBuilder()
+    fact, dim = b.scan("t", schema=["k", "v"]), b.scan("d",
+                                                       schema=["dk", "g"])
+    plan = (fact.join(dim, left_on="k", right_on="dk")
+            .join(dim.filter(col("g") > 2).select(["dk"])
+                  .project({"sk": col("dk")}),
+                  left_on="k", right_on="sk", how="left_semi").build())
+    ex = PlanExecutor(mode="eager")
+    ex.execute(plan, inputs)
+    done = []
+    spans = session(lambda: done.append(ex.execute(plan, inputs)))
+    joins = spans.named("ops.join")
+    assert sorted(j["how"] for j in joins) == ["inner", "left_semi"]
+    by_how = {j["how"]: j for j in joins}
+    assert by_how["inner"]["matched"] == 400 \
+        and by_how["inner"]["unmatched"] == 0
+    assert by_how["left_semi"]["matched"] == done[0].table.num_rows
+    assert done[0].outer_joins == 0 and done[0].outer_unmatched_rows == 0
+    assert spans.one("plan.execute")["outer_joins"] == 0
+
+
+def test_a_request_with_an_outer_join_is_tiled_by_its_spans(session):
+    """The walk over a request: every span lies inside `plan.execute`,
+    every operator's children lie inside it, and what a `plan.op` leaves
+    uncovered (its own time: dispatch) is small beside the request."""
+    plan, inputs = _outer_plan(), _outer_inputs()
+    ex = PlanExecutor(mode="eager")
+    ex.execute(plan, inputs)
+    spans = session(lambda: ex.execute(plan, inputs))
+    root = spans.one("plan.execute")
+    mine = [s for s in spans if s.get("request") == root["request"]
+            and s is not root]
+    assert mine and all(inside(s, root) for s in mine)
+    ops = spans.named("plan.op")
+    leaves = [s for s in mine if s["name"] in (
+        "plan.wait", "ops.host_sync", "plan.bind", "plan.optimize",
+        "plan.verify", "plan.certify", "plan.result", "plan.stats")]
+    # every blocking read or wait of the walk lies under an operator, and
+    # the join's under its `ops.join`
+    run = spans.one("plan.run")
+    j = spans.one("ops.join")
+    for s in leaves:
+        if s["name"] in ("plan.wait", "ops.host_sync") and inside(s, run):
+            assert any(inside(s, o) for o in ops), s
+    (op,) = [o for o in ops if o["op"].endswith(".HashJoin")]
+    under = [s for s in mine if inside(s, op) and s is not op]
+    assert j in under and all(inside(s, j) or s["name"] == "plan.wait"
+                              for s in under if s is not j)
+    # siblings do not overlap: the spans tile, they do not pile up
+    same = sorted((s for s in mine if s["thread"] == root["thread"]),
+                  key=lambda s: (s["t0"], -s["t1"]))
+    open_ = [root]
+    for s in same:
+        while not inside(s, open_[-1]):
+            open_.pop()
+            assert open_, s
+        open_.append(s)
 
 
 def test_plan_execute_span_counts_the_slots_the_joins_gathered(session):
