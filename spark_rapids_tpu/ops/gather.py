@@ -16,7 +16,7 @@ chip is in PERF.md, PR 31).
 from __future__ import annotations
 
 import functools
-from typing import Union
+from typing import Tuple, Union
 
 import jax
 import jax.numpy as jnp
@@ -24,8 +24,12 @@ import jax.numpy as jnp
 from ..columnar import Column, Table
 from ..columnar.column import strings_from_padded
 from ..dtypes import Kind
-from ..utils.tracing import span
-from .scans import running
+from ..utils.tracing import Tally, span
+from .scans import live_positions
+
+
+# columns with no fixed-width plane a row: gathered whole by `take`
+_RAGGED = (Kind.STRING, Kind.STRUCT, Kind.LIST)
 
 
 def _any_negative(idx) -> bool:
@@ -173,8 +177,7 @@ def take_live(cols, idx: jnp.ndarray, live) -> list:
     come back zero and, in a nullable column, invalid. Strings, lists and
     structs have no plane to chunk and take the plain gather."""
     cols = list(cols)
-    chunked = [c.dtype.kind not in (Kind.STRING, Kind.STRUCT, Kind.LIST)
-               for c in cols]
+    chunked = [c.dtype.kind not in _RAGGED for c in cols]
     got = iter(gather_live(
         [p for c, ok in zip(cols, chunked) if ok
          for p in (c.data, c.validity) if p is not None], idx, live))
@@ -184,47 +187,212 @@ def take_live(cols, idx: jnp.ndarray, live) -> list:
             for c, ok in zip(cols, chunked)]
 
 
-@functools.partial(jax.jit, static_argnames=("total",))
-def _pack_rows(mask, total: int):
+# ---------------------------------------------------------------------------
+# Row compaction: the eager tier's filter (`apply_boolean_mask`) and the
+# semi / anti joins' row lists (`kept_rows`). The map of a compaction is
+# strictly increasing, so nothing has to be gathered through it over the
+# frame: the rows move by the count the caller has read (its one host sync).
+# ---------------------------------------------------------------------------
+
+# The constants below are set from a probe on the chip (one TPU v5 lite,
+# 15 M rows; `tools/probe_compaction.py`, PERF.md section 6, PR 42):
+#
+#   the sort, a row:        1.34 ns with the key alone, 2.16 / 3.16 / 4.88 /
+#                           8.73 / 12.37 ns carrying 1 / 2 / 4 / 8 / 12 32-bit
+#                           words (0.93 ns a word), whatever share is kept;
+#                           two int64 columns as they are 5.02, an int64 and
+#                           a validity mask 3.64; a STABLE sort on the dropped
+#                           flag with the same two columns 5.62 to 5.99
+#   positions, a kept row:  153 to 176 ns (58,344 to 1,876,242 kept), 182 to
+#                           219 with two int64 columns gathered; 1.7 ms for
+#                           1,475 rows (the pass over the mask's words)
+#   what ran before, a row: the rank scan and scatter 6.0 to 8.1 ns, then a
+#                           gathered slot of an int64 column 15 ns
+#
+# "Few rows kept": at most one row in FEW_KEPT goes by positions. With four
+# words riding the two ways cost the same at one row in 36 (with none at one
+# in 114, with twelve at one in 19). The eager joins' small-side path
+# (ops/join.py) stops at the same share.
+FEW_KEPT = 32
+
+# Fewer kept rows than this go by their positions whatever the frame holds:
+# at most 2.9 ms, against a sort program's fixed costs, which the chip's
+# compiler sets by the operands and not by the rows: 9.7 s of compile and
+# 1.5 MB of code in HBM for the key alone, 8 s and 0.7 MB more a riding
+# word (code sizes from compiles for a described v5e: 4.4 MB for two int64
+# columns at 73,049 rows, where `q3.share`'s peak memory rose by 3.6 MB,
+# 2.1%, until its date filter's 6,000 rows went by positions).
+KEPT_FLOOR = 1 << 14
+
+# 32-bit words of payload one sort carries: the widest the probe ran (one
+# sort of 12 words 185.5 ms, sorts of 8 and 4 words 204 ms). A wider table
+# goes in groups of columns, each sort carrying the key again.
+RIDE_WORDS = 12
+
+
+def few_kept(kept: int, n: int) -> bool:
+    """Whether `kept` of `n` rows are few enough to go by their positions."""
+    return kept * FEW_KEPT <= n
+
+
+def compaction_path(n: int, kept: int, ragged: bool = False) -> str:
+    """How a compaction of `n` rows to `kept` moves them: `none` (every row
+    stays: the table as it is), `positions` (few kept, of the frame or in
+    all), `sort` (the columns' planes ride one sort), `sort+gather` (a
+    string, list or struct column has no plane to ride: it is gathered by
+    the positions the same sort gives). Arithmetic over counts the caller
+    holds; nothing else chooses."""
+    if kept == n:
+        return "none"
+    if few_kept(kept, n) or kept <= KEPT_FLOOR:
+        return "positions"
+    return "sort+gather" if ragged else "sort"
+
+
+def plane_words(arrays) -> Tuple[int, ...]:
+    """32-bit words a row of each 1-D plane of `arrays`, in the order
+    `_planes` gives them (an (n, k) array is k planes)."""
+    return tuple(max(a.dtype.itemsize // 4, 1) for a in arrays
+                 for _ in range(1 if a.ndim == 1 else a.shape[1]))
+
+
+def ride_groups(words, limit: int = RIDE_WORDS) -> Tuple[Tuple[int, ...], ...]:
+    """The planes (by index) each sort carries: in order, a group closed
+    before it would pass `limit` words (a plane wider than the limit rides
+    alone)."""
+    groups, group, load = [], [], 0
+    for i, w in enumerate(words):
+        if group and load + w > limit:
+            groups.append(tuple(group))
+            group, load = [], 0
+        group.append(i)
+        load += w
+    if group:
+        groups.append(tuple(group))
+    return tuple(groups)
+
+
+def _planes(arrays):
+    """The arrays as 1-D planes: an (n, k) array (DECIMAL128's limbs) as
+    its k columns."""
+    return [p for a in arrays
+            for p in ([a] if a.ndim == 1
+                      else [a[:, j] for j in range(a.shape[1])])]
+
+
+def _unplane(planes, arrays):
+    """`_planes` undone: `planes` back in the shapes of `arrays`."""
+    got, out = iter(planes), []
+    for a in arrays:
+        out.append(next(got) if a.ndim == 1 else
+                   jnp.stack([next(got) for _ in range(a.shape[1])], axis=1))
+    return out
+
+
+@functools.partial(jax.jit, static_argnames=("kept",))
+def rows_by_position(mask, arrays, *, kept: int):
+    """-> (the positions of the `kept` rows of `mask`, ascending, int32;
+    each of `arrays` at those rows). One program: the positions by
+    `live_positions`, the gathers over `kept` slots."""
+    rows = live_positions(mask, kept)[0]
+    return rows, [jnp.take(a, rows, axis=0) for a in arrays]
+
+
+@functools.partial(jax.jit, static_argnames=("kept", "groups"))
+def rows_by_sort(mask, arrays, *, kept: int, groups=()):
+    """-> (the positions of the `kept` rows of `mask`, ascending, int32;
+    each of `arrays` at those rows). ONE sort of the frame whose key is the
+    row's rank in the result: a kept row's own number, a dropped row's
+    number past the frame. Kept rows come first and in their order, as a
+    stable sort on the dropped flag leaves them, and the keys are unique,
+    so the sort need not be stable (for a stable one the chip's compiler
+    adds the row numbers as one more operand and compares two keys); the
+    key's own prefix is the kept rows' positions. The arrays' planes ride
+    as payload operands, `groups` (from `ride_groups`) saying which planes
+    share a sort; the result is cut to `kept` here, inside the program, so
+    no second copy of the frame leaves it."""
     n = mask.shape[0]
-    at = running(mask.astype(jnp.int32)) - 1
-    # a row that is kept writes its index at its rank; the others write
-    # nowhere
-    return jnp.zeros((total,), jnp.int32).at[
-        jnp.where(mask, at, total)].set(jnp.arange(n, dtype=jnp.int32),
-                                        mode="drop")
+    row = jnp.arange(n, dtype=jnp.uint32)
+    rank = jnp.where(mask, row, row + jnp.uint32(n))
+    planes = _planes(arrays)
+    out = [None] * len(planes)
+    for group in groups or ((),):
+        got = jax.lax.sort([rank] + [planes[i] for i in group],
+                           num_keys=1, is_stable=False)
+        for i, p in zip(group, got[1:]):
+            out[i] = p[:kept]
+    return got[0][:kept].astype(jnp.int32), _unplane(out, arrays)
 
 
-def kept_rows(mask) -> jnp.ndarray:
+def _count_kept(mask) -> int:
+    with span("ops.host_sync", site="gather.kept_rows"):
+        return int(jnp.sum(mask))
+
+
+def kept_rows(mask, kept: int = None) -> jnp.ndarray:
     """The rows where the (n,) bool `mask` holds, ascending, as int32:
     `jnp.nonzero(mask)[0]`, whose own lowering adds a one for EVERY row
     into the bin of its rank (a scatter-add of n colliding updates: 87 ns
     a row where 677 of 15 M rows are kept, PERF.md, PR 34). One host sync
-    for the result's size, as there."""
+    for the result's size, unless the caller has read it (`kept`)."""
     mask = jnp.asarray(mask).astype(bool)
-    with span("ops.host_sync", site="gather.kept_rows"):
-        total = int(jnp.sum(mask))
-    return _pack_rows(mask, total)
+    n = int(mask.shape[0])
+    if kept is None:
+        kept = _count_kept(mask)
+    path = compaction_path(n, kept)
+    if path == "none":
+        return jnp.arange(n, dtype=jnp.int32)
+    by = rows_by_position if path == "positions" else rows_by_sort
+    return by(mask, [], kept=kept)[0]
+
+
+# what the compactions under a `with compactions.collect()` did:
+# (path, rows in, rows kept) each (the executor's `compact=` and counters)
+compactions = Tally()
+
+
+def compact_columns(cols, mask, kept: int) -> list:
+    """`cols` (all of `mask`'s length) at the `kept` rows where the bool
+    `mask` holds, moved the way `compaction_path` says."""
+    n = int(mask.shape[0])
+    ragged = [c.dtype.kind in _RAGGED for c in cols]
+    path = compaction_path(n, kept, any(ragged))
+    compactions.note((path, n, kept))
+    if path == "none":
+        return list(cols)
+    arrays = [p for c, r in zip(cols, ragged) if not r
+              for p in (c.data, c.validity) if p is not None]
+    if path == "positions":
+        rows, got = rows_by_position(mask, arrays, kept=kept)
+    else:
+        rows, got = rows_by_sort(mask, arrays, kept=kept,
+                                 groups=ride_groups(plane_words(arrays)))
+    got = iter(got)
+    return [take(c, rows, _has_negative=False) if r else
+            Column(dtype=c.dtype, length=kept, data=next(got),
+                   validity=None if c.validity is None else next(got))
+            for c, r in zip(cols, ragged)]
 
 
 def apply_boolean_mask(table_or_col, mask) -> Union[Table, Column]:
     """Keep rows where mask is True (cudf::apply_boolean_mask — the filter
     half of read → filter → project). Null mask entries drop the row, like
-    Spark's WHERE over a nullable predicate."""
+    Spark's WHERE over a nullable predicate. One host sync (the count of
+    kept rows), then one program that moves every fixed-width column."""
     if isinstance(mask, Column):
         m = mask.data
         if mask.validity is not None:
             m = m & mask.validity
     else:
         m = jnp.asarray(mask)
-    n = (table_or_col.num_rows if isinstance(table_or_col, Table)
-         else table_or_col.length)
+    is_table = isinstance(table_or_col, Table)
+    n = table_or_col.num_rows if is_table else table_or_col.length
     if m.shape != (n,):
         raise ValueError(f"mask length {m.shape} does not match {n} rows")
-    keep = kept_rows(m)
-    if isinstance(table_or_col, Table):
-        return take_table(table_or_col, keep, _has_negative=False)
-    return take(table_or_col, keep, _has_negative=False)
+    m = m.astype(bool)
+    cols = table_or_col.columns if is_table else [table_or_col]
+    out = compact_columns(cols, m, _count_kept(m))
+    return Table(out, names=table_or_col.names) if is_table else out[0]
 
 
 def take_table(table: Table, idx: jnp.ndarray,

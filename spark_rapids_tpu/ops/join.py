@@ -62,7 +62,8 @@ line), and nowhere else.
    what is kept, and the sort join above runs over the small side and
    those survivors. Survivors keep their row order, so the maps come out
    pair for pair in the order the sort join of the whole sides gives.
-   Where more than a row in `_LOOKUP_KEEP` survives, the sort join runs.
+   Where more than a row in `ops/gather.py:FEW_KEPT` survives, the sort
+   join runs.
 
 Null keys never match (Spark equi-join); null-safe equality (<=>) is the
 `null_equal` flag, like cudf's null_equality::EQUAL — null rows get their
@@ -80,9 +81,9 @@ import jax.numpy as jnp
 from .. import dtypes
 from ..columnar import Column, Table
 from ..utils.tracing import span
-from .gather import gather_live, kept_rows, live_chunk, loop_zeros
-from .join_lookup import (lookup_side, member_mask, note_lookup,
-                          survivor_rows)
+from .gather import (few_kept, gather_live, kept_rows, live_chunk,
+                     loop_zeros, rows_by_position)
+from .join_lookup import lookup_side, member_mask, note_lookup
 from .sort import _key_operands
 
 __all__ = ["inner_join", "left_join", "left_join_counted", "full_join",
@@ -91,16 +92,6 @@ __all__ = ["inner_join", "left_join", "left_join_counted", "full_join",
            "inner_join_capped", "inner_join_capped_tail", "left_join_capped",
            "semi_join_mask",
            "join_spans", "expand_spans"]
-
-
-# The small-side path stops where more than one large-side row in
-# `_LOOKUP_KEEP` carries a key of the small side: the join of the survivors
-# would be the sort join over again, and their positions cost what a pack
-# of the frame costs (PERF.md section 6, PR 37, my chip runs:
-# `live_positions` against `_pack_rows` over 60 M rows, 5.4 against 359.7
-# ms at 4,739 rows kept, 46.9 against 359.8 at one row in 256, 320.3
-# against 359.8 at one in 32; over 15 M rows 73.0 against 90.2 at one in 32).
-_LOOKUP_KEEP = 32
 
 
 def _concat_columns(a: Column, b: Column) -> Column:
@@ -393,10 +384,10 @@ def _survivors(small, large):
     columns (no row of them is null or dead). None where so many rows pass
     that the join of the survivors would be the sort join over again."""
     mask, count = member_mask(small, large)
-    if count * _LOOKUP_KEEP > large[0].length:
+    if not few_kept(count, large[0].length):
         return None
     note_lookup(small[0].length, large[0].length)
-    rows, keys = survivor_rows(mask, [c.data for c in large], count=count)
+    rows, keys = rows_by_position(mask, [c.data for c in large], kept=count)
     return rows, [Column(dtype=c.dtype, length=count, data=d)
                   for c, d in zip(large, keys)]
 
@@ -683,11 +674,8 @@ def _lookup_semi_anti(lcols, rcols, null_equal: bool, semi: bool):
     # the membership mask IS the semi join; the anti join keeps the rest
     mask, count = member_mask(rcols, lcols)
     note_lookup(rcols[0].length, lcols[0].length)
-    if not semi:
-        return kept_rows(~mask)
-    if count * _LOOKUP_KEEP > lcols[0].length:
-        return kept_rows(mask)
-    return survivor_rows(mask, [], count=count)[0]
+    return kept_rows(mask, count) if semi \
+        else kept_rows(~mask, lcols[0].length - count)
 
 
 def _semi_anti(left_keys, right_keys, null_equal: bool, semi: bool) -> Column:

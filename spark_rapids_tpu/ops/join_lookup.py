@@ -11,17 +11,15 @@ the large side. Steps 1 and 2 of the eager joins' small-side path
    are packed past the loop's bound, so no value has to "match nothing".
    (A Pallas kernel with the keys in SMEM was 1.6 times slower on the
    chip: PERF.md section 6, PR 37.)
-2. `survivor_rows`: the positions of the rows that passed, by
-   `ops/scans.py:live_positions` (a price that follows what is kept).
+2. `ops/gather.py:rows_by_position`: the positions of the rows that
+   passed, by `ops/scans.py:live_positions` (a price that follows what is
+   kept), and their keys: the program every compaction of few rows runs.
 
 Exact: integer words compared bit for bit. A null key, on either side,
 matches nothing.
 """
 from __future__ import annotations
 
-import contextlib
-import threading
-from functools import partial
 from typing import Optional, Sequence
 
 import jax
@@ -29,8 +27,7 @@ import jax.numpy as jnp
 
 from ..columnar import Column
 from ..dtypes import Kind
-from ..utils.tracing import span
-from .scans import live_positions
+from ..utils.tracing import Tally, span
 
 # The largest small side, in rows, and the smallest large side the path
 # takes, each with the reading it was set from (PERF.md section 6, PR 37:
@@ -161,26 +158,15 @@ def _validity(cols):
     return [c.validity for c in cols if c.validity is not None]
 
 
-_collector = threading.local()
-
-
-@contextlib.contextmanager
-def lookup_counts():
-    """Collect (small rows, large rows) of every join under this context
-    that took the path (the `lookup_joins` / `lookup_compares` of
-    `plan.execute`). -> the list they land in."""
-    prev = getattr(_collector, "joins", None)
-    _collector.joins = joins = []
-    try:
-        yield joins
-    finally:
-        _collector.joins = prev
+# (small rows, large rows) of every join under a `with lookup_counts()`
+# that took the path: the `lookup_joins` / `lookup_compares` of
+# `plan.execute`
+_lookups = Tally()
+lookup_counts = _lookups.collect
 
 
 def note_lookup(small: int, large: int) -> None:
-    joins = getattr(_collector, "joins", None)
-    if joins is not None:
-        joins.append((small, large))
+    _lookups.note((small, large))
 
 
 # what `OperatorMetrics.kernel` reads for a join that took the path
@@ -196,11 +182,3 @@ def member_mask(small_cols: Sequence[Column], large_cols: Sequence[Column]):
                           [c.data for c in large_cols], _validity(large_cols))
     with span("ops.host_sync", site="join.lookup"):
         return mask, int(count)
-
-
-@partial(jax.jit, static_argnames=("count",))
-def survivor_rows(mask, data, *, count: int):
-    """-> (the positions of the `count` rows of `mask`, ascending, as
-    int32; each array of `data` at those rows)."""
-    rows = live_positions(mask, count)[0]
-    return rows, [jnp.take(d, rows, axis=0) for d in data]

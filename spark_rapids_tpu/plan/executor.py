@@ -131,6 +131,14 @@ def _op_span(node: PlanNode, idx: int, tier: str = "device"):
                    label=_span_text(node.label), tier=tier, **how)
 
 
+def _say_compaction(sp, m: OperatorMetrics, childs, out) -> None:
+    """On the `plan.op` span of an eager `Filter` / `FusedSelect`: how the
+    rows of its input moved into `out` (`m.compact`)."""
+    if m.compact:
+        sp.set_metadata(compact=m.compact, rows_in=childs[0].num_rows,
+                        rows_out=out.num_rows)
+
+
 _DECIMAL_OVERFLOW = -1      # key of `_run_capped`'s counts, see there
 _JOIN_UNIQUE = -2           # minus twice the join's index: key of the flag
 #                             that says which tail its sort join took (an
@@ -633,6 +641,10 @@ class PlanResult:
         self.lookup_joins = 0         # eager tier: joins that took the
         self.lookup_compares = 0      # small-side path, and small rows x
         #                               large rows over them (ops/join.py)
+        self.compactions = 0          # eager tier: filters that moved rows,
+        self.compact_sorted_rows = 0  # and the frame rows that went through
+        self.compact_position_rows = 0  # a sort / by the kept rows'
+        #                               positions (ops/gather.py)
         self.group_rows = 0           # over the request's keyed aggregates:
         self.groups = 0               # rows in, groups out, and the slots
         self.group_slots = 0          # their finish ran over (the groups in
@@ -857,6 +869,9 @@ class PlanExecutor:
                             expand_joins=res.expand_joins,
                             lookup_joins=res.lookup_joins,
                             lookup_compares=res.lookup_compares,
+                            compactions=res.compactions,
+                            compact_sorted_rows=res.compact_sorted_rows,
+                            compact_position_rows=res.compact_position_rows,
                             outer_joins=res.outer_joins,
                             outer_unmatched_rows=res.outer_unmatched_rows,
                             gather_slots=res.gather_slots,
@@ -898,6 +913,21 @@ class PlanExecutor:
                 if m.kernel == KERNEL_LABEL]
         res.lookup_joins = len(took)
         res.lookup_compares = sum(m.lookup_compares for m in took)
+
+    @staticmethod
+    def _count_compactions(res: PlanResult) -> None:
+        """`compactions` and the frame rows that went each way, from the
+        operators' metrics (a cached result keeps its own)."""
+        if res.cached or res.compactions:
+            return
+        for m in res.metrics.values():
+            if m.compact in ("", "none"):
+                continue
+            res.compactions += 1
+            if m.compact == "positions":
+                res.compact_position_rows += int(m.rows_in)
+            else:
+                res.compact_sorted_rows += int(m.rows_in)
 
     @staticmethod
     def _count_outer(res: PlanResult) -> None:
@@ -1006,6 +1036,7 @@ class PlanExecutor:
                     res.decimal_overflow_rows += int(sum(nulled))
             self._count_groups(res)
             self._count_lookups(res)
+            self._count_compactions(res)
             self._count_outer(res)
             # serving-session stamp (runtime/sessionctx.py,
             # docs/serving.md): results and per-op metrics carry the tenant
@@ -1520,7 +1551,7 @@ class PlanExecutor:
                 out = None
                 while True:
                     try:
-                        with _op_span(node, i):
+                        with _op_span(node, i) as osp:
                             self._faultinj_point(node)
                             if dist is not None:
                                 out = dist.exec_node(node, child_tables,
@@ -1533,6 +1564,7 @@ class PlanExecutor:
                                         metrics)
                                 out = self._exec_eager_node(
                                     node, child_tables, inputs, schemas, m)
+                                _say_compaction(osp, m, child_tables, out)
                             # blocked inside the span, so that the span
                             # holds the operator's device work (an async
                             # exchange in flight stays unblocked: that
@@ -1706,10 +1738,11 @@ class PlanExecutor:
                 m.placement = "host"  # set BEFORE dispatch: pins the
                 #                       registry to cpu kernels
                 t0 = time.perf_counter()
-                with _op_span(n, node_index[id(n)], "host"):
+                with _op_span(n, node_index[id(n)], "host") as osp:
                     self._faultinj_point(n)
                     out = self._exec_eager_node(n, childs, host_inputs,
                                                 schemas, m)
+                    _say_compaction(osp, m, childs, out)
                     with span("plan.wait", site="host_op"):
                         jax.block_until_ready(
                             [c.data for c in out.columns])
@@ -1817,9 +1850,10 @@ class PlanExecutor:
                                             describe=node.describe())
                     m.degraded = True
                     t0 = time.perf_counter()
-                    with _op_span(node, i, "degraded"):
+                    with _op_span(node, i, "degraded") as osp:
                         out = self._exec_eager_node(node, childs, cpu_inputs,
                                                     schemas, m)
+                        _say_compaction(osp, m, childs, out)
                         if self.block_per_op:
                             with span("plan.wait", site="degraded_op"):
                                 jax.block_until_ready(
@@ -1929,11 +1963,12 @@ class PlanExecutor:
         while True:
             t0 = time.perf_counter()
             try:
-                with _op_span(node, idx):
+                with _op_span(node, idx) as osp:
                     self._faultinj_point(node)
                     out = (fn(t) if fn is not None else
                            self._exec_eager_node(node, [t], inputs,
                                                  schemas, m))
+                    _say_compaction(osp, m, [t], out)
                 break
             except _fault_surface() as err:
                 if self._handle_fault(err, node.label, attempt, m):
@@ -2207,6 +2242,16 @@ class PlanExecutor:
                     jax.block_until_ready([c.data for c in out.columns])
         return out
 
+    @staticmethod
+    def _compact(t: Table, mask, m: OperatorMetrics) -> Table:
+        """The rows of `t` that pass `mask`; `m.compact` says how they
+        moved (ops/gather.py)."""
+        from ..ops.gather import compactions
+        with compactions.collect() as moved:
+            out = _ops().apply_boolean_mask(t, mask)
+        m.compact = moved[-1][0]
+        return out
+
     def _exec_eager_node(self, node, childs: List[Table], inputs, schemas,
                          m: OperatorMetrics) -> Table:
         ops = _ops()
@@ -2222,8 +2267,7 @@ class PlanExecutor:
             return node.typed(t)
         if isinstance(node, Filter):
             (t,) = childs
-            mask = node.predicate.evaluate(t)
-            return ops.apply_boolean_mask(t, mask)
+            return self._compact(t, node.predicate.evaluate(t), m)
         if isinstance(node, FusedSelect):
             # fused Filter+Project: gather ONLY the projection-referenced
             # columns through the mask, then project — one pass, instead of
@@ -2243,8 +2287,8 @@ class PlanExecutor:
             if not choice.fallback:
                 ft = choice.fn(t, node.predicate, needed)
             else:
-                mask = node.predicate.evaluate(t)
-                ft = ops.apply_boolean_mask(t.select(needed), mask)
+                ft = self._compact(t.select(needed),
+                                   node.predicate.evaluate(t), m)
             return self._project(ft, node)
         if isinstance(node, Project):
             (t,) = childs

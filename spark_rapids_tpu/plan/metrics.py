@@ -52,6 +52,11 @@ class OperatorMetrics:
     # small rows x large rows an eager join compared on the small-side path
     # (ops/join.py; `kernel` then reads "<backend>:lookup"), else 0
     lookup_compares: int = 0
+    # how an eager `Filter` / `FusedSelect` moved its rows
+    # (ops/gather.py:compaction_path): "positions", "sort", "sort+gather",
+    # "none" (every row stayed); "" where nothing is compacted (another
+    # operator, the capped tier's mask)
+    compact: str = ""
     # left rows a `left_outer` join put out null-extended (no match, or a
     # null key), else 0
     unmatched_rows: int = 0

@@ -45,6 +45,7 @@ when something is lowered, and a bracket costs two thread-local reads.
 from __future__ import annotations
 
 import collections
+import contextlib
 import threading
 
 import jax
@@ -119,6 +120,30 @@ class bracket:
                 .replace("=", ":")
         self._span.set_metadata(**attrs)
         return self._span.__exit__(*exc)
+
+
+class Tally:
+    """What the kernels under an operator say they did, for the operator's
+    span and metrics: a kernel `note`s an item, and whoever opened
+    `collect()` on this thread reads the list (nobody: the note is
+    dropped). Per thread, so concurrent requests keep apart."""
+
+    def __init__(self):
+        self._local = threading.local()
+
+    @contextlib.contextmanager
+    def collect(self):
+        prev = getattr(self._local, "items", None)
+        self._local.items = items = []
+        try:
+            yield items
+        finally:
+            self._local.items = prev
+
+    def note(self, item) -> None:
+        items = getattr(self._local, "items", None)
+        if items is not None:
+            items.append(item)
 
 
 def text(label: str) -> str:

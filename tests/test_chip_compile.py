@@ -495,3 +495,35 @@ def test_lookup_membership_compiles_for_v5e(one_chip, no_persistent_cache,
     assert sorted_rows == [small]
     temp = compiled.memory_analysis().temp_size_in_bytes
     assert temp < (4 * words + 2) * (Q18_LINES + (1 << 20)), temp
+
+
+Q13_ORDERS = 15_000_000     # `q13.batch`: orders' rows at SF10,
+Q13_KEPT = 14_834_663       # and those its filter keeps
+
+
+def test_compaction_sort_compiles_for_v5e(one_chip, no_persistent_cache):
+    """The eager filter's sort path (ops/gather.py:rows_by_sort) at
+    `q13.batch`'s own size, 15,000,000 rows with two int64 columns riding:
+    five 32-bit words a row with the key. ONE sort of the frame, not a
+    stable one (for which the compiler adds the row numbers as a sixth
+    operand), five operands after the 64-bit split, and beside the
+    arguments and the result (cut to the kept rows inside the program) the
+    sort's own buffers: no second copy of the frame. Compiles in about
+    100 s here, whatever the row count (60 s at 65,536 rows)."""
+    from spark_rapids_tpu.ops import gather
+
+    def shape(dtype):
+        return jax.ShapeDtypeStruct((Q13_ORDERS,), dtype, sharding=one_chip)
+    arrays = [shape(jnp.int64), shape(jnp.int64)]
+    words = gather.plane_words(arrays)
+    assert words == (2, 2)
+    compiled = gather.rows_by_sort.lower(
+        shape(jnp.bool_), arrays, kept=Q13_KEPT,
+        groups=gather.ride_groups(words)).compile()
+    sorts = [line for line in compiled.as_text().splitlines()
+             if " sort(" in line]
+    assert len(sorts) == 1 and "is_stable=true" not in sorts[0]
+    assert sorts[0].split(" sort(")[0].count(f"[{Q13_ORDERS}]") == 5
+    mem = compiled.memory_analysis()
+    assert mem.output_size_in_bytes < 20 * Q13_KEPT + (1 << 20)
+    assert mem.temp_size_in_bytes < 21 * Q13_ORDERS, mem.temp_size_in_bytes

@@ -254,9 +254,9 @@ def test_semi_and_anti_join():
                                       (70_000, 1.0)])
 def test_kept_rows_is_nonzero(n, share):
     """The filter's and the semi / anti joins' row ids: `jnp.nonzero` in
-    int32, ascending, by a rank scan and one scatter in which only kept
-    rows write (past 16 blocks of 4,096 the scan runs in two levels); an
-    integer mask reads as its truth."""
+    int32, ascending, few rows kept (their positions) and many (a sort);
+    an integer mask reads as its truth. tests/test_compaction.py holds
+    every path at every share."""
     import jax.numpy as jnp
     from spark_rapids_tpu.ops import apply_boolean_mask
     from spark_rapids_tpu.ops.gather import kept_rows

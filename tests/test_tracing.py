@@ -656,6 +656,55 @@ def test_plan_execute_span_counts_the_small_side_joins(session, plan_is):
             s.get("site") == "join.lookup" for s in spans.named("ops.host_sync"))
 
 
+@pytest.mark.parametrize("tier, keeps", [("eager", "most"), ("eager", "few"),
+                                         ("eager", "all"), ("capped", "most")])
+def test_filter_span_says_how_its_rows_moved(session, monkeypatch, tier,
+                                             keeps):
+    """An eager `Filter` / `FusedSelect` compacts (ops/gather.py): its
+    `plan.op` span and its metrics say which way the rows went
+    (`compact`, with `rows_in` / `rows_out`), and `plan.execute` and the
+    result count the compactions and the frame rows that went through a
+    sort or by the kept rows' positions. A filter that keeps every row
+    moves none and is not counted; in the capped tier a filter is a mask:
+    no attribute, every counter 0. (The floor under which every frame goes
+    by positions is lowered: 400 rows lie under it.)"""
+    from spark_rapids_tpu.ops import gather
+    monkeypatch.setattr(gather, "KEPT_FLOOR", 0)
+    n = 400
+    v = {"most": np.arange(n) % 100 + 1, "all": np.full(n, 50),
+         "few": np.where(np.arange(n) % 100 == 0, 50, 0)}[keeps]
+    inputs = {"t": Table([_col(np.arange(n) % 50), _col(v)],
+                         names=["k", "v"]), "d": _dim()}
+    kept = int((v > 10).sum())
+    path = {"most": "sort", "few": "positions", "all": "none"}[keeps]
+    plan = _join_plan()
+    ex = PlanExecutor(mode=tier, **({"caps": dict(row_cap=512, key_cap=16)}
+                                    if tier == "capped" else {}))
+    ex.execute(plan, inputs)                              # compile outside
+    done = []
+    spans = session(lambda: done.append(ex.execute(plan, inputs)))
+    res, got = done[0], spans.one("plan.execute")
+    want = {"eager": {"sort": (1, n, 0), "positions": (1, 0, n),
+                      "none": (0, 0, 0)}[path], "capped": (0, 0, 0)}[tier]
+    assert (got["compactions"], got["compact_sorted_rows"],
+            got["compact_position_rows"]) == want
+    assert (res.compactions, res.compact_sorted_rows,
+            res.compact_position_rows) == want
+    filters = [m for m in res.metrics.values()
+               if m.kind in ("Filter", "FusedSelect")]
+    assert len(filters) == 1
+    ops_said = [o for o in spans.named("plan.op") if "compact" in o]
+    if tier == "capped":
+        assert not ops_said and filters[0].compact == ""
+        return
+    (op,) = ops_said
+    assert op["op"].split(".")[1] == filters[0].kind
+    assert (op["compact"], op["rows_in"], op["rows_out"]) == (path, n, kept)
+    assert filters[0].compact == path
+    assert [m.compact for m in res.metrics.values()
+            if m is not filters[0]] == [""] * (len(res.metrics) - 1)
+
+
 @pytest.mark.parametrize("tier", ["eager", "capped"])
 def test_groupby_span_and_the_requests_group_counters(session, tier):
     """A keyed aggregate's kernel and finish run inside `ops.groupby`
@@ -1227,7 +1276,7 @@ def test_the_walk_finds_what_the_request_path_is_known_to_read():
                   ("parallel/autoretry.py", "auto_retry_overflow", "bool"),
                   ("ops/join.py", "_sort_inner_join", "int"),
                   ("ops/join_lookup.py", "member_mask", "int"),
-                  ("ops/gather.py", "kept_rows", "int"),
+                  ("ops/gather.py", "_count_kept", "int"),
                   ("ops/gather.py", "take", "device_get"),
                   ("ops/aggregate.py", "_groupby", "int"),
                   ("serving/cache.py", "_table_digest", "device_get")]:
