@@ -72,9 +72,9 @@ import dataclasses
 from typing import Dict, List, Optional, Tuple
 
 from .. import dtypes
-from ..plan.nodes import (Exchange, Filter, FusedSelect, HashAggregate,
-                          HashJoin, Limit, PlanNode, Project, Scan, Sort,
-                          TopK, Union)
+from ..plan.nodes import (PAIRING_JOINS, Exchange, Filter, FusedSelect,
+                          HashAggregate, HashJoin, Limit, PlanNode, Project,
+                          Scan, Sort, TopK, Union, nullable_sides)
 from .verifier import (PlanVerificationError, Violation, _propagate_schemas,
                        column_types)
 
@@ -269,7 +269,8 @@ def _rows_interval(node: PlanNode, kids: List[Tuple[int, Optional[int]]],
     """The transfer function: [lo, hi] of this operator's output rows from
     its children's intervals. Sound for every tier: filters/semijoins
     collapse lo to 0 and never raise hi; a left outer join holds its left
-    side's lo; inner joins bound by the cross
+    side's lo, a full outer join the longer side's; inner joins bound by
+    the cross
     product; keyed aggregates by their input (distinct groups <= rows)."""
     if isinstance(node, Scan):
         n = _scan_rows(node, bound_rows)
@@ -299,6 +300,12 @@ def _rows_interval(node: PlanNode, kids: List[Tuple[int, Optional[int]]],
             # nothing matches) and once a match at most
             return los[0], _mul(his[0], None if his[1] is None
                                 else max(his[1], 1))
+        if node.how == "full_outer":
+            # every row of either side comes out once at least: the longer
+            # side's rows at least, and at most every row null-extended
+            # beside every pair
+            return max(los), _add(_add(his[0], his[1]),
+                                  _mul(his[0], his[1]))
         return 0, his[0]                     # semi/anti: left-row subset
     if isinstance(node, HashAggregate):
         if not node.keys:
@@ -409,18 +416,22 @@ def certify_nodes(nodes: List[PlanNode], *, bound=None, bound_rows=None,
             nullable[id(node)] = {
                 c: src.get(c, True) for c in schemas.get(id(node), ())}
         elif isinstance(node, (Project, FusedSelect)):
-            from ..plan.expr import ColumnRef
+            from ..plan.expr import nullable as expr_nullable
+            kid_types = types.get(id(node.children[0])) or {}
             nullable[id(node)] = {
-                n: (kids_n[0].get(e.name, True)
-                    if isinstance(e, ColumnRef) else False)
+                n: expr_nullable(e, lambda c: kids_n[0].get(c, True),
+                                 kid_types.get)
                 for n, e in node.exprs}
         elif isinstance(node, HashJoin):
             out = dict(kids_n[0])
-            if node.how == "inner":
-                out.update(kids_n[1])
-            elif node.how == "left_outer":
-                # a left row without a match is null in every one
-                out.update(dict.fromkeys(kids_n[1], True))
+            if node.how in PAIRING_JOINS:
+                # a row without a match is null in every column of the
+                # other side
+                null_left, null_right = nullable_sides(node.how)
+                if null_left:
+                    out = dict.fromkeys(out, True)
+                out.update(dict.fromkeys(kids_n[1], True) if null_right
+                           else kids_n[1])
             nullable[id(node)] = out
         elif isinstance(node, HashAggregate):
             out = {k: kids_n[0].get(k, True) for k in node.keys}

@@ -47,7 +47,8 @@ import dataclasses
 import random
 from typing import Dict, List, Optional, Tuple
 
-from ..plan.expr import Expr, col, lit, scalar_max, scalar_min, scalar_sum
+from ..plan.expr import (Expr, coalesce, col, is_not_null, is_null, lit,
+                         scalar_max, scalar_min, scalar_sum, when)
 from ..plan.nodes import (Exchange, Filter, FusedSelect, HashAggregate,
                           HashJoin, JOIN_TYPES, Limit, PAIRING_JOINS,
                           PlanNode, Scan, Sort, TopK,
@@ -162,9 +163,16 @@ def _gen_predicate(rng: random.Random, rel: _Rel, depth: int = 0) -> Expr:
     name = rng.choice(numeric)
     c = col(name)
     cmp = rng.choice(("<", "<=", ">", ">=", "==", "!="))
-    if rng.random() < 0.12:
+    r = rng.random()
+    if r < 0.12:
         sagg = rng.choice((scalar_max, scalar_min, scalar_sum))
         rhs: Expr = sagg(col(rng.choice(numeric)))
+    elif r < 0.22:
+        # null-aware: TRUE over a null (an outer join below supplies them)
+        return rng.choice((is_null, is_not_null))(c)
+    elif r < 0.27 and name in rel.cols("i"):
+        c = coalesce(c, rng.randrange(8))
+        rhs = lit(rng.randrange(8))
     else:
         is_f = name in rel.cols("f")
         rhs = lit(rng.randrange(32) / 4.0 if is_f else rng.randrange(8))
@@ -189,6 +197,12 @@ def _gen_exprs(rng: random.Random, rel: _Rel, fresh) -> Tuple[
         e = {"+": col(a) + col(b), "-": col(a) - col(b),
              "*": col(a) * lit(rng.randrange(1, 4))}[op]
         exprs.append((name, e))
+        schema.append((name, "i"))
+    elif numeric and rng.random() < 0.3:
+        name = fresh("w")
+        a, b = rng.choice(numeric), rng.choice(numeric)
+        exprs.append((name, when(_gen_predicate(rng, rel, 2), col(a),
+                                 coalesce(col(b), 0))))
         schema.append((name, "i"))
     return exprs, schema
 
@@ -304,13 +318,15 @@ def gen_case(seed: int, *, max_ops: int = 8,
                 continue
             lk = (rng.choice(rel.cols("i")),)
             rk = (rng.choice(other.cols("i")),)
-            how = rng.choices(JOIN_TYPES, weights=(3, 1, 1, 1))[0]
+            how = rng.choices(JOIN_TYPES, weights=(3, 1, 1, 1, 1))[0]
             schema = (rel.schema + other.schema if how in PAIRING_JOINS
                       else list(rel.schema))
             est = (rel.est * other.est / 4 if how in PAIRING_JOINS
                    else rel.est * 0.6)
             if how == "left_outer":     # every left row comes out
                 est = max(est, rel.est)
+            elif how == "full_outer":   # and every right row
+                est = max(est, rel.est + other.est)
             out = _Rel(HashJoin(rel.node, other.node, lk, rk, how=how),
                        schema, max(est, 1.0))
         else:   # exchange: hash on an int column, or the identity marker

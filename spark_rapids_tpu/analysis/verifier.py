@@ -47,8 +47,8 @@ import dataclasses
 from typing import Dict, List, Optional, Tuple
 
 from .. import dtypes
-from ..plan.expr import (BinOp, ColumnRef, Expr, Literal, ScalarAgg,
-                         UnaryOp)
+from ..plan.expr import (BinOp, Coalesce, ColumnRef, Expr, IsNull, Literal,
+                         ScalarAgg, UnaryOp, When)
 from ..plan.nodes import (PAIRING_JOINS, Exchange, Filter, FusedSelect,
                           HashAggregate, HashJoin, Limit, PlanNode,
                           PlanValidationError, Project, Scan, Sort, TopK,
@@ -280,10 +280,36 @@ def type_expr(e: Expr, coltypes: Dict[str, Optional[dtypes.DType]],
         return dt
     if isinstance(e, Literal):
         return _lit_dtype(e.value)
-    if isinstance(e, (BinOp, UnaryOp, ScalarAgg)):
+    if isinstance(e, (BinOp, UnaryOp, ScalarAgg, When, Coalesce)):
         known, dec = _decimal_typing(e, coltypes, node, report)
         if not known or dec is not None:
+            if isinstance(e, When):
+                _check_predicate(e.cond, coltypes, node, report)
             return dec          # Spark's decimal type, or flagged
+    if isinstance(e, IsNull):
+        type_expr(e.child, coltypes, node, report)
+        return _BOOL            # never null itself
+    if isinstance(e, (When, Coalesce)):
+        if isinstance(e, When):
+            _check_predicate(e.cond, coltypes, node, report)
+        branches = e.children()[1:] if isinstance(e, When) else e.args
+        types = [type_expr(b, coltypes, node, report) for b in branches]
+        if any(t is None for t in types):
+            return None
+        kinds = {("bool" if t.kind == dtypes.Kind.BOOL else
+                  "float" if t.is_floating else
+                  "int" if t.is_integer else repr(t)) for t in types}
+        if len(kinds) > 1 and kinds != {"int", "float"}:
+            report.add("typing.branch-type-mismatch", node,
+                       f"{node.label}: the branches of {e!r} type to "
+                       f"{types!r} — one result column has one type (a "
+                       "plan states the cast)")
+            return None
+        if kinds == {"bool"}:
+            return _BOOL
+        if "float" in kinds:
+            return _FLOAT64
+        return _INT64 if kinds == {"int"} else types[0]
     if isinstance(e, BinOp):
         lt = type_expr(e.left, coltypes, node, report)
         rt = type_expr(e.right, coltypes, node, report)

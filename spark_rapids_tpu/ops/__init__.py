@@ -30,7 +30,8 @@ from .map_utils import from_json
 from .gather import take, take_live, take_table, apply_boolean_mask
 from .sort import sort_table_capped, sorted_order, sort_table
 from .aggregate import groupby_aggregate, groupby_aggregate_capped
-from .join import (full_join, inner_join, inner_join_capped,
+from .join import (full_join, full_join_counted, inner_join,
+                   inner_join_carrying, inner_join_capped,
                    inner_join_capped_tail, left_join, left_join_capped,
                    left_join_counted, left_semi_join, left_anti_join,
                    semi_join_mask)
@@ -72,9 +73,11 @@ _ADMITTED_FACTORS = {
     "apply_boolean_mask": 2.0,
     "sorted_order": 2.0, "sort_table": 3.0, "sort_table_capped": 3.0,
     "groupby_aggregate": 2.0, "groupby_aggregate_capped": 2.0,
-    "inner_join": 3.0, "inner_join_capped": 3.0,
+    "inner_join": 3.0, "inner_join_carrying": 3.0,
+    "inner_join_capped": 3.0,
     "inner_join_capped_tail": 3.0, "left_join": 3.0,
     "left_join_counted": 3.0, "left_join_capped": 3.0, "full_join": 3.0,
+    "full_join_counted": 3.0,
     "left_semi_join": 2.0, "left_anti_join": 2.0, "semi_join_mask": 2.0,
     # slice/split/halve are deliberately NOT admitted: they run inside the
     # SplitAndRetry recovery path when memory is already short, and their
@@ -115,9 +118,10 @@ __all__ = [
     "sort_table",
     "sort_table_capped",
     "groupby_aggregate", "groupby_aggregate_capped",
-    "inner_join", "inner_join_capped", "inner_join_capped_tail",
+    "inner_join", "inner_join_carrying", "inner_join_capped",
+    "inner_join_capped_tail",
     "left_join", "left_join_counted", "left_join_capped",
-    "full_join",
+    "full_join", "full_join_counted",
     "left_semi_join",
     "left_anti_join", "semi_join_mask",
     "concat_columns", "concat_tables", "slice_table", "split_table",

@@ -98,14 +98,21 @@ RIDE_PAYLOADS = 4
 
 
 def _sort_with_payloads(key_operands, iota, payloads, n_ops: int,
-                        gather: bool):
+                        gather: bool, by_row: bool = False):
     """The main key sort -> (sorted key operands, order, payloads in that
     order). The payloads ride the sort as operands, or, with `gather`
     (decimal planes: a dozen operands would ride) and more than
     `RIDE_PAYLOADS` of them, the sort moves the keys and the iota alone
-    and the payloads are gathered by it afterwards."""
+    and the payloads are gathered by it afterwards. `by_row` (a DISTINCT,
+    which has no payload): the iota is the sort's last key and the sort
+    not a stable one; no two rows tie, the order is the stable sort's, and
+    the program compiles in half the time (ops/join.py:_union_sort)."""
     gather = gather and len(payloads) > RIDE_PAYLOADS
     operands = [*key_operands, iota] + ([] if gather else list(payloads))
+    if by_row:
+        sorted_all = jax.lax.sort(operands, num_keys=n_ops + 1,
+                                  is_stable=False)
+        return sorted_all[:n_ops], sorted_all[n_ops], []
     sorted_all = jax.lax.sort(operands, num_keys=n_ops, is_stable=True)
     order = sorted_all[n_ops]
     spay = ([jnp.take(p, order, axis=0) for p in payloads] if gather
@@ -195,7 +202,8 @@ def _groupby_kernel(key_operands, agg_datas, agg_valids, *, n_ops: int,
         slots.append((d_slot, v_slot))
 
     sorted_ops, order, spay = _sort_with_payloads(
-        key_operands, iota, payloads, n_ops, gather_payloads)
+        key_operands, iota, payloads, n_ops, gather_payloads,
+        by_row=not agg_kinds)
 
     neq = jnp.zeros((n,), bool)
     for o in sorted_ops:
@@ -300,8 +308,14 @@ def _groupby_kernel(key_operands, agg_datas, agg_valids, *, n_ops: int,
             comp_pay.append(jnp.where(boundary, ext, ident))
             agg_comp.append((slot, "ext", cnt_slot))
 
-    flag = jnp.where(boundary, jnp.int32(0), jnp.int32(1))
-    comp = jax.lax.sort([flag, *comp_pay], num_keys=1, is_stable=True)[1:]
+    if not agg_kinds:
+        # a DISTINCT: the starts' positions are their own key (every other
+        # row holds `n` twice), so the sort need not be a stable one
+        comp = jax.lax.sort(comp_pay, num_keys=1, is_stable=False)
+    else:
+        flag = jnp.where(boundary, jnp.int32(0), jnp.int32(1))
+        comp = jax.lax.sort([flag, *comp_pay], num_keys=1,
+                            is_stable=True)[1:]
     starts, first_rows = comp[0], comp[1]
 
     def adj_diff(arr, tail):

@@ -336,14 +336,9 @@ def kept_rows(mask, kept: int = None) -> jnp.ndarray:
     a row where 677 of 15 M rows are kept, PERF.md, PR 34). One host sync
     for the result's size, unless the caller has read it (`kept`)."""
     mask = jnp.asarray(mask).astype(bool)
-    n = int(mask.shape[0])
     if kept is None:
         kept = _count_kept(mask)
-    path = compaction_path(n, kept)
-    if path == "none":
-        return jnp.arange(n, dtype=jnp.int32)
-    by = rows_by_position if path == "positions" else rows_by_sort
-    return by(mask, [], kept=kept)[0]
+    return compact_rows_columns([], mask, kept)[0]
 
 
 # what the compactions under a `with compactions.collect()` did:
@@ -354,12 +349,19 @@ compactions = Tally()
 def compact_columns(cols, mask, kept: int) -> list:
     """`cols` (all of `mask`'s length) at the `kept` rows where the bool
     `mask` holds, moved the way `compaction_path` says."""
+    return compact_rows_columns(cols, mask, kept)[1]
+
+
+def compact_rows_columns(cols, mask, kept: int):
+    """-> (the positions of the `kept` rows where the bool `mask` holds,
+    ascending, int32; `cols` at those rows), moved the way
+    `compaction_path` says."""
     n = int(mask.shape[0])
     ragged = [c.dtype.kind in _RAGGED for c in cols]
     path = compaction_path(n, kept, any(ragged))
     compactions.note((path, n, kept))
     if path == "none":
-        return list(cols)
+        return jnp.arange(n, dtype=jnp.int32), list(cols)
     arrays = [p for c, r in zip(cols, ragged) if not r
               for p in (c.data, c.validity) if p is not None]
     if path == "positions":
@@ -368,10 +370,10 @@ def compact_columns(cols, mask, kept: int) -> list:
         rows, got = rows_by_sort(mask, arrays, kept=kept,
                                  groups=ride_groups(plane_words(arrays)))
     got = iter(got)
-    return [take(c, rows, _has_negative=False) if r else
-            Column(dtype=c.dtype, length=kept, data=next(got),
-                   validity=None if c.validity is None else next(got))
-            for c, r in zip(cols, ragged)]
+    return rows, [take(c, rows, _has_negative=False) if r else
+                  Column(dtype=c.dtype, length=kept, data=next(got),
+                         validity=None if c.validity is None else next(got))
+                  for c, r in zip(cols, ragged)]
 
 
 def apply_boolean_mask(table_or_col, mask) -> Union[Table, Column]:

@@ -53,17 +53,36 @@ line), and nowhere else.
    annotation, argument or switch says which join is which. Both tails
    return the same arrays, slot for slot.
 
+2c. both sides' rows (`_full_join_kernel`, the eager full outer join): the
+   same union sort and general tail, read for both sides at once. The flag
+   payload marks a left row that may match (2) as it marks a right one
+   (1); a running count of the first, less its value at the run's start,
+   is at every right row the left rows that match it, and rides home in
+   the right row's slot of the routing sort, which nobody else reads. So
+   one sort of the union where `left_join` and a swapped anti join ran
+   two. Nulls match nothing here, so a nullable key brings no null-rank
+   operand (the match masks keep a null-key row out whatever run it lies
+   in), and the three sorts take the row number as their last key, or a
+   key that is unique already, and are not stable: the order is the same,
+   and XLA compiles such a sort in half the time (PERF.md section 6, PR
+   43; the other joins keep the stable form their cells were measured
+   with: ROADMAP S3).
+
 3. the small-side path (eager `inner_join`, `left_semi_join`,
    `left_anti_join` only, which hold both sides' row counts on the host):
    with one side of at most `LOOKUP_SMALL` rows and the other of at least
-   `LOOKUP_LARGE`, integer keys, the large side is not sorted. Its rows
+   `LOOKUP_LARGE`, integer keys, the large side is not sorted, whatever
+   share of it passes. Its rows
    that carry a key of the small side are found by comparison in one pass
-   (ops/join_lookup.py), their positions taken at a price that follows
-   what is kept, and the sort join above runs over the small side and
+   (ops/join_lookup.py), moved the way `ops/gather.py:compaction_path`
+   says of their count (few by their positions, many riding one sort),
+   and the sort join above runs over the small side and
    those survivors. Survivors keep their row order, so the maps come out
    pair for pair in the order the sort join of the whole sides gives.
-   Where more than a row in `ops/gather.py:FEW_KEPT` survives, the sort
-   join runs.
+   Where more than a row in `ops/gather.py:FEW_KEPT` survives an inner
+   join whose small side is the right one and has distinct keys (a fact
+   table against a filtered dimension), a second pass leaves every row's
+   one match, which rides the compaction: no sort join follows.
 
 Null keys never match (Spark equi-join); null-safe equality (<=>) is the
 `null_equal` flag, like cudf's null_equality::EQUAL — null rows get their
@@ -81,12 +100,13 @@ import jax.numpy as jnp
 from .. import dtypes
 from ..columnar import Column, Table
 from ..utils.tracing import span
-from .gather import (few_kept, gather_live, kept_rows, live_chunk,
-                     loop_zeros, rows_by_position)
-from .join_lookup import lookup_side, member_mask, note_lookup
+from .gather import (compact_rows_columns, few_kept, gather_live, kept_rows,
+                     live_chunk, loop_zeros)
+from .join_lookup import lookup_side, match_rows, member_mask, note_lookup
 from .sort import _key_operands
 
-__all__ = ["inner_join", "left_join", "left_join_counted", "full_join",
+__all__ = ["inner_join", "inner_join_carrying", "left_join", "left_join_counted", "full_join",
+           "full_join_counted",
            "left_semi_join",
            "left_anti_join",
            "inner_join_capped", "inner_join_capped_tail", "left_join_capped",
@@ -105,17 +125,21 @@ def _concat_columns(a: Column, b: Column) -> Column:
         raise TypeError(f"join key {e}") from None
 
 
-def _union_sort(operands, iota, flags, *, n_ops: int):
+def _union_sort(operands, iota, flags, *, n_ops: int, by_row: bool = False):
     """The union sort every join starts from: ONE stable multi-operand sort
     of the concatenated keys carrying two payloads, the row `iota` and a
     per-row int32 `flags`. Returns (boundary, order, flags_sorted): where a
     run of equal keys starts, each sorted position's original row, and its
     flag. Within a run the left rows come first (lower iota), then the
-    right rows, each side in its original order."""
+    right rows, each side in its original order. `by_row`: the row number
+    is the sort's last key and the sort is not a stable one; no two rows
+    tie, so the order is the stable sort's, and the program compiles in
+    half the time (for a stable sort the chip's compiler adds the row
+    numbers as one more operand and key: PERF.md, PR 43)."""
     n = operands[0].shape[0]
     # a marginal sort operand is cheaper on-chip than a post-sort gather
-    out = jax.lax.sort([*operands, iota, flags], num_keys=n_ops,
-                       is_stable=True)
+    out = jax.lax.sort([*operands, iota, flags], num_keys=n_ops + int(by_row),
+                       is_stable=not by_row)
     sorted_ops, order, f_s = out[:-2], out[-2], out[-1]
     neq = jnp.zeros((n,), bool)
     for o in sorted_ops:
@@ -149,25 +173,47 @@ def _sorted_spans(boundary, m_s):
     return lo_pos, hi_pos
 
 
-def _matchable_rows(order, m_s, *, nl: int):
-    """Matchable right-row ids packed to the front, in union-sorted order."""
+def _matchable_rows(order, m_s, *, nl: int, by_row: bool = False):
+    """Matchable right-row ids packed to the front, in union-sorted order.
+    `by_row`: keyed by the sorted position itself (every other row holds
+    the same key and the same id `n`), so the sort need not be stable."""
     n = order.shape[0]
+    if by_row:
+        at = jnp.where(m_s == 1, jnp.arange(n, dtype=jnp.int32), jnp.int32(n))
+        rid = jnp.where(m_s == 1, order - nl, jnp.int32(n))
+        return jax.lax.sort([at, rid], num_keys=1, is_stable=False)[1]
     flag = jnp.where(m_s == 1, jnp.int32(0), jnp.int32(1))
     rid = jnp.where(m_s == 1, order - nl, jnp.int32(n))
     return jax.lax.sort([flag, rid], num_keys=1, is_stable=True)[1]
 
 
-def _span_tail(boundary, order, m_s, lvalid, *, nl: int, need_rorder: bool):
+def _span_tail(boundary, order, m_s, lvalid, *, nl: int, need_rorder: bool,
+               l_s=None):
     """The general tail over the union sort (`m_s`: 1 at a matchable right
-    row): every left row's match span, for `_expand`. See _join_kernel."""
+    row): every left row's match span, for `_expand`. See _join_kernel.
+    With `l_s` (1 at a left row that may match; the frame was sorted
+    `by_row`) a fourth result: per right row, the left rows that may match
+    in its run."""
     lo_pos, hi_pos = _sorted_spans(boundary, m_s)
+    if l_s is not None:
+        # in a run the left rows come first, so at a right row the count so
+        # far less the count at the run's start (a running maximum, as for
+        # `lo`) is the run's; nobody reads a right row's span, and its slot
+        # of the routing sort carries the count home
+        seen = jnp.cumsum(l_s)
+        hits = seen - jax.lax.cummax(jnp.where(boundary, seen - l_s, 0))
+        lo_pos = jnp.where(order < nl, lo_pos, hits)
     # route lo/hi back to original row order: ONE 3-operand sort keyed by
-    # the iota payload (order is a permutation, so this inverts it)
-    routed = jax.lax.sort([order, lo_pos, hi_pos], num_keys=1)
+    # the iota payload (order is a permutation, so this inverts it, stable
+    # or not: the `by_row` frame's is not)
+    routed = jax.lax.sort([order, lo_pos, hi_pos], num_keys=1,
+                          is_stable=l_s is None)
     lo_orig, hi_orig = routed[1][:nl], routed[2][:nl]
     counts = jnp.where(lvalid, hi_orig - lo_orig, 0)
-    rorder = _matchable_rows(order, m_s, nl=nl) if need_rorder \
-        else jnp.zeros((0,), jnp.int32)
+    rorder = _matchable_rows(order, m_s, nl=nl, by_row=l_s is not None) \
+        if need_rorder else jnp.zeros((0,), jnp.int32)
+    if l_s is not None:
+        return counts, lo_orig, rorder, routed[1][nl:]
     return counts, lo_orig, rorder
 
 
@@ -191,6 +237,24 @@ def _join_kernel(operands, lvalid, rvalid, *, n_ops: int, nl: int,
                                        n_ops=n_ops)
     return _span_tail(boundary, order, m_s, lvalid, nl=nl,
                       need_rorder=need_rorder)
+
+
+@partial(jax.jit, static_argnames=("n_ops", "nl"))
+def _full_join_kernel(operands, lvalid, rvalid, *, n_ops: int, nl: int):
+    """`_join_kernel` for the join that keeps both sides' rows: ONE union
+    sort serves both. -> (counts, lo, rorder, rmiss): `_join_kernel`'s
+    three, and per right row whether no left row matches it (a row that
+    may not match among them). The flag payload says of a left row that it
+    may match (2) as it says of a right row (1)."""
+    iota = jnp.arange(operands[0].shape[0], dtype=jnp.int32)
+    may = jnp.concatenate([lvalid.astype(jnp.int32) * 2,
+                           rvalid.astype(jnp.int32)])
+    boundary, order, f_s = _union_sort(operands, iota, may, n_ops=n_ops,
+                                       by_row=True)
+    counts, lo, rorder, hits = _span_tail(
+        boundary, order, f_s & 1, lvalid, nl=nl, need_rorder=True,
+        l_s=f_s >> 1)
+    return counts, lo, rorder, ~(rvalid & (hits > 0))
 
 
 def expand_rows(eff, total: int):
@@ -319,9 +383,13 @@ def expand_spans(counts, lo, rorder, *, total: int, outer: bool = False,
     return _expand(counts, lo, rorder, total=total, outer=outer, eff=eff)
 
 
-def _union_operands(left_keys, right_keys, null_equal: bool, lalive, ralive):
+def _union_operands(left_keys, right_keys, null_equal: bool, lalive, ralive,
+                    ranked: bool = True):
     """-> (sort operands of the concatenated keys, lvalid, rvalid, nl): the
-    match masks hold null keys (unless `null_equal`) and the alive masks."""
+    match masks hold null keys (unless `null_equal`) and the alive masks.
+    Not `ranked`: a nullable key brings no null-rank operand and its data
+    goes as it lies; right where nulls match nothing, since the masks keep
+    a null-key row out of every match whatever run it sorts into."""
     lcols, rcols = list(left_keys), list(right_keys)
     if len(lcols) != len(rcols) or not lcols:
         raise ValueError("join requires equal, nonzero key column counts")
@@ -331,7 +399,8 @@ def _union_operands(left_keys, right_keys, null_equal: bool, lalive, ralive):
         # operand count depends on the padded width, so building them on the
         # union guarantees both sides agree on the encoding
         u = _concat_columns(a, b)
-        union_ops.extend(_key_operands(u, True, None))
+        union_ops.extend(_key_operands(
+            u if ranked else u.with_validity(None), True, None))
     nl = lcols[0].length
 
     def side_valid(cols, n):
@@ -378,18 +447,12 @@ def _sort_inner_join(lcols, rcols, null_equal: bool):
     return lmap, rmap, total
 
 
-def _survivors(small, large):
-    """Steps 1 and 2 of the small-side path (ops/join_lookup.py): the rows
-    of `large` that carry a key of `small`, ascending, and their keys as
-    columns (no row of them is null or dead). None where so many rows pass
-    that the join of the survivors would be the sort join over again."""
-    mask, count = member_mask(small, large)
-    if not few_kept(count, large[0].length):
-        return None
-    note_lookup(small[0].length, large[0].length)
-    rows, keys = rows_by_position(mask, [c.data for c in large], kept=count)
-    return rows, [Column(dtype=c.dtype, length=count, data=d)
-                  for c, d in zip(large, keys)]
+def _survivors(small, large, mask, count: int):
+    """Step 2 of the small-side path: the rows of `large` under
+    `member_mask`'s `mask`, ascending, and their keys as columns (no row
+    of them is null or dead), moved by the count step 1 read."""
+    return compact_rows_columns([c.with_validity(None) for c in large],
+                                mask, count)
 
 
 @jax.jit
@@ -397,21 +460,38 @@ def _map_back(rows, idx):
     return jnp.take(rows, idx, axis=0)
 
 
-def _lookup_inner_join(lcols, rcols, null_equal: bool):
-    """The inner join by the small-side path, or None where it declines."""
+def _lookup_inner_join(lcols, rcols, null_equal: bool, carry=()):
+    """The inner join by the small-side path -> (lmap, rmap, total,
+    carried), or None where it declines. The large side is read once
+    whatever share of it passes. Few rows (`few_kept`): the sort join runs
+    over the small side and the survivors. Many, the small side on the
+    right with distinct keys (a fact table against a filtered dimension,
+    Spark's broadcast hash join): a second pass leaves every row's match,
+    which rides the survivors' compaction, and no sort join follows; the
+    columns of `carry` (of the left side's length) ride it too, and come
+    back as `carried`: the left map's rows of them, which no gather has to
+    fetch. Else the sort join over the survivors, as with few, and
+    `carried` is None."""
     side = lookup_side(lcols, rcols, null_equal)
     if side is None:
         return None
     small, large = (lcols, rcols) if side == "left" else (rcols, lcols)
-    found = _survivors(small, large)
-    if found is None:
-        return None
-    rows, keys = found
+    mask, count = member_mask(small, large)
+    n = large[0].length
+    note_lookup(small[0].length, n)
+    if side == "right" and not few_kept(count, n):
+        match = match_rows(small, large, mask)
+        if match is not None:
+            lmap, moved = compact_rows_columns(
+                [*carry, Column(dtype=dtypes.INT32, length=n, data=match)],
+                mask, count)
+            return lmap, moved[-1].data, count, moved[:-1]
+    rows, keys = _survivors(small, large, mask, count)
     if side == "left":
         lmap, rmap, total = _sort_inner_join(lcols, keys, null_equal)
-        return lmap, _map_back(rows, rmap), total
+        return lmap, _map_back(rows, rmap), total, None
     lmap, rmap, total = _sort_inner_join(keys, rcols, null_equal)
-    return _map_back(rows, lmap), rmap, total
+    return _map_back(rows, lmap), rmap, total, None
 
 
 def inner_join(left_keys, right_keys,
@@ -422,11 +502,23 @@ def inner_join(left_keys, right_keys,
     of the small side are found by comparison, and the sort join runs over
     the small side and those survivors, which keep their row order, so the
     pairs come out as the sort join of the whole sides gives them."""
+    return inner_join_carrying(left_keys, right_keys, (), null_equal)[:2]
+
+
+def inner_join_carrying(left_keys, right_keys, carry,
+                        null_equal: bool = False):
+    """`inner_join`, and the columns of `carry` (of the left side's length:
+    its output columns) at the left map's rows where the join moved them
+    itself: -> (left_map, right_map, carried). Where many rows of a large
+    left side pass a small right side of distinct keys, the survivors'
+    columns ride the one sort that compacts them and `carried` holds them;
+    else it is None and the caller gathers by the left map."""
     lcols, rcols = _cols(left_keys), _cols(right_keys)
-    lmap, rmap, total = _lookup_inner_join(lcols, rcols, null_equal) \
-        or _sort_inner_join(lcols, rcols, null_equal)
+    lmap, rmap, total, carried = \
+        _lookup_inner_join(lcols, rcols, null_equal, carry) \
+        or (*_sort_inner_join(lcols, rcols, null_equal), None)
     return (Column(dtype=dtypes.INT32, length=total, data=lmap),
-            Column(dtype=dtypes.INT32, length=total, data=rmap))
+            Column(dtype=dtypes.INT32, length=total, data=rmap), carried)
 
 
 @jax.jit
@@ -640,21 +732,51 @@ def semi_join_mask(left_keys, right_keys, *, lalive=None, ralive=None,
     return counts > 0
 
 
+@jax.jit
+def _full_totals(counts, rmiss):
+    """-> `_outer_totals`, and the right rows without a match."""
+    return (*_outer_totals(counts), jnp.sum(rmiss.astype(jnp.int64)))
+
+
+def full_join_counted(left_keys, right_keys, null_equal: bool = False):
+    """`full_join` and what its one host sync read: (left_map, right_map,
+    matched, unmatched, unmatched_right): `left_join_counted`'s output,
+    then one (-1, j) row per right row j without a match (a null key
+    among them), ascending. Both sides' answers come off ONE union sort
+    (`_full_join_kernel`), and `unmatched_right` says whether the left map
+    holds a -1, as `unmatched` says of the right map: the caller's gathers
+    need not ask the device."""
+    lcols, rcols = _cols(left_keys), _cols(right_keys)
+    operands, lvalid, rvalid, nl = _union_operands(
+        lcols, rcols, null_equal, None, None, ranked=null_equal)
+    counts, lo, rorder, rmiss = _full_join_kernel(
+        operands, lvalid, rvalid, n_ops=len(operands), nl=nl)
+    with span("ops.host_sync", site="join.full"):
+        matched, unmatched, unmatched_right = (int(x) for x in jax.device_get(
+            _full_totals(counts, rmiss)))     # the one host sync
+    total = matched + unmatched
+    lmap, rmap = _expand(counts, lo, rorder, total=total, outer=True)
+    if unmatched_right:
+        lmap, rmap = _append_right(lmap, rmap,
+                                   kept_rows(rmiss, unmatched_right))
+        total += unmatched_right
+    return (Column(dtype=dtypes.INT32, length=total, data=lmap),
+            Column(dtype=dtypes.INT32, length=total, data=rmap),
+            matched, unmatched, unmatched_right)
+
+
+@jax.jit
+def _append_right(lmap, rmap, extra):
+    return (jnp.concatenate([lmap, jnp.full(extra.shape, -1, jnp.int32)]),
+            jnp.concatenate([rmap, extra]))
+
+
 def full_join(left_keys, right_keys,
               null_equal: bool = False) -> Tuple[Column, Column]:
     """Full outer join: left_join's output plus one (-1, j) row per
     UNMATCHED right row j (cudf::full_join's gather-map contract; take()
-    turns the -1s into null rows on either side). The unmatched-right set
-    comes from one swapped-sides span pass (counts only, no expansion)."""
-    lmap, rmap = left_join(left_keys, right_keys, null_equal)
-    extra = left_anti_join(right_keys, left_keys, null_equal).data
-    n_extra = int(extra.shape[0])
-    total = lmap.length + n_extra
-    ldata = jnp.concatenate([lmap.data,
-                             jnp.full((n_extra,), -1, jnp.int32)])
-    rdata = jnp.concatenate([rmap.data, extra])
-    return (Column(dtype=dtypes.INT32, length=total, data=ldata),
-            Column(dtype=dtypes.INT32, length=total, data=rdata))
+    turns the -1s into null rows on either side)."""
+    return full_join_counted(left_keys, right_keys, null_equal)[:2]
 
 
 def _sort_semi_anti(lcols, rcols, null_equal: bool, semi: bool):
@@ -665,12 +787,14 @@ def _sort_semi_anti(lcols, rcols, null_equal: bool, semi: bool):
 def _lookup_semi_anti(lcols, rcols, null_equal: bool, semi: bool):
     """The semi or anti join's rows by the small-side path, or None."""
     side = lookup_side(lcols, rcols, null_equal)
-    if side == "left":
-        found = _survivors(lcols, rcols)
-        return None if found is None \
-            else _sort_semi_anti(lcols, found[1], null_equal, semi)
     if side is None:
         return None
+    if side == "left":
+        mask, count = member_mask(lcols, rcols)
+        note_lookup(lcols[0].length, rcols[0].length)
+        return _sort_semi_anti(
+            lcols, _survivors(lcols, rcols, mask, count)[1], null_equal,
+            semi)
     # the membership mask IS the semi join; the anti join keeps the rest
     mask, count = member_mask(rcols, lcols)
     note_lookup(rcols[0].length, lcols[0].length)

@@ -11,9 +11,15 @@ the large side. Steps 1 and 2 of the eager joins' small-side path
    are packed past the loop's bound, so no value has to "match nothing".
    (A Pallas kernel with the keys in SMEM was 1.6 times slower on the
    chip: PERF.md section 6, PR 37.)
-2. `ops/gather.py:rows_by_position`: the positions of the rows that
-   passed, by `ops/scans.py:live_positions` (a price that follows what is
-   kept), and their keys: the program every compaction of few rows runs.
+2. `ops/gather.py:compact_rows_columns`: the positions of the rows that
+   passed and their keys, moved the way `compaction_path` says of the
+   count step 1 read: few rows by `ops/scans.py:live_positions` (a price
+   that follows what is kept), many riding one sort, all as they lie.
+2'. `_match` (a broadcast join in which MANY rows pass, the small side on
+   the right with distinct keys: a fact table against a filtered
+   dimension): a second pass of the same compares that leaves, at every
+   large-side row, the small-side row it matched. That index rides step
+   2's compaction and IS the join's right map: no sort join follows.
 
 Exact: integer words compared bit for bit. A null key, on either side,
 matches nothing.
@@ -141,6 +147,53 @@ def _member(small_data, small_live, large_data, large_validity):
     return mask, jnp.sum(mask, dtype=jnp.int32)
 
 
+def _match_rows(small, rows, count, large):
+    """(n,) int32: at every large-side row the entry of `rows` that goes
+    with the small key it equals (the last of them, among the first
+    `count`), -1 where it equals none. `_member_rows` with a select where
+    that has an OR."""
+    n = large[0].shape[0]
+    lanes = jnp.arange(_CHUNK, dtype=jnp.int32)
+
+    def chunk(i, idx):
+        at = i * jnp.int32(_CHUNK)
+        live = at + lanes < count
+        keys = jax.lax.dynamic_slice_in_dim(small, at, _CHUNK, axis=1)
+        keys = jnp.where(live, keys, keys[:, :1])
+        ids = jax.lax.dynamic_slice_in_dim(rows, at, _CHUNK)
+        ids = jnp.where(live, ids, ids[:1])
+        for j in range(_CHUNK):
+            eq = large[0] == keys[0, j]
+            for p in range(1, len(large)):
+                eq = eq & (large[p] == keys[p, j])
+            idx = jnp.where(eq, ids[j], idx)
+        return idx
+
+    steps = (count + jnp.int32(_CHUNK - 1)) // jnp.int32(_CHUNK)
+    return jax.lax.fori_loop(jnp.int32(0), steps, chunk,
+                             jnp.full((n,), -1, jnp.int32))
+
+
+@jax.jit
+def _match(small_data, small_live, large_data, mask):
+    """-> (for every large-side row of `mask` the small-side row whose key
+    it carries, -1 outside the mask; whether the live small keys are
+    distinct, so that the row is the only one). Inputs as `_member`'s."""
+    order = jnp.argsort(~small_live, stable=True)
+    words = [w for d in small_data for w in _words(d)]
+    count = jnp.sum(small_live, dtype=jnp.int32)
+    idx = _match_rows(jnp.stack([w[order] for w in words]),
+                      order.astype(jnp.int32), count,
+                      [w for d in large_data for w in _words(d)])
+    # dead keys last; two equal live keys then lie side by side
+    dead, *srt = jax.lax.sort([(~small_live).astype(jnp.int32), *words],
+                              num_keys=1 + len(words))
+    same = dead[1:] == 0
+    for w in srt:
+        same = same & (w[1:] == w[:-1])
+    return jnp.where(mask, idx, -1), ~jnp.any(same)
+
+
 @jax.jit
 def _pad_small(data, validity):
     """The small side's key data and match mask, padded to LOOKUP_SMALL
@@ -182,3 +235,17 @@ def member_mask(small_cols: Sequence[Column], large_cols: Sequence[Column]):
                           [c.data for c in large_cols], _validity(large_cols))
     with span("ops.host_sync", site="join.lookup"):
         return mask, int(count)
+
+
+def match_rows(small_cols: Sequence[Column], large_cols: Sequence[Column],
+               mask):
+    """At every large-side row of `member_mask`'s `mask` the row of the
+    small side whose key it carries ((n,) int32, -1 outside the mask), or
+    None where two live small keys are equal and a large-side row has
+    more matches than one (one host sync says which)."""
+    small_data, small_live = _pad_small([c.data for c in small_cols],
+                                        _validity(small_cols))
+    match, distinct = _match(small_data, small_live,
+                             [c.data for c in large_cols], mask)
+    with span("ops.host_sync", site="join.lookup_distinct"):
+        return match if bool(distinct) else None

@@ -508,7 +508,23 @@ def make_signature(lcols: Sequence[Column], rcols: Sequence[Column],
                       for a, b in zip(lcols, rcols))
     return Signature.of(list(lcols) + list(rcols), how=how, tier=tier,
                         kinds_match=kinds_match,
-                        build_rows=rcols[0].length if rcols else 0)
+                        build_rows=rcols[0].length if rcols else 0,
+                        probe_rows=lcols[0].length if lcols else 0)
+
+
+# The largest probe side the EAGER entry takes (defined down here: a line
+# added above a `pallas_call` moves its location, which is part of every
+# holding program's compile-cache key). From this many rows on an eager
+# join with a small build side is the small-side path's (ops/join.py,
+# which needs `ops/join_lookup.py:LOOKUP_LARGE` rows, fewer): the count
+# kernel took 122 ns a probe row at 18 M and at 36 M rows against 366 build
+# rows (2.196 and 4.391 s, and the emit kernel 0.34 and 0.68 s more; my
+# chip run, PR 43, `q97.batch`'s date joins), where that path reads a row
+# for 0.33 ns and moves it for 10 to 12. The kernel's only measured eager
+# probe below is `q3.share`'s 333 K rows (67 ns a row against 193 build
+# rows, 22.3 ms; PERF.md section 5), which stays here: between the two
+# nothing was compared. The capped entry has no row count to branch on.
+EAGER_MAX_PROBE = 1 << 20
 
 
 def _supports(sig) -> bool:
@@ -516,6 +532,8 @@ def _supports(sig) -> bool:
             and sig.extra("tier") in ("eager", "capped")
             and bool(sig.extra("kinds_match"))
             and (sig.extra("build_rows") or 0) <= MAX_BUILD
+            and (sig.extra("tier") != "eager"
+                 or (sig.extra("probe_rows") or 0) < EAGER_MAX_PROBE)
             and all(k in _SUPPORTED_KINDS for k in sig.kinds))
 
 

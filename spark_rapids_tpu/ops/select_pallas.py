@@ -131,7 +131,9 @@ def _pure_literal(e) -> bool:
 def _compilable(e, table: Table) -> bool:
     from ..plan import expr as pexpr
     if isinstance(e, pexpr.ColumnRef):
-        return table[e.name].dtype.kind.value in _PRED_KINDS
+        # the kernel reads data planes alone: a nullable input declines
+        return (table[e.name].dtype.kind.value in _PRED_KINDS
+                and table[e.name].validity is None)
     if isinstance(e, pexpr.Literal):
         if isinstance(e.value, bool):
             return True
@@ -224,7 +226,7 @@ def fused_select_compact(table: Table, predicate, needed: Sequence[str],
                          block_rows: int = 2 * _LANES,
                          interpret: Optional[bool] = None) -> Table:
     """The compacted `needed` columns of rows passing `predicate` — drop-in
-    for `apply_boolean_mask(table.select(needed), predicate.evaluate(table))`
+    for `apply_boolean_mask(table.select(needed), predicate.truth(table))`
     (the eager FusedSelect front half; the caller projects the result)."""
     if block_rows % _LANES:
         raise ValueError(f"block_rows must be a multiple of {_LANES}")

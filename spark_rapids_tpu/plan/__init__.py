@@ -47,7 +47,8 @@ diagnostics.
 See docs/plan.md for the operator contract and how a JVM/plugin front-end
 targets this layer.
 """
-from .expr import col, lit, scalar_max, scalar_min, scalar_sum, Expr
+from .expr import (col, lit, scalar_max, scalar_min, scalar_sum, is_null,
+                   is_not_null, when, coalesce, Expr)
 from .nodes import (Exchange, Filter, FusedSelect, HashAggregate, HashJoin,
                     Limit, PlanNode, Project, Scan, Sort, TopK, Union)
 from .builder import Plan, PlanBuilder, PlanValidationError
@@ -58,7 +59,8 @@ from .optimizer import (OptimizeReport, optimize, plan_fingerprint,
 from .stats import StatsStore, active_store, scoped_store
 
 __all__ = [
-    "col", "lit", "scalar_max", "scalar_min", "scalar_sum", "Expr",
+    "col", "lit", "scalar_max", "scalar_min", "scalar_sum", "is_null",
+    "is_not_null", "when", "coalesce", "Expr",
     "Scan", "Filter", "Project", "FusedSelect", "HashJoin",
     "HashAggregate", "Sort", "TopK", "Exchange", "Limit", "Union",
     "PlanNode",

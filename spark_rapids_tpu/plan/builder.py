@@ -156,6 +156,12 @@ class Rel:
                                  tuple(tuple(a) for a in aggs),
                                  key_cap=key_cap))
 
+    def distinct(self, keys: Sequence[str],
+                 key_cap: Optional[int] = None) -> "Rel":
+        """The distinct rows of `keys` (SELECT DISTINCT / a GROUP BY with
+        no aggregate function; NULLs group together)."""
+        return self.aggregate(keys, (), key_cap=key_cap)
+
     def sort(self, keys: Sequence[str],
              ascending: TUnion[bool, Sequence[bool]] = True) -> "Rel":
         asc = ((ascending,) * len(keys) if isinstance(ascending, bool)

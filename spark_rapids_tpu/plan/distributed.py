@@ -866,13 +866,15 @@ class DistContext:
         (c,) = childs
         if not isinstance(c, ShardedRel) or c.replicated:
             return None
-        mask = self._eval(node.predicate, c.table, c.valid)
+        mask = self._eval(node.predicate, c.table, c.valid, truth=True)
         return ShardedRel(c.table, c.valid & mask, part=c.part,
                           order_keys=c.order_keys)
 
-    def _eval(self, e, table: Table, valid):
+    def _eval(self, e, table: Table, valid, truth: bool = False):
         """An expression over a sharded relation, as ONE program whose
-        result is born row-sharded. Evaluated eagerly a literal is an
+        result is born row-sharded: (data, validity or None), or with
+        `truth` the rows where a predicate is TRUE (a null drops the row).
+        Evaluated eagerly a literal is an
         array of the relation's whole length on the first device (1.27 GB
         for a zero column of the store channel), and the next operator
         reshards it."""
@@ -883,17 +885,18 @@ class DistContext:
 
         def run(valid, *arrays):      # closes over no buffer: it is cached
             t = Table(_unpack_cols(list(arrays), layout), names=names)
-            v = e.evaluate(t, valid)
-            return jnp.broadcast_to(v, (n,)) if v.ndim == 0 else v
+            if truth:
+                return e.truth(t, valid)
+            c = e.column(t, valid)
+            return c.data, c.validity
 
-        key = ("expr", self.mesh, self.axis, _fp_expr(e), n,
+        key = ("expr", truth, self.mesh, self.axis, _fp_expr(e), n,
                tuple((nm, repr(dt), has_v) for nm, dt, has_v in layout))
         return _jitted(key, run, out_shardings=spec)(
             valid, *arrays)
 
     def _dist_project(self, node, childs):
-        from .executor import _col_from_array
-        from .expr import ColumnRef, decimal_type
+        from .expr import ColumnRef, decimal_type, untyped_column
         (c,) = childs
         if not isinstance(c, ShardedRel) or c.replicated:
             return None
@@ -903,15 +906,15 @@ class DistContext:
             #                 shards: the gather boundary, as decimal128
         valid = c.valid
         if isinstance(node, FusedSelect):
-            mask = self._eval(node.predicate, c.table, valid)
+            mask = self._eval(node.predicate, c.table, valid, truth=True)
             valid = valid & mask
         cols = []
         for name, e in node.exprs:
             if isinstance(e, ColumnRef):
                 cols.append(c.table[e.name])
             else:
-                cols.append(_col_from_array(
-                    self._eval(e, c.table, valid)))
+                data, validity = self._eval(e, c.table, valid)
+                cols.append(untyped_column(data, validity))
         part = transfer_part(node, [c.part])
         order = None
         if c.order_keys:

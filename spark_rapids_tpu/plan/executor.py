@@ -92,7 +92,8 @@ from .builder import Plan
 from .metrics import OperatorMetrics, render_profile
 from .nodes import (PAIRING_JOINS, Exchange, Filter, FusedSelect,
                     HashAggregate, HashJoin, Limit, PlanNode,
-                    PlanValidationError, Project, Scan, Sort, TopK, Union)
+                    PlanValidationError, Project, Scan, Sort, TopK, Union,
+                    nullable_sides)
 from .expr import ColumnRef
 from ..utils.tracing import bracket, span, text as _span_text
 
@@ -131,12 +132,21 @@ def _op_span(node: PlanNode, idx: int, tier: str = "device"):
                    label=_span_text(node.label), tier=tier, **how)
 
 
-def _say_compaction(sp, m: OperatorMetrics, childs, out) -> None:
+def _say_op(sp, m: OperatorMetrics, node, childs, out) -> None:
     """On the `plan.op` span of an eager `Filter` / `FusedSelect`: how the
-    rows of its input moved into `out` (`m.compact`)."""
+    rows of its input moved into `out` (`m.compact`); on those and a
+    `Project`'s, how many of the columns its expressions read can hold a
+    null (`nullable_inputs`: whether a validity mask is there, no read)."""
     if m.compact:
         sp.set_metadata(compact=m.compact, rows_in=childs[0].num_rows,
                         rows_out=out.num_rows)
+    if isinstance(node, (Filter, Project, FusedSelect)) \
+            and isinstance(childs[0], Table):
+        from .optimizer import _node_exprs
+        refs = frozenset().union(*(e.references()
+                                   for e in _node_exprs(node)))
+        sp.set_metadata(nullable_inputs=sum(
+            childs[0][n].validity is not None for n in refs))
 
 
 _DECIMAL_OVERFLOW = -1      # key of `_run_capped`'s counts, see there
@@ -157,10 +167,10 @@ def _scope_name(idx: int, node: PlanNode) -> str:
 
 
 def _null_row(table: Table) -> Table:
-    """One row of nulls in `table`'s schema: what a `left_outer` join puts
-    in an EMPTY right side's place. Its null key matches nothing, so every
-    left row comes out null-extended, and the gathers of the right side's
-    columns have a row to read (a gather from no rows has none)."""
+    """One row of nulls in `table`'s schema: what an outer join's gathers
+    read in an EMPTY null-supplying side's place (a gather from no rows
+    has none); as a join side its null key matches nothing, so every row
+    of the other side comes out null-extended."""
     return Table([Column.from_pylist([None], c.dtype)
                   for c in table.columns], names=list(table.names))
 
@@ -297,23 +307,6 @@ def _table_to_cpu(t: Table, dev) -> Table:
     if dev is None:
         return t
     return Table([col_cpu(c) for c in t.columns], names=list(t.names))
-
-
-def _np_dtype_to_dt(np_dt) -> dtypes.DType:
-    m = {"b": dtypes.BOOL, "i1": dtypes.INT8, "i2": dtypes.INT16,
-         "i4": dtypes.INT32, "i8": dtypes.INT64,
-         "f4": dtypes.FLOAT32, "f8": dtypes.FLOAT64}
-    np_dt = np.dtype(np_dt)
-    key = "b" if np_dt.kind == "b" else f"{np_dt.kind}{np_dt.itemsize}"
-    if key not in m:
-        raise PlanValidationError(
-            f"expression produced unsupported dtype {np_dt}")
-    return m[key]
-
-
-def _col_from_array(arr) -> Column:
-    dt = _np_dtype_to_dt(arr.dtype)
-    return Column(dtype=dt, length=int(arr.shape[0]), data=arr)
 
 
 def _input_has_floats(t) -> bool:
@@ -638,6 +631,9 @@ class PlanResult:
         self.outer_joins = 0          # the request's `left_outer` joins and
         self.outer_unmatched_rows = 0  # the left rows they put out
         #                               null-extended (no match, a null key)
+        self.full_joins = 0           # its `full_outer` joins, the left
+        self.full_unmatched_rows = 0  # rows they put out null-extended and
+        self.full_unmatched_right_rows = 0  # the right rows
         self.lookup_joins = 0         # eager tier: joins that took the
         self.lookup_compares = 0      # small-side path, and small rows x
         #                               large rows over them (ops/join.py)
@@ -704,18 +700,21 @@ class _CappedRel:
     `unique`, on a sort join's output, the scalar that says which tail the
     join took (ops/join.py:inner_join_capped_tail); `expanded`, on an inner
     or outer join's, what its expansion touched
-    (ops/join.py:expansion_slots); `unmatched`, on a `left_outer` join's,
-    its null-extended rows (a device scalar)."""
+    (ops/join.py:expansion_slots); `unmatched`, on an outer join's, the
+    left rows it put out null-extended, and `unmatched_right`, on a
+    `full_outer` join's, the right rows (device scalars)."""
 
-    __slots__ = ("table", "alive", "unique", "expanded", "unmatched")
+    __slots__ = ("table", "alive", "unique", "expanded", "unmatched",
+                 "unmatched_right")
 
     def __init__(self, table: Table, alive: jnp.ndarray, unique=None,
-                 expanded=None, unmatched=None):
+                 expanded=None, unmatched=None, unmatched_right=None):
         self.table = table
         self.alive = alive
         self.unique = unique
         self.expanded = expanded
         self.unmatched = unmatched
+        self.unmatched_right = unmatched_right
 
 
 class PlanExecutor:
@@ -874,6 +873,10 @@ class PlanExecutor:
                             compact_position_rows=res.compact_position_rows,
                             outer_joins=res.outer_joins,
                             outer_unmatched_rows=res.outer_unmatched_rows,
+                            full_joins=res.full_joins,
+                            full_unmatched_rows=res.full_unmatched_rows,
+                            full_unmatched_right_rows=(
+                                res.full_unmatched_right_rows),
                             gather_slots=res.gather_slots,
                             cap_slots=res.cap_slots,
                             expand_slots=res.expand_slots,
@@ -931,16 +934,22 @@ class PlanExecutor:
 
     @staticmethod
     def _count_outer(res: PlanResult) -> None:
-        """`outer_joins`, `outer_unmatched_rows` of a result, from its
-        operators' metrics (a cached result keeps its own)."""
-        if res.cached or res.outer_joins:
+        """`outer_joins`, `outer_unmatched_rows` and the `full_*` counters
+        of a result, from its operators' metrics (a cached result keeps
+        its own)."""
+        if res.cached or res.outer_joins or res.full_joins:
             return
         for node in res.plan.nodes:
             m = res.metrics.get(node.label)
-            if isinstance(node, HashJoin) and node.how == "left_outer" \
-                    and m is not None:
+            if not isinstance(node, HashJoin) or m is None:
+                continue
+            if node.how == "left_outer":
                 res.outer_joins += 1
                 res.outer_unmatched_rows += int(m.unmatched_rows)
+            elif node.how == "full_outer":
+                res.full_joins += 1
+                res.full_unmatched_rows += int(m.unmatched_rows)
+                res.full_unmatched_right_rows += int(m.unmatched_right_rows)
 
     def _execute_request(self, plan, inputs, tier, placement,
                          nulled=()) -> PlanResult:
@@ -1143,7 +1152,7 @@ class PlanExecutor:
                 continue        # an execution has counted it since
             with span("ops.host_sync", site="optimize.counted_filter"):
                 out["filter:" + fp] = int(
-                    jnp.sum(node.predicate.evaluate(t)))
+                    jnp.sum(node.predicate.truth(t)))
         return out
 
     def _optimized(self, plan, inputs, bound):
@@ -1564,7 +1573,7 @@ class PlanExecutor:
                                         metrics)
                                 out = self._exec_eager_node(
                                     node, child_tables, inputs, schemas, m)
-                                _say_compaction(osp, m, child_tables, out)
+                                _say_op(osp, m, node, child_tables, out)
                             # blocked inside the span, so that the span
                             # holds the operator's device work (an async
                             # exchange in flight stays unblocked: that
@@ -1742,7 +1751,7 @@ class PlanExecutor:
                     self._faultinj_point(n)
                     out = self._exec_eager_node(n, childs, host_inputs,
                                                 schemas, m)
-                    _say_compaction(osp, m, childs, out)
+                    _say_op(osp, m, n, childs, out)
                     with span("plan.wait", site="host_op"):
                         jax.block_until_ready(
                             [c.data for c in out.columns])
@@ -1853,7 +1862,7 @@ class PlanExecutor:
                     with _op_span(node, i, "degraded") as osp:
                         out = self._exec_eager_node(node, childs, cpu_inputs,
                                                     schemas, m)
-                        _say_compaction(osp, m, childs, out)
+                        _say_op(osp, m, node, childs, out)
                         if self.block_per_op:
                             with span("plan.wait", site="degraded_op"):
                                 jax.block_until_ready(
@@ -1968,7 +1977,7 @@ class PlanExecutor:
                     out = (fn(t) if fn is not None else
                            self._exec_eager_node(node, [t], inputs,
                                                  schemas, m))
-                    _say_compaction(osp, m, [t], out)
+                    _say_op(osp, m, node, [t], out)
                 break
             except _fault_surface() as err:
                 if self._handle_fault(err, node.label, attempt, m):
@@ -2183,15 +2192,14 @@ class PlanExecutor:
                     m: OperatorMetrics) -> Table:
         """The eager tiers' join, in an `ops.join` span that holds its
         device work (the maps, the output columns' gathers and, where the
-        executor blocks per operator, the wait for them). `inner` and
-        `left_outer` share the gathers: the outer join's right map holds a
-        -1 at every left row without a match, which `take` turns into a
-        null row, and the join's own count says so (no read for it)."""
+        executor blocks per operator, the wait for them). The pairing
+        joins share the gathers: an outer join's map holds a -1 at every
+        row the other side has no match for, which `take` turns into a
+        null row, and the join's own counts say which maps hold one (no
+        read for it)."""
         ops = _ops()
-        outer = node.how == "left_outer"
-        rows_right = rt.num_rows
-        if outer and not rows_right:
-            rt = _null_row(rt)      # a null key matches nothing
+        outer_left, outer_right = nullable_sides(node.how)
+        rows_left, rows_right = lt.num_rows, rt.num_rows
         lkeys = [lt[k] for k in node.left_keys]
         rkeys = [rt[k] for k in node.right_keys]
         from ..ops import join_pallas
@@ -2199,11 +2207,15 @@ class PlanExecutor:
             "hash_join",
             join_pallas.make_signature(lkeys, rkeys, node.how, "eager"), m)
         from ..ops.join_lookup import KERNEL_LABEL, lookup_counts
-        with span("ops.join", how=node.how, rows_left=lt.num_rows,
+        with span("ops.join", how=node.how, rows_left=rows_left,
                   rows_right=rows_right) as sp:
-            matched = unmatched = 0
+            matched = unmatched = unmatched_right = 0
+            carried = None
             with lookup_counts() as looked:
-                if outer:
+                if node.how == "full_outer":
+                    lm, rm, matched, unmatched, unmatched_right = \
+                        ops.full_join_counted(lkeys, rkeys)
+                elif outer_right:
                     lm, rm, matched, unmatched = ops.left_join_counted(
                         lkeys, rkeys)
                 elif node.how != "inner":
@@ -2213,34 +2225,71 @@ class PlanExecutor:
                 elif not choice.fallback:
                     lm, rm = choice.fn(lkeys, rkeys)
                 else:
-                    lm, rm = ops.inner_join(lkeys, rkeys)
+                    # (where many rows pass a small right side the left
+                    # side's columns ride the survivors' one sort; a key
+                    # column's mask need not: no pair holds a null key)
+                    lm, rm, carried = ops.inner_join_carrying(
+                        lkeys, rkeys,
+                        [c.with_validity(None) if n in node.left_keys else c
+                         for n, c in zip(lt.names, lt.columns)])
             if looked:      # the small-side path answered (ops/join.py)
                 m.kernel = KERNEL_LABEL
                 m.lookup_compares = sum(a * b for a, b in looked)
                 # its wall is no timing of a registered hash_join kernel
                 m.__dict__.pop("_kernel_sig", None)
             if node.how in PAIRING_JOINS:
-                if not outer:
+                if node.how == "inner":
                     matched = lm.length
-                out = Table(
-                    list(ops.take_table(lt, lm.data,
-                                        _has_negative=False).columns) +
-                    list(ops.take_table(rt, rm.data,
-                                        _has_negative=outer).columns),
-                    names=list(lt.names) + list(rt.names))
+                # an outer join's empty side joins nothing: every index
+                # into it is a -1, and a row of nulls is what the gather
+                # reads (a gather from no rows has none)
+                lsrc = lt if rows_left or not outer_left else _null_row(lt)
+                rsrc = rt if rows_right or not outer_right else _null_row(rt)
+                if carried is not None:
+                    out = self._carried_join(node, lt, rt, carried, rm)
+                else:
+                    out = Table(
+                        list(ops.take_table(
+                            lsrc, lm.data,
+                            _has_negative=unmatched_right > 0).columns) +
+                        list(ops.take_table(
+                            rsrc, rm.data,
+                            _has_negative=outer_right and unmatched > 0
+                        ).columns),
+                        names=list(lt.names) + list(rt.names))
             else:
                 out = ops.take_table(lt, keep.data, _has_negative=False)
                 if node.how == "left_semi":
                     matched = keep.length
                 else:
                     unmatched = keep.length
-            m.unmatched_rows = unmatched if outer else 0
+            m.unmatched_rows = unmatched if outer_right else 0
+            m.unmatched_right_rows = unmatched_right
             sp.set_metadata(matched=matched, unmatched=unmatched,
                             kernel=_span_text(m.kernel))
+            if outer_left:
+                sp.set_metadata(unmatched_right=unmatched_right)
             if self.block_per_op:
                 with span("plan.wait", site="join"):
                     jax.block_until_ready([c.data for c in out.columns])
         return out
+
+    @staticmethod
+    def _carried_join(node: HashJoin, lt: Table, rt: Table, carried,
+                      rm) -> Table:
+        """An inner join's output where the join moved the left side's
+        columns itself (`ops.inner_join_carrying`): those, and the right
+        side's columns, a key column as the left key it equals row for
+        row (an inner join's pairs hold no null key, and the path takes
+        keys of one type), any other gathered by the right map."""
+        ops = _ops()
+        left = dict(zip(lt.names, carried))
+        key_of = dict(zip(node.right_keys, node.left_keys))
+        right = [left[key_of[n]].with_validity(None) if n in key_of
+                 else ops.take(rt[n], rm.data, _has_negative=False)
+                 for n in rt.names]
+        return Table(list(carried) + right,
+                     names=list(lt.names) + list(rt.names))
 
     @staticmethod
     def _compact(t: Table, mask, m: OperatorMetrics) -> Table:
@@ -2267,7 +2316,7 @@ class PlanExecutor:
             return node.typed(t)
         if isinstance(node, Filter):
             (t,) = childs
-            return self._compact(t, node.predicate.evaluate(t), m)
+            return self._compact(t, node.predicate.truth(t), m)
         if isinstance(node, FusedSelect):
             # fused Filter+Project: gather ONLY the projection-referenced
             # columns through the mask, then project — one pass, instead of
@@ -2288,7 +2337,7 @@ class PlanExecutor:
                 ft = choice.fn(t, node.predicate, needed)
             else:
                 ft = self._compact(t.select(needed),
-                                   node.predicate.evaluate(t), m)
+                                   node.predicate.truth(t), m)
             return self._project(ft, node)
         if isinstance(node, Project):
             (t,) = childs
@@ -2339,22 +2388,11 @@ class PlanExecutor:
 
     def _project(self, t: Table, node: Project,
                  alive: Optional[jnp.ndarray] = None) -> Table:
-        from .expr import decimal_type
-        cols = []
-        for name, e in node.exprs:
-            if isinstance(e, ColumnRef):
-                cols.append(t[e.name])      # preserve dtype + validity
-            elif decimal_type(e, lambda n: t[n].dtype) is not None:
-                # Spark's result type, validity, overflow rows null
-                cols.append(e.column(t, alive))
-            else:
-                v = e.evaluate(t, alive)
-                if getattr(v, "ndim", 1) == 0:
-                    # bare scalar aggregate (or literal fold): broadcast to
-                    # the relation's length, as the Expr contract promises
-                    v = jnp.broadcast_to(v, (t.num_rows,))
-                cols.append(_col_from_array(v))
-        return Table(cols, names=[n for n, _ in node.exprs])
+        # a bare reference keeps its column (type and validity); any other
+        # expression is its value, Spark's result type and its validity
+        return Table([t[e.name] if isinstance(e, ColumnRef)
+                      else e.column(t, alive) for _, e in node.exprs],
+                     names=[n for n, _ in node.exprs])
 
     def _global_aggregate(self, t: Table, node: HashAggregate,
                           alive: Optional[jnp.ndarray] = None) -> Table:
@@ -2676,9 +2714,12 @@ class PlanExecutor:
                     bytes_out=bytes_map.get(i, 0),
                     escalations=escal if uses_cap else 0,
                     kernel=kernel)
-                if isinstance(node, HashJoin) and node.how == "left_outer":
+                if isinstance(node, HashJoin) and nullable_sides(node.how)[1]:
                     metrics[node.label].unmatched_rows = int(
                         counts_np[_JOIN_UNIQUE - 2 * i][1])
+                if isinstance(node, HashJoin) and node.how == "full_outer":
+                    metrics[node.label].unmatched_right_rows = int(
+                        counts_np[len(plan.nodes) + i][0])
                 if isinstance(node, Scan) and node.source in scan_io:
                     io = scan_io[node.source]
                     mm = metrics[node.label]
@@ -2706,7 +2747,11 @@ class PlanExecutor:
                 if isinstance(node, HashJoin) \
                         and node.how in PAIRING_JOINS:
                     cap = self._node_cap(final_caps, "row_cap", i)
-                    res.gather_slots += live_slots(counts_np[i][1], cap)
+                    # (a full join's right rows without a match lie past
+                    # the capped frame: concatenated, not gathered)
+                    res.gather_slots += live_slots(
+                        counts_np[i][1]
+                        - metrics[node.label].unmatched_right_rows, cap)
                     res.cap_slots += cap
         return res
 
@@ -2777,6 +2822,11 @@ class PlanExecutor:
                         # null-extended rows beside it
                         counts[_JOIN_UNIQUE - 2 * i] = (jnp.int64(0),
                                                         rel.unmatched)
+                    if rel.unmatched_right is not None:
+                        # past every operator's index: a `full_outer`
+                        # join's null-extended right rows
+                        counts[len(plan.nodes) + i] = (rel.unmatched_right,
+                                                       jnp.int64(0))
                     if rel.expanded is not None:
                         counts[_JOIN_EXPAND - 2 * i] = rel.expanded
                     bytes_map[i] = operand_nbytes(rel.table)
@@ -2814,7 +2864,7 @@ class PlanExecutor:
             (c,) = childs
             # predicate as a mask AND — the jit tier's filter idiom: no
             # compaction, dead rows stay and stay dead
-            mask = node.predicate.evaluate(c.table, c.alive)
+            mask = node.predicate.truth(c.table, c.alive)
             return _CappedRel(c.table, c.alive & mask), None
         if isinstance(node, FusedSelect):
             # filter-then-project over the padded frame: the predicate ANDs
@@ -2827,7 +2877,7 @@ class PlanExecutor:
             pick("fused_select",
                  select_pallas.make_signature(c.table, node.predicate,
                                               node.exprs, "capped"))
-            mask = node.predicate.evaluate(c.table, c.alive)
+            mask = node.predicate.truth(c.table, c.alive)
             alive = c.alive & mask
             return _CappedRel(self._project(c.table, node, alive),
                               alive), None
@@ -2837,8 +2887,13 @@ class PlanExecutor:
                               c.alive), None
         if isinstance(node, HashJoin):
             l, r = childs
-            if node.how == "left_outer" and not r.table.num_rows:
+            # an outer join's empty side: one dead row of nulls, so that a
+            # gather has a row to read
+            outer_left, outer_right = nullable_sides(node.how)
+            if outer_right and not r.table.num_rows:
                 r = _CappedRel(_null_row(r.table), jnp.zeros((1,), bool))
+            if outer_left and not l.table.num_rows:
+                l = _CappedRel(_null_row(l.table), jnp.zeros((1,), bool))
             lkeys = [l.table[k] for k in node.left_keys]
             rkeys = [r.table[k] for k in node.right_keys]
             from ..ops import join_pallas
@@ -2848,7 +2903,7 @@ class PlanExecutor:
             if node.how in PAIRING_JOINS:
                 row_cap = self._node_cap(caps, "row_cap", idx)
                 unique = rvalid = None
-                if node.how == "left_outer":
+                if outer_right:
                     lm, rm, rvalid, valid, ovf = ops.left_join_capped(
                         lkeys, rkeys, row_cap=row_cap, lalive=l.alive,
                         ralive=r.alive)
@@ -2885,8 +2940,22 @@ class PlanExecutor:
                           list(r.table.names))
                 unmatched = None if rvalid is None else jnp.sum(
                     valid & ~rvalid, dtype=jnp.int64)
-                return _CappedRel(t, valid, unique, expanded,
-                                  unmatched), ovf
+                unmatched_right = None
+                if outer_left:
+                    # the swapped anti pass: the right rows no left row
+                    # matched follow the left join's frame as they lie,
+                    # alive where unmatched, the left columns null
+                    lonely = r.alive & ~ops.semi_join_mask(
+                        rkeys, lkeys, lalive=r.alive, ralive=l.alive)
+                    nobody = jnp.full((r.table.num_rows,), -1, jnp.int32)
+                    t = ops.concat_tables([t, Table(
+                        [ops.take(c, nobody, _has_negative=True)
+                         for c in l.table.columns]
+                        + list(r.table.columns), names=list(t.names))])
+                    valid = jnp.concatenate([valid, lonely])
+                    unmatched_right = jnp.sum(lonely, dtype=jnp.int64)
+                return _CappedRel(t, valid, unique, expanded, unmatched,
+                                  unmatched_right), ovf
             mask = ops.semi_join_mask(lkeys, rkeys, lalive=l.alive,
                                       ralive=r.alive)
             alive = (l.alive & mask if node.how == "left_semi"

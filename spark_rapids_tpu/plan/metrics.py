@@ -57,9 +57,11 @@ class OperatorMetrics:
     # "none" (every row stayed); "" where nothing is compacted (another
     # operator, the capped tier's mask)
     compact: str = ""
-    # left rows a `left_outer` join put out null-extended (no match, or a
-    # null key), else 0
+    # left rows a `left_outer` or `full_outer` join put out null-extended
+    # (no match, or a null key), else 0
     unmatched_rows: int = 0
+    # right rows a `full_outer` join put out null-extended, else 0
+    unmatched_right_rows: int = 0
     # streaming-scan IO metrics (Scan nodes bound to a parquet source;
     # docs/io.md). Decode wall is host-side bitstream decode; overlap is
     # the time decode of chunk N+1 ran concurrently with executing chunk N

@@ -21,12 +21,22 @@ from typing import Optional, Tuple
 
 from .expr import Expr
 
-JOIN_TYPES = ("inner", "left_semi", "left_anti", "left_outer")
+JOIN_TYPES = ("inner", "left_semi", "left_anti", "left_outer", "full_outer")
 # the join types whose output is left ++ right columns (and whose row count
 # a match count decides): everything that asks "does this join expand"
 # reads this, so that no walk takes an outer join for a filter of its left
 # side, nor for an inner join
-PAIRING_JOINS = ("inner", "left_outer")
+PAIRING_JOINS = ("inner", "left_outer", "full_outer")
+# the pairing joins that keep a side's rows without a match, null-extended
+# on the other side: the sides whose columns are nullable after the join
+# are the OTHER ones (`nullable_sides`)
+OUTER_JOINS = ("left_outer", "full_outer")
+
+
+def nullable_sides(how: str):
+    """(left, right): which side's columns a join of type `how` can put
+    out null though its input held none."""
+    return how == "full_outer", how in OUTER_JOINS
 AGG_OPS = ("sum", "count", "min", "max", "mean", "size")   # ops.aggregate.AGG_OPS
 
 _ids = itertools.count()
@@ -251,13 +261,16 @@ class FusedSelect(PlanNode):
 
 @dataclasses.dataclass(frozen=True, eq=False)
 class HashJoin(PlanNode):
-    """Equi-join on key column lists. `inner` and `left_outer` output
-    left++right columns (`left_outer` keeps every left row: one without a
-    match, a null key included, comes out once with the right side's
-    columns null, so those columns are nullable after the join); semi/anti
+    """Equi-join on key column lists. `inner`, `left_outer` and
+    `full_outer` output left++right columns (`left_outer` keeps every left
+    row: one without a match, a null key included, comes out once with the
+    right side's columns null, so those columns are nullable after the
+    join; `full_outer` keeps every right row the same way too, after the
+    left join's rows, so both sides' columns are nullable); semi/anti
     output the left columns only (the right side is a filter). `row_cap`,
     when set, overrides the executor's shared row cap for this node in the
-    capped tier."""
+    capped tier (a `full_outer` join's frame is that cap plus the right
+    side's rows)."""
     left: PlanNode
     right: PlanNode
     left_keys: Tuple[str, ...]
@@ -304,7 +317,9 @@ class HashJoin(PlanNode):
 @dataclasses.dataclass(frozen=True, eq=False)
 class HashAggregate(PlanNode):
     """Group by `keys`, computing `aggs` [(column, op, out_name)]; empty
-    `keys` is a global (one-row) aggregate. Output schema: keys ++ out
+    `keys` is a global (one-row) aggregate; keys and no aggregate is a
+    DISTINCT over the keys (Spark's `HashAggregate(keys, functions=[])`:
+    NULL keys group together). Output schema: keys ++ out
     names. `key_cap` overrides the executor's shared key cap."""
     child: PlanNode
     keys: Tuple[str, ...]
@@ -316,8 +331,8 @@ class HashAggregate(PlanNode):
         object.__setattr__(self, "keys", tuple(self.keys))
         object.__setattr__(self, "aggs", tuple(
             (c, o, n) for c, o, n in self.aggs))
-        _require(len(self.aggs) > 0,
-                 f"{self.label}: at least one aggregation is required")
+        _require(len(self.aggs) > 0 or len(self.keys) > 0,
+                 f"{self.label}: a key or an aggregation is required")
         for c, o, n in self.aggs:
             _require(o in AGG_OPS,
                      f"{self.label}: unknown aggregation {o!r} (have "
@@ -348,7 +363,7 @@ class HashAggregate(PlanNode):
 
     def describe(self):
         aggs = ", ".join(f"{o}({c}) AS {n}" for c, o, n in self.aggs)
-        return f"keys=[{', '.join(self.keys)}] {aggs}"
+        return f"keys=[{', '.join(self.keys)}] {aggs or 'distinct'}"
 
 
 @dataclasses.dataclass(frozen=True, eq=False)

@@ -63,9 +63,10 @@ import hashlib
 from typing import Dict, List, Optional, Tuple
 
 from .builder import Plan, _toposort
-from .expr import (BinOp, ColumnRef, Expr, Literal, ScalarAgg, UnaryOp,
-                   col, fold, has_scalar_agg, substitute)
-from .nodes import (PAIRING_JOINS, Exchange, Filter, FusedSelect,
+from .expr import (BinOp, Coalesce, ColumnRef, Expr, IsNull, Literal,
+                   ScalarAgg, UnaryOp, When, col, fold, has_scalar_agg,
+                   null_aware, substitute)
+from .nodes import (OUTER_JOINS, PAIRING_JOINS, Exchange, Filter, FusedSelect,
                     HashAggregate, HashJoin,
                     Limit, PlanNode, PlanValidationError, Project, Scan,
                     Sort, TopK, Union)
@@ -100,6 +101,11 @@ def _fp_expr(e: Expr) -> Tuple:
         return ("un", e.op, _fp_expr(e.child))
     if isinstance(e, ScalarAgg):
         return ("agg", e.op, _fp_expr(e.child))
+    if isinstance(e, IsNull):
+        return ("isnull", e.negate, _fp_expr(e.child))
+    if isinstance(e, (When, Coalesce)):
+        return (type(e).__name__.lower(),
+                *(_fp_expr(c) for c in e.children()))
     return ("expr", repr(e))
 
 
@@ -564,6 +570,8 @@ class _Estimator:
         if isinstance(node, Union):
             return sum(kids), src, runs
         if isinstance(node, HashJoin):
+            if node.how == "full_outer":
+                return sum(kids), src, runs     # each side's rows at least
             if node.how in PAIRING_JOINS:
                 # (an outer join holds its left rows at least: the same)
                 return max(kids), src, runs
@@ -694,17 +702,23 @@ def _rule_predicate_pushdown(root, ctx):
             ls = ctx.schemas.of(child.left)
             rs = ctx.schemas.of(child.right)
             if child.how == "inner" and rs is not None and refs <= set(rs):
-                # NOT below a `left_outer` join: over its output the
-                # predicate drops the null-extended rows (or keeps them
-                # alone, `is_null`); below its null-supplying side it
-                # would turn the dropped matches INTO null-extended rows
+                # NOT below an outer join's null-supplying side (the right
+                # of `left_outer`, either of `full_outer`): over the
+                # join's output the predicate drops the null-extended rows
+                # (or keeps them alone: `is_null`, a `when`, a `coalesce`
+                # can be TRUE over a null); below that side it would turn
+                # the dropped matches INTO null-extended rows. An inner
+                # join supplies no nulls, so a null-aware predicate passes
+                # below it like any other
                 hits[0] += 1
                 return dataclasses.replace(
                     child, right=Filter(child.right, p))
-            if ls is not None and refs <= set(ls):
-                # inner, left_outer: left-only columns (an outer join
-                # keeps or drops a left row's outputs together); semi/
-                # anti: output IS the left
+            if child.how != "full_outer" \
+                    and ls is not None and refs <= set(ls):
+                # inner, left_outer: left-only columns (a left outer join
+                # keeps or drops a left row's outputs together, and never
+                # nulls them, so a null-aware predicate commutes too);
+                # semi/anti: output IS the left
                 # schema, so a row filter always commutes to the left side
                 hits[0] += 1
                 return dataclasses.replace(child, left=Filter(child.left, p))
@@ -788,7 +802,9 @@ def _rule_build_side(root, ctx):
         node2 = (_with_children(n, kids)
                  if any(k is not c for k, c in zip(kids, n.children)) else n)
         # inner alone: a `left_outer` join with its sides exchanged is a
-        # right outer join, another answer
+        # right outer join, another answer, and a `full_outer` join's
+        # output order (the left join's rows, then the lonely right rows)
+        # and column order are its sides'
         if (isinstance(n, HashJoin) and n.how == "inner"
                 and id(n) in safe):
             le = ctx.est.of(n.left)
@@ -898,7 +914,8 @@ def _rule_column_pruning(root, ctx):
                     push(n, 0, set(req) | set(n.left_keys))
                     push(n, 1, set(n.right_keys))
             elif isinstance(n, HashAggregate):
-                kept = [a for a in n.aggs if a[2] in req] or [n.aggs[0]]
+                # (a DISTINCT has no aggregate to keep)
+                kept = [a for a in n.aggs if a[2] in req] or list(n.aggs[:1])
                 r = set(n.keys) | {c for c, o, _ in kept if o != "size"}
                 if not r:                 # global size-only aggregate
                     r = {schemas[id(n.children[0])][0]}
@@ -968,7 +985,7 @@ def _rule_column_pruning(root, ctx):
                 note_pruned(len(n.exprs) - len(kept), ctx.est.of(n))
                 node2 = dataclasses.replace(node2, exprs=kept)
         elif isinstance(n, HashAggregate):
-            kept = tuple(a for a in n.aggs if a[2] in req) or (n.aggs[0],)
+            kept = tuple(a for a in n.aggs if a[2] in req) or n.aggs[:1]
             if len(kept) < len(n.aggs):
                 note_pruned(len(n.aggs) - len(kept), ctx.est.of(n))
                 node2 = dataclasses.replace(node2, aggs=kept)
@@ -1031,17 +1048,34 @@ _RULES = (
 
 def mesh_local_reason(nodes) -> Optional[Tuple[str, str]]:
     """(label, why) of the first node that keeps a WHOLE plan on one chip
-    although its executor has a mesh, or None. A `left_outer` join has a
-    shard-local kernel (parallel/relational.py) and no lowering in
-    plan/distributed.py's walk; a walk that met one half way would run it
-    through the one-chip fallback above a gather, so the plan is not put on
-    the mesh at all, and the optimize report says so under
-    `<label>/mesh`."""
+    although its executor has a mesh, or None. An outer join has no
+    lowering in plan/distributed.py's walk (`left_outer` has a shard-local
+    kernel in parallel/relational.py, `full_outer` none); a walk that met
+    one half way would run it through the one-chip fallback above a
+    gather, so the plan is not put on the mesh at all, and the optimize
+    report says so under `<label>/mesh`. A null-aware expression
+    (`is_null`, `when`, `coalesce`) keeps its plan local the same way: the
+    walk evaluates value and validity of every other expression
+    (`_eval`), and these have not run over shards."""
     for n in nodes:
-        if isinstance(n, HashJoin) and n.how == "left_outer":
-            return n.label, ("local (left_outer has no distributed "
+        if isinstance(n, HashJoin) and n.how in OUTER_JOINS:
+            return n.label, (f"local ({n.how} has no distributed "
                              "lowering: the whole plan runs on one chip)")
+        if any(null_aware(e) for e in _node_exprs(n)):
+            return n.label, ("local (a null-aware expression has no "
+                             "distributed lowering: the whole plan runs "
+                             "on one chip)")
     return None
+
+
+def _node_exprs(n: PlanNode) -> Tuple[Expr, ...]:
+    """The expressions a node evaluates."""
+    out = ()
+    if isinstance(n, (Filter, FusedSelect)):
+        out += (n.predicate,)
+    if isinstance(n, (Project, FusedSelect)):
+        out += tuple(e for _, e in n.exprs)
+    return out
 
 
 def _statically_distributable(n: PlanNode, float_inputs: bool) -> bool:
@@ -1060,6 +1094,9 @@ def _statically_distributable(n: PlanNode, float_inputs: bool) -> bool:
             return False
         if float_inputs:
             return False
+        if not n.aggs:
+            return False    # a DISTINCT: the fused two-phase program
+            #                 merges partial aggregates, and there are none
     return True
 
 

@@ -527,3 +527,72 @@ def test_compaction_sort_compiles_for_v5e(one_chip, no_persistent_cache):
     mem = compiled.memory_analysis()
     assert mem.output_size_in_bytes < 20 * Q13_KEPT + (1 << 20)
     assert mem.temp_size_in_bytes < 21 * Q13_ORDERS, mem.temp_size_in_bytes
+
+
+Q97_STORE = 36_000_000      # `q97.batch`: one rank's store_sales rows
+
+
+def test_lookup_match_compiles_for_v5e(one_chip, no_persistent_cache):
+    """The second pass of a broadcast join in which many rows pass
+    (ops/join_lookup.py:_match, PR 43) at `q97.batch`'s store side: ONE
+    loop over the frame, the small side's two sorts (the packing and the
+    distinctness check) and no sort of the frame; beside the arguments and
+    the (n,) int32 result it needs the frame's key words and the loop's
+    carry, never a frame's sort buffers."""
+    from spark_rapids_tpu.ops import join_lookup
+
+    def shape(n, dtype):
+        return jax.ShapeDtypeStruct((n,), dtype, sharding=one_chip)
+    small = join_lookup.LOOKUP_SMALL
+    compiled = join_lookup._match.lower(
+        [shape(small, jnp.int64)], shape(small, jnp.bool_),
+        [shape(Q97_STORE, jnp.int64)], shape(Q97_STORE, jnp.bool_)).compile()
+    text = compiled.as_text()
+    assert len(_op_names(text, "while")) == 1
+    sorted_rows = [int(re.search(r"\[(\d+)", line.split(" sort(")[0]).group(1))
+                   for line in text.splitlines() if " sort(" in line]
+    assert sorted_rows and set(sorted_rows) == {small}
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < (4 * 2 + 4 + 2) * (Q97_STORE + (1 << 20)), temp
+
+
+def test_eager_full_join_on_a_two_column_key_compiles_for_v5e(
+        one_chip, no_persistent_cache):
+    """The eager `full_outer` join's ONE kernel
+    (`ops/join.py:_full_join_kernel`, what `full_join_counted` runs: both
+    sides' answers off one union sort) over a two-column NULLABLE int64
+    key at 65,536 + 32,768 rows. Nulls match nothing, so the keys bring no
+    null-rank operand: two int64 key operands, the row number as the third
+    key of a sort that is NOT a stable one, one flag payload; then the
+    routing sort and the matchable right rows' packing, neither stable. A
+    sort program's compile time follows its keys and whether it is stable,
+    not its rows: about 100 s here for these three sorts, where the left
+    pass of the stable form (four key operands, `_join_kernel`) took 220 s
+    and the swapped anti pass as much again (`q97.batch` runs it at
+    6,597,944 + 3,350,369 rows: 164 s here where the two passes took over
+    1,000; PERF.md section 6, PR 43)."""
+    from spark_rapids_tpu.ops import join
+    nl, nr = 1 << 16, 1 << 15
+
+    def shape(n, dtype):
+        return jax.ShapeDtypeStruct((n,), dtype, sharding=one_chip)
+    lkeys = [Column(dtype=dtypes.INT64, length=8,
+                    data=jnp.zeros((8,), jnp.int64),
+                    validity=jnp.ones((8,), bool)) for _ in range(2)]
+    per_key = jax.eval_shape(
+        lambda: join._union_operands(lkeys, lkeys, False, None, None,
+                                     ranked=False)[0])
+    assert [str(o.dtype) for o in per_key] == ["int64", "int64"]
+    operands = tuple(shape(nl + nr, o.dtype) for o in per_key)
+    compiled = join._full_join_kernel.lower(
+        operands, shape(nl, jnp.bool_), shape(nr, jnp.bool_),
+        n_ops=len(operands), nl=nl).compile()
+    sorts = [line for line in compiled.as_text().splitlines()
+             if " sort(" in line]
+    # the union sort, the routing sort, the matchable right rows' packing
+    assert len(sorts) == 3
+    assert not any("is_stable=true" in line for line in sorts)
+    # counts, lo, rorder and the right rows' flag: three int32 planes, the
+    # third of the frame, and a byte a right row
+    out = compiled.memory_analysis().output_size_in_bytes
+    assert out < 4 * (2 * nl + nl + nr) + nr + (1 << 16), out

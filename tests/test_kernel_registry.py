@@ -441,6 +441,20 @@ def test_hash_join_signature_declines():
         join_pallas.make_signature(i, big, "inner", "eager"))
     assert join_pallas._supports(
         join_pallas.make_signature(big, i, "inner", "capped"))
+    # an eager probe side of a fact table's size is the small-side path's
+    # (ops/join.py); the capped entry has no row count to branch on
+    fact = [Column.from_numpy(np.zeros(join_pallas.EAGER_MAX_PROBE,
+                                       np.int64))]
+    under = [Column.from_numpy(np.zeros(join_pallas.EAGER_MAX_PROBE - 1,
+                                        np.int64))]
+    assert not join_pallas._supports(
+        join_pallas.make_signature(fact, i, "inner", "eager"))
+    assert join_pallas._supports(
+        join_pallas.make_signature(under, i, "inner", "eager"))
+    assert join_pallas._supports(
+        join_pallas.make_signature(fact, i, "inner", "capped"))
+    from spark_rapids_tpu.ops import join_lookup
+    assert join_lookup.lookup_side(fact, i, False) == "right"
 
 
 # ---- executor integration ---------------------------------------------------

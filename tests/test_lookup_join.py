@@ -3,7 +3,10 @@ one side of at most LOOKUP_SMALL rows and the other of at least
 LOOKUP_LARGE, membership by comparison, the positions of the survivors,
 then the sort join over the small side and the survivors. Every case holds
 the public join to the sort join of the whole sides: the same gather maps,
-pair for pair IN ORDER.
+pair for pair IN ORDER. Since PR 43 the path reads the large side once
+whatever share of it passes (one row in 16, in 5, in 2, all): the
+survivors move the way `compaction_path` says, and where the small side is
+the right one with distinct keys no sort join follows.
 """
 import numpy as np
 import pytest
@@ -80,8 +83,20 @@ def _sides(case: str):
         return [_col(rng.integers(1 << 41, 1 << 42, 100))], [_col(large)]
     if case == "every_row":
         return [_col(np.arange(64))], [_col(rng.integers(0, 64, N))]
-    if case == "one_row_in_16":              # past the share that is kept
-        return [_col(np.arange(4))], [_col(rng.integers(0, 64, N))]
+    if case.startswith("one_row_in_"):       # past the share of "few kept"
+        keys = 64 // int(case[len("one_row_in_"):])
+        return [_col(np.arange(keys))], [_col(rng.integers(0, 64, N))]
+    if case == "many_duplicates":            # one row in 4, each key twice
+        return ([_col(np.repeat(np.arange(16), 2))],
+                [_col(rng.integers(0, 64, N))])
+    if case == "many_nulls":                 # one row in 2 less the nulls
+        return ([_col(np.arange(32), valid=rng.random(32) > 0.2)],
+                [_col(rng.integers(0, 64, N), valid=rng.random(N) > 0.1)])
+    if case == "many_two_columns":           # one pair in 4
+        return ([_col(np.arange(16) % 8, dtypes.INT32),
+                 _col(np.arange(16) // 8)],
+                [_col(rng.integers(0, 8, N), dtypes.INT32),
+                 _col(rng.integers(0, 8, N))])
     if case.startswith("small_"):
         n = int(case[len("small_"):])
         return [_col(np.resize(hits, n))], [_col(large)]
@@ -90,10 +105,13 @@ def _sides(case: str):
 
 CASES = ["int64", "int32", "date32", "decimal64", "high_words_differ",
          "two_columns", "nulls", "nulls_two_columns", "duplicates",
-         "no_match", "every_row", "one_row_in_16", "small_0", "small_1",
-         f"small_{S}"]
-# so many rows pass that the path hands an inner join back to the sort join
-HANDED_BACK = ("every_row", "one_row_in_16")
+         "no_match", "every_row", "one_row_in_16", "one_row_in_5",
+         "one_row_in_2", "many_duplicates", "many_nulls", "many_two_columns",
+         "small_0", "small_1", f"small_{S}"]
+# more rows pass than `few_kept` holds: until PR 43 the path handed such an
+# inner join back to the sort join of the whole sides
+MANY_PASS = ("every_row", "one_row_in_16", "one_row_in_5", "one_row_in_2",
+             "many_duplicates", "many_nulls", "many_two_columns")
 
 
 def _order(case, small_side):
@@ -113,9 +131,34 @@ def test_inner_join_gives_the_sort_joins_pairs_in_order(case, small_side):
     assert np.array_equal(lmap.data, want_l)
     assert np.array_equal(rmap.data, want_r)
     small, large = sorted((lcols[0].length, rcols[0].length))
-    assert took == ([] if case in HANDED_BACK else [(small, large)])
+    assert took == [(small, large)]
     if case not in ("no_match", "small_0"):
         assert total > 0
+    if case in MANY_PASS:
+        assert total * 32 > large
+
+
+@pytest.mark.parametrize("case", MANY_PASS)
+def test_many_rows_pass_a_distinct_right_side_and_no_sort_join_runs(
+        case, monkeypatch):
+    """A fact table against a filtered dimension (the small side on the
+    right, its keys distinct): the match rides the survivors' compaction.
+    Duplicate small keys, or the small side on the left, take the sort
+    join over the survivors, never over the whole large side."""
+    small, large = _sides(case)
+    sorted_rows = []
+    sort_join = J._sort_inner_join
+
+    def counted(lcols, rcols, null_equal):
+        sorted_rows.append(lcols[0].length + rcols[0].length)
+        return sort_join(lcols, rcols, null_equal)
+    monkeypatch.setattr(J, "_sort_inner_join", counted)
+    survivors = jl.member_mask(small, large)[1]
+    J.inner_join(large, small)
+    assert sorted_rows == ([small[0].length + survivors]
+                           if case == "many_duplicates" else [])
+    J.inner_join(small, large)
+    assert sorted_rows[-1] == small[0].length + survivors
 
 
 @pytest.mark.parametrize("how", ["semi", "anti"])
@@ -130,9 +173,9 @@ def test_semi_and_anti_join_give_the_sort_joins_rows(case, small_side, how):
     assert keep.length == want.shape[0]
     assert np.array_equal(keep.data, want)
     # with the large side on the left the mask is the answer whatever
-    # share of the rows it holds
-    handed_back = case in HANDED_BACK and small_side == "left"
-    assert len(took) == (0 if handed_back else 1)
+    # share of the rows it holds; on the right its survivors are what the
+    # sort join runs over
+    assert len(took) == 1
 
 
 def test_null_safe_join_over_keys_without_nulls_takes_the_path():

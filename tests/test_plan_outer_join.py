@@ -252,6 +252,36 @@ def test_a_right_side_predicate_above_the_join_stays_above_it(tier):
     assert _sorted(wrong) != _sorted(want)
 
 
+@pytest.mark.parametrize("tier", TIERS)
+def test_expressions_above_the_join_read_no_filler_under_a_null(tier):
+    """Q13's join with a `Filter` and a `Project` over the null-supplying
+    side's column above it. Until PR 43 an expression read the data buffer
+    alone, and a null-extended row's `rv` is whatever the gather left
+    under its null (it passes `rv < 1010`); Spark's answer: the comparison
+    is null there and the row goes, `rv + 1` is null, `rv is null` true."""
+    from spark_rapids_tpu.plan import is_null
+    left, right, payload = _tables("duplicates")
+    inputs = _inputs(left, right, payload)
+    rows = _pandas_left(left, right)
+    res = _run(tier, _join_plan(above=col("rv") < 1010), inputs)
+    want = [r for r in rows if r[3] is not None and r[3] < 1010]
+    assert want and _rows(res) == _sorted(want)
+    assert any(r[3] is None for r in rows)      # rows the filler would pass
+    b = PlanBuilder()
+    plan = (b.scan("l", schema=["k", "lv"])
+            .join(b.scan("r", schema=["rk", "rv"]), left_on="k",
+                  right_on="rk", how="left_outer")
+            .project({"lv": col("lv"), "next": col("rv") + 1,
+                      "lonely": is_null(col("rv")),
+                      "both": (col("rv") > 0) & (col("lv") >= 0)}).build())
+    res = _run(tier, plan, inputs)
+    assert _rows(res) == _sorted(
+        (r[1], None if r[3] is None else r[3] + 1, r[3] is None,
+         None if r[3] is None else True) for r in rows)
+    assert res.table["next"].validity is not None
+    assert res.table["lonely"].validity is None
+
+
 def test_a_left_side_predicate_above_the_join_passes_below_it():
     left, right, payload = _tables("null_right_keys")
     inputs = _inputs(left, right, payload)
@@ -350,10 +380,11 @@ def test_the_verifier_types_both_sides_columns():
 def test_an_unknown_join_type_is_refused_by_name():
     from spark_rapids_tpu.plan import PlanValidationError
     b = PlanBuilder()
-    with pytest.raises(PlanValidationError, match="full_outer"):
+    # (`full_outer` was the example until PR 43 made it a join type)
+    with pytest.raises(PlanValidationError, match="right_outer"):
         b.scan("l", schema=["k"]).join(b.scan("r", schema=["rk"]),
                                        left_on="k", right_on="rk",
-                                       how="full_outer")
+                                       how="right_outer")
 
 
 def test_the_certifier_bounds_its_rows_from_below_by_the_left_sides():

@@ -721,6 +721,50 @@ def test_full_join_matches_multiset_oracle():
     assert got_pairs == want
 
 
+def _full_join_sides(rng, nl, nr, n_keys, nulls):
+    def side(n, null):
+        return [col(rng.integers(0, 12 if n_keys > 1 else 60, n)
+                    .astype(np.int64),
+                    nulls=(rng.random(n) < 0.2) if null else None)
+                for _ in range(n_keys)]
+    return side(nl, nulls in ("left", "both")), \
+        side(nr, nulls in ("right", "both"))
+
+
+@pytest.mark.parametrize("null_equal", [False, True])
+@pytest.mark.parametrize("nulls", ["none", "left", "right", "both"])
+@pytest.mark.parametrize("shape", [(1, 90, 70), (2, 90, 70), (2, 0, 40),
+                                   (2, 40, 0), (1, 0, 0)],
+                         ids=lambda s: "keys%d_%dx%d" % s)
+def test_full_join_is_the_left_join_then_the_unmatched_right_rows(
+        shape, nulls, null_equal):
+    """`full_join_counted` reads both sides' answers off ONE union sort
+    (`_full_join_kernel`, PR 43: no null-rank operand unless nulls match,
+    the row number as the sort's last key); held, map for map and count
+    for count, to what it ran before: `left_join` and then the swapped
+    anti join's rows, over duplicates on both sides, one and two key
+    columns, nulls on either side, `<=>`, and an empty side."""
+    from spark_rapids_tpu.ops import (full_join_counted, left_anti_join,
+                                      left_join_counted)
+    n_keys, nl, nr = shape
+    lk, rk = _full_join_sides(np.random.default_rng(nl + 7 * nr + n_keys),
+                              nl, nr, n_keys, nulls)
+    lmap, rmap, matched, unmatched, unmatched_right = full_join_counted(
+        lk, rk, null_equal)
+    wl, wr, wmatched, wunmatched = left_join_counted(lk, rk, null_equal)
+    extra = np.asarray(left_anti_join(rk, lk, null_equal).data)
+    assert (matched, unmatched, unmatched_right) \
+        == (wmatched, wunmatched, len(extra))
+    assert lmap.length == rmap.length == matched + unmatched + len(extra)
+    np.testing.assert_array_equal(
+        np.asarray(lmap.data),
+        np.concatenate([np.asarray(wl.data), np.full(len(extra), -1)]))
+    np.testing.assert_array_equal(
+        np.asarray(rmap.data), np.concatenate([np.asarray(wr.data), extra]))
+    if nl and nr:       # the case has rows of all three kinds
+        assert matched and unmatched and unmatched_right
+
+
 def test_capped_join_x64_guard():
     """The capped joins' int64 match-count overflow guard must not silently
     degrade to int32 when a host app flips jax_enable_x64 off (round-5

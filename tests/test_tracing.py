@@ -829,6 +829,97 @@ def test_join_span_of_an_inner_and_a_semi_join(session):
     assert spans.one("plan.execute")["outer_joins"] == 0
 
 
+def _full_plan():
+    from spark_rapids_tpu.plan import is_not_null, is_null, when
+    b = PlanBuilder()
+    return (b.scan("d", schema=["dk", "g"]).distinct(["dk"])
+            .join(b.scan("t", schema=["k", "v"]).filter(col("v") > 10)
+                  .distinct(["k"]),
+                  left_on="dk", right_on="k", how="full_outer")
+            .project({"only_d": when(is_not_null(col("dk"))
+                                     & is_null(col("k")), 1, 0),
+                      "only_t": when(is_null(col("dk")), 1, 0)})
+            .aggregate([], [("only_d", "sum", "only_d"),
+                            ("only_t", "sum", "only_t")]).build())
+
+
+def _full_inputs():
+    # keys 0..49 on the left, 20..59 on the right: twenty lonely rows a side
+    fact = _fact()
+    return {"d": _dim(),
+            "t": Table([_col(np.asarray(fact["k"].data) % 40 + 20),
+                        fact["v"]], names=["k", "v"])}
+
+
+@pytest.mark.parametrize("tier", ["eager", "capped", "cpu"])
+def test_full_join_span_and_the_requests_counters(session, tier):
+    """A `full_outer` join's `ops.join` says `unmatched_right` beside
+    `matched` and `unmatched`; the request's `plan.execute` carries
+    `full_joins` and the unmatched rows of each side in every tier; the
+    DISTINCTs' `ops.groupby` spans say `planes=0`; the projection's
+    `plan.op` says how many of its inputs can hold a null."""
+    plan, inputs = _full_plan(), _full_inputs()
+    ex = PlanExecutor(mode="capped" if tier == "capped" else "eager",
+                      **({"caps": dict(row_cap=1024, key_cap=64)}
+                         if tier == "capped" else {}))
+    run = lambda: ex.execute(plan, inputs,
+                             tier="cpu" if tier == "cpu" else None)
+    run()                                                 # compile outside
+    done = []
+    spans = session(lambda: done.append(run()))
+    res, got = done[0], spans.one("plan.execute")
+    (join,) = [m for m in res.metrics.values() if m.kind == "HashJoin"]
+    assert (res.full_joins, res.full_unmatched_rows,
+            res.full_unmatched_right_rows) == (1, 20, 10)
+    assert (join.unmatched_rows, join.unmatched_right_rows) == (20, 10)
+    assert (got["full_joins"], got["full_unmatched_rows"],
+            got["full_unmatched_right_rows"]) == (1, 20, 10)
+    assert (got["outer_joins"], got["outer_unmatched_rows"]) == (0, 0)
+    t = res.compact()
+    assert (t["only_d"].to_pylist(), t["only_t"].to_pylist()) \
+        == ([20], [10])
+    if tier == "capped":
+        assert not spans.named("ops.join")        # one program, run warm
+        return
+    j = spans.one("ops.join")
+    assert (j["how"], j["rows_left"], j["rows_right"]) \
+        == ("full_outer", 50, 40)
+    assert (j["matched"], j["unmatched"], j["unmatched_right"]) \
+        == (30, 20, 10)
+    (op,) = [o for o in spans.named("plan.op")
+             if o["op"].endswith(".HashJoin")]
+    assert op["how"] == "full_outer" and inside(j, op)
+    # ONE read for both sides' counts, inside the span
+    assert [s["site"] for s in spans.named("ops.host_sync")
+            if inside(s, j)] == ["join.full"]
+    assert [g["planes"] for g in spans.named("ops.groupby")] == [0, 0]
+    nullable = {o["op"].split(".")[1]: o["nullable_inputs"]
+                for o in spans.named("plan.op") if "nullable_inputs" in o}
+    # the projection reads both keys, nullable after the join; the filter
+    # below the right side reads a column that has no mask
+    assert nullable["Project"] == 2
+    assert nullable.get("Filter", nullable.get("FusedSelect")) == 0
+
+
+def test_a_plan_without_them_counts_no_full_join_and_no_nullable_input(
+        session):
+    plan, inputs = _join_plan(), {"t": _fact(), "d": _dim()}
+    ex = PlanExecutor(mode="eager")
+    ex.execute(plan, inputs)
+    done = []
+    spans = session(lambda: done.append(ex.execute(plan, inputs)))
+    got = spans.one("plan.execute")
+    assert (got["full_joins"], got["full_unmatched_rows"],
+            got["full_unmatched_right_rows"]) == (0, 0, 0)
+    assert (done[0].full_joins, done[0].full_unmatched_right_rows) == (0, 0)
+    assert "unmatched_right" not in spans.one("ops.join")
+    assert all(o.get("nullable_inputs", 0) == 0
+               for o in spans.named("plan.op"))
+    assert all("nullable_inputs" not in o for o in spans.named("plan.op")
+               if o["op"].split(".")[1] in ("HashJoin", "HashAggregate",
+                                            "Sort", "Scan"))
+
+
 def test_a_request_with_an_outer_join_is_tiled_by_its_spans(session):
     """The walk over a request: every span lies inside `plan.execute`,
     every operator's children lie inside it, and what a `plan.op` leaves
@@ -1275,6 +1366,7 @@ def test_the_walk_finds_what_the_request_path_is_known_to_read():
                   ("plan/distributed.py", "_repartition_rel", "np.asarray"),
                   ("parallel/autoretry.py", "auto_retry_overflow", "bool"),
                   ("ops/join.py", "_sort_inner_join", "int"),
+                  ("ops/join.py", "full_join_counted", "device_get"),
                   ("ops/join_lookup.py", "member_mask", "int"),
                   ("ops/gather.py", "_count_kept", "int"),
                   ("ops/gather.py", "take", "device_get"),
