@@ -27,10 +27,11 @@ from .parse_uri import (parse_uri_to_protocol, parse_uri_to_host,
                         parse_uri_to_query_column)
 from .histogram import create_histogram_if_valid, percentile_from_histogram
 from .map_utils import from_json
-from .gather import take, take_live, take_table, apply_boolean_mask
+from .gather import (take, take_live, take_table, apply_boolean_mask,
+                     outer_join_columns)
 from .sort import sort_table_capped, sorted_order, sort_table
 from .aggregate import groupby_aggregate, groupby_aggregate_capped
-from .join import (full_join, full_join_counted, inner_join,
+from .join import (full_join, full_join_counted, full_join_parts, inner_join,
                    inner_join_carrying, inner_join_capped,
                    inner_join_capped_tail, left_join, left_join_capped,
                    left_join_counted, left_semi_join, left_anti_join,
@@ -70,14 +71,14 @@ _ADMITTED_FACTORS = {
     "create_histogram_if_valid": 2.0, "percentile_from_histogram": 2.0,
     "from_json": 3.0,
     "take": 2.0, "take_live": 2.0, "take_table": 2.0,
-    "apply_boolean_mask": 2.0,
+    "apply_boolean_mask": 2.0, "outer_join_columns": 2.0,
     "sorted_order": 2.0, "sort_table": 3.0, "sort_table_capped": 3.0,
     "groupby_aggregate": 2.0, "groupby_aggregate_capped": 2.0,
     "inner_join": 3.0, "inner_join_carrying": 3.0,
     "inner_join_capped": 3.0,
     "inner_join_capped_tail": 3.0, "left_join": 3.0,
     "left_join_counted": 3.0, "left_join_capped": 3.0, "full_join": 3.0,
-    "full_join_counted": 3.0,
+    "full_join_counted": 3.0, "full_join_parts": 3.0,
     "left_semi_join": 2.0, "left_anti_join": 2.0, "semi_join_mask": 2.0,
     # slice/split/halve are deliberately NOT admitted: they run inside the
     # SplitAndRetry recovery path when memory is already short, and their
@@ -114,14 +115,15 @@ __all__ = [
     "parse_uri_to_query_literal", "parse_uri_to_query_column",
     "create_histogram_if_valid", "percentile_from_histogram",
     "from_json",
-    "take", "take_live", "take_table", "apply_boolean_mask", "sorted_order",
+    "take", "take_live", "take_table", "apply_boolean_mask",
+    "outer_join_columns", "sorted_order",
     "sort_table",
     "sort_table_capped",
     "groupby_aggregate", "groupby_aggregate_capped",
     "inner_join", "inner_join_carrying", "inner_join_capped",
     "inner_join_capped_tail",
     "left_join", "left_join_counted", "left_join_capped",
-    "full_join", "full_join_counted",
+    "full_join", "full_join_counted", "full_join_parts",
     "left_semi_join",
     "left_anti_join", "semi_join_mask",
     "concat_columns", "concat_tables", "slice_table", "split_table",

@@ -12,6 +12,15 @@ The capped tier's joins gather at a static cap of which a prefix is live:
 `take_live` / `gather_live` do that gather in chunks over the live rows
 only, the count read on the device (what a chunk and a slot cost on the
 chip is in PERF.md, PR 31).
+
+The eager tier moves rows by counts its callers have read: a filter's
+compaction (`compaction_path`: few kept rows by their positions, the rest
+riding one sort; PR 42) and an outer join's output columns
+(`outer_join_paths`, `outer_join_columns`: a side whose map is the
+identity is not gathered, a right side that hardly matches is a null
+frame with the matched slots written in, a full join's lonely right rows
+are a compaction; PR 44). A frame-long `take` is what is left where no
+count says better.
 """
 from __future__ import annotations
 
@@ -404,3 +413,143 @@ def take_table(table: Table, idx: jnp.ndarray,
         _has_negative = int(idx.shape[0]) > 0 and _any_negative(idx)
     return Table([take(c, idx, _has_negative=_has_negative)
                   for c in table.columns], names=table.names)
+
+
+# ---------------------------------------------------------------------------
+# An eager outer join's output columns (`left_outer`, `full_outer`). The join
+# has read, in its one host sync, how many pairs matched and how many rows
+# of each side came out alone; those counts say what its gather maps hold,
+# and a map that is the identity, a run of -1, a compaction, or a -1 nearly
+# everywhere is not gathered through over the frame.
+# ---------------------------------------------------------------------------
+
+def outer_join_paths(how: str, rows_left: int, rows_right: int, matched: int,
+                     unmatched: int, unmatched_right: int,
+                     ragged: bool = False) -> Tuple[str, str, str]:
+    """How an outer join's output columns are made -> (left, body, tail),
+    by arithmetic over the join's own counts; nothing else chooses.
+
+    `left`, the left side over the first `matched + unmatched` slots: every
+    left row emits a slot or more, in row order, so when the slots are as
+    many as the rows the map is `arange(rows_left)`: `as_is` (the columns
+    as they stand), else `take`. `body`, the right side over those slots:
+    `nulls` (nothing matched), `sparse` (`few_kept`, or at most `KEPT_FLOOR`
+    matched: the matched slots' rows are gathered and written into a null
+    frame; `sparse+gather` where a string, list or struct column has no
+    plane to write: it takes the plain gather), else `take`. `tail`, a full
+    join's last `unmatched_right` slots, the right rows without a match
+    under a run of left nulls: `compaction_path`'s word for them, `empty`
+    where there are none, "" for a join that has no tail."""
+    slots = matched + unmatched
+    left = "as_is" if slots == rows_left else "take"
+    if matched == 0:
+        body = "nulls"
+    elif few_kept(matched, slots) or matched <= KEPT_FLOOR:
+        body = "sparse+gather" if ragged else "sparse"
+    else:
+        body = "take"
+    if how != "full_outer":
+        tail = ""
+    elif unmatched_right == 0:
+        tail = "empty"
+    else:
+        tail = compaction_path(rows_right, unmatched_right, ragged)
+    return left, body, tail
+
+
+def null_rows(col: Column, n: int) -> Column:
+    """`n` null rows of `col`'s type (the data under a null is zeros)."""
+    no = jnp.zeros((n,), bool)
+    k = col.dtype.kind
+    if k == Kind.STRUCT:
+        return Column(dtype=col.dtype, length=n, validity=no,
+                      children=tuple(null_rows(c, n) for c in col.children))
+    if k == Kind.LIST:
+        return Column(dtype=col.dtype, length=n, validity=no,
+                      offsets=jnp.zeros((n + 1,), jnp.int32),
+                      children=(null_rows(col.children[0], 0),))
+    if k == Kind.STRING:
+        return Column(dtype=col.dtype, length=n, validity=no,
+                      data=jnp.zeros((0,), col.data.dtype),
+                      offsets=jnp.zeros((n + 1,), jnp.int32))
+    return Column(dtype=col.dtype, length=n, validity=no,
+                  data=jnp.zeros((n,) + col.data.shape[1:], col.data.dtype))
+
+
+@functools.partial(jax.jit, static_argnames=("kept",))
+def _write_rows(idx, arrays, *, kept: int):
+    """-> (a bool plane, true at the `kept` slots where the (m,) map `idx`
+    holds a row; per array of `arrays` a zero frame of `m` rows with the
+    array's row at each of those slots). One program: the slots by
+    `live_positions`, gathers over `kept` rows, and a scatter in which no
+    two rows share a slot."""
+    m = idx.shape[0]
+    at = live_positions(idx >= 0, kept)[0]
+    rows = jnp.take(idx, at, axis=0)
+
+    def written(values):
+        return jnp.zeros((m,) + values.shape[1:], values.dtype).at[at].set(
+            values, indices_are_sorted=True, unique_indices=True)
+    return (written(jnp.ones((kept,), bool)),
+            [written(jnp.take(a, rows, axis=0)) for a in arrays])
+
+
+def sparse_rows(cols, idx, kept: int) -> list:
+    """`[take(c, idx) for c in cols]` for a gather map that holds a row at
+    `kept` of its slots and -1 at every other, `kept` few: the rows are
+    fetched for those slots alone. A string, list or struct column takes
+    the plain gather."""
+    m = int(idx.shape[0])
+    ragged = [c.dtype.kind in _RAGGED for c in cols]
+    hit, got = _write_rows(
+        idx, [p for c, r in zip(cols, ragged) if not r
+              for p in (c.data, c.validity) if p is not None], kept=kept)
+    got = iter(got)
+    return [take(c, idx, _has_negative=kept < m) if r else
+            Column(dtype=c.dtype, length=m, data=next(got),
+                   validity=next(got) if c.validity is not None
+                   else hit if kept < m else None)
+            for c, r in zip(cols, ragged)]
+
+
+def outer_join_columns(left: Table, right: Table, how: str, lmap, rmap,
+                       lonely, matched: int, unmatched: int,
+                       unmatched_right: int = 0):
+    """The output columns of an eager `left_outer` / `full_outer` join ->
+    (left's columns ++ right's columns, over `matched + unmatched +
+    unmatched_right` rows; what was done: `left_out`, `right_out`, and the
+    `planes_gathered` / `slots_gathered` that still went through a
+    frame-long `take`). Row for row, nulls included, what
+    `take_table(left, lmap) ++ take_table(right, rmap)` gives over the
+    join's whole maps, made the way `outer_join_paths` says of the join's
+    counts: `lmap`, `rmap` are `left_join`'s maps (the first `matched +
+    unmatched` slots), `lonely` a full join's mask of the right rows
+    without a match (`ops/join.py:full_join_parts`)."""
+    from .copying import _concat2
+    slots = matched + unmatched
+    left_out, body, tail = outer_join_paths(
+        how, left.num_rows, right.num_rows, matched, unmatched,
+        unmatched_right,
+        any(c.dtype.kind in _RAGGED for c in right.columns))
+    taken = []      # the columns that came out of a frame-long `take`
+    lcols, rcols = list(left.columns), list(right.columns)
+    if left_out == "take":
+        lcols = [take(c, lmap, _has_negative=False) for c in lcols]
+        taken += lcols
+    if body == "nulls":
+        rcols = [null_rows(c, slots) for c in rcols]
+    elif body == "take":
+        rcols = [take(c, rmap, _has_negative=unmatched > 0) for c in rcols]
+        taken += rcols
+    else:
+        rcols = sparse_rows(rcols, rmap, matched)
+        taken += [c for c in rcols if c.dtype.kind in _RAGGED]
+    planes = [1 + (c.validity is not None) for c in taken]
+    if unmatched_right:
+        lcols = [_concat2(c, null_rows(c, unmatched_right)) for c in lcols]
+        rcols = [_concat2(c, t) for c, t in zip(rcols, compact_columns(
+            right.columns, lonely, unmatched_right))]
+    return lcols + rcols, {
+        "left_out": left_out, "right_out": body + "/" * bool(tail) + tail,
+        "planes_gathered": sum(planes),
+        "slots_gathered": sum(planes) * slots}

@@ -66,7 +66,13 @@ line), and nowhere else.
    key that is unique already, and are not stable: the order is the same,
    and XLA compiles such a sort in half the time (PERF.md section 6, PR
    43; the other joins keep the stable form their cells were measured
-   with: ROADMAP S3).
+   with: ROADMAP S3). `full_join_parts` hands back the left join's
+   maps, the mask of the right rows no left row matches and the three
+   counts of the one read: the join's last rows are those right rows,
+   ascending, a compaction by a count in hand, so the eager executor
+   builds its output columns from the parts
+   (`ops/gather.py:outer_join_columns`) and only `full_join_counted`,
+   the public map contract, writes the tail out as gather maps.
 
 3. the small-side path (eager `inner_join`, `left_semi_join`,
    `left_anti_join` only, which hold both sides' row counts on the host):
@@ -106,7 +112,7 @@ from .join_lookup import lookup_side, match_rows, member_mask, note_lookup
 from .sort import _key_operands
 
 __all__ = ["inner_join", "inner_join_carrying", "left_join", "left_join_counted", "full_join",
-           "full_join_counted",
+           "full_join_counted", "full_join_parts",
            "left_semi_join",
            "left_anti_join",
            "inner_join_capped", "inner_join_capped_tail", "left_join_capped",
@@ -738,31 +744,48 @@ def _full_totals(counts, rmiss):
     return (*_outer_totals(counts), jnp.sum(rmiss.astype(jnp.int64)))
 
 
+def full_join_parts(left_keys, right_keys, null_equal: bool = False):
+    """A full outer join as the parts its one host sync tells apart ->
+    (left_map, right_map, lonely, matched, unmatched, unmatched_right):
+    `left_join_counted`'s maps over the first `matched + unmatched` slots,
+    and `lonely`, a bool per right row: no left row matches it (a null key
+    among them). The join's last `unmatched_right` slots are those rows,
+    ascending, under a -1 in the left map: a compaction of the right side
+    under `lonely` by a count in hand, which a caller that builds the
+    output columns moves by that count (`ops/gather.py:
+    outer_join_columns`) and `full_join_counted` writes out as maps. Both
+    sides' answers come off ONE union sort (`_full_join_kernel`)."""
+    lcols, rcols = _cols(left_keys), _cols(right_keys)
+    operands, lvalid, rvalid, nl = _union_operands(
+        lcols, rcols, null_equal, None, None, ranked=null_equal)
+    counts, lo, rorder, lonely = _full_join_kernel(
+        operands, lvalid, rvalid, n_ops=len(operands), nl=nl)
+    with span("ops.host_sync", site="join.full"):
+        matched, unmatched, unmatched_right = (int(x) for x in jax.device_get(
+            _full_totals(counts, lonely)))    # the one host sync
+    total = matched + unmatched
+    lmap, rmap = _expand(counts, lo, rorder, total=total, outer=True)
+    return (Column(dtype=dtypes.INT32, length=total, data=lmap),
+            Column(dtype=dtypes.INT32, length=total, data=rmap),
+            lonely, matched, unmatched, unmatched_right)
+
+
 def full_join_counted(left_keys, right_keys, null_equal: bool = False):
     """`full_join` and what its one host sync read: (left_map, right_map,
     matched, unmatched, unmatched_right): `left_join_counted`'s output,
     then one (-1, j) row per right row j without a match (a null key
-    among them), ascending. Both sides' answers come off ONE union sort
-    (`_full_join_kernel`), and `unmatched_right` says whether the left map
-    holds a -1, as `unmatched` says of the right map: the caller's gathers
+    among them), ascending. `unmatched_right` says whether the left map
+    holds a -1, as `unmatched` says of the right map: a caller's gathers
     need not ask the device."""
-    lcols, rcols = _cols(left_keys), _cols(right_keys)
-    operands, lvalid, rvalid, nl = _union_operands(
-        lcols, rcols, null_equal, None, None, ranked=null_equal)
-    counts, lo, rorder, rmiss = _full_join_kernel(
-        operands, lvalid, rvalid, n_ops=len(operands), nl=nl)
-    with span("ops.host_sync", site="join.full"):
-        matched, unmatched, unmatched_right = (int(x) for x in jax.device_get(
-            _full_totals(counts, rmiss)))     # the one host sync
-    total = matched + unmatched
-    lmap, rmap = _expand(counts, lo, rorder, total=total, outer=True)
+    lm, rm, lonely, matched, unmatched, unmatched_right = full_join_parts(
+        left_keys, right_keys, null_equal)
     if unmatched_right:
-        lmap, rmap = _append_right(lmap, rmap,
-                                   kept_rows(rmiss, unmatched_right))
-        total += unmatched_right
-    return (Column(dtype=dtypes.INT32, length=total, data=lmap),
-            Column(dtype=dtypes.INT32, length=total, data=rmap),
-            matched, unmatched, unmatched_right)
+        lmap, rmap = _append_right(lm.data, rm.data,
+                                   kept_rows(lonely, unmatched_right))
+        total = lm.length + unmatched_right
+        lm = Column(dtype=dtypes.INT32, length=total, data=lmap)
+        rm = Column(dtype=dtypes.INT32, length=total, data=rmap)
+    return lm, rm, matched, unmatched, unmatched_right
 
 
 @jax.jit

@@ -167,10 +167,10 @@ def _scope_name(idx: int, node: PlanNode) -> str:
 
 
 def _null_row(table: Table) -> Table:
-    """One row of nulls in `table`'s schema: what an outer join's gathers
-    read in an EMPTY null-supplying side's place (a gather from no rows
-    has none); as a join side its null key matches nothing, so every row
-    of the other side comes out null-extended."""
+    """One row of nulls in `table`'s schema: what a capped outer join's
+    gathers read in an EMPTY null-supplying side's place (a gather from no
+    rows has none); as a join side its null key matches nothing, so every
+    row of the other side comes out null-extended."""
     return Table([Column.from_pylist([None], c.dtype)
                   for c in table.columns], names=list(table.names))
 
@@ -634,6 +634,12 @@ class PlanResult:
         self.full_joins = 0           # its `full_outer` joins, the left
         self.full_unmatched_rows = 0  # rows they put out null-extended and
         self.full_unmatched_right_rows = 0  # the right rows
+        self.join_planes_gathered = 0  # eager tier, over the outer joins:
+        self.join_slots_gathered = 0  # output planes (a column's data, its
+        #                               validity) that went through a
+        #                               frame-long `take`, and planes x
+        #                               slots (ops/gather.py:
+        #                               outer_join_columns)
         self.lookup_joins = 0         # eager tier: joins that took the
         self.lookup_compares = 0      # small-side path, and small rows x
         #                               large rows over them (ops/join.py)
@@ -877,6 +883,8 @@ class PlanExecutor:
                             full_unmatched_rows=res.full_unmatched_rows,
                             full_unmatched_right_rows=(
                                 res.full_unmatched_right_rows),
+                            join_planes_gathered=res.join_planes_gathered,
+                            join_slots_gathered=res.join_slots_gathered,
                             gather_slots=res.gather_slots,
                             cap_slots=res.cap_slots,
                             expand_slots=res.expand_slots,
@@ -934,9 +942,10 @@ class PlanExecutor:
 
     @staticmethod
     def _count_outer(res: PlanResult) -> None:
-        """`outer_joins`, `outer_unmatched_rows` and the `full_*` counters
-        of a result, from its operators' metrics (a cached result keeps
-        its own)."""
+        """`outer_joins`, `outer_unmatched_rows`, the `full_*` counters
+        and the eager outer joins' `join_planes_gathered` /
+        `join_slots_gathered` of a result, from its operators' metrics (a
+        cached result keeps its own)."""
         if res.cached or res.outer_joins or res.full_joins:
             return
         for node in res.plan.nodes:
@@ -950,6 +959,8 @@ class PlanExecutor:
                 res.full_joins += 1
                 res.full_unmatched_rows += int(m.unmatched_rows)
                 res.full_unmatched_right_rows += int(m.unmatched_right_rows)
+            res.join_planes_gathered += int(m.planes_gathered)
+            res.join_slots_gathered += int(m.slots_gathered)
 
     def _execute_request(self, plan, inputs, tier, placement,
                          nulled=()) -> PlanResult:
@@ -2191,12 +2202,12 @@ class PlanExecutor:
     def _eager_join(self, node: HashJoin, lt: Table, rt: Table,
                     m: OperatorMetrics) -> Table:
         """The eager tiers' join, in an `ops.join` span that holds its
-        device work (the maps, the output columns' gathers and, where the
-        executor blocks per operator, the wait for them). The pairing
-        joins share the gathers: an outer join's map holds a -1 at every
-        row the other side has no match for, which `take` turns into a
-        null row, and the join's own counts say which maps hold one (no
-        read for it)."""
+        device work (the maps, the output columns and, where the executor
+        blocks per operator, the wait for them). An inner join gathers
+        its output columns through its maps, or carries them; an outer
+        join's are made the way its own counts say
+        (`ops.outer_join_columns`: a map that is the identity or nearly
+        all -1 is not gathered through; `m.left_out`, `m.right_out`)."""
         ops = _ops()
         outer_left, outer_right = nullable_sides(node.how)
         rows_left, rows_right = lt.num_rows, rt.num_rows
@@ -2210,11 +2221,11 @@ class PlanExecutor:
         with span("ops.join", how=node.how, rows_left=rows_left,
                   rows_right=rows_right) as sp:
             matched = unmatched = unmatched_right = 0
-            carried = None
+            carried = lonely = None
             with lookup_counts() as looked:
                 if node.how == "full_outer":
-                    lm, rm, matched, unmatched, unmatched_right = \
-                        ops.full_join_counted(lkeys, rkeys)
+                    lm, rm, lonely, matched, unmatched, unmatched_right = \
+                        ops.full_join_parts(lkeys, rkeys)
                 elif outer_right:
                     lm, rm, matched, unmatched = ops.left_join_counted(
                         lkeys, rkeys)
@@ -2237,25 +2248,24 @@ class PlanExecutor:
                 m.lookup_compares = sum(a * b for a, b in looked)
                 # its wall is no timing of a registered hash_join kernel
                 m.__dict__.pop("_kernel_sig", None)
-            if node.how in PAIRING_JOINS:
-                if node.how == "inner":
-                    matched = lm.length
-                # an outer join's empty side joins nothing: every index
-                # into it is a -1, and a row of nulls is what the gather
-                # reads (a gather from no rows has none)
-                lsrc = lt if rows_left or not outer_left else _null_row(lt)
-                rsrc = rt if rows_right or not outer_right else _null_row(rt)
+            if outer_right:
+                cols, made = ops.outer_join_columns(
+                    lt, rt, node.how, lm.data, rm.data, lonely, matched,
+                    unmatched, unmatched_right)
+                out = Table(cols, names=list(lt.names) + list(rt.names))
+                for name, value in made.items():
+                    setattr(m, name, value)
+                sp.set_metadata(left_out=m.left_out, right_out=m.right_out)
+            elif node.how == "inner":
+                matched = lm.length
                 if carried is not None:
                     out = self._carried_join(node, lt, rt, carried, rm)
                 else:
                     out = Table(
                         list(ops.take_table(
-                            lsrc, lm.data,
-                            _has_negative=unmatched_right > 0).columns) +
+                            lt, lm.data, _has_negative=False).columns) +
                         list(ops.take_table(
-                            rsrc, rm.data,
-                            _has_negative=outer_right and unmatched > 0
-                        ).columns),
+                            rt, rm.data, _has_negative=False).columns),
                         names=list(lt.names) + list(rt.names))
             else:
                 out = ops.take_table(lt, keep.data, _has_negative=False)

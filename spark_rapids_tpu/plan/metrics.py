@@ -57,6 +57,16 @@ class OperatorMetrics:
     # "none" (every row stayed); "" where nothing is compacted (another
     # operator, the capped tier's mask)
     compact: str = ""
+    # how an eager `left_outer` / `full_outer` join made each side's output
+    # columns (ops/gather.py:outer_join_paths): the left side `as_is` or by
+    # `take`; the right side `nulls`, `sparse` or `take`, for a full join
+    # then `/` and how the right rows without a match were compacted; and
+    # the planes, and planes x slots, that still went through a frame-long
+    # `take`. "" and 0 for another join or tier
+    left_out: str = ""
+    right_out: str = ""
+    planes_gathered: int = 0
+    slots_gathered: int = 0
     # left rows a `left_outer` or `full_outer` join put out null-extended
     # (no match, or a null key), else 0
     unmatched_rows: int = 0

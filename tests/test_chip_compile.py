@@ -596,3 +596,33 @@ def test_eager_full_join_on_a_two_column_key_compiles_for_v5e(
     # third of the frame, and a byte a right row
     out = compiled.memory_analysis().output_size_in_bytes
     assert out < 4 * (2 * nl + nl + nr) + nr + (1 << 16), out
+
+
+Q97_PAIRS = (6_597_944, 3_350_369)      # `q97.batch`: distinct (customer,
+Q97_MATCHED = 440                       # item) pairs a side, pairs in both
+
+
+def test_sparse_join_output_compiles_for_v5e(one_chip, no_persistent_cache):
+    """The right side of `q97.batch`'s full join over the left join's
+    slots (`ops/gather.py:_write_rows`, PR 44): 440 matched slots of
+    6,597,944, two int64 key columns and their masks. One program with no
+    sort and no gather over the frame: the matched slots' positions, four
+    gathers of 440 rows, five scatters into zero frames; beside the
+    frames it hands back it holds a table a 32nd of the mask."""
+    from spark_rapids_tpu.ops import gather
+    slots, rows = Q97_PAIRS
+
+    def shape(n, dtype):
+        return jax.ShapeDtypeStruct((n,), dtype, sharding=one_chip)
+    compiled = gather._write_rows.lower(
+        shape(slots, jnp.int32),
+        [shape(rows, jnp.int64), shape(rows, jnp.bool_)] * 2,
+        kept=Q97_MATCHED).compile()
+    text = compiled.as_text()
+    assert " sort(" not in text
+    gathered = [int(n) for n in re.findall(
+        r"= \w+\[(\d+)[\],][^=]* gather\(", text)]
+    assert gathered and max(gathered) <= Q97_MATCHED, gathered
+    memory = compiled.memory_analysis()
+    assert memory.output_size_in_bytes < ((8 + 1) * 2 + 1) * slots + (1 << 16)
+    assert memory.temp_size_in_bytes < slots, memory.temp_size_in_bytes

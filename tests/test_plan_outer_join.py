@@ -144,6 +144,17 @@ def test_left_outer_equals_pandas(tier, case):
     # the null-supplying side's columns are nullable after the join
     assert res.table["rv"].validity is not None
     assert res.table["lv"].validity is None
+    # the eager join says how it made each side (no tail: a left join)
+    join = next(m for n, m in zip(res.plan.nodes, res.metrics.values())
+                if isinstance(n, HashJoin))
+    if tier == "capped":
+        assert (join.left_out, join.right_out) == ("", "")
+    else:
+        assert join.left_out in ("as_is", "take")
+        assert join.right_out in ("sparse", "nulls")
+        assert (res.join_planes_gathered > 0) == (join.left_out == "take")
+        assert res.join_slots_gathered \
+            == res.join_planes_gathered * join.rows_out
 
 
 @pytest.mark.parametrize("tier", TIERS)

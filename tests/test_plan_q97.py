@@ -100,6 +100,19 @@ def test_q97_equals_the_plain_reference(cell, q97, draws, executors, tier,
                 if isinstance(n, HashJoin) and n.how == "full_outer")
     assert join.rows_out == (counts["matched"] + counts["unmatched"]
                              + counts["unmatched_right"])
+    # both sides hold distinct pairs and hardly match: the left columns go
+    # out as they stand, the few matched slots are written into a null
+    # frame, the lonely right rows are compacted; no frame-long gather
+    if tier == "capped":
+        assert (join.left_out, join.right_out) == ("", "")
+    else:
+        from spark_rapids_tpu.ops.gather import compaction_path
+        assert counts["matched"] + counts["unmatched"] \
+            == cell.batch["store_pairs"]
+        assert (join.left_out, join.right_out) == (
+            "as_is", "sparse/" + compaction_path(
+                cell.batch["catalog_pairs"], counts["unmatched_right"]))
+    assert (res.join_planes_gathered, res.join_slots_gathered) == (0, 0)
     # the two DISTINCTs: rows in, pairs out
     assert (res.group_rows, res.groups) == (
         cell.batch["store_date_rows"] + cell.batch["catalog_date_rows"],
@@ -304,6 +317,19 @@ def test_full_outer_equals_pandas(tier, case):
         == len(want) or case == "decimal64_payload"
     if case.startswith("decimal") or case == "bool_payload":
         assert res.table["lv"].dtype == res.table["rv"].dtype == payload
+    # the eager join says how it made each side: at these sizes the right
+    # side never takes a frame-long gather, the left side only where a
+    # left row emits more than one slot
+    join = next(m for n, m in zip(res.plan.nodes, res.metrics.values())
+                if isinstance(n, HashJoin))
+    if tier == "capped":
+        assert (join.left_out, join.right_out) == ("", "")
+    else:
+        assert join.left_out in ("as_is", "take")
+        assert join.right_out.split("/")[0] in ("sparse", "nulls")
+        assert (res.join_planes_gathered > 0) == (join.left_out == "take")
+        assert res.join_slots_gathered == res.join_planes_gathered * (
+            join.rows_out - res.full_unmatched_right_rows)
 
 
 @pytest.mark.parametrize("tier", TIERS)

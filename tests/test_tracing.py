@@ -785,6 +785,8 @@ def test_join_span_and_the_requests_outer_join_counters(session, tier):
         == (1, res.outer_unmatched_rows)
     if tier == "capped":
         assert not spans.named("ops.join")        # one program, run warm
+        assert (got["join_planes_gathered"], res.join_slots_gathered,
+                join.left_out, join.right_out) == (0, 0, "", "")
         owners = set(ex.device_op_owners(plan, inputs).values())
         assert sum(o.endswith(".HashJoin") for o in owners) == 1
         return
@@ -798,6 +800,16 @@ def test_join_span_and_the_requests_outer_join_counters(session, tier):
     assert (j["matched"], j["unmatched"]) \
         == (join.rows_out - join.unmatched_rows, join.unmatched_rows)
     assert j["kernel"] == join.kernel and "hash_join" in j["kernel"]
+    # how each side's output columns were made (a dimension row's matches
+    # fan out: the left side is gathered; 1,000 matched rows lie under
+    # the floor: the right side writes them into a null frame), and the
+    # planes that still went through a frame-long `take`: `d`'s
+    assert (j["left_out"], j["right_out"]) == ("take", "sparse") \
+        == (join.left_out, join.right_out)
+    assert (got["join_planes_gathered"], got["join_slots_gathered"]) \
+        == (res.join_planes_gathered, res.join_slots_gathered) \
+        == (join.planes_gathered, join.planes_gathered * join.rows_out)
+    assert join.planes_gathered >= 1
     # the join's one read and its wait lie inside the span
     assert [s["site"] for s in spans.named("ops.host_sync")
             if inside(s, j)] == ["join.left"]
@@ -827,6 +839,10 @@ def test_join_span_of_an_inner_and_a_semi_join(session):
     assert by_how["left_semi"]["matched"] == done[0].table.num_rows
     assert done[0].outer_joins == 0 and done[0].outer_unmatched_rows == 0
     assert spans.one("plan.execute")["outer_joins"] == 0
+    # only an outer join says how it made its sides, and counts
+    assert not any("left_out" in j or "right_out" in j for j in joins)
+    assert (spans.one("plan.execute")["join_planes_gathered"],
+            done[0].join_slots_gathered) == (0, 0)
 
 
 def _full_plan():
@@ -886,6 +902,13 @@ def test_full_join_span_and_the_requests_counters(session, tier):
         == ("full_outer", 50, 40)
     assert (j["matched"], j["unmatched"], j["unmatched_right"]) \
         == (30, 20, 10)
+    # distinct keys on both sides: the left columns as they stand, the
+    # right side's 30 matched slots written into a null frame, its 10
+    # lonely rows by their positions; nothing is gathered over the frame
+    assert (j["left_out"], j["right_out"]) == ("as_is", "sparse/positions") \
+        == (join.left_out, join.right_out)
+    assert (got["join_planes_gathered"], got["join_slots_gathered"],
+            res.join_planes_gathered, res.join_slots_gathered) == (0,) * 4
     (op,) = [o for o in spans.named("plan.op")
              if o["op"].endswith(".HashJoin")]
     assert op["how"] == "full_outer" and inside(j, op)
@@ -1366,7 +1389,7 @@ def test_the_walk_finds_what_the_request_path_is_known_to_read():
                   ("plan/distributed.py", "_repartition_rel", "np.asarray"),
                   ("parallel/autoretry.py", "auto_retry_overflow", "bool"),
                   ("ops/join.py", "_sort_inner_join", "int"),
-                  ("ops/join.py", "full_join_counted", "device_get"),
+                  ("ops/join.py", "full_join_parts", "device_get"),
                   ("ops/join_lookup.py", "member_mask", "int"),
                   ("ops/gather.py", "_count_kept", "int"),
                   ("ops/gather.py", "take", "device_get"),
