@@ -35,7 +35,7 @@ from .join import (full_join, full_join_counted, full_join_parts, inner_join,
                    inner_join_carrying, inner_join_capped,
                    inner_join_capped_tail, left_join, left_join_capped,
                    left_join_counted, left_semi_join, left_anti_join,
-                   semi_join_mask)
+                   outer_join_parts, semi_join_mask)
 from .copying import (concat_columns, concat_tables, slice_table,
                       split_table, halve_table, replace_nulls, if_else,
                       drop_duplicates)
@@ -79,6 +79,7 @@ _ADMITTED_FACTORS = {
     "inner_join_capped_tail": 3.0, "left_join": 3.0,
     "left_join_counted": 3.0, "left_join_capped": 3.0, "full_join": 3.0,
     "full_join_counted": 3.0, "full_join_parts": 3.0,
+    "outer_join_parts": 3.0,
     "left_semi_join": 2.0, "left_anti_join": 2.0, "semi_join_mask": 2.0,
     # slice/split/halve are deliberately NOT admitted: they run inside the
     # SplitAndRetry recovery path when memory is already short, and their
@@ -124,7 +125,7 @@ __all__ = [
     "inner_join_capped_tail",
     "left_join", "left_join_counted", "left_join_capped",
     "full_join", "full_join_counted", "full_join_parts",
-    "left_semi_join",
+    "outer_join_parts", "left_semi_join",
     "left_anti_join", "semi_join_mask",
     "concat_columns", "concat_tables", "slice_table", "split_table",
     "halve_table", "replace_nulls", "if_else", "drop_duplicates",

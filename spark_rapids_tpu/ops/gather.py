@@ -19,13 +19,15 @@ riding one sort; PR 42) and an outer join's output columns
 (`outer_join_paths`, `outer_join_columns`: a side whose map is the
 identity is not gathered, a right side that hardly matches is a null
 frame with the matched slots written in, a full join's lonely right rows
-are a compaction; PR 44). A frame-long `take` is what is left where no
-count says better.
+are a compaction; PR 44; a right side of which every row has one partner
+at most is a PERMUTATION with null slots between, and rides one sort
+keyed on the row's slot, `rows_by_slot`; PR 45). A frame-long `take` is
+what is left where no count says better.
 """
 from __future__ import annotations
 
 import functools
-from typing import Tuple, Union
+from typing import NamedTuple, Optional, Tuple, Union
 
 import jax
 import jax.numpy as jnp
@@ -39,6 +41,12 @@ from .scans import live_positions
 
 # columns with no fixed-width plane a row: gathered whole by `take`
 _RAGGED = (Kind.STRING, Kind.STRUCT, Kind.LIST)
+
+
+def any_ragged(cols) -> bool:
+    """Whether a string, list or struct column is among `cols`: such a
+    column has no plane to ride a sort, and `take` gathers it whole."""
+    return any(c.dtype.kind in _RAGGED for c in cols)
 
 
 def _any_negative(idx) -> bool:
@@ -298,6 +306,19 @@ def _unplane(planes, arrays):
     return out
 
 
+def _ride(key, planes, groups, keep: int):
+    """`planes` riding a not-stable sort on `key` (unique under the rows
+    that are kept), a sort for each of `groups` (`ride_groups`), cut to
+    the first `keep` rows -> (the sorted key, the planes)."""
+    out = [None] * len(planes)
+    for group in groups or ((),):
+        got = jax.lax.sort([key] + [planes[i] for i in group],
+                           num_keys=1, is_stable=False)
+        for i, p in zip(group, got[1:]):
+            out[i] = p[:keep]
+    return got[0][:keep], out
+
+
 @functools.partial(jax.jit, static_argnames=("kept",))
 def rows_by_position(mask, arrays, *, kept: int):
     """-> (the positions of the `kept` rows of `mask`, ascending, int32;
@@ -323,14 +344,8 @@ def rows_by_sort(mask, arrays, *, kept: int, groups=()):
     n = mask.shape[0]
     row = jnp.arange(n, dtype=jnp.uint32)
     rank = jnp.where(mask, row, row + jnp.uint32(n))
-    planes = _planes(arrays)
-    out = [None] * len(planes)
-    for group in groups or ((),):
-        got = jax.lax.sort([rank] + [planes[i] for i in group],
-                           num_keys=1, is_stable=False)
-        for i, p in zip(group, got[1:]):
-            out[i] = p[:kept]
-    return got[0][:kept].astype(jnp.int32), _unplane(out, arrays)
+    rows, out = _ride(rank, _planes(arrays), groups, kept)
+    return rows.astype(jnp.int32), _unplane(out, arrays)
 
 
 def _count_kept(mask) -> int:
@@ -419,13 +434,44 @@ def take_table(table: Table, idx: jnp.ndarray,
 # An eager outer join's output columns (`left_outer`, `full_outer`). The join
 # has read, in its one host sync, how many pairs matched and how many rows
 # of each side came out alone; those counts say what its gather maps hold,
-# and a map that is the identity, a run of -1, a compaction, or a -1 nearly
-# everywhere is not gathered through over the frame.
+# and a map that is the identity, a run of -1, a compaction, a -1 nearly
+# everywhere, or a permutation is not gathered through over the frame.
 # ---------------------------------------------------------------------------
+
+class RightSlots(NamedTuple):
+    """What an outer join hands back in place of its right map where its
+    counts say that every right row has one partner at most (no two left
+    rows that match share a key; `ops/join.py:_expand_slots`): the map's
+    inverse. The right side of the output is then the right rows moved to
+    their slots with a null slot for every left row without a match, and
+    `rows_by_slot` moves them by one sort."""
+    slot: jnp.ndarray    # (rows_right,) int32: a right row's output slot,
+    #                      `matched + unmatched` where it has none
+    alone: jnp.ndarray   # (rows_left,) int32: a left row's slot where it
+    #                      matches nothing, `matched + unmatched` else
+
+
+class OuterJoin(NamedTuple):
+    """An eager outer join as the parts its one host sync tells apart
+    (`ops/join.py:outer_join_parts`), and how its output columns are made
+    of them: the one place that asks `outer_join_paths`, so that the join
+    builds the map the assembly will read and `outer_join_columns` reads
+    what the join built."""
+    paths: Tuple[str, str, str]     # `outer_join_paths` of the counts below
+    left_map: jnp.ndarray           # int32 over `matched + unmatched` slots
+    right_map: Union[jnp.ndarray, RightSlots]   # the same; its inverse
+    #                                 where `paths[1]` is `sort`
+    lonely: Optional[jnp.ndarray]   # a full join's mask of the right rows
+    #                                 without a match; None for a left join
+    matched: int
+    unmatched: int
+    unmatched_right: int            # 0 for a left join
+
 
 def outer_join_paths(how: str, rows_left: int, rows_right: int, matched: int,
                      unmatched: int, unmatched_right: int,
-                     ragged: bool = False) -> Tuple[str, str, str]:
+                     ragged: bool = False,
+                     distinct: bool = False) -> Tuple[str, str, str]:
     """How an outer join's output columns are made -> (left, body, tail),
     by arithmetic over the join's own counts; nothing else chooses.
 
@@ -436,10 +482,13 @@ def outer_join_paths(how: str, rows_left: int, rows_right: int, matched: int,
     `nulls` (nothing matched), `sparse` (`few_kept`, or at most `KEPT_FLOOR`
     matched: the matched slots' rows are gathered and written into a null
     frame; `sparse+gather` where a string, list or struct column has no
-    plane to write: it takes the plain gather), else `take`. `tail`, a full
-    join's last `unmatched_right` slots, the right rows without a match
-    under a run of left nulls: `compaction_path`'s word for them, `empty`
-    where there are none, "" for a join that has no tail."""
+    plane to write: it takes the plain gather), `sort` (`distinct`: the
+    join has read that no two left rows that match share a key, so every
+    right row has one slot at most, and the columns ride one sort to their
+    slots; a string, list or struct column cannot), else `take`. `tail`, a
+    full join's last `unmatched_right` slots, the right rows without a
+    match under a run of left nulls: `compaction_path`'s word for them,
+    `empty` where there are none, "" for a join that has no tail."""
     slots = matched + unmatched
     left = "as_is" if slots == rows_left else "take"
     if matched == 0:
@@ -447,7 +496,7 @@ def outer_join_paths(how: str, rows_left: int, rows_right: int, matched: int,
     elif few_kept(matched, slots) or matched <= KEPT_FLOOR:
         body = "sparse+gather" if ragged else "sparse"
     else:
-        body = "take"
+        body = "sort" if distinct and not ragged else "take"
     if how != "full_outer":
         tail = ""
     elif unmatched_right == 0:
@@ -512,38 +561,70 @@ def sparse_rows(cols, idx, kept: int) -> list:
             for c, r in zip(cols, ragged)]
 
 
-def outer_join_columns(left: Table, right: Table, how: str, lmap, rmap,
-                       lonely, matched: int, unmatched: int,
-                       unmatched_right: int = 0):
+@functools.partial(jax.jit, static_argnames=("slots", "groups"))
+def rows_by_slot(slot, alone, arrays, *, slots: int, groups=()):
+    """-> (a bool plane over `slots` output rows, true where a row of
+    `arrays` landed; each of `arrays` with row `r` at slot `slot[r]`, zeros
+    at every slot of `alone`). No two entries of `slot` and `alone` under
+    `slots` are alike, and together they are every slot; an entry of
+    `slots` or more goes nowhere. ONE sort of the rows and a zero
+    placeholder per entry of `alone`, keyed on twice the slot (a
+    placeholder's key is odd, and says so after the sort): the keys under
+    `2 * slots` are unique, so the sort need not be stable, and the
+    arrays' planes ride as `rows_by_sort`'s do (`groups`). The result is
+    cut to `slots` inside the program."""
+    pad = alone.shape[0]
+    key = jnp.concatenate([slot.astype(jnp.uint32) << 1,
+                           (alone.astype(jnp.uint32) << 1) | 1])
+    planes = [jnp.concatenate([p, jnp.zeros((pad,), p.dtype)])
+              for p in _planes(arrays)]
+    landed, out = _ride(key, planes, groups, slots)
+    return (landed & 1) == 0, _unplane(out, arrays)
+
+
+def outer_join_columns(left: Table, right: Table, parts: OuterJoin):
     """The output columns of an eager `left_outer` / `full_outer` join ->
     (left's columns ++ right's columns, over `matched + unmatched +
     unmatched_right` rows; what was done: `left_out`, `right_out`, and the
     `planes_gathered` / `slots_gathered` that still went through a
     frame-long `take`). Row for row, nulls included, what
     `take_table(left, lmap) ++ take_table(right, rmap)` gives over the
-    join's whole maps, made the way `outer_join_paths` says of the join's
-    counts: `lmap`, `rmap` are `left_join`'s maps (the first `matched +
-    unmatched` slots), `lonely` a full join's mask of the right rows
-    without a match (`ops/join.py:full_join_parts`)."""
+    join's whole maps, made the way `parts.paths` says (`outer_join_paths`
+    of the join's counts, asked once, by the join:
+    `ops/join.py:outer_join_parts` over these tables' keys, with `right`
+    handed in): the maps are `left_join`'s over the first `matched +
+    unmatched` slots, the right one its inverse (`RightSlots`) where the
+    body is `sort`; `lonely` is a full join's mask of the right rows
+    without a match."""
     from .copying import _concat2
+    (left_out, body, tail), lmap, rmap, lonely, matched, unmatched, \
+        unmatched_right = parts
     slots = matched + unmatched
-    left_out, body, tail = outer_join_paths(
-        how, left.num_rows, right.num_rows, matched, unmatched,
-        unmatched_right,
-        any(c.dtype.kind in _RAGGED for c in right.columns))
     taken = []      # the columns that came out of a frame-long `take`
     lcols, rcols = list(left.columns), list(right.columns)
-    if left_out == "take":
-        lcols = [take(c, lmap, _has_negative=False) for c in lcols]
-        taken += lcols
     if body == "nulls":
         rcols = [null_rows(c, slots) for c in rcols]
     elif body == "take":
         rcols = [take(c, rmap, _has_negative=unmatched > 0) for c in rcols]
         taken += rcols
+    elif body == "sort":
+        arrays = [p for c in rcols for p in (c.data, c.validity)
+                  if p is not None]
+        hit, got = rows_by_slot(rmap.slot, rmap.alone, arrays,
+                                slots=slots,
+                                groups=ride_groups(plane_words(arrays)))
+        got = iter(got)
+        rcols = [Column(dtype=c.dtype, length=slots, data=next(got),
+                        validity=next(got) if c.validity is not None
+                        else hit if unmatched else None) for c in rcols]
     else:
         rcols = sparse_rows(rcols, rmap, matched)
         taken += [c for c in rcols if c.dtype.kind in _RAGGED]
+    # (the left side last: a sort's operands and results are not held
+    # beside the left side's gathered columns)
+    if left_out == "take":
+        lcols = [take(c, lmap, _has_negative=False) for c in lcols]
+        taken += lcols
     planes = [1 + (c.validity is not None) for c in taken]
     if unmatched_right:
         lcols = [_concat2(c, null_rows(c, unmatched_right)) for c in lcols]

@@ -53,7 +53,8 @@ line), and nowhere else.
    annotation, argument or switch says which join is which. Both tails
    return the same arrays, slot for slot.
 
-2c. both sides' rows (`_full_join_kernel`, the eager full outer join): the
+2c. both sides' rows (`_full_join_kernel`, the eager outer joins: the full
+   join needs both answers, the left join the count of 2d): the
    same union sort and general tail, read for both sides at once. The flag
    payload marks a left row that may match (2) as it marks a right one
    (1); a running count of the first, less its value at the run's start,
@@ -73,6 +74,32 @@ line), and nowhere else.
    builds its output columns from the parts
    (`ops/gather.py:outer_join_columns`) and only `full_join_counted`,
    the public map contract, writes the tail out as gather maps.
+
+2d. a right side that is a permutation (`_expand_slots`, the eager outer
+   joins for a caller that builds the output columns,
+   `outer_join_parts`): when no two left rows that
+   match share a key, every right row has one partner at most, and the
+   right side of the output is the right rows moved to their slots with a
+   null slot for each left row alone: a sort on the slot with the columns
+   riding, not a gather through a map (every primary-key to foreign-key
+   outer join: a dimension LEFT OUTER its fact table). The kernel of 2c
+   has counted it already: the pairs are as many as the right rows that
+   have a partner (`matched + unmatched_right == rows_right`) exactly
+   when each has one, so the eager left join runs that kernel too, and
+   no second read is made. Where that holds and the
+   right side's body would be a frame-long `take`
+   (`ops/gather.py:outer_join_paths`) the join hands back, in place of
+   the right map, its inverse (`RightSlots`): per right row its slot, per
+   left row alone its own. The matchable right row at packed rank `q` in
+   the span of left row `l` lands at `starts[l] + q - lo[l]`, so over the
+   ranks each left row that matches adds its span's own number where the
+   span starts and takes it back where it ends, a 32-bit running sum
+   holds it along the span, and ONE two-word sort keyed on `rorder`
+   brings the slots to right-row order. What that saves: the right map's
+   own three gathered planes (`first`, `matches`, `rorder`) and a
+   frame-long gather for every plane of the right side's columns. A
+   repeated matching key, or a caller whose columns cannot ride a sort
+   or who wants the maps themselves, gets the maps as before.
 
 3. the small-side path (eager `inner_join`, `left_semi_join`,
    `left_anti_join` only, which hold both sides' row counts on the host):
@@ -106,13 +133,15 @@ import jax.numpy as jnp
 from .. import dtypes
 from ..columnar import Column, Table
 from ..utils.tracing import span
-from .gather import (compact_rows_columns, few_kept, gather_live, kept_rows,
-                     live_chunk, loop_zeros)
+from .gather import (OuterJoin, RightSlots, any_ragged, compact_rows_columns,
+                     few_kept, gather_live, kept_rows, live_chunk,
+                     loop_zeros, outer_join_paths)
 from .join_lookup import lookup_side, match_rows, member_mask, note_lookup
+from .scans import running
 from .sort import _key_operands
 
 __all__ = ["inner_join", "inner_join_carrying", "left_join", "left_join_counted", "full_join",
-           "full_join_counted", "full_join_parts",
+           "full_join_counted", "full_join_parts", "outer_join_parts",
            "left_semi_join",
            "left_anti_join",
            "inner_join_capped", "inner_join_capped_tail", "left_join_capped",
@@ -534,21 +563,124 @@ def _outer_totals(counts):
             jnp.sum((counts == 0).astype(jnp.int64)))
 
 
+@partial(jax.jit, static_argnames=("total", "holes"))
+def _expand_slots(counts, lo, rorder, rvalid, *, total: int, holes: bool):
+    """`_expand(outer=True)` for a join in which every right row has one
+    partner at most, read the other way: the right map's inverse says all
+    the map says. -> (the left map; per right row its output slot, `total`
+    where it has none; per left row its slot where it matches nothing,
+    else `total`). No frame-long gather: the right map, the planes `first`
+    and `matches` and the gather of `rorder` that make it are not built.
+
+    The matchable right row at packed rank `q` in the span of left row `l`
+    lands at `starts[l] + q - lo[l]` (`_expand`'s `rpos`, solved for the
+    slot): the rank plus a number that is its span's own. The spans are
+    disjoint, so over the ranks every left row that matches adds that
+    number, less `total`, where its span starts and takes it back where
+    it ends (in chunks over the left rows, as `expand_rows` writes), and a
+    running sum, in 32 bits that may wrap, holds at every rank its span's
+    number or, between the spans, `total`: a rank no left row matches
+    reads `total` or more. `rorder` names the row at each rank, so ONE
+    two-word sort keyed on it brings the slots to right-row order; where
+    the right side `holes` rows that may not match (a null key, which
+    `rorder` leaves out), they join that sort under their own numbers."""
+    nl = counts.shape[0]
+    n = rorder.shape[0]
+    nr = n - nl
+    lmap, starts, _ = expand_rows(jnp.maximum(counts, 1), total)
+    c = live_chunk(nl)
+    pad = (-nl) % c
+    # a row that matches nothing writes past the ranks: nowhere
+    first = jnp.pad(jnp.where(counts > 0, lo, nr), (0, pad),
+                    constant_values=nr)
+    last = jnp.pad(jnp.where(counts > 0, lo + counts, nr), (0, pad),
+                   constant_values=nr)
+    own = jnp.pad(jax.lax.bitcast_convert_type(starts - lo, jnp.uint32)
+                  - jnp.uint32(total), (0, pad))
+
+    def step(i, frame):
+        at = i * jnp.int32(c)
+        add = jax.lax.dynamic_slice_in_dim(own, at, c)
+        return (frame
+                .at[jax.lax.dynamic_slice_in_dim(first, at, c)].add(
+                    add, mode="drop")
+                .at[jax.lax.dynamic_slice_in_dim(last, at, c)].add(
+                    -add, mode="drop"))
+
+    rank = jnp.arange(nr, dtype=jnp.int32)
+    slot = jnp.minimum(
+        running(jax.lax.fori_loop(
+            jnp.int32(0), jnp.int32((nl + pad) // c), step,
+            loop_zeros((nr,), jnp.uint32, counts)))
+        + jnp.uint32(total) + rank.astype(jnp.uint32),
+        jnp.uint32(total)).astype(jnp.int32)
+    rows = rorder[:nr]              # every rank lies under the right rows
+    if holes:
+        rows = jnp.concatenate([rows, jnp.where(rvalid, n, rank)])
+        slot = jnp.concatenate([slot, jnp.full((nr,), total, jnp.int32)])
+    slot = jax.lax.sort([rows, slot], num_keys=1, is_stable=False)[1][:nr]
+    return lmap, slot, jnp.where(counts == 0, starts, total)
+
+
+def outer_join_parts(how: str, left_keys, right_keys, right: Table = None,
+                     null_equal: bool = False) -> OuterJoin:
+    """An eager `left_outer` / `full_outer` join for the caller that builds
+    its output columns (`ops/gather.py:outer_join_columns(left, right,
+    parts)`): both sides' answers off ONE union sort (`_full_join_kernel`),
+    one read of its three counts, and `outer_join_paths` asked of them
+    ONCE, here, so that the join builds the map the assembly reads.
+    `right` is the table whose columns the caller will place (None: the
+    caller wants the maps themselves, `left_join_counted`).
+
+    The three counts say whether any right row has two partners: the
+    pairs are as many as the right rows that have a partner exactly when
+    each has one (`matched + unmatched_right == rows_right`: no two left
+    rows that match share a key). Where that holds and the right side's
+    body would be a frame-long `take` over columns that can ride a sort,
+    the body is `sort` and the right map is its inverse, `RightSlots`;
+    the map itself is never built. A left join has no tail: its `lonely`
+    is None and its `unmatched_right` 0."""
+    lcols, rcols = _cols(left_keys), _cols(right_keys)
+    operands, lvalid, rvalid, nl = _union_operands(
+        lcols, rcols, null_equal, None, None, ranked=null_equal)
+    counts, lo, rorder, lonely = _full_join_kernel(
+        operands, lvalid, rvalid, n_ops=len(operands), nl=nl)
+    full = how == "full_outer"
+    with span("ops.host_sync", site="join.full" if full else "join.left"):
+        matched, unmatched, unmatched_right = (int(x) for x in jax.device_get(
+            _full_totals(counts, lonely)))    # the one host sync
+    total, nr = matched + unmatched, rcols[0].length
+    paths = outer_join_paths(
+        how, nl, nr, matched, unmatched, unmatched_right if full else 0,
+        ragged=right is None or any_ragged(right.columns),
+        distinct=matched + unmatched_right == nr)
+    if paths[1] == "sort":
+        lmap, slot, alone = _expand_slots(
+            counts, lo, rorder, rvalid, total=total,
+            holes=not null_equal and any(c.validity is not None
+                                         for c in rcols))
+        rmap = RightSlots(slot, alone)
+    else:
+        lmap, rmap = _expand(counts, lo, rorder, total=total, outer=True)
+    return OuterJoin(paths, lmap, rmap, lonely if full else None, matched,
+                     unmatched, unmatched_right if full else 0)
+
+
+def _map_columns(parts: OuterJoin) -> Tuple[Column, Column]:
+    """The parts' two maps as the int32 columns the map contracts return."""
+    return tuple(Column(dtype=dtypes.INT32, length=m.shape[0], data=m)
+                 for m in (parts.left_map, parts.right_map))
+
+
 def left_join_counted(left_keys, right_keys, null_equal: bool = False):
     """`left_join` and what its one host sync read: (left_map, right_map,
     matched, unmatched), the pairs that matched and the left rows that
     came out null-extended (a null key among them). The caller that
     gathers the right side's columns knows from `unmatched` that the map
     holds a -1 (take(_has_negative=...)) and need not ask the device."""
-    counts, lo, rorder = _prep(_cols(left_keys), _cols(right_keys), null_equal)
-    with span("ops.host_sync", site="join.left"):
-        matched, unmatched = (int(x) for x in jax.device_get(
-            _outer_totals(counts)))           # the one host sync
-    total = matched + unmatched
-    lmap, rmap = _expand(counts, lo, rorder, total=total, outer=True)
-    return (Column(dtype=dtypes.INT32, length=total, data=lmap),
-            Column(dtype=dtypes.INT32, length=total, data=rmap),
-            matched, unmatched)
+    parts = outer_join_parts("left_outer", left_keys, right_keys,
+                             null_equal=null_equal)
+    return (*_map_columns(parts), parts.matched, parts.unmatched)
 
 
 def left_join(left_keys, right_keys,
@@ -752,22 +884,13 @@ def full_join_parts(left_keys, right_keys, null_equal: bool = False):
     among them). The join's last `unmatched_right` slots are those rows,
     ascending, under a -1 in the left map: a compaction of the right side
     under `lonely` by a count in hand, which a caller that builds the
-    output columns moves by that count (`ops/gather.py:
-    outer_join_columns`) and `full_join_counted` writes out as maps. Both
-    sides' answers come off ONE union sort (`_full_join_kernel`)."""
-    lcols, rcols = _cols(left_keys), _cols(right_keys)
-    operands, lvalid, rvalid, nl = _union_operands(
-        lcols, rcols, null_equal, None, None, ranked=null_equal)
-    counts, lo, rorder, lonely = _full_join_kernel(
-        operands, lvalid, rvalid, n_ops=len(operands), nl=nl)
-    with span("ops.host_sync", site="join.full"):
-        matched, unmatched, unmatched_right = (int(x) for x in jax.device_get(
-            _full_totals(counts, lonely)))    # the one host sync
-    total = matched + unmatched
-    lmap, rmap = _expand(counts, lo, rorder, total=total, outer=True)
-    return (Column(dtype=dtypes.INT32, length=total, data=lmap),
-            Column(dtype=dtypes.INT32, length=total, data=rmap),
-            lonely, matched, unmatched, unmatched_right)
+    output columns moves by that count (`outer_join_parts`,
+    `ops/gather.py:outer_join_columns`) and `full_join_counted` writes out
+    as maps. Both sides' answers come off ONE union sort
+    (`_full_join_kernel`)."""
+    parts = outer_join_parts("full_outer", left_keys, right_keys,
+                             null_equal=null_equal)
+    return (*_map_columns(parts), *parts[3:])
 
 
 def full_join_counted(left_keys, right_keys, null_equal: bool = False):

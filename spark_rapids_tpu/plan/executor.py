@@ -2206,8 +2206,9 @@ class PlanExecutor:
         blocks per operator, the wait for them). An inner join gathers
         its output columns through its maps, or carries them; an outer
         join's are made the way its own counts say
-        (`ops.outer_join_columns`: a map that is the identity or nearly
-        all -1 is not gathered through; `m.left_out`, `m.right_out`)."""
+        (`ops.outer_join_columns`: a map that is the identity, nearly
+        all -1 or a permutation is not gathered through; `m.left_out`,
+        `m.right_out`)."""
         ops = _ops()
         outer_left, outer_right = nullable_sides(node.how)
         rows_left, rows_right = lt.num_rows, rt.num_rows
@@ -2221,14 +2222,13 @@ class PlanExecutor:
         with span("ops.join", how=node.how, rows_left=rows_left,
                   rows_right=rows_right) as sp:
             matched = unmatched = unmatched_right = 0
-            carried = lonely = None
+            carried = None
             with lookup_counts() as looked:
-                if node.how == "full_outer":
-                    lm, rm, lonely, matched, unmatched, unmatched_right = \
-                        ops.full_join_parts(lkeys, rkeys)
-                elif outer_right:
-                    lm, rm, matched, unmatched = ops.left_join_counted(
-                        lkeys, rkeys)
+                if outer_right:
+                    # (the join asks its counts how the right table's
+                    # columns will be made, and builds the map for that)
+                    parts = ops.outer_join_parts(node.how, lkeys, rkeys, rt)
+                    matched, unmatched, unmatched_right = parts[4:]
                 elif node.how != "inner":
                     keep = (ops.left_semi_join(lkeys, rkeys)
                             if node.how == "left_semi"
@@ -2249,9 +2249,7 @@ class PlanExecutor:
                 # its wall is no timing of a registered hash_join kernel
                 m.__dict__.pop("_kernel_sig", None)
             if outer_right:
-                cols, made = ops.outer_join_columns(
-                    lt, rt, node.how, lm.data, rm.data, lonely, matched,
-                    unmatched, unmatched_right)
+                cols, made = ops.outer_join_columns(lt, rt, parts)
                 out = Table(cols, names=list(lt.names) + list(rt.names))
                 for name, value in made.items():
                     setattr(m, name, value)

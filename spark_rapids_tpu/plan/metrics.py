@@ -59,7 +59,9 @@ class OperatorMetrics:
     compact: str = ""
     # how an eager `left_outer` / `full_outer` join made each side's output
     # columns (ops/gather.py:outer_join_paths): the left side `as_is` or by
-    # `take`; the right side `nulls`, `sparse` or `take`, for a full join
+    # `take`; the right side `nulls`, `sparse`, `sort` (its rows rode one
+    # sort to their slots: the matching left rows' keys are distinct) or
+    # `take`, for a full join
     # then `/` and how the right rows without a match were compacted; and
     # the planes, and planes x slots, that still went through a frame-long
     # `take`. "" and 0 for another join or tier

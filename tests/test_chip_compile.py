@@ -626,3 +626,67 @@ def test_sparse_join_output_compiles_for_v5e(one_chip, no_persistent_cache):
     memory = compiled.memory_analysis()
     assert memory.output_size_in_bytes < ((8 + 1) * 2 + 1) * slots + (1 << 16)
     assert memory.temp_size_in_bytes < slots, memory.temp_size_in_bytes
+
+
+Q13_ROWS = (1_500_000, 14_834_663)      # `q13.batch`: customers, the orders
+Q13_SLOTS = 15_334_665                  # that pass the filter; output slots
+
+
+def test_right_slots_compile_without_a_frame_long_gather_for_v5e(
+        one_chip, no_persistent_cache):
+    """The right map's inverse for `q13.batch`'s outer join
+    (`ops/join.py:_expand_slots`, PR 45): the left map by `expand_rows`,
+    the right rows' slots by chunked writes over the packed ranks, a
+    32-bit running sum and ONE two-word sort by `rorder`. No gather is as
+    long as a frame (the chunks' are a 64th of the left rows), and the
+    program's code, which lies in HBM beside a peak with 15 MB of room
+    under its bound, stays under what it was compiled at (13.0 MB; the
+    same writes as two flat scatters were 24 MB, a 64-bit running maximum
+    18.5 MB alone)."""
+    from spark_rapids_tpu.ops import join
+    nl, nr = Q13_ROWS
+
+    def shape(n, dtype):
+        return jax.ShapeDtypeStruct((n,), dtype, sharding=one_chip)
+    compiled = join._expand_slots.lower(
+        shape(nl, jnp.int32), shape(nl, jnp.int32), shape(nl + nr, jnp.int32),
+        shape(nr, jnp.bool_), total=Q13_SLOTS, holes=False).compile()
+    text = compiled.as_text()
+    # (a chunk's writes are a sort of that chunk inside the loop)
+    assert [n for n in _op_names(text, "sort") if "while/body" not in n] \
+        == ["jit(_expand_slots)/sort"]
+    assert max(_gather_slots(text), default=0) < nl // 32
+    memory = compiled.memory_analysis()
+    assert memory.generated_code_size_in_bytes < 15 << 20
+    # the left map, a slot a right row, a slot a left row; nothing else
+    assert memory.output_size_in_bytes < 4 * (Q13_SLOTS + nr + nl) + (1 << 16)
+
+
+def test_rows_by_slot_holds_one_frame_beside_its_results_for_v5e(
+        one_chip, no_persistent_cache):
+    """`q13.batch`'s destination sort at its real operands
+    (`ops/gather.py:rows_by_slot`, PR 45: `o_custkey` and `o_orderkey`,
+    int64 and without masks, the right rows' slots and a placeholder per
+    customer; `ride_groups` gives one group): the 16,334,663-row frame of
+    the key and the planes is the program's only temporary (12 bytes a
+    row; the concatenations are not a second copy, and sorting the two
+    columns in two groups on the same key compiles to the same buffers:
+    196.1 MB either way), the results are cut to the slots inside the
+    program, and its code stays under what it was compiled at (8.7 MB)."""
+    from spark_rapids_tpu.ops import gather
+    nl, nr = Q13_ROWS
+
+    def shape(n, dtype):
+        return jax.ShapeDtypeStruct((n,), dtype, sharding=one_chip)
+    arrays = [shape(nr, jnp.int64), shape(nr, jnp.int64)]
+    groups = gather.ride_groups(gather.plane_words(arrays))
+    assert groups == ((0, 1),)
+    compiled = gather.rows_by_slot.lower(
+        shape(nr, jnp.int32), shape(nl, jnp.int32), arrays,
+        slots=Q13_SLOTS, groups=groups).compile()
+    assert len(_op_names(compiled.as_text(), "sort")) == 1
+    memory = compiled.memory_analysis()
+    assert memory.temp_size_in_bytes < 13 * (nl + nr), \
+        memory.temp_size_in_bytes
+    assert memory.output_size_in_bytes < (8 + 8 + 1) * Q13_SLOTS + (1 << 16)
+    assert memory.generated_code_size_in_bytes < 10 << 20

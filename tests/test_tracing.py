@@ -817,6 +817,36 @@ def test_join_span_and_the_requests_outer_join_counters(session, tier):
             if inside(w, j)] == ["join"]
 
 
+def test_join_span_says_the_right_side_rode_a_sort(session, monkeypatch):
+    """An outer join whose counts say that every right row has one partner
+    at most says `right_out=sort` on `ops.join`: the dimension's key is
+    distinct, so every fact row has one slot at most (the floor under
+    which every count goes by positions is lowered for the test alone).
+    Only the left side's planes still count as gathered, and the join's
+    one read, which brought those counts, lies inside the span."""
+    from spark_rapids_tpu.ops import gather
+    monkeypatch.setattr(gather, "KEPT_FLOOR", 4)
+    plan, inputs = _outer_plan(), _outer_inputs()
+    ex = PlanExecutor(mode="eager")
+    ex.execute(plan, inputs)                              # compile outside
+    done = []
+    spans = session(lambda: done.append(ex.execute(plan, inputs)))
+    res, got = done[0], spans.one("plan.execute")
+    (join,) = [m for m in res.metrics.values() if m.kind == "HashJoin"]
+    j = spans.one("ops.join")
+    assert (j["left_out"], j["right_out"]) == ("take", "sort") \
+        == (join.left_out, join.right_out)
+    # what is read of `d` is its key, which has no mask: one plane
+    # through `lmap`; the right side's `k` and `v` count for nothing
+    assert (got["join_planes_gathered"], got["join_slots_gathered"]) \
+        == (res.join_planes_gathered, res.join_slots_gathered) \
+        == (1, join.rows_out)
+    assert [s["site"] for s in spans.named("ops.host_sync")
+            if inside(s, j)] == ["join.left"]
+    t = res.compact()
+    assert t["n"].to_pylist()[30:] == [0] * 20      # the null-extended rows
+
+
 def test_join_span_of_an_inner_and_a_semi_join(session):
     """Every eager join has the span; `how` tells them apart."""
     inputs = {"t": _fact(), "d": _dim()}
@@ -1389,7 +1419,7 @@ def test_the_walk_finds_what_the_request_path_is_known_to_read():
                   ("plan/distributed.py", "_repartition_rel", "np.asarray"),
                   ("parallel/autoretry.py", "auto_retry_overflow", "bool"),
                   ("ops/join.py", "_sort_inner_join", "int"),
-                  ("ops/join.py", "full_join_parts", "device_get"),
+                  ("ops/join.py", "outer_join_parts", "device_get"),
                   ("ops/join_lookup.py", "member_mask", "int"),
                   ("ops/gather.py", "_count_kept", "int"),
                   ("ops/gather.py", "take", "device_get"),

@@ -7,6 +7,14 @@
 Prints one line a reading (`ms` is the fastest of three runs after the
 compiling one) and writes them all to `chiprun_out/probe_compaction.jsonl`.
 PERF.md section 6 (PR 42) holds the table read off it.
+
+`--slots N` (PR 45; `--rows` empty runs it alone) sets the two ways of
+putting a permuted side into an outer join's output order beside each
+other at N rows (15,334,665: `q13.batch`'s slots): `rows_by_slot`, a
+not-stable sort on a unique 32-bit key with 5 and 6 words riding, against
+`jnp.take` of an int64 plane through a random map.
+
+    chiprun --chips 1 -- python3 -m tools.probe_compaction --rows --slots 15334665
 """
 from __future__ import annotations
 
@@ -69,6 +77,7 @@ def main():
     ap.add_argument("--rows", type=int, nargs="*",
                     default=[15_000_000, 60_000_000])
     ap.add_argument("--words", type=int, nargs="*", default=[1, 2, 4, 8, 12])
+    ap.add_argument("--slots", type=int, nargs="*", default=[15_334_665])
     ap.add_argument("--seed", type=int, default=42)
     args = ap.parse_args()
     os.makedirs(os.path.dirname(OUT), exist_ok=True)
@@ -151,6 +160,24 @@ def main():
                                     kept=kept)
                 report(n, name, kept, w, first, best, one_in=one_in)
         del plane, wide
+
+    for n in args.slots:
+        # a permuted side to its slots: one sort on the slot, or a gather
+        # through the map a plane
+        rng = np.random.default_rng(args.seed)
+        where = rng.permutation(n).astype(np.int32)
+        slot, none = jnp.asarray(where), jnp.zeros((0,), jnp.int32)
+        wide = jnp.asarray(rng.integers(-2**62, 2**62, n, dtype=np.int64))
+        plane = jnp.asarray(where) + jnp.int32(7)
+        for w, arrays in ((5, [wide, wide + 1, plane]),
+                          (6, [wide, wide + 1, plane, plane + 1])):
+            first, best = timed(
+                gather.rows_by_slot, slot, none, arrays, slots=n,
+                groups=gather.ride_groups(gather.plane_words(arrays)))
+            report(n, "slot_sort", n, w, first, best)
+        first, best = timed(take_all, [wide], slot)
+        report(n, "take.int64", n, 2, first, best)
+        del where, slot, wide, plane
 
 
 if __name__ == "__main__":
