@@ -5,7 +5,7 @@ machine-GENERATES the plans to check them on. A `FuzzCase` is a seeded
 random operator DAG (Scan, Filter, Project, FusedSelect, HashJoin,
 HashAggregate, Sort, TopK, Limit, Union, Exchange — the full node set,
 including the optimizer-produced kinds, authored directly) plus the bound
-tables it runs over. Every case must satisfy six properties:
+tables it runs over. Every case must satisfy five properties:
 
 1. the authored plan VERIFIES (generator correctness — schema, typing and
    pruning layers clean);
@@ -25,13 +25,7 @@ tables it runs over. Every case must satisfy six properties:
    inside the certified `[lo, hi]` interval and the observed eager
    bytes stay at or under the certified byte bound; and the optimizer
    may only keep or tighten the root's certified bounds (a rewrite
-   that loosens a proof is a bug even when results agree);
-6. the plan executed with the co-placement rule ON
-   (SPARK_RAPIDS_TPU_PLACEMENT, plan/optimizer.py placement rule)
-   agrees bit-for-bit with the placement-OFF run, error class included
-   — moving a subtree onto a host worker thread overlapped with device
-   execution may change *where* it runs, never *what* it returns
-   (docs/optimizer.md#placement).
+   that loosens a proof is a bug even when results agree).
 
 Determinism is a contract: `gen_case(seed)` builds the same DAG (same
 fingerprint) and the same table bytes every time — `random.Random(seed)`
@@ -84,10 +78,6 @@ class FuzzResult:
     # bytes inside the certified bounds, every op, every run) and
     # monotonicity (optimized root bound <= authored root bound)
     cert_sound: Optional[bool] = None
-    # property 6 (docs/optimizer.md#placement): placement-on vs
-    # placement-off bit-exact parity, error class included — co-placement
-    # may change WHERE a subtree runs, never what it returns
-    placement_parity: Optional[bool] = None
     error: Optional[str] = None
 
     @property
@@ -95,8 +85,7 @@ class FuzzResult:
         return (self.verified and self.optimized_verified
                 and self.error is None and self.parity is not False
                 and self.adaptive_parity is not False
-                and self.cert_sound is not False
-                and self.placement_parity is not False)
+                and self.cert_sound is not False)
 
 
 # ---- deterministic relation/expression generation ---------------------------
@@ -487,55 +476,9 @@ def run_case(case: FuzzCase, *, execute: bool = True) -> FuzzResult:
                      f"warm={runs[1]!r}")
         return res
 
-    # property 6: the same plan with the co-placement rule off and on —
-    # the ON run takes the rule's certified cold path (fuzz tables are
-    # tiny, so eligible build sides place readily) and must agree
-    # bit-for-bit, error class included. Fresh static scope per run: the
-    # knob is read at use time (config.py's monkeypatch contract), and a
-    # stats store would make the second run warm, entangling this with
-    # property 4. Join-free plans skip the A/B — the rule fires only on
-    # HashJoin build sides, so on==off is vacuous there and the paired
-    # executions would double corpus cost for zero discrimination.
-    import os
-    if "HashJoin" not in case.kinds:
-        res.placement_parity = True
-        return _finish_cert_soundness(case, res, cert_runs, bound,
-                                      input_dtypes, input_nullable)
-    pouts = {}
-    prev = os.environ.get("SPARK_RAPIDS_TPU_PLACEMENT")
-    try:
-        for pon in (False, True):
-            os.environ["SPARK_RAPIDS_TPU_PLACEMENT"] = \
-                "on" if pon else "off"
-            with stats_mod.scoped_store(None):
-                ex = PlanExecutor(mode="eager", optimize=True)
-                try:
-                    r = ex.execute(case.plan, dict(case.tables))
-                    pouts[pon] = ("ok", r.compact().to_pydict())
-                    cert_runs.append(r)
-                except Exception as e:
-                    pouts[pon] = ("err", type(e).__name__)
-    finally:
-        if prev is None:
-            os.environ.pop("SPARK_RAPIDS_TPU_PLACEMENT", None)
-        else:
-            os.environ["SPARK_RAPIDS_TPU_PLACEMENT"] = prev
-    res.placement_parity = pouts[False] == pouts[True]
-    if not res.placement_parity:
-        res.error = (f"placement parity broke: off={pouts[False]!r} "
-                     f"on={pouts[True]!r}")
-        return res
-
-    return _finish_cert_soundness(case, res, cert_runs, bound,
-                                  input_dtypes, input_nullable)
-
-
-def _finish_cert_soundness(case, res, cert_runs, bound, input_dtypes,
-                           input_nullable):
-    """Property 5 (soundness half): every successful run — unoptimized,
-    optimized, cold and warm, placement off and on — stays inside the
-    certified bounds of ITS executed plan (cold and warm may have
-    rewritten differently)."""
+    # property 5 (soundness half): every successful run — unoptimized,
+    # optimized, cold and warm — stays inside the certified bounds of ITS
+    # executed plan (cold and warm may have rewritten differently)
     for r in cert_runs:
         bad = _cert_soundness(case, r, bound, input_dtypes,
                               input_nullable)

@@ -33,8 +33,6 @@ equivalents, all read at use time (not import time) so tests can monkeypatch:
 | SPARK_RAPIDS_TPU_EXCHANGE_PACK   | on   | exchange transport packing (plan/transport.py, docs/distributed.md#transport): ship packed columnar wire planes across hash/broadcast/gather edges; "off" restores the byte-identical legacy per-column payload |
 | SPARK_RAPIDS_TPU_EXCHANGE_CODECS | auto | codec families the transport layer may choose from: auto (for,dict,rle,bitpack), none (layout-only pass-through), or a comma subset |
 | SPARK_RAPIDS_TPU_EXCHANGE_ASYNC  | off  | async exchange dispatch: an Exchange's pack+transfer runs on a worker thread and overlaps downstream compute until its consumer resolves it (overlap-ms on OperatorMetrics) |
-| SPARK_RAPIDS_TPU_PLACEMENT       | off  | co-placement optimizer rule (plan/optimizer.py, docs/optimizer.md#placement): annotate cheap/small subtrees "host" and execute them on a worker thread overlapped with device execution of the sibling side; "off" keeps the single-backend walk byte-identical |
-| SPARK_RAPIDS_TPU_PLACEMENT_BYTES | 1 MiB | cold-path placement threshold: a candidate subtree qualifies for host placement when its certified output-byte hi-bound is at or below this (warm fingerprints use backend-keyed observed wall instead) |
 | SPARK_RAPIDS_TPU_VERIFY_PLANS    | 0    | static plan verifier gate (analysis/verifier.py): 1 verifies every plan pre-execution and every optimizer rule's output; on in tests (conftest), off in production |
 | SPARK_RAPIDS_TPU_STATS           | on   | per-fingerprint operator-stats store (plan/stats.py, docs/adaptive.md): observed cardinalities drive join build sides / exchange modes, cap seeding, chunk sizing, and kernel tie-breaks; "off" restores fully static decisions |
 | SPARK_RAPIDS_TPU_STATS_CAPACITY  | 256  | stats store LRU bound: per-(backend, fingerprint) plan entries retained (subtree/kernel tables scale off this) |
@@ -47,7 +45,7 @@ equivalents, all read at use time (not import time) so tests can monkeypatch:
 | SPARK_RAPIDS_TPU_SERVING_CACHE_ENTRIES | 64 | plan-result cache LRU bound (serving/cache.py); 0 disables the cache |
 | SPARK_RAPIDS_TPU_SERVING_CACHE_BYTES | 256 MiB | plan-result cache RESIDENT-BYTES bound: cached result tables are live buffers no quota charges, so the cache evicts LRU past this and refuses any single result larger than it |
 | SPARK_RAPIDS_TPU_SERVING_CACHE_TTL_S | 300 | plan-result cache entry time-to-live (seconds) |
-| SPARK_RAPIDS_TPU_SERVING_OVER_QUOTA | reject | what a plan whose quota charge exceeds the session's remaining quota ceiling does: reject (typed ServingRejectedError naming session + operator, before compilation) / degrade (run on the CPU tier — the device quota does not bind there) / partial (offload enough certified subtrees to host threads that the DEVICE-placed remainder fits the quota, falling back to the CPU tier only when no split fits — docs/serving.md#partial-placement) |
+| SPARK_RAPIDS_TPU_SERVING_OVER_QUOTA | reject | what a plan whose quota charge exceeds the session's remaining quota ceiling does: reject (typed ServingRejectedError naming session + operator, before compilation) / degrade (run on the CPU tier — the device quota does not bind there) |
 | SPARK_RAPIDS_TPU_SERVING_BACKPRESSURE | block | submit() behavior at a full queue: block (wait for space) / reject (fast ServingRejectedError); per-submit override wins |
 | SPARK_RAPIDS_TPU_SERVING_FEEDBACK | on | dispatch-fairness feedback loop (serving/scheduler.py): a session's WDRR credit grant scales down by its decayed cumulative wall-ms + retry cost, floored at a quarter of the configured weight; "off" restores pure weight-proportional credit |
 | SPARK_RAPIDS_TPU_SERVING_FEEDBACK_HALFLIFE_S | 300 | half-life of the feedback cost decay — one bad hour fades instead of starving a tenant forever; <=0 disables decay (cost only accumulates) |
@@ -306,32 +304,6 @@ def exchange_async() -> bool:
     return v == "on"
 
 
-def placement_enabled() -> bool:
-    """Co-placement optimizer rule gate (plan/optimizer.py,
-    docs/optimizer.md#placement): when on, the post-fixpoint placement
-    pass may annotate small/cheap exclusive subtrees "host" and the
-    executor runs them on a worker thread overlapped with device
-    execution of the sibling side (the PendingRel async-resolve shape
-    applied to a whole subtree; measured overlap-ms lands on the
-    consuming operator's metrics). Off (default) keeps the
-    single-backend walk byte-identical — no annotation, no thread.
-    Same strict-typo policy as the kernel selectors."""
-    v = os.environ.get("SPARK_RAPIDS_TPU_PLACEMENT", "off")
-    if v not in ("on", "off"):
-        raise ValueError(
-            f"SPARK_RAPIDS_TPU_PLACEMENT={v!r}: expected on or off")
-    return v == "on"
-
-
-def placement_bytes() -> int:
-    """Cold-path host-placement byte threshold: a candidate subtree with
-    no observed wall on either backend qualifies for host placement only
-    when its certified output-byte hi-bound (analysis/footprint.py) is
-    at or below this. Warm fingerprints ignore it — backend-keyed
-    observed wall decides instead (plan/stats.observed_wall)."""
-    return max(1, _int_env("SPARK_RAPIDS_TPU_PLACEMENT_BYTES", 1 << 20))
-
-
 def verify_plans() -> bool:
     """Static plan verifier gate (analysis/verifier.py, docs/analysis.md):
     when on, PlanExecutor.execute() verifies the (optimized) plan before
@@ -457,16 +429,13 @@ def serving_over_quota() -> str:
     ceiling: "reject" raises a typed ServingRejectedError naming the
     session and the operator that set the certified peak, BEFORE any
     compilation; "degrade" runs the plan on the CPU tier, where the
-    device quota does not bind; "partial" offloads certified subtrees
-    to co-placement host threads until the device-placed remainder fits
-    the quota (charging only the device footprint), falling back to the
-    CPU tier when no split fits (docs/serving.md#partial-placement).
+    device quota does not bind.
     Same strict-typo policy as the kernel selectors."""
     v = os.environ.get("SPARK_RAPIDS_TPU_SERVING_OVER_QUOTA", "reject")
-    if v not in ("reject", "degrade", "partial"):
+    if v not in ("reject", "degrade"):
         raise ValueError(
-            f"SPARK_RAPIDS_TPU_SERVING_OVER_QUOTA={v!r}: expected reject, "
-            "degrade, or partial")
+            f"SPARK_RAPIDS_TPU_SERVING_OVER_QUOTA={v!r}: expected reject "
+            "or degrade")
     return v
 
 

@@ -125,8 +125,8 @@ def _sessionctx():
 def _op_span(node: PlanNode, idx: int, tier: str = "device"):
     """The eager tiers' per-operator bracket (utils/tracing.py). `op` is
     `<toposort index>.<kind>`, the name the operator's scope carries
-    inside a capped program; `tier` is where it ran: device, host
-    (co-placement thread) or degraded (CPU tier); a join's says `how`."""
+    inside a capped program; `tier` is where it ran: device or degraded
+    (CPU tier); a join's says `how`."""
     how = {"how": node.how} if isinstance(node, HashJoin) else {}
     return bracket("plan.op", op=_scope_name(idx, node),
                    label=_span_text(node.label), tier=tier, **how)
@@ -318,126 +318,6 @@ def _input_has_floats(t) -> bool:
             np.issubdtype(np.dtype(c.dtype.storage_dtype()), np.floating)
             for c in t.columns)
     return bool(getattr(t, "has_floats", True))
-
-
-# ---- co-placement dispatch (placement rule, docs/optimizer.md#placement) ----
-
-def _subtree_sources(node: PlanNode) -> frozenset:
-    """Scan sources reachable from `node` — invariant under optimizer
-    rewrites (pruning narrows a scan's projection but keeps its source;
-    fusions and Sort+Limit->TopK rebuild nodes but never move a scan
-    across a join boundary), which is what makes it a rewrite-stable
-    subtree identity for the remap below."""
-    out = set()
-    stack, seen = [node], set()
-    while stack:
-        n = stack.pop()
-        if id(n) in seen:
-            continue
-        seen.add(id(n))
-        if isinstance(n, Scan):
-            out.add(n.source)
-        stack.extend(n.children)
-    return frozenset(out)
-
-
-def _remap_placement_labels(authored, plan, labels):
-    """Serving-forced placement labels name AUTHORED subtree roots
-    (serving/scheduler._partial_placement admits against the authored
-    cert); the executed plan may have rebuilt the root under a new label
-    (Sort+Limit fused to TopK, Filter+Project to FusedSelect). Labels
-    present in the executed plan pass through; a renamed one remaps to
-    the unique MAXIMAL executed node reading the same scan-source set —
-    ambiguity (two joins over the same sources) skips the label rather
-    than guessing, so a lost remap costs only the offload, never
-    correctness."""
-    executed = {n.label for n in plan.nodes}
-    by_label = {n.label: n for n in authored.nodes}
-    parents: Dict[int, List[PlanNode]] = {}
-    for n in plan.nodes:
-        for c in n.children:
-            parents.setdefault(id(c), []).append(n)
-    out = []
-    for lbl in labels:
-        if lbl in executed:
-            out.append(lbl)
-            continue
-        a = by_label.get(lbl)
-        if a is None:
-            continue
-        srcs = _subtree_sources(a)
-        matches = [n for n in plan.nodes if n is not plan.root
-                   and _subtree_sources(n) == srcs]
-        ids = {id(n) for n in matches}
-        maximal = [n for n in matches
-                   if all(id(p) not in ids
-                          for p in parents.get(id(n), []))]
-        if len(maximal) == 1:
-            out.append(maximal[0].label)
-    return out
-
-
-class _PendingHostRel:
-    """A host-placed subtree still in flight on a co-placement worker
-    thread (the PendingRel async-resolve shape from plan/distributed.py
-    applied to a WHOLE subtree): the main walk launches every host
-    subtree up front — a placed subtree is self-contained, its leaves
-    bind only to plan inputs — and keeps executing the device side; the
-    consuming operator `resolve()`s at its join point. The host wall
-    that ran while the main thread was NOT blocked waiting here is the
-    consumer's measured `placement_overlap_ms`. The join is LOCK-FREE
-    (a bare timeout-less `Thread.join`, no engine lock held — the
-    lint_concurrency blocking-under-lock rule's contract). A host
-    failure raises the original error ONCE at the consumer, whose
-    fault-retry loop gets REAL re-execution: each later resolve re-runs
-    the subtree synchronously instead of re-raising a cached error."""
-
-    pending = True
-
-    def __init__(self, fn, root_label: str):
-        self._fn = fn
-        self.root_label = root_label
-        self._outputs = None        # id(node) -> Table, whole subtree
-        self._node_metrics = None   # label -> OperatorMetrics
-        self._err = None
-        self._t0 = self._t1 = 0.0
-        self._resolved = False
-
-        def work():
-            self._t0 = time.perf_counter()
-            try:
-                # _run_host_subtree blocks per node, so the subtree has
-                # genuinely COMPLETED on the thread — otherwise "async"
-                # would just defer the host work to the consumer and the
-                # overlap would be fiction
-                self._outputs, self._node_metrics = fn()
-            except BaseException as e:      # surfaces at the consumer
-                self._err = e
-            finally:
-                self._t1 = time.perf_counter()
-
-        self._thread = threading.Thread(
-            target=work, daemon=True, name="spark-rapids-tpu-coplace")
-        self._thread.start()
-
-    def resolve(self, consumer_metric: Optional[OperatorMetrics] = None):
-        """(outputs by node id, metrics by label); stamps the overlap on
-        `consumer_metric` at the first (joining) resolve."""
-        if not self._resolved:
-            w0 = time.perf_counter()
-            self._thread.join()
-            blocked = time.perf_counter() - w0
-            self._resolved = True
-            if consumer_metric is not None:
-                dur = self._t1 - self._t0
-                consumer_metric.placement_overlap_ms = \
-                    max(0.0, dur - blocked) * 1e3
-        if self._outputs is None:
-            err, self._err = self._err, None
-            if err is not None:
-                raise err
-            self._outputs, self._node_metrics = self._fn()
-        return self._outputs, self._node_metrics
 
 
 class _StreamBreaker(Exception):
@@ -833,26 +713,13 @@ class PlanExecutor:
     # ---- entry point ------------------------------------------------------
     def execute(self, plan: Plan,
                 inputs: Optional[Dict[str, Table]] = None,
-                tier: Optional[str] = None,
-                placement=None) -> PlanResult:
+                tier: Optional[str] = None) -> PlanResult:
         """Run `plan` over `inputs`. `tier` pins the execution tier:
         None/"device" is the normal path (device with breaker-gated CPU
         degradation); "cpu" runs the WHOLE plan on the degraded CPU tier
         without touching the device — the serving layer's route for
         over-quota admission under the degrade policy and for draining a
-        queue while the breaker is open (docs/serving.md).
-
-        `placement` (iterable of node LABELS) forces those subtrees onto
-        co-placement host worker threads in addition to anything the
-        optimizer's placement rule annotated — the serving layer's
-        partial-placement route (SPARK_RAPIDS_TPU_SERVING_OVER_QUOTA=
-        partial, docs/serving.md#partial-placement): offload enough of an
-        over-quota plan to host threads that the device remainder fits
-        the session quota. Labels that do not survive the optimizer
-        rewrite, or that fail the executor's subtree-exclusivity
-        validation, are silently skipped (execution stays correct; only
-        the offload is lost). Eager tier only — the capped tier traces
-        one XLA program and has no per-subtree dispatch to overlap."""
+        queue while the breaker is open (docs/serving.md)."""
         # request identity for the program's spans (utils/tracing.py):
         # the serving worker has scoped its ticket's number; a direct
         # call takes this executor's next one
@@ -863,8 +730,7 @@ class PlanExecutor:
         from ..ops.decimal_utils import overflow_counts
         with ctx.request_scope(request), bracket("plan.execute") as sp, \
                 overflow_counts() as nulled:
-            res = self._execute_request(plan, inputs, tier, placement,
-                                        nulled)
+            res = self._execute_request(plan, inputs, tier, nulled)
             # what the request lowered (utils/tracing.py): 0 once warm
             res.lowerings, res.lowering_ms = sp.lowered()
             sp.set_metadata(decimal_overflow_rows=res.decimal_overflow_rows,
@@ -962,8 +828,7 @@ class PlanExecutor:
             res.join_planes_gathered += int(m.planes_gathered)
             res.join_slots_gathered += int(m.slots_gathered)
 
-    def _execute_request(self, plan, inputs, tier, placement,
-                         nulled=()) -> PlanResult:
+    def _execute_request(self, plan, inputs, tier, nulled=()) -> PlanResult:
         if tier not in (None, "device", "cpu"):
             raise ValueError(f"unknown execution tier {tier!r} "
                              "(expected device or cpu)")
@@ -1002,19 +867,6 @@ class PlanExecutor:
         # cold-run cap seeding, and compared against the device budget
         # BEFORE any compilation when one is configured
         cert = self._certify(plan, inputs, bound)
-        # merged co-placement annotations (plan/optimizer.py placement
-        # rule, docs/optimizer.md#placement): the optimizer's observed/
-        # certified host placements plus any serving-forced labels.
-        # Annotation-only — the tree is never mutated; each label is
-        # re-validated against the EXECUTED plan's structure in
-        # _execute_eager (subtree exclusivity, no exchanges, no
-        # streaming-chain overlap) before a worker thread launches.
-        placements: Dict[str, str] = {}
-        if report is not None and not report.fell_back:
-            placements.update(report.placements)
-        if placement:
-            for lbl in _remap_placement_labels(authored, plan, placement):
-                placements[lbl] = "host"
         res = None
         if tier == "cpu":
             # pinned to the degraded tier: same machinery as a breaker
@@ -1047,8 +899,7 @@ class PlanExecutor:
                     (active_session(self.session)
                      if self.session is not None
                      else contextlib.nullcontext()):
-                res = self._execute(plan, inputs, schemas, source_fp,
-                                    cert, placements)
+                res = self._execute(plan, inputs, schemas, source_fp, cert)
         with span("plan.result"):
             res.cert = cert
             if nulled:  # eager tiers: one read-back, decimal plans only
@@ -1214,16 +1065,10 @@ class PlanExecutor:
         store = stats_mod.active_store()
         stats_gen = None if store is None else (store.uid,
                                                 store.generation)
-        # the placement rule's decisions depend on the knob state AND the
-        # cold-path byte threshold (read at use time per config.py's
-        # monkeypatch contract) — both join the cache key
-        placement_on = config.placement_enabled()
-        placement_bytes = config.placement_bytes() if placement_on else None
         key = (plan.root, tuple(sorted(bound.items())),
                tuple(sorted((n, t.num_rows) for n, t in inputs.items())),
                floats, streaming, mesh_peers, bc_rows, bc_bytes,
-               verify_rules, dtype_sig, stats_gen,
-               placement_on, placement_bytes)
+               verify_rules, dtype_sig, stats_gen)
         hit = self._opt_cache.get(key)
         if hit is None:
             bound_rows = {n: t.num_rows for n, t in inputs.items()}
@@ -1235,8 +1080,7 @@ class PlanExecutor:
                 plan, bound, bound_rows,
                 float_inputs=floats, streaming_sources=streaming,
                 mesh_peers=mesh_peers, verify_rules=verify_rules,
-                stats=store, backend=backend, input_dtypes=input_dtypes,
-                placement=placement_on, placement_bytes=placement_bytes)
+                stats=store, backend=backend, input_dtypes=input_dtypes)
             if (store is not None and not verify_rules
                     and opt is not plan and not report.fell_back
                     and report.stats_driven()):
@@ -1256,16 +1100,11 @@ class PlanExecutor:
                     # _verify_execution's `planned`)
                     planned=bool(mesh_peers and mesh_peers > 1))
                 if not rep.ok:
-                    # the static re-run keeps the placement knobs: with
-                    # no stats the rule falls back to its certified-bytes
-                    # cold path, which IS the static placement decision
                     opt, report = run_optimizer(
                         plan, bound, bound_rows,
                         float_inputs=floats, streaming_sources=streaming,
                         mesh_peers=mesh_peers, verify_rules=verify_rules,
-                        input_dtypes=input_dtypes,
-                        placement=placement_on,
-                        placement_bytes=placement_bytes)
+                        input_dtypes=input_dtypes)
                     report.stats_reverted = True
             hit = (opt, opt.resolve_schemas(bound), report)
             self._opt_cache[key] = hit
@@ -1311,10 +1150,9 @@ class PlanExecutor:
         except Exception:
             return None
 
-    def _execute(self, plan, inputs, schemas, source_fp=None, cert=None,
-                 placements=None):
+    def _execute(self, plan, inputs, schemas, source_fp=None, cert=None):
         if self.mode == "eager":
-            return self._execute_eager(plan, inputs, schemas, placements)
+            return self._execute_eager(plan, inputs, schemas)
         return self._execute_capped(plan, inputs, schemas, source_fp,
                                     cert)
 
@@ -1490,8 +1328,7 @@ class PlanExecutor:
             pass
 
     # ---- eager tier -------------------------------------------------------
-    def _execute_eager(self, plan, inputs, schemas,
-                       placements=None) -> PlanResult:
+    def _execute_eager(self, plan, inputs, schemas) -> PlanResult:
         from ..runtime.admission import operand_nbytes
         t_plan0 = time.perf_counter()
         results: Dict[int, Table] = {}
@@ -1518,30 +1355,8 @@ class PlanExecutor:
         chains = {} if dist is not None else self._stream_chains(plan, inputs)
         chain_interior = {id(n) for ch in chains.values() for n in ch[:-1]}
         node_index = {id(n): i for i, n in enumerate(plan.nodes)}
-        # co-placement dispatch (plan/optimizer.py placement rule,
-        # docs/optimizer.md#placement): validated host subtrees launch on
-        # worker threads UP FRONT — a placed subtree is self-contained
-        # (its leaves bind only to plan inputs), so its host execution
-        # overlaps the whole device walk, not just the sibling side. The
-        # consuming operator joins in _resolve_placed. Single-device only:
-        # the distributed tier has its own overlap story (async exchanges).
-        host_roots: Dict[int, List[PlanNode]] = {}
-        host_skip: set = set()
-        if placements and dist is None:
-            host_roots, host_skip = self._placement_subtrees(
-                plan, placements, inputs, chains, chain_interior)
-        request = _sessionctx().current_request()
-        for rid, sub in host_roots.items():
-            results[rid] = _PendingHostRel(
-                (lambda s: lambda: self._run_host_subtree(
-                    s, inputs, schemas, node_index, request))(sub),
-                sub[-1].label)
         try:
             for i, node in enumerate(plan.nodes):
-                if id(node) in host_skip:
-                    # runs on its co-placement worker thread; outputs and
-                    # metrics merge at the consumer's resolve
-                    continue
                 if id(node) in chain_interior:
                     continue        # runs inside its chain, at the tail
                 if id(node) in chains:
@@ -1578,10 +1393,6 @@ class PlanExecutor:
                                                      inputs, schemas, m,
                                                      metrics)
                             else:
-                                if host_roots:
-                                    child_tables = self._resolve_placed(
-                                        node, child_tables, results, m,
-                                        metrics)
                                 out = self._exec_eager_node(
                                     node, child_tables, inputs, schemas, m)
                                 _say_op(osp, m, node, child_tables, out)
@@ -1661,157 +1472,6 @@ class PlanExecutor:
                 res.dist_cap_escalations = dist.cap_escalations
         return res
 
-    # ---- co-placement host subtrees (docs/optimizer.md#placement) ---------
-    @staticmethod
-    def _placement_subtrees(plan, placements, inputs, chains,
-                            chain_interior):
-        """Re-validate every `label -> "host"` annotation against the
-        EXECUTED plan's structure and return ({id(root): postorder node
-        list}, {all claimed node ids}). Placements are annotations — the
-        optimizer never mutated the tree for them — so the executor owns
-        the safety checks: the subtree must be EXCLUSIVE (every interior
-        node consumed only inside it — its output merges at exactly one
-        join point), free of Exchanges (device-resident by construction),
-        disjoint from streaming chains (their interior never materializes
-        a Table to hand a thread), with every Scan bound to a Table.
-        Labels that fail (e.g. a serving-forced label the rewrite
-        renamed) are skipped, never an error: placement is an
-        optimization and must not fail a query that would otherwise
-        run."""
-        parents: Dict[int, List[PlanNode]] = {}
-        for n in plan.nodes:
-            for c in n.children:
-                parents.setdefault(id(c), []).append(n)
-        by_label = {n.label: n for n in plan.nodes}
-        roots: Dict[int, List[PlanNode]] = {}
-        claimed: set = set()
-        # plan.nodes order makes the claim order deterministic
-        for cand in plan.nodes:
-            if placements.get(cand.label) != "host" or cand is plan.root:
-                continue
-            sub: List[PlanNode] = []
-            seen: set = set()
-
-            def walk(n):
-                if id(n) in seen:
-                    return
-                seen.add(id(n))
-                for c in n.children:
-                    walk(c)
-                sub.append(n)
-
-            walk(cand)
-            ids = {id(s) for s in sub}
-            if ids & claimed:
-                continue
-            ok = True
-            for s in sub:
-                if isinstance(s, Exchange) or id(s) in chain_interior \
-                        or id(s) in chains:
-                    ok = False
-                    break
-                if isinstance(s, Scan) and \
-                        not isinstance(inputs.get(s.source), Table):
-                    ok = False
-                    break
-                if s is not cand and any(id(p) not in ids
-                                         for p in parents.get(id(s), [])):
-                    ok = False   # interior node consumed outside: not
-                    break        # exclusive, no single join point
-            if ok:
-                roots[id(cand)] = sub
-                claimed |= ids
-        return roots, claimed
-
-    def _run_host_subtree(self, sub, inputs, schemas, node_index,
-                          request: int):
-        """Execute one host-placed subtree (postorder node list) — the
-        co-placement worker thread's body, also re-run synchronously on
-        the main thread when a consumer retries after a host failure.
-        Pins JAX dispatch to the CPU device and the kernel registry to
-        the cpu backend (via m.placement, see _kernel_choice); copies the
-        subtree's OWN scan bindings host-side only. Fault injection stays
-        LIVE (thread-local suppression is not set here — host placement
-        is an optimization of a healthy device, not degradation), so
-        injected faults surface at the consumer's retry loop with the
-        same classes as the device walk. Admission wrappers apply as
-        everywhere. Returns (outputs by id(node), metrics by label);
-        every output is blocked-until-ready so the overlap the consumer
-        measures is real completed work."""
-        from ..runtime.admission import operand_nbytes
-        cpu = _cpu_device()
-        ctx = (jax.default_device(cpu) if cpu is not None
-               else contextlib.nullcontext())
-        outs: Dict[int, Table] = {}
-        ms: Dict[str, OperatorMetrics] = {}
-        # the worker thread joins the launching request's spans
-        with ctx, _sessionctx().request_scope(request):
-            host_inputs = dict(inputs)
-            for n in sub:
-                if isinstance(n, Scan):
-                    host_inputs[n.source] = _table_to_cpu(
-                        inputs[n.source], cpu)
-            for n in sub:
-                childs = [outs[id(c)] for c in n.children]
-                m = OperatorMetrics(label=n.label, kind=n.kind,
-                                    describe=n.describe())
-                m.placement = "host"  # set BEFORE dispatch: pins the
-                #                       registry to cpu kernels
-                t0 = time.perf_counter()
-                with _op_span(n, node_index[id(n)], "host") as osp:
-                    self._faultinj_point(n)
-                    out = self._exec_eager_node(n, childs, host_inputs,
-                                                schemas, m)
-                    _say_op(osp, m, n, childs, out)
-                    with span("plan.wait", site="host_op"):
-                        jax.block_until_ready(
-                            [c.data for c in out.columns])
-                m.wall_ms = (time.perf_counter() - t0) * 1e3
-                m.rows_in = sum(t.num_rows for t in childs)
-                m.rows_out = out.num_rows
-                m.bytes_out = operand_nbytes(out)
-                ms[n.label] = m
-                outs[id(n)] = out
-        return outs, ms
-
-    @staticmethod
-    def _resolve_placed(node, child_tables, results, m, metrics):
-        """Join point of the co-placement dispatch: resolve any host
-        subtree this operator consumes — a LOCK-FREE, timeout-less
-        Thread.join (no engine lock is held anywhere on this path; the
-        lint_concurrency contract for blocking joins) — merge the
-        subtree's per-op metrics and ALL its node outputs (the degraded
-        tier's salvage walk may need interior outputs too), and stamp
-        the overlapped host wall on THIS consumer's metric row. Runs
-        inside the consumer's fault-retry loop, so a host-subtree
-        failure gets the plan-level retry/degrade policy: the first
-        resolve raises the original error, each retry re-runs the
-        subtree synchronously."""
-        resolved = list(child_tables)
-        for idx, c in enumerate(node.children):
-            r = resolved[idx]
-            if not isinstance(r, _PendingHostRel):
-                continue
-            outs, hms = r.resolve(m)
-            metrics.update(hms)
-            results.update(outs)
-            resolved[idx] = outs[id(c)]
-        return resolved
-
-    @staticmethod
-    def _drain_placed(results, metrics):
-        """Force-resolve every in-flight co-placement handle before the
-        degraded tier salvages `results` — the salvage walk needs real
-        Tables, and a placed subtree's interior outputs must be present
-        for consumers past the degrade point. A host failure raises
-        here; the salvage except treats it like lost device buffers and
-        restarts from the scans."""
-        for r in list(results.values()):
-            if isinstance(r, _PendingHostRel):
-                outs, hms = r.resolve(None)
-                metrics.update(hms)
-                results.update(outs)
-
     # ---- degraded CPU tier ------------------------------------------------
     def _execute_degraded(self, plan, inputs, schemas, results, metrics,
                           start: int, t_plan0: float, mode: str,
@@ -1839,7 +1499,6 @@ class PlanExecutor:
                else contextlib.nullcontext())
         with faultinj.suppressed(), ctx:
             try:
-                self._drain_placed(results, metrics)
                 cpu_results = {k: _table_to_cpu(t, cpu)
                                for k, t in results.items()}
                 cpu_inputs = {k: _table_to_cpu(t, cpu)
@@ -2179,14 +1838,10 @@ class PlanExecutor:
         and stamp the choice on the operator's metrics. On the degraded CPU
         tier the backend is pinned to "cpu" (default_backend still reports
         the quarantined platform under jax.default_device): auto-selection
-        must not hand work back to the device the breaker just isolated.
-        Host-PLACED operators (co-placement worker threads, m.placement ==
-        "host") pin the same way — the whole point of the placement is
-        that the subtree does not touch the device."""
+        must not hand work back to the device the breaker just isolated."""
         from ..ops.registry import REGISTRY
         backend = "cpu" if (pin_degraded and m is not None
-                            and (m.degraded or m.placement == "host")) \
-            else None
+                            and m.degraded) else None
         choice = REGISTRY.select(op, sig, backend=backend)
         if m is not None:
             m.kernel = choice.label

@@ -109,16 +109,6 @@ class OperatorMetrics:
     exchange_codecs: str = ""
     exchange_overlap_ms: float = 0.0
     n_peers: int = 0               # mesh size the operator ran over
-    # co-placement metrics (plan/optimizer.py placement rule,
-    # docs/optimizer.md#placement): `placement` is "host" when the
-    # operator executed on a co-placement host worker thread (the
-    # optimizer placed its subtree on CPU overlapped with device work),
-    # "" for the device walk. `placement_overlap_ms` lands on the
-    # CONSUMING operator at the join point: the host-subtree wall that
-    # ran concurrently with device execution of the sibling side (0 when
-    # the device side finished first and the join blocked).
-    placement: str = ""
-    placement_overlap_ms: float = 0.0
 
     def to_dict(self) -> Dict:
         d = dataclasses.asdict(self)
@@ -216,11 +206,4 @@ def render_profile(rows: List[OperatorMetrics],
             if m.exchange_overlap_ms:
                 parts.append(f"overlap {m.exchange_overlap_ms:.3f} ms")
             out.append(f"  dist: {', '.join(parts)}")
-        if m.placement or m.placement_overlap_ms:
-            parts = []
-            if m.placement:
-                parts.append(m.placement)
-            if m.placement_overlap_ms:
-                parts.append(f"overlap {m.placement_overlap_ms:.3f} ms")
-            out.append(f"  placement: {', '.join(parts)}")
     return "\n".join(out)

@@ -178,7 +178,7 @@ def subtree_fingerprints(root: PlanNode) -> Dict[int, str]:
 
 RULE_NAMES = ("constant_folding", "predicate_pushdown", "limit_pushdown",
               "build_side", "column_pruning", "select_fusion",
-              "scan_pruning", "exchange_planning", "placement")
+              "scan_pruning", "exchange_planning")
 
 
 # ---- pruning-conjunct extraction (shared with the executor's scan IO) -------
@@ -257,13 +257,6 @@ class OptimizeReport:
     # must never silently mix cold and warm decisions.
     decision_sources: Dict[str, str] = dataclasses.field(
         default_factory=dict)
-    # co-placement annotation (placement rule, docs/optimizer.md#
-    # placement): subtree-root label -> "host" for every subtree the
-    # executor should run on a host worker thread overlapped with device
-    # execution of the sibling side. ANNOTATION ONLY — the tree is never
-    # mutated, so fingerprints (and with them the compiled-program and
-    # caps memos) are placement-independent.
-    placements: Dict[str, str] = dataclasses.field(default_factory=dict)
     # a stats-driven rewrite failed the verify_rewrite gate and the
     # pipeline re-ran statically (defensive — the same guards protect
     # both paths; see PlanExecutor._optimized)
@@ -295,13 +288,6 @@ class OptimizeReport:
                 return True
             if key.endswith("/build_side") and v.startswith("swap"):
                 return True
-            if key.endswith("/placement") and v.startswith("host"):
-                # an observed-wall-driven host placement changes HOW the
-                # plan executes — it rides the same verify-or-revert gate
-                # as every stats-driven rewrite (the tree is unchanged,
-                # so the verify trivially passes, but a revert restores
-                # the static placement decision too)
-                return True
         return False
 
     def to_dict(self) -> Dict:
@@ -316,7 +302,6 @@ class OptimizeReport:
                 "exchanges_elided": self.exchanges_elided,
                 "sharding": dict(self.sharding),
                 "decision_sources": dict(self.decision_sources),
-                "placements": dict(self.placements),
                 "stats_driven": self.stats_driven(),
                 "stats_reverted": self.stats_reverted}
 
@@ -344,10 +329,6 @@ class OptimizeReport:
             lines.append("  sharding:")
             for label, spec in self.sharding.items():
                 lines.append(f"    {label}: {spec}")
-        if self.placements:
-            lines.append("  placement: " + ", ".join(
-                f"{label}->{where}"
-                for label, where in sorted(self.placements.items())))
         if self.decision_sources:
             lines.append("  decision sources"
                          + (" [STATS REVERTED: observed-driven rewrite "
@@ -1242,104 +1223,6 @@ def _plan_exchanges(root: PlanNode, ctx: "_Ctx", n_peers: int):
     return new_root, sum(stats.values())
 
 
-# ---- co-placement (placement rule, docs/optimizer.md#placement) -------------
-
-def _host_placeable(sub_nodes, ctx: "_Ctx") -> bool:
-    """Whether a candidate subtree may run on a host worker thread at
-    all: exclusive (no node inside it is DAG-shared with a consumer
-    outside it — a deferred result another branch reads synchronously
-    would serialize the overlap away), no Exchange boundaries (the
-    distributed tier owns those), and no streaming-bound scans (the
-    morsel pipeline's prefetch threads stay single-walk)."""
-    for s in sub_nodes:
-        if isinstance(s, Exchange):
-            return False
-        if id(s) in ctx.shared:
-            return False
-        if isinstance(s, Scan) and (s.source in ctx.streaming
-                                    or getattr(s, "parquet", None)
-                                    is not None):
-            return False
-    return True
-
-
-def _plan_placement(root: PlanNode, ctx: "_Ctx",
-                    max_bytes: Optional[int] = None) -> int:
-    """Post-fixpoint co-placement annotation: pick HashJoin build
-    (right) sides to run on a host worker thread OVERLAPPED with device
-    execution of the probe side (plan/executor.py's co-placement
-    dispatch; "Revisiting Co-Processing for Hash Joins on the Coupled
-    CPU-GPU Architecture", PAPERS.md). PURE ANNOTATION — the tree is
-    never mutated (fingerprints and compiled-program memos stay
-    placement-independent); the executor reads `report.placements`
-    (subtree-root label -> "host").
-
-    Decision, per candidate: WARM fingerprints compare backend-keyed
-    observed cumulative subtree wall (plan/stats.observed_wall) — host
-    wins when its "cpu" wall is at or below the device wall for the
-    same subtree shape; COLD subtrees qualify when every node's
-    certified output-byte hi-bound (analysis/footprint.py) fits
-    `max_bytes` (config.placement_bytes() when None). Either way the
-    decision source is stamped on `report.decision_sources`
-    ("<join label>/placement" -> "host|keep (observed:N|certified:B)"),
-    and an observed-driven host placement counts as stats-driven — the
-    executor's verify-or-revert gate covers it like every other
-    stats-driven rewrite. Placements never nest: a join inside (or
-    overlapping) an already-placed subtree is skipped — its build side
-    already runs on the host thread as part of the outer subtree.
-    Single-node subtrees (a bare scan) are skipped: there is no host
-    compute to overlap, only a round trip."""
-    from .. import config
-    report = ctx.report
-    if max_bytes is None:
-        max_bytes = config.placement_bytes()
-    est = ctx.est
-    placed: set = set()
-    n_placed = 0
-    for n in _toposort(root):
-        if not isinstance(n, HashJoin):
-            continue
-        cand = n.right
-        sub = list(_toposort(cand))
-        ids = {id(s) for s in sub}
-        if len(sub) < 2 or id(n) in placed or ids & placed:
-            continue
-        if not _host_placeable(sub, ctx):
-            continue
-        decision = None
-        if est.stats is not None and est.backend is not None:
-            fp = est._subtree_fp(cand)
-            host = est.stats.observed_wall("cpu", fp)
-            dev = est.stats.observed_wall(est.backend, fp)
-            if host is not None and dev is not None:
-                runs = min(host[1], dev[1])
-                cmp = "<=" if host[0] <= dev[0] else ">"
-                decision = ("host" if host[0] <= dev[0] else "keep",
-                            f"observed:{runs}; cpu:{host[0]:.3f}ms{cmp}"
-                            f"{est.backend}:{dev[0]:.3f}ms")
-        if decision is None:
-            sub_hi: Optional[int] = 0
-            for s in sub:
-                b = ctx.cert_out_bytes_hi(s)
-                if b is None:
-                    sub_hi = None
-                    break
-                sub_hi = max(sub_hi, b)
-            if sub_hi is not None and sub_hi <= max_bytes:
-                decision = ("host",
-                            f"certified:{sub_hi}B<={max_bytes}B")
-            else:
-                decision = ("keep", "unbounded" if sub_hi is None else
-                            f"certified:{sub_hi}B>{max_bytes}B")
-        report.decision_sources[f"{n.label}/placement"] = \
-            f"{decision[0]} ({decision[1]})"
-        if decision[0] == "host":
-            report.placements[cand.label] = "host"
-            placed |= ids | {id(n)}
-            n_placed += 1
-    return n_placed
-
-
 # ---- fall-back diagnostics (analysis/verifier.py, docs/analysis.md) ---------
 
 def _plan_error(root: PlanNode, bound=None) -> Optional[PlanValidationError]:
@@ -1389,7 +1272,6 @@ def _fall_back(plan: Plan, report: OptimizeReport):
     report.exchanges_elided = 0
     report.sharding = {}
     report.decision_sources = {}
-    report.placements = {}
     report.fingerprint = report.source_fingerprint
     return plan, report
 
@@ -1451,9 +1333,7 @@ def optimize(plan: Plan,
              verify_rules: bool = False,
              stats=None,
              backend: Optional[str] = None,
-             input_dtypes: Optional[Dict[str, Dict]] = None,
-             placement: bool = False,
-             placement_bytes: Optional[int] = None
+             input_dtypes: Optional[Dict[str, Dict]] = None
              ) -> Tuple[Plan, OptimizeReport]:
     """Run the rule pipeline to fixpoint over `plan`. `bound` maps scan
     source -> actual column names and `bound_rows` -> actual row counts
@@ -1487,14 +1367,7 @@ def optimize(plan: Plan,
     (analysis/footprint.py): broadcast-join legality becomes a proven
     byte ceiling (`SPARK_RAPIDS_TPU_BROADCAST_BYTES`) and estimator
     dead-ends fall back to certified rows-hi bounds with a
-    `certified:<bound>` decision source. `placement` (the executor
-    passes `config.placement_enabled()`) runs the post-fixpoint
-    co-placement pass (`_plan_placement`): HashJoin build sides
-    annotated "host" on `report.placements` for the executor's
-    overlapped host-thread dispatch — single-device walks only (a mesh
-    execution keeps its exchange boundaries), annotation-only (the
-    returned plan and fingerprint are placement-independent);
-    `placement_bytes` overrides the cold certified-byte threshold.
+    `certified:<bound>` decision source.
     Returns the optimized Plan (the SAME object when nothing fired) +
     the report."""
     report = OptimizeReport(rules={name: 0 for name in RULE_NAMES})
@@ -1539,11 +1412,6 @@ def optimize(plan: Plan,
                     return _fall_back(plan, report)
             root = new_root
             report.rules["exchange_planning"] += n
-        if placement and (mesh_peers is None or mesh_peers <= 1):
-            ctx = _Ctx(root, bound, bound_rows, report, float_inputs,
-                       streaming, stats, backend, input_dtypes)
-            report.rules["placement"] += _plan_placement(
-                root, ctx, placement_bytes)
     except PlanValidationError as err:
         # an invalid mid-pipeline rewrite can detonate inside a LATER
         # rule's schema resolution (not just at the end-of-pipeline

@@ -87,14 +87,6 @@ class StatsStore:
     - subtrees: (backend, subtree fingerprint) -> {rows (high-water),
                 runs} — observed output cardinality of that exact
                 operator subtree, the optimizer's estimate override
-    - walls:    (backend, subtree fingerprint) -> {wall_ms (EWMA of the
-                CUMULATIVE subtree wall — the node plus every
-                descendant), runs} — the placement rule's warm input:
-                host-vs-device wall for the same subtree shape. Kept
-                separate from `subtrees` because a host-placed op inside
-                a device result files its wall under "cpu" (that is
-                where it ran) while its cardinality is
-                backend-independent
     - io:       (backend, scan subtree fingerprint) -> {rows_per_ms
                 (EWMA), runs} — streaming-scan decode throughput
     - kernels:  (backend, op, signature repr) -> {kernel name:
@@ -132,7 +124,6 @@ class StatsStore:
             (path or None)
         self._plans: Dict[Tuple, Dict] = LruDict(self.capacity)
         self._subtrees: Dict[Tuple, Dict] = LruDict(self.capacity * 16)
-        self._walls: Dict[Tuple, Dict] = LruDict(self.capacity * 16)
         self._io: Dict[Tuple, Dict] = LruDict(self.capacity * 4)
         self._kernels: Dict[Tuple, Dict] = LruDict(self.capacity * 16)
         self.generation = 0
@@ -176,28 +167,10 @@ class StatsStore:
                 int(result.metrics[c.label].bytes_out)
                 for c in node.children if c.label in result.metrics)
             peak = max(peak, tot)
-        # cumulative subtree wall (node plus every descendant; a shared
-        # child counts toward each referencing subtree, matching the
-        # subtree-fingerprint definition) — None wherever any descendant
-        # lacks a per-op wall (capped/SPMD tiers time the whole plan)
-        swall: Dict[int, Optional[float]] = {}
-        for node in plan.nodes:
-            m = result.metrics.get(node.label)
-            w = None if (m is None or m.wall_ms is None) \
-                else float(m.wall_ms)
-            if w is not None:
-                for c in node.children:
-                    cw = swall.get(id(c))
-                    if cw is None:
-                        w = None
-                        break
-                    w += cw
-            swall[id(node)] = w
         event = {"backend": backend, "source_fp": source_fp,
                  "executed_fp": plan.fingerprint, "caps": {},
                  "peak_bytes": peak,
-                 "ops": {}, "subtrees": {}, "subtree_walls": {},
-                 "io": {}, "kernels": []}
+                 "ops": {}, "subtrees": {}, "io": {}, "kernels": []}
         with self._lock:
             key = (backend, source_fp)
             ps = self._plans.get(key) or {
@@ -230,20 +203,6 @@ class StatsStore:
                 e["runs"] += 1
                 self._subtrees[(backend, sfp)] = e
                 event["subtrees"][sfp] = e["rows"]
-                w = swall.get(id(node))
-                if w is not None and \
-                        not (result.degraded and not m.degraded):
-                    # a host-placed subtree inside a device result ran
-                    # on CPU — its wall files under "cpu", the backend
-                    # the time was actually spent on (the placement
-                    # rule's warm comparison depends on this purity)
-                    wb = "cpu" if m.placement == "host" else backend
-                    we = self._walls.get((wb, sfp)) or \
-                        {"wall_ms": None, "runs": 0}
-                    we["wall_ms"] = _ewma(we["wall_ms"], w)
-                    we["runs"] += 1
-                    self._walls[(wb, sfp)] = we
-                    event["subtree_walls"][sfp] = [wb, we["wall_ms"]]
                 if result.degraded and not m.degraded:
                     # a partially-degraded plan: this op ran on the
                     # DEVICE before the breaker tripped. Its observed
@@ -328,21 +287,6 @@ class StatsStore:
                 return None
             self.hits += 1
             return int(e["rows"]), int(e["runs"])
-
-    def observed_wall(self, backend: str,
-                      subtree_fp: str) -> Optional[Tuple[float, int]]:
-        """(EWMA cumulative subtree wall ms, run count) observed for this
-        exact operator subtree on this backend — the placement rule's
-        warm decision input (docs/optimizer.md#placement): host wins a
-        subtree when its "cpu" wall is at or below the device wall for
-        the same fingerprint. None when never timed here (cold — the
-        rule falls back to certified bytes)."""
-        with self._lock:
-            e = self._walls.get((backend, subtree_fp))
-            if e is None or e["wall_ms"] is None:
-                return None
-            self.hits += 1
-            return float(e["wall_ms"]), int(e["runs"])
 
     def observed_caps(self, backend: str, source_fp: str,
                       executed_fp: Optional[str] = None) -> Dict[str, int]:
@@ -480,10 +424,7 @@ class StatsStore:
     def op_stats(self, backend: str, source_fp: str) -> Dict[int, Dict]:
         """toposort index -> {rows_out, bytes_out, wall_ms, kernel} of
         the last recorded execution of this authored plan on `backend`.
-        The per-op history the ROADMAP's CPU/TPU co-placement direction
-        reads (observed per-op wall on BOTH backends — the store is
-        backend-keyed — is exactly the placement-rule input); today's
-        in-tree consumers are observability (tests, future profile
+        Today's in-tree consumers are observability (tests, profile
         surfaces), not decisions."""
         with self._lock:
             ps = self._plans.get((backend, source_fp))
@@ -570,13 +511,6 @@ class StatsStore:
                     e["rows"] = max(int(e["rows"]), int(rows))
                     e["runs"] += 1
                     self._subtrees[(backend, sfp)] = e
-                for sfp, (wb, wall) in (ev.get("subtree_walls")
-                                        or {}).items():
-                    we = self._walls.get((wb, sfp)) or \
-                        {"wall_ms": None, "runs": 0}
-                    we["wall_ms"] = _ewma(we["wall_ms"], float(wall))
-                    we["runs"] += 1
-                    self._walls[(wb, sfp)] = we
                 for sfp, rate in (ev.get("io") or {}).items():
                     ioe = self._io.get((backend, sfp)) or \
                         {"rows_per_ms": None, "runs": 0}

@@ -32,11 +32,7 @@ across tenants while every per-tenant bound stays per-tenant:
   is stamped on the ticket (`charge_source`) and the soak's JSONL. A
   charge that can NEVER fit the session quota rejects (typed, naming
   session + the operator that set the certified peak, before any
-  compilation), pins the plan to the CPU tier, or — under
-  `SPARK_RAPIDS_TPU_SERVING_OVER_QUOTA=partial` — offloads certified
-  join build-side subtrees to co-placement host threads until the
-  device remainder fits, charging quota for the device footprint only
-  (docs/serving.md#partial-placement, `charge_source="partial"`); a
+  compilation) or pins the plan to the CPU tier; a
   charge that fits but is currently crowded out just waits — the
   dispatcher skips the session until its in-flight charges drain;
 - **backpressure** — the queue is bounded; a full queue blocks submit()
@@ -109,9 +105,6 @@ class Ticket:
         self.queue_wait_ms: float = 0.0
         self.cached = False
         self.charge_source = ""   # "observed" | "certified" | "default"
-        #                           | "partial" (over-quota split:
-        #                           device-footprint charge only,
-        #                           docs/serving.md#partial-placement)
         self.worker = ""          # fleet worker id ("" single-worker)
         self._event = threading.Event()
         self._result = None
@@ -210,13 +203,12 @@ class _SessionState:
 class _Job:
     __slots__ = ("plan", "inputs", "state", "ticket", "charge",
                  "charge_source", "op_label", "tier", "cache_key",
-                 "enqueued_at", "deadline", "placement")
+                 "enqueued_at", "deadline")
 
     def __init__(self, plan, inputs, state: _SessionState, ticket: Ticket,
                  charge: int, charge_source: str, op_label: str, tier: str,
                  cache_key, enqueued_at: float,
-                 deadline: Optional[float] = None,
-                 placement=None):
+                 deadline: Optional[float] = None):
         self.plan = plan
         self.inputs = inputs
         self.state = state
@@ -228,10 +220,6 @@ class _Job:
         self.cache_key = cache_key
         self.enqueued_at = enqueued_at
         self.deadline = deadline          # submit-side deadline (clock units)
-        self.placement = placement        # host-placed subtree labels under
-        #                                   OVER_QUOTA=partial (None normal):
-        #                                   `charge` covers the DEVICE
-        #                                   remainder only
 
 
 class ServingSession:
@@ -331,10 +319,10 @@ class ServingScheduler:
                                      else int(default_charge_bytes))
         self.over_quota = (config.serving_over_quota()
                            if over_quota is None else over_quota)
-        if self.over_quota not in ("reject", "degrade", "partial"):
+        if self.over_quota not in ("reject", "degrade"):
             raise ValueError(f"unknown over_quota policy "
-                             f"{self.over_quota!r} (expected reject, "
-                             "degrade, or partial)")
+                             f"{self.over_quota!r} (expected reject or "
+                             "degrade)")
         bp = (config.serving_backpressure() if backpressure is None
               else backpressure)
         if bp not in ("block", "reject"):
@@ -456,98 +444,6 @@ class ServingScheduler:
             return None
         return None if obs is None else int(obs[0])
 
-    def _partial_placement(self, plan, inputs, cert, quota_bytes):
-        """Over-quota split under SPARK_RAPIDS_TPU_SERVING_OVER_QUOTA=
-        partial (docs/serving.md#partial-placement): offload certified
-        join build-side subtrees of the AUTHORED plan to co-placement
-        host worker threads — largest certified residency first — until
-        the certified peak of the DEVICE-placed remainder fits the
-        session quota. Returns (host subtree root labels, device
-        charge) or None when no split fits (the caller falls back to
-        the whole-plan CPU pin).
-
-        The candidate shape mirrors the optimizer's placement rule
-        (plan/optimizer.py): a HashJoin build (right) side of >= 2
-        nodes, no Exchange, every Scan bound to a Table, exclusive (one
-        consumer). The executor re-validates each label against the
-        OPTIMIZED plan and skips any the rewrite renamed — execution
-        stays correct either way; only the offload (and with it the
-        accounting's tightness) is lost, so build-side roots that
-        survive rewrites (Filter, HashAggregate) make the best
-        candidates. Defensive None on any error: admission sizing must
-        never fail a submission."""
-        from ..columnar import Table
-        from ..plan.nodes import Exchange, HashJoin, Scan
-        try:
-            if cert is None or cert.peak_bytes_hi is None:
-                return None
-            parents: Dict[int, List] = {}
-            for n in plan.nodes:
-                for c in n.children:
-                    parents.setdefault(id(c), []).append(n)
-            cands = []          # (root label, member labels, weight)
-            claimed: set = set()
-            for n in plan.nodes:
-                if not isinstance(n, HashJoin):
-                    continue
-                cand = n.children[1]
-                sub, seen = [], set()
-
-                def walk(x):
-                    if id(x) in seen:
-                        return
-                    seen.add(id(x))
-                    for c in x.children:
-                        walk(c)
-                    sub.append(x)
-
-                walk(cand)
-                ids = {id(s) for s in sub}
-                if len(sub) < 2 or ids & claimed or cand is plan.root:
-                    continue
-                ok = True
-                for s in sub:
-                    if isinstance(s, Exchange) or (
-                            isinstance(s, Scan) and not isinstance(
-                                inputs.get(s.source), Table)):
-                        ok = False
-                        break
-                    ps = parents.get(id(s), [])
-                    if (len(ps) != 1 if s is cand else
-                            any(id(p) not in ids for p in ps)):
-                        ok = False
-                        break
-                if not ok:
-                    continue
-                members = {s.label for s in sub}
-                weight = max((cert.by_label[lbl].resident_bytes_hi or 0
-                              for lbl in members
-                              if lbl in cert.by_label), default=0)
-                cands.append((cand.label, members, weight))
-                claimed |= ids
-            bounds = [b for b in cert.ops
-                      if b.resident_bytes_hi is not None]
-            offloaded: set = set()
-
-            def device_peak():
-                vals = [b.resident_bytes_hi for b in bounds
-                        if b.label not in offloaded]
-                return max(vals) if vals else 0
-
-            chosen = []
-            for root_label, members, _ in sorted(
-                    cands, key=lambda c: -c[2]):
-                if device_peak() <= quota_bytes:
-                    break
-                offloaded |= members
-                chosen.append(root_label)
-            peak = device_peak()
-            if not chosen or peak > quota_bytes:
-                return None
-            return tuple(chosen), int(peak)
-        except Exception:
-            return None
-
     def _submit(self, state: _SessionState, plan, inputs: Optional[Dict],
                 *, block: Optional[bool], timeout: Optional[float],
                 pin_cpu: bool = False) -> Ticket:
@@ -595,13 +491,13 @@ class ServingScheduler:
                 ticket._complete(result=hit)
                 return ticket
             with span("serving.admit"):
-                charge, source, op_label, tier, placement = self._admit(
+                charge, source, op_label, tier = self._admit(
                     state, plan, inputs, pin_cpu)
             ticket.charge_source = source
             deadline = None if timeout is None else self._clock() + timeout
             job = _Job(plan, inputs, state, ticket, charge, source,
                        op_label, tier, key, self._clock(),
-                       deadline=deadline, placement=placement)
+                       deadline=deadline)
             with span("serving.enqueue"):
                 self._enqueue(job, block, timeout)
         return ticket
@@ -609,8 +505,8 @@ class ServingScheduler:
     def _admit(self, state: _SessionState, plan, inputs: Dict,
                pin_cpu: bool):
         """Size the submission against the session quota BEFORE any
-        compilation -> (charge, charge source, operator label, tier,
-        placement); raises the typed over-quota rejection."""
+        compilation -> (charge, charge source, operator label, tier);
+        raises the typed over-quota rejection."""
         from ..analysis.footprint import quota_charge
         cert = self._certify(plan, inputs)
         charge, source, op_label = quota_charge(cert,
@@ -625,7 +521,6 @@ class ServingScheduler:
                 else observed
             source = "observed"
         tier = "device"
-        placement = None
         if pin_cpu:
             # fleet quarantine degrade (serving/fleet.py): the device
             # never sees this plan, so the device quota does not bind —
@@ -633,10 +528,8 @@ class ServingScheduler:
             tier, charge = "cpu", 0
         elif charge > state.quota_bytes:
             # can NEVER fit this session's quota: resolve now, before any
-            # compilation — reject with an attributable diagnostic, pin
-            # to the CPU tier where the device quota does not bind, or
-            # (partial) offload enough certified subtrees to co-placement
-            # host threads that the DEVICE remainder fits
+            # compilation — reject with an attributable diagnostic, or
+            # pin to the CPU tier where the device quota does not bind
             if self.over_quota == "reject":
                 with self._lock:
                     state.submitted += 1
@@ -646,20 +539,8 @@ class ServingScheduler:
                     f"plan charges {charge} B ({source}) against a "
                     f"{state.quota_bytes} B session quota",
                     session=state.id, operator=op_label)
-            split = None
-            if self.over_quota == "partial":
-                split = self._partial_placement(plan, inputs, cert,
-                                                state.quota_bytes)
-            if split is not None:
-                # quota is charged for the DEVICE footprint only — the
-                # host-placed subtrees never occupy device memory
-                # (docs/serving.md#partial-placement); the job stays on
-                # the device tier instead of the whole-plan CPU pin
-                placement, charge = split
-                source = "partial"
-            else:
-                tier, charge = "cpu", 0
-        return charge, source, op_label, tier, placement
+            tier, charge = "cpu", 0
+        return charge, source, op_label, tier
 
     def _enqueue(self, job: _Job, block: bool,
                  timeout: Optional[float]) -> None:
@@ -903,15 +784,9 @@ class ServingScheduler:
                 # attribute trips to fingerprints instead of guessing
                 scopes.enter_context(self.executor.health.attribution(
                     job.plan.fingerprint))
-                # placement= is only forwarded when a partial split is
-                # actually armed: executor doubles (tests, shims) that
-                # stub execute() keep working unchanged on the default
-                # path, and the kwarg's absence IS the default anyway
-                kw = ({"placement": job.placement}
-                      if job.placement is not None else {})
             return self.executor.execute(
                 job.plan, job.inputs,
-                tier="cpu" if job.tier == "cpu" else None, **kw), False
+                tier="cpu" if job.tier == "cpu" else None), False
 
     def _complete_job(self, job: _Job, wait_ms: float, result, error,
                       served_hit: bool) -> None:

@@ -95,6 +95,29 @@ def _shed_xla_map_pressure():
         jax.clear_caches()
 
 
+@pytest.fixture
+def lowers_nothing_again():
+    """-> check(plan, inputs, other): an eager executor that has run `plan`
+    over `inputs` once lowers nothing when it runs it again, nor over
+    `other` (another seed's arrays of the same shapes and counts). ROADMAP
+    D14's guard, one a one-chip eager cell, at the cell's rehearsal size."""
+    from spark_rapids_tpu.plan import PlanExecutor
+    from spark_rapids_tpu.utils import tracing
+
+    def check(plan, inputs, other):
+        ex = PlanExecutor(mode="eager")
+        ex.execute(plan, inputs)
+        with tracing.bracket("test.lowers_nothing_again") as b:
+            res = ex.execute(plan, inputs)
+        n, _ = b.lowered()
+        assert (n, list(tracing._lowered.names)[-n:] if n else []) == (0, [])
+        assert (res.lowerings, res.lowering_ms) == (0, 0.0)
+        with tracing.bracket("test.lowers_nothing_again") as b:
+            ex.execute(plan, other)
+        assert b.lowered()[0] == 0, list(tracing._lowered.names)[-4:]
+    return check
+
+
 def pytest_sessionfinish(session, exitstatus):
     """Armed-run verdict: observed lock-order cycles or dynamic edges
     the static linter failed to predict FAIL the suite even when every

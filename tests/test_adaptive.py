@@ -104,7 +104,7 @@ def test_store_round_trip_and_evict():
     sub = subtree_fingerprints(last.plan.root)
     got = store.observed_rows(backend, sub[id(last.plan.root)])
     assert got is not None and got[0] == 5 and got[1] == 1  # 5 groups
-    # per-op history round-trips too (the co-placement input surface)
+    # per-op history round-trips too
     ops = store.op_stats(backend, plans[2].fingerprint)
     root_idx = len(last.plan.nodes) - 1
     assert ops[root_idx]["rows_out"] == 5
@@ -303,6 +303,28 @@ def test_persistence_round_trip(tmp_path):
         warm = PlanExecutor(mode="capped").execute(plan, dict(inputs))
     assert warm.attempts == 1
     assert warm.compact().to_pydict() == res.compact().to_pydict()
+
+
+def test_a_record_with_the_old_wall_table_still_loads(tmp_path):
+    """A file written before the per-backend subtree walls went (they fed
+    CPU/TPU co-placement alone) carries `subtree_walls`; the key is not
+    read, and everything else of the line is."""
+    path = str(tmp_path / "stats.jsonl")
+    plan = _fanout_plan()
+    with scoped_store(StatsStore(capacity=8, path=path)):
+        res = PlanExecutor(mode="eager").execute(plan, _fanout_tables())
+    (event,) = [json.loads(x) for x in open(path).read().splitlines()]
+    assert "subtree_walls" not in event
+    sfp = next(iter(event["subtrees"]))
+    event["subtree_walls"] = {sfp: ["cpu", 1.25]}
+    with open(path, "w") as f:
+        f.write(json.dumps(event) + "\n")
+    old = StatsStore(capacity=8, path=path)
+    assert old.plan_runs("cpu", plan.fingerprint) == 1
+    assert old.observed_rows("cpu", sfp) == (event["subtrees"][sfp], 1)
+    root = len(res.plan.nodes) - 1
+    assert old.op_stats("cpu", plan.fingerprint)[root]["rows_out"] \
+        == res.table.num_rows
 
 
 def test_persistence_knob_off_writes_nothing(tmp_path, monkeypatch):

@@ -1,6 +1,8 @@
 """The documents name what exists: every repository path and every
 `SPARK_RAPIDS_TPU_*` name written in README.md, docs/*.md and the verify
-skill is real. Plain `re` over text; nothing of the package is imported."""
+skill is real, and `config.py`'s table of them is what the package reads.
+Plain `re` and `ast` over text; nothing of the package is imported."""
+import ast
 import glob
 import os
 import re
@@ -57,3 +59,30 @@ def test_knobs_in_documents_are_defined():
         f"{doc}: {name}" for doc, text in _documents()
         for name in set(KNOB.findall(text)) if not known(name))
     assert not unknown, "\n".join(unknown)
+
+
+def test_the_knob_table_is_what_the_package_reads():
+    """Every `SPARK_RAPIDS_TPU_*` name the package reads has a row in
+    `config.py`'s table and every row is read: a name is read where it
+    stands whole in a string of the code (what `os.environ` is asked
+    for). A name written anywhere else under the package (a docstring, a
+    comment, the `SPARK_RAPIDS_TPU_BREAKER_*` of a family) is a row or
+    the prefix of one."""
+    rows, read, written = [], set(), set()
+    row = re.compile(r"\| (SPARK_RAPIDS_TPU_[A-Z0-9_]+) *\|")
+    for path in glob.glob(os.path.join(PKG, "**", "*.py"), recursive=True):
+        with open(path, encoding="utf-8") as f:
+            text = f.read()
+        if path == os.path.join(PKG, "config.py"):
+            rows = [m.group(1) for m in map(row.match, text.splitlines())
+                    if m]
+        read.update(
+            n.value for n in ast.walk(ast.parse(text))
+            if isinstance(n, ast.Constant) and isinstance(n.value, str)
+            and re.fullmatch(r"SPARK_RAPIDS_TPU_[A-Z0-9_]+", n.value))
+        written.update(re.findall(r"SPARK_RAPIDS_TPU_[A-Z0-9_]*", text))
+    assert len(rows) == len(set(rows)) == 51
+    assert read == set(rows), sorted(read ^ set(rows))
+    loose = sorted(w for w in written
+                   if not any(r.startswith(w) for r in rows))
+    assert not loose, loose

@@ -173,6 +173,15 @@ def test_eager_matches_oracle_with_metrics():
                for m in prof.values())
 
 
+def test_execute_takes_no_placement():
+    """CPU/TPU co-placement went: a caller that still forces subtrees to
+    the host fails here, not silently on the device."""
+    sales, dims = _tables()
+    with pytest.raises(TypeError, match="placement"):
+        PlanExecutor(mode="eager").execute(
+            _plan(), {"sales": sales, "dims": dims}, placement=["dims"])
+
+
 def test_limit_both_tiers():
     sales, dims = _tables()
     b = PlanBuilder()

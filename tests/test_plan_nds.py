@@ -1,7 +1,9 @@
 """NDS q3/q5/q23/q72 through the plan engine, each plan in BOTH tiers —
 eager (per-operator dispatch) and capped (one XLA program,
 plan-granularity cap escalation) — against the pandas reference of
-examples/nds.py, which shares no line with the engine."""
+examples/nds.py, which shares no line with the engine. The eager q3 of
+the cell `q3.share`, at its rehearsal size, lowers nothing when it runs
+a second time."""
 import json
 
 import pandas as pd
@@ -66,6 +68,25 @@ def test_nds_plan_matches_pandas(query, mode):
             assert res.optimizer is not None and res.optimizer["rules_fired"]
             join1 = next(m for m in res.profile() if m["kind"] == "HashJoin")
             assert join1["wall_ms"] is not None and join1["wall_ms"] > 0
+
+
+def test_the_second_eager_execution_of_q3_share_lowers_nothing(
+        lowers_nothing_again):
+    from chipbench import harness, tpcds
+    cell = harness.Cell("q3.share", tiny=True)
+    q3 = cell.plan
+    gen = q3.batch_generator(cell.sizes, cell.batch)
+    inputs = []
+    # a resident cell's join keys are the configuration's draw: another
+    # seed moves the prices and the rows' order, not a count
+    for seed in (77, 4100000007):
+        drawn = gen(*harness.batch_keys(cell, seed, harness.TABLE_STREAM))
+        inputs.append({n: tpcds.table(c)
+                       for n, c in q3.dimensions(cell.sizes).items()})
+        inputs[-1].update(
+            {name: tpcds.table(cols, {}, q3.COLUMNS[name])
+             for name, (cols, _) in drawn.items()})
+    lowers_nothing_again(q3.plan(), *inputs)
 
 
 def test_nds_q23_plan_subquery_reuse():

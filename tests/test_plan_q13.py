@@ -118,23 +118,7 @@ def test_the_predicate_stays_below_the_null_supplying_side(q13, draws,
     assert not any(n.kind == "Filter" for n in res.plan.nodes)
 
 
-def test_the_second_eager_execution_lowers_nothing(q13, draws):
-    from spark_rapids_tpu.utils import tracing
-    inputs, _ = draws[SEEDS[1]]
-    ex = PlanExecutor(mode="eager")
-    plan = q13.plan()
-    ex.execute(plan, inputs)
-
-    def lowered():
-        with tracing.bracket("test.q13") as b:
-            res = ex.execute(plan, inputs)
-        n, _ = b.lowered()
-        return n, (list(tracing._lowered.names)[-n:] if n else []), res
-    n, names, res = lowered()
-    assert (n, names) == (0, [])
-    assert (res.lowerings, res.lowering_ms) == (0, 0.0)
-    # another seed's arrays have the same shapes: nothing again
-    other, _ = draws[SEEDS[2]]
-    with tracing.bracket("test.q13") as b:
-        ex.execute(plan, other)
-    assert b.lowered()[0] == 0, list(tracing._lowered.names)[-4:]
+def test_the_second_eager_execution_lowers_nothing(q13, draws,
+                                                   lowers_nothing_again):
+    # another seed's arrays have the same shapes
+    lowers_nothing_again(q13.plan(), draws[SEEDS[1]][0], draws[SEEDS[2]][0])

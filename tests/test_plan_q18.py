@@ -4,7 +4,7 @@ reference: a decimal group-by an order, a `Filter` over its DECIMAL128
 sum, a semi join, two joins, a five-key group-by with a DECIMAL64 key and
 the top 100. A few thousand orders, so the HAVING's QUANTITY (a
 substitution parameter of the query) is 250 here: at 300 an order in
-26,000 passes.
+26,000 passes. A second eager execution of the plan lowers nothing.
 """
 import numpy as np
 import pytest
@@ -158,6 +158,14 @@ def test_q18_key_cap_overflows_and_escalates(q18, draws):
     assert res.attempts > 1 and res.caps["key_cap"] >= BATCH["orders_rows"]
     assert _compare(q18, res, q18.reference(tables, quantity=QUANTITY)) \
         == EXACT
+
+
+def test_the_second_eager_execution_lowers_nothing(q18, draws,
+                                                   lowers_nothing_again):
+    # another seed's orders have the same keys and quantities, so the
+    # counts its programs are compiled for are the same
+    lowers_nothing_again(q18.plan(QUANTITY), draws[SEEDS[0]][0],
+                         draws[SEEDS[1]][0])
 
 
 def test_q18_generator_is_dbgens_shape(q18, draws):

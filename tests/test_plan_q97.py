@@ -150,22 +150,10 @@ def test_the_store_date_join_reads_its_large_side_once(cell, q97, draws,
     assert res.lookup_joins == 1
 
 
-def test_the_second_eager_execution_lowers_nothing(q97, draws):
-    from spark_rapids_tpu.utils import tracing
-    inputs, _ = draws[SEEDS[1]]
-    ex = PlanExecutor(mode="eager")
-    plan = q97.plan()
-    ex.execute(plan, inputs)
-    with tracing.bracket("test.q97") as b:
-        res = ex.execute(plan, inputs)
-    n, _ = b.lowered()
-    assert (n, list(tracing._lowered.names)[-n:] if n else []) == (0, [])
-    assert (res.lowerings, res.lowering_ms) == (0, 0.0)
-    # another seed's arrays have the same shapes: nothing again
-    other, _ = draws[SEEDS[2]]
-    with tracing.bracket("test.q97") as b:
-        ex.execute(plan, other)
-    assert b.lowered()[0] == 0, list(tracing._lowered.names)[-4:]
+def test_the_second_eager_execution_lowers_nothing(q97, draws,
+                                                   lowers_nothing_again):
+    # another seed's arrays have the same shapes
+    lowers_nothing_again(q97.plan(), draws[SEEDS[1]][0], draws[SEEDS[2]][0])
 
 
 # ---- `full_outer` against pandas -------------------------------------------------

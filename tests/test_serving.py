@@ -282,6 +282,21 @@ def test_over_quota_degrade_policy_runs_cpu_tier_with_parity():
         assert sched.metrics()["sessions"]["tiny"]["degraded"] == 1
 
 
+@pytest.mark.parametrize("how", ["environment", "constructor"])
+def test_over_quota_partial_is_no_policy(monkeypatch, how):
+    """`partial` went with CPU/TPU co-placement: asking for it is the
+    knob's typed error, which names the two values that are left."""
+    from spark_rapids_tpu import config
+    with pytest.raises(ValueError, match="reject or degrade") as ei:
+        if how == "environment":
+            monkeypatch.setenv("SPARK_RAPIDS_TPU_SERVING_OVER_QUOTA",
+                               "partial")
+            config.serving_over_quota()
+        else:
+            ServingScheduler(workers=1, over_quota="partial")
+    assert "'partial'" in str(ei.value)
+
+
 def test_quota_admits_within_bound():
     plan, t = _plan(), _table()
     cert = PlanExecutor(mode="eager")._certify(
