@@ -23,7 +23,7 @@ python tools/lint_hazards.py spark_rapids_tpu
 # tools/lint_concurrency_allowlist.txt — STALE entries fail the run
 python tools/lint_concurrency.py
 # fixed fuzz corpus (analysis/fuzz.py): 24 seeded random plans covering
-# all 11 node kinds — verify + optimize (per-rule re-validation) + eager
+# all 12 node kinds — verify + optimize (per-rule re-validation) + eager
 # optimized-vs-unoptimized parity + cold-vs-warm adaptive parity +
 # certifier soundness/monotonicity; the nightly runs the deep sweep
 JAX_PLATFORMS=cpu python -m spark_rapids_tpu.analysis.fuzz --start 0 --count 24 --cpu

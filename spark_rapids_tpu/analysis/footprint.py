@@ -74,7 +74,7 @@ from typing import Dict, List, Optional, Tuple
 from .. import dtypes
 from ..plan.nodes import (PAIRING_JOINS, Exchange, Filter, FusedSelect,
                           HashAggregate, HashJoin, Limit, PlanNode, Project,
-                          Scan, Sort, TopK, Union, nullable_sides)
+                          Scan, Sort, TopK, Union, Window, nullable_sides)
 from .verifier import (PlanVerificationError, Violation, _propagate_schemas,
                        column_types)
 
@@ -282,8 +282,8 @@ def _rows_interval(node: PlanNode, kids: List[Tuple[int, Optional[int]]],
     his = [hi for _, hi in kids]
     if isinstance(node, (Filter, FusedSelect)):
         return 0, his[0]
-    if isinstance(node, (Project, Sort, Exchange)):
-        return los[0], his[0]
+    if isinstance(node, (Project, Sort, Exchange, Window)):
+        return los[0], his[0]           # a row in, a row out
     if isinstance(node, (Limit, TopK)):
         return (min(node.n, los[0]),
                 None if his[0] is None else min(node.n, his[0]))
@@ -415,6 +415,13 @@ def certify_nodes(nodes: List[PlanNode], *, bound=None, bound_rows=None,
             src = dict((input_nullable or {}).get(node.source) or {})
             nullable[id(node)] = {
                 c: src.get(c, True) for c in schemas.get(id(node), ())}
+        elif isinstance(node, Filter):
+            # `col IS NOT NULL` as a conjunct: not null in the rows kept
+            from ..plan.expr import not_null_columns
+            out = dict(kids_n[0])
+            out.update(dict.fromkeys(
+                not_null_columns(node.predicate) & set(out), False))
+            nullable[id(node)] = out
         elif isinstance(node, (Project, FusedSelect)):
             from ..plan.expr import nullable as expr_nullable
             kid_types = types.get(id(node.children[0])) or {}
@@ -436,6 +443,13 @@ def certify_nodes(nodes: List[PlanNode], *, bound=None, bound_rows=None,
         elif isinstance(node, HashAggregate):
             out = {k: kids_n[0].get(k, True) for k in node.keys}
             out.update({n: True for _, _, n in node.aggs})
+            nullable[id(node)] = out
+        elif isinstance(node, Window):
+            # a running sum / min / max is NULL until the partition's
+            # first value, so nullable where its input is; a count never
+            out = dict(kids_n[0])
+            out.update({n: o != "count" and kids_n[0].get(c, True)
+                        for n, o, c in node.functions})
             nullable[id(node)] = out
         elif isinstance(node, Union):
             merged = {}
@@ -485,6 +499,10 @@ def certify_nodes(nodes: List[PlanNode], *, bound=None, bound_rows=None,
             ctypes = types.get(id(node.children[0])) or {}
             w = _agg_widths(node, ctypes)
             working = _mul(kid_bounds[0].rows_hi, w)
+        elif isinstance(node, Window):
+            # the sort's operands: the child's columns and a row number
+            working = _add(kid_bounds[0].out_bytes_hi,
+                           _mul(kid_bounds[0].rows_hi, 4))
 
         # exchange payload per planned edge (docs/distributed.md): hash
         # moves each row at most once; broadcast lands one extra copy on

@@ -1,9 +1,9 @@
-"""Property-based plan fuzzer: seeded random DAGs over all 11 node kinds.
+"""Property-based plan fuzzer: seeded random DAGs over all 12 node kinds.
 
 The verifier (analysis/verifier.py) machine-checks invariants; this module
 machine-GENERATES the plans to check them on. A `FuzzCase` is a seeded
 random operator DAG (Scan, Filter, Project, FusedSelect, HashJoin,
-HashAggregate, Sort, TopK, Limit, Union, Exchange — the full node set,
+HashAggregate, Window, Sort, TopK, Limit, Union, Exchange — the full node set,
 including the optimizer-produced kinds, authored directly) plus the bound
 tables it runs over. Every case must satisfy five properties:
 
@@ -46,10 +46,11 @@ from ..plan.expr import (Expr, coalesce, col, is_not_null, is_null, lit,
 from ..plan.nodes import (Exchange, Filter, FusedSelect, HashAggregate,
                           HashJoin, JOIN_TYPES, Limit, PAIRING_JOINS,
                           PlanNode, Scan, Sort, TopK,
-                          Union)
+                          Union, WINDOW_OPS, Window)
 
 ALL_KINDS = ("Scan", "Filter", "Project", "FusedSelect", "HashJoin",
-             "HashAggregate", "Sort", "TopK", "Limit", "Union", "Exchange")
+             "HashAggregate", "Window", "Sort", "TopK", "Limit", "Union",
+             "Exchange")
 
 _GLOBAL_AGGS = ("sum", "count", "size")      # empty-relation-safe
 _KEYED_AGGS = ("sum", "count", "min", "max", "mean", "size")
@@ -327,6 +328,29 @@ def gen_case(seed: int, *, max_ops: int = 8,
                 out = _Rel(Exchange(rel.node, ()), rel.schema, rel.est)
         rels[idx] = out
 
+    # a Window over one of the relations, one case in four, drawn from a
+    # generator of its own: the cases of every seed drawn before the node
+    # existed stay what they were. Keys and values are integer columns
+    # (nullable, with ties: rows that tie take the frame in the child's
+    # order, which no rewrite may change below a window)
+    wrng = random.Random(seed * 7919 + 47)
+    if wrng.random() < 0.25:
+        idx = wrng.randrange(len(rels))
+        rel = rels[idx]
+        ints = rel.cols("i")
+        if ints:
+            part = tuple(wrng.sample(ints, wrng.randrange(0, min(
+                2, len(ints)) + 1)))
+            order = (wrng.choice(ints),)
+            fns = tuple((fresh("v"), wrng.choice(WINDOW_OPS),
+                         wrng.choice(ints))
+                        for _ in range(wrng.randrange(1, 3)))
+            rels[idx] = _Rel(
+                Window(rel.node, part, order, fns,
+                       (wrng.random() < 0.7,)),
+                rel.schema + [(n, "i") for n, _, _ in fns], rel.est)
+            if wrng.random() < 0.7:     # mostly the plan's root
+                rels = [rels[idx]]
     root = rng.choice(rels)
     plan = Plan(root.node)
     return FuzzCase(seed=seed, plan=plan, tables=dict(tables),

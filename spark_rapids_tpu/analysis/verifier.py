@@ -52,7 +52,7 @@ from ..plan.expr import (BinOp, Coalesce, ColumnRef, Expr, IsNull, Literal,
 from ..plan.nodes import (PAIRING_JOINS, Exchange, Filter, FusedSelect,
                           HashAggregate, HashJoin, Limit, PlanNode,
                           PlanValidationError, Project, Scan, Sort, TopK,
-                          Union)
+                          Union, Window)
 
 __all__ = ["Violation", "VerifyReport", "PlanVerificationError",
            "verify", "verify_rewrite", "check_build", "resolve_schemas",
@@ -466,6 +466,31 @@ def _check_types(nodes, schemas, input_dtypes, report: VerifyReport
                         "not row-shaped, and decimals aggregate in "
                         "grouped sum/mean (min/max up to 18 digits)")
                 out[n] = _agg_out_dtype(o, cdt)
+            types[id(node)] = out
+            continue
+        if isinstance(node, Window):
+            # the kernel's own rules (ops/window.py), asked before
+            # anything runs: a key that is not fixed-width and a value
+            # type it does not lower are rejected by name, never lowered
+            # wrongly (a frame or a function it does not lower never
+            # became a node: plan/nodes.py refuses it)
+            from ..ops import window as window_ops
+            out = dict(kids[0])
+            for role, keys in (("partition", node.partition_by),
+                               ("order", node.order_by)):
+                for k in keys:
+                    try:
+                        window_ops.check_key(k, kids[0].get(k), role)
+                    except TypeError as err:
+                        report.add("typing.window-key-not-fixed-width",
+                                   node, f"{node.label}: {err}")
+            for n, o, c in node.functions:
+                try:
+                    out[n] = window_ops.result_type(o, kids[0].get(c))
+                except TypeError as err:
+                    report.add("typing.window-not-lowered", node,
+                               f"{node.label}: {o}({c}): {err}")
+                    out[n] = None
             types[id(node)] = out
             continue
         if isinstance(node, Union):

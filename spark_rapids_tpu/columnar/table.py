@@ -22,7 +22,8 @@ class Table:
     columns: Tuple[Column, ...]
     names: Tuple[str, ...]
 
-    def __init__(self, columns: Sequence[Column], names: Sequence[str] = None):
+    def __init__(self, columns: Sequence[Column], names: Sequence[str] = None,
+                 ordered_by: Sequence[str] = ()):
         columns = tuple(columns)
         if names is None:
             names = tuple(f"c{i}" for i in range(len(columns)))
@@ -35,6 +36,13 @@ class Table:
                 assert c.length == n0, "all columns must have equal length"
         object.__setattr__(self, "columns", columns)
         object.__setattr__(self, "names", tuple(names))
+        # the leading columns the rows are known to lie sorted by
+        # (ascending, nulls first, as ops/sort.py orders key operands),
+        # where the operator that made the table says so: the sorted
+        # group-by and the window do, and a window over such a child does
+        # not sort again. A statement about THIS object, not part of the
+        # pytree: whatever rebuilds the table drops it
+        object.__setattr__(self, "ordered_by", tuple(ordered_by))
 
     def tree_flatten(self):
         return (self.columns,), (self.names,)

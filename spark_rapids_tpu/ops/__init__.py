@@ -31,6 +31,7 @@ from .gather import (take, take_live, take_table, apply_boolean_mask,
                      outer_join_columns)
 from .sort import sort_table_capped, sorted_order, sort_table
 from .aggregate import groupby_aggregate, groupby_aggregate_capped
+from .window import window_functions
 from .join import (full_join, full_join_counted, full_join_parts, inner_join,
                    inner_join_carrying, inner_join_capped,
                    inner_join_capped_tail, left_join, left_join_capped,
@@ -74,6 +75,7 @@ _ADMITTED_FACTORS = {
     "apply_boolean_mask": 2.0, "outer_join_columns": 2.0,
     "sorted_order": 2.0, "sort_table": 3.0, "sort_table_capped": 3.0,
     "groupby_aggregate": 2.0, "groupby_aggregate_capped": 2.0,
+    "window_functions": 3.0,
     "inner_join": 3.0, "inner_join_carrying": 3.0,
     "inner_join_capped": 3.0,
     "inner_join_capped_tail": 3.0, "left_join": 3.0,
@@ -120,7 +122,7 @@ __all__ = [
     "outer_join_columns", "sorted_order",
     "sort_table",
     "sort_table_capped",
-    "groupby_aggregate", "groupby_aggregate_capped",
+    "groupby_aggregate", "groupby_aggregate_capped", "window_functions",
     "inner_join", "inner_join_carrying", "inner_join_capped",
     "inner_join_capped_tail",
     "left_join", "left_join_counted", "left_join_capped",

@@ -104,20 +104,43 @@ def _sort_with_payloads(key_operands, iota, payloads, n_ops: int,
     (decimal planes: a dozen operands would ride) and more than
     `RIDE_PAYLOADS` of them, the sort moves the keys and the iota alone
     and the payloads are gathered by it afterwards. `by_row` (a DISTINCT,
-    which has no payload): the iota is the sort's last key and the sort
-    not a stable one; no two rows tie, the order is the stable sort's, and
-    the program compiles in half the time (ops/join.py:_union_sort)."""
+    which has no payload, and a window, whose payloads ride): the iota is
+    the sort's last key and the sort not a stable one; no two rows tie,
+    the order is the stable sort's, and the program compiles in half the
+    time (ops/join.py:_union_sort)."""
     gather = gather and len(payloads) > RIDE_PAYLOADS
     operands = [*key_operands, iota] + ([] if gather else list(payloads))
     if by_row:
         sorted_all = jax.lax.sort(operands, num_keys=n_ops + 1,
                                   is_stable=False)
-        return sorted_all[:n_ops], sorted_all[n_ops], []
+        return sorted_all[:n_ops], sorted_all[n_ops], sorted_all[n_ops + 1:]
     sorted_all = jax.lax.sort(operands, num_keys=n_ops, is_stable=True)
     order = sorted_all[n_ops]
     spay = ([jnp.take(p, order, axis=0) for p in payloads] if gather
             else sorted_all[n_ops + 1:])
     return sorted_all[:n_ops], order, spay
+
+
+def run_boundaries(sorted_ops, n: int):
+    """(n,) bool: the rows of a sorted frame at which a run of equal keys
+    starts (row 0 always), from the key operands in their sorted order."""
+    neq = jnp.zeros((n,), bool)
+    for o in sorted_ops:
+        neq = neq | (o != jnp.roll(o, 1))
+    return neq.at[0].set(True) if n else neq   # guard: empty scatter OOB
+
+
+def sorted_runs(key_operands, iota, payloads, n_ops: int, gather: bool,
+                by_row: bool = False, n_run_ops: Optional[int] = None):
+    """What the sorted group-by kernels and the window kernel
+    (ops/window.py) share: the main key sort with its payloads, and the
+    flags of the runs of equal keys in it -> (sorted key operands, order,
+    payloads in that order, boundary). A run is a group; a window's run
+    is a partition, the first `n_run_ops` operands alone."""
+    sorted_ops, order, spay = _sort_with_payloads(
+        key_operands, iota, payloads, n_ops, gather, by_row=by_row)
+    boundary = run_boundaries(sorted_ops[:n_run_ops], iota.shape[0])
+    return sorted_ops, order, spay, boundary
 
 
 @partial(jax.jit,
@@ -201,14 +224,9 @@ def _groupby_kernel(key_operands, agg_datas, agg_valids, *, n_ops: int,
             payloads.append(valid.astype(jnp.int8))
         slots.append((d_slot, v_slot))
 
-    sorted_ops, order, spay = _sort_with_payloads(
+    sorted_ops, order, spay, boundary = sorted_runs(
         key_operands, iota, payloads, n_ops, gather_payloads,
         by_row=not agg_kinds)
-
-    neq = jnp.zeros((n,), bool)
-    for o in sorted_ops:
-        neq = neq | (o != jnp.roll(o, 1))
-    boundary = neq.at[0].set(True) if n else neq   # guard: empty scatter OOB
     ends_flag = jnp.roll(boundary, -1).at[-1].set(True) if n else boundary
     if has_alive:
         num_groups = jnp.sum((boundary & (sorted_ops[0] == 0))
@@ -407,13 +425,8 @@ def _groupby_kernel_scatter(key_operands, agg_datas, agg_valids, *,
             payloads.append(valid.astype(jnp.int8))
         slots.append((d_slot, v_slot))
 
-    sorted_ops, order, spay = _sort_with_payloads(
+    sorted_ops, order, spay, boundary = sorted_runs(
         key_operands, iota, payloads, n_ops, gather_payloads)
-
-    neq = jnp.zeros((n,), bool)
-    for o in sorted_ops:
-        neq = neq | (o != jnp.roll(o, 1))
-    boundary = neq.at[0].set(True) if n else neq
     if has_alive:
         num_groups = jnp.sum((boundary & (sorted_ops[0] == 0))
                              .astype(jnp.int32))
@@ -786,7 +799,8 @@ def _groupby(table, key_names, aggs, _cap, _alive, sp):
                                data=d.astype(dt.storage_dtype()), validity=v))
 
     if _cap is None:
-        return Table(out_cols, names)
+        # every kernel puts its groups out in the key operands' order
+        return Table(out_cols, names, ordered_by=names[:len(keys)])
     out_cols = [_pad_column(c, _cap) for c in out_cols]
     valid = jnp.arange(_cap, dtype=jnp.int32) < num_groups
     return Table(out_cols, names), valid, num_groups > _cap

@@ -11,7 +11,10 @@ levels. The block is the one size tried.
 
 `running` is the one definition: the SPMD walk's sorted group-by
 (`parallel/relational.py`) and the `scan` group-by kernel
-(`ops/aggregate.py`) both call it. `live_positions` (PR 32, moved here
+(`ops/aggregate.py`) both call it, and `running_in_runs`, the scans of
+the window kernel (`ops/window.py`) that restart at every partition, is
+built from it: no flat `associative_scan` over a frame, whose unrolled
+program is what takes minutes to compile. `live_positions` (PR 32, moved here
 unchanged in PR 37) is the one definition too: the SPMD walk's compaction
 and the eager joins' small-side path (`ops/join_lookup.py`) call it.
 """
@@ -45,6 +48,61 @@ def running(x: jnp.ndarray, op: str = "sum") -> jnp.ndarray:
         before = jnp.concatenate([lowest, jax.lax.cummax(totals)[:-1]])
         out = jnp.maximum(inner, before[:, None])
     return out.reshape(-1)[:n]
+
+
+def _carried_word(rank, word):
+    """The running maximum of the pair (rank, 32-bit word) as one int64,
+    its low word back as uint32. `rank` never falls, so the maximum at a
+    row is taken over the rows of its own rank alone."""
+    packed = (rank.astype(jnp.int64) << 32) \
+        | word.astype(jnp.uint32).astype(jnp.int64)
+    return running(packed, "max").astype(jnp.uint32)
+
+
+def _join_words(hi, lo):
+    return (hi.astype(jnp.int64) << 32) | lo.astype(jnp.int64)
+
+
+def running_in_runs(x: jnp.ndarray, head: jnp.ndarray, rank: jnp.ndarray,
+                    op: str = "sum") -> jnp.ndarray:
+    """Inclusive running sum (or maximum) of an int64 vector that restarts
+    at every row `head` flags: the forward segmented scan of a window's
+    partitions. `rank` is the run's number, `running(head) - 1` (a caller
+    with several vectors computes it once).
+
+    A sum is the frame-long running sum less its value before the run's
+    head, and that value reaches the run's rows as the running maximum of
+    (rank, word), a word at a time: no gather through the head's position
+    (15 ns a slot, PERF.md PR 42) and no segmented `associative_scan`.
+    A maximum is the lexicographic one of (rank, high word, low word): the
+    running maximum of (rank, high word) gives the high word; it never
+    falls, so the rows over which it stays the same are runs themselves,
+    and inside one the low word is the running maximum over the rows that
+    hold that high word.
+
+    A 32-bit `x` is a COUNT (never negative, `op` "sum"): its running
+    total never falls, so the value before the run's head is carried by
+    one 32-bit running maximum, and the program holds no 64-bit scan (18.5
+    MB of code each on the chip against 1.7, PERF.md PR 45)."""
+    sign = jnp.uint32(1 << 31)
+    if x.dtype == jnp.int32:
+        csum = running(x)
+        return csum - running(jnp.where(head, csum - x, jnp.int32(0)), "max")
+    if op == "sum":
+        csum = running(x)
+        before = jnp.where(head, csum - x, jnp.int64(0))
+        return csum - _join_words(_carried_word(rank, before >> 32),
+                                  _carried_word(rank, before))
+    hi = (x >> 32).astype(jnp.uint32) ^ sign         # order as unsigned
+    packed = (rank.astype(jnp.int64) << 32) | hi.astype(jnp.int64)
+    top = running(packed, "max")
+    n = x.shape[0]
+    turn = top != jnp.roll(top, 1)
+    turn = turn.at[0].set(True) if n else turn
+    sub = running(turn.astype(jnp.int32)) - 1
+    lo = _carried_word(sub, jnp.where(packed == top, x.astype(jnp.uint32),
+                                      jnp.uint32(0)))
+    return _join_words(top.astype(jnp.uint32) ^ sign, lo)
 
 
 _MASK_WORD = 32

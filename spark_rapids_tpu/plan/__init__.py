@@ -11,7 +11,7 @@ DAG of typed operator nodes over `columnar.Table` — and the engine-side
 concerns live in ONE executor:
 
 - `nodes` / `expr`: the operator set (Scan, Filter, Project, HashJoin,
-  HashAggregate, Sort, Exchange, Limit, Union) and the expression
+  HashAggregate, Window, Sort, Exchange, Limit, Union) and the expression
   mini-language predicates/projections are written in.
 - `builder`: fluent, validating construction (`PlanBuilder`); schema and
   reference errors surface at build time as `PlanValidationError`.
@@ -50,7 +50,7 @@ targets this layer.
 from .expr import (col, lit, scalar_max, scalar_min, scalar_sum, is_null,
                    is_not_null, when, coalesce, Expr)
 from .nodes import (Exchange, Filter, FusedSelect, HashAggregate, HashJoin,
-                    Limit, PlanNode, Project, Scan, Sort, TopK, Union)
+                    Limit, PlanNode, Project, Scan, Sort, TopK, Union, Window)
 from .builder import Plan, PlanBuilder, PlanValidationError
 from .executor import PlanExecutor, PlanResult
 from .metrics import OperatorMetrics
@@ -62,7 +62,7 @@ __all__ = [
     "col", "lit", "scalar_max", "scalar_min", "scalar_sum", "is_null",
     "is_not_null", "when", "coalesce", "Expr",
     "Scan", "Filter", "Project", "FusedSelect", "HashJoin",
-    "HashAggregate", "Sort", "TopK", "Exchange", "Limit", "Union",
+    "HashAggregate", "Window", "Sort", "TopK", "Exchange", "Limit", "Union",
     "PlanNode",
     "Plan", "PlanBuilder", "PlanValidationError",
     "PlanExecutor", "PlanResult", "OperatorMetrics",

@@ -15,7 +15,7 @@ from typing import Dict, List, Optional, Sequence, Tuple, Union as TUnion
 from .expr import Expr
 from .nodes import (Exchange, Filter, HashAggregate, HashJoin, Limit,
                     PlanNode, PlanValidationError, Project, Scan, Sort,
-                    Union)
+                    Union, Window)
 
 __all__ = ["Plan", "PlanBuilder", "Rel", "PlanValidationError"]
 
@@ -167,6 +167,20 @@ class Rel:
         asc = ((ascending,) * len(keys) if isinstance(ascending, bool)
                else tuple(ascending))
         return Rel(Sort(self.node, tuple(keys), asc))
+
+    def window(self, functions: Sequence[Tuple[str, str, str]],
+               partition_by: Sequence[str] = (),
+               order_by: Sequence[str] = (),
+               ascending: TUnion[bool, Sequence[bool]] = True,
+               frame: str = "running") -> "Rel":
+        """This relation's columns plus one column a function `(out_name,
+        op, column)`: `op(column) OVER (PARTITION BY partition_by ORDER BY
+        order_by <frame>)`; `running` is `ROWS BETWEEN UNBOUNDED PRECEDING
+        AND CURRENT ROW` (plan/nodes.py:Window has the null rules)."""
+        asc = ((ascending,) * len(order_by) if isinstance(ascending, bool)
+               else tuple(ascending))
+        return Rel(Window(self.node, tuple(partition_by), tuple(order_by),
+                          tuple(tuple(f) for f in functions), asc, frame))
 
     def limit(self, n: int) -> "Rel":
         return Rel(Limit(self.node, n))

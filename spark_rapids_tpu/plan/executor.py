@@ -93,6 +93,7 @@ from .metrics import OperatorMetrics, render_profile
 from .nodes import (PAIRING_JOINS, Exchange, Filter, FusedSelect,
                     HashAggregate, HashJoin, Limit, PlanNode,
                     PlanValidationError, Project, Scan, Sort, TopK, Union,
+                    Window,
                     nullable_sides)
 from .expr import ColumnRef
 from ..utils.tracing import bracket, span, text as _span_text
@@ -186,7 +187,8 @@ def _scope_owners(hlo_text: str, nested: bool = False) -> Dict[str, str]:
     `nested`: followed by the innermost scope a kernel opened below the
     operator's: `/decimal.<op>` (ops/decimal_utils.py) or, outside any
     of those, `/ops.groupby` (ops/aggregate.py: the group-by's kernel and
-    the rest of its finish)."""
+    the rest of its finish) or `/ops.window` (ops/window.py: the same of
+    a window)."""
     owners: Dict[str, str] = {}
     for line in hlo_text.splitlines():
         m = _HLO_INSTRUCTION.match(line)
@@ -196,7 +198,8 @@ def _scope_owners(hlo_text: str, nested: bool = False) -> Dict[str, str]:
                        if _SCOPE.match(p)), None)
             if at is not None:
                 inner = [p for p in parts[at + 1:]
-                         if p.startswith("decimal.") or p == "ops.groupby"] \
+                         if p.startswith("decimal.")
+                         or p in ("ops.groupby", "ops.window")] \
                     if nested else []
                 owners[m.group(1)] = "/".join(parts[at:at + 1] + inner[-1:])
     return owners
@@ -520,6 +523,9 @@ class PlanResult:
         #                               frame-long `take`, and planes x
         #                               slots (ops/gather.py:
         #                               outer_join_columns)
+        self.windows = 0              # the request's `Window` operators,
+        self.window_rows = 0          # the rows into them and (the eager
+        self.window_partitions = 0    # tier's) the partitions they held
         self.lookup_joins = 0         # eager tier: joins that took the
         self.lookup_compares = 0      # small-side path, and small rows x
         #                               large rows over them (ops/join.py)
@@ -703,7 +709,7 @@ class PlanExecutor:
             return
         for n in plan.nodes:
             if isinstance(n, (Exchange, HashJoin, HashAggregate, Sort,
-                              TopK, Union)):
+                              TopK, Union, Window)):
                 raise PlanValidationError(
                     f"{n.label}: distributed lowering (mesh=) exists only "
                     "in the eager tier; a capped executor would silently "
@@ -751,6 +757,9 @@ class PlanExecutor:
                                 res.full_unmatched_right_rows),
                             join_planes_gathered=res.join_planes_gathered,
                             join_slots_gathered=res.join_slots_gathered,
+                            windows=res.windows,
+                            window_rows=res.window_rows,
+                            window_partitions=res.window_partitions,
                             gather_slots=res.gather_slots,
                             cap_slots=res.cap_slots,
                             expand_slots=res.expand_slots,
@@ -827,6 +836,19 @@ class PlanExecutor:
                 res.full_unmatched_right_rows += int(m.unmatched_right_rows)
             res.join_planes_gathered += int(m.planes_gathered)
             res.join_slots_gathered += int(m.slots_gathered)
+
+    @staticmethod
+    def _count_windows(res: PlanResult) -> None:
+        """`windows`, `window_rows`, `window_partitions` of a result, from
+        its operators' metrics (a cached result keeps its own)."""
+        if res.cached or res.windows:
+            return
+        for node in res.plan.nodes:
+            m = res.metrics.get(node.label)
+            if isinstance(node, Window) and m is not None:
+                res.windows += 1
+                res.window_rows += int(m.rows_in)
+                res.window_partitions += int(m.window_partitions)
 
     def _execute_request(self, plan, inputs, tier, nulled=()) -> PlanResult:
         if tier not in (None, "device", "cpu"):
@@ -909,6 +931,7 @@ class PlanExecutor:
             self._count_lookups(res)
             self._count_compactions(res)
             self._count_outer(res)
+            self._count_windows(res)
             # serving-session stamp (runtime/sessionctx.py,
             # docs/serving.md): results and per-op metrics carry the tenant
             # they executed for — dispatcher worker threads are multiplexed
@@ -1964,6 +1987,20 @@ class PlanExecutor:
         m.compact = moved[-1][0]
         return out
 
+    @staticmethod
+    def _proven(t: Table, predicate) -> Table:
+        """`t`, the rows a filter on `predicate` kept (or, under a cap,
+        left alive), without the validity masks of the columns the
+        predicate proves not null (plan/expr.py:not_null_columns)."""
+        from .expr import not_null_columns
+        proven = not_null_columns(predicate)
+        if not any(n in proven and c.validity is not None
+                   for n, c in zip(t.names, t.columns)):
+            return t
+        return Table([c.with_validity(None) if n in proven else c
+                      for n, c in zip(t.names, t.columns)], t.names,
+                     ordered_by=t.ordered_by)
+
     def _exec_eager_node(self, node, childs: List[Table], inputs, schemas,
                          m: OperatorMetrics) -> Table:
         ops = _ops()
@@ -1979,7 +2016,8 @@ class PlanExecutor:
             return node.typed(t)
         if isinstance(node, Filter):
             (t,) = childs
-            return self._compact(t, node.predicate.truth(t), m)
+            return self._proven(
+                self._compact(t, node.predicate.truth(t), m), node.predicate)
         if isinstance(node, FusedSelect):
             # fused Filter+Project: gather ONLY the projection-referenced
             # columns through the mask, then project — one pass, instead of
@@ -2001,7 +2039,7 @@ class PlanExecutor:
             else:
                 ft = self._compact(t.select(needed),
                                    node.predicate.truth(t), m)
-            return self._project(ft, node)
+            return self._project(self._proven(ft, node.predicate), node)
         if isinstance(node, Project):
             (t,) = childs
             return self._project(t, node)
@@ -2020,7 +2058,19 @@ class PlanExecutor:
             agg = ops.groupby_aggregate(t, list(node.keys),
                                         [(c, o) for c, o, _ in node.aggs])
             out_names = schemas[id(node)]
-            return Table(list(agg.columns), names=out_names)
+            # the kernel says its groups lie in key order: so do these
+            return Table(list(agg.columns), names=out_names,
+                         ordered_by=out_names[:len(agg.ordered_by)])
+        if isinstance(node, Window):
+            (t,) = childs
+            from ..ops import window as window_ops
+            with window_ops.windows.collect() as did:
+                out = ops.window_functions(
+                    t, node.partition_by, node.order_by, node.ascending,
+                    node.functions, node.frame)
+            m.kernel = "xla:" + window_ops.KERNEL
+            m.window_partitions, m.window_sorted, m.window_key = did[-1]
+            return out
         if isinstance(node, Sort):
             (t,) = childs
             return ops.sort_table(t, key_names=list(node.keys),
@@ -2053,9 +2103,16 @@ class PlanExecutor:
                  alive: Optional[jnp.ndarray] = None) -> Table:
         # a bare reference keeps its column (type and validity); any other
         # expression is its value, Spark's result type and its validity
+        # (and the order its input says it lies in, as far as the leading
+        # columns of that order pass through, under their new names)
+        passed = {e.name: n for n, e in reversed(node.exprs)
+                  if isinstance(e, ColumnRef)}
+        ordered = list(itertools.takewhile(passed.__contains__,
+                                           t.ordered_by))
         return Table([t[e.name] if isinstance(e, ColumnRef)
                       else e.column(t, alive) for _, e in node.exprs],
-                     names=[n for n, _ in node.exprs])
+                     names=[n for n, _ in node.exprs],
+                     ordered_by=[passed[c] for c in ordered])
 
     def _global_aggregate(self, t: Table, node: HashAggregate,
                           alive: Optional[jnp.ndarray] = None) -> Table:
@@ -2528,7 +2585,8 @@ class PlanExecutor:
             # predicate as a mask AND — the jit tier's filter idiom: no
             # compaction, dead rows stay and stay dead
             mask = node.predicate.truth(c.table, c.alive)
-            return _CappedRel(c.table, c.alive & mask), None
+            return _CappedRel(self._proven(c.table, node.predicate),
+                              c.alive & mask), None
         if isinstance(node, FusedSelect):
             # filter-then-project over the padded frame: the predicate ANDs
             # into alive and the projection evaluates under the new mask
@@ -2542,8 +2600,9 @@ class PlanExecutor:
                                               node.exprs, "capped"))
             mask = node.predicate.truth(c.table, c.alive)
             alive = c.alive & mask
-            return _CappedRel(self._project(c.table, node, alive),
-                              alive), None
+            return _CappedRel(self._project(
+                self._proven(c.table, node.predicate), node, alive),
+                alive), None
         if isinstance(node, Project):
             (c,) = childs
             return _CappedRel(self._project(c.table, node, c.alive),
@@ -2641,6 +2700,16 @@ class PlanExecutor:
                 alive=c.alive)
             t = Table(list(agg.columns), names=schemas[id(node)])
             return _CappedRel(t, valid), ovf
+        if isinstance(node, Window):
+            # dead rows sort last as a partition of their own and carry
+            # into nothing (ops/window.py): the frame keeps its length
+            (c,) = childs
+            from ..ops import window as window_ops
+            kernel_map[idx] = "xla:" + window_ops.KERNEL
+            t, alive = ops.window_functions(
+                c.table, node.partition_by, node.order_by, node.ascending,
+                node.functions, node.frame, alive=c.alive)
+            return _CappedRel(t, alive), None
         if isinstance(node, Sort):
             (c,) = childs
             t, alive = ops.sort_table_capped(

@@ -618,6 +618,22 @@ def nullable(e: Expr, col_nullable, col_type=None) -> bool:
     return any(nullable(c, col_nullable, col_type) for c in e.children())
 
 
+def not_null_columns(pred: Expr) -> frozenset:
+    """The columns a filter on `pred` proves hold no NULL in the rows it
+    keeps: a filter keeps TRUE alone, so a top-level AND conjunct `col IS
+    NOT NULL` says it of `col` (Catalyst narrows an attribute's
+    nullability by the same constraint). The filter's output drops those
+    columns' validity masks, and every sort below stops carrying a null
+    rank and a mask plane for a key that cannot be null (TPC-DS's `where
+    ws_item_sk is not null`)."""
+    if isinstance(pred, BinOp) and pred.op == "&":
+        return not_null_columns(pred.left) | not_null_columns(pred.right)
+    if isinstance(pred, IsNull) and pred.negate \
+            and isinstance(pred.child, ColumnRef):
+        return frozenset((pred.child.name,))
+    return frozenset()
+
+
 def null_aware(e: Expr) -> bool:
     """Whether the expression can be TRUE or non-null over a row whose
     inputs are null (`is_null`, `when`, `coalesce` anywhere in it): such a

@@ -74,6 +74,13 @@ class OperatorMetrics:
     unmatched_rows: int = 0
     # right rows a `full_outer` join put out null-extended, else 0
     unmatched_right_rows: int = 0
+    # an eager `Window`: its partitions, whether its kernel sorted (`sort`)
+    # or took the child's order (`child`), and the sort's key: `packed`
+    # (every key operand and the row number in one 64-bit word),
+    # `operands` or `none`; 0 and "" elsewhere
+    window_partitions: int = 0
+    window_sorted: str = ""
+    window_key: str = ""
     # streaming-scan IO metrics (Scan nodes bound to a parquet source;
     # docs/io.md). Decode wall is host-side bitstream decode; overlap is
     # the time decode of chunk N+1 ran concurrently with executing chunk N

@@ -38,6 +38,11 @@ def nullable_sides(how: str):
     out null though its input held none."""
     return how == "full_outer", how in OUTER_JOINS
 AGG_OPS = ("sum", "count", "min", "max", "mean", "size")   # ops.aggregate.AGG_OPS
+# what a `Window` may say today (ops.window.FRAMES / WINDOW_OPS): the other
+# frames, `row_number`, `rank`, `lag` / `lead` are later VALUES of the same
+# fields, not other nodes
+WINDOW_FRAMES = ("running",)
+WINDOW_OPS = ("sum", "min", "max", "count")
 
 _ids = itertools.count()
 
@@ -364,6 +369,83 @@ class HashAggregate(PlanNode):
     def describe(self):
         aggs = ", ".join(f"{o}({c}) AS {n}" for c, o, n in self.aggs)
         return f"keys=[{', '.join(self.keys)}] {aggs or 'distinct'}"
+
+
+@dataclasses.dataclass(frozen=True, eq=False)
+class Window(PlanNode):
+    """Window functions (Spark's WindowExec): the child's columns plus one
+    column a function `(out_name, op, column)`, each `op(column) OVER
+    (PARTITION BY partition_by ORDER BY order_by <frame>)`. One window
+    specification a node; `frame` and `op` are values: `running` is `ROWS
+    BETWEEN UNBOUNDED PRECEDING AND CURRENT ROW`, the ops `sum`, `min`,
+    `max`, `count` (a frame or an op the kernel does not lower is refused
+    here by name). Empty `partition_by`: the whole relation is one
+    partition. A NULL partition key is a partition of its own (GROUP BY's
+    rule); a NULL order key sorts first ascending, last descending; a NULL
+    value is skipped and the result is NULL until the partition's first
+    value (`count` never is). The output's ROW ORDER is (partition, order),
+    whatever the child's was: SQL promises none. Rows that tie on every key
+    take the frame in the child's order."""
+    child: PlanNode
+    partition_by: Tuple[str, ...]
+    order_by: Tuple[str, ...]
+    functions: Tuple[Tuple[str, str, str], ...]
+    ascending: Tuple[bool, ...] = ()
+    frame: str = "running"
+
+    def __post_init__(self):
+        super().__post_init__()
+        object.__setattr__(self, "partition_by", tuple(self.partition_by))
+        object.__setattr__(self, "order_by", tuple(self.order_by))
+        object.__setattr__(self, "functions", tuple(
+            (n, o, c) for n, o, c in self.functions))
+        asc = self.ascending
+        if isinstance(asc, bool):
+            asc = (asc,) * len(self.order_by)
+        elif not asc:
+            asc = (True,) * len(self.order_by)
+        object.__setattr__(self, "ascending", tuple(asc))
+        _require(self.frame in WINDOW_FRAMES,
+                 f"{self.label}: window frame {self.frame!r} is not lowered "
+                 f"(have {WINDOW_FRAMES})")
+        _require(len(self.functions) > 0,
+                 f"{self.label}: needs at least one window function")
+        for n, o, c in self.functions:
+            _require(o in WINDOW_OPS,
+                     f"{self.label}: window function {o!r} is not lowered "
+                     f"(have {WINDOW_OPS})")
+        _require(len(self.order_by) > 0,
+                 f"{self.label}: a {self.frame!r} frame needs an order "
+                 "(ORDER BY): without one its rows have no sequence")
+        _require(len(self.ascending) == len(self.order_by),
+                 f"{self.label}: ascending list must match the order keys")
+
+    @property
+    def children(self):
+        return (self.child,)
+
+    def output_names(self, child_schemas):
+        (schema,) = child_schemas
+        for role, keys in (("partition", self.partition_by),
+                           ("order", self.order_by)):
+            missing = set(keys) - set(schema)
+            _require(not missing, f"{self.label}: {role} key(s) "
+                                  f"{sorted(missing)} not in {list(schema)}")
+        for n, o, c in self.functions:
+            _require(c in schema,
+                     f"{self.label}: window function input {c!r} not in "
+                     f"{list(schema)}")
+        names = list(schema) + [n for n, _, _ in self.functions]
+        _require(len(set(names)) == len(names),
+                 f"{self.label}: duplicate output name in {names}")
+        return tuple(names)
+
+    def describe(self):
+        fns = ", ".join(f"{o}({c}) AS {n}" for n, o, c in self.functions)
+        order = ", ".join(f"{k} {'ASC' if a else 'DESC'}"
+                          for k, a in zip(self.order_by, self.ascending))
+        return (f"{fns} over (partition by [{', '.join(self.partition_by)}] "
+                f"order by [{order}] {self.frame})")
 
 
 @dataclasses.dataclass(frozen=True, eq=False)

@@ -18,7 +18,9 @@ with a pass-count guard (`MAX_PASSES`):
   `Limit(0)` (an empty relation of the same schema — no new node kind).
 - `predicate_pushdown`: Filter moves below Project (predicate rewritten
   through cheap ColumnRef/Literal projections), below Union (one copy per
-  input), and into the side of a HashJoin whose columns it references —
+  input), below a Window where it reads partition keys alone (it keeps or
+  drops whole partitions), and into the side of a HashJoin whose columns
+  it references —
   rows die before the join/union/materialization instead of after.
 - `limit_pushdown`: Limit(Limit) collapses, Limit moves below row-wise
   Projects, and Limit(Sort) fuses into one `TopK` operator.
@@ -32,9 +34,9 @@ with a pass-count guard (`MAX_PASSES`):
   results row-for-row identical.
 - `column_pruning`: required columns walk top-down through the DAG;
   Scans narrow to a `projection` (unused columns never enter the plan),
-  Project/FusedSelect outputs and HashAggregate agg lists drop dead
-  entries, and width-sensitive operators (join/aggregate/sort/exchange
-  inputs) get a zero-copy select-Project inserted when their input still
+  Project/FusedSelect outputs, HashAggregate agg lists and a Window's
+  functions drop dead entries, and width-sensitive operators
+  (join/aggregate/window/sort/exchange inputs) get a zero-copy select-Project inserted when their input still
   carries dead columns (e.g. a Filter's predicate-only columns).
 - `select_fusion`: adjacent Filters merge (`a & b`) and Project(Filter)
   fuses into one `FusedSelect` node, so the eager tier gathers the
@@ -69,7 +71,7 @@ from .expr import (BinOp, Coalesce, ColumnRef, Expr, IsNull, Literal,
 from .nodes import (OUTER_JOINS, PAIRING_JOINS, Exchange, Filter, FusedSelect,
                     HashAggregate, HashJoin,
                     Limit, PlanNode, PlanValidationError, Project, Scan,
-                    Sort, TopK, Union)
+                    Sort, TopK, Union, Window)
 
 __all__ = ["optimize", "plan_fingerprint", "subtree_fingerprints",
            "OptimizeReport", "RULE_NAMES", "MAX_PASSES",
@@ -542,7 +544,7 @@ class _Estimator:
                 runs = r if runs is None else min(runs, r)
         if isinstance(node, (Filter, FusedSelect)):
             return 0.5 * kids[0], src, runs
-        if isinstance(node, (Project, Exchange, Sort)):
+        if isinstance(node, (Project, Exchange, Sort, Window)):
             return kids[0], src, runs
         if isinstance(node, Limit):
             return min(float(node.n), kids[0]), src, runs
@@ -678,6 +680,17 @@ def _rule_predicate_pushdown(root, ctx):
         if isinstance(child, Union) and not has_scalar_agg(p):
             hits[0] += 1
             return Union(tuple(Filter(i, p) for i in child.inputs))
+        if isinstance(child, Window) and not has_scalar_agg(p):
+            # below a window only what keeps or drops WHOLE partitions: a
+            # predicate over partition keys alone (every row of a
+            # partition holds the same keys, a NULL key included). One
+            # that reads an order key, a value or a function's column
+            # would change the frames of the rows it keeps
+            if p.references() <= set(child.partition_by):
+                hits[0] += 1
+                return dataclasses.replace(
+                    child, child=Filter(child.child, p))
+            return None
         if isinstance(child, HashJoin) and not has_scalar_agg(p):
             refs = p.references()
             ls = ctx.schemas.of(child.left)
@@ -842,7 +855,7 @@ def _rule_select_fusion(root, ctx):
 
 # width-sensitive operators: a dead column crossing one of these edges is
 # materialized/sorted/shuffled, so a zero-copy select pays for itself
-_NARROW_PARENTS = (HashJoin, HashAggregate, Sort, TopK, Exchange)
+_NARROW_PARENTS = (HashJoin, HashAggregate, Sort, TopK, Exchange, Window)
 
 
 def _rule_column_pruning(root, ctx):
@@ -903,6 +916,14 @@ def _rule_column_pruning(root, ctx):
                 push(n, 0, r)
             elif isinstance(n, (Sort, TopK)):
                 push(n, 0, set(req) | set(n.keys))
+            elif isinstance(n, Window):
+                # the keys, the kept functions' inputs, and what passes
+                # through to a reader above
+                kept = [f for f in n.functions if f[0] in req] \
+                    or list(n.functions[:1])
+                made = {f[0] for f in n.functions}
+                push(n, 0, (set(req) - made) | set(n.partition_by)
+                     | set(n.order_by) | {c for _, _, c in kept})
             elif isinstance(n, Exchange):
                 push(n, 0, set(req) | set(n.keys))
             elif isinstance(n, (Limit, Union)):
@@ -970,6 +991,14 @@ def _rule_column_pruning(root, ctx):
             if len(kept) < len(n.aggs):
                 note_pruned(len(n.aggs) - len(kept), ctx.est.of(n))
                 node2 = dataclasses.replace(node2, aggs=kept)
+        elif isinstance(n, Window):
+            # a function nobody reads goes (one stays: the node's rows and
+            # their order are its output too)
+            kept = tuple(f for f in n.functions if f[0] in req) \
+                or n.functions[:1]
+            if len(kept) < len(n.functions):
+                note_pruned(len(n.functions) - len(kept), ctx.est.of(n))
+                node2 = dataclasses.replace(node2, functions=kept)
         memo[id(n)] = node2
         return node2
 
@@ -1037,10 +1066,15 @@ def mesh_local_reason(nodes) -> Optional[Tuple[str, str]]:
     report says so under `<label>/mesh`. A null-aware expression
     (`is_null`, `when`, `coalesce`) keeps its plan local the same way: the
     walk evaluates value and validity of every other expression
-    (`_eval`), and these have not run over shards."""
+    (`_eval`), and these have not run over shards. A `Window` has no
+    lowering in the walk either (its partitions would need an exchange by
+    the partition keys and a sort a shard)."""
     for n in nodes:
         if isinstance(n, HashJoin) and n.how in OUTER_JOINS:
             return n.label, (f"local ({n.how} has no distributed "
+                             "lowering: the whole plan runs on one chip)")
+        if isinstance(n, Window):
+            return n.label, ("local (a window has no distributed "
                              "lowering: the whole plan runs on one chip)")
         if any(null_aware(e) for e in _node_exprs(n)):
             return n.label, ("local (a null-aware expression has no "

@@ -690,3 +690,148 @@ def test_rows_by_slot_holds_one_frame_beside_its_results_for_v5e(
         memory.temp_size_in_bytes
     assert memory.output_size_in_bytes < (8 + 8 + 1) * Q13_SLOTS + (1 << 16)
     assert memory.generated_code_size_in_bytes < 10 << 20
+
+
+def _q51_batch() -> dict:
+    """`q51.batch`'s stated counts (the configuration's fixed draw)."""
+    import json
+    here = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(here, "chipbench", "configs",
+                           "nds-q51-sf100.json")) as f:
+        return json.load(f)["batches"]["share"]
+
+
+# `q51.batch`'s three windows: (rows, operands' dtypes, planes' dtypes,
+# n_part_ops, fns, the kernel's form). A channel's running sum reads a
+# sorted group-by's output on its own keys (`presorted`: the item and its
+# null rank, the day); the running maxima above the full join sort by one
+# packed key (two nullable int64 keys: four operands) with both totals
+# and their masks riding
+Q51_WINDOWS = {
+    "store_sum": ("store_groups", ("int32", "int64", "int64"),
+                  ("int64", "int8"), 2, (("sum", 0, 1, None),), "presorted"),
+    "web_sum": ("web_groups", ("int32", "int64", "int64"),
+                ("int64", "int8"), 2, (("sum", 0, 1, None),), "presorted"),
+    "both_max": ("join_rows", ("int32", "int64", "int32", "int64"),
+                 ("int64", "int8", "int64", "int8"), 2,
+                 (("max", 0, 1, None), ("max", 2, 3, None)), "packed"),
+}
+
+
+@pytest.mark.parametrize("window", sorted(Q51_WINDOWS))
+def test_window_kernel_compiles_for_v5e(one_chip, no_persistent_cache,
+                                        window):
+    """`ops/window.py:_window_kernel` at the shapes of `q51.batch`'s three
+    windows (PR 47). Over a group-by's output the program holds NO sort
+    and no gather: two-level scans alone. Above the full join it holds ONE
+    sort, not stable, on ONE key (the packed word), with the child's six
+    words riding, and no gather as long as a frame; its temporaries are a
+    few frames of the key and the planes, and its code, which lies in HBM,
+    stays under what it was compiled at (77.2 MB: four 64-bit running
+    maxima are 18.5 MB each, PR 45; the sort by its five key OPERANDS was
+    124.9 MB of code and took 494 s to compile here, this takes 105)."""
+    from spark_rapids_tpu.ops import window as window_ops
+    rows, operands, planes, n_part_ops, fns, form = Q51_WINDOWS[window]
+    n = int(_q51_batch()[rows])
+    assert n > 1_000_000, "the configuration states the cell's counts"
+
+    def shape(dtype, dims=(n,)):
+        return jax.ShapeDtypeStruct(dims, jnp.dtype(dtype), sharding=one_chip)
+    packing = None
+    if form == "packed":
+        k = len(operands)
+        packing = (shape("int64", (k,)),) * 3 + (shape("int64", ()),) * 2
+    compiled = window_ops._window_kernel.lower(
+        tuple(shape(d) for d in operands), tuple(shape(d) for d in planes),
+        packing, n_part_ops=n_part_ops, fns=fns,
+        presorted=form == "presorted").compile()
+    text = compiled.as_text()
+    sorts = [line for line in text.splitlines() if " sort(" in line]
+    assert max(_gather_slots(text), default=0) < n // 32
+    memory = compiled.memory_analysis()
+    plane_bytes = sum(jnp.dtype(d).itemsize for d in planes)
+    if form == "presorted":
+        assert not sorts
+        assert memory.generated_code_size_in_bytes < 40 << 20
+        # the sum, its count and the partitions: a few int64 frames
+        assert memory.temp_size_in_bytes < 64 * n, memory.temp_size_in_bytes
+    else:
+        assert len(sorts) == 1 and "is_stable=true" not in sorts[0]
+        # one key: the comparator reads two parameters
+        assert "dimensions={0}" in sorts[0]
+        assert memory.generated_code_size_in_bytes < 90 << 20
+        assert memory.temp_size_in_bytes < 6 * (8 + plane_bytes) * n, \
+            memory.temp_size_in_bytes
+
+
+# sha256 (first 16 hex digits) of `_groupby_kernel.lower(...).as_text()` at
+# the shapes of the cells that run it, taken on the parent of PR 47
+# (5d70ac1) and the same on its tree: the sort and the run flags moved
+# into `sorted_runs`, which the window kernel calls too, and the programs
+# are the parent's text for text. (Compiling them for a described v5e
+# takes 4 to 8 minutes each: the stable sorts; tier-1 holds the text.)
+GROUPBY_TEXT_AT_PR46 = {
+    "q18.batch": "6bd43132fc25418b",        # 60 M rows, decimal planes
+    "q97.batch": "d13056d635b90a43",        # a DISTINCT, two nullable keys
+    "q13.batch/count": "9f182b2db03ef9d3",  # count of a nullable column
+    "q13.batch/size": "6c25a289b7124646",
+}
+
+
+def _groupby_lowering(cell: str):
+    from spark_rapids_tpu.ops import aggregate
+
+    def shape(n, dtype):
+        return jax.ShapeDtypeStruct((n,), jnp.dtype(dtype))
+    if cell == "q18.batch":
+        n = 59_986_052
+        return aggregate._groupby_kernel.lower(
+            (shape(n, "int64"),),
+            (shape(n, "uint32"), shape(n, "int32"), shape(n, "int8")),
+            (None, None, None), n_ops=1, agg_kinds=("sum", "sum", "count"),
+            has_valids=(False,) * 3, has_alive=False, gather_payloads=True)
+    if cell == "q97.batch":
+        n = 6_874_157
+        ops = tuple(shape(n, d) for d in ("int32", "int64") * 2)
+        return aggregate._groupby_kernel.lower(
+            ops, (), (), n_ops=4, agg_kinds=(), has_valids=(),
+            has_alive=False, gather_payloads=False)
+    if cell == "q13.batch/count":
+        n = 15_334_665
+        return aggregate._groupby_kernel.lower(
+            (shape(n, "int64"),), (shape(n, "int8"),), (shape(n, "bool"),),
+            n_ops=1, agg_kinds=("count",), has_valids=(True,),
+            has_alive=False, gather_payloads=False)
+    n = 1_500_000
+    return aggregate._groupby_kernel.lower(
+        (shape(n, "int64"),), (shape(n, "int8"),), (None,), n_ops=1,
+        agg_kinds=("size",), has_valids=(False,), has_alive=False,
+        gather_payloads=False)
+
+
+@pytest.mark.parametrize("cell", sorted(GROUPBY_TEXT_AT_PR46))
+def test_groupby_kernel_lowers_to_the_text_it_had(cell):
+    text = _groupby_lowering(cell).as_text()
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] \
+        == GROUPBY_TEXT_AT_PR46[cell]
+
+
+def test_q51_keyed_sum_lowers_through_the_shared_sort(one_chip):
+    """`q51.batch`'s keyed sums (6.9 M and 1.7 M rows into (item, day)
+    groups, a nullable int64 sum riding) are `_groupby_kernel` as split:
+    two sorts (the key sort with the value and its mask riding, the
+    compaction sort), both stable as every cell's group-by still has them
+    (ROADMAP S6 (e)), and the run flags by `run_boundaries`."""
+    from spark_rapids_tpu.ops import aggregate
+    n = int(_q51_batch()["store_date_rows"])
+
+    def shape(dtype):
+        return jax.ShapeDtypeStruct((n,), jnp.dtype(dtype))
+    text = aggregate._groupby_kernel.lower(
+        (shape("int32"), shape("int64"), shape("int64")),
+        (shape("int64"),), (shape("bool"),), n_ops=3, agg_kinds=("sum",),
+        has_valids=(True,), has_alive=False,
+        gather_payloads=False).as_text()
+    sorts = re.findall(r"stablehlo\.sort.*?is_stable = (\w+)", text,
+                       flags=re.S)
+    assert sorts == ["true", "true"]

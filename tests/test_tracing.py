@@ -1011,6 +1011,108 @@ def test_a_request_with_an_outer_join_is_tiled_by_its_spans(session):
         open_.append(s)
 
 
+def _window_plan():
+    b = PlanBuilder()
+    return (b.scan("t", schema=["k", "v"])
+            .aggregate(["k", "v"], [("v", "size", "n")])
+            .window([("run", "sum", "n")], partition_by=["k"],
+                    order_by=["v"])
+            .join(b.scan("d", schema=["dk", "g"]), left_on="k",
+                  right_on="dk")
+            .window([("top", "max", "run"), ("seen", "count", "run")],
+                    partition_by=["g"], order_by=["v", "k"],
+                    ascending=[False, True])
+            .build())
+
+
+@pytest.mark.parametrize("tier", ["eager", "capped"])
+def test_window_span_and_the_requests_window_counters(session, tier):
+    """A window's kernel, its finish and the wait for them run inside
+    `ops.window` (rows, partitions, functions, frame, kernel, planes: the
+    32-bit words riding the sort, sorted: `sort`, or `child` where the
+    child's order was taken), below the operator; `plan.execute` carries
+    the request's windows, the rows into them and, in the eager tier, the
+    partitions they held."""
+    plan, inputs = _window_plan(), {"t": _fact(), "d": _dim()}
+    ex = PlanExecutor(mode=tier, **({"caps": dict(row_cap=512, key_cap=512)}
+                                    if tier == "capped" else {}))
+    ex.execute(plan, inputs)                              # compile outside
+    done = []
+    spans = session(lambda: done.append(ex.execute(plan, inputs)))
+    res, got = done[0], spans.one("plan.execute")
+    wins = [m for m in res.metrics.values() if m.kind == "Window"]
+    assert len(wins) == 2 and all(m.kernel == "xla:sort_scan" for m in wins)
+    assert (res.windows, res.window_rows) == (
+        2, sum(m.rows_in for m in wins))
+    assert (got["windows"], got["window_rows"], got["window_partitions"]) \
+        == (res.windows, res.window_rows, res.window_partitions)
+    if tier == "capped":
+        assert res.window_partitions == 0 and not spans.named("ops.window")
+        owners = ex.device_op_owners(plan, inputs, nested=True)
+        held = {o.split("/")[0] for o in owners.values()
+                if o.endswith("/ops.window")}
+        assert len(held) == 2 and all(o.endswith(".Window") for o in held)
+        return
+    ops = [o for o in spans.named("plan.op") if o["op"].endswith(".Window")]
+    said = sorted(spans.named("ops.window"), key=lambda s: s["t0"])
+    assert len(ops) == len(said) == 2
+    for w, op, m in zip(said, sorted(ops, key=lambda s: s["t0"]), wins):
+        assert inside(w, op) and w["request"] == op["request"]
+        assert (w["rows"], w["frame"], w["kernel"]) == (
+            m.rows_in, "running", "sort_scan")
+        assert (w["partitions"], w["sorted"]) == (m.window_partitions,
+                                                  m.window_sorted)
+        # a window that sorts reads its key operands' ranges first (one
+        # packed sort key where they fit), then both read their partitions
+        under = [s for s in spans if inside(s, w) and s is not w]
+        assert [s["name"] for s in sorted(under, key=lambda s: s["t0"])] \
+            == ["ops.host_sync"] * (2 if w["sorted"] == "sort" else 1) \
+            + ["plan.wait"]
+        assert w["key"] == m.window_key
+    first, second = said
+    # over the sorted group-by's own keys nothing rides and nothing sorts;
+    # after the join: `g`, `v` and `k` are read back from their operands,
+    # `n`, `run` and `dk` ride (two words each)
+    assert (first["sorted"], first["planes"], first["functions"],
+            first["partitions"]) == ("child", 0, 1, 50)
+    assert (second["sorted"], second["planes"], second["functions"],
+            second["partitions"], second["key"]) == ("sort", 6, 2, 7,
+                                                     "packed")
+    assert res.window_partitions == 57
+
+
+def test_a_request_with_a_window_is_tiled_by_its_spans(session):
+    """The walk over a request that holds two windows: every span lies
+    inside `plan.execute`, every blocking read of the run lies under an
+    operator, a window's under its `ops.window`, and siblings do not
+    overlap."""
+    plan, inputs = _window_plan(), {"t": _fact(), "d": _dim()}
+    ex = PlanExecutor(mode="eager")
+    ex.execute(plan, inputs)
+    spans = session(lambda: ex.execute(plan, inputs))
+    root = spans.one("plan.execute")
+    mine = [s for s in spans if s.get("request") == root["request"]
+            and s is not root]
+    assert mine and all(inside(s, root) for s in mine)
+    ops, run = spans.named("plan.op"), spans.one("plan.run")
+    for s in mine:
+        if s["name"] in ("plan.wait", "ops.host_sync") and inside(s, run):
+            assert any(inside(s, o) for o in ops), s
+    for op in (o for o in ops if o["op"].endswith(".Window")):
+        (w,) = [s for s in spans.named("ops.window") if inside(s, op)]
+        under = [s for s in mine if inside(s, op) and s is not op]
+        assert all(s is w or inside(s, w) or s["name"] == "plan.wait"
+                   for s in under)
+    same = sorted((s for s in mine if s["thread"] == root["thread"]),
+                  key=lambda s: (s["t0"], -s["t1"]))
+    open_ = [root]
+    for s in same:
+        while not inside(s, open_[-1]):
+            open_.pop()
+            assert open_, s
+        open_.append(s)
+
+
 def test_plan_execute_span_counts_the_slots_the_joins_gathered(session):
     """A capped join gathers its output columns over whole chunks of its
     live rows (ops/gather.py:gather_live), not over its cap: the first
@@ -1243,13 +1345,14 @@ def test_collectives_run_under_an_exchange_scope():
 WALKED = ["plan/executor.py", "plan/distributed.py", "parallel/relational.py",
           "parallel/autoretry.py", "ops/join.py", "ops/join_lookup.py",
           "ops/join_pallas.py", "ops/select_pallas.py", "ops/gather.py",
-          "ops/aggregate.py", "serving/scheduler.py", "serving/cache.py"]
+          "ops/aggregate.py", "ops/window.py", "serving/scheduler.py",
+          "serving/cache.py"]
 HOLDERS = {"ops.host_sync", "plan.wait", "plan.readback"}
 DEVICE_ROOTS = {"jnp", "lax", "jax"}
 HOST_ATTRS = {"shape", "dtype", "ndim", "num_rows", "length", "nbytes", "size", "names", "padded_rows"}
 DEVICE_ATTRS = {"valid", "data", "validity", "offsets", "planes", "alive"}
 DEVICE_CALLS = {"fn", "fn1", "spans", "emit", "_retry", "_fold_buffers",
-                "_member", "auto_retry_overflow"}
+                "_member", "auto_retry_overflow", "_window_kernel"}
 DEVICE_PARAMS = {"lost", "valid", "mask", "idx", "nulled"}
 PASS_THROUGH = {"list", "tuple", "sum", "zip", "max", "min"}
 CONVERT = {"int", "bool", "float"}
@@ -1424,6 +1527,7 @@ def test_the_walk_finds_what_the_request_path_is_known_to_read():
                   ("ops/gather.py", "_count_kept", "int"),
                   ("ops/gather.py", "take", "device_get"),
                   ("ops/aggregate.py", "_groupby", "int"),
+                  ("ops/window.py", "_window", "int"),
                   ("serving/cache.py", "_table_digest", "device_get")]:
         assert known in found, known
     assert len(SITES) >= 40
