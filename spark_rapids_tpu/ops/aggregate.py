@@ -46,8 +46,8 @@ import jax.numpy as jnp
 from .. import dtypes
 from ..columnar import Column, Table
 from ..dtypes import Kind
-from ..utils.tracing import span
-from .gather import take
+from ..utils.tracing import Tally, span
+from .gather import plane_words, take, words_ride
 from .scans import running
 from .sort import NULLS_LAST, _key_operands
 
@@ -143,12 +143,42 @@ def sorted_runs(key_operands, iota, payloads, n_ops: int, gather: bool,
     return sorted_ops, order, spay, boundary
 
 
+def _key_words(op):
+    """A key operand as the 32-bit words that ride the compaction sort: a
+    64-bit one as its low and its high word. The chip's compiler sorts it
+    as two words either way; handed back as words, the 64-bit values are
+    put together by the caller over the groups (`_from_key_words`), not
+    here over the frame (a frame of the two halves beside the frame of
+    the whole: `q13.batch`'s and `q18.batch`'s peaks stand at this
+    kernel)."""
+    if op.dtype.itemsize < 8:
+        return [op]
+    return [op.astype(jnp.uint32), (op >> 32).astype(jnp.int32)]
+
+
+@partial(jax.jit, static_argnames=("dtypes", "g"))
+def _from_key_words(words, *, dtypes: Tuple[str, ...], g: int):
+    """`_key_words` undone over the first `g` rows -> the operands of
+    `dtypes`, in order. One program for a group-by's keys."""
+    words = iter(words)
+    out = []
+    for dtype in dtypes:
+        low = next(words)[:g]
+        if jnp.dtype(dtype).itemsize == 8:
+            high = next(words)[:g].astype(jnp.int64)
+            low = ((high << 32) | low.astype(jnp.int64)).astype(dtype)
+        out.append(low)
+    return out
+
+
 @partial(jax.jit,
          static_argnames=("n_ops", "agg_kinds", "has_valids", "has_alive",
-                          "gather_payloads"))
+                          "gather_payloads", "ride_keys", "with_starts"))
 def _groupby_kernel(key_operands, agg_datas, agg_valids, *, n_ops: int,
                     agg_kinds: Tuple[str, ...], has_valids: Tuple[bool, ...],
-                    has_alive: bool = False, gather_payloads: bool = False):
+                    has_alive: bool = False, gather_payloads: bool = False,
+                    ride_keys: Tuple[int, ...] = (),
+                    with_starts: bool = False):
     """Scatter-free, gather-free sorted aggregation (round-4 redesign).
 
     On-chip primitive costs (round-2 TPU measurement, recorded in
@@ -189,14 +219,35 @@ def _groupby_kernel(key_operands, agg_datas, agg_valids, *, n_ops: int,
         difference correct for the last group for free;
       * float sums and min/max: one REVERSE segmented associative_scan each
         (result lands on the group's first row — the row compaction keeps);
-      * ONE boundary-compaction sort packs every group-start row (position,
-        original row id, and all per-agg results) to the front — replacing
-        both the old starts sort and every per-agg gather. searchsorted
+      * ONE boundary-compaction sort packs every group-start row to the
+        front, replacing both the old starts sort and every per-agg
+        gather. Its ONE key is the start's position (`n` at every other
+        row): the starts' positions are unique and ascending, and the
+        other rows tie with payloads that are all alike (`n`, the scan's
+        total, 0.0, the identity, 0 for a key word), so the sort is not a
+        stable one (for which the chip's compiler adds the row numbers
+        as an operand, runs a sixth longer and compiles in twice the
+        time: PR 42, PR 43). It carries the per-agg results and what the
+        caller makes the groups' keys from: the key operands named by
+        `ride_keys` (indices into `key_operands`) at the start rows, which
+        for an integer, date, timestamp, DECIMAL32/64 or boolean key ARE
+        the key (ops/sort.py:_key_operands: the data zeroed under a null,
+        the null rank in front; 0.93 ns a 32-bit word a row, where the
+        gather of a key plane through the first rows was 15-18 ns a
+        slot: PR 48; a 64-bit operand as its two words, `_key_words`),
+        and the group's first ORIGINAL row number only where some key
+        operand does not ride (a float, DECIMAL128 or string key, whose
+        operands are not its data; a caller that gathers). searchsorted
         stays banned (it lowers to ~log2(n) whole-array gather passes).
 
-    Returns (num_groups, starts, first_rows, outs): all n-length, entries
-    past num_groups are padding (positions hold n), sliced/masked by the
-    caller.
+    Returns (num_groups, starts, first_rows, outs, rode): all n-length,
+    entries past num_groups are padding (positions hold n), sliced/masked
+    by the caller; `first_rows` is None where every key operand rides,
+    `rode` the compacted words of `ride_keys`' operands, in its order
+    (`_key_words`; the caller puts them together, `_from_key_words`),
+    `starts` None unless `with_starts` (the string extremes read it: every
+    frame-long output is held in HBM until the caller's finish ends, and
+    `q13.batch`'s and `q18.batch`'s peaks stand there).
 
     `has_alive`: key_operands[0] is a dead-row flag (0 alive, 1 dead) the
     caller prepended — the jit-pipeline contract where upstream capped ops
@@ -257,8 +308,16 @@ def _groupby_kernel(key_operands, agg_datas, agg_valids, *, n_ops: int,
     # compaction operands: group-start rows to the front, everything they
     # need riding along as payloads
     pad_i32 = jnp.int32(n)
-    comp_pay: List = [jnp.where(boundary, iota, pad_i32),       # position
-                      jnp.where(boundary, order, pad_i32)]      # first row
+    comp_pay: List = [jnp.where(boundary, iota, pad_i32)]       # position
+    # the first original row, unless every key operand rides instead
+    row_slot = None
+    if set(ride_keys) != set(range(int(has_alive), n_ops)):
+        row_slot = len(comp_pay)
+        comp_pay.append(jnp.where(boundary, order, pad_i32))
+    first_key = len(comp_pay)
+    comp_pay.extend(jnp.where(boundary, w, jnp.zeros((), w.dtype))
+                    for k in ride_keys for w in _key_words(sorted_ops[k]))
+    key_slots = slice(first_key, len(comp_pay))
     # per-agg: (payload index in comp_pay, mode, pad-side info)
     agg_comp: List = []
     totals = {}          # comp_pay slot -> cumsum grand total (traced scalar)
@@ -326,15 +385,12 @@ def _groupby_kernel(key_operands, agg_datas, agg_valids, *, n_ops: int,
             comp_pay.append(jnp.where(boundary, ext, ident))
             agg_comp.append((slot, "ext", cnt_slot))
 
-    if not agg_kinds:
-        # a DISTINCT: the starts' positions are their own key (every other
-        # row holds `n` twice), so the sort need not be a stable one
-        comp = jax.lax.sort(comp_pay, num_keys=1, is_stable=False)
-    else:
-        flag = jnp.where(boundary, jnp.int32(0), jnp.int32(1))
-        comp = jax.lax.sort([flag, *comp_pay], num_keys=1,
-                            is_stable=True)[1:]
-    starts, first_rows = comp[0], comp[1]
+    # the starts' positions are their own key; every other row holds `n`
+    # and the pads, so the sort need not be a stable one
+    comp = jax.lax.sort(comp_pay, num_keys=1, is_stable=False)
+    starts = comp[0]
+    first_rows = None if row_slot is None else comp[row_slot]
+    rode = tuple(comp[key_slots])
 
     def adj_diff(arr, tail):
         if n == 0:
@@ -380,7 +436,8 @@ def _groupby_kernel(key_operands, agg_datas, agg_valids, *, n_ops: int,
         else:   # "ext"
             outs.append((comp[slot], cnt > 0))
 
-    return num_groups, starts, first_rows, outs
+    return (num_groups, starts if with_starts else None, first_rows, outs,
+            rode)
 
 
 @partial(jax.jit,
@@ -618,8 +675,9 @@ def groupby_aggregate(table: Table,
     groupby_aggregate_capped's `alive`).
 
     The kernel and its finish run in an `ops.groupby` span (with `rows`,
-    `groups`: the count once it is read, the key cap under a cap; `kernel`
-    and `planes`, the 32-bit decimal planes summed) and under the scope of
+    `groups`: the count once it is read, the key cap under a cap; `kernel`;
+    `planes`, the 32-bit decimal planes summed; `keys`, how the groups'
+    keys came back: `ride`, `take` or `ride+take`) and under the scope of
     that name, which a capped program's `device_op_owners(nested=True)`
     reads back below its operator's."""
     with span("ops.groupby", rows=table.num_rows) as sp, \
@@ -634,9 +692,31 @@ def groupby_aggregate(table: Table,
         return out
 
 
+# what the keyed aggregates under a `with group_keys.collect()` did with
+# their groups' keys: (`ride` / `take` / `ride+take`, key planes x slots
+# that went through `take`) each (the executor's `group_key_slots_gathered`)
+group_keys = Tally()
+
+
+def _key_rides(col: Column) -> bool:
+    """Whether the key's sort operands ARE its data (`_key_operands`: the
+    data zeroed under a null, behind the null rank), so that a group's key
+    is read off the sorted operands at the run's first row. Not a float
+    (the total-order transform), a DECIMAL128 (biased limbs) or a string
+    (packed words)."""
+    k = col.dtype.kind
+    return k == Kind.BOOL or col.dtype.is_integer or k in _EXACT_KINDS
+
+
+def _key_planes(cols) -> int:
+    """The planes a `take` of `cols` gathers: a column's data, its mask."""
+    return sum(1 + (c.validity is not None) for c in cols)
+
+
 def _groupby(table, key_names, aggs, _cap, _alive, sp):
     """`groupby_aggregate` inside its span `sp`, which it stamps with the
-    groups, the kernel and the planes once they are known."""
+    groups, the kernel, the planes and how the keys came back once they
+    are known."""
     keys = [table[k] for k in key_names]
     if not keys:
         raise ValueError("groupby requires at least one key column")
@@ -645,12 +725,17 @@ def _groupby(table, key_names, aggs, _cap, _alive, sp):
             raise TypeError("nested group keys are not supported")
 
     operands = []
+    key_ops: List[Tuple[int, ...]] = []     # key -> its operands' indices
     for c in keys:
-        operands.extend(_key_operands(c, True, None))
+        ops_c = _key_operands(c, True, None)
+        key_ops.append(tuple(range(len(operands),
+                                   len(operands) + len(ops_c))))
+        operands.extend(ops_c)
     if _alive is not None:
         # leading dead-flag operand: dead rows sort last as their own
         # groups, counted out of num_groups by the kernel (has_alive)
         operands = [jnp.where(_alive, jnp.int32(0), jnp.int32(1))] + operands
+        key_ops = [tuple(k + 1 for k in ks) for ks in key_ops]
 
     from . import decimal_utils
     n = table.num_rows
@@ -710,7 +795,24 @@ def _groupby(table, key_names, aggs, _cap, _alive, sp):
     # "direct" is chosen only for exact aggregates: no string extreme
     extra = ({"cap": _cap} if choice.name == "direct"
              else {"gather_payloads": bool(decimal_parts)})
-    num_groups, first_sorted, first_rows_full, outs = choice.fn(
+    # The groups' keys: gathered through the groups' first rows, or (the
+    # `scan` kernel's compaction sort) the keys' own sort operands riding
+    # in the row number's place. Priced before the kernel runs, over the
+    # most groups there can be: their gathered slots a plane against the
+    # frame's rows a word added (the row number leaves when every key
+    # rides). A key whose operands are not its data keeps `take`.
+    riding = [i for i, c in enumerate(keys) if _key_rides(c)]
+    ride_ops = [k for i in riding for k in key_ops[i]]
+    if choice.name != "scan" or not words_ride(
+            n, sum(plane_words([operands[k] for k in ride_ops]))
+            - (len(riding) == len(keys)),
+            n if _cap is None else min(_cap, n),
+            _key_planes(keys[i] for i in riding)):
+        riding, ride_ops = [], []
+    if choice.name == "scan":
+        extra.update(ride_keys=tuple(ride_ops),
+                     with_starts=bool(string_extremes))
+    num_groups, first_sorted, first_rows_full, outs, *rode = choice.fn(
         tuple(operands), tuple(agg_datas), tuple(agg_valids),
         n_ops=len(operands), agg_kinds=tuple(agg_kinds),
         has_valids=tuple(v is not None for v in agg_valids),
@@ -723,17 +825,35 @@ def _groupby(table, key_names, aggs, _cap, _alive, sp):
         # must accept small batches, and a too-small cap must be retryable
         # with a bigger one regardless of n)
         g = min(_cap, n)
+    taken = [c for i, c in enumerate(keys) if i not in riding]
+    path = "+".join(w for w, some in (("ride", riding), ("take", taken))
+                    if some)
+    group_keys.note((path, _key_planes(taken) * g))
     sp.set_metadata(groups=g if _cap is None else _cap, kernel=choice.name,
-                    planes=sum(len(p) for p, _ in decimal_parts.values()))
-    # padded entries hold n: clip for the gathers — rows past num_groups are
-    # garbage by contract, masked by the capped valid vector
-    first_sorted = jnp.clip(first_sorted, 0, max(n - 1, 0))
-
-    # key columns: row index (original frame) of each group's first sorted
-    # row — carried straight through the compaction sort, no order gather
-    first_rows = jnp.clip(first_rows_full[:g], 0, max(n - 1, 0))
-    # first_rows is non-negative by construction: skip take()'s any<0 sync
-    out_cols = [take(c, first_rows, _has_negative=False) for c in keys]
+                    planes=sum(len(p) for p, _ in decimal_parts.values()),
+                    keys=path)
+    # key columns. A key that rode: its operands at the groups' starts,
+    # the data as it is (0 under a null) and the null rank (1: valid,
+    # `_key_operands`' NULLS_FIRST ascending). Any other: gathered at the
+    # row index (original frame) of each group's first sorted row, carried
+    # through the compaction sort, no order gather
+    if taken:
+        first_rows = jnp.clip(first_rows_full[:g], 0, max(n - 1, 0))
+    rode = iter(_from_key_words(
+        rode[0], dtypes=tuple(str(operands[k].dtype) for k in ride_ops),
+        g=g) if riding else ())
+    out_cols = []
+    for i, c in enumerate(keys):
+        if i not in riding:
+            # first_rows is non-negative by construction: skip take()'s
+            # any<0 sync
+            out_cols.append(take(c, first_rows, _has_negative=False))
+            continue
+        rank = None if c.validity is None else next(rode)
+        out_cols.append(Column(
+            dtype=c.dtype, length=g,
+            data=next(rode).astype(c.dtype.storage_dtype()),
+            validity=None if rank is None else rank == 1))
     names = [table.names[k] if isinstance(k, int) else k for k in key_names]
 
     # string min/max: ONE extra value-ordered sort per string column. With
@@ -758,7 +878,10 @@ def _groupby(table, key_names, aggs, _cap, _alive, sp):
                             jnp.arange(n, dtype=jnp.int32)],
                            num_keys=len(operands) + len(vops), is_stable=True)
         order2 = srt[-1]
-        starts = first_sorted[:g]
+        # padded entries hold n: clip for the gathers — rows past
+        # num_groups are garbage by contract, masked by the capped valid
+        # vector
+        starts = jnp.clip(first_sorted[:g], 0, max(n - 1, 0))
         at_start = take(c, jnp.take(order2, starts, axis=0),
                         _has_negative=False)
         at_last = None

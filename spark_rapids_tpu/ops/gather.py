@@ -21,8 +21,10 @@ identity is not gathered, a right side that hardly matches is a null
 frame with the matched slots written in, a full join's lonely right rows
 are a compaction; PR 44; a right side of which every row has one partner
 at most is a PERMUTATION with null slots between, and rides one sort
-keyed on the row's slot, `rows_by_slot`; PR 45). A frame-long `take` is
-what is left where no count says better.
+keyed on the row's slot, `rows_by_slot`; PR 45), and a sorted group-by's
+keys ride the compaction sort it runs anyway where `words_ride` says the
+words cost less than the gathered slots (ops/aggregate.py; PR 48). A
+frame-long `take` is what is left where no count says better.
 """
 from __future__ import annotations
 
@@ -250,6 +252,17 @@ RIDE_WORDS = 12
 def few_kept(kept: int, n: int) -> bool:
     """Whether `kept` of `n` rows are few enough to go by their positions."""
     return kept * FEW_KEPT <= n
+
+
+def words_ride(n: int, words: int, slots: int, planes: int) -> bool:
+    """Whether `words` 32-bit words riding a sort of `n` rows that its
+    caller runs anyway cost less than `planes` planes gathered over
+    `slots` slots, at the probe's prices above: 0.93 ns a riding word a
+    row, 15 ns a gathered slot a plane. Never at or under `KEPT_FLOOR`
+    rows: a riding word's fixed costs (code in HBM, compile time) do not
+    follow the rows. Arithmetic over what the caller holds before its
+    kernel runs; nothing else chooses."""
+    return n > KEPT_FLOOR and 0.93 * words * n < 15.0 * planes * slots
 
 
 def compaction_path(n: int, kept: int, ragged: bool = False) -> str:

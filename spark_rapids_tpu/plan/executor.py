@@ -157,6 +157,12 @@ _JOIN_UNIQUE = -2           # minus twice the join's index: key of the flag
 #                             null-extended rows)
 _JOIN_EXPAND = -3           # minus twice the join's index: key of the slots
 #                             its expansion touched, and its frames'
+_KEY_SLOTS = -1             # minus the aggregate's index: key, in a capped
+#                             program's `bytes_map`, of the key planes x
+#                             slots its group-by gathers (0: its keys
+#                             ride). There and in no map of its own:
+#                             `_jitted_capped` hands back four values, and
+#                             chipbench's deviceless compile unpacks them
 
 
 def _scope_name(idx: int, node: PlanNode) -> str:
@@ -538,6 +544,12 @@ class PlanResult:
         self.group_slots = 0          # their finish ran over (the groups in
         #                               the eager tier, the key caps in the
         #                               capped)
+        self.group_key_slots_gathered = 0  # and the key planes (a key's
+        #                               data, its validity) x slots that
+        #                               went through a `take` by the
+        #                               groups' first rows (0 where every
+        #                               key rode the compaction sort:
+        #                               ops/aggregate.py)
         self.expand_slots = 0         # capped tier, over the joins that
         self.expand_cap_slots = 0     # expanded (the general tail, the
         #                               Pallas join): left rows the scatter
@@ -742,6 +754,8 @@ class PlanExecutor:
             sp.set_metadata(decimal_overflow_rows=res.decimal_overflow_rows,
                             group_rows=res.group_rows, groups=res.groups,
                             group_slots=res.group_slots,
+                            group_key_slots_gathered=(
+                                res.group_key_slots_gathered),
                             unique_joins=res.unique_joins,
                             expand_joins=res.expand_joins,
                             lookup_joins=res.lookup_joins,
@@ -773,8 +787,9 @@ class PlanExecutor:
             return res
 
     def _count_groups(self, res: PlanResult) -> None:
-        """`group_rows`, `groups`, `group_slots` of a result, from its
-        operators' metrics (a cached or degraded result keeps its own)."""
+        """`group_rows`, `groups`, `group_slots`, `group_key_slots_gathered`
+        of a result, from its operators' metrics (a cached or degraded
+        result keeps its own)."""
         if res.cached or res.group_slots:
             return
         for i, node in enumerate(res.plan.nodes):
@@ -787,6 +802,7 @@ class PlanExecutor:
             res.group_slots += (self._node_cap(res.caps, "key_cap", i)
                                 if res.mode == "capped" and res.caps
                                 else int(m.rows_out))
+            res.group_key_slots_gathered += int(m.key_slots_gathered)
 
     @staticmethod
     def _count_lookups(res: PlanResult) -> None:
@@ -2055,8 +2071,11 @@ class PlanExecutor:
             # scatter pick keys on jax.default_backend(), exactly like the
             # kernel itself
             self._kernel_choice("groupby", None, m, pin_degraded=False)
-            agg = ops.groupby_aggregate(t, list(node.keys),
-                                        [(c, o) for c, o, _ in node.aggs])
+            from ..ops.aggregate import group_keys
+            with group_keys.collect() as keyed:
+                agg = ops.groupby_aggregate(
+                    t, list(node.keys), [(c, o) for c, o, _ in node.aggs])
+            m.key_slots_gathered = keyed[-1][1]
             out_names = schemas[id(node)]
             # the kernel says its groups lie in key order: so do these
             return Table(list(agg.columns), names=out_names,
@@ -2433,7 +2452,8 @@ class PlanExecutor:
                     rows_in=rows_in, rows_out=rows_out,
                     bytes_out=bytes_map.get(i, 0),
                     escalations=escal if uses_cap else 0,
-                    kernel=kernel)
+                    kernel=kernel,
+                    key_slots_gathered=bytes_map.get(_KEY_SLOTS - i, 0))
                 if isinstance(node, HashJoin) and nullable_sides(node.how)[1]:
                     metrics[node.label].unmatched_rows = int(
                         counts_np[_JOIN_UNIQUE - 2 * i][1])
@@ -2521,6 +2541,7 @@ class PlanExecutor:
         rels: Dict[int, _CappedRel] = {}
         # counts/bytes key on the toposort index: stable across
         # fingerprint-equal plans, whose labels differ (see _jitted_capped)
+        from ..ops.aggregate import group_keys
         from ..ops.decimal_utils import overflow_counts
         counts: Dict[int, Tuple] = {}
         overflow = jnp.asarray(False)
@@ -2529,9 +2550,12 @@ class PlanExecutor:
                 childs = [rels[id(c)] for c in node.children]
                 # the operator's name inside the program, which
                 # device_op_owners reads back
-                with jax.named_scope(_scope_name(i, node)):
+                with jax.named_scope(_scope_name(i, node)), \
+                        group_keys.collect() as keyed:
                     rel, ovf = self._exec_capped_node(
                         node, i, childs, tables, schemas, caps, kernel_map)
+                    if keyed:       # a keyed aggregate: what it gathers
+                        bytes_map[_KEY_SLOTS - i] = keyed[-1][1]
                     if ovf is not None:
                         overflow = overflow | ovf
                     if rel.unique is not None:

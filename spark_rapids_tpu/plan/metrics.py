@@ -74,6 +74,11 @@ class OperatorMetrics:
     unmatched_rows: int = 0
     # right rows a `full_outer` join put out null-extended, else 0
     unmatched_right_rows: int = 0
+    # a keyed `HashAggregate`: the key planes (a key column's data, its
+    # validity) x slots its group-by gathered through the groups' first
+    # rows (the groups in the eager tier, the key cap in the capped one); 0
+    # where every key rode the compaction sort (ops/aggregate.py)
+    key_slots_gathered: int = 0
     # an eager `Window`: its partitions, whether its kernel sorted (`sort`)
     # or took the child's order (`child`), and the sort's key: `packed`
     # (every key operand and the row number in one 64-bit word),

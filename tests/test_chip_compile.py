@@ -765,73 +765,141 @@ def test_window_kernel_compiles_for_v5e(one_chip, no_persistent_cache,
 
 
 # sha256 (first 16 hex digits) of `_groupby_kernel.lower(...).as_text()` at
-# the shapes of the cells that run it, taken on the parent of PR 47
-# (5d70ac1) and the same on its tree: the sort and the run flags moved
-# into `sorted_runs`, which the window kernel calls too, and the programs
-# are the parent's text for text. (Compiling them for a described v5e
-# takes 4 to 8 minutes each: the stable sorts; tier-1 holds the text.)
-GROUPBY_TEXT_AT_PR46 = {
-    "q18.batch": "6bd43132fc25418b",        # 60 M rows, decimal planes
-    "q97.batch": "d13056d635b90a43",        # a DISTINCT, two nullable keys
-    "q13.batch/count": "9f182b2db03ef9d3",  # count of a nullable column
-    "q13.batch/size": "6c25a289b7124646",
+# the shapes of the cells that run it, with `ride_keys` as the cells run it
+# since PR 48 (every key operand of these four rides the compaction sort,
+# which is keyed on the start's position and not stable; the row number
+# rides in none). Taken on PR 48's tree: a later PR that means to leave
+# the group-by's programs alone holds them to these. (Compiling them for
+# a described v5e takes 3 to 6 minutes each; tier-1 holds the text.)
+GROUPBY_TEXT_AT_PR48 = {
+    "q18.batch": "c941b114f2a13391",        # 60 M rows, decimal planes
+    "q97.batch": "6df180c8cb8d9fcd",        # a DISTINCT, two nullable keys
+    "q13.batch/count": "83957acb55f1999a",  # count of a nullable column
+    "q13.batch/size": "b248de870018fbe1",
 }
 
 
-def _groupby_lowering(cell: str):
-    from spark_rapids_tpu.ops import aggregate
+def _shape(n, dtype):
+    return jax.ShapeDtypeStruct((n,), jnp.dtype(dtype))
 
-    def shape(n, dtype):
-        return jax.ShapeDtypeStruct((n,), jnp.dtype(dtype))
+
+def _groupby_lowering(cell: str):
+    """`_groupby_kernel` lowered at an eager cell's shape, its keys riding
+    as `ops/aggregate.py:_groupby` has them ride there (integer keys on
+    frames over `KEPT_FLOOR` rows: every key operand)."""
+    from spark_rapids_tpu.ops import aggregate
     if cell == "q18.batch":
         n = 59_986_052
         return aggregate._groupby_kernel.lower(
-            (shape(n, "int64"),),
-            (shape(n, "uint32"), shape(n, "int32"), shape(n, "int8")),
+            (_shape(n, "int64"),),
+            (_shape(n, "uint32"), _shape(n, "int32"), _shape(n, "int8")),
             (None, None, None), n_ops=1, agg_kinds=("sum", "sum", "count"),
-            has_valids=(False,) * 3, has_alive=False, gather_payloads=True)
+            has_valids=(False,) * 3, has_alive=False, gather_payloads=True,
+            ride_keys=(0,))
     if cell == "q97.batch":
         n = 6_874_157
-        ops = tuple(shape(n, d) for d in ("int32", "int64") * 2)
+        ops = tuple(_shape(n, d) for d in ("int32", "int64") * 2)
         return aggregate._groupby_kernel.lower(
             ops, (), (), n_ops=4, agg_kinds=(), has_valids=(),
-            has_alive=False, gather_payloads=False)
+            has_alive=False, gather_payloads=False, ride_keys=(0, 1, 2, 3))
+    if cell == "q51.batch":
+        # (item, d_date), neither nullable (the item's mask stays behind
+        # its `IS NOT NULL` filter), a nullable int64 sum riding
+        n = int(_q51_batch()["store_date_rows"])
+        return aggregate._groupby_kernel.lower(
+            (_shape(n, "int64"), _shape(n, "int64")), (_shape(n, "int64"),),
+            (_shape(n, "bool"),), n_ops=2, agg_kinds=("sum",),
+            has_valids=(True,), has_alive=False, gather_payloads=False,
+            ride_keys=(0, 1))
     if cell == "q13.batch/count":
         n = 15_334_665
         return aggregate._groupby_kernel.lower(
-            (shape(n, "int64"),), (shape(n, "int8"),), (shape(n, "bool"),),
-            n_ops=1, agg_kinds=("count",), has_valids=(True,),
-            has_alive=False, gather_payloads=False)
+            (_shape(n, "int64"),), (_shape(n, "int8"),),
+            (_shape(n, "bool"),), n_ops=1, agg_kinds=("count",),
+            has_valids=(True,), has_alive=False, gather_payloads=False,
+            ride_keys=(0,))
     n = 1_500_000
     return aggregate._groupby_kernel.lower(
-        (shape(n, "int64"),), (shape(n, "int8"),), (None,), n_ops=1,
+        (_shape(n, "int64"),), (_shape(n, "int8"),), (None,), n_ops=1,
         agg_kinds=("size",), has_valids=(False,), has_alive=False,
-        gather_payloads=False)
+        gather_payloads=False, ride_keys=(0,))
 
 
-@pytest.mark.parametrize("cell", sorted(GROUPBY_TEXT_AT_PR46))
+@pytest.mark.parametrize("cell", sorted(GROUPBY_TEXT_AT_PR48))
 def test_groupby_kernel_lowers_to_the_text_it_had(cell):
     text = _groupby_lowering(cell).as_text()
     assert hashlib.sha256(text.encode()).hexdigest()[:16] \
-        == GROUPBY_TEXT_AT_PR46[cell]
+        == GROUPBY_TEXT_AT_PR48[cell]
+
+
+def _lowered_sorts(text: str):
+    """-> [(stable, 32-bit words a row over its operands)] of the sorts in
+    a lowered (StableHLO) text, in order: a 64-bit operand is two words
+    (the chip's compiler splits it), anything narrower one."""
+    out = []
+    for stable, args in re.findall(
+            r'"stablehlo\.sort"\(.*?is_stable = (\w+)\}> \(\{\s*'
+            r'\^bb0\((.*?)\):', text, flags=re.S):
+        bits = [int(b) for b in re.findall(r"tensor<[a-z]+(\d+)>", args)]
+        assert len(bits) % 2 == 0       # the comparator sees each twice
+        out.append((stable == "true", sum(max(b // 32, 1) for b in bits) // 2))
+    return out
 
 
 def test_q51_keyed_sum_lowers_through_the_shared_sort(one_chip):
     """`q51.batch`'s keyed sums (6.9 M and 1.7 M rows into (item, day)
     groups, a nullable int64 sum riding) are `_groupby_kernel` as split:
-    two sorts (the key sort with the value and its mask riding, the
-    compaction sort), both stable as every cell's group-by still has them
-    (ROADMAP S6 (e)), and the run flags by `run_boundaries`."""
-    from spark_rapids_tpu.ops import aggregate
-    n = int(_q51_batch()["store_date_rows"])
-
-    def shape(dtype):
-        return jax.ShapeDtypeStruct((n,), jnp.dtype(dtype))
-    text = aggregate._groupby_kernel.lower(
-        (shape("int32"), shape("int64"), shape("int64")),
-        (shape("int64"),), (shape("bool"),), n_ops=3, agg_kinds=("sum",),
-        has_valids=(True,), has_alive=False,
-        gather_payloads=False).as_text()
+    two sorts, the key sort with the value and its mask riding, a stable
+    one as every cell's group-by with an aggregate still has it (ROADMAP
+    S6 (e)), and the compaction sort, which since PR 48 is not; the run
+    flags by `run_boundaries`."""
+    text = _groupby_lowering("q51.batch").as_text()
     sorts = re.findall(r"stablehlo\.sort.*?is_stable = (\w+)", text,
                        flags=re.S)
-    assert sorts == ["true", "true"]
+    assert sorts == ["true", "false"]
+
+
+@pytest.mark.parametrize("cell,words", [
+    ("q18.batch", 7),           # position; the int64 key; two int64 sums
+    ("q13.batch/count", 4),     # position; the int64 key; the count
+    ("q97.batch", 7),           # position; a rank and an int64, twice
+    ("q51.batch", 8),           # position; two int64 keys; count; sum
+])
+def test_compaction_sort_carries_the_keys_in_the_row_numbers_place(cell,
+                                                                   words):
+    """The sorted group-by's compaction sort at each eager cell's shape
+    (PR 48): ONE key, the start's position, not stable; the groups' key
+    operands ride it and neither a `flag` operand nor the first rows'
+    numbers do, so `q18.batch` (whose peak memory is this sort's operands
+    in and out, 0.24 GB a word a side) and `q13.batch` carry the words
+    they carried. (ISSUE 48 reckoned nine for `q51.batch`: its date key
+    comes from `date_dim` without a mask, so no rank rides.)"""
+    *_, (stable, got) = _lowered_sorts(_groupby_lowering(cell).as_text())
+    assert (stable, got) == (False, words)
+
+
+def test_capped_groupby_at_q3_tasks_shape_lowers_without_key_words(
+        monkeypatch):
+    """The capped tier's keyed aggregate at `q3.tasks`' shape (a frame of
+    360,000 rows, a key cap of 8,192, two int64 keys and a live mask):
+    16,384 gathered key slots are 0.25 ms, three words riding over
+    360,000 rows 1 ms (`ops/gather.py:words_ride`), so its compaction
+    sort carries the position, the first rows' numbers and the sum, and
+    no key word; the keys are gathered over the cap's slots."""
+    from spark_rapids_tpu.ops import aggregate
+    monkeypatch.setenv("SPARK_RAPIDS_TPU_GROUPBY_KERNEL", "scan")
+    n, cap = 360_000, 8_192
+    t = Table([_i64(n), _i64(n), _i64(n)], names=["y", "b", "v"])
+
+    def capped(t, alive):
+        return aggregate.groupby_aggregate_capped(
+            t, ["y", "b"], [("v", "sum")], key_cap=cap, alive=alive)
+    with aggregate.group_keys.collect() as did:
+        text = jax.jit(capped).lower(t, _shape(n, "bool")).as_text()
+    assert did == [("take", 2 * cap)]
+    (stable, keyed), (stable2, comp) = _lowered_sorts(text)
+    assert (stable, stable2) == (True, False)
+    assert comp == 4            # position, first row, the int64 sum
+    # `jnp.take` lowers to one private function, called for each key
+    takes = re.findall(r"call @_take\w*\(.*?-> tensor<(\d+)xi64>", text)
+    assert takes == [str(cap)] * 2

@@ -728,19 +728,60 @@ def test_groupby_span_and_the_requests_group_counters(session, tier):
     assert res.group_slots == (7 if tier == "eager" else 16)
     assert (got["group_rows"], got["groups"], got["group_slots"]) \
         == (res.group_rows, res.groups, res.group_slots)
+    # a frame of a few hundred rows: the one key column (a plane) is
+    # gathered through the groups' first rows, over the finish's slots
+    assert got["group_key_slots_gathered"] == res.group_slots \
+        == res.group_key_slots_gathered == agg.key_slots_gathered
     if tier == "eager":
         (op,) = [o for o in spans.named("plan.op")
                  if o["op"].endswith(".HashAggregate")]
         g = spans.one("ops.groupby")
         assert inside(g, op) and g["request"] == op["request"]
         assert (g["rows"], g["groups"], g["planes"]) == (agg.rows_in, 7, 0)
-        assert g["kernel"] in ("scan", "scatter")
+        assert g["kernel"] in ("scan", "scatter") and g["keys"] == "take"
     else:
         assert not spans.named("ops.groupby")     # one program, run warm
         owners = ex.device_op_owners(plan, inputs, nested=True)
         held = {o for o in owners.values() if o.endswith("/ops.groupby")}
         assert len(held) == 1 and held.pop().split("/")[0] \
             .endswith(".HashAggregate")
+
+
+@pytest.mark.parametrize("tier,key_cap,keys", [
+    ("eager", None, "ride"), ("eager", None, "take"),
+    ("capped", 64, "ride"), ("capped", 16, "take")])
+def test_groupby_says_how_its_keys_came_back(session, monkeypatch, tier,
+                                             key_cap, keys):
+    """`ops.groupby` says `keys=`: `ride` (the `scan` kernel's compaction
+    sort carried the key operands; nothing gathered), `take` (through the
+    groups' first rows) or `ride+take`; `plan.execute` counts the key
+    planes x slots gathered, `group_key_slots_gathered`, 0 where every
+    key rode. The choice is `ops/gather.py:words_ride`'s arithmetic: the
+    eager tier rides over `KEPT_FLOOR` rows (lowered here), the capped
+    one where the cap's slots are dear enough beside the frame's rows (a
+    frame of 512: a cap of 64 rides, one of 16 gathers 16 slots). The
+    capped program's span is a trace-time one: the run is a cold one."""
+    from spark_rapids_tpu.ops import gather
+    monkeypatch.setenv("SPARK_RAPIDS_TPU_GROUPBY_KERNEL", "scan")
+    if (tier, keys) != ("eager", "take"):
+        monkeypatch.setattr(gather, "KEPT_FLOOR", 0)
+    plan, inputs = _join_plan(), {"t": _fact(), "d": _dim()}
+    ex = PlanExecutor(mode=tier, **({"caps": dict(row_cap=512,
+                                                   key_cap=key_cap)}
+                                    if tier == "capped" else {}))
+    done = []
+    spans = session(lambda: done.append(ex.execute(plan, inputs)))
+    res, got = done[0], spans.one("plan.execute")
+    (agg,) = [m for m in res.metrics.values() if m.kind == "HashAggregate"]
+    assert {g["keys"] for g in spans.named("ops.groupby")} == {keys}
+    want = 0 if keys == "ride" else res.group_slots
+    assert (got["group_key_slots_gathered"], res.group_key_slots_gathered,
+            agg.key_slots_gathered) == (want,) * 3
+    assert res.groups == 7 and res.compact()["g"].to_pylist() \
+        == list(range(7))
+    # a warm run of the capped program says the same without a trace
+    again = ex.execute(plan, inputs)
+    assert again.group_key_slots_gathered == want
 
 
 def _outer_plan():
